@@ -1,0 +1,823 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — prove that the serving path still starts and answers on the chip.
+
+    python3 chip_smoke.py             one chip: serve qwen3-0.6b, then check kernels
+    python3 chip_smoke.py --chips 4   four chips: tp=4 against tp=1, and llama3-8b tp=4
+
+Run it where JAX finds a TPU (the builder's chip tool); without one it exits
+non-zero and prints no result line. This process never initialises a JAX
+backend — a chip belongs to one process at a time — so everything that needs
+the chip runs in a child, one child on the chip at a time:
+
+1. SERVING. The processes a user starts (README "Quick start"): a discovery
+   store, ``python -m dynamo_tpu.engine --platform tpu --preset qwen3-0.6b``
+   (full published width and depth, random bf16 weights from the engine's fixed
+   seed) and ``python -m dynamo_tpu.frontend`` (kept off the chip with
+   JAX_PLATFORMS=cpu). OpenAI requests go over HTTP to the frontend: chat and
+   completions, streaming and not, greedy and sampled, prompts in two prefill
+   buckets and one multi-chunk prefill, then long prompts admitted on top of
+   resident decodes so the fused mixed step is dispatched.
+2. KERNELS. After the servers have released the chip, a child runs every
+   Pallas kernel of that path compiled at qwen3-0.6b's shapes against its
+   pure-JAX twin on the same chip.
+
+With ``--chips 4`` only the sharded path and what it is compared with run:
+qwen3-0.6b at --tp 1 and --tp 4 (same seed, same prompts), then
+``--preset llama3-8b --tp 4``, each through the same engine entry point.
+
+Any failed check, a child that dies, or a platform other than ``tpu`` ends
+the run non-zero. The last line of a passing run is
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``.
+Child logs go to ``chiprun_out/chip_smoke/``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+import urllib.error
+import urllib.request
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, Iterator, List, Optional
+
+from dynamo_tpu.runtime.device import compile_cache_dir  # imports no JAX
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+LOG_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
+MODEL = "smoke"
+SEED = 0
+PLATFORM = "tpu"
+
+# kernel-against-twin tolerance, per element: 2^-6 * max(1, |twin|). Both
+# sides accumulate in float32 from the same bf16 inputs (the twin at
+# "highest" matmul precision) and round once to bf16, whose spacing is 2^-7
+# relative at the bottom of a binade: the bound is two such steps, so two
+# roundings that fall either side of a boundary pass and a wrong key or a
+# wrong mask (errors of order 1) cannot.
+KERNEL_TOL = 2.0 ** -6
+# tp=4 against tp=1, per prompt: greedy tokens agree until a near-tie. The
+# psum changes the order of bf16 additions, so exact agreement for ever is
+# not promised (random weights make flat logits and frequent ties); a wrong
+# shard would move logprobs by nats. While the tokens agree their logprobs
+# differ by at most this much, and where they first part each side ranks
+# the other's token in its top 5 within this much of its own choice.
+TP_LOGPROB_TOL = 0.25
+# bytes in use on the fullest device over the emptiest, llama3-8b at tp=4
+TP_BALANCE_RATIO = 1.25
+
+
+class SmokeFailure(Exception):
+    """A check of the smoke did not hold."""
+
+
+def check(cond: bool, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+# ------------------------------------------------------------------ children
+class Child:
+    """One child process with its output in a log file."""
+
+    def __init__(self, name: str, argv: List[str], env: Dict[str, str]):
+        os.makedirs(LOG_DIR, exist_ok=True)
+        self.name = name
+        self.log_path = os.path.join(LOG_DIR, f"{name}.log")
+        self._log = open(self.log_path, "wb")
+        self.started = time.monotonic()
+        self.proc = subprocess.Popen(
+            argv, cwd=REPO, env=env, stdout=self._log,
+            stderr=subprocess.STDOUT,
+        )
+
+    def log_text(self) -> str:
+        with open(self.log_path, "r", errors="replace") as f:
+            return f.read()
+
+    def wait_for(self, marker: str, timeout_s: float) -> float:
+        """Seconds from process start until ``marker`` shows in the log."""
+        deadline = time.monotonic() + timeout_s
+        while time.monotonic() < deadline:
+            if marker in self.log_text():
+                return time.monotonic() - self.started
+            if self.proc.poll() is not None:
+                self.fail(f"exited with {self.proc.returncode} before {marker!r}")
+            time.sleep(0.2)
+        self.fail(f"no {marker!r} within {timeout_s:.0f}s")
+
+    def fail(self, why: str) -> None:
+        sys.stderr.write(
+            f"--- {self.name}: {why}; end of {self.log_path}:\n"
+            + self.log_text()[-6000:] + "\n"
+        )
+        raise SmokeFailure(f"{self.name}: {why}")
+
+    def alive(self) -> bool:
+        return self.proc.poll() is None
+
+    def wait(self, timeout_s: float) -> int:
+        """The exit code (SIGKILL past the timeout)."""
+        try:
+            code = self.proc.wait(timeout=timeout_s)
+        except subprocess.TimeoutExpired:
+            self.proc.kill()
+            self.proc.wait()
+            code = -9
+        self._log.close()
+        return code
+
+    def terminate(self, timeout_s: float = 60.0) -> int:
+        """SIGTERM, then the exit code."""
+        if self.alive():
+            self.proc.send_signal(signal.SIGTERM)
+        return self.wait(timeout_s)
+
+    def maps_libtpu(self) -> bool:
+        """Whether the TPU runtime is mapped into the process — it is once a
+        TPU backend was initialised, and never before."""
+        with open(f"/proc/{self.proc.pid}/maps", "r") as f:
+            return "libtpu" in f.read()
+
+
+def child_env(on_chip: bool) -> Dict[str, str]:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["TPU_LOG_DIR"] = env.get("TPU_LOG_DIR", "disabled")
+    if on_chip:
+        # the worker forces its platform itself (--platform tpu)
+        env.pop("JAX_PLATFORMS", None)
+    else:
+        env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
+# ---------------------------------------------------------------------- HTTP
+def http_get(url: str, timeout_s: float = 30.0) -> bytes:
+    with urllib.request.urlopen(url, timeout=timeout_s) as r:
+        check(r.status == 200, f"GET {url} -> {r.status}")
+        return r.read()
+
+
+def sse_events(resp) -> Iterator[Dict[str, Any]]:
+    for raw in resp:
+        line = raw.decode("utf-8").strip()
+        if not line.startswith("data:"):
+            continue
+        data = line[5:].strip()
+        if data == "[DONE]":
+            return
+        yield json.loads(data)
+
+
+class Answer:
+    """One finished OpenAI response, streaming or not, in one shape."""
+
+    def __init__(self) -> None:
+        self.text = ""
+        self.finish_reason: Optional[str] = None
+        self.usage: Dict[str, Any] = {}
+        self.tokens: List[str] = []     # per-token strings (logprobs on)
+        self.logprobs: List[float] = []
+        self.top: List[Dict[str, float]] = []   # per-token alternatives
+        self.seconds = 0.0
+
+    def take_choice(self, choice: Dict[str, Any], chat: bool) -> None:
+        if chat:
+            part = choice.get("delta") or choice.get("message") or {}
+            self.text += part.get("content") or ""
+            for ent in (choice.get("logprobs") or {}).get("content") or []:
+                self.tokens.append(ent["token"])
+                self.logprobs.append(ent["logprob"])
+        else:
+            self.text += choice.get("text") or ""
+            lp = choice.get("logprobs") or {}
+            self.tokens += lp.get("tokens") or []
+            self.logprobs += lp.get("token_logprobs") or []
+            self.top += lp.get("top_logprobs") or []
+        if choice.get("finish_reason"):
+            self.finish_reason = choice["finish_reason"]
+
+
+def ask(base: str, path: str, body: Dict[str, Any],
+        on_first=None, timeout_s: float = 900.0) -> Answer:
+    """POST an OpenAI request; any status but 200 fails. ``on_first`` fires
+    when the first streamed chunk arrives."""
+    chat = path.endswith("/chat/completions")
+    body = dict(body, model=MODEL)
+    if body.get("stream"):
+        body["stream_options"] = {"include_usage": True}
+    req = urllib.request.Request(
+        base + path, data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"},
+    )
+    ans = Answer()
+    t0 = time.monotonic()
+    try:
+        resp = urllib.request.urlopen(req, timeout=timeout_s)
+    except urllib.error.HTTPError as e:
+        raise SmokeFailure(
+            f"POST {path} -> {e.code}: {e.read()[:500]!r}"
+        ) from None
+    with resp:
+        check(resp.status == 200, f"POST {path} -> {resp.status}")
+        if body.get("stream"):
+            for ev in sse_events(resp):
+                check("error" not in ev, f"{path} streamed an error: {ev}")
+                for choice in ev.get("choices") or []:
+                    ans.take_choice(choice, chat)
+                if ev.get("usage"):
+                    ans.usage = ev["usage"]
+                if on_first is not None:
+                    on_first()
+                    on_first = None
+        else:
+            doc = json.loads(resp.read())
+            for choice in doc["choices"]:
+                ans.take_choice(choice, chat)
+            ans.usage = doc.get("usage") or {}
+    ans.seconds = time.monotonic() - t0
+    return ans
+
+
+def check_answer(ans: Answer, what: str, *, max_tokens: int,
+                 prompt_tokens: Optional[int] = None,
+                 exact_length: bool = True) -> None:
+    check(bool(ans.text), f"{what}: empty text")
+    u = ans.usage
+    check(bool(u), f"{what}: no usage")
+    if exact_length:
+        check(ans.finish_reason == "length",
+              f"{what}: finish_reason {ans.finish_reason!r}, want 'length'")
+        check(u["completion_tokens"] == max_tokens,
+              f"{what}: {u['completion_tokens']} completion tokens, "
+              f"asked {max_tokens}")
+    else:
+        check(ans.finish_reason in ("length", "stop"),
+              f"{what}: finish_reason {ans.finish_reason!r}")
+        check(1 <= u["completion_tokens"] <= max_tokens,
+              f"{what}: {u['completion_tokens']} completion tokens of "
+              f"{max_tokens}")
+        if ans.finish_reason == "length":
+            check(u["completion_tokens"] == max_tokens,
+                  f"{what}: finished by length short of max_tokens")
+    if prompt_tokens is not None:
+        check(u["prompt_tokens"] == prompt_tokens,
+              f"{what}: {u['prompt_tokens']} prompt tokens, sent "
+              f"{prompt_tokens}")
+    check(u["total_tokens"] == u["prompt_tokens"] + u["completion_tokens"],
+          f"{what}: usage does not add up: {u}")
+
+
+def token_prompt(n: int, salt: int) -> List[int]:
+    """n token ids from the seed (the byte tokenizer's plain range)."""
+    return [(SEED * 7919 + salt * 131 + j * 7) % 251 + 1 for j in range(n)]
+
+
+def text_prompt(n_bytes: int, salt: int) -> str:
+    words = ["paged", "cache", "kernel", "decode", "prefill", "router",
+             "block", "shard", "token", "mesh", "batch", "chunk"]
+    out, j = [], SEED + salt
+    while sum(len(w) + 1 for w in out) < n_bytes:
+        out.append(words[(j * 5 + salt) % len(words)])
+        j += 1
+    return " ".join(out)[:n_bytes]
+
+
+# ------------------------------------------------------------------- serving
+class Stack:
+    """Discovery store + one engine worker on the chip + the frontend. A
+    context manager: whatever is still running on the way out is stopped,
+    also when the stack never came up."""
+
+    def __init__(self, tag: str, worker_args: List[str],
+                 ready_timeout_s: float):
+        self.tag = tag
+        self.children: List[Child] = []
+        try:
+            self._start_all(worker_args, ready_timeout_s)
+        except BaseException:
+            self.close()
+            raise
+
+    def __enter__(self) -> "Stack":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    def _start_all(self, worker_args: List[str],
+                   ready_timeout_s: float) -> None:
+        py = sys.executable
+        store_port, self.status_port, http_port = (
+            free_port(), free_port(), free_port()
+        )
+        store_args = ["--store", "tcp", "--store-path",
+                      f"127.0.0.1:{store_port}"]
+        self.store = self._start(
+            "store", [py, "-m", "dynamo_tpu.runtime.discovery.netstore",
+                      "--port", str(store_port)], on_chip=False)
+        self.store.wait_for("KVSTORE_READY", 60)
+        self.worker = self._start(
+            "worker", [py, "-m", "dynamo_tpu.engine", "--platform", PLATFORM,
+                       "--model", MODEL, "--status-port",
+                       str(self.status_port)] + store_args + worker_args,
+            on_chip=True)
+        self.frontend = self._start(
+            "frontend", [py, "-m", "dynamo_tpu.frontend", "--host",
+                         "127.0.0.1", "--port", str(http_port)] + store_args,
+            on_chip=False)
+        self.base = f"http://127.0.0.1:{http_port}"
+        self.status = f"http://127.0.0.1:{self.status_port}"
+        self.cold_start_s = self.worker.wait_for(
+            "TPU_ENGINE_READY", ready_timeout_s
+        )
+        deadline = time.monotonic() + 60
+        while MODEL not in self._models():
+            check(time.monotonic() < deadline,
+                  "frontend never listed the worker's model")
+            check(self.frontend.alive(), "frontend died")
+            time.sleep(0.2)
+
+    def _start(self, name: str, argv: List[str], on_chip: bool) -> Child:
+        child = Child(f"{self.tag}-{name}", argv, child_env(on_chip))
+        self.children.append(child)
+        return child
+
+    def _models(self) -> List[str]:
+        try:
+            doc = json.loads(http_get(self.base + "/v1/models", 5))
+        except (urllib.error.URLError, ConnectionError, SmokeFailure):
+            return []
+        return [m["id"] for m in doc.get("data", [])]
+
+    def metadata(self) -> Dict[str, Any]:
+        return json.loads(http_get(self.status + "/metadata"))
+
+    def step_counts(self) -> Dict[str, int]:
+        """phase -> engine steps, from the worker's /metrics."""
+        counts: Dict[str, int] = {}
+        prefix = "dtpu_engine_step_duration_seconds_count{"
+        for line in http_get(self.status + "/metrics").decode().splitlines():
+            if line.startswith(prefix) and 'phase="' in line:
+                phase = line.split('phase="', 1)[1].split('"', 1)[0]
+                counts[phase] = counts.get(phase, 0) + int(
+                    float(line.rsplit(" ", 1)[1])
+                )
+        return counts
+
+    def check_processes(self) -> None:
+        """Only the worker may have brought up a TPU backend."""
+        check(self.worker.maps_libtpu(),
+              "the worker never loaded the TPU runtime")
+        for child in (self.store, self.frontend):
+            check(not child.maps_libtpu(),
+                  f"{child.name} loaded the TPU runtime: it must stay off "
+                  "the chip")
+
+    def stop(self) -> None:
+        """SIGTERM every child; each must be alive until then and exit 0."""
+        dead = [c.name for c in self.children if not c.alive()]
+        codes = self.close()
+        check(not dead, f"children died before shutdown: {dead}")
+        bad = {n: c for n, c in codes.items() if c != 0}
+        check(not bad, f"children did not exit 0 on SIGTERM: {bad}")
+
+    def close(self) -> Dict[str, int]:
+        """Stop whatever still runs, frontend first; name -> exit code."""
+        codes = {c.name: c.terminate() for c in reversed(self.children)}
+        self.children = []
+        return codes
+
+
+def report_worker(stack: Stack) -> Dict[str, Any]:
+    """Print what the worker says it runs on, and hold it to the chip."""
+    meta = stack.metadata()
+    eng = meta["engine"]
+    dev = eng["device"]
+    say(f"[{stack.tag}] worker device: {json.dumps(dev)}")
+    say(f"[{stack.tag}] use_pallas: {json.dumps(eng['use_pallas'])}  "
+        f"mixed_enabled: {json.dumps(eng['mixed_enabled'])}  "
+        f"kernels_interpreted: {json.dumps(eng['kernels_interpreted'])}  "
+        f"decode_steps: {eng['decode_steps']}  "
+        f"decode_pipeline: {eng['decode_pipeline']}")
+    check(dev["platform"] == PLATFORM, f"worker platform {dev['platform']!r}")
+    check(eng["use_pallas"] is True, "the Pallas kernels resolved off")
+    check(eng["kernels_interpreted"] is False,
+          "Pallas kernels run in the interpreter")
+    return meta
+
+
+def serving_phase() -> Dict[str, Any]:
+    with Stack(
+        "serve",
+        ["--preset", "qwen3-0.6b", "--prefill-chunk", "512",
+         "--max-context", "2048"],
+        ready_timeout_s=600,
+    ) as stack:
+        say(f"[serve] cold start (process start -> TPU_ENGINE_READY): "
+            f"{stack.cold_start_s:.1f} s")
+        meta = report_worker(stack)
+        check(meta["engine"]["mixed_enabled"] is True,
+              "the fused mixed step resolved off")
+        base = stack.base
+        comp, chat = "/v1/completions", "/v1/chat/completions"
+        greedy = {"temperature": 0.0, "ignore_eos": True, "max_tokens": 64}
+
+        # -- one at a time: two prefill buckets, one multi-chunk prefill --
+        short = token_prompt(40, 1)                   # bucket 64
+        first = ask(base, comp, dict(greedy, prompt=short))
+        check_answer(first, "first completion", max_tokens=64,
+                     prompt_tokens=40)
+        say(f"[serve] first request (compiles): {first.seconds:.1f} s")
+        again = ask(base, comp, dict(greedy, prompt=short))
+        check_answer(again, "repeated completion", max_tokens=64,
+                     prompt_tokens=40)
+        say(f"[serve] same request, warm: {again.seconds:.2f} s")
+        check(again.text == first.text,
+              "the same greedy request gave two different texts")
+        check((again.usage.get("cached_tokens") or 0) > 0,
+              f"repeated prompt reported no cached tokens: {again.usage}")
+
+        mid = text_prompt(180, 2)                     # bucket 256 w/ template
+        streamed = ask(base, chat, dict(
+            greedy, messages=[{"role": "user", "content": mid}], stream=True,
+        ))
+        check_answer(streamed, "streamed chat", max_tokens=64)
+        check(streamed.usage["prompt_tokens"] >= 180,
+              f"chat prompt shorter than its content: {streamed.usage}")
+        fork = ask(base, chat, dict(
+            greedy,
+            messages=[{"role": "user", "content": mid + " and then some"}],
+        ))
+        check_answer(fork, "chat sharing a prefix", max_tokens=64)
+        check((fork.usage.get("cached_tokens") or 0) > 0,
+              f"shared prefix reported no cached tokens: {fork.usage}")
+
+        sampled = ask(base, chat, {
+            "messages": [{"role": "user", "content": text_prompt(60, 3)}],
+            "temperature": 0.8, "top_p": 0.9, "top_k": 50, "seed": 7,
+            "max_tokens": 64,
+        })
+        check_answer(sampled, "sampled chat", max_tokens=64,
+                     exact_length=False)
+
+        long_ = token_prompt(1100, 4)                 # chunks 512 + 512 + 76
+        chunked = ask(base, comp, dict(greedy, prompt=long_, stream=True))
+        check_answer(chunked, "multi-chunk streamed completion",
+                     max_tokens=64, prompt_tokens=1100)
+
+        # -- together: long prefills admitted on top of resident decodes --
+        residents = [
+            dict(greedy, prompt=token_prompt(48, 10 + i), stream=True,
+                 max_tokens=1024, **extra)
+            for i, extra in enumerate((
+                {}, {}, {"temperature": 0.7, "top_p": 0.95, "seed": 3},
+            ))
+        ]
+        riders = [
+            dict(greedy, prompt=token_prompt(1100, 20)),
+            dict(greedy, prompt=token_prompt(700, 21)),
+        ]
+        with ThreadPoolExecutor(len(residents) + len(riders)) as pool:
+            decoding = [threading.Event() for _ in residents]
+            res_f = [
+                pool.submit(ask, base, comp, body, ev.set)
+                for body, ev in zip(residents, decoding)
+            ]
+            for ev, fut in zip(decoding, res_f):
+                while not ev.wait(0.2):
+                    if fut.done():
+                        fut.result()  # raises what the request raised
+                        raise SmokeFailure(
+                            "a resident stream ended before its first chunk"
+                        )
+            ride_f = [pool.submit(ask, base, comp, body) for body in riders]
+            for i, fut in enumerate(ride_f):
+                check_answer(fut.result(), f"rider {i}", max_tokens=64,
+                             prompt_tokens=len(riders[i]["prompt"]))
+            for i, fut in enumerate(res_f):
+                check_answer(fut.result(), f"resident {i}", max_tokens=1024,
+                             prompt_tokens=48)
+
+        steps = stack.step_counts()
+        say(f"[serve] engine steps by phase: {json.dumps(steps, sort_keys=True)}")
+        for phase in ("prefill", "decode", "mixed"):
+            check(steps.get(phase, 0) > 0,
+                  f"no {phase!r} step was dispatched: {steps}")
+        http_get(stack.status + "/health")  # 503 when a target is unhealthy
+        meta = stack.metadata()
+        say(f"[serve] compile cache: {json.dumps(meta['compile_cache'])}")
+        stack.check_processes()
+        say("[serve] worker healthy; frontend and store never loaded the "
+            "TPU runtime")
+        stack.stop()
+        say("[serve] every child exited 0 on SIGTERM")
+        return meta["engine"]["device"]
+
+
+# ------------------------------------------------------------------- kernels
+def kernel_phase() -> Dict[str, Any]:
+    """The kernel-against-reference child: see ``_kernel_child``."""
+    child = Child(
+        "kernels",
+        [sys.executable, "-c",
+         "import chip_smoke; chip_smoke._kernel_child()"],
+        child_env(on_chip=True),
+    )
+    code = child.wait(900)
+    text = child.log_text()
+    lines = [ln for ln in text.splitlines() if ln.startswith("KERNEL ")]
+    for ln in lines:
+        say("[kernels] " + ln[len("KERNEL "):])
+    if code != 0:
+        child.fail(f"exited with {code}")
+    result = [ln for ln in text.splitlines() if ln.startswith("KERNELS_OK ")]
+    check(len(result) == 1, "the kernel child printed no result")
+    return json.loads(result[0][len("KERNELS_OK "):])
+
+
+def _kernel_child() -> None:
+    """Runs IN THE CHILD that owns the chip: every Pallas kernel of the
+    serving path, compiled (never interpreted) at qwen3-0.6b's shapes,
+    against its pure-JAX twin on the same device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops import attention as att
+    from dynamo_tpu.ops import block_copy as bc
+    from dynamo_tpu.ops import pallas_attention as pa
+    from dynamo_tpu.ops import pallas_prefill as pf
+    from dynamo_tpu.ops import pallas_unified as pun
+    from dynamo_tpu.runtime.device import (
+        device_info,
+        enable_compile_cache,
+        on_tpu,
+    )
+
+    jax.config.update("jax_platforms", PLATFORM)
+    enable_compile_cache()
+    dev = device_info()
+    print(f"KERNEL device: {json.dumps(dev)}", flush=True)
+    if not on_tpu():
+        raise SystemExit("kernel phase needs the TPU backend")
+
+    NB, BS, KVH, H, D, MB, B = 2048, 16, 8, 16, 128, 128, 8
+    bf = jnp.bfloat16
+    rng = np.random.default_rng(SEED)
+
+    def rnd(*shape):
+        return jnp.asarray(rng.standard_normal(shape), bf)
+
+    k_cache, v_cache = rnd(NB, BS, KVH, D), rnd(NB, BS, KVH, D)
+    # every row owns its own pages, in a shuffled order
+    perm = rng.permutation(NB - 1)[: (B + 1) * MB] + 1
+    tables = jnp.asarray(perm.reshape(B + 1, MB), jnp.int32)
+
+    def highest(fn):
+        def run(*a, **kw):
+            with jax.default_matmul_precision("highest"):
+                return fn(*a, **kw)
+        return jax.jit(run, static_argnames=("softcap",))
+
+    worst = 0.0
+
+    def compare(name, got, ref):
+        nonlocal worst
+        got = np.asarray(got, np.float32)
+        ref = np.asarray(ref, np.float32)
+        if not np.all(np.isfinite(got)):
+            raise SystemExit(f"{name}: non-finite output")
+        err = np.abs(got - ref)
+        share = float(np.max(err / (KERNEL_TOL * np.maximum(1.0, np.abs(ref)))))
+        worst = max(worst, share)
+        print(f"KERNEL {name}: max |kernel - twin| = {float(err.max()):.3e} "
+              f"(max |twin| = {float(np.abs(ref).max()):.2f}), "
+              f"{share:.2f} of the tolerance 2^-6 * max(1, |twin|)",
+              flush=True)
+        if share > 1.0:
+            raise SystemExit(f"{name}: over tolerance ({share:.2f} of it)")
+
+    # paged decode: ragged lengths incl. one token, a block edge, full context
+    lens = jnp.asarray([1, 16, 17, 333, 1024, 1500, 2047, 2048], jnp.int32)
+    q = rnd(B, H, D)
+    compare(
+        "paged_decode_attention",
+        pa.paged_decode_attention(q, k_cache, v_cache, tables[:B], lens),
+        highest(att.paged_decode_attention)(
+            q, k_cache, v_cache, tables[:B], lens),
+    )
+
+    # flash extend: a 512-token chunk continuing a 1024-token prefix
+    S, T, start = 512, 2048, 1024
+    k_ctx, v_ctx = att.gather_kv(k_cache, v_cache, tables[0])
+    qs = rnd(S, H, D)
+    pos = jnp.arange(start, start + S, dtype=jnp.int32)
+    total = jnp.asarray(start + S, jnp.int32)
+    compare(
+        "flash_extend_attention",
+        pf.flash_extend_attention(qs, k_ctx, v_ctx, pos, total),
+        highest(att.extend_attention)(qs, k_ctx, v_ctx, pos, total),
+    )
+
+    # unified ragged: the mixed step's shape — one 512-token chunk at a
+    # 1024-token prefix + eight decode rows (one idle) in one launch
+    R = B + 1
+    q_lens = jnp.asarray([S, 1, 1, 1, 0, 1, 1, 1, 1], jnp.int32)
+    seq_lens = jnp.asarray(
+        [start + S, 1, 17, 333, 0, 1024, 1500, 2047, 2048], jnp.int32)
+    q_starts = jnp.asarray([0] + [S + i for i in range(B)], jnp.int32)
+    qu = rnd(S + B, H, D)
+    u_args = (qu, k_cache, v_cache, tables[:R], q_starts, q_lens, seq_lens)
+    ref_unified = highest(att.ragged_paged_attention)
+    compare(
+        "ragged_paged_attention plain",
+        pun.ragged_paged_attention(*u_args),
+        ref_unified(*u_args),
+    )
+    windows = jnp.asarray([128, 128, 0, 64, 0, 128, 1000, 128, 0], jnp.int32)
+    compare(
+        "ragged_paged_attention windowed rows",
+        pun.ragged_paged_attention(*u_args, windows=windows),
+        ref_unified(*u_args, windows=windows),
+    )
+    sinks = jnp.asarray(rng.standard_normal(H), jnp.float32)
+    compare(
+        "ragged_paged_attention windows + sinks + softcap",
+        pun.ragged_paged_attention(
+            *u_args, windows=windows, sinks=sinks, softcap=30.0),
+        ref_unified(*u_args, windows=windows, sinks=sinks, softcap=30.0),
+    )
+
+    # block moves are copies: exact
+    ids = jnp.asarray(rng.permutation(NB)[:32], jnp.int32)
+    got = bc.gather_blocks(k_cache, ids)
+    compare("gather_blocks", got, bc.gather_blocks_ref(k_cache, ids))
+    pages = rnd(32, BS, KVH, D)
+    want = bc.scatter_blocks_ref(v_cache, ids, pages)
+    compare("scatter_blocks", bc.scatter_blocks(v_cache + 0, ids, pages), want)
+    src, dst = ids[:16], ids[16:]
+    want = bc.copy_blocks_ref(k_cache, src, dst)
+    compare("copy_blocks", bc.copy_blocks(k_cache + 0, src, dst), want)
+
+    # does block_until_ready wait for the device? Time a chain of matmuls
+    # to its block_until_ready, then fetch: a wait that waited leaves
+    # nothing for the fetch to wait for
+    x = rnd(4096, 4096)
+
+    @jax.jit
+    def chain(a):
+        y = jax.lax.fori_loop(0, 64, lambda _, y: (y @ a) * 0.01, a)
+        return y, y[0, :8]  # a few bytes to fetch, from the same program
+
+    jax.block_until_ready(chain(x))  # compile
+    t0 = time.perf_counter()
+    y, probe = chain(x)
+    t_dispatch = time.perf_counter() - t0
+    y.block_until_ready()
+    t_block = time.perf_counter() - t0
+    np.asarray(probe)
+    t_fetch = time.perf_counter() - t0 - t_block
+    waits = t_block > 4 * max(t_dispatch, 1e-4) and t_fetch < 0.25 * t_block
+    print(f"KERNEL block_until_ready waits for the device: "
+          f"{json.dumps(bool(waits))} (dispatch returned after "
+          f"{t_dispatch * 1e3:.2f} ms, block_until_ready after "
+          f"{t_block * 1e3:.2f} ms, the fetch took {t_fetch * 1e3:.2f} ms "
+          f"more)", flush=True)
+    print("KERNELS_OK " + json.dumps({"device": dev, "worst": worst}),
+          flush=True)
+
+
+# ---------------------------------------------------------------- four chips
+TP_PROMPTS = [token_prompt(40, 31), token_prompt(24, 32), token_prompt(56, 33)]
+
+
+def tp_session(tag: str, worker_args: List[str], ready_timeout_s: float,
+               want_devices: int) -> Dict[str, Any]:
+    """One engine worker through the normal entry points; the same three
+    greedy prompts; what it answered and what its devices hold."""
+    with Stack(tag, worker_args, ready_timeout_s) as stack:
+        say(f"[{tag}] cold start: {stack.cold_start_s:.1f} s")
+        meta = report_worker(stack)
+        check(meta["engine"]["device"]["count"] == want_devices,
+              f"{tag}: worker sees {meta['engine']['device']['count']} "
+              f"devices, want {want_devices}")
+        answers = []
+        for i, prompt in enumerate(TP_PROMPTS):
+            ans = ask(stack.base, "/v1/completions", {
+                "prompt": prompt, "temperature": 0.0, "ignore_eos": True,
+                "max_tokens": 32, "logprobs": 5,
+            })
+            check_answer(ans, f"{tag} prompt {i}", max_tokens=32,
+                         prompt_tokens=len(prompt))
+            check(len(ans.tokens) == 32,
+                  f"{tag} prompt {i}: {len(ans.tokens)} logprob entries")
+            say(f"[{tag}] prompt {i}: {ans.seconds:.1f} s, first tokens "
+                f"{ans.tokens[:8]}")
+            answers.append(ans)
+        meta = stack.metadata()
+        mem = meta["engine"]["device_bytes_in_use"]
+        say(f"[{tag}] bytes in use per mesh device: {mem}")
+        stack.check_processes()
+        stack.stop()
+        return {"answers": answers, "mem": mem,
+                "device": meta["engine"]["device"]}
+
+
+def four_chip_phase() -> Dict[str, Any]:
+    qwen = ["--preset", "qwen3-0.6b", "--max-context", "1024"]
+    one = tp_session("qwen-tp1", qwen + ["--tp", "1"], 600, 4)
+    four = tp_session("qwen-tp4", qwen + ["--tp", "4"], 600, 4)
+    llama = tp_session(
+        "llama3-8b-tp4",
+        ["--preset", "llama3-8b", "--tp", "4", "--max-context", "1024"],
+        900, 4,
+    )
+    # every session has printed what it saw; now hold them to the rules
+    faults = []
+    for i, (a, b) in enumerate(zip(one["answers"], four["answers"])):
+        agree = next(
+            (j for j, (x, y) in enumerate(zip(a.tokens, b.tokens)) if x != y),
+            len(a.tokens),
+        )
+        drift = max(
+            [abs(x - y)
+             for x, y in zip(a.logprobs[:agree], b.logprobs[:agree])],
+            default=0.0,
+        )
+        line = (f"[tp] prompt {i}: tp=4 and tp=1 agree on the first {agree} "
+                f"of {len(a.tokens)} greedy tokens, max |logprob difference|"
+                f" over them {drift:.4f} (bound {TP_LOGPROB_TOL})")
+        if drift > TP_LOGPROB_TOL:
+            faults.append(f"prompt {i}: logprobs drift {drift:.3f}")
+        if agree < len(a.tokens):
+            # where they part: how much worse does each side think the
+            # other's token is than its own? (inf: not in its top 5)
+            gaps = [
+                mine.logprobs[agree]
+                - mine.top[agree].get(other.tokens[agree], float("-inf"))
+                for mine, other in ((a, b), (b, a))
+            ]
+            line += (f"; they part at a tie: tp=1 ranks tp=4's token "
+                     f"{gaps[0]:.4f} below its own, tp=4 ranks tp=1's "
+                     f"{gaps[1]:.4f} below its own")
+            if max(gaps) > TP_LOGPROB_TOL:
+                faults.append(f"prompt {i}: parted at token {agree} without "
+                              f"a near-tie (gaps {gaps})")
+        say(line)
+    mem = llama["mem"]
+    check(len(mem) == 4 and all(isinstance(m, int) and m > 0 for m in mem),
+          f"no per-device memory from the worker: {mem}")
+    ratio = max(mem) / min(mem)
+    say(f"[tp] llama3-8b tp=4 bytes in use per device: {mem} "
+        f"(max/min {ratio:.3f}, bound {TP_BALANCE_RATIO})")
+    check(not faults, "tp=4 against tp=1: " + "; ".join(faults))
+    check(ratio <= TP_BALANCE_RATIO,
+          f"llama3-8b shards unbalanced across devices: {mem}")
+    # a whole bf16 copy is ~16 GB: a device holding its quarter (and its
+    # quarter of the KV pages) stays far under half of that
+    check(max(mem) < 8 * 2**30,
+          f"a device holds more than a shard of llama3-8b: {mem}")
+    return llama["device"]
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the tp=4 phase and what it is "
+                         "compared with (needs four chips)")
+    args = ap.parse_args()
+    say(f"[smoke] compile cache: {compile_cache_dir()}")
+    if args.chips == 4:
+        device = four_chip_phase()
+    else:
+        device = serving_phase()
+        kernels = kernel_phase()
+        check(kernels["device"] == device,
+              f"kernel child saw {kernels['device']}, worker {device}")
+    check(device["platform"] == PLATFORM, f"platform {device['platform']!r}")
+    if args.chips == 4:
+        check(device["count"] == 4, f"{device['count']} devices, not 4")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

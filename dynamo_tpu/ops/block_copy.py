@@ -7,14 +7,15 @@ expressed as explicit HBM<->HBM DMAs driven by scalar-prefetched index lists —
 no VMEM round-trip, no materialized gather indices, and the batch of copies
 runs as overlapping async DMAs.
 
-Used by:
-  - engine/transfer.py: gather sealed blocks into a contiguous staging buffer
-    for the transfer plane (disaggregation KV handoff);
-  - kvbm: onboarding host/disk blocks back into device pages;
-  - allocator defragmentation (copy_blocks).
-
-All entry points fall back to pure-JAX gather/scatter off-TPU (CPU tests, and
-interpret=True runs the real kernel in the Pallas interpreter).
+Meant for: gathering sealed blocks into a contiguous staging buffer for the
+transfer plane, onboarding host/disk blocks back into device pages, and
+allocator defragmentation (copy_blocks). The engine does not dispatch them
+today — it moves pages with the XLA forms below (``cache[ids]`` /
+``.at[ids].set``, the ``*_ref`` functions). The kernels compile for v5e
+(tests/test_tpu_compile.py) and match the XLA forms on the chip
+(chip_smoke.py) at float and int8 page shapes; a ``[num_blocks, kvh]`` scale
+array does not (its row slice is not aligned to the 128-lane tiling), so
+the quantized wrappers move scale rows with the XLA form always.
 """
 
 from __future__ import annotations
@@ -59,6 +60,7 @@ def gather_blocks(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(out_shape, cache.dtype),
         interpret=interpret,
+        name="gather_blocks",
     )(block_ids.astype(jnp.int32), cache)
 
 
@@ -104,6 +106,7 @@ def scatter_blocks(
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         input_output_aliases={2: 0},  # cache (after 1 scalar-prefetch arg + blocks)
         interpret=interpret,
+        name="scatter_blocks",
     )(block_ids.astype(jnp.int32), blocks, cache)
 
 
@@ -141,6 +144,7 @@ def copy_blocks(
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="copy_blocks",
     )(src_ids.astype(jnp.int32), dst_ids.astype(jnp.int32), cache)
 
 
@@ -165,25 +169,18 @@ def copy_blocks_ref(
 # A quantized page move is two moves — the int8 payload and its f32 scale
 # row — that MUST travel together (a payload under the wrong scale is silent
 # corruption, not an error). These wrappers keep the pair atomic for the
-# KVBM offload/onboard and transfer staging paths; per-array they reuse the
-# same DMA kernels/refs above, so the TPU path stays all-async.
-# NOTE (hardware): the scale array's DMA slice is a [kvh] f32 row (minor dim
-# not 128-aligned) — the SAME Mosaic caveat flagged on the in-kernel scale
-# DMA in pallas_attention._decode_kernel; the first real-TPU int8 run must
-# confirm both sites (fallback: the _ref paths below, or kv_dtype=model).
+# KVBM offload/onboard and transfer staging paths. ``interpret=True`` runs
+# the payload through the DMA kernels in the Pallas interpreter (tests);
+# otherwise both halves take the XLA forms, like the engine's float pages.
 def gather_blocks_quant(cache, block_ids: jax.Array, *, interpret: bool = False):
     """QuantizedKV pages -> (payload [M, bs, kvh, d] int8, scales [M, kvh])."""
     from .quant import QuantizedKV
 
-    if on_tpu() or interpret:
-        return QuantizedKV(
-            gather_blocks(cache.data, block_ids, interpret=interpret),
-            gather_blocks(cache.scale, block_ids, interpret=interpret),
-        )
-    return QuantizedKV(
-        gather_blocks_ref(cache.data, block_ids),
-        gather_blocks_ref(cache.scale, block_ids),
+    payload = (
+        gather_blocks(cache.data, block_ids, interpret=True) if interpret
+        else gather_blocks_ref(cache.data, block_ids)
     )
+    return QuantizedKV(payload, gather_blocks_ref(cache.scale, block_ids))
 
 
 def scatter_blocks_quant(
@@ -192,18 +189,11 @@ def scatter_blocks_quant(
     """Scatter (payload, scales) pages into a QuantizedKV cache."""
     from .quant import QuantizedKV
 
-    if on_tpu() or interpret:
-        return QuantizedKV(
-            scatter_blocks(cache.data, block_ids, blocks.data,
-                           interpret=interpret),
-            scatter_blocks(cache.scale, block_ids, blocks.scale,
-                           interpret=interpret),
-        )
-    return QuantizedKV(
-        scatter_blocks_ref(cache.data, block_ids, blocks.data),
-        scatter_blocks_ref(cache.scale, block_ids, blocks.scale),
+    payload = (
+        scatter_blocks(cache.data, block_ids, blocks.data, interpret=True)
+        if interpret
+        else scatter_blocks_ref(cache.data, block_ids, blocks.data)
     )
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+    return QuantizedKV(
+        payload, scatter_blocks_ref(cache.scale, block_ids, blocks.scale)
+    )

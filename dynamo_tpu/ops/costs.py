@@ -1,9 +1,9 @@
 """Deterministic kernel-side perf accounting: FLOP/HBM-byte counts.
 
-The device bench is unreliable on this image (BENCH_NOTES: one clean
-datapoint in five runs), so kernel work iterates against MODELED bytes
-instead of measured seconds — the kernel-side half of the ROADMAP item 5
-perf gate. Two complementary sources:
+Counts computed from shapes: a check of behaviour that needs no chip, not
+evidence of speed (the models' one comparison with a chip was on earlier
+chip runs, since deleted; not measured on today's code). Two complementary
+sources:
 
 - :func:`jaxpr_counts` traces a jitted fn and walks the jaxpr, tallying
   MXU FLOPs (``dot_general``) and memory-moving op bytes (gather / scatter /
@@ -11,9 +11,10 @@ perf gate. Two complementary sources:
   XLA's view of bytes (the kernel drives its own DMAs), so they are
   surfaced as entries for the caller to price with the analytic models;
 - the analytic models below price the paged-attention DMA traffic of the
-  three Pallas kernels exactly — pages touched (window-skipped pages
-  excluded for sliding-window rows), scale rows, q/o streams, and the
-  gather copies the split path pays that the unified kernel does not —
+  three Pallas kernels — pages touched (window-skipped pages excluded for
+  sliding-window rows; a prefill row's prefix once per query block in the
+  unified kernel), scale rows, q/o streams, and the gather copies the
+  split path pays that the unified kernel does not —
   parameterized by the concrete per-row (query_len, seq_len[, window])
   mix. Spec-decode verify rows (query_len = k+1) price against the
   retired split prefix-extend launch (:func:`spec_verify_vs_split`).
@@ -73,11 +74,9 @@ def _walk(jaxpr, acc: Dict[str, Any]) -> None:
             acc["flops"] += f
             acc["by_op"][name] = acc["by_op"].get(name, 0) + f
         elif name == "pallas_call":
-            info = eqn.params.get(
-                "name_and_src_info", eqn.params.get("name", "")
-            )
             acc["pallas_calls"].append({
-                "name": str(info).split(" at ")[0] or "pallas_call",
+                # every kernel in ops/ passes pallas_call a stable name
+                "name": eqn.params["name"] or "pallas_call",
                 "in_shapes": [tuple(v.aval.shape) for v in eqn.invars],
                 "out_shapes": [tuple(v.aval.shape) for v in eqn.outvars],
             })
@@ -129,31 +128,45 @@ def unified_attention_bytes(
     kv_itemsize: int = 2,              # bf16 pages; 1 for int8
     q_itemsize: int = 2,
     quantized: bool = False,
+    q_block: int = 128,                # ops/pallas_unified.Q_BLOCK
 ) -> int:
-    """HBM bytes one unified ragged launch moves (ops/pallas_unified):
-    each active row's LIVE pages stream once per kv head as per-head slices
-    (total = the full page bytes), plus int8 scale rows, plus the packed
-    q read and o write. No gather, no per-q-tile context re-read.
+    """HBM bytes one unified ragged launch moves (ops/pallas_unified): the
+    grid is over blocks of ``q_block`` packed query tokens, and for every
+    block a row has tokens in, the row's LIVE pages stream once as whole
+    pages — up to the causal limit of the row's last token in that block —
+    plus int8 scale rows, plus the packed q read and o write. No gather,
+    and no read past a block's causal limit. A decode row (one token, one
+    block) streams its pages exactly once; a prefill chunk spanning several
+    blocks re-streams its growing prefix once per block. Rows are taken as
+    packed densely in order, the engine's layout up to bucket padding.
 
     A row may carry a third element — a positive sliding-window bound —
     in which case the kernel never DMAs the pages the window aged out:
-    live pages start at ``max(ctx_start - w + 1, 0) // bs`` (page-granular,
-    matching the kernel's windowed head skip)."""
+    live pages start at the page of the first key the block's earliest
+    token can see (page-granular, matching the kernel's windowed head
+    skip)."""
     total_q = sum(max(r[0], 0) for r in rows)
+    page_bytes = block_size * kv_heads * head_dim * kv_itemsize
     kv = 0
+    start = 0                          # packed offset of the row's segment
     for row in rows:
         q_len, seq_len = row[0], row[1]
         w = row[2] if len(row) > 2 else 0
         if q_len <= 0 or seq_len <= 0:
+            start += max(q_len, 0)
             continue
-        p = _pages(seq_len, block_size)
-        if w and w > 0:
-            ctx_start = seq_len - q_len
-            p -= max(ctx_start - w + 1, 0) // block_size
-        kv += 2 * p * block_size * kv_heads * head_dim * kv_itemsize
-        if quantized:
-            # the kernel DMAs the full [kvh] scale row per page per kv head
-            kv += 2 * p * kv_heads * kv_heads * SCALE_BYTES
+        ctx_start = seq_len - q_len
+        for blk in range(start // q_block, (start + q_len - 1) // q_block + 1):
+            a = max(start, blk * q_block)
+            b = min(start + q_len, (blk + 1) * q_block)
+            p = _pages(min(ctx_start + (b - start), seq_len), block_size)
+            if w and w > 0:
+                p -= max(ctx_start + (a - start) - w + 1, 0) // block_size
+            kv += 2 * p * page_bytes
+            if quantized:
+                # one [kvh] f32 scale row rides each page DMA
+                kv += 2 * p * kv_heads * SCALE_BYTES
+        start += q_len
     qo = 2 * total_q * num_heads * head_dim * q_itemsize
     return kv + qo
 

@@ -257,6 +257,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(
         block_tables.reshape(-1).astype(jnp.int32),
         seq_lens.astype(jnp.int32),

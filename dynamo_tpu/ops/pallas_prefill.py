@@ -197,6 +197,7 @@ def flash_extend_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kvh, S, g, d), q.dtype),
         interpret=interpret,
+        name="flash_extend_attention",
     )(
         q_positions[:1].astype(jnp.int32),  # chunk start (row 0's position)
         jnp.asarray(total_len, jnp.int32).reshape(1),

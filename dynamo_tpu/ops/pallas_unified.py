@@ -13,9 +13,9 @@ additions that let the gated model families ride the same launch:
 
 - ``windows`` [R] int32: per-row sliding-window bound (``<= 0`` = full
   attention). Key ``j`` is visible to query ``i`` iff ``i - w < j <= i``,
-  and the page-chunk loop STARTS at the first chunk the row's earliest
-  query can see — a 128-token window over a 128k context streams ~window
-  keys, not the whole cache (the gpt-oss/gemma sliding layers);
+  and the page-chunk loop STARTS at the first chunk the earliest query of
+  the block can see — a 128-token window over a 128k context streams
+  ~window keys, not the whole cache (the gpt-oss/gemma sliding layers);
 - ``sinks`` [h] f32: per-head attention-sink logits (gpt-oss), folded into
   the softmax denominator by seeding each tile's online-softmax state with
   the sink as a virtual zero-value key (``m0 = sink, l0 = 1, acc0 = 0``) —
@@ -26,47 +26,39 @@ additions that let the gated model families ride the same launch:
 A speculative-decode verify pass is just a row with ``query_len = k + 1``
 (candidate tokens at the context tail) — no special case in the kernel.
 
-Versus the two split kernels this also removes two whole classes of HBM
-traffic:
+Versus the split prefill path there is no gather: that path materializes
+the FULL padded context (``gather_kv`` over ``max_blocks_per_seq`` pages, an
+HBM->HBM copy) before the flash kernel even starts; here KV pages stream
+straight from the paged cache, and only the real pages below each query
+block's causal limit are ever touched. ``ops/costs.py`` turns both layouts
+into byte counts; the tier-1 gate pins mixed <= split (including the
+windowed and spec-verify row shapes).
 
-- no gather: the prefill side of the split path materializes the FULL
-  padded context (``gather_kv`` over ``max_blocks_per_seq`` pages, an
-  HBM->HBM copy) before the flash kernel even starts; here KV pages stream
-  straight from the paged cache, and only the ``ceil(seq_len / bs)`` real
-  pages of each row are ever touched;
-- single pass over KV: the flash-extend grid re-reads the gathered context
-  once per q tile; here the chunk loop is OUTER and the q-tile loop INNER,
-  so each row's pages are DMA'd exactly once per kv head regardless of how
-  many query tokens ride on them.
+Layout: paged cache ``[num_blocks, block_size, kv_heads, head_dim]``, shared
+with the decode kernel and the transfer plane. A page moves as ONE whole
+``[bs, kvh, d]`` DMA, as in the decode kernel: Mosaic tiles the cache's two
+minor dims ``(kvh, d)`` together (bf16 packs two kv heads into one 32-bit
+sublane word), so a single head cannot be sliced out of a page in HBM — the
+head is selected in VMEM instead. int8 caches (ops/quant.QuantizedKV) DMA
+the int8 pages PLUS their per-block ``[kvh]`` f32 scale rows on the same
+scalar-prefetched table indices and dequantize in-register; that scale-row
+copy is interpret-only (Mosaic refuses its unaligned minor dim), so the
+engine refuses int8 + Pallas on the TPU backend at construction.
 
-``ops/costs.py`` turns both layouts into byte counts; the tier-1 gate pins
-mixed <= split (including the windowed and spec-verify row shapes).
-
-Layout/machinery shared with the PR 2 kernels: paged cache
-``[num_blocks, block_size, kv_heads, head_dim]``; int8 caches
-(ops/quant.QuantizedKV) DMA the int8 pages PLUS their per-block
-``[kvh]`` f32 scale rows on the same scalar-prefetched table indices and
-dequantize in-register (the scale-row DMA machinery introduced by the
-decode kernel — and carrying the same hardware caveat: the scale row's
-minor dim is kvh, not 128-aligned; CPU tier-1 exercises interpret mode
-only, and tests/test_unified_attention.py pins the grow-scale rescale RMW
-path there).
-
-Grid: ``(kvh, R)`` — kv head OUTER so the packed q/o blocks for one head
-stay VMEM-resident across all R rows; rows iterate on the minor dim. Per
-(head, row): double-buffered page-slice DMAs (``[bs, d]`` per page for this
-head) chunked ``chunk_pages`` at a time, a DYNAMIC inner loop over the
-row's ``ceil(q_len / q_seg)`` query tiles with online-softmax state per
-tile in VMEM scratch, and a masked read-modify-write emit so neighbouring
-segments' outputs survive clamped tile writes. A decode row costs one
-``q_seg``-row tile per chunk (bandwidth-bound, unchanged page bytes); a
-prefill chunk amortizes the same page stream over all its tiles.
-
-NOTE (hardware): the dynamic scratch slices step in ``q_seg * g`` sublanes
-and the per-head page DMA strides over kv heads; both run interpret-clean
-and need the first real-TPU run to confirm Mosaic lowering (same protocol
-as the PR 2 scale-row caveat — fallback: use_pallas=False). The windowed
-variant additionally starts its chunk loop at a traced lower bound.
+Grid: ``(Tq_pad / q_block,)`` — one program per BLOCK of ``q_block`` packed
+query tokens, all heads. The q/o blocks ``[kvh, q_block * g, d]`` are
+BlockSpec-pipelined and each o block is written by exactly one program
+(zeros for tokens no row owns). Inside a program a loop walks the R rows
+and skips those with no token in the block; for a row that has some:
+double-buffered whole-page DMAs chunked ``chunk_pages`` at a time up to the
+block's causal limit, a DYNAMIC inner loop over the row's ``q_seg``-token
+sub-tiles inside the block with online-softmax state per (head, sub-tile)
+in VMEM scratch, and a masked emit into the o block so rows that share a
+sub-tile (consecutive decode tokens) keep each other's outputs. A decode
+row costs one sub-tile per chunk (bandwidth-bound, each page read once); a
+prefill chunk spanning several blocks re-streams its causal prefix once per
+block (``q_block`` = 128 matches the flash-extend q tile, without that
+path's gather or its reads past the causal limit).
 """
 
 from __future__ import annotations
@@ -84,10 +76,15 @@ from .quant import QuantizedKV, is_quantized
 
 NEG_INF = -1e30
 
-# default query-tile rows per inner iteration: small enough that a decode
-# row (q_len=1) stays bandwidth-bound, large enough that q_seg * g fills
-# MXU sublanes for common GQA group sizes
-Q_SEG = 8
+# packed query tokens per grid program (one VMEM-resident q/o block)
+Q_BLOCK = 128
+
+
+def default_q_seg(g: int) -> int:
+    """Query tokens per inner sub-tile: small enough that a decode row
+    (q_len=1) stays bandwidth-bound, and ``q_seg * g`` a multiple of 16 so
+    a sub-tile's rows are whole packed sublane tiles in bf16."""
+    return 8 if g % 2 == 0 else 16
 
 
 def _unified_kernel(
@@ -95,6 +92,8 @@ def _unified_kernel(
     max_blocks: int,
     chunk_pages: int,
     q_seg: int,
+    q_block: int,
+    num_rows: int,
     quantized: bool,
     has_window: bool,
     has_sinks: bool,
@@ -103,12 +102,13 @@ def _unified_kernel(
     # args layout (optional pieces gated by the static flags):
     #   scalar prefetch (SMEM): starts [R], qlens [R], lens [R],
     #     [windows [R]], tables [R * max_blocks]
-    #   inputs: q VMEM [1, Tq, g, d], [sinks VMEM [1, g]],
+    #   inputs: q VMEM [kvh, QB*g, d], [sinks VMEM [kvh, q_seg*g, 1]],
     #     k/v ANY/HBM [num_blocks, bs, kvh, d],
     #     [k/v scales ANY/HBM [num_blocks, kvh] f32]
-    #   outputs: o VMEM [1, Tq, g, d]
-    #   scratch: k/v_buf VMEM [2, CP, bs, d], [k/v scale bufs [2, CP, kvh]],
-    #     m/l/acc VMEM [Tq_pad*g, 1/1/d] f32, DMA sems [2, 2, CP] (+quant)
+    #   outputs: o VMEM [kvh, QB*g, d]
+    #   scratch: k/v_buf VMEM [2, CP, bs, kvh, d], [k/v scale bufs
+    #     [2, CP, kvh]], m/l/acc VMEM [kvh, QB*g, 1/1/d] f32,
+    #     DMA sems [2, 2, CP] (+quant)
     it = iter(args)
     starts_ref = next(it)
     qlens_ref = next(it)
@@ -136,251 +136,269 @@ def _unified_kernel(
     sem = next(it)
     ssem = next(it) if quantized else None
 
-    kh = pl.program_id(0)
-    r = pl.program_id(1)
     bs, kvh, d = k_hbm.shape[1], k_hbm.shape[2], k_hbm.shape[3]
-    Tq, g = q_ref.shape[1], q_ref.shape[2]
+    g = q_ref.shape[1] // q_block
     CP = chunk_pages
     T = CP * bs
     QG = q_seg * g
-
-    q_start = starts_ref[r]
-    q_len = qlens_ref[r]
-    seq_len = lens_ref[r]
-    w = windows_ref[r] if has_window else None
-
-    @pl.when(r == 0)
-    def _zero_out():
-        # fresh block per kv head: padding tokens (gaps between segments)
-        # must read back deterministic zeros, matching the reference twin
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    num_pages = pl.cdiv(seq_len, bs)
-    num_chunks = pl.cdiv(num_pages, CP)
-    nq = pl.cdiv(q_len, q_seg)
-    active = jnp.logical_and(q_len > 0, seq_len > 0)
-    chunks = jnp.where(active, num_chunks, 0)
-    ctx_start = seq_len - q_len  # absolute position of the segment's row 0
-    if has_window:
-        # a windowed row's earliest query (position ctx_start) sees no key
-        # below ctx_start - w + 1: pages a sliding window already aged out
-        # are never DMA'd (page-granular, like the split decode path's
-        # trailing-window gather), and the chunk loop starts at the first
-        # chunk holding a live page
-        lo_page = jnp.where(
-            w > 0, jnp.maximum(ctx_start - w + 1, 0) // bs, 0
-        )
-        c_lo = lo_page // CP
-    else:
-        lo_page = 0
-        c_lo = 0
-
-    def page_dma(kind, c, j, slot):
-        """DMA this kv head's slice of page j of chunk c: [bs, d]."""
-        idx = tables_ref[r * max_blocks + c * CP + j]
-        src = k_hbm if kind == 0 else v_hbm
-        dst = k_buf if kind == 0 else v_buf
-        return pltpu.make_async_copy(
-            src.at[idx, :, kh], dst.at[slot, j], sem.at[kind, slot, j]
-        )
-
-    def scale_dma(kind, c, j, slot):
-        """Full [kvh] scale row for page j — the PR 2 scale-row machinery
-        (one tiny f32 row riding the same prefetched table index)."""
-        idx = tables_ref[r * max_blocks + c * CP + j]
-        src = ks_hbm if kind == 0 else vs_hbm
-        dst = ks_buf if kind == 0 else vs_buf
-        return pltpu.make_async_copy(
-            src.at[idx], dst.at[slot, j], ssem.at[kind, slot, j]
-        )
-
-    def page_live(c, j):
-        """Page j of chunk c holds keys some query of this row can see."""
-        live = c * CP + j < num_pages
-        if has_window:
-            live = jnp.logical_and(live, c * CP + j >= lo_page)
-        return live
-
-    def start_chunk(c, slot):
-        for j in range(CP):  # static unroll; guard ragged tail + window
-            @pl.when(page_live(c, j))
-            def _():
-                page_dma(0, c, j, slot).start()
-                page_dma(1, c, j, slot).start()
-                if quantized:
-                    scale_dma(0, c, j, slot).start()
-                    scale_dma(1, c, j, slot).start()
-
-    def wait_chunk(c, slot):
-        for j in range(CP):
-            @pl.when(page_live(c, j))
-            def _():
-                page_dma(0, c, j, slot).wait()
-                page_dma(1, c, j, slot).wait()
-                if quantized:
-                    scale_dma(0, c, j, slot).wait()
-                    scale_dma(1, c, j, slot).wait()
-
-    # per-row online-softmax state: one (m, l, acc) strip per q tile,
-    # reset every row (only the first nq tiles are ever touched). With
-    # sinks, the state is seeded as if one virtual zero-value key with
-    # logit sinks[h] had already been folded in (m0 = sink, l0 = 1) —
-    # exactly _sink_softmax's denominator term.
-    if has_sinks:
-        srow = sinks_ref[0].astype(jnp.float32)              # [g], this head
-        m_scr[...] = jnp.broadcast_to(
-            srow[None, :], (Tq, g)
-        ).reshape(Tq * g, 1)
-        l_scr[...] = jnp.ones_like(l_scr)
-    else:
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(active)
-    def _prime():
-        start_chunk(c_lo, jax.lax.rem(c_lo, 2) if has_window else 0)
-
     scale = 1.0 / (d ** 0.5)
+    blk_lo = pl.program_id(0) * q_block
 
-    def tile_start(qt):
-        # clamped so the static-size q slice stays in bounds; overlapping
-        # tiles recompute identical rows (each tile's masks derive from its
-        # ACTUAL packed offset, not qt * q_seg)
-        return jnp.minimum(q_start + qt * q_seg, Tq - q_seg)
+    # tokens of this block that no row owns (gaps between segments, bucket
+    # padding) must read back deterministic zeros, matching the twin
+    o_ref[...] = jnp.zeros_like(o_ref)
 
-    def chunk_body(c, carry):
-        slot = jax.lax.rem(c, 2)
+    def sub_tile(st):
+        """Rows [st*QG, (st+1)*QG) of the (token, group)-flattened block."""
+        return pl.ds(pl.multiple_of(st * QG, QG), QG)
 
-        @pl.when(c + 1 < chunks)
-        def _():
-            start_chunk(c + 1, jax.lax.rem(c + 1, 2))
+    def tile_tokens(st):
+        # packed token index per flattened (q, g) pair, built directly in
+        # the [QG, 1] layout (iota // g keeps the lane dim fixed — see
+        # pallas_prefill)
+        row = jax.lax.broadcasted_iota(jnp.int32, (QG, 1), 0) // g
+        return blk_lo + st * q_seg + row
 
-        wait_chunk(c, slot)
+    def row_body(r, carry):
+        q_start = starts_ref[r]
+        q_len = qlens_ref[r]
+        seq_len = lens_ref[r]
+        # [a, b): the row's tokens that fall inside this block
+        a = jnp.maximum(q_start, blk_lo)
+        b = jnp.minimum(q_start + q_len, blk_lo + q_block)
 
-        if quantized:
-            # dequantize in-register: this head's scale is one lane of the
-            # [CP, kvh] rows that just DMA'd in (lane-select via one-hot —
-            # kh is a grid index, so a dynamic lane slice is avoided)
-            sel = (
-                jax.lax.broadcasted_iota(jnp.int32, (1, kvh), 1) == kh
-            ).astype(jnp.float32)                                  # [1, kvh]
-            ksc = jnp.sum(ks_buf[slot] * sel, axis=1)              # [CP]
-            vsc = jnp.sum(vs_buf[slot] * sel, axis=1)
-            k = k_buf[slot].astype(jnp.float32) * ksc[:, None, None]
-            v = v_buf[slot].astype(jnp.float32) * vsc[:, None, None]
-        else:
-            k = k_buf[slot].astype(jnp.float32)
-            v = v_buf[slot].astype(jnp.float32)
-        k = k.reshape(T, d)
-        v = v.reshape(T, d)
-        # rows past seq_len were never DMA'd (garbage / NaN): scores are
-        # masked below, but V must be zeroed too — 0-weight * NaN = NaN.
-        # Same for pages a row's sliding window skipped at the head.
-        row_pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
-        v_live = row_pos < seq_len
-        if has_window:
-            v_live = jnp.logical_and(v_live, row_pos >= lo_page * bs)
-        v = jnp.where(v_live, v, 0.0)
-        key_pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-
-        def tile_body(qt, carry2):
-            seg = tile_start(qt)
-            # row index per flattened (q, g) pair in the [QG, 1] layout
-            # (iota // g keeps the lane dim fixed — see pallas_prefill)
-            row = jax.lax.broadcasted_iota(jnp.int32, (QG, 1), 0) // g
-            local = (seg - q_start) + row
-            member = jnp.logical_and(local >= 0, local < q_len)
-            q_pos = ctx_start + local
-            lim = jnp.where(member, jnp.minimum(q_pos + 1, seq_len), 0)
-            # causal tile-skip: this chunk's keys start at c*T; the tile's
-            # highest attention limit is its last member row's
-            hi = jnp.minimum(ctx_start + (seg - q_start) + q_seg, seq_len)
-            do_tile = c * T < hi
+        @pl.when(jnp.logical_and(b > a, seq_len > 0))
+        def _row():
+            # packed token index + off = absolute position in the context
+            off = seq_len - q_len - q_start
+            # keys any member query can see end at the last member's limit
+            kv_end = jnp.minimum(b + off, seq_len)
+            num_pages = pl.cdiv(kv_end, bs)
+            chunks = pl.cdiv(num_pages, CP)
+            st_lo = (a - blk_lo) // q_seg
+            st_hi = pl.cdiv(b - blk_lo, q_seg)
             if has_window:
-                # window tile-skip: the tile's EARLIEST member query sits
-                # at q_pos_min; a chunk whose last key is below its window
-                # contributes nothing to any row of the tile
-                q_pos_min = ctx_start + jnp.maximum(seg - q_start, 0)
-                do_tile = jnp.logical_and(
-                    do_tile,
-                    jnp.where(w > 0, (c + 1) * T > q_pos_min - w + 1, True),
+                # the block's earliest member query (position a + off) sees
+                # no key below a + off - w + 1: pages a sliding window
+                # already aged out are never DMA'd (page-granular, like the
+                # split decode path's trailing-window gather), and the chunk
+                # loop starts at the first chunk holding a live page
+                w = windows_ref[r]
+                lo_page = jnp.where(
+                    w > 0, jnp.maximum(a + off - w + 1, 0) // bs, 0
+                )
+                c_lo = lo_page // CP
+            else:
+                w = None
+                lo_page = 0
+                c_lo = 0
+
+            def page_dma(kind, c, j, slot):
+                """Whole-page DMA [bs, kvh, d] for page j of chunk c."""
+                idx = tables_ref[r * max_blocks + c * CP + j]
+                src = k_hbm if kind == 0 else v_hbm
+                dst = k_buf if kind == 0 else v_buf
+                return pltpu.make_async_copy(
+                    src.at[idx], dst.at[slot, j], sem.at[kind, slot, j]
                 )
 
-            @pl.when(do_tile)
-            def _():
-                qf = (
-                    q_ref[0, pl.ds(seg, q_seg)].astype(jnp.float32) * scale
-                ).reshape(QG, d)
-                s = jax.lax.dot_general(
-                    qf, k,
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )                                                  # [QG, T]
-                if softcap is not None:
-                    s = jnp.tanh(s / softcap) * softcap
-                valid = key_pos < lim
+            def scale_dma(kind, c, j, slot):
+                """[kvh] f32 scale row for page j, riding the same
+                prefetched table index (interpret-only: see module doc)."""
+                idx = tables_ref[r * max_blocks + c * CP + j]
+                src = ks_hbm if kind == 0 else vs_hbm
+                dst = ks_buf if kind == 0 else vs_buf
+                return pltpu.make_async_copy(
+                    src.at[idx], dst.at[slot, j], ssem.at[kind, slot, j]
+                )
+
+            def page_live(c, j):
+                """Page j of chunk c holds keys some member query sees."""
+                live = c * CP + j < num_pages
                 if has_window:
-                    lo = jnp.where(
-                        jnp.logical_and(member, w > 0), q_pos - w + 1, 0
+                    live = jnp.logical_and(live, c * CP + j >= lo_page)
+                return live
+
+            def start_chunk(c, slot):
+                for j in range(CP):  # static unroll; guard ragged tail + window
+                    @pl.when(page_live(c, j))
+                    def _():
+                        page_dma(0, c, j, slot).start()
+                        page_dma(1, c, j, slot).start()
+                        if quantized:
+                            scale_dma(0, c, j, slot).start()
+                            scale_dma(1, c, j, slot).start()
+
+            def wait_chunk(c, slot):
+                for j in range(CP):
+                    @pl.when(page_live(c, j))
+                    def _():
+                        page_dma(0, c, j, slot).wait()
+                        page_dma(1, c, j, slot).wait()
+                        if quantized:
+                            scale_dma(0, c, j, slot).wait()
+                            scale_dma(1, c, j, slot).wait()
+
+            start_chunk(c_lo, jax.lax.rem(c_lo, 2) if has_window else 0)
+
+            # per-row online-softmax state: one (m, l, acc) strip per
+            # (head, sub-tile), reset for the sub-tiles this row touches.
+            # With sinks, the state is seeded as if one virtual zero-value
+            # key with logit sinks[h] had already been folded in (m0 = sink,
+            # l0 = 1) — exactly _sink_softmax's denominator term.
+            def init_tile(st, carry2):
+                sl = sub_tile(st)
+                for i in range(kvh):
+                    if has_sinks:
+                        m_scr[i, sl] = sinks_ref[i]
+                        l_scr[i, sl] = jnp.ones((QG, 1), jnp.float32)
+                    else:
+                        m_scr[i, sl] = jnp.full((QG, 1), NEG_INF, jnp.float32)
+                        l_scr[i, sl] = jnp.zeros((QG, 1), jnp.float32)
+                    acc_scr[i, sl] = jnp.zeros((QG, d), jnp.float32)
+                return carry2
+
+            jax.lax.fori_loop(st_lo, st_hi, init_tile, 0)
+
+            def chunk_body(c, carry2):
+                slot = jax.lax.rem(c, 2)
+
+                @pl.when(c + 1 < chunks)
+                def _():
+                    start_chunk(c + 1, jax.lax.rem(c + 1, 2))
+
+                wait_chunk(c, slot)
+
+                if quantized:
+                    # dequantize in-register: int8 page chunks -> f32 scaled
+                    # by the per-(page, kv-head) rows that DMA'd in with them
+                    k = (
+                        k_buf[slot].astype(jnp.float32)
+                        * ks_buf[slot][:, None, :, None]
                     )
-                    valid = jnp.logical_and(valid, key_pos >= lo)
-                s = jnp.where(valid, s, NEG_INF)
-                sl = pl.ds(qt * QG, QG)
-                m_prev = m_scr[sl]
-                l_prev = l_scr[sl]
-                acc_prev = acc_scr[sl]
-                m_cur = jnp.max(s, axis=-1, keepdims=True)
-                m_new = jnp.maximum(m_prev, m_cur)
-                if has_window:
-                    # a windowed row's FIRST visible chunk can still hand a
-                    # tile an all-masked score row (the row's own window
-                    # starts mid-chunk): exp(NEG_INF - NEG_INF) would be 1,
-                    # so masked lanes are zeroed explicitly
-                    p = jnp.where(
-                        s > NEG_INF * 0.5, jnp.exp(s - m_new), 0.0
+                    v = (
+                        v_buf[slot].astype(jnp.float32)
+                        * vs_buf[slot][:, None, :, None]
                     )
                 else:
-                    p = jnp.exp(s - m_new)
-                alpha = jnp.exp(m_prev - m_new)
-                m_scr[sl] = m_new
-                l_scr[sl] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-                acc_scr[sl] = alpha * acc_prev + jax.lax.dot_general(
-                    p, v,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
+                    k = k_buf[slot].astype(jnp.float32)
+                    v = v_buf[slot].astype(jnp.float32)
+                k = k.reshape(T, kvh, d)
+                v = v.reshape(T, kvh, d)
+                # pages past kv_end were never DMA'd (garbage / NaN): scores
+                # are masked below, but V must be zeroed too — 0-weight * NaN
+                # = NaN. Same for pages a sliding window skipped at the head.
+                row_pos = c * T + jax.lax.broadcasted_iota(
+                    jnp.int32, (T, 1, 1), 0
                 )
-            return carry2
+                v_live = row_pos < kv_end
+                if has_window:
+                    v_live = jnp.logical_and(v_live, row_pos >= lo_page * bs)
+                v = jnp.where(v_live, v, 0.0)
+                # head select in VMEM, once per chunk (loop-invariant for
+                # the sub-tile loop below)
+                k_heads = [k[:, i, :] for i in range(kvh)]
+                v_heads = [v[:, i, :] for i in range(kvh)]
+                key_pos = c * T + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, T), 1
+                )
 
-        jax.lax.fori_loop(0, nq, tile_body, 0)
+                def tile_body(st, carry3):
+                    tok = tile_tokens(st)
+                    member = jnp.logical_and(tok >= a, tok < b)
+                    q_pos = tok + off
+                    lim = jnp.where(member, jnp.minimum(q_pos + 1, seq_len), 0)
+                    # causal tile-skip: this chunk's keys start at c*T; the
+                    # tile's highest attention limit is its last token's
+                    tile_lo = blk_lo + st * q_seg
+                    do_tile = c * T < jnp.minimum(tile_lo + q_seg + off, kv_end)
+                    if has_window:
+                        # window tile-skip: a chunk whose last key is below
+                        # the window of the tile's EARLIEST member query
+                        # contributes nothing to any row of the tile
+                        q_pos_min = jnp.maximum(tile_lo, a) + off
+                        do_tile = jnp.logical_and(
+                            do_tile,
+                            jnp.where(
+                                w > 0, (c + 1) * T > q_pos_min - w + 1, True
+                            ),
+                        )
+
+                    @pl.when(do_tile)
+                    def _():
+                        sl = sub_tile(st)
+                        valid = key_pos < lim
+                        if has_window:
+                            lo = jnp.where(
+                                jnp.logical_and(member, w > 0),
+                                q_pos - w + 1, 0,
+                            )
+                            valid = jnp.logical_and(valid, key_pos >= lo)
+                        for i in range(kvh):
+                            qf = q_ref[i, sl, :].astype(jnp.float32) * scale
+                            s = jax.lax.dot_general(
+                                qf, k_heads[i],
+                                dimension_numbers=(((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                            )                                      # [QG, T]
+                            if softcap is not None:
+                                s = jnp.tanh(s / softcap) * softcap
+                            s = jnp.where(valid, s, NEG_INF)
+                            m_prev = m_scr[i, sl]
+                            l_prev = l_scr[i, sl]
+                            m_cur = jnp.max(s, axis=-1, keepdims=True)
+                            m_new = jnp.maximum(m_prev, m_cur)
+                            # a tile can hold an all-masked score row (a
+                            # neighbouring row's token, or a window that
+                            # starts mid-chunk): exp(NEG_INF - NEG_INF)
+                            # would be 1, so masked lanes are zeroed
+                            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                            alpha = jnp.exp(m_prev - m_new)
+                            m_scr[i, sl] = m_new
+                            l_scr[i, sl] = alpha * l_prev + jnp.sum(
+                                p, axis=-1, keepdims=True
+                            )
+                            acc_scr[i, sl] = (
+                                alpha * acc_scr[i, sl]
+                                + jax.lax.dot_general(
+                                    p, v_heads[i],
+                                    dimension_numbers=(((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32,
+                                )
+                            )
+                    return carry3
+
+                jax.lax.fori_loop(st_lo, st_hi, tile_body, 0)
+                return carry2
+
+            jax.lax.fori_loop(c_lo, chunks, chunk_body, 0)
+
+            def emit_tile(st, carry2):
+                sl = sub_tile(st)
+                tok = tile_tokens(st)
+                member = jnp.logical_and(tok >= a, tok < b)
+                for i in range(kvh):
+                    out = acc_scr[i, sl] / jnp.maximum(l_scr[i, sl], 1e-30)
+                    # masked merge: a sub-tile can span a neighbouring
+                    # row's tokens — their already-written outputs survive
+                    cur = o_ref[i, sl, :].astype(jnp.float32)
+                    o_ref[i, sl, :] = jnp.where(member, out, cur).astype(
+                        o_ref.dtype
+                    )
+                return carry2
+
+            jax.lax.fori_loop(st_lo, st_hi, emit_tile, 0)
+
         return carry
 
-    jax.lax.fori_loop(c_lo, chunks, chunk_body, 0)
-
-    def emit_tile(qt, carry):
-        seg = tile_start(qt)
-        sl = pl.ds(qt * QG, QG)
-        out = acc_scr[sl] / jnp.maximum(l_scr[sl], 1e-30)          # [QG, d]
-        row = jax.lax.broadcasted_iota(jnp.int32, (QG, 1), 0) // g
-        local = (seg - q_start) + row
-        member = jnp.logical_and(local >= 0, local < q_len)
-        # masked read-modify-write: a clamped tile spans neighbouring
-        # segments' tokens — their already-written outputs must survive
-        cur = o_ref[0, pl.ds(seg, q_seg)].astype(jnp.float32).reshape(QG, d)
-        merged = jnp.where(member, out, cur)
-        o_ref[0, pl.ds(seg, q_seg)] = merged.reshape(
-            q_seg, g, d
-        ).astype(o_ref.dtype)
-        return carry
-
-    @pl.when(active)
-    def _emit():
-        jax.lax.fori_loop(0, nq, emit_tile, 0)
+    jax.lax.fori_loop(0, num_rows, row_body, 0)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("q_seg", "chunk_tokens", "interpret", "softcap"),
+    static_argnames=(
+        "q_seg", "q_block", "chunk_tokens", "interpret", "softcap"
+    ),
 )
 def ragged_paged_attention(
     q: jax.Array,             # [Tq, h, d] densely packed ragged queries
@@ -394,7 +412,8 @@ def ragged_paged_attention(
     windows: jax.Array = None,   # [R] int32 per-row window (<=0 = full)
     sinks: jax.Array = None,     # [h] f32 per-head sink logits
     softcap: float = None,       # static logit softcap (gemma-2)
-    q_seg: int = Q_SEG,
+    q_seg: int = None,
+    q_block: int = Q_BLOCK,
     chunk_tokens: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
@@ -407,8 +426,7 @@ def ragged_paged_attention(
     static ``softcap`` extend the same launch to the gpt-oss/gemma
     families and spec-verify rows (``q_len = k+1``). ``k_cache``/
     ``v_cache`` may be ``QuantizedKV`` — int8 pages + per-block scale rows
-    DMA together and dequantize in-register, halving per-page HBM bytes
-    vs bf16."""
+    DMA together and dequantize in-register (interpret mode only)."""
     Tq, h, d = q.shape
     _, bs, kvh, _ = k_cache.shape
     R, max_blocks = block_tables.shape
@@ -417,24 +435,32 @@ def ragged_paged_attention(
     quantized = is_quantized(k_cache)
     has_window = windows is not None
     has_sinks = sinks is not None
+    if q_seg is None:
+        q_seg = default_q_seg(g)
 
-    # pad the packed buffer so every clamped q tile is in bounds
-    Tq_pad = max(q_seg, -(-Tq // q_seg) * q_seg)
+    # pad the packed buffer to whole sub-tiles, and to whole blocks once it
+    # spans more than one
+    Tq_pad = -(-Tq // q_seg) * q_seg
+    if Tq_pad > q_block:
+        q_block = -(-q_block // q_seg) * q_seg
+        Tq_pad = -(-Tq // q_block) * q_block
+    else:
+        q_block = Tq_pad
     if Tq_pad != Tq:
         q = jnp.pad(q, ((0, Tq_pad - Tq), (0, 0), (0, 0)))
 
     kernel = functools.partial(
         _unified_kernel, max_blocks=max_blocks, chunk_pages=chunk_pages,
-        q_seg=q_seg, quantized=quantized, has_window=has_window,
-        has_sinks=has_sinks, softcap=softcap,
+        q_seg=q_seg, q_block=q_block, num_rows=R, quantized=quantized,
+        has_window=has_window, has_sinks=has_sinks, softcap=softcap,
     )
     cache_specs = [
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch = [
-        pltpu.VMEM((2, chunk_pages, bs, d), k_cache.dtype),
-        pltpu.VMEM((2, chunk_pages, bs, d), v_cache.dtype),
+        pltpu.VMEM((2, chunk_pages, bs, kvh, d), k_cache.dtype),
+        pltpu.VMEM((2, chunk_pages, bs, kvh, d), v_cache.dtype),
     ]
     if quantized:
         cache_specs += [
@@ -446,34 +472,39 @@ def ragged_paged_attention(
             pltpu.VMEM((2, chunk_pages, kvh), jnp.float32),
         ]
     scratch += [
-        pltpu.VMEM((Tq_pad * g, 1), jnp.float32),   # m
-        pltpu.VMEM((Tq_pad * g, 1), jnp.float32),   # l
-        pltpu.VMEM((Tq_pad * g, d), jnp.float32),   # acc
+        pltpu.VMEM((kvh, q_block * g, 1), jnp.float32),   # m
+        pltpu.VMEM((kvh, q_block * g, 1), jnp.float32),   # l
+        pltpu.VMEM((kvh, q_block * g, d), jnp.float32),   # acc
     ]
     scratch.append(pltpu.SemaphoreType.DMA((2, 2, chunk_pages)))
     if quantized:
         scratch.append(pltpu.SemaphoreType.DMA((2, 2, chunk_pages)))
 
-    # [Tq, h, d] -> [kvh, Tq, g, d]: each kv head's q group contiguous; the
-    # kv head is the OUTER grid dim so the block stays resident across rows
-    qg = q.reshape(Tq_pad, kvh, g, d).transpose(1, 0, 2, 3)
-    in_specs = [
-        pl.BlockSpec((1, Tq_pad, g, d), lambda kh, r, *_: (kh, 0, 0, 0))
-    ]
+    # [Tq, h, d] -> [kvh, Tq*g, d]: each kv head's q group contiguous and
+    # (token, group)-flattened, so a sub-tile is a dense [q_seg*g, d] slab
+    qg = q.reshape(Tq_pad, kvh, g, d).transpose(1, 0, 2, 3).reshape(
+        kvh, Tq_pad * g, d
+    )
+    qo_spec = pl.BlockSpec((kvh, q_block * g, d), lambda t, *_: (0, t, 0))
+    in_specs = [qo_spec]
+    inputs = [qg]
     if has_sinks:
-        # this head's [g] sink logits ride a tiny VMEM block; the head
-        # grouping matches the q reshape (head = kh * g + gi)
+        # head kh*g + gi's sink logit, tiled over the q_seg tokens of a
+        # sub-tile in the same (token, group) row order as q
         in_specs.append(
-            pl.BlockSpec((1, g), lambda kh, r, *_: (kh, 0))
+            pl.BlockSpec((kvh, q_seg * g, 1), lambda t, *_: (0, 0, 0))
+        )
+        inputs.append(
+            jnp.tile(
+                sinks.astype(jnp.float32).reshape(kvh, 1, g), (1, q_seg, 1)
+            ).reshape(kvh, q_seg * g, 1)
         )
     in_specs += cache_specs
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 + (1 if has_window else 0),
-        grid=(kvh, R),
+        grid=(Tq_pad // q_block,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, Tq_pad, g, d), lambda kh, r, *_: (kh, 0, 0, 0)
-        ),
+        out_specs=qo_spec,
         scratch_shapes=scratch,
     )
     cache_args = (
@@ -488,17 +519,17 @@ def ragged_paged_attention(
     if has_window:
         prefetch.append(windows.astype(jnp.int32))
     prefetch.append(block_tables.reshape(-1).astype(jnp.int32))
-    inputs = [qg]
-    if has_sinks:
-        inputs.append(sinks.astype(jnp.float32).reshape(kvh, g))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((kvh, Tq_pad, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((kvh, Tq_pad * g, d), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(*prefetch, *inputs, *cache_args)
-    # [kvh, Tq_pad, g, d] -> [Tq, h, d]
-    return out.transpose(1, 0, 2, 3).reshape(Tq_pad, h, d)[:Tq]
+    # [kvh, Tq_pad*g, d] -> [Tq, h, d]
+    return out.reshape(kvh, Tq_pad, g, d).transpose(1, 0, 2, 3).reshape(
+        Tq_pad, h, d
+    )[:Tq]
 
 
 def sharded_ragged_paged_attention(

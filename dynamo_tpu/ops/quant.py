@@ -1,7 +1,8 @@
 """Int8 paged-KV quantization: the storage format and its numerics.
 
-Decode is HBM-bandwidth-bound (BENCH_NOTES: the decode program sits at ~77%
-of the roofline and paged-KV reads dominate the per-step bytes at batch).
+Decode is taken to be HBM-bandwidth-bound, with paged-KV reads dominating
+the per-step bytes at batch (from earlier chip runs, since deleted; not
+measured on today's code).
 Storing the paged cache as int8 with per-block-per-kv-head float32 scales
 halves the KV bytes on every path that touches them — the HBM page reads in
 both attention kernels, the disagg transfer wire, and the KVBM host/disk

@@ -28,22 +28,11 @@ AXIS_SP = "sp"
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with a version shim: older jaxlib ships it only as
-    ``jax.experimental.shard_map`` with the ``check_vma`` knob spelled
-    ``check_rep``. Every shard_map construction site in the package routes
-    through here so the whole parallelism substrate (ring/sp, pp wavefront,
-    EP psum, pallas sharding) serves on either jax generation."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    return legacy_sm(
+    """The one shard_map construction site of the package (ring/sp, pp
+    wavefront, EP psum, pallas sharding all route through here)."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
 
 

@@ -1,7 +1,12 @@
 """ctypes surface over the C++ transfer agent (native/transfer/agent.cpp).
 
 The library builds on demand with `make -C native` (g++ is in the image;
-pybind11 is not, hence the C ABI + ctypes). Everything degrades gracefully:
+pybind11 is not, hence the C ABI + ctypes). `make` runs once per process
+BEFORE the library is loaded, whether or not a binary is already on disk —
+the Makefile decides staleness, so what runs is built from
+native/transfer/agent.cpp as it stands, never a leftover
+native/build/*.so (the directory is git-ignored but travels with a copied
+working tree). Everything degrades gracefully:
 ``native_available()`` is False when the toolchain or build is missing and
 callers fall back to the Python request-plane transfer path.
 
@@ -31,6 +36,7 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libdtpu_transfer.so")
 
 _lib = None
 _lib_lock = threading.Lock()
+_built = False          # `make -C native` succeeded in this process
 _build_failed = False
 _build_thread: Optional[threading.Thread] = None
 # arenas whose agent teardown leaked its threads: kept alive forever so the
@@ -39,12 +45,13 @@ _LEAKED_ARENAS: list = []
 
 
 def _build() -> bool:
-    global _build_failed
+    global _built, _build_failed
     try:
         subprocess.run(
             ["make", "-C", _NATIVE_DIR],
             check=True, capture_output=True, timeout=120,
         )
+        _built = True
         return True
     except Exception as e:
         log.warning("native transfer build failed (%s); using python path", e)
@@ -59,9 +66,8 @@ def _load(build: bool = True) -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            if not build or not _build():
-                return None
+        if not _built and (not build or not _build()):
+            return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError as e:
@@ -93,9 +99,9 @@ def _load(build: bool = True) -> Optional[ctypes.CDLL]:
 
 def native_available() -> bool:
     """True iff the native library is usable NOW. Never blocks the caller on
-    a compile: when the .so is missing, the build is kicked off on a daemon
-    thread and this returns False until it lands (async paths — the engine
-    loop, request handlers — must not stall ~seconds on `make`)."""
+    a compile: until `make` has run in this process, it is kicked off on a
+    daemon thread and this returns False until it lands (async paths — the
+    engine loop, request handlers — must not stall ~seconds on `make`)."""
     global _build_thread
     if _load(build=False) is not None:
         return True

@@ -33,7 +33,6 @@ async def _run(tmp_path):
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/dtpu_jax_cache")
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "tests", "_kv_src_helper.py")],
         stdout=open(log_path, "wb"), stderr=subprocess.STDOUT,

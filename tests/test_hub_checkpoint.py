@@ -167,7 +167,6 @@ def test_serve_real_checkpoint_e2e(tmp_path):
     build_checkpoint(ckpt)
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/dtpu_jax_cache")
     r = subprocess.run(
         [sys.executable, "-m", "dynamo_tpu.run",
          "in=text:hello world", f"out={ckpt}",
@@ -191,7 +190,6 @@ def test_serve_hub_reference_e2e(tmp_path):
     env["JAX_PLATFORMS"] = "cpu"
     env["DTPU_HUB_CACHE"] = str(cache)
     env["DTPU_HUB_OFFLINE"] = "1"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/dtpu_jax_cache")
     r = subprocess.run(
         [sys.executable, "-m", "dynamo_tpu.run",
          "in=text:hello world", "out=acme/tiny-llama",

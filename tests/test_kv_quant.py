@@ -461,3 +461,30 @@ def test_int8_rejects_uncovered_modes():
     )
     with pytest.raises(ValueError, match="int8"):
         TpuEngine(cfg)
+
+
+def test_int8_with_pallas_is_refused_on_the_tpu_backend(monkeypatch):
+    """On the TPU backend the Pallas kernels' scale-row DMA does not compile
+    (tests/test_tpu_compile.py pins Mosaic's refusal), so the engine says so
+    at construction — never a compile error at the first decode, never a
+    silent switch to the pure-JAX ops. Off-TPU (the interpreter) and with
+    use_pallas=False the combination keeps working."""
+    from dynamo_tpu.engine import engine as eng
+
+    cfg = TpuEngineConfig(
+        model=MODEL, num_blocks=16, block_size=4, max_batch_size=2,
+        max_context=64, prefill_buckets=(16, 32, 64), kv_dtype="int8",
+        use_pallas=True, decode_steps=4, decode_pipeline=1,
+    )
+    monkeypatch.setattr(eng, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="does not compile for the TPU"):
+        TpuEngine(cfg)
+    monkeypatch.setattr(eng, "on_tpu", lambda: False)
+    e = TpuEngine(cfg)
+    try:
+        assert e.use_pallas and e.kernels_interpreted
+        snap = e.snapshot()
+        assert snap["kernels_interpreted"] is True
+        assert snap["device"]["platform"] == "cpu"
+    finally:
+        e.stop()

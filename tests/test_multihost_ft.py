@@ -37,7 +37,6 @@ def _env() -> dict:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/dtpu_jax_cache")
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
     return env
 

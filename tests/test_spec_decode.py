@@ -353,3 +353,43 @@ def test_spec_config_gates():
             sp=2,
         )
         TpuEngine(cfg)
+
+
+def test_gemma_draft_under_pallas_main_keeps_pure_jax_decode():
+    """ADVICE r5 #1: a Gemma draft whose head_dim divides 128 under a
+    Pallas main engine must not route its windowed/softcapped attention into
+    the split decode kernel (which takes no per-row attributes — a
+    TypeError at trace time). The draft is gated by the same auto rule as a
+    main model of its family. Traced, not run: the spec program's jaxpr
+    holds the MAIN model's unified verify launches and no decode kernel."""
+    from dynamo_tpu.models.gemma import GemmaConfig
+    from dynamo_tpu.ops import costs
+
+    main = LlamaConfig(
+        vocab_size=256, hidden_size=32, num_layers=1, num_heads=2,
+        num_kv_heads=1, head_dim=16, intermediate_size=64, dtype=jnp.float32,
+    )
+    draft = GemmaConfig.tiny_gemma2(
+        vocab_size=256, hidden_size=32, num_layers=2, num_heads=1,
+        num_kv_heads=1, head_dim=128, intermediate_size=64,
+    )
+    cfg = TpuEngineConfig(
+        model=main, spec_draft=draft, num_blocks=32, block_size=4,
+        max_batch_size=2, max_context=64, prefill_buckets=(16,),
+        decode_steps=4, decode_pipeline=1, spec_k=2, use_pallas=True,
+    )
+    e = TpuEngine(cfg, mesh=make_mesh(tp=1, devices=jax.devices()[:1]))
+    try:
+        B = cfg.max_batch_size
+        counted = costs.jaxpr_counts(
+            e._spec_multi_fn, e.params, e.draft_params, e.k_caches,
+            e.v_caches, e.draft_k_caches, e.draft_v_caches,
+            jnp.zeros((B,), jnp.int32), jnp.full((B,), 5, jnp.int32),
+            jnp.zeros((B, cfg.max_blocks_per_seq), jnp.int32),
+            jnp.ones((B,), bool), jnp.zeros((B,), jnp.int32),
+            {}, jnp.zeros((B,), jnp.int32),
+        )
+    finally:
+        e.stop()
+    kernels = {p["name"] for p in counted["pallas_calls"]}
+    assert kernels == {"ragged_paged_attention"}, kernels

@@ -1,0 +1,191 @@
+"""Rehearsal compiles: every Pallas kernel of the serving path, at
+qwen3-0.6b's real widths, compiled by the TPU's own compiler for a v5e that
+is described and not attached (on-chip-measurement guide, section 2).
+
+Interpret-mode tests cannot see what Mosaic refuses — a DMA slice not
+aligned to the tiling, more VMEM than a kernel may use, a kernel that cannot
+be partitioned. These compiles can, at about two seconds each and no chip
+time; nothing runs, so they say nothing about results (chip_smoke.py does,
+on the chip). Skipped only where the topology cannot be described.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from dynamo_tpu.ops import block_copy as bc
+from dynamo_tpu.ops import pallas_attention as pa
+from dynamo_tpu.ops import pallas_prefill as pf
+from dynamo_tpu.ops import pallas_unified as pun
+from dynamo_tpu.ops.quant import QuantizedKV
+
+# qwen3-0.6b as `python -m dynamo_tpu.engine --preset qwen3-0.6b` serves it:
+# 16 q heads / 8 kv heads x 128, 2048 pages x 16 tokens, context 2048, batch 8
+NB, BS, KVH, H, D, MB, B = 2048, 16, 8, 16, 128, 128, 8
+BF, I32, F32 = jnp.bfloat16, jnp.int32, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Devices of a described v5e 2x2, with the persistent compilation cache
+    off around the module (an entry written for a described chip cannot be
+    read back without one; the next compile would warn and redo it)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _shapes(sharding, kvh=KVH, h=H):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    cache = s((NB, BS, kvh, D), BF)
+    unified = (  # one prefill chunk + B decode rows: the mixed step's launch
+        s((512 + B, h, D), BF), cache, cache, s((B + 1, MB), I32),
+        s((B + 1,), I32), s((B + 1,), I32), s((B + 1,), I32),
+    )
+    rows = s((B + 1,), I32)
+    ids = s((32,), I32)
+    return s, cache, unified, rows, ids
+
+
+def _cases():
+    """name -> (fn, shape-args builder). Builders take the ShapeDtypeStruct
+    factory so one table serves any sharding."""
+
+    def decode(kvh, h):
+        def build(sh):
+            s, cache, *_ = _shapes(sh, kvh, h)
+            return (s((B, h, D), BF), cache, cache, s((B, MB), I32),
+                    s((B,), I32))
+        return pa.paged_decode_attention, build
+
+    def flash(S):
+        def build(sh):
+            s, *_ = _shapes(sh)
+            ctx = s((MB * BS, KVH, D), BF)
+            return (s((S, H, D), BF), ctx, ctx, s((S,), I32), s((), I32))
+        return pf.flash_extend_attention, build
+
+    def unified(fn, extra=()):
+        def build(sh):
+            s, _, args, rows, _ = _shapes(sh)
+            return args + tuple(
+                rows if e == "rows" else s((H,), F32) for e in extra
+            )
+        return fn, build
+
+    def unified_wide(sh):
+        s, cache, _, _, _ = _shapes(sh)
+        return (s((2048 + B, H, D), BF), cache, cache, s((B + 1, MB), I32),
+                s((B + 1,), I32), s((B + 1,), I32), s((B + 1,), I32))
+
+    def windowed(q, k, v, t, a, b, c, w):
+        return pun.ragged_paged_attention(q, k, v, t, a, b, c, windows=w)
+
+    def sinks(q, k, v, t, a, b, c, w, snk):
+        return pun.ragged_paged_attention(
+            q, k, v, t, a, b, c, windows=w, sinks=snk, softcap=30.0
+        )
+
+    def moves(fn, n_ids, with_pages):
+        def build(sh):
+            s, cache, _, _, ids = _shapes(sh)
+            pages = (s((32, BS, KVH, D), BF),) if with_pages else ()
+            return (cache,) + (ids,) * n_ids + pages
+        return fn, build
+
+    return {
+        "decode-bf16-kvh8": decode(KVH, H),
+        "decode-bf16-kvh2-tp4-shard": decode(KVH // 4, H // 4),
+        "flash-extend-S128": flash(128),
+        "flash-extend-S512": flash(512),
+        "flash-extend-S2048": flash(2048),
+        "unified-plain": unified(pun.ragged_paged_attention),
+        "unified-plain-chunk2048": (pun.ragged_paged_attention, unified_wide),
+        "unified-windowed": unified(windowed, ("rows",)),
+        "unified-window-sinks-softcap": unified(sinks, ("rows", "sinks")),
+        "gather-blocks": moves(bc.gather_blocks, 1, False),
+        "scatter-blocks": moves(bc.scatter_blocks, 1, True),
+        "copy-blocks": moves(bc.copy_blocks, 2, False),
+    }
+
+
+CASES = _cases()
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(v5e, name):
+    """The kernel lowers through Mosaic for one v5e chip as a real custom
+    call (not interpreted)."""
+    fn, build = CASES[name]
+    args = build(SingleDeviceSharding(v5e[0]))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_decode_compiles_on_tp4_mesh(v5e):
+    """The shard_map'd decode kernel on a tp=4 mesh of the described
+    devices: q on heads, pages on kv heads (2 per shard), one custom call
+    per device and no collective (attention is head-wise independent)."""
+    from dynamo_tpu.parallel.mesh import AXIS_TP, make_mesh
+
+    mesh = make_mesh(tp=4, devices=v5e)
+
+    def s(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    cache = s((NB, BS, KVH, D), BF, P(None, None, AXIS_TP, None))
+    args = (
+        s((B, H, D), BF, P(None, AXIS_TP, None)), cache, cache,
+        s((B, MB), I32, P()), s((B,), I32, P()),
+    )
+    fn = functools.partial(pa.sharded_paged_decode_attention, mesh, AXIS_TP)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce(" not in text and "all-gather(" not in text
+
+
+@pytest.mark.parametrize("kernel", ["decode", "unified", "gather-scales"])
+def test_int8_scale_rows_are_refused_by_mosaic(v5e, kernel):
+    """Why the engine refuses kv_dtype=int8 with the Pallas kernels on the
+    TPU backend (engine construction; tests/test_kv_quant.py): the [kv_heads]
+    f32 scale-row DMA is not aligned to the 128-lane tiling. When a layout
+    change makes these compile, this test fails — lift the refusal then."""
+    s, cache, unified, _, ids = _shapes(SingleDeviceSharding(v5e[0]))
+    qcache = QuantizedKV(
+        jax.ShapeDtypeStruct(cache.shape, jnp.int8, sharding=cache.sharding),
+        s((NB, KVH), F32),
+    )
+    if kernel == "decode":
+        fn = pa.paged_decode_attention
+        args = (s((B, H, D), BF), qcache, qcache, s((B, MB), I32),
+                s((B,), I32))
+    elif kernel == "unified":
+        fn = pun.ragged_paged_attention
+        args = (unified[0], qcache, qcache) + unified[3:]
+    else:
+        fn, args = bc.gather_blocks, (qcache.scale, ids)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(fn).lower(*args).compile()

@@ -26,12 +26,16 @@ ATOL = 2e-5  # same pallas-vs-reference bounds as the split kernels' tests
 
 
 def _make_case(rng, rows, h, kvh, d, bs, num_blocks, max_blocks,
-               dtype=jnp.float32, quant=False, gap_after=0):
+               dtype=jnp.float32, quant=False, gap_after=0, pad_rows=0,
+               pad_q=1):
     """rows: [(q_len, seq_len)]; packs segments densely with an optional
-    padding gap after the first segment (tokens belonging to no row)."""
+    padding gap after the first segment (tokens belonging to no row).
+    ``pad_rows`` / ``pad_q`` append idle rows and trailing unowned tokens so
+    parametrized cases share one kernel shape (one compile, not one each)."""
+    rows = list(rows) + [(0, 0)] * (pad_rows - len(rows))
     R = len(rows)
     Tq = sum(max(q, 0) for q, _ in rows) + gap_after
-    Tq = max(Tq, 1)
+    Tq = max(Tq, pad_q)
     q = jnp.asarray(rng.standard_normal((Tq, h, d)), dtype)
     k_cache = jnp.asarray(rng.standard_normal((num_blocks, bs, kvh, d)), dtype)
     v_cache = jnp.asarray(rng.standard_normal((num_blocks, bs, kvh, d)), dtype)
@@ -78,7 +82,7 @@ def test_unified_matches_reference(name, quant):
     rng = np.random.default_rng(hash(name) % (2**32))
     args = _make_case(
         rng, ROW_MIXES[name], h=8, kvh=4, d=32, bs=16, num_blocks=64,
-        max_blocks=6, quant=quant, gap_after=3,
+        max_blocks=6, quant=quant, gap_after=3, pad_rows=4, pad_q=32,
     )
     ref = att.ragged_paged_attention(*args)
     got = pu.ragged_paged_attention(
@@ -131,11 +135,13 @@ def test_unified_row_attributes_match_reference(name, quant):
     rng = np.random.default_rng(hash(name) % (2**32))
     args = _make_case(
         rng, case["rows"], h=8, kvh=4, d=32, bs=8, num_blocks=64,
-        max_blocks=8, quant=quant, gap_after=3,
+        max_blocks=8, quant=quant, gap_after=3, pad_rows=4, pad_q=24,
     )
     kw = {}
     if "windows" in case:
-        kw["windows"] = jnp.asarray(case["windows"], jnp.int32)
+        kw["windows"] = jnp.asarray(
+            case["windows"] + [0] * (4 - len(case["windows"])), jnp.int32
+        )
     if case.get("sinks"):
         kw["sinks"] = jnp.asarray(rng.standard_normal(8), jnp.float32)
     if case.get("softcap"):
@@ -143,6 +149,35 @@ def test_unified_row_attributes_match_reference(name, quant):
     ref = att.ragged_paged_attention(*args, **kw)
     got = pu.ragged_paged_attention(
         *args, **kw, q_seg=4, chunk_tokens=16, interpret=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), atol=1e-5, rtol=ATOL
+    )
+
+
+@pytest.mark.parametrize("attrs", ["plain", "window-sinks-softcap"])
+def test_unified_rows_spanning_query_blocks(attrs):
+    """The grid is over BLOCKS of packed query tokens: a prefill row that
+    spans several blocks re-streams its causal prefix per block, rows that
+    share a block (and a sub-tile) keep each other's outputs, and a row cut
+    by a block edge is served by both programs. q_block=8 forces all three
+    on a small case; a gap leaves tokens no row owns (zeros)."""
+    rng = np.random.default_rng(17)
+    rows = [(21, 37), (1, 9), (1, 30), (0, 0), (6, 22)]
+    args = _make_case(
+        rng, rows, h=8, kvh=4, d=32, bs=8, num_blocks=64, max_blocks=8,
+        gap_after=2,
+    )
+    kw = {}
+    if attrs != "plain":
+        kw = dict(
+            windows=jnp.asarray([11, 0, 7, 0, 5], jnp.int32),
+            sinks=jnp.asarray(rng.standard_normal(8), jnp.float32),
+            softcap=40.0,
+        )
+    ref = att.ragged_paged_attention(*args, **kw)
+    got = pu.ragged_paged_attention(
+        *args, **kw, q_seg=4, q_block=8, chunk_tokens=16, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), atol=1e-5, rtol=ATOL
@@ -439,7 +474,9 @@ def test_jaxpr_counts_traces_kernel_and_reference():
         lambda *a: pu.ragged_paged_attention(*a, interpret=True),
         q, kc, vc, tables, qs, ql, sl,
     )
-    assert any("_unified_kernel" in p["name"] for p in c["pallas_calls"])
+    assert any(
+        p["name"] == "ragged_paged_attention" for p in c["pallas_calls"]
+    )
     c2 = costs.jaxpr_counts(
         att.ragged_paged_attention, q, kc, vc, tables, qs, ql, sl
     )

@@ -3,7 +3,7 @@
 A ``.item()`` / ``np.asarray`` / ``device_get`` / ``block_until_ready`` on a
 traced value forces a device round-trip: inside a jit-decorated function it
 is at best a silent tracer materialization, and on the engine step path it
-stalls the dispatch pipeline for a full (possibly tunneled, 100ms+) RTT —
+stalls the dispatch pipeline for a full device round trip —
 the exact failure mode the ROADMAP item-1 kernel work must not reintroduce.
 
 Two scopes, two rule ids:
