@@ -1,0 +1,6 @@
+"""Share of the traced window in which device 0 sat idle between two program executions while the host was in the loop's admit, book, reap and publish spans (admission, the allocator's work for a step, reaping, KV events). _host_spans.py has the rule."""
+from benchmarks.metrics import _host_spans
+
+
+def read(ctx):
+    return _host_spans.idle_share(ctx, "schedule")

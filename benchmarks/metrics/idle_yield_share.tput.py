@@ -1,0 +1,6 @@
+"""Share of the traced window in which device 0 sat idle between two program executions while the host was in the loop's yield and idle spans and the part of step outside the executor's spans (the event loop given to the callers' tasks; thread hand-off). _host_spans.py has the rule."""
+from benchmarks.metrics import _host_spans
+
+
+def read(ctx):
+    return _host_spans.idle_share(ctx, "yield")

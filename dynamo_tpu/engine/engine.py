@@ -66,7 +66,7 @@ from ..runtime.logging import get_logger
 from ..runtime.tracing import get_tracer
 from ..tokens import TokenBlockSequence
 from .allocator import BlockAllocator, OutOfBlocks
-from .telemetry import StepStats
+from .telemetry import PENDING_SPANS_MAX, StepStats, loop_span, pending_spans
 from .sampling import (
     TOP_LOGPROBS_K,
     apply_penalties,
@@ -3020,52 +3020,48 @@ class TpuEngine:
                 )
 
     # ------------------------------------------------------------- step loop
+    # pending host spans and admission waits since the last StepStats
+    # (engine/telemetry.py loop_span): made by _loop, filled only while
+    # stats_hook is set, bounded, carried away by _step_stats
+    _host_spans: Optional[deque] = None
+    _admit_waits: Optional[deque] = None
+
     async def _loop(self) -> None:
-        import os as _os
-
         loop = asyncio.get_event_loop()
-        trace = _os.environ.get("DTPU_LOOP_TRACE")
-        t_mark = time.perf_counter()
-
-        def mark(phase: str) -> None:
-            nonlocal t_mark
-            now = time.perf_counter()
-            if trace and now - t_mark > 0.002:
-                import sys as _sys
-
-                print(f"loop {phase:<10s} {(now - t_mark) * 1e3:6.1f} ms",
-                      file=_sys.stderr, flush=True)
-            t_mark = now
-
+        self._host_spans = pending_spans()
+        self._admit_waits = deque(maxlen=PENDING_SPANS_MAX)
         try:
             while True:
                 if not self._waiting and all(s is None for s in self._slots):
-                    self._chains.clear()  # all snapshot seqs are done by now
-                    self._wake.clear()
-                    await self._wake.wait()
-                mark("idle")
-                # chaos drill hook: an armed engine.step fault crashes the
-                # loop through the real crash path below (error finishes,
-                # watchdog dereg, migration replay) — no-op unarmed
-                await FAULTS.ainject("engine.step")
-                self._admit_cancelled()
-                self._try_admit()
-                mark("admit")
+                    with loop_span(self, "idle"):
+                        self._chains.clear()  # all snapshot seqs are done by now
+                        self._wake.clear()
+                        await self._wake.wait()
+                with loop_span(self, "admit"):
+                    # chaos drill hook: an armed engine.step fault crashes
+                    # the loop through the real crash path below (error
+                    # finishes, watchdog dereg, migration replay) — no-op
+                    # unarmed
+                    await FAULTS.ainject("engine.step")
+                    self._admit_cancelled()
+                    self._try_admit()
                 # chunked prefill: ONE bounded chunk per tick, so running
                 # decodes keep making progress under a long prefill; round-
                 # robin across prefilling sequences so a short prompt is not
                 # starved behind a long one
-                prefilling = [
-                    s for s in self._slots
-                    if s is not None and not s.done and not s.prefilled
-                    and not s.prefill_inflight
-                ]
                 did_mixed = False
                 mixed_blocked = False
-                if prefilling:
-                    pick = prefilling[self._prefill_rr % len(prefilling)]
-                    self._prefill_rr += 1
-                    if pick.context.is_stopped():
+                pick = mixed_seqs = None
+                with loop_span(self, "book"):
+                    prefilling = [
+                        s for s in self._slots
+                        if s is not None and not s.done and not s.prefilled
+                        and not s.prefill_inflight
+                    ]
+                    if prefilling:
+                        pick = prefilling[self._prefill_rr % len(prefilling)]
+                        self._prefill_rr += 1
+                    if pick is not None and pick.context.is_stopped():
                         # client gone mid-prefill: stop burning chunks, free
                         # the slot at the next reap
                         pick.done = True
@@ -3073,7 +3069,8 @@ class TpuEngine:
                             finish_reason="cancelled",
                             cumulative_tokens=pick.produced,
                         ))
-                    else:
+                        pick = None
+                    elif pick is not None:
                         if pick.t_prefill_start == 0:
                             pick.t_prefill_start = time.time_ns()
                         chunk_from = pick.prefill_pos
@@ -3082,7 +3079,6 @@ class TpuEngine:
                         # stale device state past the fused step), the chunk
                         # rides along with ONE decode step in a single
                         # program — decode never stalls behind the prefill
-                        mixed_seqs = None
                         if self.mixed_enabled and not self._chains:
                             snap = self._decode_snapshot()
                             if any(s is not None for s in snap):
@@ -3095,22 +3091,24 @@ class TpuEngine:
                                     # pipelining rather than wait for a
                                     # fused step that cannot book
                                     mixed_blocked = True
-                        t_step = time.perf_counter()
+                if pick is not None:
+                    t_step = time.perf_counter()
+                    with loop_span(self, "step"):
                         if mixed_seqs is not None:
                             results, res = await loop.run_in_executor(
                                 self._executor, self._run_mixed_step, pick,
                                 mixed_seqs,
                             )
                             did_mixed = True
-                            self._commit_prefilled_blocks(pick)
-                            for rst, tok, lp, tids, tvals in results:
-                                self._accept_token(rst, tok, lp, tids, tvals)
                         else:
                             results = []
                             res = await loop.run_in_executor(
                                 self._executor, self._run_prefill_chunk, pick
                             )
-                            self._commit_prefilled_blocks(pick)
+                    with loop_span(self, "emit"):
+                        self._commit_prefilled_blocks(pick)
+                        for rst, tok, lp, tids, tvals in results:
+                            self._accept_token(rst, tok, lp, tids, tvals)
                         if res is not None:
                             fut = self._fetch_executor.submit(
                                 self._fetch_prefill_result, *res
@@ -3125,11 +3123,6 @@ class TpuEngine:
                             time.perf_counter() - t_step,
                             (pick.prefill_pos - chunk_from) + len(results),
                         )
-                        mark("mixed" if mixed_seqs is not None else "prefill")
-                has_active = any(
-                    s is not None and not s.done and s.prefilled
-                    for s in self._slots
-                )
                 # top up the horizon pipeline BEFORE fetching the oldest
                 # results: the readback overlaps the in-flight horizons'
                 # device compute. Dispatch runs on
@@ -3141,75 +3134,98 @@ class TpuEngine:
                 # every tick runs one fused chunk+decode step until the
                 # prefill completes — decode keeps advancing, prefill keeps
                 # chunking, nothing stalls
-                mixed_wait = (
-                    self.mixed_enabled and bool(prefilling) and has_active
-                    and not mixed_blocked
-                )
-                while (
-                    has_active
-                    and not self._waiting
-                    and not did_mixed
-                    and not mixed_wait
-                    and len(self._chains) < self.cfg.decode_pipeline
-                    and (not self._chains or self._can_chain(self._chains[-1]))
-                    and self._prepare_horizon(depth=len(self._chains) + 1)
-                ):
-                    prev = self._chains[-1] if self._chains else None
-                    snapshot = self._decode_snapshot()
-                    chain = await loop.run_in_executor(
-                        self._executor, self._dispatch_horizon, prev, snapshot
+                with loop_span(self, "book"):
+                    has_active = any(
+                        s is not None and not s.done and s.prefilled
+                        for s in self._slots
                     )
-                    chain.fetch = self._fetch_executor.submit(np.asarray, chain.packed)
-                    self._chains.append(chain)
-                    mark("dispatch")
+                    mixed_wait = (
+                        self.mixed_enabled and bool(prefilling)
+                        and has_active and not mixed_blocked
+                    )
+                while True:
+                    with loop_span(self, "book"):
+                        top_up = (
+                            has_active
+                            and not self._waiting
+                            and not did_mixed
+                            and not mixed_wait
+                            and len(self._chains) < self.cfg.decode_pipeline
+                            and (not self._chains
+                                 or self._can_chain(self._chains[-1]))
+                            and self._prepare_horizon(
+                                depth=len(self._chains) + 1)
+                        )
+                        if top_up:
+                            prev = self._chains[-1] if self._chains else None
+                            snapshot = self._decode_snapshot()
+                    if not top_up:
+                        break
+                    with loop_span(self, "step"):
+                        chain = await loop.run_in_executor(
+                            self._executor, self._dispatch_horizon, prev,
+                            snapshot,
+                        )
+                        chain.fetch = self._fetch_executor.submit(
+                            np.asarray, chain.packed
+                        )
+                        self._chains.append(chain)
                 if self._chains:
                     chain = self._chains.popleft()
                     t_step = time.perf_counter()
-                    packed = await asyncio.wrap_future(chain.fetch)
-                    mark("fetch")
-                    emitted_before = sum(
-                        s.produced for s in chain.seqs if s is not None
-                    )
-                    self._apply_packed(chain, packed)
-                    self._step_stats(
-                        "decode", time.perf_counter() - t_step,
-                        sum(s.produced for s in chain.seqs if s is not None)
-                        - emitted_before,
-                    )
-                    mark("apply")
+                    with loop_span(self, "fetch"):
+                        packed = await asyncio.wrap_future(chain.fetch)
+                    with loop_span(self, "emit"):
+                        emitted_before = sum(
+                            s.produced for s in chain.seqs if s is not None
+                        )
+                        self._apply_packed(chain, packed)
+                        self._step_stats(
+                            "decode", time.perf_counter() - t_step,
+                            sum(s.produced for s in chain.seqs if s is not None)
+                            - emitted_before,
+                        )
                 elif has_active and not did_mixed:
                     t_step = time.perf_counter()
-                    results = await loop.run_in_executor(
-                        self._executor, self._run_decode, self._decode_snapshot()
-                    )
-                    for rst, tok, lp, tids, tvals in results:
-                        self._accept_token(rst, tok, lp, tids, tvals)
-                    self._step_stats(
-                        "decode", time.perf_counter() - t_step, len(results)
-                    )
+                    with loop_span(self, "book"):
+                        snapshot = self._decode_snapshot()
+                    with loop_span(self, "step"):
+                        results = await loop.run_in_executor(
+                            self._executor, self._run_decode, snapshot
+                        )
+                    with loop_span(self, "emit"):
+                        for rst, tok, lp, tids, tvals in results:
+                            self._accept_token(rst, tok, lp, tids, tvals)
+                        self._step_stats(
+                            "decode", time.perf_counter() - t_step, len(results)
+                        )
                 elif self._prefill_tasks and not prefilling:
                     # nothing to compute until a first-token readback lands:
-                    # park instead of busy-spinning through the loop
-                    self._wake.clear()
-                    try:
-                        await asyncio.wait_for(self._wake.wait(), 0.05)
-                    except asyncio.TimeoutError:
-                        pass
-                self._reap_finished()
-                if self._offload_pending and self.kvbm is not None:
-                    pending, self._offload_pending = self._offload_pending, []
-                    # gather ENQUEUE happens here on the loop thread, in
-                    # program order before any later horizon dispatch that
-                    # could evict+rewrite the pages; only the host fetch is
-                    # fire-and-forget (on its own thread so it never delays
-                    # the decode executor)
-                    gathered = self._enqueue_offload_gather(pending)
-                    self._offload_executor.submit(
-                        self._offload_fetch, pending, gathered
-                    )
-                await self._publish_events()
-                mark("publish")
-                await asyncio.sleep(0)
+                    # park instead of busy-spinning through the loop (a wait
+                    # for device results, like a horizon's: "fetch")
+                    with loop_span(self, "fetch"):
+                        self._wake.clear()
+                        try:
+                            await asyncio.wait_for(self._wake.wait(), 0.05)
+                        except asyncio.TimeoutError:
+                            pass
+                with loop_span(self, "reap"):
+                    self._reap_finished()
+                    if self._offload_pending and self.kvbm is not None:
+                        pending, self._offload_pending = self._offload_pending, []
+                        # gather ENQUEUE happens here on the loop thread, in
+                        # program order before any later horizon dispatch
+                        # that could evict+rewrite the pages; only the host
+                        # fetch is fire-and-forget (on its own thread so it
+                        # never delays the decode executor)
+                        gathered = self._enqueue_offload_gather(pending)
+                        self._offload_executor.submit(
+                            self._offload_fetch, pending, gathered
+                        )
+                with loop_span(self, "publish"):
+                    await self._publish_events()
+                with loop_span(self, "yield"):
+                    await asyncio.sleep(0)
         except asyncio.CancelledError:
             pass
         except Exception as crash:
@@ -3375,6 +3391,10 @@ class TpuEngine:
                         self._slot_dirty[j] = True
             admitted.append(st)
             st.t_admitted = time.time_ns()
+            if self.stats_hook is not None:
+                self._admit_waits.append(
+                    max(0, st.t_admitted - st.t_queued) / 1e9
+                )
             get_flight_recorder().record(
                 st.req.request_id, "admitted",
                 slot=slot, cached_tokens=st.cached_tokens,
@@ -3504,57 +3524,64 @@ class TpuEngine:
         prefill, protocols.rs:112): writes the chunk's KV pages; the final
         chunk also samples the first token. Returns None for intermediate
         chunks, else the (st, tok, lp, tlp...) acceptance tuple."""
-        prompt = st.seq.tokens()
-        start = st.prefill_pos
-        remaining = len(prompt) - start
-        cap = self.cfg.prefill_chunk
-        is_final = remaining <= cap
-        chunk_len = remaining if is_final else cap
-        (tokens, positions, new_block_ids), dev = self._take_chunk_arrays(
-            st, prompt, start, chunk_len
-        )
-        S_pad = len(tokens)  # the bucketed width (_mm_chunk needs it)
+        with loop_span(self, "pack"):
+            prompt = st.seq.tokens()
+            start = st.prefill_pos
+            remaining = len(prompt) - start
+            cap = self.cfg.prefill_chunk
+            is_final = remaining <= cap
+            chunk_len = remaining if is_final else cap
+            (tokens, positions, new_block_ids), dev = self._take_chunk_arrays(
+                st, prompt, start, chunk_len
+            )
+            S_pad = len(tokens)  # the bucketed width (_mm_chunk needs it)
 
-        s = st.req.sampling
-        total_len = start + chunk_len
-        _j = self._j
-        d_tokens, d_positions, d_new_blocks = (
-            dev if dev is not None
-            else (_j(tokens), _j(positions), _j(new_block_ids))
-        )
-        g_args = ()
-        if self.guided_enabled:
-            # full versioned device tables, indexed by slot in the program;
-            # the FSM state travels by value (0, or walked over prior
-            # tokens for disagg/migration resumes)
-            ga, gc, gt = self._guided_dev()
-            g_args = (ga, _j(np.int32(st.guided_state)), gc, gt)
-        (self.k_caches, self.v_caches, self.output_counts, tok, lp, tlp_vals,
-         tlp_ids) = self._prefill_fn(
-            self.params, self.k_caches, self.v_caches, self.output_counts,
-            d_tokens, d_positions,
-            _j(self._block_tables[st.slot]),
-            d_new_blocks, _j(np.int32(total_len)), _j(np.int32(start)),
-            _j(np.array([self._seeds[st.slot]], np.uint32)),
-            _j(np.array([0], np.int32)),
-            _j(np.array([s.temperature], np.float32)),
-            _j(np.array([s.top_k], np.int32)),
-            _j(np.array([s.top_p], np.float32)),
-            _j(np.array([s.min_p], np.float32)),
-            _j(np.array([s.presence_penalty], np.float32)),
-            _j(np.array([s.frequency_penalty], np.float32)),
-            _j(np.array([s.repetition_penalty], np.float32)),
-            self.prompt_masks, _j(np.int32(st.slot)),
-            _j(np.bool_(self._lp_ns[st.slot] > 0)),
-            _j(np.bool_(is_final)),
-            self._lora_tables(), _j(np.int32(self._lora_slots[st.slot])),
-            self._dev("proc_masks", self._lp_masks),
-            *self._mm_chunk(st, start, chunk_len, S_pad),
-            *g_args,
-        )
-        st.prefill_pos = total_len
-        self._schedule_next_chunk(st, prompt, is_final)
-        self._advance_draft_prefill(st, prompt)
+            s = st.req.sampling
+            total_len = start + chunk_len
+            d_tokens, d_positions, d_new_blocks = (
+                dev if dev is not None
+                else (tokens, positions, new_block_ids)
+            )
+            g_args = ()
+            if self.guided_enabled:
+                # full versioned device tables, indexed by slot in the
+                # program; the FSM state travels by value (0, or walked over
+                # prior tokens for disagg/migration resumes)
+                ga, gc, gt = self._guided_dev()
+                g_args = (ga, np.int32(st.guided_state), gc, gt)
+            host = (
+                self.params, self.k_caches, self.v_caches, self.output_counts,
+                d_tokens, d_positions,
+                self._block_tables[st.slot],
+                d_new_blocks, np.int32(total_len), np.int32(start),
+                np.array([self._seeds[st.slot]], np.uint32),
+                np.array([0], np.int32),
+                np.array([s.temperature], np.float32),
+                np.array([s.top_k], np.int32),
+                np.array([s.top_p], np.float32),
+                np.array([s.min_p], np.float32),
+                np.array([s.presence_penalty], np.float32),
+                np.array([s.frequency_penalty], np.float32),
+                np.array([s.repetition_penalty], np.float32),
+                self.prompt_masks, np.int32(st.slot),
+                np.bool_(self._lp_ns[st.slot] > 0),
+                np.bool_(is_final),
+                self._lora_tables(), np.int32(self._lora_slots[st.slot]),
+                self._dev("proc_masks", self._lp_masks),
+                *self._mm_chunk(st, start, chunk_len, S_pad),
+                *g_args,
+            )
+        with loop_span(self, "upload"):
+            args = self._upload(host)
+        with loop_span(self, "launch"):
+            (self.k_caches, self.v_caches, self.output_counts, tok, lp,
+             tlp_vals, tlp_ids) = self._prefill_fn(*args)
+        with loop_span(self, "pack"):
+            # the next chunk's arrays, built under this chunk's compute
+            del host, args  # donated caches: hold no stale handles
+            st.prefill_pos = total_len
+            self._schedule_next_chunk(st, prompt, is_final)
+            self._advance_draft_prefill(st, prompt)
         if not is_final:
             return None
         # NO sync readback here: converting tok/lp on this thread would pay
@@ -3690,61 +3717,70 @@ class TpuEngine:
         Returns (decode acceptance tuples like _run_decode's, prefill
         result tuple like _run_prefill_chunk's or None for intermediate
         chunks)."""
-        prompt = st.seq.tokens()
-        start = st.prefill_pos
-        remaining = len(prompt) - start
-        cap = self.cfg.prefill_chunk
-        is_final = remaining <= cap
-        chunk_len = remaining if is_final else cap
-        (tokens, positions, new_block_ids), dev = self._take_chunk_arrays(
-            st, prompt, start, chunk_len
-        )
-        (d_positions, d_seq_lens, write_blocks, write_offsets, steps) = (
-            self._decode_dispatch_arrays(seqs)
-        )
-        lp_need = bool(np.any((self._lp_ns > 0) & (d_seq_lens > 0)))
-        c_lp_need = self._lp_ns[st.slot] > 0
-        _j = self._j
-        g_args = ()
-        if self.guided_enabled:
-            # decode rows resync the host FSM states (mixed steps are never
-            # chained); the chunk row's state travels by value like prefill
-            g_active, g_class, g_trans = self._guided_dev()
-            g_args = (
-                g_active, _j(self._g_state.copy()),
-                _j(np.int32(st.guided_state)), g_class, g_trans,
+        with loop_span(self, "pack"):
+            prompt = st.seq.tokens()
+            start = st.prefill_pos
+            remaining = len(prompt) - start
+            cap = self.cfg.prefill_chunk
+            is_final = remaining <= cap
+            chunk_len = remaining if is_final else cap
+            (tokens, positions, new_block_ids), dev = self._take_chunk_arrays(
+                st, prompt, start, chunk_len
             )
-        d_tokens, d_pos_chunk, d_new_blocks = (
-            dev if dev is not None
-            else (_j(tokens), _j(positions), _j(new_block_ids))
-        )
-        (self.k_caches, self.v_caches, self.output_counts, toks, lps,
-         tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids) = (
-            self._mixed_fn(
+            (d_positions, d_seq_lens, write_blocks, write_offsets, steps) = (
+                self._decode_dispatch_arrays(seqs)
+            )
+            lp_need = bool(np.any((self._lp_ns > 0) & (d_seq_lens > 0)))
+            c_lp_need = self._lp_ns[st.slot] > 0
+            g_args = ()
+            if self.guided_enabled:
+                # decode rows resync the host FSM states (mixed steps are
+                # never chained); the chunk row's state travels by value
+                # like prefill
+                g_active, g_class, g_trans = self._guided_dev()
+                g_args = (
+                    g_active, self._g_state.copy(),
+                    np.int32(st.guided_state), g_class, g_trans,
+                )
+            d_tokens, d_pos_chunk, d_new_blocks = (
+                dev if dev is not None
+                else (tokens, positions, new_block_ids)
+            )
+            host = (
                 self.params, self.k_caches, self.v_caches, self.output_counts,
                 d_tokens, d_pos_chunk,
-                _j(self._block_tables[st.slot]), d_new_blocks,
-                _j(np.int32(start + chunk_len)), _j(np.int32(start)),
-                _j(np.int32(st.slot)), _j(np.bool_(is_final)),
-                _j(np.bool_(c_lp_need)),
-                _j(self._tokens), _j(d_positions),
-                _j(self._block_tables), _j(d_seq_lens),
-                _j(write_blocks), _j(write_offsets),
-                _j(self._seeds), _j(steps),
-                _j(self._temps), _j(self._top_ks), _j(self._top_ps),
-                _j(self._min_ps), _j(self._pres), _j(self._freqs),
-                _j(self._reps),
-                self.prompt_masks, _j(np.bool_(lp_need)),
-                self._lora_tables(), _j(self._lora_slots),
+                self._block_tables[st.slot], d_new_blocks,
+                np.int32(start + chunk_len), np.int32(start),
+                np.int32(st.slot), np.bool_(is_final),
+                np.bool_(c_lp_need),
+                self._tokens, d_positions,
+                self._block_tables, d_seq_lens,
+                write_blocks, write_offsets,
+                self._seeds, steps,
+                self._temps, self._top_ks, self._top_ps,
+                self._min_ps, self._pres, self._freqs,
+                self._reps,
+                self.prompt_masks, np.bool_(lp_need),
+                self._lora_tables(), self._lora_slots,
                 self._dev("proc_masks", self._lp_masks),
                 *g_args,
             )
-        )
-        st.prefill_pos = start + chunk_len
-        self._schedule_next_chunk(st, prompt, is_final)
-        self._advance_draft_prefill(st, prompt)
-        results = self._decode_results(seqs, toks, lps, tlp_ids, tlp_vals,
-                                       lp_need)
+        with loop_span(self, "upload"):
+            args = self._upload(host)
+        with loop_span(self, "launch"):
+            (self.k_caches, self.v_caches, self.output_counts, toks, lps,
+             tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids) = (
+                self._mixed_fn(*args)
+            )
+        with loop_span(self, "pack"):
+            # the next chunk's arrays, built under this step's compute
+            del host, args  # donated caches: hold no stale handles
+            st.prefill_pos = start + chunk_len
+            self._schedule_next_chunk(st, prompt, is_final)
+            self._advance_draft_prefill(st, prompt)
+        with loop_span(self, "sync"):
+            results = self._decode_results(seqs, toks, lps, tlp_ids,
+                                           tlp_vals, lp_need)
         prefill_res = None
         if is_final:
             # same async-readback protocol as _run_prefill_chunk: the loop
@@ -3862,6 +3898,16 @@ class TpuEngine:
         through — the leader wrapper broadcasts host data, and pulling an
         uploaded array straight back would pay a blocking D2H per arg."""
         return host_val if self._mh is not None else jnp.asarray(host_val)
+
+    def _upload(self, host_args: tuple) -> tuple:
+        """A dispatch's arguments with every host array and scalar placed
+        (``_j``); what is on the device already passes through. Apart from
+        ``pack`` so that the loop's spans tell building the host arrays from
+        handing them to the device."""
+        return tuple(
+            self._j(a) if isinstance(a, (np.ndarray, np.generic)) else a
+            for a in host_args
+        )
 
     def _dev(self, name: str, host_arr: np.ndarray) -> jax.Array:
         """Device-resident copy of a slot array, re-uploaded only on change
@@ -4011,34 +4057,35 @@ class TpuEngine:
         snapshot. With ``chain`` given, the carry (tokens/seq_lens/steps)
         comes straight from the in-flight dispatch — no host round-trip;
         otherwise it is synced up from host state."""
-        B = self.cfg.max_batch_size
-        active = np.zeros(B, bool)
-        for i, st in enumerate(seqs):
-            if st is not None:
-                active[i] = True
-        if chain is not None:
-            tokens, seq_lens, steps = chain.tokens, chain.seq_lens, chain.steps
-        else:
-            seq_lens_np = np.zeros(B, np.int32)
-            steps_np = np.zeros(B, np.int32)
+        with loop_span(self, "pack"):
+            B = self.cfg.max_batch_size
+            active = np.zeros(B, bool)
             for i, st in enumerate(seqs):
-                if st is None:
-                    continue
-                seq_lens_np[i] = len(st.seq)
-                steps_np[i] = st.produced
-                self._tokens[i] = st.last_token
-            # host numpy feeds jit directly (same H2D copy jnp.asarray paid);
-            # snapshot _tokens — the loop mutates it after dispatch. In
-            # multihost mode numpy-vs-jax.Array is also the carry/resync
-            # signal (engine _wire_multihost carry_in).
-            tokens = self._tokens.copy()
-            seq_lens = seq_lens_np
-            steps = steps_np
-
-        if self.cfg.spec_draft is not None and self._spec_eligible(seqs):
-            (self.k_caches, self.v_caches, self.draft_k_caches,
-             self.draft_v_caches, packed, tokens, seq_lens, steps) = (
-                self._spec_multi_fn(
+                if st is not None:
+                    active[i] = True
+            if chain is not None:
+                tokens, seq_lens, steps = (
+                    chain.tokens, chain.seq_lens, chain.steps
+                )
+            else:
+                seq_lens_np = np.zeros(B, np.int32)
+                steps_np = np.zeros(B, np.int32)
+                for i, st in enumerate(seqs):
+                    if st is None:
+                        continue
+                    seq_lens_np[i] = len(st.seq)
+                    steps_np[i] = st.produced
+                    self._tokens[i] = st.last_token
+                # host numpy feeds jit directly (same H2D copy jnp.asarray
+                # paid); snapshot _tokens — the loop mutates it after
+                # dispatch. In multihost mode numpy-vs-jax.Array is also the
+                # carry/resync signal (engine _wire_multihost carry_in).
+                tokens = self._tokens.copy()
+                seq_lens = seq_lens_np
+                steps = steps_np
+            spec = self.cfg.spec_draft is not None and self._spec_eligible(seqs)
+            if spec:
+                args = (
                     self.params, self.draft_params, self.k_caches,
                     self.v_caches, self.draft_k_caches, self.draft_v_caches,
                     tokens, seq_lens,
@@ -4048,57 +4095,66 @@ class TpuEngine:
                     self._lora_tables(),
                     self._dev("lora_slots", self._lora_slots),
                 )
-            )
+            else:
+                g_args = ()
+                if self.guided_enabled:
+                    g_active, g_class, g_trans = self._guided_dev()
+                    g_state = (
+                        chain.g_state
+                        if chain is not None and chain.g_state is not None
+                        else self._g_state.copy()
+                    )
+                    g_args = (g_active, g_state, g_class, g_trans)
+                args = (
+                    self.params, self.k_caches, self.v_caches,
+                    self.output_counts,
+                    tokens, seq_lens,
+                    self._dev("tables", self._block_tables),
+                    self._dev("active", active),
+                    self._dev("seeds", self._seeds),
+                    steps,
+                    self._dev("temps", self._temps),
+                    self._dev("top_ks", self._top_ks),
+                    self._dev("top_ps", self._top_ps),
+                    self._dev("min_ps", self._min_ps),
+                    self._dev("pres", self._pres),
+                    self._dev("freqs", self._freqs),
+                    self._dev("reps", self._reps),
+                    self.prompt_masks,
+                    jnp.bool_(bool(np.any(self._lp_ns[active] > 0))),
+                    self._lora_tables(),
+                    self._dev("lora_slots", self._lora_slots),
+                    self._dev("proc_masks", self._lp_masks),
+                    *g_args,
+                )
+        # no "sync" here: a horizon's results are awaited by the loop ("fetch")
+        with loop_span(self, "launch"):
+            if spec:
+                (self.k_caches, self.v_caches, self.draft_k_caches,
+                 self.draft_v_caches, packed, tokens, seq_lens, steps) = (
+                    self._spec_multi_fn(*args)
+                )
+                packed.copy_to_host_async()
+                return _Chain(
+                    packed, tokens, seq_lens, steps, seqs,
+                    spec_k=self.cfg.spec_k,
+                )
+            res = self._decode_multi_fn(*args)
+            del args  # donated caches: hold no stale handles
+            g_state_out = None
+            if self.guided_enabled:
+                (self.k_caches, self.v_caches, self.output_counts, packed,
+                 tokens, seq_lens, steps, g_state_out) = res
+            else:
+                (self.k_caches, self.v_caches, self.output_counts, packed,
+                 tokens, seq_lens, steps) = res
+            # start the D2H readback immediately: by the time this horizon's
+            # turn to be applied comes (decode_pipeline-1 horizons later) the
+            # bytes are already on host and np.asarray is a no-wait copy
             packed.copy_to_host_async()
             return _Chain(
-                packed, tokens, seq_lens, steps, seqs,
-                spec_k=self.cfg.spec_k,
+                packed, tokens, seq_lens, steps, seqs, g_state=g_state_out
             )
-
-        g_args = ()
-        if self.guided_enabled:
-            g_active, g_class, g_trans = self._guided_dev()
-            g_state = (
-                chain.g_state
-                if chain is not None and chain.g_state is not None
-                else self._g_state.copy()
-            )
-            g_args = (g_active, g_state, g_class, g_trans)
-        res = self._decode_multi_fn(
-            self.params, self.k_caches, self.v_caches, self.output_counts,
-            tokens, seq_lens,
-            self._dev("tables", self._block_tables),
-            self._dev("active", active),
-            self._dev("seeds", self._seeds),
-            steps,
-            self._dev("temps", self._temps),
-            self._dev("top_ks", self._top_ks),
-            self._dev("top_ps", self._top_ps),
-            self._dev("min_ps", self._min_ps),
-            self._dev("pres", self._pres),
-            self._dev("freqs", self._freqs),
-            self._dev("reps", self._reps),
-            self.prompt_masks,
-            jnp.bool_(bool(np.any(self._lp_ns[active] > 0))),
-            self._lora_tables(),
-            self._dev("lora_slots", self._lora_slots),
-            self._dev("proc_masks", self._lp_masks),
-            *g_args,
-        )
-        g_state_out = None
-        if self.guided_enabled:
-            (self.k_caches, self.v_caches, self.output_counts, packed,
-             tokens, seq_lens, steps, g_state_out) = res
-        else:
-            (self.k_caches, self.v_caches, self.output_counts, packed,
-             tokens, seq_lens, steps) = res
-        # start the D2H readback immediately: by the time this horizon's turn
-        # to be applied comes (decode_pipeline-1 horizons later) the bytes
-        # are already on host and np.asarray is a no-wait copy
-        packed.copy_to_host_async()
-        return _Chain(
-            packed, tokens, seq_lens, steps, seqs, g_state=g_state_out
-        )
 
     def _spec_eligible(self, seqs: List[Optional["_Seq"]]) -> bool:
         """Every active row must be greedy with no sampling-state coupling:
@@ -4230,35 +4286,41 @@ class TpuEngine:
         return results
 
     def _run_decode(self, seqs: List[Optional["_Seq"]]) -> List[Tuple[_Seq, int, float]]:
-        (positions, seq_lens, write_blocks, write_offsets, steps) = (
-            self._decode_dispatch_arrays(seqs)
-        )
-        lp_need = bool(np.any((self._lp_ns > 0) & (seq_lens > 0)))
-        _j = self._j
-        g_args = ()
-        if self.guided_enabled:
-            g_active, g_class, g_trans = self._guided_dev()
-            # single-step dispatches are never chained: the host FSM state
-            # (walked in _accept_tokens) is authoritative
-            g_args = (g_active, _j(self._g_state.copy()), g_class, g_trans)
-        (self.k_caches, self.v_caches, self.output_counts, toks, lps,
-         tlp_vals, tlp_ids) = self._decode_fn(
-            self.params, self.k_caches, self.v_caches, self.output_counts,
-            _j(self._tokens), _j(positions),
-            _j(self._block_tables), _j(seq_lens),
-            _j(write_blocks), _j(write_offsets),
-            _j(self._seeds), _j(steps),
-            _j(self._temps),
-            _j(self._top_ks), _j(self._top_ps),
-            _j(self._min_ps), _j(self._pres),
-            _j(self._freqs), _j(self._reps),
-            self.prompt_masks, _j(np.bool_(lp_need)),
-            self._lora_tables(), _j(self._lora_slots),
-            self._dev("proc_masks", self._lp_masks),
-            *g_args,
-        )
-        return self._decode_results(seqs, toks, lps, tlp_ids, tlp_vals,
-                                    lp_need)
+        with loop_span(self, "pack"):
+            (positions, seq_lens, write_blocks, write_offsets, steps) = (
+                self._decode_dispatch_arrays(seqs)
+            )
+            lp_need = bool(np.any((self._lp_ns > 0) & (seq_lens > 0)))
+            g_args = ()
+            if self.guided_enabled:
+                g_active, g_class, g_trans = self._guided_dev()
+                # single-step dispatches are never chained: the host FSM
+                # state (walked in _accept_tokens) is authoritative
+                g_args = (g_active, self._g_state.copy(), g_class, g_trans)
+            host = (
+                self.params, self.k_caches, self.v_caches, self.output_counts,
+                self._tokens, positions,
+                self._block_tables, seq_lens,
+                write_blocks, write_offsets,
+                self._seeds, steps,
+                self._temps,
+                self._top_ks, self._top_ps,
+                self._min_ps, self._pres,
+                self._freqs, self._reps,
+                self.prompt_masks, np.bool_(lp_need),
+                self._lora_tables(), self._lora_slots,
+                self._dev("proc_masks", self._lp_masks),
+                *g_args,
+            )
+        with loop_span(self, "upload"):
+            args = self._upload(host)
+        with loop_span(self, "launch"):
+            (self.k_caches, self.v_caches, self.output_counts, toks, lps,
+             tlp_vals, tlp_ids) = self._decode_fn(*args)
+            del host, args  # donated caches: hold no stale handles
+        with loop_span(self, "sync"):
+            return self._decode_results(seqs, toks, lps, tlp_ids, tlp_vals,
+                                        lp_need)
 
     # -- host-side token bookkeeping -----------------------------------------
     def _accept_token(
@@ -4518,6 +4580,12 @@ class TpuEngine:
             if self._prep is not None and phase in ("prefill", "mixed")
             else None
         )
+        # what the host did since the last StepStats (popleft, not a copy
+        # and clear: the deques lose nothing to a concurrent append, and
+        # the spans, three values each, stay whole)
+        spans, waits = self._host_spans, self._admit_waits
+        host_spans = tuple(spans.popleft() for _ in range(len(spans)))
+        admit_wait_s = tuple(waits.popleft() for _ in range(len(waits)))
         try:
             hook(StepStats(
                 phase=phase,
@@ -4535,6 +4603,8 @@ class TpuEngine:
                 prep_hit=(prep["hit"] if prep is not None else None),
                 prep_build_s=(prep["build_s"] if prep is not None else 0.0),
                 prep_wait_s=(prep["wait_s"] if prep is not None else 0.0),
+                host_spans=host_spans,
+                admit_wait_s=admit_wait_s,
             ))
         except Exception:
             log.exception("stats hook failed")

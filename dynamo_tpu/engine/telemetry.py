@@ -10,6 +10,28 @@ touches jit-traced code or forces a device sync (durations are host wall
 time around executor calls; token counts come from ``_accept_tokens``'s own
 ``produced`` counters).
 
+``loop_span`` is the loop's one timing mechanism (it replaced a debug print
+keyed on an environment variable). Entering it opens a
+``jax.profiler.TraceAnnotation("dtpu.loop.<name>")``, so the phase lies on
+the profiler's own timeline beside the device planes whenever a profile is
+being taken (an inactive TraceMe costs a flag test); leaving it appends
+``name, t0_ns, t1_ns`` on ``time.monotonic_ns()`` to a bounded pending
+list on the engine, but only while ``engine.stats_hook`` is set. The next
+``StepStats`` carries the list away as ``host_spans``, next to
+``admit_wait_s`` (queued -> admitted, one value per request admitted since
+the last ``StepStats``). Spans are opened on the loop thread
+(``LOOP_PHASES``) and on the one step-executor thread, inside ``step``
+(``EXECUTOR_PHASES``); never under ``jit``.
+
+``host_spans`` is FLAT, three values a span, and not a tuple per span
+(``span_triples`` reads it back as triples). A hook that keeps its
+``StepStats`` keeps the spans, and a tuple per span is a dozen more objects
+a tick for the cyclic collector to count and to walk: twice the
+youngest-generation passes over a 50 s serving window on the chip (PERF.md
+section 6, PR 24). Strings and integers are not the collector's business,
+and one flat tuple a step is one object, which it lets go of at its first
+pass.
+
 ``EngineTelemetry`` is the standard consumer: it projects StepStats onto
 the runtime metrics registry (histograms split by phase, occupancy/KV/queue
 gauges, spec-decode acceptance) under the caller's hierarchy labels
@@ -24,7 +46,10 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Any, Dict, Optional
+import time
+from typing import Any, Dict, Optional, Tuple
+
+import jax
 
 from ..runtime import metrics as M
 from ..runtime.config import ENV_SLOW_STEP_MS, env_float
@@ -37,6 +62,64 @@ log = get_logger("engine.telemetry")
 _STEP_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                  1.0, 2.5, 5.0, 15.0, 60.0)
 _TOKEN_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 4096)
+
+# the loop thread's phases: every instant of a loop tick lies in exactly one
+LOOP_PHASES = ("idle", "admit", "book", "step", "fetch", "emit", "reap",
+               "publish", "yield")
+# the step-executor thread's, inside a ``step`` span of the loop thread
+EXECUTOR_PHASES = ("pack", "upload", "launch", "sync")
+_ANNOTATION = {p: f"dtpu.loop.{p}" for p in LOOP_PHASES + EXECUTOR_PHASES}
+# pending spans / admission waits kept on an engine between two StepStats:
+# beyond this the oldest go (a hook set on a loop that turns without stepping)
+PENDING_SPANS_MAX = 4096
+
+
+def pending_spans() -> collections.deque:
+    """The engine's pending list: ``name, t0_ns, t1_ns`` of each span, flat."""
+    return collections.deque(maxlen=3 * PENDING_SPANS_MAX)
+
+
+def span_triples(host_spans: Tuple[Any, ...]):
+    """``StepStats.host_spans`` (or a pending list) as ``(name, t0_ns,
+    t1_ns)`` triples, oldest first."""
+    return zip(host_spans[0::3], host_spans[1::3], host_spans[2::3])
+
+
+_now_ns = time.monotonic_ns
+
+
+class loop_span(jax.profiler.TraceAnnotation):
+    """``with loop_span(engine, "admit"): ...`` around one phase of the step
+    loop (module docstring). It IS the annotation (a TraceMe starts when it
+    is constructed), so a span costs one object: a dozen are made every loop
+    tick. The recorded span holds the annotation's own cost: that is the
+    phase's, not a hole between two phases."""
+
+    __slots__ = ("_engine", "_name", "_t0")
+
+    def __init__(self, engine: Any, name: str):
+        self._t0 = _now_ns()
+        super().__init__(_ANNOTATION[name])
+        self._engine = engine
+        self._name = name
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        super().__exit__(exc_type, exc, tb)
+        t1 = _now_ns()
+        if self._engine.stats_hook is not None:
+            # one extend: the loop thread and the executor thread both come
+            # here, and the list stays a whole number of triples
+            self._engine._host_spans.extend((self._name, self._t0, t1))
+        return False
+
+
+def _ns_by_phase(steps) -> Dict[str, int]:
+    """Host nanoseconds per loop phase over the ``host_spans`` of ``steps``."""
+    spent: Dict[str, int] = {}
+    for s in steps:
+        for name, t0, t1 in span_triples(s.host_spans):
+            spent[name] = spent.get(name, 0) + (t1 - t0)
+    return spent
 
 
 @dataclasses.dataclass
@@ -62,6 +145,12 @@ class StepStats:
     prep_hit: Optional[bool] = None
     prep_build_s: float = 0.0
     prep_wait_s: float = 0.0
+    # what the host did since the last StepStats: phase, t0_ns, t1_ns of
+    # each span on time.monotonic_ns(), FLAT (span_triples above; module
+    # docstring), loop-thread and executor-thread spans together, and
+    # queued -> admitted seconds of each request admitted since then
+    host_spans: Tuple[Any, ...] = ()
+    admit_wait_s: Tuple[float, ...] = ()
 
 
 class EngineTelemetry:
@@ -109,6 +198,14 @@ class EngineTelemetry:
             M.SLOW_STEPS_TOTAL, "steps slower than DTPU_SLOW_STEP_MS",
             extra_labels=("phase",),
         )
+        # host seconds per loop phase (loop_span): the rate of each series is
+        # that phase's share of the loop's wall time. One counter family,
+        # no histogram per phase.
+        self._loop_phase = scope.counter(
+            M.LOOP_PHASE_SECONDS_TOTAL,
+            "host seconds spent in each phase of the engine step loop",
+            extra_labels=("phase",),
+        )
         self.slow_steps = 0
         # small rolling window + last-seen gauges for the /debug/worker
         # snapshot (runtime/health.py): step telemetry without a Prometheus
@@ -139,10 +236,16 @@ class EngineTelemetry:
             }
             for phase, agg in sorted(by_phase.items())
         }
+        loop_ns = _ns_by_phase(recent)
         out: Dict[str, Any] = {
             "steps_total": self.steps,
             "slow_steps_total": self.slow_steps,
             "recent": phases,
+            # mean host seconds per loop phase per step over the window
+            "loop_phases": {
+                name: round(loop_ns[name] / 1e9 / len(recent), 6)
+                for name in _ANNOTATION if name in loop_ns
+            },
         }
         last = self._last
         if last is not None:
@@ -173,6 +276,10 @@ class EngineTelemetry:
             self._decode_blocks.set(s.kv_active_blocks)
             if s.spec_acceptance is not None:
                 self._spec.set(s.spec_acceptance)
+            spent = _ns_by_phase((s,))
+            for name in _ANNOTATION:  # the label's fixed set
+                if name in spent:
+                    self._loop_phase.inc(spent[name] / 1e9, phase=name)
             if s.duration_s > self.slow_step_s:
                 self.slow_steps += 1
                 self._slow.inc(phase=s.phase)

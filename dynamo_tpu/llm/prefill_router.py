@@ -450,24 +450,41 @@ class PrefillRouter:
                 worker=f"{plan.worker_id:016x}", wire=plan.wire,
                 est_transfer_s=round(plan.est_transfer_s, 6),
             )
-            try:
-                stream = await self.client.generate(
-                    preq.to_obj(), context.child(), plan.worker_id
-                )
-                async for item in stream:
-                    out = (
-                        item if isinstance(item, BackendOutput)
-                        else BackendOutput.from_obj(item)
+            # trace hop, as in run_prefill: the clone's dispatch is its own
+            # span and the prefill worker's spans parent on it
+            tracer = get_tracer()
+            span = tracer.span(
+                "router.prefill",
+                traceparent=preq.annotations.get("traceparent"),
+                request_id=preq.request_id,
+                worker=f"{plan.worker_id:016x}", dp_rank=plan.dp_rank,
+                overlap_blocks=plan.overlap_blocks, wire=plan.wire,
+                est_transfer_s=round(plan.est_transfer_s, 6), streamed=True,
+            )
+            with span:
+                if tracer.enabled:
+                    preq.annotations["traceparent"] = span.traceparent()
+                try:
+                    stream = await self.client.generate(
+                        preq.to_obj(), context.child(), plan.worker_id
                     )
-                    if out.finish_reason is not None:
-                        break
-            except Exception:
-                # decode side recomputes whatever never streams over — the
-                # request still completes, just without the overlap win
-                log.exception(
-                    "streamed prefill failed for %s; decode side recomputes",
-                    preq.request_id[:8],
-                )
+                    async for item in stream:
+                        out = (
+                            item if isinstance(item, BackendOutput)
+                            else BackendOutput.from_obj(item)
+                        )
+                        if out.finish_reason is not None:
+                            break
+                except Exception as e:
+                    # decode side recomputes whatever never streams over —
+                    # the request still completes, just without the overlap
+                    # win
+                    span.status = "ERROR"
+                    span.set(error=repr(e))
+                    log.exception(
+                        "streamed prefill failed for %s; decode side "
+                        "recomputes", preq.request_id[:8],
+                    )
 
         # spawn_bg: a swallowed prefill failure would silently serialize
         # every streamed request behind the decode-side wait budget

@@ -68,7 +68,6 @@ ENV_FAULTS = "DTPU_FAULTS"                            # fault-injection spec, e.
 # engine/weight_service.py, parallel/pp_serving.py, runtime/multihost.py)
 ENV_MIXED = "DTPU_MIXED"                              # mixed continuous batching on/off/auto
 ENV_KV_DTYPE = "DTPU_KV_DTYPE"                        # paged KV cache dtype (int8 opt-in)
-ENV_LOOP_TRACE = "DTPU_LOOP_TRACE"                    # engine step-loop debug trace
 ENV_WARM_CACHE = "DTPU_WARM_CACHE"                    # host weight cache dir
 ENV_WEIGHT_SERVICE = "DTPU_WEIGHT_SERVICE"            # shared weight service address
 ENV_WEIGHT_SHM = "DTPU_WEIGHT_SHM"                    # weight shm segment prefix

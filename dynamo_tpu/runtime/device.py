@@ -2,7 +2,7 @@
 
 One place answers the three questions every entry point that compiles for a
 device used to answer on its own (engine worker, diffusion worker, profiler,
-``run.py``, ``bench.py``, ``tools/profile_*.py``, ``chip_smoke.py``):
+``run.py``, ``bench.py``, ``chip_smoke.py``):
 
 - is the backend a TPU (``on_tpu`` — the one predicate the kernel selection
   and the interpret switch share);

@@ -39,6 +39,8 @@ from __future__ import annotations
 import asyncio
 import collections
 import dataclasses
+import shutil
+import tempfile
 import time
 from typing import Any, Awaitable, Callable, Dict, List, Optional
 
@@ -58,6 +60,9 @@ from .request_plane.tcp import TcpClient
 from .tasks import spawn_bg
 
 log = get_logger("runtime.health")
+
+PROFILE_MAX_S = 30.0  # POST /debug/profile: the longest trace it takes
+PROFILE_KEEP = 4  # ... and how many trace directories a worker keeps
 
 
 class HealthState:
@@ -562,6 +567,13 @@ class StatusServer:
                  KV directory stats, drain state, restore mode, health
                  events) — the unit the frontend's ``/debug/fleet`` fan-out
                  merges (llm/fleet.py)
+      POST /debug/profile  ``?seconds=N`` (default 5, capped at 30): a
+                 ``jax.profiler`` trace of this process for N seconds,
+                 Python tracer off; answers ``{"dir", "seconds"}`` when it
+                 is written (a ``dtpu-profile-*`` directory under this
+                 host's TMPDIR; the last 4 are kept). The engine loop's ``dtpu.loop.*`` annotations
+                 (engine/telemetry.py) lie in it beside the device planes.
+                 One profile at a time: 409 while one runs
       POST /drain  planned-reclaim notice (engine/drain.py DrainCoordinator;
                  docs/operations.md §13): body ``{"deadline_s": 30}`` —
                  flips discovery to `draining`, evacuates/checkpoints, 409
@@ -605,8 +617,11 @@ class StatusServer:
         app.router.add_get("/debug/requests", self._debug_requests)
         app.router.add_get("/debug/slo", self._debug_slo)
         app.router.add_get("/debug/worker", self._debug_worker)
+        app.router.add_post("/debug/profile", self._debug_profile)
         app.router.add_post("/drain", self._drain)
         self.app = app
+        self._profiling = False
+        self._profile_dirs: collections.deque = collections.deque()
 
     async def _health(self, request: web.Request) -> web.Response:
         snap = self.state.snapshot()
@@ -660,6 +675,44 @@ class StatusServer:
             doc = {"health": self.state.snapshot()}
         doc = dict(doc, uptime_s=round(time.time() - self.started_at, 3))
         return web.json_response(doc)
+
+    async def _debug_profile(self, request: web.Request) -> web.Response:
+        raw = request.query.get("seconds", "5")
+        try:
+            seconds = min(float(raw), PROFILE_MAX_S)
+        except ValueError:
+            seconds = 0.0
+        if not seconds > 0:
+            return web.json_response({"error": f"bad seconds {raw!r}"}, status=400)
+        if self._profiling:
+            return web.json_response(
+                {"error": "a profile is already being taken"}, status=409
+            )
+        self._profiling = True
+        try:
+            import jax  # only a process that runs JAX has anything to profile
+
+            while len(self._profile_dirs) >= PROFILE_KEEP:
+                shutil.rmtree(self._profile_dirs.popleft(), ignore_errors=True)
+            trace_dir = tempfile.mkdtemp(prefix="dtpu-profile-")
+            self._profile_dirs.append(trace_dir)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0  # the Python tracer slows the host most
+            loop = asyncio.get_running_loop()
+            try:
+                await loop.run_in_executor(
+                    None,
+                    lambda: jax.profiler.start_trace(trace_dir, profiler_options=opts),
+                )
+            except RuntimeError as e:  # a profile started by other means
+                return web.json_response({"error": str(e)}, status=409)
+            try:
+                await asyncio.sleep(seconds)
+            finally:
+                await loop.run_in_executor(None, jax.profiler.stop_trace)
+        finally:
+            self._profiling = False
+        return web.json_response({"dir": trace_dir, "seconds": seconds})
 
     async def _drain(self, request: web.Request) -> web.Response:
         if self.drain_fn is None:

@@ -381,6 +381,9 @@ async def test_disagg_trace_reconstructs_hop_sequence(tmp_path, monkeypatch):
     # bytes (co-resident engines would silently take the ICI device path)
     monkeypatch.setenv("DTPU_ICI_TRANSFER", "0")
     monkeypatch.setenv("DTPU_DEVICE_TRANSFER", "0")
+    # the 30-token prompt is under deflect_max_tokens (128): without this the
+    # router serves it aggregated and no prefill hop exists to trace
+    monkeypatch.setenv("DTPU_DEFLECT", "0")
     path = str(tmp_path / "spans.jsonl")
     tracer = Tracer(JsonlExporter(path), batch_size=1)
     set_tracer(tracer)
