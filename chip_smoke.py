@@ -625,6 +625,27 @@ def _kernel_child() -> None:
             q, k_cache, v_cache, tables[:B], lens),
     )
 
+    # ...and the long-cache cell's contexts (BENCHMARK.json): 544 pages a
+    # row, one token short of them, a row that is padding, a one-token row
+    long_lens = np.asarray([8703, 0, 8704, 1, 4100], np.int32)
+    long_mb = 544
+    long_tables = np.zeros((len(long_lens), long_mb), np.int32)
+    pages = iter(rng.permutation(NB - 1) + 1)
+    for row, n in enumerate(long_lens):
+        for j in range(-(-int(n) // BS)):
+            long_tables[row, j] = next(pages)
+    ql = rnd(len(long_lens), H, D)
+    long_args = (ql, k_cache, v_cache, jnp.asarray(long_tables),
+                 jnp.asarray(long_lens))
+    got = np.asarray(pa.paged_decode_attention(*long_args), np.float32)
+    ref = np.asarray(highest(att.paged_decode_attention)(*long_args),
+                     np.float32)
+    live = long_lens > 0
+    if got[~live].any():
+        raise SystemExit("paged_decode_attention: an empty row is not zeros")
+    compare("paged_decode_attention 8k ragged, one empty row",
+            got[live], ref[live])
+
     # flash extend: a 512-token chunk continuing a 1024-token prefix
     S, T, start = 512, 2048, 1024
     k_ctx, v_ctx = att.gather_kv(k_cache, v_cache, tables[0])

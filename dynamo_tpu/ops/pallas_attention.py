@@ -5,21 +5,47 @@ hot path. The pure-JAX formulation gathers every sequence's full (padded) page
 table out of HBM each step; this kernel instead walks each sequence's *actual*
 pages with explicit HBM->VMEM DMAs, double-buffered so page fetch overlaps the
 flash-attention compute. HBM traffic becomes proportional to the ragged sum of
-true context lengths rather than B * max_blocks.
+true context lengths rather than B * max_blocks, and the kernel runs at the
+speed of those page reads (PERF.md section 5).
 
 This is the TPU analog of what the reference delegates to vLLM/FlashInfer
 paged-attention CUDA kernels (engine-internal; see SURVEY.md §2.5) — written
 from scratch against the paged layout ``[num_blocks, block_size, kv_heads,
 head_dim]`` shared with ops/attention.py and the KVBM transfer plane.
 
-Grid: one program per sequence. Scalar-prefetched block tables + sequence
-lengths (SMEM) drive the page DMAs; online-softmax (flash) accumulation over
-chunks of pages keeps VMEM usage constant in context length.
+Grid: ONE program; the rows of the batch are a loop inside it, so that reads
+stay in flight from one row to the next. Scalar-prefetched block tables +
+sequence lengths (SMEM) drive the page DMAs.
+
+Chunking: a row's pages are walked in chunks of ``chunk_pages`` pages, as many
+as ``_VMEM_CHUNK_BYTES`` holds in two slots of K and V (512 tokens at 8 kv
+heads x 128 in bf16) and no more than a row can have. The caches are handed
+over viewed as ``[num_blocks, block_size * kv_heads, head_dim]`` (the same
+bytes), so a chunk lands in VMEM as one dense ``[tokens * kv_heads, head_dim]``
+matrix: chunk row ``r`` is token ``r // kv_heads`` of kv head ``r % kv_heads``.
+
+Compute: ALL kv heads go through one product a chunk. ``q[h, d] . K^T`` gives
+``[h, tokens * kv_heads]``; query head ``i`` keeps the columns of its own kv
+head (``col % kv_heads == i // g``, a mask built once a call and added as a
+bias) and the rest leave the softmax as exact zeros, so ``p . V`` over the
+whole chunk is the per-head sum. Q and K meet the matrix unit in the dtype they
+arrive in (bf16 x bf16 products are exact in the f32 accumulator) and
+``1/sqrt(d)`` is applied to the f32 scores; the softmax state and ``p`` are f32.
+Only a row's LAST chunk can hold tokens past ``seq_len``: it alone masks the
+scores against the length and zeroes those V rows (never-read or stale VMEM
+may hold NaN, and 0 * NaN = NaN); full chunks skip both.
+
+In flight: while chunk ``c`` of a row is computed, chunk ``c + 1`` is being
+read into the other slot; while a row's last chunk is computed, the first
+chunk of the next non-empty row is. Rows with ``seq_len == 0`` (padding) read
+nothing, wait for nothing and return zeros. One DMA semaphore a slot and
+kind: every page copy of a chunk signals it, and it is waited once a page.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,164 +58,203 @@ from .quant import QuantizedKV, is_quantized
 
 NEG_INF = -1e30
 
+# VMEM for the page buffers: two slots each of K and V. 4 MiB reads 512 tokens
+# a chunk at 8 kv heads x 128 in bf16; on a v5e 256 to 1024 tokens a chunk all
+# run within 1% of each other (PERF.md section 6, PR 25).
+_VMEM_CHUNK_BYTES = 4 * 1024 * 1024
+
 
 def _decode_kernel(
     # scalar prefetch (SMEM)
     tables_ref,     # [B * max_blocks] int32 flattened block tables
     lens_ref,       # [B] int32 context lengths (incl. current token)
     # inputs
-    q_ref,          # VMEM [1, h, d] this sequence's query
-    k_hbm,          # ANY/HBM [num_blocks, bs, kvh, d] (model dtype or int8)
-    v_hbm,          # ANY/HBM [num_blocks, bs, kvh, d]
+    q_ref,          # VMEM [B, h, d] every sequence's query
+    k_hbm,          # ANY/HBM [num_blocks, bs * kvh, d] (model dtype or int8)
+    v_hbm,          # ANY/HBM [num_blocks, bs * kvh, d]
     # quantized=True only: ks_hbm/vs_hbm ANY/HBM [num_blocks, kvh] f32 scales
     # outputs
-    # o_ref         VMEM [1, h, d]
+    # o_ref         VMEM [B, h, d]
     # scratch
-    # k_buf/v_buf   VMEM [2, CP, bs, kvh, d] double-buffered page chunks
+    # k_buf/v_buf   VMEM [2, CP, bs * kvh, d] double-buffered page chunks
     # quantized=True only: ks_buf/vs_buf VMEM [2, CP, kvh] f32 scale rows
-    # sem           DMA sems [2, 2, CP] (k/v, slot, page)
-    # quantized=True only: ssem DMA sems [2, 2, CP] for the scale rows
+    # bias_ref      VMEM [h, CP * bs * kvh] f32: 0 on a query head's own kv
+    #               head's columns, NEG_INF elsewhere
+    # sem           DMA sems [2, 2] (k/v, slot)
+    # quantized=True only: ssem DMA sems [2, 2] for the scale rows
     *rest,
     max_blocks: int,
     chunk_pages: int,
+    kvh: int,
     quantized: bool,
 ):
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem,
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, bias_ref, sem,
          ssem) = rest
     else:
-        o_ref, k_buf, v_buf, sem = rest
+        o_ref, k_buf, v_buf, bias_ref, sem = rest
         ks_hbm = vs_hbm = ks_buf = vs_buf = ssem = None
-    b = pl.program_id(0)
-    bs, kvh, d = k_hbm.shape[1], k_hbm.shape[2], k_hbm.shape[3]
-    h = q_ref.shape[1]
+    B, h, d = q_ref.shape
+    R = k_hbm.shape[1]      # rows of a page: (token, kv head) pairs
+    bs = R // kvh
     g = h // kvh
     CP = chunk_pages
+    N = CP * R
     T = CP * bs
 
-    seq_len = lens_ref[b]
-    num_pages = pl.cdiv(seq_len, bs)
-    num_chunks = pl.cdiv(num_pages, CP)
-
-    def page_dma(kind, c, j, slot):
-        """DMA descriptor for page j of chunk c into buffer slot."""
-        idx = tables_ref[b * max_blocks + c * CP + j]
-        src = k_hbm if kind == 0 else v_hbm
-        dst = k_buf if kind == 0 else v_buf
-        return pltpu.make_async_copy(
-            src.at[idx], dst.at[slot, j], sem.at[kind, slot, j]
-        )
-
-    def scale_dma(kind, c, j, slot):
-        """Scale-row DMA for page j: rides the same prefetched table index
-        the page DMA uses — [kvh] f32 per page, ~1000x smaller than the
-        payload it describes. NOTE (hardware): this slice's minor dim is
-        kvh, not 128-aligned; CPU tier-1 only exercises interpret mode, so
-        the first real-TPU int8 run must confirm Mosaic accepts the copy
-        (fallback if not: use_pallas=False or pad scales to [nb, kvh, 128]
-        sublane-major)."""
-        idx = tables_ref[b * max_blocks + c * CP + j]
-        src = ks_hbm if kind == 0 else vs_hbm
-        dst = ks_buf if kind == 0 else vs_buf
-        return pltpu.make_async_copy(
-            src.at[idx], dst.at[slot, j], ssem.at[kind, slot, j]
-        )
-
-    def start_chunk(c, slot):
-        for j in range(CP):  # static unroll; guard ragged tail
-            @pl.when(c * CP + j < num_pages)
-            def _():
-                page_dma(0, c, j, slot).start()
-                page_dma(1, c, j, slot).start()
-                if quantized:
-                    scale_dma(0, c, j, slot).start()
-                    scale_dma(1, c, j, slot).start()
-
-    def wait_chunk(c, slot):
-        for j in range(CP):
-            @pl.when(c * CP + j < num_pages)
-            def _():
-                page_dma(0, c, j, slot).wait()
-                page_dma(1, c, j, slot).wait()
-                if quantized:
-                    scale_dma(0, c, j, slot).wait()
-                    scale_dma(1, c, j, slot).wait()
-
-    start_chunk(0, 0)
-
-    scale = 1.0 / (d ** 0.5)
-    qf = q_ref[0].astype(jnp.float32) * scale  # [h, d]
-
-    def body(c, carry):
-        m_prev, l_prev, acc_prev = carry
-        slot = jax.lax.rem(c, 2)
-
-        @pl.when(c + 1 < num_chunks)
-        def _():
-            start_chunk(c + 1, jax.lax.rem(c + 1, 2))
-
-        wait_chunk(c, slot)
-
+    def chunk_copies(slot, idx, j):
+        """Descriptors of page ``idx`` into place ``j`` of ``slot``. Scale
+        rows ride the same prefetched table index — [kvh] f32 per page, ~1000x
+        smaller than the payload they describe. NOTE (hardware): that slice's
+        minor dim is kvh, not 128-aligned; Mosaic refuses the copy
+        (tests/test_tpu_compile.py pins it) and the engine refuses int8 with
+        the Pallas kernels on the TPU backend, so this runs interpreted only."""
+        copies = [
+            pltpu.make_async_copy(
+                k_hbm.at[idx], k_buf.at[slot, j], sem.at[0, slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[idx], v_buf.at[slot, j], sem.at[1, slot]),
+        ]
         if quantized:
-            # dequantize in-register: int8 page chunks -> f32 scaled by the
-            # per-(page, kv-head) rows that just DMA'd in alongside them.
-            # HBM traffic for the K/V bytes themselves is halved vs bf16.
-            k = (
-                k_buf[slot].astype(jnp.float32)
-                * ks_buf[slot][:, None, :, None]
-            ).reshape(T, kvh, d)
-            v = (
-                v_buf[slot].astype(jnp.float32)
-                * vs_buf[slot][:, None, :, None]
-            ).reshape(T, kvh, d)
-        else:
-            k = k_buf[slot].reshape(T, kvh, d).astype(jnp.float32)
-            v = v_buf[slot].reshape(T, kvh, d).astype(jnp.float32)
-        # rows past seq_len were never DMA'd (garbage / NaN): scores are
-        # masked below, but V must be zeroed too — 0-weight * NaN = NaN in
-        # the PV matmul otherwise
-        row_pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1, 1), 0)
-        v = jnp.where(row_pos < seq_len, v, 0.0)
+            copies += [
+                pltpu.make_async_copy(
+                    ks_hbm.at[idx], ks_buf.at[slot, j], ssem.at[0, slot]),
+                pltpu.make_async_copy(
+                    vs_hbm.at[idx], vs_buf.at[slot, j], ssem.at[1, slot]),
+            ]
+        return copies
 
-        # scores [h, T]: per-kv-head MXU matmuls (GQA grouping: q heads
-        # [i*g, (i+1)*g) attend kv head i, matching attention._gqa_scores)
-        parts = []
-        for i in range(kvh):
-            s_i = jax.lax.dot_general(
-                qf[i * g:(i + 1) * g], k[:, i, :],
+    def pages_in_chunk(row, c):
+        return jnp.minimum(CP, pl.cdiv(lens_ref[row], bs) - c * CP)
+
+    def start_chunk(row, c, slot):
+        base = row * max_blocks + c * CP
+
+        def issue(j, carry):
+            for copy in chunk_copies(slot, tables_ref[base + j], j):
+                copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, pages_in_chunk(row, c), issue, 0)
+
+    def wait_chunk(row, c, slot):
+        def one(j, carry):
+            # the descriptor only says how many bytes one page signals
+            for copy in chunk_copies(slot, 0, 0):
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, pages_in_chunk(row, c), one, 0)
+
+    def start_next_row(row, slot):
+        """Start chunk 0 of the first non-empty row after ``row``, if any."""
+        nxt = jax.lax.while_loop(
+            lambda r: jnp.logical_and(
+                r < B, lens_ref[jnp.minimum(r, B - 1)] == 0),
+            lambda r: r + 1,
+            row + 1,
+        )
+
+        @pl.when(nxt < B)
+        def _():
+            start_chunk(nxt, 0, slot)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, N), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (h, N), 0)
+    bias_ref[...] = jnp.where(
+        jax.lax.rem(col, kvh) == head // g, 0.0, NEG_INF
+    ).astype(jnp.float32)
+    scale = 1.0 / (d ** 0.5)
+    start_next_row(-1, 0)
+
+    def row_body(b, slot0):
+        seq_len = lens_ref[b]
+        num_chunks = pl.cdiv(pl.cdiv(seq_len, bs), CP)
+        q = q_ref[b]
+
+        def chunk(c, carry, *, tail):
+            m_prev, l_prev, acc_prev = carry
+            slot = jax.lax.rem(slot0 + c, 2)
+            if tail:
+                start_next_row(b, 1 - slot)
+            else:
+                start_chunk(b, c + 1, 1 - slot)
+            wait_chunk(b, c, slot)
+
+            # chunk rows below `limit` are inside the context
+            limit = (seq_len - c * T) * kvh
+            k = k_buf[slot].reshape(N, d)
+            v = v_buf[slot].reshape(N, d)
+            if quantized:
+                # dequantize in-register: int8 page chunks -> f32 scaled by
+                # the per-(page, kv-head) rows that DMA'd in alongside them.
+                # HBM traffic for the K/V bytes themselves is halved vs bf16.
+                def dequant(x, s):
+                    x = x.astype(jnp.float32).reshape(CP, bs, kvh, d)
+                    return (x * s[:, None, :, None]).reshape(N, d)
+
+                k, v = dequant(k, ks_buf[slot]), dequant(v, vs_buf[slot])
+            v = v.astype(jnp.float32)
+            if tail:
+                # rows past seq_len were never DMA'd (stale / NaN): scores
+                # are masked below, but V must be zeroed too — 0-weight *
+                # NaN = NaN in the PV matmul otherwise
+                rows = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
+                v = jnp.where(rows < limit, v, 0.0)
+            qk = q
+            if k.dtype != q.dtype:
+                qk, k = q.astype(jnp.float32), k.astype(jnp.float32)
+
+            # scores [h, N] of every query head against every kv head's keys
+            # in ONE product; the bias keeps a query head's own kv head (GQA
+            # grouping: q heads [i*g, (i+1)*g) attend kv head i, matching
+            # attention._gqa_scores)
+            s = jax.lax.dot_general(
+                qk, k,
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [g, T]
-            parts.append(s_i)
-        s = jnp.concatenate(parts, axis=0) if kvh > 1 else parts[0]
+            ) * scale + bias_ref[...]
+            if tail:
+                key_row = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
+                s = jnp.where(key_row < limit, s, NEG_INF)
 
-        key_pos = c * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        s = jnp.where(key_pos < seq_len, s, NEG_INF)
-
-        m_cur = jnp.max(s, axis=-1, keepdims=True)            # [h, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                                # [h, T]
-        alpha = jnp.exp(m_prev - m_new)                       # [h, 1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-
-        outs = []
-        for i in range(kvh):
-            o_i = jax.lax.dot_general(
-                p[i * g:(i + 1) * g], v[:, i, :],
+            m_cur = jnp.max(s, axis=-1, keepdims=True)            # [h, 1]
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)                                # [h, N]
+            alpha = jnp.exp(m_prev - m_new)                       # [h, 1]
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p, v,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [g, d]
-            outs.append(o_i)
-        pv = jnp.concatenate(outs, axis=0) if kvh > 1 else outs[0]
-        acc_new = alpha * acc_prev + pv
-        return m_new, l_new, acc_new
+            )  # [h, d]
+            return m_new, l_new, alpha * acc_prev + pv
 
-    m0 = jnp.full((h, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((h, 1), jnp.float32)
-    a0 = jnp.zeros((h, d), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, num_chunks, body, (m0, l0, a0))
+        @pl.when(seq_len == 0)
+        def _():
+            o_ref[b] = jnp.zeros((h, d), o_ref.dtype)
 
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        @pl.when(seq_len > 0)
+        def _():
+            m0 = jnp.full((h, 1), NEG_INF, jnp.float32)
+            l0 = jnp.zeros((h, 1), jnp.float32)
+            a0 = jnp.zeros((h, d), jnp.float32)
+            carry = jax.lax.fori_loop(
+                0, num_chunks - 1, functools.partial(chunk, tail=False),
+                (m0, l0, a0),
+            )
+            _, l, acc = chunk(num_chunks - 1, carry, tail=True)
+            o_ref[b] = (acc / l).astype(o_ref.dtype)
+
+        return jax.lax.rem(slot0 + num_chunks, 2)
+
+    jax.lax.fori_loop(0, B, row_body, 0)
+
+
+def _chunk_pages(bs: int, kvh: int, d: int, dtype, max_blocks: int) -> int:
+    """Pages a chunk: what ``_VMEM_CHUNK_BYTES`` holds, at most a row's."""
+    page_bytes = bs * kvh * d * jnp.dtype(dtype).itemsize
+    return max(1, min(_VMEM_CHUNK_BYTES // (4 * page_bytes), max_blocks))
 
 
 @functools.partial(
@@ -202,31 +267,37 @@ def paged_decode_attention(
     block_tables: jax.Array,  # [B, max_blocks] int32
     seq_lens: jax.Array,      # [B] int32
     *,
-    chunk_tokens: int = 128,
+    chunk_tokens: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Ragged paged decode attention (Pallas). Same semantics as
-    ``ops.attention.paged_decode_attention``. ``k_cache``/``v_cache`` may be
-    ``QuantizedKV`` (int8 payload + per-block scales): the kernel DMAs the
-    int8 pages plus their scale rows and dequantizes in-register, so the
-    per-page HBM bytes halve vs bf16."""
+    ``ops.attention.paged_decode_attention``; a row with ``seq_len == 0``
+    returns zeros. ``k_cache``/``v_cache`` may be ``QuantizedKV`` (int8
+    payload + per-block scales): the kernel DMAs the int8 pages plus their
+    scale rows and dequantizes in-register, so the per-page HBM bytes halve
+    vs bf16. ``chunk_tokens`` overrides the derived chunk size (tests: a
+    chunk loop over small contexts); no call site of the program sets it."""
     B, h, d = q.shape
-    _, bs, kvh, _ = k_cache.shape
+    nb, bs, kvh, _ = k_cache.shape
     max_blocks = block_tables.shape[1]
-    chunk_pages = max(1, chunk_tokens // bs)
     quantized = is_quantized(k_cache)
+    pages = k_cache.data if quantized else k_cache
+    if chunk_tokens is None:
+        chunk_pages = _chunk_pages(bs, kvh, d, pages.dtype, max_blocks)
+    else:
+        chunk_pages = max(1, chunk_tokens // bs)
 
     kernel = functools.partial(
         _decode_kernel, max_blocks=max_blocks, chunk_pages=chunk_pages,
-        quantized=quantized,
+        kvh=kvh, quantized=quantized,
     )
     cache_specs = [
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     scratch = [
-        pltpu.VMEM((2, chunk_pages, bs, kvh, d), k_cache.dtype),
-        pltpu.VMEM((2, chunk_pages, bs, kvh, d), v_cache.dtype),
+        pltpu.VMEM((2, chunk_pages, bs * kvh, d), pages.dtype),
+        pltpu.VMEM((2, chunk_pages, bs * kvh, d), pages.dtype),
     ]
     if quantized:
         cache_specs += [
@@ -237,20 +308,25 @@ def paged_decode_attention(
             pltpu.VMEM((2, chunk_pages, kvh), jnp.float32),
             pltpu.VMEM((2, chunk_pages, kvh), jnp.float32),
         ]
-    scratch.append(pltpu.SemaphoreType.DMA((2, 2, chunk_pages)))
+    scratch.append(pltpu.VMEM((h, chunk_pages * bs * kvh), jnp.float32))
+    scratch.append(pltpu.SemaphoreType.DMA((2, 2)))
     if quantized:
-        scratch.append(pltpu.SemaphoreType.DMA((2, 2, chunk_pages)))
+        scratch.append(pltpu.SemaphoreType.DMA((2, 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[pl.BlockSpec((1, h, d), lambda b, *_: (b, 0, 0))]
+        grid=(1,),
+        in_specs=[pl.BlockSpec((B, h, d), lambda i, *_: (0, 0, 0))]
         + cache_specs,
-        out_specs=pl.BlockSpec((1, h, d), lambda b, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((B, h, d), lambda i, *_: (0, 0, 0)),
         scratch_shapes=scratch,
     )
+
+    def rows(cache):  # [nb, bs, kvh, d] -> [nb, bs * kvh, d]: the same bytes
+        return cache.reshape(nb, bs * kvh, d)
+
     cache_args = (
-        (k_cache.data, v_cache.data, k_cache.scale, v_cache.scale)
-        if quantized else (k_cache, v_cache)
+        (rows(k_cache.data), rows(v_cache.data), k_cache.scale, v_cache.scale)
+        if quantized else (rows(k_cache), rows(v_cache))
     )
     return pl.pallas_call(
         kernel,

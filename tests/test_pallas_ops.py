@@ -5,6 +5,7 @@ and delegated attention kernels behaviorally; here the same kernels that run
 compiled on TPU execute under the Pallas interpreter so CI needs no chips.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -69,6 +70,115 @@ def test_pallas_decode_chunk_larger_than_context():
         q, kc, vc, tables, lens, chunk_tokens=4 * 16, interpret=True
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def _poisoned_case(rng, lens, h, kvh, d, bs, dtype, int8):
+    """A paged case whose every token slot OUTSIDE a row's context would show
+    in the output if the kernel read it: the clean caches (zeros there) go to
+    the reference, the poisoned ones (NaN values under keys aligned with the
+    query; NaN scales on the unused pages for int8) to the kernel. Page 0 is
+    what the padding of a block table points at."""
+    B = len(lens)
+    pages = [-(-n // bs) for n in lens]
+    mb = max(max(pages), 1)
+    nb = sum(pages) + 2
+    q = rng.standard_normal((B, h, d)).astype(np.float32)
+    k = rng.standard_normal((nb, bs, kvh, d)).astype(np.float32)
+    v = rng.standard_normal((nb, bs, kvh, d)).astype(np.float32)
+    inside = np.zeros((nb, bs), bool)
+    tables = np.zeros((B, mb), np.int32)
+    free = list(range(1, nb))
+    for b, n in enumerate(lens):
+        for j in range(pages[b]):
+            tables[b, j] = page = free.pop()
+            inside[page, : min(bs, n - j * bs)] = True
+        if n:
+            # the LAST token of a context decides the output, so a kernel
+            # that drops it is off by far more than the tolerance
+            page, slot = tables[b, pages[b] - 1], (n - 1) % bs
+            k[page, slot] = 4.0 * q[b].reshape(kvh, h // kvh, d).mean(1)
+    k[~inside] = 0.0
+    v[~inside] = 0.0
+    args = jnp.asarray(q, dtype), jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+    if int8:
+        from dynamo_tpu.ops import quant
+
+        kq, ks = quant.quantize_blocks(jnp.asarray(k))
+        vq, vs = quant.quantize_blocks(jnp.asarray(v))
+        unused = jnp.asarray(~inside.any(axis=1))[:, None]
+        clean = quant.QuantizedKV(kq, ks), quant.QuantizedKV(vq, vs)
+        dirty = (quant.QuantizedKV(kq, jnp.where(unused, jnp.nan, ks)),
+                 quant.QuantizedKV(vq, jnp.where(unused, jnp.nan, vs)))
+        return args, clean, dirty
+    kd, vd = k.copy(), v.copy()
+    kd[~inside] = 50.0
+    vd[~inside] = np.nan
+    as_dtype = lambda *xs: tuple(jnp.asarray(x, dtype) for x in xs)
+    return args, as_dtype(k, v), as_dtype(kd, vd)
+
+
+# lens, h, kvh, d, bs, dtype, chunk_tokens (None: the derived size), int8
+DECODE_CASES = {
+    # the long-cache cell's shapes (BENCHMARK.json): one token short of the
+    # full 544 pages, and all of them; 34 chunks of 256 tokens in f32, 17 of
+    # 512 in bf16
+    "cell-8703-8704-f32": ([8703, 8704], 16, 8, 128, 16, jnp.float32, None, False),
+    "cell-8703-8704-bf16": ([8703, 8704], 16, 8, 128, 16, jnp.bfloat16, None, False),
+    # padding rows first, in the middle and last; one token; exactly one
+    # chunk, one chunk + 1, a multiple of the chunk (64 tokens a chunk)
+    "ragged-with-empty-rows": (
+        [0, 1, 64, 0, 0, 65, 128, 192, 17, 0], 16, 8, 32, 16, jnp.float32, 64, False),
+    "ragged-with-empty-rows-bf16": (
+        [0, 1, 64, 0, 0, 65, 128, 192, 17, 0], 16, 8, 32, 16, jnp.bfloat16, 64, False),
+    "all-rows-empty": ([0, 0, 0], 8, 4, 32, 16, jnp.float32, 64, False),
+    # a tp=4 shard of Mistral-7B: 2 kv heads, 4 query heads each
+    "kvh2-g4-tp4-shard": (
+        [0, 1, 64, 65, 130, 0, 300], 8, 2, 32, 16, jnp.float32, 64, False),
+    "kvh1-g-is-h": ([5, 0, 64, 65, 129], 4, 1, 32, 16, jnp.float32, 64, False),
+    "derived-chunk-one-row-many-chunks": (
+        [1500, 0, 3], 8, 4, 128, 16, jnp.float32, None, False),
+    "int8-ragged-with-empty-rows": (
+        [0, 1, 64, 0, 0, 65, 128, 192, 17, 0], 16, 8, 32, 16, jnp.float32, 64, True),
+    "int8-kvh2-g4": (
+        [0, 1, 64, 65, 130, 0, 300], 8, 2, 32, 16, jnp.float32, 64, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECODE_CASES))
+def test_pallas_decode_reads_exactly_the_context(name):
+    """Every row attends to exactly its seq_len tokens (interpret mode,
+    against the pure-JAX twin at `highest` precision): the shapes the chip
+    benchmark runs and its `correct` check, over 200-600 token contexts,
+    does not. An empty row reads nothing and returns zeros."""
+    lens, h, kvh, d, bs, dtype, chunk_tokens, int8 = DECODE_CASES[name]
+    (q, tables, seq_lens), clean, dirty = _poisoned_case(
+        np.random.default_rng(25), lens, h, kvh, d, bs, dtype, int8
+    )
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(
+            att.paged_decode_attention(q, *clean, tables, seq_lens), np.float32)
+    got = np.asarray(
+        pa.paged_decode_attention(
+            q, *dirty, tables, seq_lens, chunk_tokens=chunk_tokens,
+            interpret=True),
+        np.float32,
+    )
+    live = np.asarray(lens) > 0
+    assert not got[~live].any()
+    tol = 2e-5 if dtype == jnp.float32 else 2.0 ** -7  # bf16: the output's ulp
+    np.testing.assert_allclose(got[live], ref[live], atol=tol, rtol=tol)
+
+
+def test_derived_chunk_fits_the_vmem_budget():
+    """The chunk is what the budget holds in two slots of K and V, and never
+    more pages than a row has."""
+    page = 16 * 8 * 128 * 2
+    assert pa._chunk_pages(16, 8, 128, jnp.bfloat16, 544) == 32
+    assert 4 * 32 * page == pa._VMEM_CHUNK_BYTES
+    assert pa._chunk_pages(16, 8, 128, jnp.float32, 544) == 16
+    assert pa._chunk_pages(16, 2, 128, jnp.bfloat16, 192) == 128
+    assert pa._chunk_pages(16, 8, 128, jnp.bfloat16, 6) == 6
+    assert pa._chunk_pages(16, 8, 128, jnp.int8, 544) == 64
 
 
 def test_gather_blocks():

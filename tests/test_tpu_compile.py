@@ -72,11 +72,12 @@ def _cases():
     """name -> (fn, shape-args builder). Builders take the ShapeDtypeStruct
     factory so one table serves any sharding."""
 
-    def decode(kvh, h):
+    def decode(kvh, h, rows=B, max_blocks=MB, num_blocks=NB):
         def build(sh):
-            s, cache, *_ = _shapes(sh, kvh, h)
-            return (s((B, h, D), BF), cache, cache, s((B, MB), I32),
-                    s((B,), I32))
+            s, *_ = _shapes(sh, kvh, h)
+            cache = s((num_blocks, BS, kvh, D), BF)
+            return (s((rows, h, D), BF), cache, cache,
+                    s((rows, max_blocks), I32), s((rows,), I32))
         return pa.paged_decode_attention, build
 
     def flash(S):
@@ -117,6 +118,10 @@ def _cases():
     return {
         "decode-bf16-kvh8": decode(KVH, H),
         "decode-bf16-kvh2-tp4-shard": decode(KVH // 4, H // 4),
+        # the benchmark's internlm2 cells (BENCHMARK.json): 6 400 pages,
+        # batch 12 over 8 704-token contexts, batch 32 over 3 072
+        "decode-bf16-longcache-cell": decode(KVH, H, 12, 544, 6400),
+        "decode-bf16-chat-cell": decode(KVH, H, 32, 192, 6400),
         "flash-extend-S128": flash(128),
         "flash-extend-S512": flash(512),
         "flash-extend-S2048": flash(2048),
