@@ -564,6 +564,7 @@ def _kernel_child() -> None:
     from dynamo_tpu.ops import attention as att
     from dynamo_tpu.ops import block_copy as bc
     from dynamo_tpu.ops import pallas_attention as pa
+    from dynamo_tpu.ops import pallas_moe as pmoe
     from dynamo_tpu.ops import pallas_prefill as pf
     from dynamo_tpu.ops import pallas_unified as pun
     from dynamo_tpu.runtime.device import (
@@ -686,6 +687,21 @@ def _kernel_child() -> None:
             *u_args, windows=windows, sinks=sinks, softcap=30.0),
         ref_unified(*u_args, windows=windows, sinks=sinks, softcap=30.0),
     )
+
+    # grouped expert multiplication (the one-chip MoE layer): 300 sorted
+    # rows over 8 experts, one empty, groups that straddle a 128-row tile;
+    # the SwiGLU front half (two stacks) and the down projection (one)
+    sizes = jnp.asarray([70, 0, 7, 90, 1, 100, 2, 30], jnp.int32)
+    rows = rnd(300, 512)
+    w_gate, w_up = (rnd(8, 512, 384) * 0.044 for _ in range(2))
+    w_down = rnd(8, 384, 512) * 0.051
+    ref_grouped = highest(pmoe.grouped_matmul_reference)
+    act = pmoe.grouped_matmul(rows, (w_gate, w_up), sizes)
+    compare("moe_grouped_matmul gate, up and SwiGLU",
+            act, ref_grouped(rows, (w_gate, w_up), sizes))
+    compare("moe_grouped_matmul down",
+            pmoe.grouped_matmul(act, (w_down,), sizes),
+            ref_grouped(act, (w_down,), sizes))
 
     # block moves are copies: exact
     ids = jnp.asarray(rng.permutation(NB)[:32], jnp.int32)

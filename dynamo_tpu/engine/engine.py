@@ -46,6 +46,7 @@ from ..llm.protocols.common import (
     PreprocessedRequest,
 )
 from ..models import llama, registry
+from ..models import moe as moe_lib
 from ..models.vision import IMAGE_TOKEN_ID
 from ..ops import attention as att
 from ..parallel import mesh as meshlib
@@ -561,8 +562,19 @@ class TpuEngine:
 
         # --- place params + caches on the mesh ---
         self._forward = (
-            None if config.pp > 1 else registry.forward_fn(self.mcfg, self.mesh)
+            None if config.pp > 1 else registry.forward_fn(
+                self.mcfg, self.mesh, use_pallas=self.use_pallas,
+                interpret=self.kernels_interpreted,
+            )
         )
+        # the one-chip grouped expert path reports its routing each step
+        # (StepStats.moe_*): three numbers riding the readback a decode or
+        # mixed step already makes. None where no step carried them.
+        self._moe_counted = (
+            registry.is_moe(self.mcfg) and config.pp == 1
+            and meshlib.tp_size(self.mesh) == 1
+        )
+        self._moe_last: Optional[Tuple[int, int, int]] = None
         self._lm_logits = registry.lm_logits_fn(self.mcfg)
         with self.mesh:
             if params is None and (
@@ -987,12 +999,16 @@ class TpuEngine:
         dim, so odd head sizes fall back to pure JAX); the shard_map'd
         kernel shards the cache on kv_heads, so fewer kv heads than TP
         shards (MQA / MLA latent) falls back to the GSPMD pure-JAX path.
-        The windowed/sink families (gpt-oss, gemma) are SUPPORTED by the
-        unified kernel's per-row attributes but stay off the auto rule
-        until a real-TPU run confirms the windowed chunk-start lowering
-        (the PR 2 caveat protocol) — an explicit use_pallas=True routes
-        their windowed/sink layers through the unified launch. pp serving
-        never uses Pallas (construction rejects the combination)."""
+        Per-row WINDOWS are confirmed on the chip (PR 26, TPU v5e: the
+        unified kernel's windowed launch, chunk-start page skip included,
+        compiled by Mosaic and held to the float32 reference at 4 kv heads,
+        16-token pages, window 1 024, contexts to 7 168, in prefill chunks,
+        mixed steps and q_len=1 decode rows), so a windowed MoeConfig rides
+        the auto rule. Per-head SINKS and the logit SOFTCAP have only run
+        in the interpreter: gpt-oss and gemma stay off the auto rule until
+        a chip run holds them too — an explicit use_pallas=True routes
+        their layers through the unified launch. pp serving never uses
+        Pallas (construction rejects the combination)."""
         if self.cfg.pp > 1:
             return False
         if self.cfg.use_pallas is not None:
@@ -1219,9 +1235,13 @@ class TpuEngine:
 
         vision_enabled = cfg.vision is not None
 
+        moe_counted = self._moe_counted
+
         def call_fwd(params, tokens, positions, attend, lora_tables, lora_ids,
-                     mm_embeds=None, mm_mask=None):
+                     mm_embeds=None, mm_mask=None, moe_stats=None):
             kw = {}
+            if moe_stats is not None:
+                kw["stats"] = moe_stats
             if lora_enabled:
                 from ..lora import make_lora_fn
 
@@ -1308,19 +1328,26 @@ class TpuEngine:
         def _fetchable(x):
             return jax.lax.with_sharding_constraint(x, repl)
 
-        def pack_step(toks, lps, tlp_vals, tlp_ids):
+        def pack_step(toks, lps, tlp_vals, tlp_ids, moe=None):
             """[B] toks/lps + [B,K] top-logprob rows -> one [B, 2+2K] f32 row
             (token ids are exact in f32 below 2^24) so the host pays a single
-            device->host fetch per horizon."""
-            return jnp.concatenate(
-                [
-                    toks.astype(jnp.float32)[:, None],
-                    lps[:, None],
-                    tlp_ids.astype(jnp.float32),
-                    tlp_vals,
-                ],
-                axis=-1,
-            )
+            device->host fetch per horizon. ``moe`` ([3], the step's routing
+            counters) rides as three more columns, the same in every row."""
+            cols = [
+                toks.astype(jnp.float32)[:, None],
+                lps[:, None],
+                tlp_ids.astype(jnp.float32),
+                tlp_vals,
+            ]
+            if moe is not None:
+                cols.append(jnp.broadcast_to(moe[None], (toks.shape[0], 3)))
+            return jnp.concatenate(cols, axis=-1)
+
+        def routing_stats(valid):
+            """A collector for this forward's routing counts (one-chip MoE),
+            or None: a TRACE-time branch, other families' programs are
+            unchanged."""
+            return moe_lib.RoutingStats(valid) if moe_counted else None
 
         # guided decoding ops (cfg.guided_max_states > 0): one [B, C] row
         # gather + one [B, V] class lookup per step. Callers pass g_* only
@@ -1517,9 +1544,10 @@ class TpuEngine:
                 )
                 return out[:, None]
 
+            moe_stats = routing_stats(seq_lens > 0)
             hidden = call_fwd(
                 params, tokens[:, None], positions[:, None], attend,
-                lora_tables, lora_ids,
+                lora_tables, lora_ids, moe_stats=moe_stats,
             )  # [B, 1, H]
             logits = logits_fn(params, mcfg, hidden[:, 0])  # [B, V]
             pen = apply_penalties(logits, counts, prompt_masks, pres, freqs, reps)
@@ -1531,6 +1559,9 @@ class TpuEngine:
                 counts, toks, seq_lens > 0, counts_need(pres, freqs, reps, proc_masks)
             )
             lps = logprobs_of(logits, toks)
+            if moe_stats is not None:
+                # the counters ride the logprob readback: lps is [B + 3]
+                lps = jnp.concatenate([lps, moe_stats.reduce()])
             tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
             toks, lps, tlp_vals, tlp_ids = map(
                 _fetchable, (toks, lps, tlp_vals, tlp_ids)
@@ -1578,9 +1609,10 @@ class TpuEngine:
                     )
                     return out[:, None]
 
+                moe_stats = routing_stats(active)
                 hidden = call_fwd(
                     params, tokens[:, None], positions[:, None], attend,
-                    lora_tables, lora_ids,
+                    lora_tables, lora_ids, moe_stats=moe_stats,
                 )
                 logits = logits_fn(params, mcfg, hidden[:, 0])
                 pen = apply_penalties(logits, counts, prompt_masks, pres, freqs, reps)
@@ -1598,7 +1630,10 @@ class TpuEngine:
                 seq_lens = seq_lens + active.astype(jnp.int32)
                 return (
                     (k_caches, v_caches, counts, toks, seq_lens, g_st),
-                    pack_step(toks, lps, tlp_vals, tlp_ids),
+                    pack_step(
+                        toks, lps, tlp_vals, tlp_ids,
+                        moe=None if moe_stats is None else moe_stats.reduce(),
+                    ),
                 )
 
             g0 = g_state if g_state is not None else jnp.zeros_like(tokens)
@@ -1712,9 +1747,12 @@ class TpuEngine:
                 ])
             else:
                 packed_lora_ids = lora_ids
+            moe_stats = routing_stats(
+                jnp.concatenate([c_positions < c_total_len, active])
+            )
             hidden = call_fwd(
                 params, tokens, positions, attend, lora_tables,
-                packed_lora_ids,
+                packed_lora_ids, moe_stats=moe_stats,
             )  # [S_pad + B, H]
 
             # -- decode epilogue: verbatim decode() ---------------------------
@@ -1733,6 +1771,9 @@ class TpuEngine:
                 counts_need(pres, freqs, reps, proc_masks),
             )
             lps = logprobs_of(logits, toks)
+            if moe_stats is not None:
+                # as in decode(): lps is [B + 3], chunk rows counted too
+                lps = jnp.concatenate([lps, moe_stats.reduce()])
             tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
 
             # -- chunk epilogue: verbatim prefill() (slot-sliced args) --------
@@ -4206,7 +4247,14 @@ class TpuEngine:
         toks = packed_np[:, :, 0].astype(np.int32)
         lps = packed_np[:, :, 1]
         tlp_ids = packed_np[:, :, 2 : 2 + K].astype(np.int32)
-        tlp_vals = packed_np[:, :, 2 + K :]
+        tlp_vals = packed_np[:, :, 2 + K : 2 + 2 * K]
+        if self._moe_counted:
+            # three more columns, the same in every row: a horizon sums its
+            # steps' routed rows and touched experts, and keeps the largest load
+            moe = packed_np[:, 0, 2 + 2 * K :]
+            self._moe_last = (
+                int(moe[:, 0].sum()), int(moe[:, 1].sum()), int(moe[:, 2].max())
+            )
         for i, st in enumerate(chain.seqs):
             if st is None or st.done:
                 continue
@@ -4270,6 +4318,11 @@ class TpuEngine:
         tuples (shared by _run_decode and _run_mixed_step)."""
         toks_np = np.asarray(toks)
         lps_np = np.asarray(lps)
+        if self._moe_counted:
+            # [B + 3]: the step's routing counters behind the logprobs
+            self._moe_last = tuple(
+                int(x) for x in lps_np[self.cfg.max_batch_size:]
+            )
         tlp_ids_np = np.asarray(tlp_ids) if lp_need else None
         tlp_vals_np = np.asarray(tlp_vals) if lp_need else None
         results = []
@@ -4586,6 +4639,9 @@ class TpuEngine:
         spans, waits = self._host_spans, self._admit_waits
         host_spans = tuple(spans.popleft() for _ in range(len(spans)))
         admit_wait_s = tuple(waits.popleft() for _ in range(len(waits)))
+        # set by the step's own readback; a prefill-only step has none
+        routed, touched, load_max = self._moe_last or (None,) * 3
+        self._moe_last = None
         try:
             hook(StepStats(
                 phase=phase,
@@ -4605,6 +4661,9 @@ class TpuEngine:
                 prep_wait_s=(prep["wait_s"] if prep is not None else 0.0),
                 host_spans=host_spans,
                 admit_wait_s=admit_wait_s,
+                moe_tokens_routed=routed,
+                moe_experts_touched=touched,
+                moe_load_max=load_max,
             ))
         except Exception:
             log.exception("stats hook failed")

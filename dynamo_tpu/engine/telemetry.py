@@ -151,6 +151,25 @@ class StepStats:
     # queued -> admitted seconds of each request admitted since then
     host_spans: Tuple[Any, ...] = ()
     admit_wait_s: Tuple[float, ...] = ()
+    # expert routing of the step (one-chip grouped MoE path; None elsewhere
+    # and on prefill-only steps, which have no readback to carry them):
+    # (token, expert) rows routed, T x K summed over layers; experts with at
+    # least one row, summed over layers; the largest row count on one
+    # expert, max over layers. A decode horizon sums its steps (the max
+    # stays a max). Padding rows are not counted. Computed in the step
+    # program from the routing it already does and read with its results.
+    moe_tokens_routed: Optional[int] = None
+    moe_experts_touched: Optional[int] = None
+    moe_load_max: Optional[int] = None
+
+
+def moe_load_imbalance(s: StepStats) -> Optional[float]:
+    """Largest expert load over the mean load of the experts that got rows
+    (1.0 = even), from the three counters alone: a horizon's sums are over
+    the same (expert, layer, step) cells, so their ratio is the mean."""
+    if not s.moe_tokens_routed or not s.moe_experts_touched:
+        return None
+    return s.moe_load_max * s.moe_experts_touched / s.moe_tokens_routed
 
 
 class EngineTelemetry:
@@ -206,6 +225,10 @@ class EngineTelemetry:
             "host seconds spent in each phase of the engine step loop",
             extra_labels=("phase",),
         )
+        self._moe_imbalance = scope.gauge(
+            M.MOE_LOAD_IMBALANCE,
+            "largest expert load over the mean load of touched experts, last step",
+        )
         self.slow_steps = 0
         # small rolling window + last-seen gauges for the /debug/worker
         # snapshot (runtime/health.py): step telemetry without a Prometheus
@@ -258,6 +281,18 @@ class EngineTelemetry:
                 "kv_free_blocks": last.kv_free_blocks,
                 "kv_total_blocks": last.kv_total_blocks,
             }
+        # the last step that carried expert routing counters (one-chip MoE)
+        moe = next(
+            (s for s in reversed(recent) if s.moe_tokens_routed is not None),
+            None,
+        )
+        if moe is not None:
+            out["moe"] = {
+                "phase": moe.phase,
+                "tokens_routed": moe.moe_tokens_routed,
+                "experts_touched": moe.moe_experts_touched,
+                "load_max": moe.moe_load_max,
+            }
         return out
 
     def on_step(self, s: StepStats) -> None:
@@ -276,6 +311,9 @@ class EngineTelemetry:
             self._decode_blocks.set(s.kv_active_blocks)
             if s.spec_acceptance is not None:
                 self._spec.set(s.spec_acceptance)
+            imbalance = moe_load_imbalance(s)
+            if imbalance is not None:
+                self._moe_imbalance.set(imbalance)
             spent = _ns_by_phase((s,))
             for name in _ANNOTATION:  # the label's fixed set
                 if name in spent:

@@ -36,7 +36,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .llama import AttendFn, LlamaConfig, Params
+from .llama import AttendFn, LlamaConfig, Params, window_for_kind
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,7 +63,7 @@ class GemmaConfig(LlamaConfig):
         return "full" if (layer_idx + 1) % self.sliding_pattern == 0 else "sliding"
 
     def window_for_layer(self, layer_idx: int) -> Optional[int]:
-        return self.sliding_window if self.kind_for_layer(layer_idx) == "sliding" else None
+        return window_for_kind(self.kind_for_layer(layer_idx), self.sliding_window)
 
     @classmethod
     def tiny_gemma2(cls, **kw) -> "GemmaConfig":

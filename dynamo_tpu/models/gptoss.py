@@ -32,7 +32,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .llama import LlamaConfig, Params, apply_rope, rms_norm
+from .llama import LlamaConfig, Params, apply_rope, rms_norm, window_for_kind
+from .llama import yarn_inv_freq as llama_yarn_inv_freq
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,7 +58,7 @@ class GptOssConfig(LlamaConfig):
             kind = self.layer_types[layer_idx]
         else:
             kind = "sliding_attention" if layer_idx % 2 == 0 else "full_attention"
-        return self.sliding_window if kind == "sliding_attention" else None
+        return window_for_kind(kind, self.sliding_window)
 
     @classmethod
     def tiny_gptoss(cls, **kw) -> "GptOssConfig":
@@ -99,36 +100,13 @@ class GptOssConfig(LlamaConfig):
 
 
 def yarn_inv_freq(cfg: GptOssConfig) -> Tuple[jax.Array, float]:
-    """(inv_freq [d/2], attention_factor) per the YaRN recipe
-    (transformers _compute_yarn_parameters semantics: interpolated and
-    extrapolated frequencies blended over a linear ramp between the
-    beta_fast/beta_slow correction dims; cos/sin scaled by
-    0.1*ln(factor)+1)."""
-    d, base = cfg.head_dim, cfg.rope_theta
-    pos_freqs = base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    inv_extra = 1.0 / pos_freqs
-    factor = cfg.rope_scaling_factor
-    if factor <= 1.0:
-        return inv_extra, 1.0
-    inv_interp = 1.0 / (factor * pos_freqs)
-
-    def corr_dim(rot):
-        return (d * math.log(cfg.rope_original_max_position / (rot * 2 * math.pi))) / (
-            2 * math.log(base)
-        )
-
-    low, high = corr_dim(cfg.rope_beta_fast), corr_dim(cfg.rope_beta_slow)
-    if cfg.rope_truncate:
-        low, high = math.floor(low), math.ceil(high)
-    low, high = max(low, 0), min(high, d - 1)
-    if low == high:
-        high += 0.001
-    ramp = jnp.clip(
-        (jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0, 1
+    """(inv_freq [d/2], attention_factor) of this config's YaRN scaling
+    (the recipe itself lives in models/llama.py, shared with moe.py)."""
+    return llama_yarn_inv_freq(
+        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling_factor,
+        cfg.rope_original_max_position, cfg.rope_beta_fast,
+        cfg.rope_beta_slow, cfg.rope_truncate,
     )
-    extra_factor = 1.0 - ramp
-    inv_freq = inv_interp * (1 - extra_factor) + inv_extra * extra_factor
-    return inv_freq, 0.1 * math.log(factor) + 1.0
 
 
 def rope_tables(cfg: GptOssConfig, positions: jax.Array):
@@ -222,7 +200,7 @@ def _expert_apply(cfg: GptOssConfig, w_gu, b_gu, w_dn, b_dn, x):
 
 def experts_gather(p: Params, cfg: GptOssConfig, x: jax.Array, routed) -> jax.Array:
     """Sparse exact path (replicated experts): per-slot weight gathers, K
-    static — the same shape as moe.moe_ffn_gather but with gpt-oss's fused
+    static — gpt-oss's own, with its fused
     biased projections and clamped swiglu."""
     topw, topi = routed
     y = jnp.zeros(x.shape, jnp.float32)

@@ -152,6 +152,56 @@ def rope_cos_sin(
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def yarn_inv_freq(
+    head_dim: int,
+    theta: float,
+    factor: float,
+    original_max_position: int,
+    beta_fast: float = 32.0,
+    beta_slow: float = 1.0,
+    truncate: bool = True,
+) -> Tuple[jax.Array, float]:
+    """(inv_freq [d/2], default attention_factor) per the YaRN recipe
+    (transformers _compute_yarn_parameters semantics: interpolated and
+    extrapolated frequencies blended over a linear ramp between the
+    beta_fast/beta_slow correction dims; cos/sin scaled by
+    0.1*ln(factor)+1 unless the config states its own attention_factor).
+    ``factor <= 1`` is plain rope. The one copy: gpt-oss and the windowed
+    MoE family (models/gptoss.py, models/moe.py) both read it."""
+    d = head_dim
+    pos_freqs = theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    inv_extra = 1.0 / pos_freqs
+    if factor <= 1.0:
+        return inv_extra, 1.0
+    inv_interp = 1.0 / (factor * pos_freqs)
+
+    def corr_dim(rot):
+        return (d * math.log(original_max_position / (rot * 2 * math.pi))) / (
+            2 * math.log(theta)
+        )
+
+    low, high = corr_dim(beta_fast), corr_dim(beta_slow)
+    if truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0, 1
+    )
+    extra_factor = 1.0 - ramp
+    inv_freq = inv_interp * (1 - extra_factor) + inv_extra * extra_factor
+    return inv_freq, 0.1 * math.log(factor) + 1.0
+
+
+def window_for_kind(kind: str, sliding_window: Optional[int]) -> Optional[int]:
+    """A layer's sliding-window bound from its ``layer_types`` entry
+    ("sliding_attention"/"sliding" against "full_attention"/"full"): the one
+    rule the windowed families (gpt-oss, gemma, the windowed MoE) share.
+    ``None`` = causal over the whole context."""
+    return sliding_window if kind.startswith("sliding") else None
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """x [..., n_heads, head_dim], cos/sin broadcastable [..., 1, head_dim//2].
 

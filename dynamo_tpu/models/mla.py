@@ -38,7 +38,7 @@ unchanged. Two subtleties:
 FFN is the dense SwiGLU for ``num_experts == 0``, otherwise DeepSeek-MoE
 style: ``first_dense_layers`` leading dense layers, sigmoid-or-softmax
 top-k routing with ``routed_scaling_factor``, optional always-on shared
-experts, reusing models/moe.py's expert kernels (gather / dense / EP-psum).
+experts, reusing models/moe.py's expert paths (grouped / dense / EP-psum).
 """
 
 from __future__ import annotations
@@ -296,14 +296,14 @@ def expert_params(p: Params) -> Params:
 def _moe_ffn(
     p: Params, cfg: MlaConfig, x: jax.Array, expert_fn=None
 ) -> jax.Array:
-    """Routed experts (moe.py gather kernel fed by this module's DeepSeek
+    """Routed experts (moe.py grouped path fed by this module's DeepSeek
     router, or a mesh-aware ``expert_fn`` injected by the registry for EP)
     + the always-on shared-expert SwiGLU."""
     routed = route(p, cfg, x)
     if expert_fn is not None:
         y = expert_fn(expert_params(p), x, routed)
     else:
-        y = moelib.moe_ffn_gather(expert_params(p), cfg, x, routed=routed)
+        y = moelib.moe_ffn_grouped(expert_params(p), cfg, x, routed=routed)
     if cfg.num_shared_experts > 0:
         sg = jax.nn.silu((x @ p["w_shared_gate"]).astype(jnp.float32)).astype(x.dtype)
         y = y + (sg * (x @ p["w_shared_up"])) @ p["w_shared_down"]

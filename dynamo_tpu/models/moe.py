@@ -6,7 +6,11 @@ The reference only passes EP knobs through to engine-internal all-to-all
 120-122; SGLang EPLB docs) — this framework owns the model, so EP is
 implemented directly over the mesh:
 
-* ``moe_ffn``            — dense reference (single device / replicated).
+* ``moe_ffn``            — dense reference (the in-repo oracle).
+* ``moe_ffn_grouped``    — the one-chip serving path for every token count:
+  the T*K assignments sorted by expert, one grouped multiplication for gate
+  and up and one for down (ops/pallas_moe.py), weighted combine. Reads each
+  touched expert once and multiplies only routed rows.
 * ``moe_ffn_ep_psum``    — experts sharded over an axis, tokens REPLICATED
   on it (the engine's decode layout: EP rides the tp axis); each shard
   computes its local experts' contribution, one psum combines. Same
@@ -17,27 +21,33 @@ implemented directly over the mesh:
   scale path for large-batch prefill.
 
 Routing is softmax-then-top-k with optional top-k renormalization
-(Qwen3-MoE convention). Expert-load counts are returned for an
-EPLB-style rebalancing feed (reference: docs/backends/sglang/
-expert-distribution-eplb.md — pattern only).
+(Qwen3-MoE convention). A config may also state per-layer attention kinds
+(``layer_types``: sliding-window layers beside full ones, each kind with its
+own rotary table — plain for sliding, YaRN for full), the window riding the
+paged attention ops' ``window`` argument as in gptoss.py and gemma.py.
+Expert-load counts are returned for an EPLB-style rebalancing feed
+(reference: docs/backends/sglang/expert-distribution-eplb.md — pattern
+only).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas_moe import grouped_matmul_reference
 from . import llama
 from .llama import (
     AttendFn,
     Params,
     apply_rope,
     rms_norm,
-    rope_cos_sin,
+    window_for_kind,
+    yarn_inv_freq,
 )
 
 
@@ -58,10 +68,29 @@ class MoeConfig(llama.LlamaConfig):
     # tokens across its replicas, and TpuEngine.eplb_rebalance() re-plans
     # the replica set from measured counts at runtime. 0 disables.
     redundant_experts: int = 0
+    # per-layer attention kind ("sliding_attention" / "full_attention", the
+    # public config.json's spelling); empty = every layer full, as before
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: Optional[int] = None
+    # rotary parameters per layer kind: sliding layers use plain positions
+    # at rope_theta; FULL layers use YaRN when rope_scaling_factor > 1
+    # (``rope_attention_factor`` on cos and sin; None = YaRN's own
+    # 0.1 ln(factor) + 1)
+    rope_scaling_factor: float = 0.0
+    rope_original_max_position: int = 8192
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_truncate: bool = True
+    rope_attention_factor: Optional[float] = None
 
     @property
     def num_physical_experts(self) -> int:
         return self.num_experts + self.redundant_experts
+
+    def window_for_layer(self, layer_idx: int) -> Optional[int]:
+        if not self.layer_types:
+            return None
+        return window_for_kind(self.layer_types[layer_idx], self.sliding_window)
 
     @classmethod
     def tiny_moe(cls, **kw) -> "MoeConfig":
@@ -143,7 +172,7 @@ def route(
     p: Params, cfg: MoeConfig, x: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """softmax-then-top-k router. x [T, H] -> (weights [T, K] f32, idx [T, K])."""
-    logits = (x @ p["w_router"]).astype(jnp.float32)
+    logits = jnp.dot(x, p["w_router"], preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     topw, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)
     if cfg.norm_topk_prob:
@@ -254,29 +283,72 @@ def moe_ffn(p: Params, cfg: MoeConfig, x: jax.Array) -> jax.Array:
     return jnp.einsum("te,eth->th", weights.astype(x.dtype), out_all)
 
 
-def moe_ffn_gather(
-    p: Params, cfg: MoeConfig, x: jax.Array, routed=None
-) -> jax.Array:
-    """Sparse exact serving path (replicated experts): compute only the K
-    routed experts per token via per-slot weight gathers.
+class RoutingStats:
+    """Trace-time collector of one forward pass's routing: each expert
+    layer adds its per-expert row counts, ``reduce`` folds them into the
+    three numbers a step reports (engine/telemetry.py ``StepStats.moe_*``).
+    ``valid`` [T] masks rows that are padding (a bucket's tail, an empty
+    decode slot): they are multiplied, but no token needed them."""
 
-    FLOPs are T*K*3HI vs the dense reference's T*E*3HI (16x less for a
-    128-expert/top-8 model), and HBM reads touch only the selected experts'
-    weights — the decode-step win for high-E/low-K models. K is static and
-    small, so the loop unrolls under jit into K gather+einsum chains.
+    def __init__(self, valid: Optional[jax.Array] = None):
+        self.valid = valid
+        self.counts: List[jax.Array] = []
+
+    def add(self, topi: jax.Array, num_experts: int) -> None:
+        T, K = topi.shape
+        weights = None
+        if self.valid is not None:
+            weights = jnp.repeat(self.valid.reshape(T).astype(jnp.int32), K)
+        self.counts.append(
+            jnp.bincount(topi.reshape(-1), weights=weights, length=num_experts)
+        )
+
+    def reduce(self) -> jax.Array:
+        """[3] float32: rows routed (T x K summed over layers), experts
+        touched (summed over layers), the largest count on one expert."""
+        c = jnp.stack(self.counts)                       # [L, E]
+        return jnp.stack([c.sum(), (c > 0).sum(), c.max()]).astype(jnp.float32)
+
+
+def moe_ffn_grouped(
+    p: Params, cfg: MoeConfig, x: jax.Array, routed=None,
+    stats: Optional[RoutingStats] = None,
+    matmul=grouped_matmul_reference,
+) -> jax.Array:
+    """Sparse exact serving path (replicated experts), one path for every
+    token count: route, sort the T*K assignments by expert, ONE grouped
+    multiplication for gate and up and one for down over the sorted rows,
+    weighted combine back per token.
+
+    FLOPs are T*K*3HI vs the dense reference's T*E*3HI, and HBM reads touch
+    each routed-to expert's weights once — at a decode batch about
+    E(1-(1-K/E)^T) experts, not T*K private copies.
 
     ``routed`` overrides the router output (topw, topi) — the MLA family
-    passes its DeepSeek-style routing through the same kernel."""
-    topw, topi = routed if routed is not None else route(p, cfg, x)
-    y = jnp.zeros_like(x)
-    for k in range(topi.shape[1]):
-        idx = topi[:, k]                                 # [T]
-        gate = jnp.einsum("th,thi->ti", x, p["w_gate"][idx])
-        up = jnp.einsum("th,thi->ti", x, p["w_up"][idx])
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-        contrib = jnp.einsum("ti,tih->th", act, p["w_down"][idx])
-        y = y + topw[:, k, None].astype(x.dtype) * contrib
-    return y
+    passes its DeepSeek-style routing through the same path. ``matmul`` is
+    the grouped multiplication: the Pallas kernel on the kernel path
+    (registry.forward_fn), its ``jax.lax.ragged_dot`` twin otherwise."""
+    T, H = x.shape
+    with jax.named_scope("moe_route"):
+        topw, topi = routed if routed is not None else route(p, cfg, x)
+    K = topi.shape[1]
+    slots = p["w_gate"].shape[0]  # E, or E+R physical slots (replicas idle)
+    if stats is not None:
+        stats.add(topi, cfg.num_experts)
+    with jax.named_scope("moe_sort"):
+        flat = topi.reshape(-1)                          # [T*K]
+        order = jnp.argsort(flat)                        # stable: by expert
+        sizes = jnp.bincount(flat, length=slots).astype(jnp.int32)
+        rows = x[order // K]                             # [T*K, H]
+    with jax.named_scope("moe_experts"):
+        act = matmul(rows, (p["w_gate"], p["w_up"]), sizes)   # [T*K, I]
+        out = matmul(act, (p["w_down"],), sizes)              # [T*K, H]
+    with jax.named_scope("moe_combine"):
+        back = out[jnp.argsort(order)].reshape(T, K, H)  # un-sort
+        y = jnp.einsum(
+            "tk,tkh->th", topw.astype(jnp.float32), back.astype(jnp.float32)
+        )
+    return y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -382,8 +454,10 @@ def layer_forward(
     attend: AttendFn,
     layer_idx: int,
     ffn_fn=None,
+    stats: Optional[RoutingStats] = None,
 ) -> jax.Array:
-    """Same attention block as llama.layer_forward (cited there); the MLP is
+    """Same attention block as llama.layer_forward (cited there), with the
+    layer's sliding window (if it has one) handed to ``attend``; the MLP is
     the sparse MoE. ``ffn_fn(p, cfg, x2d)`` overrides the FFN strategy."""
     h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
     q = h @ p["wq"]
@@ -398,7 +472,10 @@ def layer_forward(
         k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    attn_out = attend(q, k, v, layer_idx)
+    # a full layer passes nothing, so it keeps the plain families' kernels
+    window = cfg.window_for_layer(layer_idx)
+    extra = {} if window is None else {"window": window}
+    attn_out = attend(q, k, v, layer_idx, **extra)
     attn_out = attn_out.reshape(*new_shape, cfg.q_size)
     x = x + attn_out @ p["wo"]
 
@@ -406,8 +483,24 @@ def layer_forward(
     lead = h.shape[:-1]
     h2d = h.reshape(-1, cfg.hidden_size)
     fn = ffn_fn if ffn_fn is not None else moe_ffn
-    y = fn(p, cfg, h2d).reshape(*lead, cfg.hidden_size)
+    kw = {} if stats is None else {"stats": stats}
+    y = fn(p, cfg, h2d, **kw).reshape(*lead, cfg.hidden_size)
     return x + y
+
+
+def rope_tables(cfg: MoeConfig, positions: jax.Array, yarn: bool):
+    """cos/sin [..., 1, d/2] of one layer kind: YaRN (full layers of a
+    config that states a scaling factor) or plain rotary positions."""
+    inv_freq, att = yarn_inv_freq(
+        cfg.head_dim, cfg.rope_theta,
+        cfg.rope_scaling_factor if yarn else 0.0,
+        cfg.rope_original_max_position, cfg.rope_beta_fast,
+        cfg.rope_beta_slow, cfg.rope_truncate,
+    )
+    if yarn and cfg.rope_attention_factor is not None:
+        att = cfg.rope_attention_factor
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    return (jnp.cos(angles) * att)[..., None, :], (jnp.sin(angles) * att)[..., None, :]
 
 
 def forward(
@@ -417,12 +510,21 @@ def forward(
     positions: jax.Array,
     attend: AttendFn,
     ffn_fn=None,
+    stats: Optional[RoutingStats] = None,
 ) -> jax.Array:
+    """``stats`` (one-chip grouped path only) collects each layer's routing
+    counts for the step's counters."""
     x = params["embed"][token_ids]
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-    cos, sin = cos[..., None, :], sin[..., None, :]
+    # one table per layer KIND, built once per forward (two, not num_layers)
+    tables = {}
     for i, layer in enumerate(params["layers"]):
-        x = layer_forward(layer, cfg, x, cos, sin, attend, i, ffn_fn=ffn_fn)
+        yarn = cfg.rope_scaling_factor > 1.0 and cfg.window_for_layer(i) is None
+        if yarn not in tables:
+            tables[yarn] = rope_tables(cfg, positions, yarn)
+        cos, sin = tables[yarn]
+        x = layer_forward(
+            layer, cfg, x, cos, sin, attend, i, ffn_fn=ffn_fn, stats=stats
+        )
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 

@@ -10,6 +10,7 @@ from functools import partial
 
 from jax.sharding import PartitionSpec as P
 
+from ..ops.pallas_moe import grouped_matmul, grouped_matmul_reference
 from ..parallel.mesh import AXIS_TP, shard_map
 from . import gemma, gptoss, llama, mla, moe
 
@@ -83,12 +84,16 @@ def _ep_psum_shard_map(mesh, weight_specs, kernel, n_extra_args):
     )
 
 
-def forward_fn(cfg, mesh=None):
+def forward_fn(cfg, mesh=None, use_pallas: bool = False,
+               interpret: bool = False):
     """Forward pass for the family. For MoE the FFN strategy is picked here
     so serving never pays dense all-expert FLOPs (ADVICE r2):
 
-    - experts replicated (no mesh / tp==1): exact per-token gather
-      (moe_ffn_gather, T*K expert applications instead of T*E)
+    - experts replicated (no mesh / tp==1): the token-sorted grouped path
+      (moe_ffn_grouped, T*K routed rows, each touched expert read once) for
+      every token count; ``use_pallas`` picks its multiplication — the
+      Pallas kernel ``moe_grouped_matmul`` (``interpret``: off-TPU tests)
+      or the ``jax.lax.ragged_dot`` twin
     - experts sharded over tp (EP rides the TP axis): shard_map'd
       moe_ffn_ep_psum — each shard computes only its local experts, one
       psum combines (same collective as a TP row matmul)
@@ -119,7 +124,7 @@ def forward_fn(cfg, mesh=None):
         return partial(gptoss.forward, expert_fn=gptoss_expert_fn)
     if is_mla(cfg):
         if cfg.num_experts == 0 or mesh is None or mesh.shape.get(AXIS_TP, 1) == 1:
-            # per-token gather kernel (exact, sparse) on replicated experts
+            # token-sorted grouped path (exact, sparse) on replicated experts
             return mla.forward
 
         # EP: expert stacks shard on the expert dim over the tp axis (same
@@ -153,17 +158,13 @@ def forward_fn(cfg, mesh=None):
         return gemma.forward
     if not is_moe(cfg):
         return llama.forward
-    # the gather path materializes [T, H, I] per-token weight copies: a win
-    # at decode widths, an OOM at prefill widths — pick per program off the
-    # static token count (each prefill bucket compiles its own program)
-    GATHER_MAX_TOKENS = 32
     if mesh is None or mesh.shape.get(AXIS_TP, 1) == 1:
-        def ffn_local(p, _cfg, x):
-            if x.shape[0] <= GATHER_MAX_TOKENS:
-                return moe.moe_ffn_gather(p, _cfg, x)
-            return moe.moe_ffn(p, _cfg, x)
-
-        return partial(moe.forward, ffn_fn=ffn_local)
+        matmul = grouped_matmul_reference
+        if use_pallas:
+            matmul = partial(grouped_matmul, interpret=interpret)
+        return partial(
+            moe.forward, ffn_fn=partial(moe.moe_ffn_grouped, matmul=matmul)
+        )
 
     # one source of truth for the expert layout: the same specs the engine
     # places the params with (below)

@@ -79,6 +79,9 @@ NEG_INF = -1e30
 # packed query tokens per grid program (one VMEM-resident q/o block)
 Q_BLOCK = 128
 
+KERNEL_NAME = "ragged_paged_attention"
+KERNEL_NAME_WINDOWED = "ragged_paged_attention_windowed"
+
 
 def default_q_seg(g: int) -> int:
     """Query tokens per inner sub-tile: small enough that a decode row
@@ -524,7 +527,9 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kvh, Tq_pad * g, d), q.dtype),
         interpret=interpret,
-        name="ragged_paged_attention",
+        # the windowed launch under a name of its own: the device trace
+        # tells a sliding layer's calls from a full layer's
+        name=KERNEL_NAME_WINDOWED if has_window else KERNEL_NAME,
     )(*prefetch, *inputs, *cache_args)
     # [kvh, Tq_pad*g, d] -> [Tq, h, d]
     return out.reshape(kvh, Tq_pad, g, d).transpose(1, 0, 2, 3).reshape(

@@ -48,9 +48,9 @@ class TestEpEquivalence:
         self.ref = moe.moe_ffn(self.p, self.cfg, self.x)
 
     def test_gather_matches_dense(self):
-        """The sparse serving path (per-token expert gathers, T*K FLOPs)
-        is exact: identical to the dense all-expert reference."""
-        got = moe.moe_ffn_gather(self.p, self.cfg, self.x)
+        """The sparse serving path (token-sorted grouped multiplication,
+        T*K FLOPs) is exact: identical to the dense all-expert reference."""
+        got = moe.moe_ffn_grouped(self.p, self.cfg, self.x)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(self.ref), rtol=2e-5, atol=2e-5
         )

@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from dynamo_tpu.ops import block_copy as bc
 from dynamo_tpu.ops import pallas_attention as pa
+from dynamo_tpu.ops import pallas_moe as pmoe
 from dynamo_tpu.ops import pallas_prefill as pf
 from dynamo_tpu.ops import pallas_unified as pun
 from dynamo_tpu.ops.quant import QuantizedKV
@@ -108,6 +109,30 @@ def _cases():
             q, k, v, t, a, b, c, windows=w, sinks=snk, softcap=30.0
         )
 
+    def windowed_moe_cell(sh):
+        # the benchmark's mellum2 cell (BENCHMARK.json): 32 q / 4 kv heads,
+        # 7 168 pages of 16 tokens, contexts to 7 168 (448 pages a row), a
+        # 512-token chunk row plus 16 q_len=1 rows, window 1 024
+        s, *_ = _shapes(sh)
+        cache = s((7168, BS, 4, D), BF)
+        rows = s((17,), I32)
+        return (s((512 + 16, 32, D), BF), cache, cache, s((17, 448), I32),
+                rows, rows, rows, rows)
+
+    def grouped(rows_sorted, k, n, n_rhs):
+        # the same cell's expert layer: 64 experts, hidden 2 304, width 896;
+        # 128 sorted rows is a decode step (16 rows x top 8), 4 224 a
+        # 512-token chunk beside 16 decode rows
+        def build(sh):
+            s, *_ = _shapes(sh)
+            return (s((rows_sorted, k), BF), s((64,), I32)) + (
+                s((64, k, n), BF),
+            ) * n_rhs
+
+        def fn(lhs, sizes, *rhs):
+            return pmoe.grouped_matmul(lhs, rhs, sizes)
+        return fn, build
+
     def moves(fn, n_ids, with_pages):
         def build(sh):
             s, cache, _, _, ids = _shapes(sh)
@@ -129,6 +154,11 @@ def _cases():
         "unified-plain-chunk2048": (pun.ragged_paged_attention, unified_wide),
         "unified-windowed": unified(windowed, ("rows",)),
         "unified-window-sinks-softcap": unified(sinks, ("rows", "sinks")),
+        "unified-windowed-moe-cell": (windowed, windowed_moe_cell),
+        "grouped-matmul-gate-up-rows128": grouped(128, 2304, 896, 2),
+        "grouped-matmul-down-rows128": grouped(128, 896, 2304, 1),
+        "grouped-matmul-gate-up-rows4224": grouped(4224, 2304, 896, 2),
+        "grouped-matmul-down-rows4224": grouped(4224, 896, 2304, 1),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),
