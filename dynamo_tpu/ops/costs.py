@@ -134,7 +134,9 @@ def unified_attention_bytes(
     grid is over blocks of ``q_block`` packed query tokens, and for every
     block a row has tokens in, the row's LIVE pages stream once as whole
     pages — up to the causal limit of the row's last token in that block —
-    plus int8 scale rows, plus the packed q read and o write. No gather,
+    plus int8 scale rows, plus the packed q read and o write, each in the
+    kernel's two layouts (token-major for rows of few tokens, kv-head-major
+    for the rest; a token is written in one and zero in the other). No gather,
     and no read past a block's causal limit. A decode row (one token, one
     block) streams its pages exactly once; a prefill chunk spanning several
     blocks re-streams its growing prefix once per block. Rows are taken as
@@ -167,7 +169,7 @@ def unified_attention_bytes(
                 # one [kvh] f32 scale row rides each page DMA
                 kv += 2 * p * kv_heads * SCALE_BYTES
         start += q_len
-    qo = 2 * total_q * num_heads * head_dim * q_itemsize
+    qo = 2 * 2 * total_q * num_heads * head_dim * q_itemsize
     return kv + qo
 
 

@@ -18,8 +18,10 @@ stay in flight from one row to the next. Scalar-prefetched block tables +
 sequence lengths (SMEM) drive the page DMAs.
 
 Chunking: a row's pages are walked in chunks of ``chunk_pages`` pages, as many
-as ``_VMEM_CHUNK_BYTES`` holds in two slots of K and V (512 tokens at 8 kv
-heads x 128 in bf16) and no more than a row can have. The caches are handed
+as ``pallas_paged.VMEM_CHUNK_BYTES`` holds in two slots of K and V (512 tokens
+at 8 kv heads x 128 in bf16) and no more than a row can have (the rule, the
+page copies and the own-head bias live in ops/pallas_paged.py, shared with the
+ragged kernel). The caches are handed
 over viewed as ``[num_blocks, block_size * kv_heads, head_dim]`` (the same
 bytes), so a chunk lands in VMEM as one dense ``[tokens * kv_heads, head_dim]``
 matrix: chunk row ``r`` is token ``r // kv_heads`` of kv head ``r % kv_heads``.
@@ -54,14 +56,9 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.mesh import shard_map
+from . import pallas_paged as paged
+from .pallas_paged import NEG_INF
 from .quant import QuantizedKV, is_quantized
-
-NEG_INF = -1e30
-
-# VMEM for the page buffers: two slots each of K and V. 4 MiB reads 512 tokens
-# a chunk at 8 kv heads x 128 in bf16; on a v5e 256 to 1024 tokens a chunk all
-# run within 1% of each other (PERF.md section 6, PR 25).
-_VMEM_CHUNK_BYTES = 4 * 1024 * 1024
 
 
 def _decode_kernel(
@@ -102,49 +99,21 @@ def _decode_kernel(
     N = CP * R
     T = CP * bs
 
-    def chunk_copies(slot, idx, j):
-        """Descriptors of page ``idx`` into place ``j`` of ``slot``. Scale
-        rows ride the same prefetched table index — [kvh] f32 per page, ~1000x
-        smaller than the payload they describe. NOTE (hardware): that slice's
-        minor dim is kvh, not 128-aligned; Mosaic refuses the copy
-        (tests/test_tpu_compile.py pins it) and the engine refuses int8 with
-        the Pallas kernels on the TPU backend, so this runs interpreted only."""
-        copies = [
-            pltpu.make_async_copy(
-                k_hbm.at[idx], k_buf.at[slot, j], sem.at[0, slot]),
-            pltpu.make_async_copy(
-                v_hbm.at[idx], v_buf.at[slot, j], sem.at[1, slot]),
-        ]
-        if quantized:
-            copies += [
-                pltpu.make_async_copy(
-                    ks_hbm.at[idx], ks_buf.at[slot, j], ssem.at[0, slot]),
-                pltpu.make_async_copy(
-                    vs_hbm.at[idx], vs_buf.at[slot, j], ssem.at[1, slot]),
-            ]
-        return copies
+    pages = paged.PageReader(
+        tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
+        (ks_hbm, vs_hbm, ks_buf, vs_buf, ssem) if quantized else None,
+    )
 
     def pages_in_chunk(row, c):
         return jnp.minimum(CP, pl.cdiv(lens_ref[row], bs) - c * CP)
 
     def start_chunk(row, c, slot):
-        base = row * max_blocks + c * CP
-
-        def issue(j, carry):
-            for copy in chunk_copies(slot, tables_ref[base + j], j):
-                copy.start()
-            return carry
-
-        jax.lax.fori_loop(0, pages_in_chunk(row, c), issue, 0)
+        pages.start(
+            row * max_blocks + c * CP, pages_in_chunk(row, c), slot
+        )
 
     def wait_chunk(row, c, slot):
-        def one(j, carry):
-            # the descriptor only says how many bytes one page signals
-            for copy in chunk_copies(slot, 0, 0):
-                copy.wait()
-            return carry
-
-        jax.lax.fori_loop(0, pages_in_chunk(row, c), one, 0)
+        pages.wait(pages_in_chunk(row, c), slot)
 
     def start_next_row(row, slot):
         """Start chunk 0 of the first non-empty row after ``row``, if any."""
@@ -159,11 +128,7 @@ def _decode_kernel(
         def _():
             start_chunk(nxt, 0, slot)
 
-    col = jax.lax.broadcasted_iota(jnp.int32, (h, N), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (h, N), 0)
-    bias_ref[...] = jnp.where(
-        jax.lax.rem(col, kvh) == head // g, 0.0, NEG_INF
-    ).astype(jnp.float32)
+    bias_ref[...] = paged.own_head_bias(h, g, kvh, N)
     scale = 1.0 / (d ** 0.5)
     start_next_row(-1, 0)
 
@@ -251,12 +216,6 @@ def _decode_kernel(
     jax.lax.fori_loop(0, B, row_body, 0)
 
 
-def _chunk_pages(bs: int, kvh: int, d: int, dtype, max_blocks: int) -> int:
-    """Pages a chunk: what ``_VMEM_CHUNK_BYTES`` holds, at most a row's."""
-    page_bytes = bs * kvh * d * jnp.dtype(dtype).itemsize
-    return max(1, min(_VMEM_CHUNK_BYTES // (4 * page_bytes), max_blocks))
-
-
 @functools.partial(
     jax.jit, static_argnames=("chunk_tokens", "interpret")
 )
@@ -283,7 +242,7 @@ def paged_decode_attention(
     quantized = is_quantized(k_cache)
     pages = k_cache.data if quantized else k_cache
     if chunk_tokens is None:
-        chunk_pages = _chunk_pages(bs, kvh, d, pages.dtype, max_blocks)
+        chunk_pages = paged.chunk_pages(bs, kvh, d, pages.dtype, max_blocks)
     else:
         chunk_pages = max(1, chunk_tokens // bs)
 

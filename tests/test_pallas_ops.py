@@ -13,6 +13,7 @@ import pytest
 from dynamo_tpu.ops import attention as att
 from dynamo_tpu.ops import block_copy as bc
 from dynamo_tpu.ops import pallas_attention as pa
+from dynamo_tpu.ops import pallas_paged as paged
 
 
 def _make_paged_case(rng, B, h, kvh, d, bs, num_blocks, max_blocks, dtype):
@@ -173,12 +174,12 @@ def test_derived_chunk_fits_the_vmem_budget():
     """The chunk is what the budget holds in two slots of K and V, and never
     more pages than a row has."""
     page = 16 * 8 * 128 * 2
-    assert pa._chunk_pages(16, 8, 128, jnp.bfloat16, 544) == 32
-    assert 4 * 32 * page == pa._VMEM_CHUNK_BYTES
-    assert pa._chunk_pages(16, 8, 128, jnp.float32, 544) == 16
-    assert pa._chunk_pages(16, 2, 128, jnp.bfloat16, 192) == 128
-    assert pa._chunk_pages(16, 8, 128, jnp.bfloat16, 6) == 6
-    assert pa._chunk_pages(16, 8, 128, jnp.int8, 544) == 64
+    assert paged.chunk_pages(16, 8, 128, jnp.bfloat16, 544) == 32
+    assert 4 * 32 * page == paged.VMEM_CHUNK_BYTES
+    assert paged.chunk_pages(16, 8, 128, jnp.float32, 544) == 16
+    assert paged.chunk_pages(16, 2, 128, jnp.bfloat16, 192) == 128
+    assert paged.chunk_pages(16, 8, 128, jnp.bfloat16, 6) == 6
+    assert paged.chunk_pages(16, 8, 128, jnp.int8, 544) == 64
 
 
 def test_gather_blocks():

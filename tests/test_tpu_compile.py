@@ -119,6 +119,20 @@ def _cases():
         return (s((512 + 16, 32, D), BF), cache, cache, s((17, 448), I32),
                 rows, rows, rows, rows)
 
+    def unified_cell(h, kvh, q_tokens, rows, max_blocks, num_blocks,
+                     window=False):
+        # a cell's real launch (BENCHMARK.json): the packed tokens of one
+        # bucketed chunk + one token a resident row, tables of the cell's
+        # context, the cell's page pool
+        def build(sh):
+            s, *_ = _shapes(sh)
+            cache = s((num_blocks, BS, kvh, D), BF)
+            r = s((rows,), I32)
+            return (s((q_tokens, h, D), BF), cache, cache,
+                    s((rows, max_blocks), I32), r, r, r) + (
+                        (r,) if window else ())
+        return (windowed if window else pun.ragged_paged_attention), build
+
     def grouped(rows_sorted, k, n, n_rhs):
         # the same cell's expert layer: 64 experts, hidden 2 304, width 896;
         # 128 sorted rows is a decode step (16 rows x top 8), 4 224 a
@@ -155,6 +169,17 @@ def _cases():
         "unified-windowed": unified(windowed, ("rows",)),
         "unified-window-sinks-softcap": unified(sinks, ("rows", "sinks")),
         "unified-windowed-moe-cell": (windowed, windowed_moe_cell),
+        # the cells' mixed launches through the rebuilt loop (PR 27):
+        # InternLM2 512 + 12 rows over 8 704-token tables, Mistral 512 + 8
+        # over 4 608, Mellum's sliding layers in a decode step (16 q_len=1
+        # rows) and its full layers in a mixed step, one tp=4 shard (kvh 2)
+        "unified-longcache-cell": unified_cell(16, 8, 524, 13, 544, 6400),
+        "unified-rag-cell": unified_cell(32, 8, 520, 9, 288, 2048),
+        "unified-windowed-moe-cell-decode": unified_cell(
+            32, 4, 16, 16, 448, 7168, window=True),
+        "unified-moe-cell-full-layers": unified_cell(
+            32, 4, 528, 17, 448, 7168),
+        "unified-kvh2-tp4-shard": unified_cell(8, 2, 520, 9, 192, 8192),
         "grouped-matmul-gate-up-rows128": grouped(128, 2304, 896, 2),
         "grouped-matmul-down-rows128": grouped(128, 896, 2304, 1),
         "grouped-matmul-gate-up-rows4224": grouped(4224, 2304, 896, 2),
@@ -197,6 +222,31 @@ def test_sharded_decode_compiles_on_tp4_mesh(v5e):
         s((B, MB), I32, P()), s((B,), I32, P()),
     )
     fn = functools.partial(pa.sharded_paged_decode_attention, mesh, AXIS_TP)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce(" not in text and "all-gather(" not in text
+
+
+def test_sharded_unified_compiles_on_tp4_mesh(v5e):
+    """The shard_map'd ragged kernel on a tp=4 mesh at Mistral's widths (8
+    q / 2 kv heads a shard, 8 KiB pages): a mixed launch of 512 + 8 rows
+    compiles to one custom call a device and no collective."""
+    from dynamo_tpu.parallel.mesh import AXIS_TP, make_mesh
+
+    mesh = make_mesh(tp=4, devices=v5e)
+
+    def s(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    cache = s((8192, BS, 8, D), BF, P(None, None, AXIS_TP, None))
+    rows = s((9,), I32)
+    args = (
+        s((520, 32, D), BF, P(None, AXIS_TP, None)), cache, cache,
+        s((9, 192), I32), rows, rows, rows,
+    )
+    fn = functools.partial(pun.sharded_ragged_paged_attention, mesh, AXIS_TP)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce(" not in text and "all-gather(" not in text

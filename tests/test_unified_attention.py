@@ -86,7 +86,7 @@ def test_unified_matches_reference(name, quant):
     )
     ref = att.ragged_paged_attention(*args)
     got = pu.ragged_paged_attention(
-        *args, q_seg=4, chunk_tokens=32, interpret=True
+        *args, chunk_tokens=32, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), atol=ATOL, rtol=ATOL
@@ -148,7 +148,7 @@ def test_unified_row_attributes_match_reference(name, quant):
         kw["softcap"] = case["softcap"]
     ref = att.ragged_paged_attention(*args, **kw)
     got = pu.ragged_paged_attention(
-        *args, **kw, q_seg=4, chunk_tokens=16, interpret=True
+        *args, **kw, chunk_tokens=16, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), atol=1e-5, rtol=ATOL
@@ -177,11 +177,114 @@ def test_unified_rows_spanning_query_blocks(attrs):
         )
     ref = att.ragged_paged_attention(*args, **kw)
     got = pu.ragged_paged_attention(
-        *args, **kw, q_seg=4, q_block=8, chunk_tokens=16, interpret=True
+        *args, **kw, q_block=8, chunk_tokens=16, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), atol=1e-5, rtol=ATOL
     )
+
+
+# ------------------------------------------ the cells' head geometries (PR 27)
+# The benchmark's three configurations at their real head counts, head_dim
+# and page size, so that the DERIVED chunk and tile sizes are the ones the
+# chip runs: bf16 caches (the per-head side cuts heads out of 32-bit words of
+# the dense chunk), a chunk row that fills its blocks beside decode rows
+# (one row on each side of the regime rule), contexts over several chunks
+# with a ragged last page, an empty row between live rows (the read-ahead
+# across rows must skip it), a window that starts mid-chunk and mid-page.
+GEOMETRY_CASES = {
+    # InternLM2 (g 2 / kvh 8): a 512-token chunk + decode rows
+    "g2-kvh8-chunk512": dict(
+        h=16, kvh=8, rows=[(512, 1300), (1, 1100), (0, 0), (1, 1037),
+                           (1, 513), (2, 700)],
+    ),
+    # Mistral (g 4 / kvh 8): a 128-token bucket holding 100 tokens, a
+    # spec-verify row (q_len 4), a two-token row, rows of one page
+    "g4-kvh8-chunk128": dict(
+        h=32, kvh=8, gap_after=28,
+        rows=[(100, 1124), (1, 1500), (4, 900), (0, 0), (2, 600), (1, 17)],
+    ),
+    # Mellum (g 8 / kvh 4), sliding layers: window 1 024 over contexts to
+    # 2 600, chunks of 1 024 tokens; one context shorter than the window
+    "g8-kvh4-windowed-chunk512": dict(
+        h=32, kvh=4, window=1024,
+        rows=[(512, 2600), (1, 2500), (0, 0), (1, 1030), (1, 900),
+              (3, 2100)],
+    ),
+    "g8-kvh4-windowed-decode": dict(
+        h=32, kvh=4, window=1024,
+        rows=[(1, 2300), (1, 1100), (0, 0), (0, 0), (1, 1025), (1, 1024),
+              (1, 16), (1, 2047)],
+    ),
+    # float32 pages (the generic head cut) at tight tolerance
+    "g4-kvh8-float32": dict(
+        h=32, kvh=8, dtype=jnp.float32, tol=2e-5,
+        rows=[(128, 700), (1, 530), (0, 0), (2, 300), (1, 257)],
+    ),
+    "g8-kvh4-windowed-float32": dict(
+        h=32, kvh=4, window=300, dtype=jnp.float32, tol=2e-5,
+        rows=[(128, 1400), (1, 1300), (0, 0), (3, 700), (1, 200), (1, 301)],
+    ),
+    # int8 pages (interpreted only) and the gated families' attributes
+    "g2-kvh8-int8": dict(
+        h=16, kvh=8, dtype=jnp.float32, quant=True, tol=2e-5,
+        rows=[(128, 1200), (1, 1100), (0, 0), (4, 530), (1, 513)],
+    ),
+    "g8-kvh4-window-sinks-softcap": dict(
+        h=32, kvh=4, window=300, sinks=True, softcap=30.0,
+        rows=[(128, 1400), (1, 1300), (0, 0), (4, 700), (1, 200)],
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GEOMETRY_CASES))
+def test_unified_cell_geometries(name):
+    case = GEOMETRY_CASES[name]
+    rng = np.random.default_rng(sorted(GEOMETRY_CASES).index(name))
+    dtype = case.get("dtype", jnp.bfloat16)
+    rows = case["rows"]
+    pages = sum(-(-sl // 16) for _, sl in rows)
+    args = _make_case(
+        rng, rows, h=case["h"], kvh=case["kvh"], d=128, bs=16,
+        num_blocks=pages + 2, max_blocks=max(-(-sl // 16) for _, sl in rows),
+        dtype=dtype, quant=case.get("quant", False),
+        gap_after=case.get("gap_after", 0),
+    )
+    kw = {}
+    if "window" in case:
+        kw["windows"] = jnp.full((len(rows),), case["window"], jnp.int32)
+    if case.get("sinks"):
+        kw["sinks"] = jnp.asarray(
+            rng.standard_normal(case["h"]), jnp.float32)
+    if case.get("softcap"):
+        kw["softcap"] = case["softcap"]
+    ref = att.ragged_paged_attention(*args, **kw)
+    got = pu.ragged_paged_attention(*args, **kw, interpret=True)
+    tol = case.get("tol", 3e-2)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref, np.float32),
+        atol=tol, rtol=tol,
+    )
+
+
+def test_unified_derived_sizes():
+    """Chunk and tile sizes follow the shapes: no call site sets them."""
+    assert pu._tile_tokens(2, 128) == 128      # InternLM2: a block, 256 rows
+    assert pu._tile_tokens(4, 128) == 128      # Mistral: 512 rows a tile
+    assert pu._tile_tokens(8, 128) == 64       # Mellum: 512 rows a tile
+    assert pu._tile_tokens(1, 128) == 128
+    assert pu._tile_tokens(8, 8) == 8          # the tests' small blocks
+    # tokens of a row in a block that still take the masked product
+    assert pu._few_tokens(16, jnp.bfloat16, 128) == 8    # InternLM2
+    assert pu._few_tokens(32, jnp.bfloat16, 128) == 4    # Mistral, Mellum
+    assert pu._few_tokens(8, jnp.bfloat16, 128) == 1     # a tp=4 shard
+    assert pu._few_tokens(8, jnp.float32, 128) == 8
+    import inspect
+
+    params = inspect.signature(pu.ragged_paged_attention).parameters
+    assert "q_seg" not in params
+    assert params["q_block"].default is None
+    assert params["chunk_tokens"].default is None
 
 
 def test_unified_scalar_window_equals_per_row():
@@ -218,7 +321,7 @@ def test_unified_sharded_wrapper_with_attributes():
     with mesh:
         got = pu.sharded_ragged_paged_attention(
             mesh, AXIS_TP, *args, windows=windows, sinks=sinks,
-            softcap=40.0, q_seg=4, chunk_tokens=16, interpret=True,
+            softcap=40.0, chunk_tokens=16, interpret=True,
         )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), atol=1e-5, rtol=ATOL
@@ -274,7 +377,7 @@ def test_unified_bf16_and_head_layouts():
         )
         ref = att.ragged_paged_attention(*args)
         got = pu.ragged_paged_attention(
-            *args, q_seg=4, chunk_tokens=16, interpret=True
+            *args, chunk_tokens=16, interpret=True
         )
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(ref, np.float32),
@@ -322,7 +425,7 @@ def test_unified_int8_grow_scale_rmw():
     args = (q, k_cache, v_cache, tables, q_starts, q_lens, seq_lens)
     ref = att.ragged_paged_attention(*args)
     got = pu.ragged_paged_attention(
-        *args, q_seg=4, chunk_tokens=16, interpret=True
+        *args, chunk_tokens=16, interpret=True
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), atol=ATOL, rtol=ATOL
@@ -342,7 +445,7 @@ def test_unified_sharded_wrapper_tp():
     mesh = make_mesh(tp=2, devices=jax.devices()[:2])
     with mesh:
         got = pu.sharded_ragged_paged_attention(
-            mesh, AXIS_TP, *args, q_seg=4, chunk_tokens=16, interpret=True
+            mesh, AXIS_TP, *args, chunk_tokens=16, interpret=True
         )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), atol=ATOL, rtol=ATOL
