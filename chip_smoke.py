@@ -563,10 +563,10 @@ def _kernel_child() -> None:
 
     from dynamo_tpu.ops import attention as att
     from dynamo_tpu.ops import block_copy as bc
-    from dynamo_tpu.ops import pallas_attention as pa
     from dynamo_tpu.ops import pallas_moe as pmoe
-    from dynamo_tpu.ops import pallas_prefill as pf
     from dynamo_tpu.ops import pallas_unified as pun
+    from dynamo_tpu.ops.paged_attention import PagedAttention
+    from dynamo_tpu.parallel.mesh import single_device_mesh
     from dynamo_tpu.runtime.device import (
         device_info,
         enable_compile_cache,
@@ -598,6 +598,9 @@ def _kernel_child() -> None:
                 return fn(*a, **kw)
         return jax.jit(run, static_argnames=("softcap",))
 
+    # the attention seam on its two sides: compiled kernels, pure-JAX twins
+    mesh = single_device_mesh()
+    kernels, twins = PagedAttention(mesh, True), PagedAttention(mesh, False)
     worst = 0.0
 
     def compare(name, got, ref):
@@ -620,10 +623,9 @@ def _kernel_child() -> None:
     lens = jnp.asarray([1, 16, 17, 333, 1024, 1500, 2047, 2048], jnp.int32)
     q = rnd(B, H, D)
     compare(
-        "paged_decode_attention",
-        pa.paged_decode_attention(q, k_cache, v_cache, tables[:B], lens),
-        highest(att.paged_decode_attention)(
-            q, k_cache, v_cache, tables[:B], lens),
+        "decode question",
+        kernels.decode(q, k_cache, v_cache, tables[:B], lens),
+        highest(twins.decode)(q, k_cache, v_cache, tables[:B], lens),
     )
 
     # ...and the long-cache cell's contexts (BENCHMARK.json): 544 pages a
@@ -638,25 +640,27 @@ def _kernel_child() -> None:
     ql = rnd(len(long_lens), H, D)
     long_args = (ql, k_cache, v_cache, jnp.asarray(long_tables),
                  jnp.asarray(long_lens))
-    got = np.asarray(pa.paged_decode_attention(*long_args), np.float32)
-    ref = np.asarray(highest(att.paged_decode_attention)(*long_args),
-                     np.float32)
+    got = np.asarray(kernels.decode(*long_args), np.float32)
+    ref = np.asarray(highest(twins.decode)(*long_args), np.float32)
     live = long_lens > 0
     if got[~live].any():
-        raise SystemExit("paged_decode_attention: an empty row is not zeros")
-    compare("paged_decode_attention 8k ragged, one empty row",
+        raise SystemExit("decode question: an empty row is not zeros")
+    compare("decode question 8k ragged, one empty row",
             got[live], ref[live])
 
-    # flash extend: a 512-token chunk continuing a 1024-token prefix
-    S, T, start = 512, 2048, 1024
-    k_ctx, v_ctx = att.gather_kv(k_cache, v_cache, tables[0])
+    # the seam's chunk question, as a lone prefill asks it: a 512-token chunk
+    # continuing a 1024-token prefix, one ragged row against the dense
+    # extend over the gathered context
+    S, start = 512, 1024
     qs = rnd(S, H, D)
     pos = jnp.arange(start, start + S, dtype=jnp.int32)
-    total = jnp.asarray(start + S, jnp.int32)
+    chunk_args = (qs, k_cache, v_cache, tables[0],
+                  jnp.asarray(start, jnp.int32),
+                  jnp.asarray(start + S, jnp.int32), pos)
     compare(
-        "flash_extend_attention",
-        pf.flash_extend_attention(qs, k_ctx, v_ctx, pos, total),
-        highest(att.extend_attention)(qs, k_ctx, v_ctx, pos, total),
+        "chunk question (one ragged row)",
+        kernels.chunk(*chunk_args),
+        highest(twins.chunk)(*chunk_args),
     )
 
     # unified ragged: the mixed step's shape — one 512-token chunk at a

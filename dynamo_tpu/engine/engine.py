@@ -49,6 +49,7 @@ from ..models import llama, registry
 from ..models import moe as moe_lib
 from ..models.vision import IMAGE_TOKEN_ID
 from ..ops import attention as att
+from ..ops.paged_attention import PagedAttention
 from ..parallel import mesh as meshlib
 from ..runtime.config import ENV_KV_BLOCK_SIZE, env_int
 from ..runtime.device import device_info, hbm_bytes_per_s, on_tpu
@@ -108,9 +109,9 @@ class TpuEngineConfig:
     pp: int = 1
     prefill_buckets: Tuple[int, ...] = (64, 128, 256, 512, 1024, 2048)
     seed: int = 0
-    # Pallas ragged decode kernel (ops/pallas_attention): None = auto-enable
-    # on the TPU backend (28x over the pure-JAX gather path on v5e), force
-    # with True/False (tests run it via the interpreter on CPU)
+    # the Pallas attention kernels behind ops/paged_attention.py: None =
+    # auto-enable on the TPU backend (_resolve_use_pallas has the rule), force
+    # with True/False (tests run them via the interpreter on CPU)
     use_pallas: Optional[bool] = None
     # decode horizon: run this many decode iterations inside one XLA program
     # (lax.scan, sampled tokens fed back device-side) so per-dispatch launch
@@ -472,12 +473,9 @@ class TpuEngine:
                     "eos_id) — see guided.vocab_bytes_from_tokenizer"
                 )
         if registry.is_gptoss(self.mcfg) or registry.is_gemma(self.mcfg):
-            # the unified ragged kernel carries per-row window/sink/softcap
-            # attributes (ops/pallas_unified), so use_pallas is no longer
-            # rejected for these families: windowed/sink layers route
-            # through the unified launch, full-attention layers keep the
-            # split decode kernel. Only the ring (sp) path still lacks the
-            # window masks.
+            # the ragged kernel carries per-row window/sink/softcap
+            # attributes, so use_pallas serves these families too. Only the
+            # ring (sp) path still lacks the window masks.
             if config.sp > 1:
                 raise ValueError(
                     "sliding-window attention (gpt-oss/gemma) does not ride"
@@ -1259,41 +1257,11 @@ class TpuEngine:
                 return fwd(params, mcfg, tokens, positions, attend)
             return fwd(params, mcfg, tokens, positions, attend, **kw)
 
-        use_pallas = self.use_pallas
-        if use_pallas:
-            from ..ops import pallas_attention as pa
-            from ..ops import pallas_unified as pun
-
-            mesh = self.mesh
-            interp = self.kernels_interpreted
-
-            def paged_attention(q, kc, vc, tables, lens, **extra):
-                if extra:
-                    # windowed/sink/softcap layers (gpt-oss/gemma): the
-                    # split decode kernel carries no per-row attributes —
-                    # serve the decode batch as q_len=1 rows of the
-                    # unified ragged kernel instead
-                    B = q.shape[0]
-                    win = extra.get("window")
-                    return pun.sharded_ragged_paged_attention(
-                        mesh, meshlib.AXIS_TP, q, kc, vc, tables,
-                        jnp.arange(B, dtype=jnp.int32),
-                        (lens > 0).astype(jnp.int32),
-                        lens.astype(jnp.int32),
-                        windows=(
-                            jnp.full((B,), win, jnp.int32)
-                            if win is not None else None
-                        ),
-                        sinks=extra.get("sinks"),
-                        softcap=extra.get("softcap"),
-                        interpret=interp,
-                    )
-                return pa.sharded_paged_decode_attention(
-                    mesh, meshlib.AXIS_TP, q, kc, vc, tables, lens,
-                    interpret=interp,
-                )
-        else:
-            paged_attention = att.paged_decode_attention
+        # the one attention seam (ops/paged_attention.py): the programs below
+        # state what rows they have, the seam picks the kernel or the twin
+        attn = PagedAttention(
+            self.mesh, self.use_pallas, self.kernels_interpreted
+        )
 
         procs = cfg.logits_processors
 
@@ -1414,59 +1382,9 @@ class TpuEngine:
                         self.mesh, q, k_new, v_new, k_ctx, v_ctx,
                         positions, chunk_start, chunk_start,
                     )
-                if use_pallas and extra:
-                    # windowed/sink/softcap chunk (gpt-oss/gemma): the
-                    # flash-extend kernel has no per-row attributes —
-                    # serve the chunk as ONE ragged row of the unified
-                    # kernel (segment at the context tail; window
-                    # page-skip included) instead of the dense reference
-                    # extend over the gathered context
-                    win = extra.get("window")
-                    return pun.sharded_ragged_paged_attention(
-                        mesh, meshlib.AXIS_TP, q, kc, vc,
-                        block_table[None],
-                        jnp.zeros((1,), jnp.int32),
-                        (total_len - chunk_start).astype(jnp.int32)[None],
-                        total_len.astype(jnp.int32)[None],
-                        windows=(
-                            jnp.full((1,), win, jnp.int32)
-                            if win is not None else None
-                        ),
-                        sinks=extra.get("sinks"),
-                        softcap=extra.get("softcap"),
-                        interpret=interp,
-                    )
-                from ..ops import pallas_prefill as pf
-
-                flash_ok = (
-                    use_pallas
-                    and not extra
-                    and q.shape[0] % pf.Q_TILE == 0
-                    and block_table.shape[0] * cfg.block_size % pf.KV_TILE == 0
-                )
-                if flash_ok and quantized:
-                    # raw-int8 gather: the flash kernel streams int8 context
-                    # tiles + per-position scale columns and dequantizes
-                    # in-register (half the context bytes vs bf16)
-                    kq, vq, ks, vs = att.gather_kv_quant(kc, vc, block_table)
-                    return pf.sharded_flash_extend_attention(
-                        self.mesh, meshlib.AXIS_TP,
-                        q, kq, vq, positions, total_len,
-                        k_scales=ks, v_scales=vs, interpret=interp,
-                    )
-                k_ctx, v_ctx = att.gather_kv(kc, vc, block_table)
-                if flash_ok:
-                    # flash extend kernel (ops/pallas_prefill): O(tile) VMEM
-                    # vs the dense [S, h, T] score tensor; TP rides a
-                    # shard_map over heads (GSPMD cannot partition a custom
-                    # call). Shapes that miss the tile grid fall back.
-                    return pf.sharded_flash_extend_attention(
-                        self.mesh, meshlib.AXIS_TP,
-                        q, k_ctx, v_ctx, positions, total_len,
-                        interpret=interp,
-                    )
-                return att.extend_attention(
-                    q, k_ctx, v_ctx, positions, total_len, **extra
+                return attn.chunk(
+                    q, kc, vc, block_table, chunk_start, total_len,
+                    positions, **extra
                 )
 
             hidden = call_fwd(
@@ -1539,7 +1457,7 @@ class TpuEngine:
                     k_new[:, 0], v_new[:, 0], write_blocks, write_offsets,
                 )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
-                out = paged_attention(
+                out = attn.decode(
                     q[:, 0], kc, vc, block_tables, seq_lens, **extra
                 )
                 return out[:, None]
@@ -1604,7 +1522,7 @@ class TpuEngine:
                         k_new[:, 0], v_new[:, 0], write_blocks, write_offsets,
                     )
                     k_caches[layer_idx], v_caches[layer_idx] = kc, vc
-                    out = paged_attention(
+                    out = attn.decode(
                         q[:, 0], kc, vc, block_tables, seq_lens, **extra
                     )
                     return out[:, None]
@@ -1650,24 +1568,6 @@ class TpuEngine:
                 tokens, seq_lens, next_steps,
             )
             return out + (g_out,) if g_active is not None else out
-
-        if use_pallas:
-            def ragged_attention(q, kc, vc, tables, q_starts, q_lens, lens,
-                                 window=None, sinks=None, softcap=None):
-                # scalar per-layer window -> per-row windows array (every
-                # row of one launch shares the layer's bound)
-                R = tables.shape[0]
-                return pun.sharded_ragged_paged_attention(
-                    self.mesh, meshlib.AXIS_TP, q, kc, vc, tables,
-                    q_starts, q_lens, lens,
-                    windows=(
-                        jnp.full((R,), window, jnp.int32)
-                        if window is not None else None
-                    ),
-                    sinks=sinks, softcap=softcap, interpret=interp,
-                )
-        else:
-            ragged_attention = att.ragged_paged_attention
 
         def mixed_step(params, k_caches, v_caches, counts,
                        c_tokens, c_positions, c_block_table, c_new_block_ids,
@@ -1732,7 +1632,7 @@ class TpuEngine:
                     c_total_len[None].astype(jnp.int32),
                     d_seq_lens.astype(jnp.int32),
                 ])
-                return ragged_attention(
+                return attn.ragged(
                     q, kc, vc, tables, q_starts, q_lens, row_lens, **extra
                 )
 
@@ -1870,9 +1770,9 @@ class TpuEngine:
                     k_w, v_w, new_block_ids,
                 )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
-                k_ctx, v_ctx = att.gather_kv(kc, vc, block_table)
-                return att.extend_attention(
-                    q, k_ctx, v_ctx, positions, total_len, **extra
+                return attn.chunk(
+                    q, kc, vc, block_table, positions[0], total_len,
+                    positions, **extra
                 )
 
             hidden = fwd(params, mcfg, tokens, positions, attend)
@@ -1902,26 +1802,14 @@ class TpuEngine:
             sk = self.cfg.spec_k
             R = self._spec_rounds
             B = self.cfg.max_batch_size
-            # the draft loop uses the split decode kernel, which has no
-            # per-row attributes: a draft rides it only when the auto rule
-            # would pick Pallas for the draft's OWN config (head_dim, kv
-            # heads vs tp, not a windowed/softcapped family — gpt-oss AND
-            # gemma drafts keep the pure-JAX decode path even under a
+            # the draft asks a seam of its own, built from the auto rule on
+            # ITS config (head_dim, kv heads vs tp, not a family off the rule:
+            # gpt-oss and gemma drafts keep the pure-JAX twins even under a
             # Pallas main engine, forced or not)
-            draft_use_pallas = use_pallas and self._pallas_auto_ok(dcfg)
-            if draft_use_pallas:
-                from ..ops import pallas_attention as dpa
-
-                d_mesh = self.mesh
-                d_interp = self.kernels_interpreted
-
-                def draft_paged_attention(q, kc, vc, tables, lens):
-                    return dpa.sharded_paged_decode_attention(
-                        d_mesh, meshlib.AXIS_TP, q, kc, vc, tables, lens,
-                        interpret=d_interp,
-                    )
-            else:
-                draft_paged_attention = att.paged_decode_attention
+            draft_attn = PagedAttention(
+                self.mesh, self.use_pallas and self._pallas_auto_ok(dcfg),
+                self.kernels_interpreted,
+            )
 
             def draft_prefill_chunk(draft_params, dkc, dvc, tokens, positions,
                                     block_table, new_block_ids, total_len):
@@ -1935,9 +1823,10 @@ class TpuEngine:
                         new_block_ids,
                     )
                     dkc[layer_idx], dvc[layer_idx] = kc, vc
-                    k_ctx, v_ctx = att.gather_kv(kc, vc, block_table)
-                    return att.extend_attention(
-                        q, k_ctx, v_ctx, positions, total_len, **extra
+                    # a chunk's first token is real: its position is the start
+                    return draft_attn.chunk(
+                        q, kc, vc, block_table, positions[0], total_len,
+                        positions, **extra
                     )
 
                 draft_fwd(draft_params, dcfg, tokens, positions, attend)
@@ -1978,7 +1867,7 @@ class TpuEngine:
                                 k_new[:, 0], v_new[:, 0], wb, wo,
                             )
                             dkc[layer_idx], dvc[layer_idx] = kc2, vc2
-                            out = draft_paged_attention(
+                            out = draft_attn.decode(
                                 q[:, 0], kc2, vc2, block_tables,
                                 seq_lens + j, **extra
                             )
@@ -2017,32 +1906,12 @@ class TpuEngine:
                                 kc2, vc2, k_new[:, s], v_new[:, s], wb, wo
                             )
                         k_caches[layer_idx], v_caches[layer_idx] = kc2, vc2
-                        if not use_pallas:
-                            # pure-JAX engines keep the batched extend op:
-                            # the unified TWIN scores the whole packed
-                            # buffer per row (O(B^2) verify FLOPs) — same
-                            # fallback split the prefill/decode paths use
-                            return att.paged_extend_attention(
-                                q, kc2, vc2, block_tables, start,
-                                seq_lens + sk, **extra
-                            )
-                        # verify rides the UNIFIED ragged kernel: each row
-                        # is a segment of query_len = sk+1 candidate tokens
-                        # at its context tail — the same launch the mixed
-                        # step uses, not a separate prefix-extend entry
-                        # point (window/sink/softcap extras included)
-                        h, d_ = q.shape[2], q.shape[3]
-                        out = ragged_attention(
-                            q.reshape(B * (sk + 1), h, d_), kc2, vc2,
-                            block_tables,
-                            jnp.arange(B, dtype=jnp.int32) * (sk + 1),
-                            jnp.where(active, sk + 1, 0).astype(jnp.int32),
-                            jnp.where(active, seq_lens + sk, 0).astype(
-                                jnp.int32
-                            ),
-                            **extra,
+                        # verify rows: sk+1 candidate tokens at each
+                        # row's context tail (window/sink/softcap included)
+                        return attn.verify(
+                            q, kc2, vc2, block_tables,
+                            jnp.where(active, seq_lens + sk, 0), **extra
                         )
-                        return out.reshape(B, sk + 1, h, d_)
 
                     hidden = call_fwd(
                         params, cand, pos, attend, lora_tables, lora_ids

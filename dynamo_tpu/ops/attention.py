@@ -1,8 +1,8 @@
 """Attention ops for paged-KV serving: prefill, prefix-extend, paged decode.
 
-Pure-JAX reference implementations (XLA fuses these well on TPU already);
-the Pallas ragged-paged-attention kernel in ops/pallas_attention.py is a
-drop-in replacement on the same interfaces for the decode hot path.
+Pure-JAX reference implementations: the twins the Pallas kernels are held to,
+and what ops/paged_attention.py (the seam the step programs ask) answers with
+wherever the kernels are off.
 
 Replaces what the reference delegates to engine-internal kernels (vLLM
 paged attention / FlashInfer); the CUDA block-copy kernel analog lives in
@@ -162,29 +162,6 @@ def gather_kv(
         k.reshape(mb * bs, *k.shape[2:]),
         v.reshape(mb * bs, *v.shape[2:]),
     )
-
-
-def gather_kv_quant(
-    k_cache: QuantizedKV,
-    v_cache: QuantizedKV,
-    block_table: jax.Array,  # [max_blocks] int32
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Raw-int8 gather for kernels that dequantize in-register
-    (ops/pallas_prefill): (k int8 [T, kvh, d], v int8, k_scales f32 [T, kvh],
-    v_scales f32 [T, kvh]) with the per-block scales broadcast to positions."""
-    bs = k_cache.shape[1]
-    mb = block_table.shape[0]
-
-    def pick(c):
-        q = c.data[block_table].reshape(mb * bs, *c.shape[2:])
-        s = jnp.broadcast_to(
-            c.scale[block_table][:, None, :], (mb, bs, c.shape[2])
-        ).reshape(mb * bs, c.shape[2])
-        return q, s
-
-    kq, ks = pick(k_cache)
-    vq, vs = pick(v_cache)
-    return kq, vq, ks, vs
 
 
 def paged_decode_attention(
@@ -413,11 +390,10 @@ def paged_extend_attention(
     """Batched paged prefix-extend: every row attends its S_new new tokens
     causally over its OWN pages (which must already contain the new tokens'
     KV). The verify pass of speculative decoding
-    (docs/speculative_decoding.md) is this shape; Pallas engines fold it
-    into the unified ragged kernel as ``query_len = k+1`` rows, while
-    pure-JAX engines keep this op as their fallback split dispatch (the
-    unified TWIN would score the whole packed buffer per row — O(B^2)
-    verify FLOPs). KERNEL-SPLIT flags any new engine call site.
+    (docs/speculative_decoding.md) is this shape: the seam's verify rows
+    (ops/paged_attention.py) are ``query_len = k+1`` rows of the ragged
+    kernel on its Pallas side and this op on its pure-JAX side (the ragged
+    TWIN would score the whole packed buffer per row — O(B^2) verify FLOPs).
 
     vmap of gather_kv + extend_attention: pure JAX, any head layout the
     single-sequence ops accept (GQA, MQA/MLA-latent), window/sinks

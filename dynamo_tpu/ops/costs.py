@@ -188,11 +188,11 @@ def split_prefill_bytes(
     q_tile: int = 128,
     bucket: int = None,
 ) -> int:
-    """HBM bytes the SPLIT prefill path moves for one chunk: gather_kv
-    materializes the FULL padded table (read + write, both K and V), then
-    the flash-extend kernel streams the gathered context once per q tile
-    (its grid re-reads every kv tile for each of the chunk's q tiles),
-    plus the q read / o write at the bucketed width."""
+    """HBM bytes a gather-then-flash prefill path moves for one chunk (a
+    layout the serving path no longer has; bench.py's byte gate compares
+    against it): gather_kv materializes the FULL padded table (read +
+    write, both K and V), then a flash kernel streams the gathered context
+    once per q tile, plus the q read / o write at the bucketed width."""
     del total_len  # the split gather width is the PADDED table, not the
     # real context — that is exactly the waste being priced
     S_pad = bucket if bucket is not None else chunk_len

@@ -1,10 +1,12 @@
 """Pallas TPU kernel: unified ragged paged attention (prefill + decode fused).
 
 ONE launch serves an arbitrary mix of prefill chunks and decode tokens — the
-"Ragged Paged Attention" formulation (PAPERS.md) that lets the engine step
-loop run true continuous batches instead of alternating a prefill-only
-kernel (ops/pallas_prefill.py flash extend) with a decode-only kernel
-(ops/pallas_attention.py ragged decode). Rows carry ``(query_len, seq_len)``
+"Ragged Paged Attention" formulation (PAPERS.md): a lone prefill chunk (one
+row), a chunk fused with the resident decode batch (the mixed step),
+spec-verify rows, and the decode rows of windowed / sink / softcap layers.
+ops/paged_attention.py launches it for every question a step program asks
+except unwindowed decode rows, which the decode-only kernel
+(ops/pallas_attention.py) serves. Rows carry ``(query_len, seq_len)``
 pairs: query tokens pack densely into one ragged buffer, each row's segment
 sits at the TAIL of its own paged context, and causal masking is per row.
 
@@ -26,13 +28,10 @@ additions that let the gated model families ride the same launch:
 A speculative-decode verify pass is just a row with ``query_len = k + 1``
 (candidate tokens at the context tail) — no special case in the kernel.
 
-Versus the split prefill path there is no gather: that path materializes
-the FULL padded context (``gather_kv`` over ``max_blocks_per_seq`` pages, an
-HBM->HBM copy) before the flash kernel even starts; here KV pages stream
-straight from the paged cache, and only the real pages below each query
-block's causal limit are ever touched. ``ops/costs.py`` turns both layouts
-into byte counts; the tier-1 gate pins mixed <= split (including the
-windowed and spec-verify row shapes).
+There is no gather: KV pages stream straight from the paged cache, and only
+the real pages below each query block's causal limit (and above a row's
+window) are ever touched. ``ops/costs.unified_attention_bytes`` turns a
+launch into a byte count.
 
 Layout: paged cache ``[num_blocks, block_size, kv_heads, head_dim]``, shared
 with the decode kernel and the transfer plane, handed over viewed as
@@ -102,10 +101,8 @@ from the earliest token's own position on, on the other a tile's chunks that
 reach past its earliest token's position; a windowed launch masks every chunk
 (a window's chunks are its two ends, seldom more). Never-read rows of V in a
 row's last chunk are zeroed (0 x NaN = NaN). A prefill chunk spanning several
-blocks re-streams its causal prefix once per block (``q_block`` = 128 matches
-the flash-extend q tile, without that path's gather or its reads past the
-causal limit); the caller adds the two o blocks, each token written in exactly
-one of them.
+blocks re-streams its causal prefix once per block of ``q_block`` = 128 tokens;
+the caller adds the two o blocks, each token written in exactly one of them.
 """
 
 from __future__ import annotations
@@ -827,7 +824,7 @@ def sharded_ragged_paged_attention(
     shard runs the kernel on its own heads (q sharded on h, caches on kvh,
     sink logits on their head dim; per-row windows replicate). shard_map
     because GSPMD cannot partition a custom call — the same treatment as
-    the split kernels' sharded wrappers."""
+    the decode kernel's sharded wrapper."""
     if mesh.shape[tp_axis] == 1:
         return ragged_paged_attention(
             q, k_cache, v_cache, block_tables, q_starts, q_lens, seq_lens,

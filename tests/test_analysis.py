@@ -12,6 +12,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from tools.analysis import core
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -634,6 +636,31 @@ def test_metric_cardinality_current_tree_clean(repo_analysis):
     _modules, _parse, findings = repo_analysis
     found = [f for f in findings if f.rule == "METRIC-CARDINALITY"]
     assert found == []
+
+
+# -- KERNEL-SPLIT ------------------------------------------------------------
+
+@pytest.mark.parametrize("path,source,hits", [
+    ("dynamo_tpu/engine/engine.py",
+     "def build():\n    from ..ops import pallas_unified as pun\n    return pun\n", 1),
+    ("dynamo_tpu/parallel/pp_serving.py",
+     "from dynamo_tpu.ops.pallas_attention import paged_decode_attention\n"
+     "import dynamo_tpu.ops.pallas_paged\n", 2),
+    # the seam and the pure-JAX twins are what a program asks
+    ("dynamo_tpu/engine/engine.py",
+     "from ..ops import attention as att\n"
+     "from ..ops.paged_attention import PagedAttention\n"
+     "x = att.paged_decode_attention\n", 0),
+    # ops/ itself, tests and chip_smoke.py hold the kernels to their twins
+    ("dynamo_tpu/ops/paged_attention.py",
+     "from .pallas_unified import ragged_paged_attention\n", 0),
+    ("chip_smoke.py", "from dynamo_tpu.ops import pallas_unified\n", 0),
+], ids=["engine-import", "pp-import-forms", "seam-and-twins", "inside-ops",
+        "chip-smoke"])
+def test_kernel_split_is_the_seam(tmp_path, path, source, hits):
+    found = analyze(tmp_path, path, source, rule="KERNEL-SPLIT")
+    assert len(found) == hits
+    assert all("ops/paged_attention.PagedAttention" in f.message for f in found)
 
 
 # -- MIXED-GATE --------------------------------------------------------------

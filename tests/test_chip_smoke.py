@@ -28,22 +28,24 @@ _RUN = (
 )
 
 
-def _repo_processes() -> set:
-    """PIDs of running ``python -m dynamo_tpu...`` processes."""
+def _marked_processes(mark: str) -> set:
+    """PIDs of running processes that carry ``mark`` in their environment:
+    the script's own descendants, whatever other tests run meanwhile."""
     pids = set()
     for pid in filter(str.isdigit, os.listdir("/proc")):
         try:
-            with open(f"/proc/{pid}/cmdline", "rb") as f:
-                if b"dynamo_tpu." in f.read():
+            with open(f"/proc/{pid}/environ", "rb") as f:
+                if mark.encode() in f.read():
                     pids.add(int(pid))
         except OSError:
-            pass  # exited while we looked
+            pass  # exited while we looked, or not ours to read
     return pids
 
 
 def test_chip_smoke_fails_without_a_tpu():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    before = _repo_processes()
+    run_id = f"{os.getpid()}-{time.monotonic_ns()}"
+    mark = f"CHIP_SMOKE_TEST_RUN={run_id}"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", CHIP_SMOKE_TEST_RUN=run_id)
     t0 = time.monotonic()
     proc = subprocess.run(
         [sys.executable, "-c", _RUN], cwd=REPO, env=env,
@@ -58,7 +60,7 @@ def test_chip_smoke_fails_without_a_tpu():
     assert "PARENT_IMPORTED_JAX=False" in proc.stdout
     assert took < 120, f"took {took:.0f}s to notice there is no chip"
     # it stops every process it started, also when the stack never came up
-    assert _repo_processes() <= before
+    assert not _marked_processes(mark)
 
 
 @pytest.mark.parametrize("env_value", ["/some/dir", "", None],

@@ -183,21 +183,22 @@ class TestQuantAttentionParity:
             np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5
         )
 
-    def test_flash_extend_quant_matches_reference(self):
-        from dynamo_tpu.ops.pallas_prefill import flash_extend_attention
+    def test_chunk_question_quant_matches_reference(self):
+        """A lone chunk over an int8 cache: one ragged row of the unified
+        kernel (interpreted; pages and scale rows dequantized in-register)
+        against the dense extend over the dequantizing gather."""
+        from dynamo_tpu.ops.paged_attention import PagedAttention
+        from dynamo_tpu.parallel.mesh import single_device_mesh
 
         rng = np.random.default_rng(13)
         _, _, kQ, vQ = _quant_cache(rng, nb=32, bs=16, kvh=4, d=32)
         table = jnp.asarray(np.arange(1, 17), jnp.int32)  # T = 256
-        kq, vq, ks, vs = att.gather_kv_quant(kQ, vQ, table)
         q = jnp.asarray(rng.standard_normal((128, 8, 32)), jnp.float32)
         qpos = jnp.arange(100, 228, dtype=jnp.int32)
-        kd, vd = att.gather_kv(kQ, vQ, table)
-        ref = att.extend_attention(q, kd, vd, qpos, jnp.int32(228))
-        got = flash_extend_attention(
-            q, kq, vq, qpos, jnp.int32(228), k_scales=ks, v_scales=vs,
-            q_tile=64, kv_tile=64, interpret=True,
-        )
+        args = (q, kQ, vQ, table, jnp.int32(100), jnp.int32(228), qpos)
+        mesh = single_device_mesh()
+        ref = PagedAttention(mesh, False).chunk(*args)
+        got = PagedAttention(mesh, True, True).chunk(*args, chunk_tokens=64)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5
         )

@@ -226,67 +226,68 @@ def test_sharded_wrapper_single_tp():
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-class TestFlashExtendAttention:
-    """ops/pallas_prefill.py: flash chunked-prefill attention (interpreter
-    on CPU; the engine auto-enables it on TPU at tp=1 for tile-aligned
-    buckets)."""
+def _paged_context(rng, T, kvh, d, bs, spare=3):
+    """A context of ``T`` tokens scattered over pages in a shuffled order:
+    (k_cache, v_cache, table). Page 0 is scratch."""
+    mb = -(-T // bs)
+    nb = mb + spare
+    k = rng.standard_normal((mb * bs, kvh, d)).astype(np.float32)
+    v = rng.standard_normal((mb * bs, kvh, d)).astype(np.float32)
+    table = rng.permutation(np.arange(1, nb))[:mb].astype(np.int32)
+    kc = np.zeros((nb, bs, kvh, d), np.float32)
+    vc = np.zeros((nb, bs, kvh, d), np.float32)
+    kc[table] = k.reshape(mb, bs, kvh, d)
+    vc[table] = v.reshape(mb, bs, kvh, d)
+    return jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(table)
 
-    def _data(self, S=128, T=256, h=8, kvh=4, d=32, seed=0):
+
+class TestChunkQuestion:
+    """The seam's chunk question (ops/paged_attention.py) on the Pallas side,
+    interpreted: a lone prefill chunk is ONE ragged row of the unified kernel
+    for every bucket, held to ``extend_attention`` over the gathered context
+    (the pure-JAX side's answer)."""
+
+    def _check(self, mesh, S_pad, start, total, h=8, kvh=4, d=32, T=256,
+               seed=0, **kw):
+        from dynamo_tpu.ops.paged_attention import PagedAttention
+
         rng = np.random.default_rng(seed)
-        q = jnp.asarray(rng.standard_normal((S, h, d)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((T, kvh, d)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((T, kvh, d)), jnp.float32)
-        return q, k, v
+        kc, vc, table = _paged_context(rng, T, kvh, d, bs=16)
+        q = jnp.asarray(rng.standard_normal((S_pad, h, d)), jnp.float32)
+        real = total - start
+        # the engine's padding: pad rows sit at the last position
+        pos = np.full(S_pad, T - 1, np.int32)
+        pos[:real] = np.arange(start, total)
+        args = (q, kc, vc, table, jnp.int32(start), jnp.int32(total),
+                jnp.asarray(pos))
+        ref = PagedAttention(mesh, False).chunk(*args)
+        got = PagedAttention(mesh, True, True).chunk(*args, **kw)
+        np.testing.assert_allclose(
+            np.asarray(got)[:real], np.asarray(ref)[:real], atol=2e-5)
+        assert not np.asarray(got)[real:].any()
 
     def test_matches_dense_first_chunk(self):
-        from dynamo_tpu.ops.attention import extend_attention
-        from dynamo_tpu.ops.pallas_prefill import flash_extend_attention
+        from dynamo_tpu.parallel.mesh import single_device_mesh
 
-        q, k, v = self._data()
-        qpos = jnp.arange(128, dtype=jnp.int32)
-        ref = extend_attention(q, k, v, qpos, jnp.int32(128))
-        got = flash_extend_attention(
-            q, k, v, qpos, jnp.int32(128), q_tile=64, kv_tile=64, interpret=True
-        )
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+        self._check(single_device_mesh(), 128, 0, 128, chunk_tokens=64)
 
     def test_matches_dense_chunked_continuation(self):
-        """Chunk starting mid-context against a cached prefix, with padded
-        (invalid) tail keys masked by total_len."""
-        from dynamo_tpu.ops.attention import extend_attention
-        from dynamo_tpu.ops.pallas_prefill import flash_extend_attention
+        """Chunk starting mid-context against a cached prefix, with a padded
+        tail of the bucket and pages past total_len never read."""
+        from dynamo_tpu.parallel.mesh import single_device_mesh
 
-        q, k, v = self._data()
-        qpos = jnp.arange(100, 228, dtype=jnp.int32)
-        ref = extend_attention(q, k, v, qpos, jnp.int32(228))
-        got = flash_extend_attention(
-            q, k, v, qpos, jnp.int32(228), q_tile=64, kv_tile=64, interpret=True
-        )
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+        self._check(single_device_mesh(), 128, 100, 220, chunk_tokens=64)
 
-    def test_rejects_unaligned_tiles(self):
-        from dynamo_tpu.ops.pallas_prefill import flash_extend_attention
+    def test_serves_an_unaligned_bucket(self):
+        """A bucket on no tile grid (S=100) is SERVED by the ragged row and
+        matches the reference."""
+        from dynamo_tpu.parallel.mesh import single_device_mesh
 
-        q, k, v = self._data(S=100)
-        with pytest.raises(ValueError, match="multiples"):
-            flash_extend_attention(
-                q, k, v, jnp.arange(100, dtype=jnp.int32), jnp.int32(100),
-                q_tile=64, kv_tile=64, interpret=True,
-            )
+        self._check(single_device_mesh(), 100, 0, 100)
 
     def test_tp_sharded_matches_dense(self):
-        """shard_map'd flash extend over a tp=2 mesh == dense single-device
-        (heads split across shards; the engine uses this under TP)."""
-        from dynamo_tpu.ops.attention import extend_attention
-        from dynamo_tpu.ops.pallas_prefill import sharded_flash_extend_attention
-        from dynamo_tpu.parallel.mesh import AXIS_TP, make_mesh
+        """The chunk question over a tp=2 mesh == the dense single-device
+        answer (heads split across shards under shard_map)."""
+        from dynamo_tpu.parallel.mesh import make_mesh
 
-        q, k, v = self._data(h=8, kvh=4)
-        qpos = jnp.arange(100, 228, dtype=jnp.int32)
-        ref = extend_attention(q, k, v, qpos, jnp.int32(228))
-        mesh = make_mesh(tp=2)
-        got = sharded_flash_extend_attention(
-            mesh, AXIS_TP, q, k, v, qpos, jnp.int32(228),
-            q_tile=64, kv_tile=64, interpret=True,
-        )
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+        self._check(make_mesh(tp=2), 128, 100, 228, chunk_tokens=64)

@@ -21,10 +21,9 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from dynamo_tpu.ops import block_copy as bc
-from dynamo_tpu.ops import pallas_attention as pa
 from dynamo_tpu.ops import pallas_moe as pmoe
-from dynamo_tpu.ops import pallas_prefill as pf
 from dynamo_tpu.ops import pallas_unified as pun
+from dynamo_tpu.ops.paged_attention import PagedAttention
 from dynamo_tpu.ops.quant import QuantizedKV
 
 # qwen3-0.6b as `python -m dynamo_tpu.engine --preset qwen3-0.6b` serves it:
@@ -55,6 +54,14 @@ def v5e():
     cc.reset_cache()
 
 
+@pytest.fixture(scope="module")
+def chip_seam(v5e):
+    """The attention seam on its Pallas side over one described chip."""
+    from dynamo_tpu.parallel.mesh import make_mesh
+
+    return PagedAttention(make_mesh(tp=1, devices=v5e[:1]), True)
+
+
 def _shapes(sharding, kvh=KVH, h=H):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -73,20 +80,31 @@ def _cases():
     """name -> (fn, shape-args builder). Builders take the ShapeDtypeStruct
     factory so one table serves any sharding."""
 
+    def seam(question):
+        """A question of the attention seam on its Pallas side: the test
+        hands ``fn`` the seam over the described chip."""
+        def fn(attn, *args):
+            return getattr(attn, question)(*args)
+        fn.asks_seam = True
+        return fn
+
     def decode(kvh, h, rows=B, max_blocks=MB, num_blocks=NB):
         def build(sh):
             s, *_ = _shapes(sh, kvh, h)
             cache = s((num_blocks, BS, kvh, D), BF)
             return (s((rows, h, D), BF), cache, cache,
                     s((rows, max_blocks), I32), s((rows,), I32))
-        return pa.paged_decode_attention, build
+        return seam("decode"), build
 
-    def flash(S):
+    def chunk(S):
+        # a lone prefill chunk as ONE ragged row over the long-cache cell's
+        # table width (544 pages) and page pool
         def build(sh):
             s, *_ = _shapes(sh)
-            ctx = s((MB * BS, KVH, D), BF)
-            return (s((S, H, D), BF), ctx, ctx, s((S,), I32), s((), I32))
-        return pf.flash_extend_attention, build
+            cache = s((6400, BS, KVH, D), BF)
+            return (s((S, H, D), BF), cache, cache, s((544,), I32),
+                    s((), I32), s((), I32), s((S,), I32))
+        return seam("chunk"), build
 
     def unified(fn, extra=()):
         def build(sh):
@@ -161,9 +179,9 @@ def _cases():
         # batch 12 over 8 704-token contexts, batch 32 over 3 072
         "decode-bf16-longcache-cell": decode(KVH, H, 12, 544, 6400),
         "decode-bf16-chat-cell": decode(KVH, H, 32, 192, 6400),
-        "flash-extend-S128": flash(128),
-        "flash-extend-S512": flash(512),
-        "flash-extend-S2048": flash(2048),
+        "chunk-row-S128": chunk(128),
+        "chunk-row-S512": chunk(512),
+        "chunk-row-S2048": chunk(2048),
         "unified-plain": unified(pun.ragged_paged_attention),
         "unified-plain-chunk2048": (pun.ragged_paged_attention, unified_wide),
         "unified-windowed": unified(windowed, ("rows",)),
@@ -194,19 +212,21 @@ CASES = _cases()
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_kernel_compiles_for_v5e(v5e, name):
+def test_kernel_compiles_for_v5e(v5e, chip_seam, name):
     """The kernel lowers through Mosaic for one v5e chip as a real custom
     call (not interpreted)."""
     fn, build = CASES[name]
+    if getattr(fn, "asks_seam", False):
+        fn = functools.partial(fn, chip_seam)
     args = build(SingleDeviceSharding(v5e[0]))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_sharded_decode_compiles_on_tp4_mesh(v5e):
-    """The shard_map'd decode kernel on a tp=4 mesh of the described
-    devices: q on heads, pages on kv heads (2 per shard), one custom call
-    per device and no collective (attention is head-wise independent)."""
+    """The decode question on a tp=4 mesh of the described devices: q on
+    heads, pages on kv heads (2 per shard), one custom call per device and
+    no collective (attention is head-wise independent)."""
     from dynamo_tpu.parallel.mesh import AXIS_TP, make_mesh
 
     mesh = make_mesh(tp=4, devices=v5e)
@@ -221,8 +241,8 @@ def test_sharded_decode_compiles_on_tp4_mesh(v5e):
         s((B, H, D), BF, P(None, AXIS_TP, None)), cache, cache,
         s((B, MB), I32, P()), s((B,), I32, P()),
     )
-    fn = functools.partial(pa.sharded_paged_decode_attention, mesh, AXIS_TP)
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    text = jax.jit(PagedAttention(mesh, True).decode).lower(
+        *args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce(" not in text and "all-gather(" not in text
 
@@ -253,7 +273,7 @@ def test_sharded_unified_compiles_on_tp4_mesh(v5e):
 
 
 @pytest.mark.parametrize("kernel", ["decode", "unified", "gather-scales"])
-def test_int8_scale_rows_are_refused_by_mosaic(v5e, kernel):
+def test_int8_scale_rows_are_refused_by_mosaic(v5e, chip_seam, kernel):
     """Why the engine refuses kv_dtype=int8 with the Pallas kernels on the
     TPU backend (engine construction; tests/test_kv_quant.py): the [kv_heads]
     f32 scale-row DMA is not aligned to the 128-lane tiling. When a layout
@@ -264,7 +284,7 @@ def test_int8_scale_rows_are_refused_by_mosaic(v5e, kernel):
         s((NB, KVH), F32),
     )
     if kernel == "decode":
-        fn = pa.paged_decode_attention
+        fn = chip_seam.decode
         args = (s((B, H, D), BF), qcache, qcache, s((B, MB), I32),
                 s((B,), I32))
     elif kernel == "unified":

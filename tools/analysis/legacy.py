@@ -471,57 +471,48 @@ _sim_wallclock_pass.RULES = ("SIM-WALLCLOCK",)
 
 # -- KERNEL-SPLIT ------------------------------------------------------------
 
-# The unified ragged paged-attention kernel (ops/pallas_unified +
-# ops/attention.ragged_paged_attention) serves arbitrary prefill/decode
-# mixes in one launch; the split-era entry points below remain ONLY for the
-# engine's fallback dispatches. A NEW reference outside ops/ (and tests,
-# which pin parity on all of them) should target the unified kernel instead
-# — existing engine fallback sites are baselined.
-SPLIT_ATTENTION_ENTRY_POINTS = frozenset({
-    "flash_extend_attention", "sharded_flash_extend_attention",
-    "paged_decode_attention", "sharded_paged_decode_attention",
-    # retired from the PALLAS verify path when spec-decode verify became
-    # unified-kernel rows (query_len = k+1); the pure-JAX engine's one
-    # fallback verify dispatch is baselined
-    "paged_extend_attention",
+# The attention seam (ops/paged_attention.PagedAttention): the step programs
+# say what rows they have and ``ops/`` decides which kernel or pure-JAX twin
+# serves them. A module outside ``ops/`` that imports a Pallas attention
+# module makes that choice itself, once more. Tests, tools and chip_smoke.py
+# hold the kernels to their twins and may import them.
+PALLAS_ATTENTION_MODULES = frozenset({
+    "pallas_attention", "pallas_unified", "pallas_paged",
 })
 
 
 def _is_kernel_split_exempt(norm_path: str) -> bool:
-    return norm_path.startswith(("dynamo_tpu/ops/", "tests/", "tools/"))
+    p = "/" + norm_path
+    return p.endswith("/chip_smoke.py") or any(
+        d in p for d in ("/dynamo_tpu/ops/", "/tests/", "/tools/")
+    )
 
 
 def kernel_split_refs(path: str, tree: ast.AST):
     out = []
 
-    def msg(name):
-        return (
-            f"legacy split-attention entry point {name} referenced outside "
-            "ops/ — new call sites should target the unified ragged kernel "
-            "(ops/pallas_unified.ragged_paged_attention or its pure-JAX "
-            "twin); the split kernels remain for fallback dispatches only"
-        )
+    def flag(lineno, names):
+        for name in sorted(PALLAS_ATTENTION_MODULES.intersection(names)):
+            out.append((path, lineno, (
+                f"import of the Pallas attention module ops.{name} outside "
+                "ops/ — ask the seam (ops/paged_attention.PagedAttention) "
+                "for attention over paged rows; which kernel or twin serves "
+                "them is decided there"
+            )))
 
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):
+            parts = (node.module or "").split(".")
+            flag(node.lineno, parts)
+            if parts[-1] == "ops":  # from ..ops import pallas_unified
+                flag(node.lineno, [a.name for a in node.names])
+        elif isinstance(node, ast.Import):
             for a in node.names:
-                if a.name in SPLIT_ATTENTION_ENTRY_POINTS:
-                    out.append((path, node.lineno, msg(a.name)))
-        elif (
-            isinstance(node, ast.Attribute)
-            and node.attr in SPLIT_ATTENTION_ENTRY_POINTS
-        ):
-            out.append((path, node.lineno, msg(node.attr)))
-        elif (
-            isinstance(node, ast.Name)
-            and node.id in SPLIT_ATTENTION_ENTRY_POINTS
-            and isinstance(node.ctx, ast.Load)
-        ):
-            out.append((path, node.lineno, msg(node.id)))
+                flag(node.lineno, a.name.split("."))
     return out
 
 
-@register("kernel-split", "legacy split-attention entry points outside ops/")
+@register("kernel-split", "Pallas attention modules imported outside ops/")
 def _kernel_split_pass(ctx: Context) -> Iterator[Finding]:
     for m in ctx.modules:
         if _is_kernel_split_exempt(m.path):
