@@ -68,6 +68,7 @@ from ..runtime.logging import get_logger
 from ..runtime.tracing import get_tracer
 from ..tokens import TokenBlockSequence
 from .allocator import BlockAllocator, OutOfBlocks
+from . import step_args
 from .telemetry import PENDING_SPANS_MAX, StepStats, loop_span, pending_spans
 from .sampling import (
     TOP_LOGPROBS_K,
@@ -735,10 +736,14 @@ class TpuEngine:
         # carry); results are fetched decode_pipeline-1 horizons behind the
         # dispatch front so readback RTT hides behind device compute
         self._chains: "deque[_Chain]" = deque()
-        # device-resident copies of slot arrays, re-uploaded only when the
-        # host copy changes (a dozen small host->device transfers per
-        # dispatch otherwise, each a blocking copy on the step thread)
-        self._dev_cache: Dict[str, jax.Array] = {}
+        # device-resident copies of per-slot state, name -> (device array,
+        # the host snapshot it was placed from), placed again only when the
+        # host copy changes (_dev); the guided tables keep their versioned
+        # entries ("g/...") here too. _h2d_placements counts the host-to-
+        # device placements since the last StepStats (_dev misses and the
+        # host values _upload hands a jitted call)
+        self._dev_cache: Dict[str, Any] = {}
+        self._h2d_placements = 0
         self._loop_task: Optional[asyncio.Task] = None
         self._prefill_tasks: set = set()  # in-flight first-token readbacks
         self._last_published_load: Tuple[int, int, int] = (-1, -1, -1)
@@ -1065,10 +1070,17 @@ class TpuEngine:
             return jnp.any((pres != 0.0) | (freqs != 0.0) | (reps != 1.0))
 
         def prefill(params, k_caches, v_caches, counts, tokens, positions,
-                    block_table, new_block_ids, total_len, chunk_start, seeds,
-                    steps, temp, top_k, top_p, min_p, pres, freq, rep,
-                    prompt_masks, slot, lp_need, is_final, lora_tables,
-                    lora_id, proc_masks, mm_embeds, mm_mask):
+                    new_block_ids, step, seeds, temps, top_ks, top_ps, min_ps,
+                    pres, freqs, reps, prompt_masks, lora_tables, lora_ids,
+                    proc_masks, mm_embeds, mm_mask):
+            a = step_args.unpack(step, cfg.max_batch_size)
+            block_table, total_len = a.table_row, a.total_len
+            slot, is_final, lp_need = a.slot, a.is_final, a.c_lp_need
+            steps = jnp.zeros((1,), jnp.int32)
+            seeds, temp, top_k, top_p, min_p, pres, freq, rep = (
+                x[slot][None] for x in
+                (seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps)
+            )
             hidden, k2, v2 = pf_fwd(
                 params, k_caches[0], v_caches[0], tokens, positions,
                 block_table, new_block_ids, total_len,
@@ -1105,10 +1117,13 @@ class TpuEngine:
             tok, lp, tlp_vals, tlp_ids = map(_fetchable, (tok, lp, tlp_vals, tlp_ids))
             return [k2], [v2], counts, tok, lp, tlp_vals, tlp_ids
 
-        def decode(params, k_caches, v_caches, counts, tokens, positions,
-                   block_tables, seq_lens, write_blocks, write_offsets, seeds,
-                   steps, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
-                   prompt_masks, lp_need, lora_tables, lora_ids, proc_masks):
+        def decode(params, k_caches, v_caches, counts, step, block_tables,
+                   seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
+                   prompt_masks, lora_tables, lora_ids, proc_masks):
+            a = step_args.unpack(step, cfg.max_batch_size)
+            tokens, positions, seq_lens = a.tokens, a.positions, a.seq_lens
+            write_blocks, write_offsets = a.write_blocks, a.write_offsets
+            steps, lp_need = a.steps, a.lp_need
             hidden, k2, v2 = dc_fwd(
                 params, k_caches[0], v_caches[0], tokens, positions,
                 block_tables, seq_lens, write_blocks, write_offsets,
@@ -1344,13 +1359,29 @@ class TpuEngine:
             from ..parallel import ring as ringlib
 
         def prefill(params, k_caches, v_caches, counts, tokens, positions,
-                    block_table, new_block_ids, total_len, chunk_start, seeds,
-                    steps, temp, top_k, top_p, min_p, pres, freq, rep,
-                    prompt_masks, slot, lp_need, is_final, lora_tables,
-                    lora_id, proc_masks, mm_embeds, mm_mask,
-                    g_active=None, g_state=None, g_class=None, g_trans=None):
+                    new_block_ids, step, seeds, temps, top_ks, top_ps, min_ps,
+                    pres, freqs, reps, prompt_masks, lora_tables, lora_ids,
+                    proc_masks, mm_embeds, mm_mask,
+                    g_active=None, g_class=None, g_trans=None):
             # tokens/positions: [S_pad] — ONE chunk of the prompt (the whole
-            # prompt when it fits a bucket); block_table: [max_blocks_per_seq]
+            # prompt when it fits a bucket); step: the chunk's per-step values
+            # (step_args.py: its block-table row [max_blocks_per_seq], span,
+            # slot and switches); the sampling arrays are the per-slot [B]
+            # ones every program takes, read at ``slot``
+            a = step_args.unpack(step, cfg.max_batch_size)
+            block_table, total_len, chunk_start = (
+                a.table_row, a.total_len, a.chunk_start
+            )
+            slot, is_final, lp_need, g_state = (
+                a.slot, a.is_final, a.c_lp_need, a.c_g_state
+            )
+            steps = jnp.zeros((1,), jnp.int32)
+            seeds, temp, top_k, top_p, min_p, pres, freq, rep = (
+                x[slot][None] for x in
+                (seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps)
+            )
+            lora_id = lora_ids[slot]
+
             def attend(q, k_new, v_new, layer_idx, **extra):
                 # extra: per-layer attention variants the model opts into
                 # (sliding ``window``, per-head ``sinks`` — models/gptoss.py);
@@ -1445,12 +1476,17 @@ class TpuEngine:
             tok, lp, tlp_vals, tlp_ids = map(_fetchable, (tok, lp, tlp_vals, tlp_ids))
             return k_caches, v_caches, counts, tok, lp, tlp_vals, tlp_ids
 
-        def decode(params, k_caches, v_caches, counts, tokens, positions,
-                   block_tables, seq_lens, write_blocks, write_offsets, seeds,
-                   steps, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
-                   prompt_masks, lp_need, lora_tables, lora_ids, proc_masks,
-                   g_active=None, g_state=None, g_class=None, g_trans=None):
-            # tokens: [B]; block_tables: [B, max_blocks_per_seq]
+        def decode(params, k_caches, v_caches, counts, step, block_tables,
+                   seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
+                   prompt_masks, lora_tables, lora_ids, proc_masks,
+                   g_active=None, g_class=None, g_trans=None):
+            # step: the [B] per-step rows (step_args.py); block_tables:
+            # [B, max_blocks_per_seq]
+            a = step_args.unpack(step, cfg.max_batch_size)
+            tokens, positions, seq_lens = a.tokens, a.positions, a.seq_lens
+            write_blocks, write_offsets = a.write_blocks, a.write_offsets
+            steps, lp_need, g_state = a.steps, a.lp_need, a.g_state
+
             def attend(q, k_new, v_new, layer_idx, **extra):
                 kc, vc = att.write_decode_kv(
                     k_caches[layer_idx], v_caches[layer_idx],
@@ -1570,16 +1606,11 @@ class TpuEngine:
             return out + (g_out,) if g_active is not None else out
 
         def mixed_step(params, k_caches, v_caches, counts,
-                       c_tokens, c_positions, c_block_table, c_new_block_ids,
-                       c_total_len, c_chunk_start, c_slot, c_is_final,
-                       c_lp_need,
-                       d_tokens, d_positions, block_tables, d_seq_lens,
-                       d_write_blocks, d_write_offsets,
-                       seeds, steps, temps, top_ks, top_ps, min_ps, pres,
-                       freqs, reps, prompt_masks, lp_need, lora_tables,
+                       c_tokens, c_positions, c_new_block_ids, step,
+                       block_tables, seeds, temps, top_ks, top_ps, min_ps,
+                       pres, freqs, reps, prompt_masks, lora_tables,
                        lora_ids, proc_masks,
-                       g_active=None, g_state=None, c_g_state=None,
-                       g_class=None, g_trans=None):
+                       g_active=None, g_class=None, g_trans=None):
             """ONE fused continuous-batching step: a prefill chunk of one
             sequence (c_* args — the prefill() conventions) rides along with
             the resident decode batch (d_* args — the decode() conventions)
@@ -1589,7 +1620,19 @@ class TpuEngine:
             0 is the chunk (query_len = chunk_len) and rows 1..B are the
             decode slots (query_len = 1, or 0 when inactive). Sampling
             epilogues are copied verbatim from prefill()/decode() so mixed
-            steps are token-identical to the split dispatches."""
+            steps are token-identical to the split dispatches. Both
+            halves' per-step values arrive in ``step`` (step_args.py)."""
+            a = step_args.unpack(step, cfg.max_batch_size)
+            c_block_table, c_total_len, c_chunk_start = (
+                a.table_row, a.total_len, a.chunk_start
+            )
+            c_slot, c_is_final, c_lp_need = a.slot, a.is_final, a.c_lp_need
+            d_tokens, d_positions, d_seq_lens = (
+                a.tokens, a.positions, a.seq_lens
+            )
+            d_write_blocks, d_write_offsets = a.write_blocks, a.write_offsets
+            steps, lp_need = a.steps, a.lp_need
+            g_state, c_g_state = a.g_state, a.c_g_state
             S_pad = c_tokens.shape[0]
             B = d_tokens.shape[0]
             chunk_len = c_total_len - c_chunk_start
@@ -2070,12 +2113,12 @@ class TpuEngine:
         # feature is compiled in (engine _build_programs); g_state travels
         # by value (resync) or as the carry sentinel
         g_prefill = (
-            # 29 (g_state) travels by value — a scalar resume state
-            {28: "g_active_dev", 30: "g_class_dev", 31: "g_trans_dev"}
+            # the chunk's resume state travels by value, in the step buffer
+            {22: "g_active_dev", 23: "g_class_dev", 24: "g_trans_dev"}
             if self.guided_enabled else {}
         )
         g_decode = (
-            {24: "g_active_dev", 26: "g_class_dev", 27: "g_trans_dev"}
+            {18: "g_active_dev", 19: "g_class_dev", 20: "g_trans_dev"}
             if self.guided_enabled else {}
         )
         g_multi = (
@@ -2085,13 +2128,13 @@ class TpuEngine:
         ops.register(
             "prefill", self._prefill_fn,
             state_in={0: "params", 1: "k", 2: "v", 3: "counts",
-                      19: "pmasks", 23: "lora", **g_prefill},
+                      16: "pmasks", 17: "lora", **g_prefill},
             state_out={0: "k", 1: "v", 2: "counts"},
         )
         ops.register(
             "decode", self._decode_fn,
             state_in={0: "params", 1: "k", 2: "v", 3: "counts",
-                      19: "pmasks", 21: "lora", **g_decode},
+                      14: "pmasks", 15: "lora", **g_decode},
             state_out={0: "k", 1: "v", 2: "counts"},
         )
         ops.register(
@@ -3446,49 +3489,41 @@ class TpuEngine:
             )
             S_pad = len(tokens)  # the bucketed width (_mm_chunk needs it)
 
-            s = st.req.sampling
             total_len = start + chunk_len
             d_tokens, d_positions, d_new_blocks = (
                 dev if dev is not None
                 else (tokens, positions, new_block_ids)
             )
-            g_args = ()
+            g_dev = ()
             if self.guided_enabled:
                 # full versioned device tables, indexed by slot in the
                 # program; the FSM state travels by value (0, or walked over
                 # prior tokens for disagg/migration resumes)
-                ga, gc, gt = self._guided_dev()
-                g_args = (ga, np.int32(st.guided_state), gc, gt)
-            host = (
-                self.params, self.k_caches, self.v_caches, self.output_counts,
-                d_tokens, d_positions,
-                self._block_tables[st.slot],
-                d_new_blocks, np.int32(total_len), np.int32(start),
-                np.array([self._seeds[st.slot]], np.uint32),
-                np.array([0], np.int32),
-                np.array([s.temperature], np.float32),
-                np.array([s.top_k], np.int32),
-                np.array([s.top_p], np.float32),
-                np.array([s.min_p], np.float32),
-                np.array([s.presence_penalty], np.float32),
-                np.array([s.frequency_penalty], np.float32),
-                np.array([s.repetition_penalty], np.float32),
-                self.prompt_masks, np.int32(st.slot),
-                np.bool_(self._lp_ns[st.slot] > 0),
-                np.bool_(is_final),
-                self._lora_tables(), np.int32(self._lora_slots[st.slot]),
-                self._dev("proc_masks", self._lp_masks),
-                *self._mm_chunk(st, start, chunk_len, S_pad),
-                *g_args,
+                g_dev = self._guided_dev()
+            step = step_args.pack(
+                self.cfg.max_batch_size, self.cfg.max_blocks_per_seq,
+                table_row=self._block_tables[st.slot],
+                total_len=total_len, chunk_start=start, slot=st.slot,
+                is_final=is_final, c_lp_need=self._lp_ns[st.slot] > 0,
+                c_g_state=st.guided_state,
             )
         with loop_span(self, "upload"):
-            args = self._upload(host)
+            args = self._upload((
+                self.params, self.k_caches, self.v_caches, self.output_counts,
+                d_tokens, d_positions, d_new_blocks, step,
+                *self._slot_sampling_dev(),
+                self.prompt_masks, self._lora_tables(),
+                self._dev("lora_slots", self._lora_slots),
+                self._dev("proc_masks", self._lp_masks),
+                *self._mm_chunk(st, start, chunk_len, S_pad),
+                *g_dev,
+            ))
         with loop_span(self, "launch"):
             (self.k_caches, self.v_caches, self.output_counts, tok, lp,
              tlp_vals, tlp_ids) = self._prefill_fn(*args)
         with loop_span(self, "pack"):
             # the next chunk's arrays, built under this chunk's compute
-            del host, args  # donated caches: hold no stale handles
+            del args  # donated caches: hold no stale handles
             st.prefill_pos = total_len
             self._schedule_next_chunk(st, prompt, is_final)
             self._advance_draft_prefill(st, prompt)
@@ -3642,41 +3677,38 @@ class TpuEngine:
             )
             lp_need = bool(np.any((self._lp_ns > 0) & (d_seq_lens > 0)))
             c_lp_need = self._lp_ns[st.slot] > 0
-            g_args = ()
+            g_dev, g_rows = (), {}
             if self.guided_enabled:
                 # decode rows resync the host FSM states (mixed steps are
                 # never chained); the chunk row's state travels by value
                 # like prefill
-                g_active, g_class, g_trans = self._guided_dev()
-                g_args = (
-                    g_active, self._g_state.copy(),
-                    np.int32(st.guided_state), g_class, g_trans,
-                )
+                g_dev = self._guided_dev()
+                g_rows = dict(g_state=self._g_state)
             d_tokens, d_pos_chunk, d_new_blocks = (
                 dev if dev is not None
                 else (tokens, positions, new_block_ids)
             )
-            host = (
-                self.params, self.k_caches, self.v_caches, self.output_counts,
-                d_tokens, d_pos_chunk,
-                self._block_tables[st.slot], d_new_blocks,
-                np.int32(start + chunk_len), np.int32(start),
-                np.int32(st.slot), np.bool_(is_final),
-                np.bool_(c_lp_need),
-                self._tokens, d_positions,
-                self._block_tables, d_seq_lens,
-                write_blocks, write_offsets,
-                self._seeds, steps,
-                self._temps, self._top_ks, self._top_ps,
-                self._min_ps, self._pres, self._freqs,
-                self._reps,
-                self.prompt_masks, np.bool_(lp_need),
-                self._lora_tables(), self._lora_slots,
-                self._dev("proc_masks", self._lp_masks),
-                *g_args,
+            step = step_args.pack(
+                self.cfg.max_batch_size, self.cfg.max_blocks_per_seq,
+                table_row=self._block_tables[st.slot],
+                total_len=start + chunk_len, chunk_start=start, slot=st.slot,
+                is_final=is_final, c_lp_need=c_lp_need, lp_need=lp_need,
+                c_g_state=st.guided_state,
+                tokens=self._tokens, positions=d_positions,
+                seq_lens=d_seq_lens, write_blocks=write_blocks,
+                write_offsets=write_offsets, steps=steps, **g_rows,
             )
         with loop_span(self, "upload"):
-            args = self._upload(host)
+            args = self._upload((
+                self.params, self.k_caches, self.v_caches, self.output_counts,
+                d_tokens, d_pos_chunk, d_new_blocks, step,
+                self._dev("tables", self._block_tables),
+                *self._slot_sampling_dev(),
+                self.prompt_masks, self._lora_tables(),
+                self._dev("lora_slots", self._lora_slots),
+                self._dev("proc_masks", self._lp_masks),
+                *g_dev,
+            ))
         with loop_span(self, "launch"):
             (self.k_caches, self.v_caches, self.output_counts, toks, lps,
              tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids) = (
@@ -3684,7 +3716,7 @@ class TpuEngine:
             )
         with loop_span(self, "pack"):
             # the next chunk's arrays, built under this step's compute
-            del host, args  # donated caches: hold no stale handles
+            del args  # donated caches: hold no stale handles
             st.prefill_pos = start + chunk_len
             self._schedule_next_chunk(st, prompt, is_final)
             self._advance_draft_prefill(st, prompt)
@@ -3802,38 +3834,65 @@ class TpuEngine:
         self._accept_token(st, tok, lp, tlp_ids, tlp_vals)
         self._wake.set()
 
+    # How a dispatch's arguments travel (PERF.md section 6, PR 29: on a TPU
+    # v5e each host value handed over is a transfer of its own, 0.15-0.25 ms):
+    # what is on the device already (weights, caches, the prep thread's chunk
+    # arrays) passes through; per-slot state, which changes on admission,
+    # finish or a new page, is a cached device copy (``_dev``); what changes
+    # every step is ONE fresh int32 buffer (step_args.py) that the jitted
+    # call places itself (``_upload``). Multihost hands host numpy over in
+    # every case: the leader wrapper broadcasts host data.
+
     def _j(self, host_val):
-        """Dispatch-arg placement: single-process uploads eagerly
-        (jnp.asarray); multihost passes host numpy
-        through — the leader wrapper broadcasts host data, and pulling an
-        uploaded array straight back would pay a blocking D2H per arg."""
+        """Place ONE host value now, for the calls no step loop makes
+        (slot reset, draft prefill, embeddings). Multihost passes host
+        numpy through: pulling an uploaded array straight back for the
+        broadcast would pay a blocking D2H per argument."""
         return host_val if self._mh is not None else jnp.asarray(host_val)
 
-    def _upload(self, host_args: tuple) -> tuple:
-        """A dispatch's arguments with every host array and scalar placed
-        (``_j``); what is on the device already passes through. Apart from
-        ``pack`` so that the loop's spans tell building the host arrays from
-        handing them to the device."""
-        return tuple(
-            self._j(a) if isinstance(a, (np.ndarray, np.generic)) else a
-            for a in host_args
+    def _upload(self, args: tuple) -> tuple:
+        """A step dispatch's arguments as the jitted call takes them. The
+        host values left in ``args`` (the step's packed buffer, a chunk's
+        arrays the prep thread did not place) stay numpy: the call places
+        each in one transfer, and they must be fresh arrays, since the loop
+        writes its slot arrays after the dispatch. Counted here and in
+        ``_dev`` for ``StepStats.h2d_placements``."""
+        self._h2d_placements += sum(
+            isinstance(a, (np.ndarray, np.generic)) for a in args
         )
+        return args
 
     def _dev(self, name: str, host_arr: np.ndarray) -> jax.Array:
-        """Device-resident copy of a slot array, re-uploaded only on change
-        (see _dev_cache)."""
+        """Device-resident copy of per-slot state, placed again only when
+        its content changed (a compare, not a transfer, on a steady step).
+        One cache under one set of names for every program, so a mixed step
+        after a horizon finds the arrays placed, and the other way round;
+        only the caches and the counts are donated, so a copy is handed to
+        program after program."""
         if self._mh is not None:
             # multihost dispatches travel as host numpy anyway (the leader
             # wrapper would immediately pull a device copy back); snapshot so
             # later slot mutations can't race the in-flight frame
             return host_arr.copy()
         cached = self._dev_cache.get(name)
-        if cached is None or not np.array_equal(
-            self._dev_cache.get(name + "/host"), host_arr
-        ):
-            self._dev_cache[name] = jnp.asarray(host_arr)
-            self._dev_cache[name + "/host"] = host_arr.copy()
-        return self._dev_cache[name]
+        if cached is None or not np.array_equal(cached[1], host_arr):
+            # place the snapshot, not the live array: the CPU backend may
+            # alias a numpy array's memory
+            snap = host_arr.copy()
+            cached = self._dev_cache[name] = (jnp.asarray(snap), snap)
+            self._h2d_placements += 1
+        return cached[0]
+
+    def _slot_sampling_dev(self) -> tuple:
+        """The per-slot sampling state in the order every step program
+        takes it: seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps."""
+        dev = self._dev
+        return (
+            dev("seeds", self._seeds), dev("temps", self._temps),
+            dev("top_ks", self._top_ks), dev("top_ps", self._top_ps),
+            dev("min_ps", self._min_ps), dev("pres", self._pres),
+            dev("freqs", self._freqs), dev("reps", self._reps),
+        )
 
     async def _compile_guided(self, spec: Dict[str, Any]):
         """Grammar spec -> TokenTables, compiled off the event loop and
@@ -3986,10 +4045,11 @@ class TpuEngine:
                     seq_lens_np[i] = len(st.seq)
                     steps_np[i] = st.produced
                     self._tokens[i] = st.last_token
-                # host numpy feeds jit directly (same H2D copy jnp.asarray
-                # paid); snapshot _tokens — the loop mutates it after
-                # dispatch. In multihost mode numpy-vs-jax.Array is also the
-                # carry/resync signal (engine _wire_multihost carry_in).
+                # a resynced carry is per-step host data: three fresh numpy
+                # arrays the jitted call places itself (see _upload; _tokens
+                # is snapshotted, the loop mutates it after dispatch). In
+                # multihost mode numpy-vs-jax.Array is also the carry/resync
+                # signal (engine _wire_multihost carry_in).
                 tokens = self._tokens.copy()
                 seq_lens = seq_lens_np
                 steps = steps_np
@@ -4015,28 +4075,22 @@ class TpuEngine:
                         else self._g_state.copy()
                     )
                     g_args = (g_active, g_state, g_class, g_trans)
+                seeds_dev, *sampling_dev = self._slot_sampling_dev()
                 args = (
                     self.params, self.k_caches, self.v_caches,
                     self.output_counts,
                     tokens, seq_lens,
                     self._dev("tables", self._block_tables),
                     self._dev("active", active),
-                    self._dev("seeds", self._seeds),
-                    steps,
-                    self._dev("temps", self._temps),
-                    self._dev("top_ks", self._top_ks),
-                    self._dev("top_ps", self._top_ps),
-                    self._dev("min_ps", self._min_ps),
-                    self._dev("pres", self._pres),
-                    self._dev("freqs", self._freqs),
-                    self._dev("reps", self._reps),
+                    seeds_dev, steps, *sampling_dev,
                     self.prompt_masks,
-                    jnp.bool_(bool(np.any(self._lp_ns[active] > 0))),
+                    self._dev("lp_need", np.any(self._lp_ns[active] > 0)),
                     self._lora_tables(),
                     self._dev("lora_slots", self._lora_slots),
                     self._dev("proc_masks", self._lp_masks),
                     *g_args,
                 )
+            args = self._upload(args)
         # no "sync" here: a horizon's results are awaited by the loop ("fetch")
         with loop_span(self, "launch"):
             if spec:
@@ -4213,33 +4267,32 @@ class TpuEngine:
                 self._decode_dispatch_arrays(seqs)
             )
             lp_need = bool(np.any((self._lp_ns > 0) & (seq_lens > 0)))
-            g_args = ()
+            g_dev, g_rows = (), {}
             if self.guided_enabled:
-                g_active, g_class, g_trans = self._guided_dev()
                 # single-step dispatches are never chained: the host FSM
                 # state (walked in _accept_tokens) is authoritative
-                g_args = (g_active, self._g_state.copy(), g_class, g_trans)
-            host = (
-                self.params, self.k_caches, self.v_caches, self.output_counts,
-                self._tokens, positions,
-                self._block_tables, seq_lens,
-                write_blocks, write_offsets,
-                self._seeds, steps,
-                self._temps,
-                self._top_ks, self._top_ps,
-                self._min_ps, self._pres,
-                self._freqs, self._reps,
-                self.prompt_masks, np.bool_(lp_need),
-                self._lora_tables(), self._lora_slots,
-                self._dev("proc_masks", self._lp_masks),
-                *g_args,
+                g_dev = self._guided_dev()
+                g_rows = dict(g_state=self._g_state)
+            step = step_args.pack(
+                self.cfg.max_batch_size, self.cfg.max_blocks_per_seq,
+                lp_need=lp_need, tokens=self._tokens, positions=positions,
+                seq_lens=seq_lens, write_blocks=write_blocks,
+                write_offsets=write_offsets, steps=steps, **g_rows,
             )
         with loop_span(self, "upload"):
-            args = self._upload(host)
+            args = self._upload((
+                self.params, self.k_caches, self.v_caches, self.output_counts,
+                step, self._dev("tables", self._block_tables),
+                *self._slot_sampling_dev(),
+                self.prompt_masks, self._lora_tables(),
+                self._dev("lora_slots", self._lora_slots),
+                self._dev("proc_masks", self._lp_masks),
+                *g_dev,
+            ))
         with loop_span(self, "launch"):
             (self.k_caches, self.v_caches, self.output_counts, toks, lps,
              tlp_vals, tlp_ids) = self._decode_fn(*args)
-            del host, args  # donated caches: hold no stale handles
+            del args  # donated caches: hold no stale handles
         with loop_span(self, "sync"):
             return self._decode_results(seqs, toks, lps, tlp_ids, tlp_vals,
                                         lp_need)
@@ -4487,6 +4540,7 @@ class TpuEngine:
     def _step_stats(self, phase: str, duration_s: float, tokens: int) -> None:
         """Feed one StepStats to the hook — scalars the loop already holds;
         never forces a device sync (engine/telemetry.py)."""
+        placed, self._h2d_placements = self._h2d_placements, 0
         hook = self.stats_hook
         if hook is None:
             return
@@ -4533,6 +4587,7 @@ class TpuEngine:
                 moe_tokens_routed=routed,
                 moe_experts_touched=touched,
                 moe_load_max=load_max,
+                h2d_placements=placed,
             ))
         except Exception:
             log.exception("stats hook failed")

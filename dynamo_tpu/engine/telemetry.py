@@ -161,6 +161,12 @@ class StepStats:
     moe_tokens_routed: Optional[int] = None
     moe_experts_touched: Optional[int] = None
     moe_load_max: Optional[int] = None
+    # host-to-device placements the dispatches made since the last StepStats
+    # (engine _upload / _dev): host values handed to a jitted call, one
+    # transfer each, and per-slot arrays placed again because they changed.
+    # A steady synchronous step makes one (its packed buffer); the prep
+    # thread's three a chunk, made under the previous step, are not counted
+    h2d_placements: int = 0
 
 
 def moe_load_imbalance(s: StepStats) -> Optional[float]:
@@ -270,6 +276,11 @@ class EngineTelemetry:
                 for name in _ANNOTATION if name in loop_ns
             },
         }
+        if recent:
+            # mean host-to-device placements a step over the window
+            out["h2d_placements"] = round(
+                sum(s.h2d_placements for s in recent) / len(recent), 3
+            )
         last = self._last
         if last is not None:
             out["last"] = {
