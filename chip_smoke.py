@@ -707,6 +707,34 @@ def _kernel_child() -> None:
             pmoe.grouped_matmul(act, (w_down,), sizes),
             ref_grouped(act, (w_down,), sizes))
 
+    # latent attention over selected keys (models/mla.py with an indexer) at
+    # GLM-5.2's widths: 64 absorbed heads over a 512-lane latent + rotary
+    # key in rows of 128 lanes; a 128-token chunk at a 24 576-token context
+    # and three decode rows (24k keys, 300 keys: all selected, an empty
+    # row), each query over its own 2 048 selected token rows
+    from dynamo_tpu.ops import pallas_sparse as psp
+
+    LNB, LMB, ctx, Sq, topk = 4864, 1600, 24576, 128, 2048
+    lat, aux = rnd(LNB, BS, 4, 128), rnd(LNB, BS, 4, 128)
+    ltables = jnp.asarray(
+        rng.permutation(LNB - 1)[: 3 * LMB].reshape(3, LMB) + 1, jnp.int32
+    )
+    contexts = [ctx + i + 1 for i in range(Sq)] + [ctx, 300, 0]
+    sel = np.full((len(contexts), topk), -1, np.int32)
+    for i, n in enumerate(contexts):
+        if n:
+            sel[i, : min(n, topk)] = rng.permutation(n)[:topk]
+    sel_rows = jnp.asarray([0] * Sq + [1, 2, 0], jnp.int32)
+    ql = rnd(len(contexts), 64, 640)
+    sparse_args = (ql, lat, aux, ltables, sel_rows, jnp.asarray(sel))
+    got = psp.sparse_latent_attention(*sparse_args, scale=1 / 16)
+    if np.asarray(got, np.float32)[-1].any():
+        raise SystemExit("sparse_latent_attention: an empty row is not zeros")
+    compare(
+        "sparse_latent_attention 24k keys, chunk + decode rows", got,
+        highest(att.sparse_latent_attention)(*sparse_args, 1 / 16),
+    )
+
     # block moves are copies: exact
     ids = jnp.asarray(rng.permutation(NB)[:32], jnp.int32)
     got = bc.gather_blocks(k_cache, ids)

@@ -473,6 +473,12 @@ class TpuEngine:
                     "guided decoding needs guided_vocab=(vocab byte forms, "
                     "eos_id) — see guided.vocab_bytes_from_tokenizer"
                 )
+        registry.check_dsa_supported(
+            self.mcfg, tp=config.tp, pp=config.pp, sp=config.sp,
+            spec=config.spec_draft is not None,
+            lora=config.lora_max_adapters > 0,
+            kv_quantized=self.kv_quantized, vision=config.vision is not None,
+        )
         if registry.is_gptoss(self.mcfg) or registry.is_gemma(self.mcfg):
             # the ragged kernel carries per-row window/sink/softcap
             # attributes, so use_pallas serves these families too. Only the
@@ -569,11 +575,12 @@ class TpuEngine:
         # the one-chip grouped expert path reports its routing each step
         # (StepStats.moe_*): three numbers riding the readback a decode or
         # mixed step already makes. None where no step carried them.
+        # A configuration with an indexer adds three more (StepStats.dsa_*).
         self._moe_counted = (
-            registry.is_moe(self.mcfg) and config.pp == 1
+            registry.counts_routing(self.mcfg) and config.pp == 1
             and meshlib.tp_size(self.mesh) == 1
         )
-        self._moe_last: Optional[Tuple[int, int, int]] = None
+        self._moe_last: Optional[Tuple[int, ...]] = None
         self._lm_logits = registry.lm_logits_fn(self.mcfg)
         with self.mesh:
             if params is None and (
@@ -1020,7 +1027,14 @@ class TpuEngine:
 
     def _pallas_auto_ok(self, mcfg) -> bool:
         """The model-side half of the auto rule, shared by the main model
-        and a speculative draft (each judged on its own config)."""
+        and a speculative draft (each judged on its own config). What it
+        asks of ``head_dim`` and ``num_kv_heads`` is asked of the PAGES the
+        attention kernels copy, whatever a family keeps in them: a latent
+        held as one 576-lane head is refused (Mosaic slices HBM by whole
+        tiles), one held as rows of 128 lanes (an MlaConfig with an indexer:
+        ops/attention.py has the layout) is admitted, and with it the fused
+        mixed step and the Pallas expert multiplication, which ask nothing
+        of the attention layout but ride the same switch."""
         return (
             mcfg.head_dim % 128 == 0
             and mcfg.num_kv_heads % meshlib.tp_size(self.mesh) == 0
@@ -1315,7 +1329,8 @@ class TpuEngine:
             """[B] toks/lps + [B,K] top-logprob rows -> one [B, 2+2K] f32 row
             (token ids are exact in f32 below 2^24) so the host pays a single
             device->host fetch per horizon. ``moe`` ([3], the step's routing
-            counters) rides as three more columns, the same in every row."""
+            counters; [6] with an indexer's) rides as that many more columns,
+            the same in every row."""
             cols = [
                 toks.astype(jnp.float32)[:, None],
                 lps[:, None],
@@ -1323,14 +1338,19 @@ class TpuEngine:
                 tlp_vals,
             ]
             if moe is not None:
-                cols.append(jnp.broadcast_to(moe[None], (toks.shape[0], 3)))
+                cols.append(
+                    jnp.broadcast_to(moe[None], (toks.shape[0], moe.shape[0]))
+                )
             return jnp.concatenate(cols, axis=-1)
 
-        def routing_stats(valid):
+        def routing_stats(valid, decode_rows=None):
             """A collector for this forward's routing counts (one-chip MoE),
             or None: a TRACE-time branch, other families' programs are
-            unchanged."""
-            return moe_lib.RoutingStats(valid) if moe_counted else None
+            unchanged. ``decode_rows``: which of the rows are decode rows,
+            where not all are (a mixed step)."""
+            if not moe_counted:
+                return None
+            return moe_lib.RoutingStats(valid, decode_rows)
 
         # guided decoding ops (cfg.guided_max_states > 0): one [B, C] row
         # gather + one [B, V] class lookup per step. Callers pass g_* only
@@ -1691,7 +1711,8 @@ class TpuEngine:
             else:
                 packed_lora_ids = lora_ids
             moe_stats = routing_stats(
-                jnp.concatenate([c_positions < c_total_len, active])
+                jnp.concatenate([c_positions < c_total_len, active]),
+                jnp.concatenate([jnp.zeros((S_pad,), bool), active]),
             )
             hidden = call_fwd(
                 params, tokens, positions, attend, lora_tables,
@@ -4176,7 +4197,8 @@ class TpuEngine:
             # steps' routed rows and touched experts, and keeps the largest load
             moe = packed_np[:, 0, 2 + 2 * K :]
             self._moe_last = (
-                int(moe[:, 0].sum()), int(moe[:, 1].sum()), int(moe[:, 2].max())
+                int(moe[:, 0].sum()), int(moe[:, 1].sum()), int(moe[:, 2].max()),
+                *(int(x) for x in moe[:, 3:].sum(axis=0)),
             )
         for i, st in enumerate(chain.seqs):
             if st is None or st.done:
@@ -4563,7 +4585,9 @@ class TpuEngine:
         host_spans = tuple(spans.popleft() for _ in range(len(spans)))
         admit_wait_s = tuple(waits.popleft() for _ in range(len(waits)))
         # set by the step's own readback; a prefill-only step has none
-        routed, touched, load_max = self._moe_last or (None,) * 3
+        routed, touched, load_max, *selection = self._moe_last or (None,) * 3
+        causal, scored, selected = selection or (None,) * 3
+        held = getattr(self.mcfg, "experts_held", None) is not None
         self._moe_last = None
         try:
             hook(StepStats(
@@ -4587,6 +4611,10 @@ class TpuEngine:
                 moe_tokens_routed=routed,
                 moe_experts_touched=touched,
                 moe_load_max=load_max,
+                moe_held_experts_touched=touched if held else None,
+                dsa_keys_causal=causal,
+                dsa_keys_scored=scored,
+                dsa_keys_selected=selected,
                 h2d_placements=placed,
             ))
         except Exception:

@@ -161,6 +161,18 @@ class StepStats:
     moe_tokens_routed: Optional[int] = None
     moe_experts_touched: Optional[int] = None
     moe_load_max: Optional[int] = None
+    # where the expert layers hold one chip's share of a layer's experts
+    # (MlaConfig.experts_held): the held experts with at least one row,
+    # summed over layers (a horizon sums its steps); the three above then
+    # count the held experts and the rows routed to them only
+    moe_held_experts_touched: Optional[int] = None
+    # learned sparse attention (an MlaConfig with an indexer), summed over
+    # the step's real decode rows and the layers: keys a row could see, keys
+    # an indexer scored (selecting layers only), keys attended over. They
+    # ride the readback beside moe_*; None elsewhere
+    dsa_keys_causal: Optional[int] = None
+    dsa_keys_scored: Optional[int] = None
+    dsa_keys_selected: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -304,6 +316,16 @@ class EngineTelemetry:
                 "experts_touched": moe.moe_experts_touched,
                 "load_max": moe.moe_load_max,
             }
+            if moe.moe_held_experts_touched is not None:
+                out["moe"]["held_experts_touched"] = moe.moe_held_experts_touched
+            if moe.dsa_keys_causal is not None:
+                # the last such step's selection: what the indexer kept
+                out["dsa"] = {
+                    "phase": moe.phase,
+                    "keys_causal": moe.dsa_keys_causal,
+                    "keys_scored": moe.dsa_keys_scored,
+                    "keys_selected": moe.dsa_keys_selected,
+                }
         return out
 
     def on_step(self, s: StepStats) -> None:

@@ -35,21 +35,38 @@ unchanged. Two subtleties:
   projections and the 1-head latent cache are replicated (an MQA cache
   cannot shard on heads — same layout real MLA deployments use).
 
+Learned sparse attention (DSA, GLM-5.x / DeepSeek-V3.2; ``index_topk > 0``):
+a small indexer scores every causal key for every query, the ``index_topk``
+best are kept, and the softmax above runs over those alone. A layer whose
+``indexer_types`` entry is ``full`` has an indexer and selects; a ``shared``
+layer attends over the selection of the nearest ``full`` layer before it.
+The cache of such a configuration is laid out for token-granular reads
+(ops/attention.py): ``num_kv_heads`` rows of 128 lanes a token in either
+paged array, the latent in the first, ``k_pe`` and the index key in the
+second; the layer hands the seam an ``ops.attention.DsaQuery`` and gets
+``[..., heads, kv_lora_rank]`` back. Configurations without an indexer keep
+the 576-lane contract described above, untouched.
+
 FFN is the dense SwiGLU for ``num_experts == 0``, otherwise DeepSeek-MoE
 style: ``first_dense_layers`` leading dense layers, sigmoid-or-softmax
 top-k routing with ``routed_scaling_factor``, optional always-on shared
 experts, reusing models/moe.py's expert paths (grouped / dense / EP-psum).
+``mlp_layer_types`` (``dense`` / ``sparse`` a layer) overrides the leading-
+dense rule; ``experts_held`` = (first, count) makes every sparse layer one
+chip's share of a layer divided over chips: its stacks hold ``count``
+experts, the router still chooses among all ``num_experts``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Callable, Optional
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..ops.attention import LATENT_LANES, DsaQuery
 from . import moe as moelib
 from .llama import (
     AttendFn,
@@ -86,10 +103,47 @@ class MlaConfig(LlamaConfig):
     # checkpoint rope layout: True = interleaved pairs (HF rope_interleave,
     # the DeepSeek default) — the loader de-interleaves to rotate-half
     rope_interleave: bool = True
+    # per-layer FFN kinds ("dense" / "sparse"); () = the leading-dense rule
+    mlp_layer_types: Tuple[str, ...] = ()
+    # (first, count): the experts this chip holds of every sparse layer
+    experts_held: Optional[Tuple[int, int]] = None
+    # learned sparse attention (index_topk == 0: none): the indexer's sizes
+    # and, a layer, "full" (has an indexer, selects) or "shared" (attends
+    # over the nearest full layer's selection)
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: Tuple[str, ...] = ()
 
     def __post_init__(self):
         # the engine reads num_kv_heads/head_dim as the KV-cache layout;
         # for MLA that layout IS the latent — pin it so presets can't drift
+        if self.index_topk > 0:
+            # rows of 128 lanes a token, so that a token can be read alone
+            # (ops/attention.py has the layout)
+            if (self.kv_lora_rank % (2 * LATENT_LANES)
+                    or self.qk_rope_head_dim > LATENT_LANES
+                    or self.index_head_dim > LATENT_LANES
+                    or self.index_head_dim < self.qk_rope_head_dim
+                    or self.q_lora_rank <= 0):
+                raise ValueError(
+                    "a latent cache read token by token needs kv_lora_rank a "
+                    "multiple of 256, qk_rope_head_dim <= index_head_dim <= "
+                    "128 and q_lora_rank > 0 (the indexer reads the query's "
+                    "latent)"
+                )
+            kinds = self.indexer_types[: self.num_layers]
+            if (len(kinds) != self.num_layers or kinds[0] != "full"
+                    or set(kinds) - {"full", "shared"}):
+                raise ValueError(
+                    "indexer_types names every layer 'full' or 'shared', and "
+                    "the first layer selects for itself"
+                )
+            object.__setattr__(
+                self, "num_kv_heads", self.kv_lora_rank // LATENT_LANES
+            )
+            object.__setattr__(self, "head_dim", LATENT_LANES)
+            return
         object.__setattr__(self, "num_kv_heads", 1)
         object.__setattr__(
             self, "head_dim", self.kv_lora_rank + self.qk_rope_head_dim
@@ -122,6 +176,26 @@ class MlaConfig(LlamaConfig):
             num_experts=4, num_experts_per_tok=2, moe_intermediate_size=64,
             moe_scoring="sigmoid", routed_scaling_factor=2.0,
             num_shared_experts=1, first_dense_layers=1, dtype=jnp.float32,
+        )
+        defaults.update(kw)
+        return cls(**defaults)
+
+    @classmethod
+    def tiny_mla_dsa(cls, **kw) -> "MlaConfig":
+        """Learned sparse attention at a test's size: a dense layer that
+        selects, a sparse one that shares, a sparse one that selects; 8
+        experts of which this share holds 4."""
+        defaults = dict(
+            vocab_size=512, hidden_size=128, num_layers=3, num_heads=4,
+            kv_lora_rank=256, qk_nope_head_dim=32, qk_rope_head_dim=16,
+            v_head_dim=32, intermediate_size=256, q_lora_rank=96,
+            num_experts=8, num_experts_per_tok=2, moe_intermediate_size=64,
+            moe_scoring="sigmoid", routed_scaling_factor=2.5,
+            num_shared_experts=1, experts_held=(4, 4),
+            mlp_layer_types=("dense", "sparse", "sparse"),
+            index_topk=16, index_n_heads=4, index_head_dim=32,
+            indexer_types=("full", "shared", "full"),
+            tie_embeddings=False, dtype=jnp.float32,
         )
         defaults.update(kw)
         return cls(**defaults)
@@ -163,11 +237,20 @@ class MlaConfig(LlamaConfig):
 
 
 def _is_moe_layer(cfg: MlaConfig, layer_idx: int) -> bool:
+    if cfg.mlp_layer_types:
+        return cfg.mlp_layer_types[layer_idx] == "sparse"
     return cfg.num_experts > 0 and layer_idx >= cfg.first_dense_layers
+
+
+def _selects(cfg: MlaConfig, layer_idx: int) -> bool:
+    return cfg.index_topk > 0 and cfg.indexer_types[layer_idx] == "full"
 
 
 def init_layer_params(rng: jax.Array, cfg: MlaConfig, layer_idx: int) -> Params:
     k = jax.random.split(rng, 16)
+    # what an indexer or a held share adds draws from keys of its own, so
+    # that the other parameters of a seed stay what they were
+    kx = jax.random.split(jax.random.fold_in(rng, 1), 4)
     h = cfg.hidden_size
     nh, rank = cfg.num_heads, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -200,14 +283,33 @@ def init_layer_params(rng: jax.Array, cfg: MlaConfig, layer_idx: int) -> Params:
         p["wq"] = (
             jax.random.normal(k[5], (h, nh * (nope + rope))) * scale
         ).astype(cfg.dtype)
+    if _selects(cfg, layer_idx):
+        # the indexer: n small query heads off the query's latent, ONE key a
+        # token (LayerNorm with bias) and a weight a head off the hidden state
+        nI, dI = cfg.index_n_heads, cfg.index_head_dim
+        p["w_iq"] = (
+            jax.random.normal(kx[0], (cfg.q_lora_rank, nI * dI))
+            / math.sqrt(cfg.q_lora_rank)
+        ).astype(cfg.dtype)
+        p["w_ik"] = (jax.random.normal(kx[1], (h, dI)) * scale).astype(cfg.dtype)
+        p["ik_norm_w"] = jnp.ones((dI,), cfg.dtype)
+        p["ik_norm_b"] = jnp.zeros((dI,), cfg.dtype)
+        p["w_iw"] = (jax.random.normal(kx[2], (h, nI)) * scale).astype(cfg.dtype)
     if _is_moe_layer(cfg, layer_idx):
         E, inter = cfg.num_experts, cfg.moe_intermediate_size
         iscale = 1.0 / math.sqrt(inter)
         p["w_router"] = (jax.random.normal(k[6], (h, E)) * scale).astype(cfg.dtype)
         if cfg.moe_scoring == "sigmoid":
             # aux-free load-balancing bias (updated out-of-band in training;
-            # inference just reads it — HF e_score_correction_bias)
-            p["router_bias"] = jnp.zeros((E,), jnp.float32)
+            # inference just reads it — HF e_score_correction_bias). Zero,
+            # except where a share is held: there the weights are a seed's,
+            # and a bias drawn small makes selection differ from the weights
+            p["router_bias"] = (
+                jnp.zeros((E,), jnp.float32) if cfg.experts_held is None
+                else 0.1 * jax.random.normal(kx[3], (E,), jnp.float32)
+            )
+        if cfg.experts_held is not None:
+            E = cfg.experts_held[1]   # the stacks hold this chip's experts
         # expert stacks use their own names (w_e*) so the TP partition spec
         # can shard the expert dim without colliding with the 2-D dense-layer
         # w_gate/w_up/w_down sharing the per-layer spec table
@@ -294,16 +396,21 @@ def expert_params(p: Params) -> Params:
 
 
 def _moe_ffn(
-    p: Params, cfg: MlaConfig, x: jax.Array, expert_fn=None
+    p: Params, cfg: MlaConfig, x: jax.Array, expert_fn=None, stats=None,
+    matmul=moelib.grouped_matmul_reference,
 ) -> jax.Array:
     """Routed experts (moe.py grouped path fed by this module's DeepSeek
     router, or a mesh-aware ``expert_fn`` injected by the registry for EP)
-    + the always-on shared-expert SwiGLU."""
+    + the always-on shared-expert SwiGLU. ``matmul`` is the grouped path's
+    multiplication (the Pallas kernel where the registry turns it on)."""
     routed = route(p, cfg, x)
     if expert_fn is not None:
         y = expert_fn(expert_params(p), x, routed)
     else:
-        y = moelib.moe_ffn_grouped(expert_params(p), cfg, x, routed=routed)
+        y = moelib.moe_ffn_grouped(
+            expert_params(p), cfg, x, routed=routed, stats=stats,
+            matmul=matmul, held=cfg.experts_held,
+        )
     if cfg.num_shared_experts > 0:
         sg = jax.nn.silu((x @ p["w_shared_gate"]).astype(jnp.float32)).astype(x.dtype)
         y = y + (sg * (x @ p["w_shared_up"])) @ p["w_shared_down"]
@@ -320,6 +427,64 @@ def _dense_ffn(p: Params, cfg: MlaConfig, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def _rope_front(x: jax.Array, cos: jax.Array, sin: jax.Array, rope: int):
+    """Rotate the first ``rope`` dims of ``x [..., heads, d]``."""
+    return jnp.concatenate(
+        [apply_rope(x[..., :rope], cos, sin), x[..., rope:]], axis=-1
+    )
+
+
+def _lanes(x: jax.Array) -> jax.Array:
+    """Zero-pad the last dim to a row of ``LATENT_LANES`` lanes."""
+    pad = LATENT_LANES - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
+def _selected_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
+                        attend, layer_idx, carry):
+    """Latent attention over the positions an indexer selects (this layer's,
+    or the one ``carry`` brings from the nearest selecting layer): the
+    layer's rows in the token-granular layout, and a ``DsaQuery`` for the
+    seam. Returns [..., heads, kv_lora_rank]."""
+    rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nI, dI = cfg.index_n_heads, cfg.index_head_dim
+    lead = h.shape[:-1]
+    dsa = DsaQuery(
+        scale=1.0 / math.sqrt(cfg.qk_head_dim), topk=cfg.index_topk
+    )
+    kI = jnp.zeros((*lead, LATENT_LANES), cfg.dtype)
+    if _selects(cfg, layer_idx):
+        qI = (cq @ p["w_iq"]).reshape(*lead, nI, dI)
+        dsa.index_q = _rope_front(qI, cos, sin, rope).astype(cfg.dtype)
+        dsa.index_w = (h @ p["w_iw"]).astype(jnp.float32) * (
+            nI ** -0.5 * dI ** -0.5
+        )
+        kf = (h @ p["w_ik"]).astype(jnp.float32)
+        kf = kf - kf.mean(-1, keepdims=True)
+        kf = kf * jax.lax.rsqrt((kf * kf).mean(-1, keepdims=True) + 1e-6)
+        kf = kf * p["ik_norm_w"].astype(jnp.float32) + p["ik_norm_b"].astype(
+            jnp.float32
+        )
+        kI = _lanes(
+            _rope_front(kf.astype(cfg.dtype)[..., None, :], cos, sin, rope)[..., 0, :]
+        )
+    else:
+        dsa.selected = carry["selected"]
+    rows = cfg.num_kv_heads
+    k_rows = c.reshape(*lead, rows, LATENT_LANES)
+    aux = jnp.stack([_lanes(k_pe[..., 0, :]), kI.astype(k_pe.dtype)], axis=-2)
+    aux = jnp.pad(
+        aux, [(0, 0)] * len(lead) + [(0, rows - 2), (0, 0)]
+    )
+    q_lat = jnp.concatenate([q_abs, _lanes(q_pe)], axis=-1)
+    o = attend(
+        q_lat.astype(cfg.dtype), k_rows.astype(cfg.dtype),
+        aux.astype(cfg.dtype), layer_idx, dsa=dsa,
+    )
+    carry["selected"] = dsa.selected
+    return o
+
+
 def layer_forward(
     p: Params,
     cfg: MlaConfig,
@@ -329,6 +494,9 @@ def layer_forward(
     attend: AttendFn,
     layer_idx: int,
     expert_fn=None,
+    stats=None,
+    matmul=moelib.grouped_matmul_reference,
+    carry: Optional[dict] = None,
 ) -> jax.Array:
     nh, rank = cfg.num_heads, cfg.kv_lora_rank
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -336,8 +504,10 @@ def layer_forward(
 
     h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
     # -- queries
+    cq = None
     if cfg.q_lora_rank > 0:
-        q = rms_norm(h @ p["w_dq"], p["q_norm"], cfg.rms_norm_eps) @ p["w_uq"]
+        cq = rms_norm(h @ p["w_dq"], p["q_norm"], cfg.rms_norm_eps)
+        q = cq @ p["w_uq"]
     else:
         q = h @ p["wq"]
     q = q.reshape(*lead, nh, nope + rope)
@@ -349,18 +519,24 @@ def layer_forward(
     k_pe = apply_rope(ckv[..., None, rank:], cos, sin)     # [..., 1, rope]
     # -- absorb W_uk into q: MQA over the latent
     q_abs = jnp.einsum("...hn,hnr->...hr", q_nope, p["w_uk"])
-    q_prime = jnp.concatenate([q_abs, q_pe], axis=-1)      # [..., nh, rank+rope]
-    # attend ops scale by 1/sqrt(rank+rope); MLA wants 1/sqrt(nope+rope)
-    q_prime = q_prime * math.sqrt((rank + rope) / (nope + rope))
-    k_prime = jnp.concatenate([c[..., None, :], k_pe], axis=-1)
-    cl = c[..., None, :]                                   # [..., 1, rank]
-    v_prime = jnp.pad(
-        cl, [(0, 0)] * (cl.ndim - 1) + [(0, rope)]
-    )
-    o = attend(
-        q_prime.astype(cfg.dtype), k_prime.astype(cfg.dtype),
-        v_prime.astype(cfg.dtype), layer_idx,
-    )                                                      # [..., nh, rank+rope]
+    if cfg.index_topk > 0:
+        o = _selected_attention(
+            p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin, attend,
+            layer_idx, carry,
+        )
+    else:
+        q_prime = jnp.concatenate([q_abs, q_pe], axis=-1)  # [..., nh, rank+rope]
+        # attend ops scale by 1/sqrt(rank+rope); MLA wants 1/sqrt(nope+rope)
+        q_prime = q_prime * math.sqrt((rank + rope) / (nope + rope))
+        k_prime = jnp.concatenate([c[..., None, :], k_pe], axis=-1)
+        cl = c[..., None, :]                               # [..., 1, rank]
+        v_prime = jnp.pad(
+            cl, [(0, 0)] * (cl.ndim - 1) + [(0, rope)]
+        )
+        o = attend(
+            q_prime.astype(cfg.dtype), k_prime.astype(cfg.dtype),
+            v_prime.astype(cfg.dtype), layer_idx,
+        )                                                  # [..., nh, rank+rope]
     # -- un-absorb W_uv past the softmax
     attn = jnp.einsum("...hr,hrv->...hv", o[..., :rank], p["w_uv"])
     x = x + attn.reshape(*lead, nh * cfg.v_head_dim) @ p["wo"]
@@ -369,7 +545,10 @@ def layer_forward(
     if _is_moe_layer(cfg, layer_idx):
         # routing indexes per token: flatten leading dims to [T, H]
         flat = h.reshape(-1, h.shape[-1])
-        return x + _moe_ffn(p, cfg, flat, expert_fn=expert_fn).reshape(h.shape)
+        y = _moe_ffn(
+            p, cfg, flat, expert_fn=expert_fn, stats=stats, matmul=matmul
+        )
+        return x + y.reshape(h.shape)
     return x + _dense_ffn(p, cfg, h)
 
 
@@ -382,14 +561,34 @@ def forward(
     lora: Optional[Callable] = None,
     inputs_embeds: Optional[jax.Array] = None,
     expert_fn=None,
+    stats=None,
+    matmul=moelib.grouped_matmul_reference,
 ) -> jax.Array:
+    """``stats`` (moe.RoutingStats): the one-chip grouped expert path counts
+    its routing into it, and a configuration with an indexer the keys its
+    real decode rows saw, scored and attended."""
     if lora is not None:
         raise NotImplementedError("LoRA is not supported for the MLA family")
     x = params["embed"][token_ids] if inputs_embeds is None else inputs_embeds
     cos, sin = rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
     cos, sin = cos[..., None, :], sin[..., None, :]
+    carry: dict = {}
     for i, layer in enumerate(params["layers"]):
-        x = layer_forward(layer, cfg, x, cos, sin, attend, i, expert_fn=expert_fn)
+        x = layer_forward(
+            layer, cfg, x, cos, sin, attend, i, expert_fn=expert_fn,
+            stats=stats, matmul=matmul, carry=carry,
+        )
+    if stats is not None and cfg.index_topk > 0:
+        # per decode row and layer: the keys it could see, those an indexer
+        # scored (selecting layers only) and those attended over
+        seen = jnp.where(
+            stats.decode_rows.reshape(-1), positions.reshape(-1) + 1, 0
+        )
+        n_sel = sum(_selects(cfg, i) for i in range(cfg.num_layers))
+        stats.add_selection(
+            seen.sum() * cfg.num_layers, seen.sum() * n_sel,
+            jnp.minimum(seen, cfg.index_topk).sum() * cfg.num_layers,
+        )
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 

@@ -290,11 +290,19 @@ class RoutingStats:
     ``valid`` [T] masks rows that are padding (a bucket's tail, an empty
     decode slot): they are multiplied, but no token needed them."""
 
-    def __init__(self, valid: Optional[jax.Array] = None):
+    def __init__(self, valid: Optional[jax.Array] = None,
+                 decode_rows: Optional[jax.Array] = None):
         self.valid = valid
+        # the rows that are decode rows (a mixed step's chunk is not):
+        # add_selection's sums are over these
+        self.decode_rows = valid if decode_rows is None else decode_rows
         self.counts: List[jax.Array] = []
+        self.selection: Optional[jax.Array] = None
 
     def add(self, topi: jax.Array, num_experts: int) -> None:
+        """``topi`` [T, K] expert of each assignment; one at or past
+        ``num_experts`` (a layer that holds a share marks the assignments
+        that land elsewhere so) is not counted: bincount drops it."""
         T, K = topi.shape
         weights = None
         if self.valid is not None:
@@ -303,22 +311,42 @@ class RoutingStats:
             jnp.bincount(topi.reshape(-1), weights=weights, length=num_experts)
         )
 
+    def add_selection(self, causal, scored, selected) -> None:
+        """A forward pass with learned sparse attention adds, once, the keys
+        its real decode rows could see, scored and attended, summed over
+        rows and layers (models/mla.py)."""
+        self.selection = jnp.stack([causal, scored, selected])
+
     def reduce(self) -> jax.Array:
-        """[3] float32: rows routed (T x K summed over layers), experts
-        touched (summed over layers), the largest count on one expert."""
+        """[3] float32: rows routed (T x K summed over layers; to the held
+        experts where the layer holds a share), experts touched (summed over
+        layers), the largest count on one expert. [6] with ``add_selection``'s
+        three behind them."""
         c = jnp.stack(self.counts)                       # [L, E]
-        return jnp.stack([c.sum(), (c > 0).sum(), c.max()]).astype(jnp.float32)
+        out = jnp.stack([c.sum(), (c > 0).sum(), c.max()]).astype(jnp.float32)
+        if self.selection is not None:
+            out = jnp.concatenate([out, self.selection.astype(jnp.float32)])
+        return out
 
 
 def moe_ffn_grouped(
     p: Params, cfg: MoeConfig, x: jax.Array, routed=None,
     stats: Optional[RoutingStats] = None,
     matmul=grouped_matmul_reference,
+    held: Optional[Tuple[int, int]] = None,
 ) -> jax.Array:
     """Sparse exact serving path (replicated experts), one path for every
     token count: route, sort the T*K assignments by expert, ONE grouped
     multiplication for gate and up and one for down over the sorted rows,
     weighted combine back per token.
+
+    ``held`` = (first, count): this layer holds ``count`` of the experts,
+    from ``first`` on, and its stacks hold only those (one chip's share of a
+    layer divided over chips). The router is the whole layer's: it chooses
+    among all ``num_experts`` and its weights are normalised over all K
+    chosen. Assignments that land on held experts are sorted to the front
+    and multiplied; the others are multiplied by nothing and add nothing
+    (their chips would have), and ``stats`` counts the held experts only.
 
     FLOPs are T*K*3HI vs the dense reference's T*E*3HI, and HBM reads touch
     each routed-to expert's weights once — at a decode batch about
@@ -333,8 +361,16 @@ def moe_ffn_grouped(
         topw, topi = routed if routed is not None else route(p, cfg, x)
     K = topi.shape[1]
     slots = p["w_gate"].shape[0]  # E, or E+R physical slots (replicas idle)
+    counted = cfg.num_experts
+    if held is not None:
+        # held experts 0..count-1; every other assignment is group ``count``,
+        # which sorts last and which no stack has
+        first, counted = held
+        topi = jnp.where(
+            (topi >= first) & (topi < first + counted), topi - first, counted
+        )
     if stats is not None:
-        stats.add(topi, cfg.num_experts)
+        stats.add(topi, counted)
     with jax.named_scope("moe_sort"):
         flat = topi.reshape(-1)                          # [T*K]
         order = jnp.argsort(flat)                        # stable: by expert
@@ -343,6 +379,9 @@ def moe_ffn_grouped(
     with jax.named_scope("moe_experts"):
         act = matmul(rows, (p["w_gate"], p["w_up"]), sizes)   # [T*K, I]
         out = matmul(act, (p["w_down"],), sizes)              # [T*K, H]
+        if held is not None:
+            # rows past the last group were not multiplied: unspecified
+            out = jnp.where((flat[order] < counted)[:, None], out, 0)
     with jax.named_scope("moe_combine"):
         back = out[jnp.argsort(order)].reshape(T, K, H)  # un-sort
         y = jnp.einsum(

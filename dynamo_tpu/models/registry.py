@@ -53,6 +53,39 @@ def check_pp_supported(cfg) -> None:
         )
 
 
+def counts_routing(cfg) -> bool:
+    """Whether the family's one-chip forward takes a ``stats`` collector
+    (moe.RoutingStats): the grouped expert path of MoeConfig, and of an
+    MlaConfig with experts."""
+    return is_moe(cfg) or (is_mla(cfg) and cfg.num_experts > 0)
+
+
+def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
+                        kv_quantized=False, vision=False) -> None:
+    """A configuration with learned sparse attention (or one that holds a
+    share of its experts) runs on the one-chip text path; what it cannot do
+    yet is refused here, at engine construction, each with its reason."""
+    if not is_mla(cfg) or not (cfg.index_topk > 0 or cfg.experts_held):
+        return
+    what = "learned sparse attention / a held share of the experts (MlaConfig)"
+    refusals = [
+        (tp > 1, "tp > 1: the latent's rows are one head's and cannot shard "
+                 "on heads, and a held share is already one chip's of a "
+                 "layer divided over chips (the exchange is not built)"),
+        (pp > 1 or sp > 1, "pp / sp > 1: neither the wavefront nor the ring "
+                           "carries a selection from layer to layer"),
+        (spec, "a speculative draft: verify rows have no selected-positions "
+               "question in the attention seam yet"),
+        (lora, "LoRA: the MLA family has no adapter path"),
+        (kv_quantized, "kv_dtype=int8: the token-granular kernel reads bf16 "
+                       "rows; an 8-bit latent needs its scales a token"),
+        (vision, "vision: multimodal serving covers the dense family only"),
+    ]
+    for hit, why in refusals:
+        if hit:
+            raise ValueError(f"{what} does not run with {why}")
+
+
 def family(cfg):
     if is_mla(cfg):
         return mla
@@ -124,7 +157,13 @@ def forward_fn(cfg, mesh=None, use_pallas: bool = False,
         return partial(gptoss.forward, expert_fn=gptoss_expert_fn)
     if is_mla(cfg):
         if cfg.num_experts == 0 or mesh is None or mesh.shape.get(AXIS_TP, 1) == 1:
-            # token-sorted grouped path (exact, sparse) on replicated experts
+            # token-sorted grouped path (exact, sparse) on replicated (or
+            # held) experts; its multiplication as for MoeConfig below
+            if use_pallas and cfg.num_experts > 0:
+                return partial(
+                    mla.forward,
+                    matmul=partial(grouped_matmul, interpret=interpret),
+                )
             return mla.forward
 
         # EP: expert stacks shard on the expert dim over the tp axis (same

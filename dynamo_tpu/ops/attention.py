@@ -22,6 +22,7 @@ against, float and int8 alike.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Tuple
 
 import jax
@@ -430,3 +431,126 @@ def paged_extend_attention(
         )
 
     return jax.vmap(one)(q, block_tables, start_pos, total_lens)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention over a paged latent (models/mla.py, DSA)
+# ---------------------------------------------------------------------------
+#
+# Layout of a latent layer's two paged arrays, both ``[num_blocks, block_size,
+# rows, 128]`` (``rows = max(kv_lora_rank / 128, 2)``, the engine's
+# ``num_kv_heads``; 128 lanes a row, so a token of either array is whole
+# Mosaic tiles and can be copied alone):
+#   K array: the normalised latent ``c``, ``kv_lora_rank / 128`` rows a token;
+#   V array: row 0 ``[k_pe | 0]`` (the rotated shared key), row 1 the index
+#            key ``[kI | 0]`` on layers with an indexer; further rows unused.
+# Values are the latent itself (``W_uv`` is applied past the softmax), so the
+# V array holds no values: it is the layer's second kind of per-token state,
+# under the same block ids as the first.
+
+LATENT_LANES = 128
+SEL_NONE = -1     # padding of a query's list of selected positions
+
+
+@dataclasses.dataclass
+class DsaQuery:
+    """What a latent layer asks of the attention seam besides q, k and v.
+
+    ``index_q`` [..., n, d] / ``index_w`` [..., n] (the indexer's queries and
+    its head weights, already scaled) are set on a layer that selects; the
+    seam scores them against the paged index keys, takes the ``topk`` largest
+    causal scores a query and leaves the positions in ``selected`` [Tq, K]
+    (``SEL_NONE`` pads). A layer that shares a selection hands the one it
+    inherited in ``selected`` and no ``index_q``. A trace-time object: it
+    lives for one forward pass of one program."""
+
+    scale: float                       # softmax scale, 1/sqrt(qk_head_dim)
+    topk: int
+    index_q: Optional[jax.Array] = None
+    index_w: Optional[jax.Array] = None
+    selected: Optional[jax.Array] = None
+
+
+def dsa_index_scores(iq: jax.Array, iw: jax.Array, keys: jax.Array) -> jax.Array:
+    """``I[q, t] = sum_j iw[q, j] relu(iq[q, j] . keys[t])``: iq [Q, n, d],
+    iw [Q, n] f32, keys [T, d] -> [Q, T] f32. All heads at once while the
+    per-head scores are small, else head by head (a chunk against a long
+    context: the [Q, n, T] scores would not fit)."""
+    Q, n, _ = iq.shape
+    T = keys.shape[0]
+    if Q * n * T * 4 <= 2 ** 28:
+        s = jnp.einsum("qjd,td->qjt", iq, keys,
+                       preferred_element_type=jnp.float32)
+        # a float32 sum, not a product on the matrix unit (which would round
+        # the scores to bf16 first and move the cut)
+        return jnp.sum(jax.nn.relu(s) * iw[:, :, None], axis=1)
+
+    def head(acc, j):
+        s = jnp.einsum("qd,td->qt", iq[:, j], keys,
+                       preferred_element_type=jnp.float32)
+        return acc + jax.nn.relu(s) * iw[:, j, None], None
+
+    acc, _ = jax.lax.scan(head, jnp.zeros((Q, T), jnp.float32), jnp.arange(n))
+    return acc
+
+
+def dsa_select(scores: jax.Array, q_pos: jax.Array, q_valid: jax.Array,
+               topk: int) -> jax.Array:
+    """Exact top-k of the causal scores: scores [Q, T], q_pos [Q] (a query
+    sees keys ``t <= q_pos``) -> [Q, min(topk, T)] int32 positions, the
+    selected ones first, ``SEL_NONE`` after them. Every causal position is
+    selected while there are at most ``topk`` of them."""
+    T = scores.shape[1]
+    seen = (jnp.arange(T)[None, :] <= q_pos[:, None]) & q_valid[:, None]
+    _, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), min(topk, T))
+    idx = idx.astype(jnp.int32)
+    ok = (idx <= q_pos[:, None]) & q_valid[:, None]
+    return jnp.where(ok, idx, SEL_NONE)
+
+
+def paged_index_keys(v_cache: jax.Array, tables: jax.Array, dim: int) -> jax.Array:
+    """The index keys (their first ``dim`` lanes) of each table's context:
+    [R, max_blocks * bs, dim]."""
+    keys = v_cache[tables, :, 1, :dim]               # [R, mb, bs, dim]
+    return keys.reshape(tables.shape[0], -1, dim)
+
+
+def selected_token_rows(tables: jax.Array, rows: jax.Array, sel: jax.Array,
+                        bs: int) -> jax.Array:
+    """Selected absolute positions -> token rows of the flattened paged
+    arrays (``block * bs + offset``), through each query's block table:
+    tables [R, mb], rows [Tq] (the table of each query), sel [Tq, K]. Padding
+    maps to token 0 (the scratch page); the caller masks it."""
+    pos = jnp.maximum(sel, 0)
+    blocks = tables[rows[:, None], pos // bs]
+    return blocks * bs + pos % bs
+
+
+def sparse_latent_attention(
+    q: jax.Array,            # [Tq, h, rank + 128]: [absorbed q | q_pe | 0]
+    k_cache: jax.Array,      # [nb, bs, rows, 128] the latent
+    v_cache: jax.Array,      # [nb, bs, rows, 128] row 0 = [k_pe | 0]
+    tables: jax.Array,       # [R, mb]
+    rows: jax.Array,         # [Tq] the table of each query
+    sel: jax.Array,          # [Tq, K] selected positions, SEL_NONE pads
+    scale: float,
+) -> jax.Array:
+    """MQA of every head of a query over ITS selected latent rows; values
+    are the rows' latent. Returns [Tq, h, rank]; a query with nothing
+    selected returns zeros. The pure-JAX twin of
+    ``ops.pallas_sparse.sparse_latent_attention``."""
+    nb, bs, r, lanes = k_cache.shape
+    rank = q.shape[-1] - lanes
+    tok = selected_token_rows(tables, rows, sel, bs)             # [Tq, K]
+    c = k_cache.reshape(nb * bs, r * lanes)[tok][..., :rank]     # [Tq, K, rank]
+    pe = v_cache.reshape(nb * bs, r, lanes)[tok, 0]              # [Tq, K, 128]
+    keys = jnp.concatenate([c, pe], axis=-1)
+    s = jnp.einsum("qhd,qkd->qhk", q, keys,
+                   preferred_element_type=jnp.float32) * scale
+    ok = (sel >= 0)[:, None, :]
+    s = jnp.where(ok, s, NEG_INF)
+    p = jnp.where(ok, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    denom = jnp.sum(p, axis=-1, keepdims=True)
+    p = p / jnp.where(denom > 0, denom, 1.0)
+    out = jnp.einsum("qhk,qkr->qhr", p, c.astype(jnp.float32))
+    return out.astype(q.dtype)

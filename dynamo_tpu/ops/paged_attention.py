@@ -14,12 +14,21 @@ straight through.
   (ops/pallas_attention.py): PERF.md section 6, PR 28 has the chip runs in
   which the ragged kernel's one-token rows read level by every median and not
   by their tail.
-- Pallas off (CPU, pp, latent attention, the families off the auto rule): the
+- Pallas off (CPU, pp, a 576-lane latent, the families off the auto rule): the
   pure-JAX twins of ops/attention.py, which are also the tests' reference.
+- A layer that hands a ``dsa`` (ops/attention.DsaQuery: latent attention over
+  the positions a learned indexer selects, models/mla.py) asks ONE further
+  question of the same rows, ``_selected``: score the indexer against the
+  paged index keys (or take the selection the layer inherited), then attend
+  each query over its own selected token rows. Pallas on, that is the launch
+  ``sparse_latent_attention`` (ops/pallas_sparse.py) for decode rows, a chunk
+  and a mixed step alike; the scoring and the top-k stay XLA under the scopes
+  ``dsa_index`` and ``dsa_select``.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
@@ -51,9 +60,55 @@ class PagedAttention:
             interpret=self.interpret, **kw,
         )
 
-    def decode(self, q, kc, vc, tables, seq_lens, **extra):
+    def _selected(self, q, kc, vc, tables, rows, q_pos, q_valid, dsa,
+                  n_chunk):
+        """Attention of packed queries ``q [Tq, h, rank + 128]`` over the
+        token positions each selected: query ``i`` sits at ``q_pos[i]`` of
+        the context of ``tables[rows[i]]`` (``q_valid`` false = padding or
+        an empty row: nothing selected, zeros back). The first ``n_chunk``
+        queries are one row's chunk and are scored against that row's keys
+        in one product; every later query is a row of its own."""
+        if dsa.selected is None:
+            Tq = q.shape[0]
+            iq = dsa.index_q.reshape(Tq, *dsa.index_q.shape[-2:])
+            iw = dsa.index_w.reshape(Tq, -1)
+            with jax.named_scope("dsa_index"):
+                keys = att.paged_index_keys(vc, tables, iq.shape[-1])
+                parts = []
+                if n_chunk:
+                    parts.append(att.dsa_index_scores(
+                        iq[:n_chunk], iw[:n_chunk], keys[0]
+                    ))
+                if Tq > n_chunk:
+                    one = lambda a, b, k: att.dsa_index_scores(  # noqa: E731
+                        a[None], b[None], k
+                    )[0]
+                    parts.append(jax.vmap(one)(
+                        iq[n_chunk:], iw[n_chunk:], keys[bool(n_chunk):]
+                    ))
+                scores = jnp.concatenate(parts, axis=0)
+            with jax.named_scope("dsa_select"):
+                dsa.selected = att.dsa_select(scores, q_pos, q_valid, dsa.topk)
+        with jax.named_scope("sparse_attend"):
+            if not self.use_pallas:
+                return att.sparse_latent_attention(
+                    q, kc, vc, tables, rows, dsa.selected, dsa.scale
+                )
+            from . import pallas_sparse as ps
+
+            return ps.sparse_latent_attention(
+                q, kc, vc, tables, rows, dsa.selected, scale=dsa.scale,
+                interpret=self.interpret,
+            )
+
+    def decode(self, q, kc, vc, tables, seq_lens, dsa=None, **extra):
         """Decode rows: ``q [B, h, d]``, one token a row at the end of a
         context of ``seq_lens[b]`` tokens (0 = an empty row)."""
+        if dsa is not None:
+            return self._selected(
+                q, kc, vc, tables, jnp.arange(q.shape[0]), seq_lens - 1,
+                seq_lens > 0, dsa, 0,
+            )
         if not self.use_pallas:
             return att.paged_decode_attention(
                 q, kc, vc, tables, seq_lens, **extra
@@ -71,10 +126,16 @@ class PagedAttention:
         )
 
     def chunk(self, q, kc, vc, table, chunk_start, total_len, positions,
-              **extra):
+              dsa=None, **extra):
         """One chunk at its context's tail: ``q [S_pad, h, d]`` at absolute
         ``positions``, the real ones ``chunk_start .. total_len - 1``, over
         ONE ``table``; the chunk's own keys are already in the cache."""
+        if dsa is not None:
+            S = q.shape[0]
+            return self._selected(
+                q, kc, vc, table[None], jnp.zeros((S,), jnp.int32),
+                positions, positions < total_len, dsa, S,
+            )
         if not self.use_pallas:
             k_ctx, v_ctx = att.gather_kv(kc, vc, table)
             return att.extend_attention(
@@ -85,14 +146,30 @@ class PagedAttention:
             (total_len - chunk_start)[None], total_len[None], **extra,
         )
 
-    def ragged(self, q, kc, vc, tables, q_starts, q_lens, seq_lens, **extra):
+    def ragged(self, q, kc, vc, tables, q_starts, q_lens, seq_lens,
+               dsa=None, **extra):
         """Ragged rows over a packed ``q [Tq, h, d]``: row ``r`` owns
         ``q[q_starts[r] : q_starts[r] + q_lens[r]]`` at the tail of its
-        context (ops/attention.ragged_paged_attention has the contract)."""
+        context (ops/attention.ragged_paged_attention has the contract).
+        With a ``dsa`` the rows are the mixed step's: row 0 a chunk at the
+        front of ``q``, every further row one token behind it."""
+        if dsa is not None:
+            Tq, R = q.shape[0], tables.shape[0]
+            n_chunk = Tq - (R - 1)
+            i = jnp.arange(Tq)
+            rows = jnp.maximum(i - n_chunk + 1, 0)
+            local = i - q_starts[rows]
+            return self._selected(
+                q, kc, vc, tables, rows,
+                seq_lens[rows] - q_lens[rows] + local,
+                (local < q_lens[rows]) & (seq_lens[rows] > 0), dsa, n_chunk,
+            )
         launch = self._launch if self.use_pallas else att.ragged_paged_attention
         return launch(q, kc, vc, tables, q_starts, q_lens, seq_lens, **extra)
 
     def verify(self, q, kc, vc, tables, seq_lens, **extra):
+        # no ``dsa``: a family with an indexer is refused a speculative
+        # draft at construction (models/registry.check_dsa_supported)
         """Ragged rows of one static length: ``q [B, n, h, d]``, row ``b``'s
         ``n`` tokens at the tail of a context of ``seq_lens[b]`` (0 = an
         empty row). The pure-JAX side keeps the batched extend op: the

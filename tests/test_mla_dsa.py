@@ -1,0 +1,353 @@
+"""Learned sparse attention over a paged latent (models/mla.py with an
+indexer; GLM-5.2's mechanism) and an expert layer that holds one chip's
+share, at a test's size, against the benchmark's plain float32 reference
+(benchmarks/reference/mla_dsa_decoder.py): the seam's new question (pure-JAX
+twin and interpreted kernel), the engine (chunked prefill, mixed steps,
+decode through the paged cache, a prefix hit), the share, the counters and
+what the family refuses at construction.
+"""
+
+import asyncio
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import system
+from benchmarks.adapters import mla_dsa as adapter
+from benchmarks.reference import mla_dsa_decoder as ref
+from dynamo_tpu.engine.engine import TpuEngine, TpuEngineConfig
+from dynamo_tpu.models import mla, moe as moelib
+from dynamo_tpu.ops import attention as att
+from dynamo_tpu.ops.paged_attention import PagedAttention
+from dynamo_tpu.parallel.mesh import make_mesh
+
+TOPK = 16
+
+
+def file_cfg(dtype="float32", **kw):
+    """A configuration file's dict (the public keys) at a test's size: layer
+    kinds dense-full, sparse-shared, sparse-full; 8 experts, 4 held."""
+    cfg = {
+        "vocab_size": 512, "hidden_size": 128, "num_hidden_layers": 3, "layer_offset": 2,
+        "num_attention_heads": 4, "intermediate_size": 256, "rms_norm_eps": 1e-5,
+        "max_position_embeddings": 4096, "tie_word_embeddings": False, "torch_dtype": dtype,
+        "q_lora_rank": 96, "kv_lora_rank": 256, "qk_nope_head_dim": 32, "qk_rope_head_dim": 16,
+        "v_head_dim": 32, "router_outputs": 8, "n_routed_experts": 4, "experts_held_first": 4,
+        "num_experts_per_tok": 2, "moe_intermediate_size": 64, "norm_topk_prob": True,
+        "routed_scaling_factor": 2.5, "n_shared_experts": 1, "n_group": 1,
+        "scoring_func": "sigmoid", "topk_method": "noaux_tc", "rope_interleave": True,
+        "indexer_rope_interleave": True,
+        "rope_parameters": {"rope_theta": 10000.0, "rope_type": "default"},
+        "index_topk": TOPK, "index_n_heads": 4, "index_head_dim": 32,
+        "indexer_types": ["full", "full", "full", "shared", "full"],
+        "mlp_layer_types": ["dense", "dense", "dense", "sparse", "sparse"],
+        "reference_tolerance": {"worst_nat": 2e-3, "mean_nat": 5e-4},
+    }
+    cfg.update(kw)
+    return cfg
+
+
+def engine_of(cfg, use_pallas=None, **kw):
+    opts = dict(num_blocks=64, block_size=16, max_batch_size=4, max_context=128,
+                prefill_buckets=(16, 32), seed=3, use_pallas=use_pallas,
+                mixed_admission=True if use_pallas else None)
+    opts.update(kw)
+    return TpuEngine(TpuEngineConfig(model=adapter.model_config(cfg), **opts))
+
+
+async def generate(engine, prompts, n_out=20):
+    recs = await asyncio.gather(*[
+        system.generate(engine, f"r{i}", p, n_out) for i, p in enumerate(prompts)
+    ])
+    return recs, [
+        {"prompt": p, "tokens": r["tokens"], "logprobs": r["logprobs"]}
+        for p, r in zip(prompts, recs)
+    ]
+
+
+def prompts_of(*lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 512, n).tolist() for n in lengths]
+
+
+# ----------------------------------------------------------- the engine
+async def test_chunked_prefill_and_decode_match_the_reference_in_float32():
+    """Pure-JAX twin, float32: prompts of 72, 40 and 20 tokens (contexts to
+    92, above and below index_topk) in chunks of 32, then 20 decoded tokens
+    through the paged cache, against the reference's one full forward."""
+    cfg = file_cfg()
+    engine = engine_of(cfg)
+    steps = []
+    engine.stats_hook = steps.append
+    try:
+        _, samples = await generate(engine, prompts_of(72, 40, 20))
+        params = adapter.reference_params(engine)
+        res = ref.compare(cfg, params, samples, 128)
+        assert res["ok"], res
+        assert res["tokens_compared"] == 60
+        # each of the family's own mistakes is far outside those bounds
+        for wrong in ({"dense_attention": True}, {"shared": "none"}, {"shared": "own"},
+                      {"topk_scale": 0.5}, {"no_router_bias": True},
+                      {"no_routed_scale": True}, {"no_shared_expert": True},
+                      {"skip_layer": 1}):
+            bad = ref.compare(cfg, params, samples, 128, **wrong)
+            assert not bad["ok"] and bad["worst_logprob_difference_nat"] > 0.02, (wrong, bad)
+    finally:
+        engine.stop()
+    counted = [s for s in steps if s.dsa_keys_causal is not None]
+    assert counted and all(s.phase in ("decode", "mixed") for s in counted)
+    for s in counted:
+        # 3 layers of which 2 select; a row attends over min(context, 16) keys
+        assert s.dsa_keys_scored * 3 == s.dsa_keys_causal * 2
+        assert 0 < s.dsa_keys_selected <= s.dsa_keys_causal
+        assert s.moe_held_experts_touched == s.moe_experts_touched <= 2 * 4 * 8
+    assert all(s.dsa_keys_causal is None for s in steps if s.phase == "prefill")
+
+
+async def test_the_interpreted_kernel_serves_mixed_steps_in_bfloat16():
+    """use_pallas forced on the CPU: the launch sparse_latent_attention and
+    the Pallas expert multiplication run interpreted, chunks ride fused
+    mixed steps. bf16 against the float32 reference moves a tiny model's
+    logprobs by a few hundredths; the selection ignored moves them by a nat."""
+    cfg = file_cfg("bfloat16", reference_tolerance={"worst_nat": 0.7, "mean_nat": 0.07})
+    engine = engine_of(cfg, use_pallas=True)
+    steps = []
+    engine.stats_hook = steps.append
+    try:
+        assert engine.use_pallas and engine.mixed_enabled and engine.kernels_interpreted
+        _, samples = await generate(engine, prompts_of(72, 40, 20))
+        params = adapter.reference_params(engine)
+        res = ref.compare(cfg, params, samples, 128)
+        assert res["ok"], res
+        bad = ref.compare(cfg, params, samples, 128, dense_attention=True)
+        assert not bad["ok"], bad
+    finally:
+        engine.stop()
+    assert {"mixed", "decode"} <= {s.phase for s in steps}
+
+
+async def test_a_prefix_hit_restores_the_index_keys():
+    """The second request's prefix comes from the cache: latent rows and
+    index keys under the same block ids, so it selects what a cold prefill
+    selects and emits the same tokens at the same logprobs."""
+    cfg = file_cfg()
+    engine = engine_of(cfg)
+    try:
+        (prompt,) = prompts_of(80, seed=5)
+        (cold,), _ = await generate(engine, [prompt], 12)
+        (warm,), _ = await generate(engine, [prompt], 12)
+        assert (cold["cached_tokens"] or 0) == 0 and warm["cached_tokens"] >= 64
+        assert warm["tokens"] == cold["tokens"]
+        np.testing.assert_allclose(warm["logprobs"], cold["logprobs"], atol=1e-5)
+    finally:
+        engine.stop()
+
+
+@pytest.mark.parametrize("what,kw", [
+    ("tp > 1", dict(tp=2)),
+    ("kv_dtype=int8", dict(kv_dtype="int8")),
+    ("a speculative draft", dict(spec_draft=mla.MlaConfig.tiny_mla(vocab_size=512))),
+    ("LoRA", dict(lora_max_adapters=2)),
+    ("pp / sp > 1", dict(sp=2)),
+])
+def test_what_the_family_cannot_do_yet_is_refused_at_construction(what, kw):
+    mesh = None
+    if "tp" in kw or "sp" in kw:
+        mesh = make_mesh(tp=kw.get("tp", 1), sp=kw.get("sp", 1), devices=jax.devices()[:2])
+    with pytest.raises(ValueError, match=what.replace(">", ".")):
+        TpuEngine(TpuEngineConfig(
+            model=adapter.model_config(file_cfg()), num_blocks=32, block_size=16,
+            max_batch_size=2, max_context=64, prefill_buckets=(16,), **kw,
+        ), mesh=mesh)
+
+
+def test_a_configuration_without_an_indexer_keeps_the_576_lane_contract():
+    cfg = mla.MlaConfig.deepseek_v3()
+    assert (cfg.num_kv_heads, cfg.head_dim, cfg.index_topk) == (1, 576, 0)
+    dsa = adapter.model_config(file_cfg())
+    assert (dsa.num_kv_heads, dsa.head_dim) == (2, 128)
+    with pytest.raises(ValueError, match="multiple of 256"):
+        mla.MlaConfig.tiny_mla_dsa(kv_lora_rank=192)
+    with pytest.raises(ValueError, match="first layer selects"):
+        mla.MlaConfig.tiny_mla_dsa(indexer_types=("shared", "full", "full"))
+
+
+# ------------------------------------------------------------ the share
+def test_the_shares_add_up_to_the_uncut_layer():
+    """model-configs guide, section 4: the routed parts both shares of 4
+    give, plus the shared expert once, are the uncut reference's whole
+    expert layer; and the router's weights are the uncut layer's."""
+    cfg = adapter.model_config(file_cfg(n_routed_experts=8, experts_held_first=0))
+    assert cfg.experts_held is None
+    lp = mla.init_layer_params(jax.random.PRNGKey(7), cfg, 1)
+    lp["router_bias"] = 0.1 * jax.random.normal(jax.random.PRNGKey(8), (8,))
+    x = jax.random.normal(jax.random.PRNGKey(9), (24, 128), jnp.float32)
+    h = mla.rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+    topw, topi = mla.route(lp, cfg, h)
+    weights = np.zeros((24, 8), np.float32)
+    np.put_along_axis(weights, np.asarray(topi), np.asarray(topw), axis=1)
+    with jax.default_matmul_precision("highest"):
+        want = ref._route(h, lp["w_router"], lp["router_bias"], top_k=2, renorm=True, scaling=2.5)
+    np.testing.assert_allclose(weights, np.asarray(want), atol=1e-6)
+
+    whole = ref._experts(lp, x, top_k=2, eps=cfg.rms_norm_eps, renorm=True, scaling=2.5, first=0)
+    routed = jnp.zeros_like(x)
+    rows = 0
+    for first in (0, 4):
+        share = {k: v[first:first + 4] for k, v in mla.expert_params(lp).items()}
+        stats = moelib.RoutingStats()
+        routed += moelib.moe_ffn_grouped(
+            share, cfg, h, routed=(topw, topi), stats=stats, held=(first, 4)
+        )
+        rows += int(stats.reduce()[0])
+    assert rows == 24 * 2                      # every assignment lands on one share
+    sg = jax.nn.silu(h @ lp["w_shared_gate"])
+    summed = x + routed + (sg * (h @ lp["w_shared_up"])) @ lp["w_shared_down"]
+    np.testing.assert_allclose(np.asarray(summed), np.asarray(whole), atol=2e-5)
+    # and the program's own layer with a share is the reference's with that share
+    held = adapter.model_config(file_cfg())
+    lp_held = dict(lp, **{k: lp[k][4:8] for k in ("w_egate", "w_eup", "w_edown")})
+    got = x + mla._moe_ffn(lp_held, held, h)
+    part = ref._experts(lp_held, x, top_k=2, eps=cfg.rms_norm_eps, renorm=True, scaling=2.5, first=4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(part), atol=2e-5)
+
+
+def test_a_share_without_rows_adds_nothing_and_counts_nothing():
+    cfg = adapter.model_config(file_cfg())
+    lp = mla.init_layer_params(jax.random.PRNGKey(1), cfg, 1)
+    assert lp["w_egate"].shape[0] == 4 and lp["w_router"].shape[1] == 8
+    x = jax.random.normal(jax.random.PRNGKey(2), (6, 128), jnp.float32)
+    routed = (jnp.full((6, 2), 0.5), jnp.tile(jnp.array([[0, 3]]), (6, 1)))
+    stats = moelib.RoutingStats()
+    y = moelib.moe_ffn_grouped(mla.expert_params(lp), cfg, x, routed=routed, stats=stats, held=(4, 4))
+    assert float(jnp.abs(y).max()) == 0.0
+    assert stats.reduce().tolist() == [0.0, 0.0, 0.0]
+
+
+# -------------------------------------------------- the seam's new question
+NB, BS, ROWS, H, RANK, MB = 24, 16, 2, 4, 256, 6
+
+
+def _paged(seed=0, dtype=jnp.bfloat16):
+    rng = np.random.default_rng(seed)
+    kc = jnp.asarray(rng.normal(size=(NB, BS, ROWS, 128)), dtype)
+    vc = jnp.asarray(rng.normal(size=(NB, BS, ROWS, 128)), dtype)
+    tables = jnp.asarray(rng.permutation(np.arange(1, NB))[:3 * MB].reshape(3, MB), jnp.int32)
+    return rng, kc, vc, tables
+
+
+def _dsa(rng, lead, selected=None, dtype=jnp.bfloat16):
+    return att.DsaQuery(
+        scale=0.125, topk=TOPK,
+        index_q=None if selected is not None else jnp.asarray(rng.normal(size=(*lead, 4, 32)), dtype),
+        index_w=None if selected is not None else jnp.asarray(rng.normal(size=(*lead, 4)), jnp.float32),
+        selected=selected,
+    )
+
+
+@pytest.fixture(scope="module")
+def seams():
+    mesh = make_mesh(tp=1, devices=jax.devices()[:1])
+    return PagedAttention(mesh, False), PagedAttention(mesh, True, interpret=True)
+
+
+def _both(seams, ask):
+    """Ask twin and interpreted kernel the same question with the same
+    indexer inputs; they select alike (the scoring is shared XLA)."""
+    outs, sels = [], []
+    for seam in seams:
+        dsa, *args = ask()
+        outs.append(np.asarray(seam_call(seam, dsa, *args), np.float32))
+        sels.append(np.asarray(dsa.selected))
+    np.testing.assert_array_equal(sels[0], sels[1])
+    np.testing.assert_allclose(outs[1], outs[0], atol=2e-2, rtol=2e-2)
+    return outs[0], sels[0]
+
+
+def seam_call(seam, dsa, kind, *args):
+    return getattr(seam, kind)(*args, dsa=dsa)
+
+
+def test_decode_rows_select_and_attend(seams):
+    """Contexts below and above index_topk, one of exactly a page, an empty
+    row; selections cross page edges (positions 0..89 over 16-token pages)."""
+    seq_lens = jnp.asarray([90, 5, 0], jnp.int32)
+
+    def ask():
+        rng, kc, vc, tables = _paged(1)
+        q = jnp.asarray(rng.normal(size=(3, H, RANK + 128)), jnp.bfloat16)
+        return _dsa(rng, (3,)), "decode", q, kc, vc, tables, seq_lens
+
+    out, sel = _both(seams, ask)
+    assert (sel[0] >= 0).sum() == TOPK and len({p // BS for p in sel[0]}) > 1
+    assert sorted(sel[1][sel[1] >= 0]) == list(range(5))      # every causal key
+    assert (sel[2] == att.SEL_NONE).all() and not out[2].any()
+
+
+def test_a_chunk_selects_query_by_query(seams):
+    """A chunk of 32 at positions 40..65 (26 real, 6 padding rows at the
+    context's far end): each real query its own top 16 of its causal keys."""
+    positions = jnp.asarray(list(range(40, 66)) + [95] * 6, jnp.int32)
+
+    def ask():
+        rng, kc, vc, tables = _paged(2)
+        q = jnp.asarray(rng.normal(size=(32, H, RANK + 128)), jnp.bfloat16)
+        return (_dsa(rng, (32,)), "chunk", q, kc, vc, tables[0], jnp.int32(40),
+                jnp.int32(66), positions)
+
+    out, sel = _both(seams, ask)
+    for i in range(26):
+        assert (sel[i] >= 0).sum() == TOPK and sel[i].max() <= 40 + i
+    assert (sel[26:] == att.SEL_NONE).all() and not out[26:].any()
+
+
+def test_a_mixed_step_and_an_inherited_selection(seams):
+    """Row 0 a chunk of 16 (12 real) behind which two decode rows ride; then
+    the same rows with the selection inherited, as a shared layer asks."""
+    q_starts = jnp.asarray([0, 16, 17], jnp.int32)
+    q_lens = jnp.asarray([12, 1, 0], jnp.int32)
+    seq_lens = jnp.asarray([60, 33, 0], jnp.int32)
+
+    def ask(selected=None):
+        rng, kc, vc, tables = _paged(3)
+        q = jnp.asarray(rng.normal(size=(18, H, RANK + 128)), jnp.bfloat16)
+        return (_dsa(rng, (18,), selected), "ragged", q, kc, vc, tables, q_starts,
+                q_lens, seq_lens)
+
+    out, sel = _both(seams, ask)
+    assert all(sel[i].max() == 48 + i or sel[i].max() <= 48 + i for i in range(12))
+    assert (sel[12:16] == att.SEL_NONE).all() and (sel[17] == att.SEL_NONE).all()
+    assert (sel[16] >= 0).sum() == TOPK and sel[16].max() <= 32
+    again, _ = _both(seams, lambda: ask(jnp.asarray(sel)))
+    np.testing.assert_array_equal(again, out)
+
+
+def test_exact_top_k_of_the_causal_scores():
+    scores = jnp.asarray(np.random.default_rng(0).normal(size=(3, 40)), jnp.float32)
+    sel = np.asarray(att.dsa_select(scores, jnp.asarray([39, 9, 20]), jnp.asarray([True, True, False]), 16))
+    want = np.argsort(-np.asarray(scores[0]))[:16]
+    assert sorted(sel[0]) == sorted(want)
+    assert sorted(sel[1][sel[1] >= 0]) == list(range(10)) and (sel[2] == -1).all()
+    # head by head (a chunk against a long context) scores as all heads at once
+    rng = np.random.default_rng(1)
+    iq, iw, keys = rng.normal(size=(5, 4, 32)), rng.normal(size=(5, 4)), rng.normal(size=(70, 32))
+    want = np.einsum("qjt,qj->qt", np.maximum(np.einsum("qjd,td->qjt", iq, keys), 0), iw)
+    got = att.dsa_index_scores(jnp.asarray(iq, jnp.float32), jnp.asarray(iw, jnp.float32), jnp.asarray(keys, jnp.float32))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+
+
+def test_bf16_index_scores_move_a_few_keys_across_the_cut():
+    """What is honest and new in the tolerance: rounding the indexer's
+    inputs to bf16 changes which keys rank just inside index_topk."""
+    cfg = file_cfg()
+    engine = engine_of(cfg)
+    try:
+        params = adapter.reference_params(engine)
+    finally:
+        engine.stop()
+    (tokens,) = prompts_of(96, seed=3)
+    flips = ref.selection_flips(cfg, params, tokens)
+    assert flips["queries"] == 96 - TOPK
+    assert 0 <= flips["mean"] <= 2.0 and flips["worst"] <= TOPK / 2

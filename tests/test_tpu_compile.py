@@ -76,6 +76,40 @@ def _shapes(sharding, kvh=KVH, h=H):
     return s, cache, unified, rows, ids
 
 
+def sparse_shapes(tq, rows):
+    def build(sharding):
+        def s(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        page = s((14336, 16, 4, 128), BF)
+        return (s((tq, 64, 640), BF), page, page, s((rows, 1600), I32),
+                s((tq, 32, 128), BF), s((tq, 32), F32))
+    return build
+
+
+def _dsa(iq, iw):
+    from dynamo_tpu.ops.attention import DsaQuery
+
+    return DsaQuery(scale=1 / 16, topk=2048, index_q=iq, index_w=iw)
+
+
+def sparse_mixed(seam, q, kc, vc, tables, iq, iw):
+    R = tables.shape[0]
+    S = q.shape[0] - (R - 1)
+    q_starts = jnp.concatenate([jnp.zeros((1,), I32), S + jnp.arange(R - 1, dtype=I32)])
+    q_lens = jnp.concatenate([jnp.full((1,), S, I32), jnp.ones((R - 1,), I32)])
+    return seam.ragged(q, kc, vc, tables, q_starts, q_lens,
+                       jnp.full((R,), 25000, I32), dsa=_dsa(iq, iw))
+
+
+def sparse_decode(seam, q, kc, vc, tables, iq, iw):
+    return seam.decode(q, kc, vc, tables, jnp.full((q.shape[0],), 25000, I32),
+                       dsa=_dsa(iq, iw))
+
+
+sparse_mixed.asks_seam = sparse_decode.asks_seam = True
+
+
 def _cases():
     """name -> (fn, shape-args builder). Builders take the ShapeDtypeStruct
     factory so one table serves any sharding."""
@@ -202,6 +236,12 @@ def _cases():
         "grouped-matmul-down-rows128": grouped(128, 896, 2304, 1),
         "grouped-matmul-gate-up-rows4224": grouped(4224, 2304, 896, 2),
         "grouped-matmul-down-rows4224": grouped(4224, 896, 2304, 1),
+        # latent attention over selected keys at GLM-5.2's widths and the
+        # long-document cell's sizes: 64 heads, a 512-lane latent in rows of
+        # 128, 14336 pages, 2048 selected of 25600; a mixed step's 512 + 8
+        # queries, and eight decode rows with the indexer's scoring
+        "sparse-latent-mixed": (sparse_mixed, sparse_shapes(520, 9)),
+        "sparse-latent-decode": (sparse_decode, sparse_shapes(8, 8)),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),
