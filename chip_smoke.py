@@ -710,8 +710,13 @@ def _kernel_child() -> None:
     # latent attention over selected keys (models/mla.py with an indexer) at
     # GLM-5.2's widths: 64 absorbed heads over a 512-lane latent + rotary
     # key in rows of 128 lanes; a 128-token chunk at a 24 576-token context
-    # and three decode rows (24k keys, 300 keys: all selected, an empty
-    # row), each query over its own 2 048 selected token rows
+    # and decode rows of two other tables, each query over its own selected
+    # token rows: 2 048 of 24k keys (whole chunks, one wait each), and every
+    # shape of tail (all of 300 keys; 1, 17, 255, 256, 257 and 2 047 keys;
+    # an empty row). Once with the chunk's pages staged in VMEM, once with
+    # every query gathering: the same bytes in the same buffer, so bitwise
+    # the same; then 200 launches back to back: a miscounted semaphore shows
+    # on the chip only, as a hang or as a stale row
     from dynamo_tpu.ops import pallas_sparse as psp
 
     LNB, LMB, ctx, Sq, topk = 4864, 1600, 24576, 128, 2048
@@ -719,21 +724,38 @@ def _kernel_child() -> None:
     ltables = jnp.asarray(
         rng.permutation(LNB - 1)[: 3 * LMB].reshape(3, LMB) + 1, jnp.int32
     )
-    contexts = [ctx + i + 1 for i in range(Sq)] + [ctx, 300, 0]
+    tails = [ctx, 300, 1, 17, 255, 256, 257, 2047, 0]
+    contexts = [ctx + i + 1 for i in range(Sq)] + tails
     sel = np.full((len(contexts), topk), -1, np.int32)
     for i, n in enumerate(contexts):
         if n:
             sel[i, : min(n, topk)] = rng.permutation(n)[:topk]
-    sel_rows = jnp.asarray([0] * Sq + [1, 2, 0], jnp.int32)
+    sel_rows = jnp.asarray(
+        [0] * Sq + [1 + i % 2 for i in range(len(tails))], jnp.int32
+    )
     ql = rnd(len(contexts), 64, 640)
     sparse_args = (ql, lat, aux, ltables, sel_rows, jnp.asarray(sel))
-    got = psp.sparse_latent_attention(*sparse_args, scale=1 / 16)
+    got = psp.sparse_latent_attention(*sparse_args, scale=1 / 16, n_chunk=Sq)
     if np.asarray(got, np.float32)[-1].any():
         raise SystemExit("sparse_latent_attention: an empty row is not zeros")
     compare(
-        "sparse_latent_attention 24k keys, chunk + decode rows", got,
-        highest(att.sparse_latent_attention)(*sparse_args, 1 / 16),
+        "sparse_latent_attention 24k keys, staged chunk + decode rows, tails",
+        got, highest(att.sparse_latent_attention)(*sparse_args, 1 / 16),
     )
+    gathered = psp.sparse_latent_attention(*sparse_args, scale=1 / 16)
+    if not bool(jnp.all(got == gathered)):
+        raise SystemExit("sparse_latent_attention: staged pages and gathered "
+                         "tokens give different bits")
+    stale = sum(
+        jnp.any(psp.sparse_latent_attention(
+            *sparse_args, scale=1 / 16, n_chunk=n) != got)
+        for n in (Sq, 0) * 100
+    )
+    if int(stale):
+        raise SystemExit(f"sparse_latent_attention: {int(stale)} of 200 "
+                         "launches back to back differ from the first")
+    print("KERNEL sparse_latent_attention: staged and gathered bitwise equal, "
+          "200 launches back to back bitwise the first", flush=True)
 
     # block moves are copies: exact
     ids = jnp.asarray(rng.permutation(NB)[:32], jnp.int32)

@@ -520,7 +520,7 @@ def selected_token_rows(tables: jax.Array, rows: jax.Array, sel: jax.Array,
     """Selected absolute positions -> token rows of the flattened paged
     arrays (``block * bs + offset``), through each query's block table:
     tables [R, mb], rows [Tq] (the table of each query), sel [Tq, K]. Padding
-    maps to token 0 (the scratch page); the caller masks it."""
+    maps to the table's position 0, a real token row; the caller masks it."""
     pos = jnp.maximum(sel, 0)
     blocks = tables[rows[:, None], pos // bs]
     return blocks * bs + pos % bs

@@ -23,7 +23,11 @@ straight through.
   each query over its own selected token rows. Pallas on, that is the launch
   ``sparse_latent_attention`` (ops/pallas_sparse.py) for decode rows, a chunk
   and a mixed step alike; the scoring and the top-k stay XLA under the scopes
-  ``dsa_index`` and ``dsa_select``.
+  ``dsa_index`` and ``dsa_select``. The seam tells the launch how many of
+  its first queries are ONE row's chunk (``n_chunk``): the kernel stages
+  that row's pages in VMEM once and those queries pick their keys there,
+  where the table's width fits (a shape of the launch); decode rows, each a
+  row of its own, gather token by token from HBM.
 """
 
 from __future__ import annotations
@@ -98,7 +102,7 @@ class PagedAttention:
 
             return ps.sparse_latent_attention(
                 q, kc, vc, tables, rows, dsa.selected, scale=dsa.scale,
-                interpret=self.interpret,
+                n_chunk=n_chunk, interpret=self.interpret,
             )
 
     def decode(self, q, kc, vc, tables, seq_lens, dsa=None, **extra):

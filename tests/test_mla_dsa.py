@@ -324,6 +324,63 @@ def test_a_mixed_step_and_an_inherited_selection(seams):
     np.testing.assert_array_equal(again, out)
 
 
+def _scratch_operands(fn, *args):
+    """How many scratch buffers the launch's pallas_call asks for: 3 where
+    every chunk buffer is gathered, 6 where a row's pages are staged too."""
+    (call,) = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns[0].params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    return call.params["grid_mapping"].num_scratch_operands
+
+
+@pytest.mark.parametrize("filling,chunk", [
+    ("gathered", 32), ("gathered", 256), ("staged", 32), ("staged", 256), ("too-wide", 32),
+])
+def test_the_kernel_across_chunks_tails_and_both_fillings(monkeypatch, filling, chunk):
+    """``pallas_sparse.sparse_latent_attention`` interpreted, against the twin,
+    with ``K`` of 3 chunks: per-query counts 0, 1, a group's edge, chunk - 1,
+    chunk, chunk + 1, two chunks and a tail, ``K``; selections cross page
+    edges and rows. ``staged``: 8 chunk queries of row 0 whose pages are
+    copied into VMEM once, then 4 decode rows of other tables that gather,
+    in one launch, bitwise what the gather alone gives. ``too-wide``: the same
+    launch over tables of 6 200 pages, more than ``STAGED_VMEM_BYTES`` holds,
+    which takes the gather. The interpreter's semaphore is an int16 that
+    saturates at 32 767 elements (jax/_src/pallas/core.py) where a whole
+    chunk's wait asks for 131 072: tier-1 holds the answers, not the wait
+    count; chip_smoke.py holds that on the chip."""
+    from dynamo_tpu.ops import pallas_sparse as ps
+
+    monkeypatch.setattr(ps, "CHUNK", chunk)
+    K = 3 * chunk
+    nb = 2 + 3 * K // BS
+    rng = np.random.default_rng(chunk)
+    kc = jnp.asarray(rng.normal(size=(nb, BS, ROWS, 128)), jnp.bfloat16)
+    vc = jnp.asarray(rng.normal(size=(nb, BS, ROWS, 128)), jnp.bfloat16)
+    tables = rng.permutation(np.arange(1, nb))[:3 * K // BS].reshape(3, K // BS)
+    counts = [0, 1, 17, chunk - 1, chunk, chunk + 1, 2 * chunk + 5, K]
+    rows = [0, 1, 2, 0, 1, 2, 0, 1]
+    n_chunk = 0
+    if filling != "gathered":
+        counts, rows, n_chunk = counts + [K, chunk + 1, 0, chunk - 1], [0] * 8 + [1, 2, 1, 2], 8
+    if filling == "too-wide":
+        tables = np.pad(tables, ((0, 0), (0, 6200 - tables.shape[1])))
+    sel = np.full((len(counts), K), att.SEL_NONE, np.int32)
+    for i, n in enumerate(counts):
+        sel[i, :n] = rng.permutation(K)[:n]
+    q = jnp.asarray(rng.normal(size=(len(counts), H, RANK + 128)), jnp.bfloat16)
+    args = (q, kc, vc, jnp.asarray(tables, jnp.int32), jnp.asarray(rows, jnp.int32), jnp.asarray(sel))
+
+    def kernel(n_chunk):
+        return lambda *a: ps.sparse_latent_attention(*a, scale=0.125, n_chunk=n_chunk, interpret=True)
+
+    assert _scratch_operands(kernel(n_chunk), *args) == (6 if filling == "staged" else 3)
+    got = np.asarray(kernel(n_chunk)(*args), np.float32)
+    want = np.asarray(att.sparse_latent_attention(*args, 0.125), np.float32)
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+    assert not got[[i for i, n in enumerate(counts) if n == 0]].any()
+    if filling == "staged":
+        np.testing.assert_array_equal(got, np.asarray(kernel(0)(*args), np.float32))
+
+
 def test_exact_top_k_of_the_causal_scores():
     scores = jnp.asarray(np.random.default_rng(0).normal(size=(3, 40)), jnp.float32)
     sel = np.asarray(att.dsa_select(scores, jnp.asarray([39, 9, 20]), jnp.asarray([True, True, False]), 16))

@@ -110,6 +110,27 @@ def sparse_decode(seam, q, kc, vc, tables, iq, iw):
 sparse_mixed.asks_seam = sparse_decode.asks_seam = True
 
 
+def sparse_launch(mb):
+    """The kernel itself, 512 chunk queries + 8 decode rows, over tables of
+    ``mb`` pages: 4 096 pages of 16 tokens x 1.5 KB are all that
+    ``STAGED_VMEM_BYTES`` holds (the launch then asks Mosaic for 112 MiB);
+    one page more and every query gathers."""
+    from dynamo_tpu.ops import pallas_sparse as ps
+
+    def fn(q, kc, vc, tables, rows, sel):
+        return ps.sparse_latent_attention(
+            q, kc, vc, tables, rows, sel, scale=1 / 16, n_chunk=512)
+
+    def build(sharding):
+        def s(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        page = s((14336, 16, 4, 128), BF)
+        return (s((520, 64, 640), BF), page, page, s((9, mb), I32),
+                s((520,), I32), s((520, 2048), I32))
+    return fn, build
+
+
 def _cases():
     """name -> (fn, shape-args builder). Builders take the ShapeDtypeStruct
     factory so one table serves any sharding."""
@@ -242,6 +263,8 @@ def _cases():
         # queries, and eight decode rows with the indexer's scoring
         "sparse-latent-mixed": (sparse_mixed, sparse_shapes(520, 9)),
         "sparse-latent-decode": (sparse_decode, sparse_shapes(8, 8)),
+        "sparse-latent-staged-all-the-vmem": sparse_launch(4096),
+        "sparse-latent-too-wide-to-stage": sparse_launch(4097),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),
