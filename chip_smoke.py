@@ -757,6 +757,76 @@ def _kernel_child() -> None:
     print("KERNEL sparse_latent_attention: staged and gathered bitwise equal, "
           "200 launches back to back bitwise the first", flush=True)
 
+    # latent attention over EVERY causal key (models/mla.py without an
+    # indexer) at A.X-K1's and DeepSeek-V3's widths: 64 absorbed heads over
+    # 512 + 64 lanes in rows of 128, 16-token pages, page-contiguous: 8
+    # decode rows over 25k keys (tails of 1, 15 and 17 tokens past a page,
+    # an empty row), a 512-query chunk at a 25k context's tail, and a mixed
+    # step of a 320-query chunk + 8 decode rows in ONE launch; each against
+    # the highest-precision twin, and its nanoseconds a (query, key) pair
+    from dynamo_tpu.ops import pallas_latent as plat
+
+    DNB, DMB, dctx = 14401, 1600, 25000
+    dlat, daux = rnd(DNB, BS, 4, 128), rnd(DNB, BS, 4, 128)
+    dtables = jnp.asarray(
+        rng.permutation(DNB - 1)[: 9 * DMB].reshape(9, DMB) + 1, jnp.int32
+    )
+    ref_latent = highest(att.paged_latent_attention)
+    dscale = 0.13086
+
+    def latent_case(name, n_chunk, q_len0, lens):
+        first = 1 if n_chunk else 0
+        n_one = len(lens) - first
+        q_lens = jnp.asarray(
+            [q_len0] * first + [int(n > 0) for n in lens[first:]], jnp.int32
+        )
+        pairs = sum(lens[first:]) + q_len0 * (lens[0] if first else 0) - (
+            q_len0 * (q_len0 - 1) // 2
+        )
+        lens = jnp.asarray(lens, jnp.int32)
+        tb = dtables[: len(lens)]
+        qd = rnd(n_chunk + n_one, 64, 640)
+        run = lambda: plat.paged_latent_attention(  # noqa: E731
+            qd, dlat, daux, tb, q_lens, lens, scale=dscale, n_chunk=n_chunk)
+        got = run()
+        # the twin scores the whole packed buffer for every row: the chunk
+        # and the one-token rows are asked of it apart
+        parts = []
+        if n_chunk:
+            parts.append(ref_latent(
+                qd[:n_chunk], dlat, daux, tb[:1], jnp.zeros((1,), jnp.int32),
+                q_lens[:1], lens[:1], dscale))
+        if n_one:
+            parts.append(ref_latent(
+                qd[n_chunk:], dlat, daux, tb[first:], jnp.arange(n_one),
+                q_lens[first:], lens[first:], dscale))
+        compare(name, got, jnp.concatenate(parts, axis=0))
+        empty = n_chunk + np.flatnonzero(np.asarray(lens[first:]) == 0)
+        if np.asarray(got, np.float32)[empty].any():
+            raise SystemExit(f"{name}: an empty row is not zeros")
+        times = []
+        for _ in range(6):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run())
+            times.append(time.perf_counter() - t0)
+        took = sorted(times[1:])[2]
+        print(f"KERNEL {name}: {took * 1e3:.3f} ms, "
+              f"{took * 1e9 / pairs:.2f} ns a (query, key) pair over "
+              f"{pairs} pairs", flush=True)
+
+    latent_case(
+        "paged_latent_attention 8 decode rows over 25k keys, tails, an "
+        "empty row", 0, 0,
+        [dctx, dctx + 1, dctx + 15, dctx + 17, 24576, 1, 0, dctx],
+    )
+    latent_case("paged_latent_attention a 512-query chunk at 25k keys",
+                512, 512, [dctx])
+    latent_case(
+        "paged_latent_attention mixed: a 320-query chunk (301 real) + 8 "
+        "decode rows, one launch", 320, 301,
+        [dctx, dctx, 24577, 17, 0, dctx + 15, 1, 24591, dctx],
+    )
+
     # block moves are copies: exact
     ids = jnp.asarray(rng.permutation(NB)[:32], jnp.int32)
     got = bc.gather_blocks(k_cache, ids)

@@ -377,6 +377,17 @@ class TpuEngine:
         # int8 paged KV (config.kv_dtype / DTPU_KV_DTYPE; ops/quant.py):
         # every cache-touching path below branches on this ONE flag
         self.kv_quantized = config.kv_quantized
+        # the parallelism is known here: a latent without an indexer is held
+        # as rows of 128 lanes (the kernel path) on the one-chip text path,
+        # as one head everywhere else (models/registry.place_latent); what a
+        # latent in rows cannot do yet is refused further down
+        asked = dict(
+            tp=config.tp, pp=config.pp, sp=config.sp,
+            spec=config.spec_draft is not None,
+            lora=config.lora_max_adapters > 0,
+            kv_quantized=self.kv_quantized, vision=config.vision is not None,
+        )
+        self.mcfg = config.model = registry.place_latent(self.mcfg, **asked)
         if self.kv_quantized:
             if config.pp > 1:
                 raise ValueError(
@@ -473,12 +484,7 @@ class TpuEngine:
                     "guided decoding needs guided_vocab=(vocab byte forms, "
                     "eos_id) — see guided.vocab_bytes_from_tokenizer"
                 )
-        registry.check_dsa_supported(
-            self.mcfg, tp=config.tp, pp=config.pp, sp=config.sp,
-            spec=config.spec_draft is not None,
-            lora=config.lora_max_adapters > 0,
-            kv_quantized=self.kv_quantized, vision=config.vision is not None,
-        )
+        registry.check_dsa_supported(self.mcfg, **asked)
         if registry.is_gptoss(self.mcfg) or registry.is_gemma(self.mcfg):
             # the ragged kernel carries per-row window/sink/softcap
             # attributes, so use_pallas serves these families too. Only the
@@ -575,12 +581,14 @@ class TpuEngine:
         # the one-chip grouped expert path reports its routing each step
         # (StepStats.moe_*): three numbers riding the readback a decode or
         # mixed step already makes. None where no step carried them.
-        # A configuration with an indexer adds three more (StepStats.dsa_*).
+        # A latent held as rows adds what its decode rows read, under the
+        # names of StepStats' fields (mla.read_counters: dsa_* or mla_*).
         self._moe_counted = (
             registry.counts_routing(self.mcfg) and config.pp == 1
             and meshlib.tp_size(self.mesh) == 1
         )
         self._moe_last: Optional[Tuple[int, ...]] = None
+        self._read_counters = registry.read_counters(self.mcfg)
         self._lm_logits = registry.lm_logits_fn(self.mcfg)
         with self.mesh:
             if params is None and (
@@ -1030,11 +1038,13 @@ class TpuEngine:
         and a speculative draft (each judged on its own config). What it
         asks of ``head_dim`` and ``num_kv_heads`` is asked of the PAGES the
         attention kernels copy, whatever a family keeps in them: a latent
-        held as one 576-lane head is refused (Mosaic slices HBM by whole
-        tiles), one held as rows of 128 lanes (an MlaConfig with an indexer:
-        ops/attention.py has the layout) is admitted, and with it the fused
-        mixed step and the Pallas expert multiplication, which ask nothing
-        of the attention layout but ride the same switch."""
+        held as one head of rank + rope lanes is refused (Mosaic slices HBM
+        by whole tiles), one held as rows of 128 lanes (an MlaConfig with an
+        indexer, or without one at widths that allow it,
+        ``MlaConfig.latent_rows``: ops/attention.py has the layout) is
+        admitted, and with it the fused mixed step and the Pallas expert
+        multiplication, which ask nothing of the attention layout but ride
+        the same switch."""
         return (
             mcfg.head_dim % 128 == 0
             and mcfg.num_kv_heads % meshlib.tp_size(self.mesh) == 0
@@ -4585,8 +4595,9 @@ class TpuEngine:
         host_spans = tuple(spans.popleft() for _ in range(len(spans)))
         admit_wait_s = tuple(waits.popleft() for _ in range(len(waits)))
         # set by the step's own readback; a prefill-only step has none
-        routed, touched, load_max, *selection = self._moe_last or (None,) * 3
-        causal, scored, selected = selection or (None,) * 3
+        routed, touched, load_max, *reads = self._moe_last or (None,) * 3
+        # behind them what a latent's decode rows read, by StepStats' names
+        reads = dict(zip(self._read_counters, reads))
         held = getattr(self.mcfg, "experts_held", None) is not None
         self._moe_last = None
         try:
@@ -4612,9 +4623,7 @@ class TpuEngine:
                 moe_experts_touched=touched,
                 moe_load_max=load_max,
                 moe_held_experts_touched=touched if held else None,
-                dsa_keys_causal=causal,
-                dsa_keys_scored=scored,
-                dsa_keys_selected=selected,
+                **reads,
                 h2d_placements=placed,
             ))
         except Exception:

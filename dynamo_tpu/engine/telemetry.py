@@ -173,6 +173,11 @@ class StepStats:
     dsa_keys_causal: Optional[int] = None
     dsa_keys_scored: Optional[int] = None
     dsa_keys_selected: Optional[int] = None
+    # a latent cache WITHOUT an indexer (an MlaConfig held as rows): the
+    # keys the step's real decode rows attended over (each its whole
+    # context), and those rows, both summed over layers; the same readback
+    mla_keys_attended: Optional[int] = None
+    mla_decode_rows: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -325,6 +330,13 @@ class EngineTelemetry:
                     "keys_causal": moe.dsa_keys_causal,
                     "keys_scored": moe.dsa_keys_scored,
                     "keys_selected": moe.dsa_keys_selected,
+                }
+            if moe.mla_keys_attended is not None:
+                # the last such step's dense latent reads
+                out["mla"] = {
+                    "phase": moe.phase,
+                    "keys_attended": moe.mla_keys_attended,
+                    "decode_rows": moe.mla_decode_rows,
                 }
         return out
 

@@ -24,7 +24,7 @@ log = get_logger("engine.weights")
 def config_from_hf(path: str) -> LlamaConfig:
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
-    if hf.get("model_type", "") in ("deepseek_v2", "deepseek_v3"):
+    if hf.get("model_type", "") in ("deepseek_v2", "deepseek_v3", "axk1"):
         return _mla_config_from_hf(hf)
     if hf.get("model_type", "") == "gpt_oss":
         return _gptoss_config_from_hf(hf)
@@ -50,10 +50,33 @@ def config_from_hf(path: str) -> LlamaConfig:
 
 
 def _mla_config_from_hf(hf: dict):
-    """DeepSeek V2/V3 config.json -> MlaConfig (models/mla.py)."""
+    """DeepSeek V2/V3 (and A.X-K1, the same layer) config.json -> MlaConfig
+    (models/mla.py). A DeepSeek-style ``rope_scaling`` dict of ``type`` (or
+    ``rope_type``) ``yarn`` becomes the YaRN fields; any other kind of
+    scaling is refused, not run plain."""
     from ..models.mla import MlaConfig
 
+    rs = hf.get("rope_scaling") or {}
+    yarn = {}
+    kind = rs.get("rope_type", rs.get("type", "default"))
+    if kind == "yarn":
+        yarn = dict(
+            rope_scaling_factor=float(rs["factor"]),
+            rope_original_max_position=int(
+                rs.get("original_max_position_embeddings")
+                or hf.get("max_position_embeddings", 4096)
+            ),
+            rope_beta_fast=float(rs.get("beta_fast") or 32.0),
+            rope_beta_slow=float(rs.get("beta_slow") or 1.0),
+            rope_mscale=float(rs.get("mscale") or 0.0),
+            rope_mscale_all_dim=float(rs.get("mscale_all_dim") or 0.0),
+        )
+    elif kind != "default":
+        raise ValueError(
+            f"rope_scaling of kind {kind!r} is not built for the MLA family"
+        )
     return MlaConfig(
+        **yarn,
         vocab_size=hf["vocab_size"],
         hidden_size=hf["hidden_size"],
         num_layers=hf["num_hidden_layers"],

@@ -44,8 +44,25 @@ The cache of such a configuration is laid out for token-granular reads
 (ops/attention.py): ``num_kv_heads`` rows of 128 lanes a token in either
 paged array, the latent in the first, ``k_pe`` and the index key in the
 second; the layer hands the seam an ``ops.attention.DsaQuery`` and gets
-``[..., heads, kv_lora_rank]`` back. Configurations without an indexer keep
-the 576-lane contract described above, untouched.
+``[..., heads, kv_lora_rank]`` back.
+
+A latent WITHOUT an indexer can take the same rows-of-128-lanes layout
+(``rows_layout``; its widths must allow it, ``rows_capable``: ``kv_lora_rank``
+a multiple of 256, ``qk_rope_head_dim <= 128``, DeepSeek-V3's and A.X-K1's
+512 + 64), because a page of it is something a kernel can copy: the layer
+hands the seam an ``ops.attention.LatentQuery`` (nothing selects: every causal
+key is attended) and the seam answers with ``paged_latent_attention``
+(ops/pallas_latent.py on the chip). The layout is chosen where the
+parallelism is known: ``TpuEngine`` takes it on the one-chip text path
+(``registry.place_latent``), and everywhere else (``tp`` / ``sp`` above 1, a
+speculative draft, LoRA, an 8-bit cache, vision, a narrower latent) the
+latent keeps the one-head contract described above and the pure-JAX path.
+
+Rotary positions are plain ``rope_theta`` or, with ``rope_scaling_factor >
+1``, YaRN as the DeepSeek lineage applies it: blended frequencies
+(``llama.yarn_inv_freq``), cos and sin scaled by ``m(mscale) /
+m(mscale_all_dim)`` and the softmax scale by ``m(mscale_all_dim) ** 2``,
+``m(a) = 0.1 a ln(factor) + 1``.
 
 FFN is the dense SwiGLU for ``num_experts == 0``, otherwise DeepSeek-MoE
 style: ``first_dense_layers`` leading dense layers, sigmoid-or-softmax
@@ -66,7 +83,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import LATENT_LANES, DsaQuery
+from ..ops.attention import LATENT_LANES, DsaQuery, LatentQuery
 from . import moe as moelib
 from .llama import (
     AttendFn,
@@ -75,6 +92,7 @@ from .llama import (
     apply_rope,
     rms_norm,
     rope_cos_sin,
+    yarn_inv_freq,
 )
 
 
@@ -114,6 +132,17 @@ class MlaConfig(LlamaConfig):
     index_n_heads: int = 0
     index_head_dim: int = 0
     indexer_types: Tuple[str, ...] = ()
+    # a latent WITHOUT an indexer held as rows of 128 lanes (with one it
+    # always is). The engine sets it (registry.place_latent) where the
+    # parallelism allows; False = one head of rank + rope lanes
+    rows_layout: bool = False
+    # YaRN (rope_scaling_factor <= 1: plain rotary positions)
+    rope_scaling_factor: float = 1.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     def __post_init__(self):
         # the engine reads num_kv_heads/head_dim as the KV-cache layout;
@@ -144,14 +173,54 @@ class MlaConfig(LlamaConfig):
             )
             object.__setattr__(self, "head_dim", LATENT_LANES)
             return
+        if self.rows_layout:
+            if not self.rows_capable:
+                raise ValueError(
+                    "a latent held as rows needs kv_lora_rank a multiple of "
+                    "256 and qk_rope_head_dim <= 128 (the cache holds it as "
+                    "rows of 128 lanes, two to a tile)"
+                )
+            object.__setattr__(
+                self, "num_kv_heads", self.kv_lora_rank // LATENT_LANES
+            )
+            object.__setattr__(self, "head_dim", LATENT_LANES)
+            return
         object.__setattr__(self, "num_kv_heads", 1)
         object.__setattr__(
             self, "head_dim", self.kv_lora_rank + self.qk_rope_head_dim
         )
 
     @property
+    def latent_rows(self) -> bool:
+        """Whether the cache holds the latent as rows of 128 lanes a token
+        (ops/attention.py has the layout): with an indexer always, without
+        one where ``rows_layout`` says so."""
+        return self.index_topk > 0 or self.rows_layout
+
+    @property
+    def rows_capable(self) -> bool:
+        """Whether the widths allow the rows layout."""
+        return (
+            self.kv_lora_rank % (2 * LATENT_LANES) == 0
+            and self.qk_rope_head_dim <= LATENT_LANES
+        )
+
+    @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        """``qk_head_dim ** -0.5``, times YaRN's ``m(mscale_all_dim) ** 2``
+        where a scaling factor is stated."""
+        return 1.0 / math.sqrt(self.qk_head_dim) * self.yarn_scale_factor
+
+    @property
+    def yarn_scale_factor(self) -> float:
+        """``m(mscale_all_dim) ** 2``; exactly 1.0 without YaRN."""
+        return _yarn_mscale(
+            self.rope_scaling_factor, self.rope_mscale_all_dim
+        ) ** 2
 
     @property
     def q_size(self) -> int:  # true q projection width (lora sizing etc.)
@@ -215,9 +284,11 @@ class MlaConfig(LlamaConfig):
 
     @classmethod
     def deepseek_v3(cls, vocab_size: int = 129280) -> "MlaConfig":
-        """DeepSeek-V3 / R1 (671B total / 37B active). head_dim = 576 is not
-        128-aligned, so attention runs the pure-JAX paged path (the Pallas
-        eligibility guard falls back automatically)."""
+        """DeepSeek-V3 / R1 (671B total / 37B active). Sharded over chips
+        (``tp`` above 1: the only way it fits) the 512 + 64 latent is one
+        576-lane head and attention runs the pure-JAX paged path; on the
+        one-chip text path (a cut of it) the engine holds the latent as rows
+        of 128 lanes and attention is the launch ``paged_latent_attention``."""
         return cls(
             vocab_size=vocab_size, hidden_size=7168, num_layers=61,
             num_heads=128, q_lora_rank=1536, kv_lora_rank=512,
@@ -229,6 +300,53 @@ class MlaConfig(LlamaConfig):
             n_group=8, topk_group=4,
             rope_theta=10000.0, tie_embeddings=False,
         )
+
+    @classmethod
+    def axk1(cls, vocab_size: int = 163840) -> "MlaConfig":
+        """A.X-K1 (519B total): DeepSeek-V3's layer at 64 heads and 192
+        experts, one leading dense layer, YaRN x32 from 4 096 positions."""
+        return cls(
+            vocab_size=vocab_size, hidden_size=7168, num_layers=61,
+            num_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            intermediate_size=18432, num_experts=192, num_experts_per_tok=8,
+            moe_intermediate_size=2048, moe_scoring="sigmoid",
+            routed_scaling_factor=2.5, norm_topk_prob=True,
+            num_shared_experts=1, first_dense_layers=1,
+            n_group=8, topk_group=4, rms_norm_eps=1e-6,
+            rope_theta=10000.0, max_position=131072, tie_embeddings=False,
+            rope_scaling_factor=32.0, rope_original_max_position=4096,
+            rope_beta_fast=32.0, rope_beta_slow=1.0,
+            rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        )
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def rope_tables(cfg: MlaConfig, positions: jax.Array):
+    """cos/sin [..., qk_rope_head_dim / 2] (float32) of the rotary dims:
+    plain ``rope_theta``, or YaRN where ``rope_scaling_factor > 1``."""
+    rope = cfg.qk_rope_head_dim
+    if cfg.rope_scaling_factor <= 1.0:
+        return rope_cos_sin(positions, rope, cfg.rope_theta)
+    inv_freq, _ = yarn_inv_freq(
+        rope, cfg.rope_theta, cfg.rope_scaling_factor,
+        cfg.rope_original_max_position, cfg.rope_beta_fast,
+        cfg.rope_beta_slow,
+    )
+    # transformers' _compute_yarn_parameters: the ratio where the config
+    # states both, else the recipe's own factor
+    f = cfg.rope_scaling_factor
+    if cfg.rope_mscale and cfg.rope_mscale_all_dim:
+        att = _yarn_mscale(f, cfg.rope_mscale) / _yarn_mscale(
+            f, cfg.rope_mscale_all_dim
+        )
+    else:
+        att = _yarn_mscale(f, 1.0)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(angles) * att, jnp.sin(angles) * att
 
 
 # ---------------------------------------------------------------------------
@@ -440,19 +558,22 @@ def _lanes(x: jax.Array) -> jax.Array:
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
-def _selected_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
-                        attend, layer_idx, carry):
-    """Latent attention over the positions an indexer selects (this layer's,
-    or the one ``carry`` brings from the nearest selecting layer): the
-    layer's rows in the token-granular layout, and a ``DsaQuery`` for the
-    seam. Returns [..., heads, kv_lora_rank]."""
+def _rows_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
+                    attend, layer_idx, carry):
+    """Latent attention over the rows layout: over the positions an indexer
+    selects (this layer's, or the one ``carry`` brings from the nearest
+    selecting layer: a ``DsaQuery`` for the seam) or, without an indexer,
+    over every causal key (a ``LatentQuery``). The layer's rows in the
+    layout. Returns [..., heads, kv_lora_rank]."""
     rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     nI, dI = cfg.index_n_heads, cfg.index_head_dim
     lead = h.shape[:-1]
-    dsa = DsaQuery(
-        scale=1.0 / math.sqrt(cfg.qk_head_dim), topk=cfg.index_topk
-    )
     kI = jnp.zeros((*lead, LATENT_LANES), cfg.dtype)
+    if cfg.index_topk == 0:
+        ask = {"latent": LatentQuery(scale=cfg.softmax_scale)}
+    else:
+        dsa = DsaQuery(scale=cfg.softmax_scale, topk=cfg.index_topk)
+        ask = {"dsa": dsa}
     if _selects(cfg, layer_idx):
         qI = (cq @ p["w_iq"]).reshape(*lead, nI, dI)
         dsa.index_q = _rope_front(qI, cos, sin, rope).astype(cfg.dtype)
@@ -468,7 +589,7 @@ def _selected_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
         kI = _lanes(
             _rope_front(kf.astype(cfg.dtype)[..., None, :], cos, sin, rope)[..., 0, :]
         )
-    else:
+    elif cfg.index_topk > 0:
         dsa.selected = carry["selected"]
     rows = cfg.num_kv_heads
     k_rows = c.reshape(*lead, rows, LATENT_LANES)
@@ -479,9 +600,10 @@ def _selected_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
     q_lat = jnp.concatenate([q_abs, _lanes(q_pe)], axis=-1)
     o = attend(
         q_lat.astype(cfg.dtype), k_rows.astype(cfg.dtype),
-        aux.astype(cfg.dtype), layer_idx, dsa=dsa,
+        aux.astype(cfg.dtype), layer_idx, **ask,
     )
-    carry["selected"] = dsa.selected
+    if cfg.index_topk > 0:
+        carry["selected"] = dsa.selected
     return o
 
 
@@ -519,15 +641,18 @@ def layer_forward(
     k_pe = apply_rope(ckv[..., None, rank:], cos, sin)     # [..., 1, rope]
     # -- absorb W_uk into q: MQA over the latent
     q_abs = jnp.einsum("...hn,hnr->...hr", q_nope, p["w_uk"])
-    if cfg.index_topk > 0:
-        o = _selected_attention(
+    if cfg.latent_rows:
+        o = _rows_attention(
             p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin, attend,
             layer_idx, carry,
         )
     else:
         q_prime = jnp.concatenate([q_abs, q_pe], axis=-1)  # [..., nh, rank+rope]
         # attend ops scale by 1/sqrt(rank+rope); MLA wants 1/sqrt(nope+rope)
-        q_prime = q_prime * math.sqrt((rank + rope) / (nope + rope))
+        # (times YaRN's factor, exactly 1.0 without it)
+        q_prime = q_prime * (
+            math.sqrt((rank + rope) / (nope + rope)) * cfg.yarn_scale_factor
+        )
         k_prime = jnp.concatenate([c[..., None, :], k_pe], axis=-1)
         cl = c[..., None, :]                               # [..., 1, rank]
         v_prime = jnp.pad(
@@ -552,6 +677,17 @@ def layer_forward(
     return x + _dense_ffn(p, cfg, h)
 
 
+def read_counters(cfg: MlaConfig) -> Tuple[str, ...]:
+    """The ``StepStats`` fields ``forward`` adds to its ``stats`` behind the
+    routing's three (``RoutingStats.add_reads``), in the order they ride the
+    step's readback: what the real decode rows read of a latent held as rows."""
+    if not cfg.latent_rows:
+        return ()
+    if cfg.index_topk > 0:
+        return ("dsa_keys_causal", "dsa_keys_scored", "dsa_keys_selected")
+    return ("mla_keys_attended", "mla_decode_rows")
+
+
 def forward(
     params: Params,
     cfg: MlaConfig,
@@ -565,12 +701,13 @@ def forward(
     matmul=moelib.grouped_matmul_reference,
 ) -> jax.Array:
     """``stats`` (moe.RoutingStats): the one-chip grouped expert path counts
-    its routing into it, and a configuration with an indexer the keys its
-    real decode rows saw, scored and attended."""
+    its routing into it, a configuration with an indexer the keys its real
+    decode rows saw, scored and attended, and a rows-layout latent without
+    one the keys its real decode rows attended over and those rows."""
     if lora is not None:
         raise NotImplementedError("LoRA is not supported for the MLA family")
     x = params["embed"][token_ids] if inputs_embeds is None else inputs_embeds
-    cos, sin = rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    cos, sin = rope_tables(cfg, positions)
     cos, sin = cos[..., None, :], sin[..., None, :]
     carry: dict = {}
     for i, layer in enumerate(params["layers"]):
@@ -578,17 +715,28 @@ def forward(
             layer, cfg, x, cos, sin, attend, i, expert_fn=expert_fn,
             stats=stats, matmul=matmul, carry=carry,
         )
-    if stats is not None and cfg.index_topk > 0:
-        # per decode row and layer: the keys it could see, those an indexer
-        # scored (selecting layers only) and those attended over
-        seen = jnp.where(
-            stats.decode_rows.reshape(-1), positions.reshape(-1) + 1, 0
-        )
-        n_sel = sum(_selects(cfg, i) for i in range(cfg.num_layers))
-        stats.add_selection(
-            seen.sum() * cfg.num_layers, seen.sum() * n_sel,
-            jnp.minimum(seen, cfg.index_topk).sum() * cfg.num_layers,
-        )
+    if stats is not None and cfg.latent_rows:
+        # what the real decode rows read of the latent, summed over rows and
+        # layers, under the names of StepStats' fields (read_counters)
+        rows = stats.decode_rows.reshape(-1)
+        seen = jnp.where(rows, positions.reshape(-1) + 1, 0)
+        if cfg.index_topk > 0:
+            # the keys a row could see, those an indexer scored (selecting
+            # layers only) and those attended over
+            n_sel = sum(_selects(cfg, i) for i in range(cfg.num_layers))
+            stats.add_reads(
+                dsa_keys_causal=seen.sum() * cfg.num_layers,
+                dsa_keys_scored=seen.sum() * n_sel,
+                dsa_keys_selected=(
+                    jnp.minimum(seen, cfg.index_topk).sum() * cfg.num_layers
+                ),
+            )
+        else:
+            # the keys attended over (each row its whole context), the rows
+            stats.add_reads(
+                mla_keys_attended=seen.sum() * cfg.num_layers,
+                mla_decode_rows=rows.sum() * cfg.num_layers,
+            )
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 
@@ -612,7 +760,7 @@ def reference_attention(
     nh, rank = cfg.num_heads, cfg.kv_lora_rank
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     S = h_normed.shape[0]
-    cos, sin = rope_cos_sin(positions, rope, cfg.rope_theta)
+    cos, sin = rope_tables(cfg, positions)
     cos, sin = cos[..., None, :], sin[..., None, :]
     if cfg.q_lora_rank > 0:
         q = rms_norm(h_normed @ p["w_dq"], p["q_norm"], cfg.rms_norm_eps) @ p["w_uq"]
@@ -632,7 +780,7 @@ def reference_attention(
     )
     qf = jnp.concatenate([q_nope, q_pe], axis=-1).astype(jnp.float32)
     s = jnp.einsum("shd,thd->hst", qf, k.astype(jnp.float32))
-    s = s / math.sqrt(nope + rope)
+    s = s / math.sqrt(nope + rope) * cfg.yarn_scale_factor
     mask = jnp.tril(jnp.ones((S, S), bool))
     s = jnp.where(mask[None], s, -1e30)
     pattn = jax.nn.softmax(s, axis=-1)

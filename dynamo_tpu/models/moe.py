@@ -294,10 +294,11 @@ class RoutingStats:
                  decode_rows: Optional[jax.Array] = None):
         self.valid = valid
         # the rows that are decode rows (a mixed step's chunk is not):
-        # add_selection's sums are over these
+        # add_reads' sums are over these
         self.decode_rows = valid if decode_rows is None else decode_rows
         self.counts: List[jax.Array] = []
-        self.selection: Optional[jax.Array] = None
+        self.read_names: Tuple[str, ...] = ()
+        self.reads: Optional[jax.Array] = None
 
     def add(self, topi: jax.Array, num_experts: int) -> None:
         """``topi`` [T, K] expert of each assignment; one at or past
@@ -311,21 +312,23 @@ class RoutingStats:
             jnp.bincount(topi.reshape(-1), weights=weights, length=num_experts)
         )
 
-    def add_selection(self, causal, scored, selected) -> None:
-        """A forward pass with learned sparse attention adds, once, the keys
-        its real decode rows could see, scored and attended, summed over
-        rows and layers (models/mla.py)."""
-        self.selection = jnp.stack([causal, scored, selected])
+    def add_reads(self, **sums) -> None:
+        """A forward pass over a latent cache adds, once, what its real
+        decode rows read, summed over rows and layers, each sum under the
+        name of its ``StepStats`` field (models/mla.py ``read_counters`` has
+        the names and their order)."""
+        self.read_names = tuple(sums)
+        self.reads = jnp.stack(list(sums.values()))
 
     def reduce(self) -> jax.Array:
         """[3] float32: rows routed (T x K summed over layers; to the held
         experts where the layer holds a share), experts touched (summed over
-        layers), the largest count on one expert. [6] with ``add_selection``'s
-        three behind them."""
+        layers), the largest count on one expert; ``add_reads``' sums behind
+        them, in the order they were named."""
         c = jnp.stack(self.counts)                       # [L, E]
         out = jnp.stack([c.sum(), (c > 0).sum(), c.max()]).astype(jnp.float32)
-        if self.selection is not None:
-            out = jnp.concatenate([out, self.selection.astype(jnp.float32)])
+        if self.reads is not None:
+            out = jnp.concatenate([out, self.reads.astype(jnp.float32)])
         return out
 
 

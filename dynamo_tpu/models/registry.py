@@ -6,6 +6,7 @@ rather than hard-coded architectures).
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 
 from jax.sharding import PartitionSpec as P
@@ -60,25 +61,59 @@ def counts_routing(cfg) -> bool:
     return is_moe(cfg) or (is_mla(cfg) and cfg.num_experts > 0)
 
 
+def place_latent(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
+                 kv_quantized=False, vision=False):
+    """The layout of a latent WITHOUT an indexer, chosen where the
+    parallelism is known (engine construction): on the one-chip text path a
+    latent whose widths allow it (``MlaConfig.rows_capable``) is held as rows
+    of 128 lanes, which the Pallas kernels can copy (``rows_layout``);
+    everywhere else (``tp`` / ``pp`` / ``sp`` above 1, a speculative draft,
+    LoRA, an 8-bit cache, vision) it stays one head of rank + rope lanes on
+    the pure-JAX path, which shards and quantizes like any other head.
+    Returns ``cfg``, or a copy with ``rows_layout`` set. A configuration that
+    already states ``rows_layout`` (or has an indexer) is returned as it is:
+    ``check_dsa_supported`` judges it."""
+    if not is_mla(cfg) or cfg.latent_rows or not cfg.rows_capable:
+        return cfg
+    one_chip_text = (
+        tp == 1 and pp == 1 and sp == 1
+        and not (spec or lora or kv_quantized or vision)
+    )
+    return dataclasses.replace(cfg, rows_layout=True) if one_chip_text else cfg
+
+
+def read_counters(cfg) -> tuple:
+    """The ``StepStats`` fields a family's forward adds behind the routing's
+    three on the step's readback (``mla.read_counters``); () for the others."""
+    return mla.read_counters(cfg) if is_mla(cfg) else ()
+
+
 def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
                         kv_quantized=False, vision=False) -> None:
-    """A configuration with learned sparse attention (or one that holds a
-    share of its experts) runs on the one-chip text path; what it cannot do
-    yet is refused here, at engine construction, each with its reason."""
-    if not is_mla(cfg) or not (cfg.index_topk > 0 or cfg.experts_held):
+    """A configuration whose latent is held as rows of 128 lanes (learned
+    sparse attention, or a latent without an indexer that states
+    ``rows_layout``: ``MlaConfig.latent_rows``), or one that holds a share of
+    its experts, runs on the one-chip text path; what it cannot do yet is
+    refused here, at engine construction, each with its reason. A latent
+    without an indexer that states no layout never comes here as rows:
+    ``place_latent`` leaves it one head wherever a refusal would hit."""
+    if not is_mla(cfg) or not (cfg.latent_rows or cfg.experts_held):
         return
-    what = "learned sparse attention / a held share of the experts (MlaConfig)"
+    what = ("a latent held as rows of 128 lanes (learned sparse attention, "
+            "or none) / a held share of the experts (MlaConfig)")
     refusals = [
         (tp > 1, "tp > 1: the latent's rows are one head's and cannot shard "
-                 "on heads, and a held share is already one chip's of a "
-                 "layer divided over chips (the exchange is not built)"),
+                 "on heads (the cache would be cut between its lanes), and a "
+                 "held share is already one chip's of a layer divided over "
+                 "chips (the exchange is not built)"),
         (pp > 1 or sp > 1, "pp / sp > 1: neither the wavefront nor the ring "
-                           "carries a selection from layer to layer"),
-        (spec, "a speculative draft: verify rows have no selected-positions "
-               "question in the attention seam yet"),
+                           "carries the rows layout (or a selection) from "
+                           "layer to layer"),
+        (spec, "a speculative draft: verify rows have no latent question in "
+               "the attention seam yet"),
         (lora, "LoRA: the MLA family has no adapter path"),
-        (kv_quantized, "kv_dtype=int8: the token-granular kernel reads bf16 "
-                       "rows; an 8-bit latent needs its scales a token"),
+        (kv_quantized, "kv_dtype=int8: the latent kernels read bf16 rows; an "
+                       "8-bit latent needs its scales a token"),
         (vision, "vision: multimodal serving covers the dense family only"),
     ]
     for hit, why in refusals:

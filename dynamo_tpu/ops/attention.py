@@ -447,6 +447,10 @@ def paged_extend_attention(
 # Values are the latent itself (``W_uv`` is applied past the softmax), so the
 # V array holds no values: it is the layer's second kind of per-token state,
 # under the same block ids as the first.
+#
+# A latent WITHOUT an indexer may keep the same two arrays (row 1 of the
+# second unwritten) and hands the seam a ``LatentQuery``: nothing selects,
+# every causal key is attended (``paged_latent_attention`` below).
 
 LATENT_LANES = 128
 SEL_NONE = -1     # padding of a query's list of selected positions
@@ -469,6 +473,14 @@ class DsaQuery:
     index_q: Optional[jax.Array] = None
     index_w: Optional[jax.Array] = None
     selected: Optional[jax.Array] = None
+
+
+@dataclasses.dataclass
+class LatentQuery:
+    """What a latent layer WITHOUT an indexer, its cache held as rows, asks
+    of the attention seam besides q, k and v: every causal key."""
+
+    scale: float                       # softmax scale (YaRN's factor in it)
 
 
 def dsa_index_scores(iq: jax.Array, iw: jax.Array, keys: jax.Array) -> jax.Array:
@@ -554,3 +566,44 @@ def sparse_latent_attention(
     p = p / jnp.where(denom > 0, denom, 1.0)
     out = jnp.einsum("qhk,qkr->qhr", p, c.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def paged_latent_attention(
+    q: jax.Array,            # [Tq, h, rank + 128]: [absorbed q | q_pe | 0]
+    k_cache: jax.Array,      # [nb, bs, rows, 128] the latent
+    v_cache: jax.Array,      # [nb, bs, rows, 128] row 0 = [k_pe | 0]
+    tables: jax.Array,       # [R, mb]
+    q_starts: jax.Array,     # [R] offset of row r's queries in q
+    q_lens: jax.Array,       # [R] queries of row r (0 = an empty row)
+    seq_lens: jax.Array,     # [R] context length incl. the row's queries
+    scale: float,
+) -> jax.Array:
+    """MQA of every head of a query over EVERY causal latent row of its
+    context; values are the rows' latent. Ragged rows as in
+    ``ragged_paged_attention``: row ``r`` owns ``q[q_starts[r] : q_starts[r]
+    + q_lens[r]]`` at the tail of ``tables[r]``'s context. Returns [Tq, h,
+    rank]; a query no row owns, or of an empty row, returns zeros. The
+    pure-JAX twin of ``ops.pallas_latent.paged_latent_attention``; like the
+    ragged twin every row scores the whole packed buffer: a reference."""
+    nb, bs, r, lanes = k_cache.shape
+    rank = q.shape[-1] - lanes
+    idx = jnp.arange(q.shape[0])
+
+    def one(table, q_start, q_len, seq_len):
+        c = k_cache[table].reshape(-1, r * lanes)[:, :rank]      # [T, rank]
+        pe = v_cache[table][:, :, 0].reshape(-1, lanes)          # [T, 128]
+        keys = jnp.concatenate([c, pe], axis=-1)
+        local = idx - q_start
+        member = (local >= 0) & (local < q_len) & (seq_len > 0)
+        q_pos = seq_len - q_len + local
+        s = jnp.einsum("qhd,td->qht", q, keys,
+                       preferred_element_type=jnp.float32) * scale
+        seen = jnp.arange(keys.shape[0])[None, :] < jnp.minimum(
+            q_pos + 1, seq_len)[:, None]
+        s = jnp.where(seen[:, None, :], s, NEG_INF)
+        out = jnp.einsum("qht,tr->qhr", jax.nn.softmax(s, axis=-1),
+                         c.astype(jnp.float32))
+        return jnp.where(member[:, None, None], out, 0.0)
+
+    outs = jax.vmap(one)(tables, q_starts, q_lens, seq_lens)
+    return jnp.sum(outs, axis=0).astype(q.dtype)

@@ -14,7 +14,7 @@ straight through.
   (ops/pallas_attention.py): PERF.md section 6, PR 28 has the chip runs in
   which the ragged kernel's one-token rows read level by every median and not
   by their tail.
-- Pallas off (CPU, pp, a 576-lane latent, the families off the auto rule): the
+- Pallas off (CPU, pp, a one-head latent, the families off the auto rule): the
   pure-JAX twins of ops/attention.py, which are also the tests' reference.
 - A layer that hands a ``dsa`` (ops/attention.DsaQuery: latent attention over
   the positions a learned indexer selects, models/mla.py) asks ONE further
@@ -28,6 +28,12 @@ straight through.
   that row's pages in VMEM once and those queries pick their keys there,
   where the table's width fits (a shape of the launch); decode rows, each a
   row of its own, gather token by token from HBM.
+- A layer that hands a ``latent`` (ops/attention.LatentQuery: a latent layer
+  WITHOUT an indexer, the same rows layout, nothing selects) asks the other
+  further question: every query attends over every causal key of its row,
+  ``_latent``. Pallas on, that is the launch
+  ``paged_latent_attention`` (ops/pallas_latent.py), page-contiguous, for
+  decode rows, a chunk and a mixed step alike (one launch each).
 """
 
 from __future__ import annotations
@@ -105,9 +111,36 @@ class PagedAttention:
                 n_chunk=n_chunk, interpret=self.interpret,
             )
 
-    def decode(self, q, kc, vc, tables, seq_lens, dsa=None, **extra):
+    def _latent(self, q, kc, vc, tables, q_lens, seq_lens, latent, n_chunk):
+        """Attention of packed queries ``q [Tq, h, rank + 128]`` over every
+        causal latent row of their contexts: the first ``n_chunk`` queries
+        are ``tables[0]``'s chunk (``q_lens[0]`` of them real), every later
+        query is a one-token row of its own (``q_lens`` 0 = empty)."""
+        with jax.named_scope("latent_attend"):
+            if not self.use_pallas:
+                first = 1 if n_chunk else 0
+                q_starts = jnp.concatenate([
+                    jnp.zeros((first,), jnp.int32),
+                    n_chunk + jnp.arange(tables.shape[0] - first),
+                ])
+                return att.paged_latent_attention(
+                    q, kc, vc, tables, q_starts, q_lens, seq_lens, latent.scale
+                )
+            from . import pallas_latent as plat
+
+            return plat.paged_latent_attention(
+                q, kc, vc, tables, q_lens, seq_lens, scale=latent.scale,
+                n_chunk=n_chunk, interpret=self.interpret,
+            )
+
+    def decode(self, q, kc, vc, tables, seq_lens, dsa=None, latent=None,
+               **extra):
         """Decode rows: ``q [B, h, d]``, one token a row at the end of a
         context of ``seq_lens[b]`` tokens (0 = an empty row)."""
+        if latent is not None:
+            return self._latent(
+                q, kc, vc, tables, seq_lens > 0, seq_lens, latent, 0
+            )
         if dsa is not None:
             return self._selected(
                 q, kc, vc, tables, jnp.arange(q.shape[0]), seq_lens - 1,
@@ -130,10 +163,15 @@ class PagedAttention:
         )
 
     def chunk(self, q, kc, vc, table, chunk_start, total_len, positions,
-              dsa=None, **extra):
+              dsa=None, latent=None, **extra):
         """One chunk at its context's tail: ``q [S_pad, h, d]`` at absolute
         ``positions``, the real ones ``chunk_start .. total_len - 1``, over
         ONE ``table``; the chunk's own keys are already in the cache."""
+        if latent is not None:
+            return self._latent(
+                q, kc, vc, table[None], (total_len - chunk_start)[None],
+                total_len[None], latent, q.shape[0],
+            )
         if dsa is not None:
             S = q.shape[0]
             return self._selected(
@@ -151,12 +189,17 @@ class PagedAttention:
         )
 
     def ragged(self, q, kc, vc, tables, q_starts, q_lens, seq_lens,
-               dsa=None, **extra):
+               dsa=None, latent=None, **extra):
         """Ragged rows over a packed ``q [Tq, h, d]``: row ``r`` owns
         ``q[q_starts[r] : q_starts[r] + q_lens[r]]`` at the tail of its
         context (ops/attention.ragged_paged_attention has the contract).
-        With a ``dsa`` the rows are the mixed step's: row 0 a chunk at the
+        With a ``dsa`` or a ``latent`` the rows are the mixed step's: row 0 a chunk at the
         front of ``q``, every further row one token behind it."""
+        if latent is not None:
+            return self._latent(
+                q, kc, vc, tables, q_lens, seq_lens, latent,
+                q.shape[0] - (tables.shape[0] - 1),
+            )
         if dsa is not None:
             Tq, R = q.shape[0], tables.shape[0]
             n_chunk = Tq - (R - 1)
@@ -172,8 +215,8 @@ class PagedAttention:
         return launch(q, kc, vc, tables, q_starts, q_lens, seq_lens, **extra)
 
     def verify(self, q, kc, vc, tables, seq_lens, **extra):
-        # no ``dsa``: a family with an indexer is refused a speculative
-        # draft at construction (models/registry.check_dsa_supported)
+        # no ``dsa``: a latent held as rows is refused a speculative draft
+        # at construction (models/registry.check_dsa_supported)
         """Ragged rows of one static length: ``q [B, n, h, d]``, row ``b``'s
         ``n`` tokens at the tail of a context of ``seq_lens[b]`` (0 = an
         empty row). The pure-JAX side keeps the batched extend op: the

@@ -1,0 +1,358 @@
+"""Pallas TPU kernel: absorbed latent attention over PAGE-CONTIGUOUS rows.
+
+A latent layer without an indexer (models/mla.py, ``index_topk`` 0) attends
+every query over EVERY causal key of its context. With ``W_uk`` folded into
+the query and ``W_uv`` applied past the softmax, that is multi-query attention
+of all ``h`` heads over one ``[c | k_pe]`` row a key, the values the latent
+``c`` itself. Neither the ragged nor the decode kernel computes it (they score
+each kv "head" of a page on its own) and the sparse kernel gathers tokens;
+this one streams a row's pages, whole.
+
+Layout (ops/attention.py): both paged arrays are ``[num_blocks, block_size,
+rows, 128]`` in bf16, the latent in the first, ``[k_pe | 0]`` in row 0 of the
+second. The launch sees them as ``[tokens, rows, 128]`` and ``[tokens, rows /
+2, 2, 128]``: a page of the first is one copy of ``block_size`` whole tokens,
+and of the second only each token's first ``(2, 128)`` tile is read (the rows
+behind it hold nothing a latent without an indexer wrote): 1 536 bytes a key
+at 512 + 64 lanes, of which 1 152 are the key. In VMEM a pair of rows shares a
+32-bit word, so a chunk's buffers are cut into bf16 matrices by a shift and a
+mask (ops/pallas_sparse._halves) and laid side by side as ONE ``[chunk tokens,
+rank + 128]`` matrix ``[c | k_pe | 0]``: the scores are one product against it
+and the values one product against its first ``rank`` lanes.
+
+Rows of a launch, known when it is traced: optionally ONE chunk row (the
+first ``n_chunk`` packed queries, at the tail of ``tables[0]``'s context),
+then one-token rows, one a table (decode rows; ``q_lens`` 0 = an empty row,
+zeros back). A lone chunk, decode rows and the mixed step are the three
+shapes of it, each ONE launch, all named ``paged_latent_attention``.
+
+Grid: one program a tile of ``Q_TILE`` chunk queries (``Q_TILE * h`` rows
+through the matrix unit at once: bound by the products), then one a decode
+row (``h`` rows: a 128 x 128 array of the matrix unit streams 64 rows a load
+of its weights, so such a row runs well under the rate of its reads; PERF.md
+section 6, PR 33 has the nanoseconds). A program walks its row's pages up to
+its last query's position in chunks of ``pallas_paged.chunk_pages`` pages, two
+slots: chunk ``c + 1`` is in flight while chunk ``c`` is computed. Page copies
+go through a ``PageReader`` whose whole chunk is waited for ONCE an array (a
+DMA semaphore counts bytes; a tail chunk waits page by page). Online softmax
+in float32 scratch; only the chunks from a tile's first query's own position
+on are masked. The first program zeroes the latent's buffer, so a row of it
+holds zeros or a token ever after and a masked key's weight (exactly 0) never
+meets a NaN.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas_paged as paged
+from .attention import LATENT_LANES
+from .pallas_paged import NEG_INF
+from .pallas_sparse import _halves
+
+KERNEL_NAME = "paged_latent_attention"
+# chunk queries a program: Q_TILE x heads rows against a chunk's keys. A lone
+# 512-query chunk over 25k keys at 64 heads ran 19.4 ms at 8, 15.0 at 16 on a
+# v5e (fewer re-reads of the context, more rows a load of the matrix unit's
+# weights; PERF.md section 6, PR 33)
+Q_TILE = 16
+# pages a pass of the loop that starts a whole chunk's copies (the scalar unit
+# issues one descriptor after the other, in the products' instruction stream):
+# 8 decode rows over 25k keys ran 2.35 / 2.20 / 2.14 / 2.14 ms at 1 / 4 / 8 /
+# 16, bitwise the same (PERF.md section 6, PR 33)
+UNROLL = 8
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+class _LatentPages(paged.PageReader):
+    """A chunk's page copies out of the token-row views: a page of the latent
+    whole, of the second array each token's first tile. A whole chunk is
+    started ``UNROLL`` pages a pass and waited for once an array."""
+
+    def __init__(self, tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bs, cp):
+        super().__init__(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem)
+        self.bs, self.cp = bs, cp
+
+    def copies(self, slot, idx, j):
+        (k_hbm, k_buf, ksem), (v_hbm, v_buf, vsem) = self.pairs
+        src = pl.ds(idx * self.bs, self.bs)
+        dst = pl.ds(j * self.bs, self.bs)
+        return [
+            pltpu.make_async_copy(
+                k_hbm.at[src], k_buf.at[slot, dst], ksem.at[slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[src, 0], v_buf.at[slot, dst], vsem.at[slot]),
+        ]
+
+    def start(self, base, num_pages, slot):
+        whole = num_pages == self.cp
+        unroll = UNROLL if self.cp % UNROLL == 0 else 1
+
+        @pl.when(whole)
+        def _chunk():
+            def group(g, carry):
+                for i in range(unroll):
+                    j = g * unroll + i
+                    for copy in self.copies(
+                            slot, self.tables_ref[base + j], j):
+                        copy.start()
+                return carry
+
+            jax.lax.fori_loop(0, self.cp // unroll, group, 0)
+
+        @pl.when(jnp.logical_not(whole))
+        def _tail():
+            super(_LatentPages, self).start(base, num_pages, slot)
+
+    def wait(self, num_pages, slot):
+        whole = num_pages == self.cp
+
+        @pl.when(whole)
+        def _chunk():
+            # never started: the descriptors say how many bytes to wait for
+            for _, buf, sem in self.pairs:
+                pltpu.make_async_copy(
+                    buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+
+        @pl.when(jnp.logical_not(whole))
+        def _tail():
+            super(_LatentPages, self).wait(num_pages, slot)
+
+
+def _kernel(lens_ref, qlens_ref, tables_ref, *refs, bs: int, cp: int,
+            mb: int, lat_rows: int, scale: float, n_ct: int, qt: int,
+            n_one: int):
+    # scalar prefetch (SMEM): lens [R] context lengths, qlens [R] query
+    # lengths, tables [R * mb]
+    it = iter(refs)
+    qc_ref = next(it) if n_ct else None    # VMEM [qt, h, rank + 128]
+    q1_ref = next(it) if n_one else None   # VMEM [1, h, rank + 128]
+    k_hbm = next(it)        # ANY/HBM [nb * bs, rows, 128] the latent
+    v_hbm = next(it)        # ANY/HBM [nb * bs, rows / 2, 2, 128]; [t, 0, 0] = k_pe
+    oc_ref = next(it) if n_ct else None    # VMEM [qt, h, rank]
+    o1_ref = next(it) if n_one else None   # VMEM [1, h, rank]
+    k_buf = next(it)        # VMEM [2, T, rows, 128] bf16
+    v_buf = next(it)        # VMEM [2, T, 2, 128] bf16
+    kcat = next(it)         # VMEM [T, rank + 128] bf16: [c | k_pe | 0]
+    m_scr = next(it)        # VMEM [M, 1] f32
+    l_scr = next(it)        # VMEM [M, 1] f32
+    acc_scr = next(it)      # VMEM [M, rank] f32
+    sem = next(it)          # DMA sems [2 (k / v), 2 (slot)]
+
+    T = cp * bs
+    rank = lat_rows * LATENT_LANES
+    h = (qc_ref if n_ct else q1_ref).shape[1]
+    i = pl.program_id(0)
+    pages = _LatentPages(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bs, cp)
+    k_words = k_buf.bitcast(jnp.uint32)     # [2, T, rows / 2, 128]
+    v_words = v_buf.bitcast(jnp.uint32)     # [2, T, 1, 128]
+
+    @pl.when(i == 0)
+    def _clean():
+        # a masked key's weight is an exact 0, and 0 * NaN = NaN: a row of
+        # the buffer holds zeros until it holds a token, never what VMEM
+        # held before the launch
+        k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+
+    def split(slot):
+        """The chunk in ``slot`` as one ``[T, rank + 128]`` bf16 matrix."""
+        for w in range(lat_rows // 2):
+            for half, x in enumerate(_halves(k_words[slot, :, w, :])):
+                lane0 = (2 * w + half) * LATENT_LANES
+                kcat[:, lane0:lane0 + LATENT_LANES] = x
+        pe, _ = _halves(v_words[slot, :, 0, :])
+        kcat[:, rank:] = pe
+
+    def attend(r, q, q_pos0, n_valid, kv_end):
+        """``q [M, rank + 128]``: ``M / h`` queries of row ``r``, all heads,
+        the first at position ``q_pos0``, ``n_valid`` of them real; keys
+        below ``kv_end``. Returns ``[M, rank]`` f32, zeros for the others."""
+        M = q.shape[0]
+        rows = pl.ds(0, M)
+        m_scr[rows] = jnp.full((M, 1), NEG_INF, jnp.float32)
+        l_scr[rows] = jnp.zeros((M, 1), jnp.float32)
+        acc_scr[rows] = jnp.zeros((M, rank), jnp.float32)
+        n_pages = pl.cdiv(kv_end, bs)
+        n_chunks = pl.cdiv(n_pages, cp)
+        tok = jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0) // h
+        real = tok < n_valid
+        q_pos = q_pos0 + tok
+
+        def count(c):
+            return jnp.minimum(cp, n_pages - c * cp)
+
+        @pl.when(n_chunks > 0)
+        def _first():
+            pages.start(r * mb, count(0), 0)
+
+        def chunk(c, carry, *, masked):
+            slot = jax.lax.rem(c, 2)
+
+            @pl.when(c + 1 < n_chunks)
+            def _next():
+                pages.start(r * mb + (c + 1) * cp, count(c + 1), 1 - slot)
+
+            pages.wait(count(c), slot)
+            split(slot)
+            s = jax.lax.dot_general(
+                q, kcat[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                           # [M, T]
+            if masked:
+                key = c * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+                valid = jnp.logical_and(key <= q_pos, real)
+                s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_scr[rows]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            if masked:
+                # a query that is not real has no key it sees:
+                # exp(NEG_INF - NEG_INF) would be 1
+                p = jnp.where(valid, p, 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            m_scr[rows] = m_new
+            l_scr[rows] = alpha * l_scr[rows] + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_scr[rows] = alpha * acc_scr[rows] + jnp.dot(
+                p.astype(kcat.dtype), kcat[:, :rank],
+                preferred_element_type=jnp.float32,
+            )
+            return carry
+
+        # chunks that end at or below the first query's position hold only
+        # keys every query sees, and no row never read
+        c_tail = jnp.clip(q_pos0 // T, 0, n_chunks)
+        jax.lax.fori_loop(
+            0, c_tail, functools.partial(chunk, masked=False), 0)
+        jax.lax.fori_loop(
+            c_tail, n_chunks, functools.partial(chunk, masked=True), 0)
+        l = l_scr[rows]
+        out = acc_scr[rows] / jnp.where(l > 0, l, 1.0)
+        return jnp.where(real, out, 0.0)
+
+    if n_ct:
+        @pl.when(i < n_ct)
+        def _tile():
+            q_len, seq_len = qlens_ref[0], lens_ref[0]
+            t0 = i * qt
+            n_valid = jnp.where(
+                seq_len > 0, jnp.clip(q_len - t0, 0, qt), 0)
+            q_pos0 = seq_len - q_len + t0
+            kv_end = jnp.where(n_valid > 0, q_pos0 + n_valid, 0)
+            W = qc_ref.shape[2]
+            out = attend(
+                0, qc_ref[...].reshape(qt * h, W), q_pos0, n_valid, kv_end)
+            oc_ref[...] = out.reshape(qt, h, rank).astype(oc_ref.dtype)
+
+    if n_one:
+        @pl.when(i >= n_ct)
+        def _row():
+            r = i - n_ct + (1 if n_ct else 0)
+            seq_len = lens_ref[r]
+            live = jnp.logical_and(qlens_ref[r] > 0, seq_len > 0)
+            out = attend(
+                r, q1_ref[0], seq_len - 1, jnp.where(live, 1, 0),
+                jnp.where(live, seq_len, 0),
+            )
+            o1_ref[0] = out.astype(o1_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "n_chunk", "interpret")
+)
+def paged_latent_attention(
+    q: jax.Array,            # [Tq, h, rank + 128]: [absorbed q | q_pe | 0]
+    k_cache: jax.Array,      # [nb, bs, rows, 128] bf16 the latent
+    v_cache: jax.Array,      # [nb, bs, rows, 128] bf16, row 0 = [k_pe | 0]
+    tables: jax.Array,       # [R, mb] int32
+    q_lens: jax.Array,       # [R] the chunk's real length, then 0 / 1 a row
+    seq_lens: jax.Array,     # [R] context lengths incl. the row's queries
+    *, scale: float, n_chunk: int = 0, interpret: bool = False,
+) -> jax.Array:
+    """ops/attention.paged_latent_attention has the contract; returns
+    [Tq, h, rank]. The first ``n_chunk`` queries are row 0's chunk (its
+    real queries first), every later query is a row of its own."""
+    Tq, h, width = q.shape
+    nb, bs, n_rows, lanes = k_cache.shape
+    R, mb = tables.shape
+    rank = width - lanes
+    lat_rows = rank // lanes
+    if (k_cache.dtype != jnp.bfloat16 or q.dtype != jnp.bfloat16
+            or lanes != LATENT_LANES or rank % (2 * lanes)
+            or n_rows != lat_rows):
+        raise ValueError(
+            "paged_latent_attention reads bf16 pages of 128 lanes a row, the "
+            f"latent an even number of rows; got {k_cache.dtype} "
+            f"{k_cache.shape}, q {q.dtype}, latent rank {rank}"
+        )
+    n_one = Tq - n_chunk
+    if n_one != R - (1 if n_chunk else 0):
+        raise ValueError(
+            f"{Tq} queries of which {n_chunk} are a chunk do not make {R} rows"
+        )
+    qt = Q_TILE
+    n_ct = -(-n_chunk // qt)
+    cp = paged.chunk_pages(bs, n_rows, lanes, k_cache.dtype, mb)
+    T = cp * bs
+    M = max(qt * h if n_ct else 0, h)
+
+    operands, in_specs, out_shapes, out_specs = [], [], [], []
+    if n_ct:
+        qc = jnp.pad(q[:n_chunk], ((0, n_ct * qt - n_chunk), (0, 0), (0, 0)))
+        operands.append(qc)
+        tile = lambda i, *_: (jnp.minimum(i, n_ct - 1), 0, 0)  # noqa: E731
+        in_specs.append(pl.BlockSpec((qt, h, width), tile))
+        out_shapes.append(jax.ShapeDtypeStruct((n_ct * qt, h, rank), q.dtype))
+        out_specs.append(pl.BlockSpec((qt, h, rank), tile))
+    if n_one:
+        operands.append(q[n_chunk:])
+        row = lambda i, *_: (jnp.maximum(i - n_ct, 0), 0, 0)  # noqa: E731
+        in_specs.append(pl.BlockSpec((1, h, width), row))
+        out_shapes.append(jax.ShapeDtypeStruct((n_one, h, rank), q.dtype))
+        out_specs.append(pl.BlockSpec((1, h, rank), row))
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    outs = pl.pallas_call(
+        functools.partial(
+            _kernel, bs=bs, cp=cp, mb=mb, lat_rows=lat_rows, scale=scale,
+            n_ct=n_ct, qt=qt, n_one=n_one,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_ct + n_one,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((2, T, n_rows, lanes), k_cache.dtype),
+                pltpu.VMEM((2, T, 2, lanes), v_cache.dtype),
+                pltpu.VMEM((T, width), k_cache.dtype),
+                pltpu.VMEM((M, 1), jnp.float32),
+                pltpu.VMEM((M, 1), jnp.float32),
+                pltpu.VMEM((M, rank), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=out_shapes,
+        compiler_params=pltpu.CompilerParams(
+            # the zeroed buffer persists across the grid's programs, in order
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(
+        seq_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
+        tables.reshape(-1).astype(jnp.int32), *operands,
+        k_cache.reshape(nb * bs, n_rows, lanes),
+        v_cache.reshape(nb * bs, n_rows // 2, 2, lanes),
+    )
+    parts = []
+    if n_ct:
+        parts.append(outs[0][:n_chunk])
+    if n_one:
+        parts.append(outs[-1])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)

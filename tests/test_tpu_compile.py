@@ -220,6 +220,31 @@ def _cases():
             return pmoe.grouped_matmul(lhs, rhs, sizes)
         return fn, build
 
+    def latent(question, q_tokens, rows, max_blocks=1600):
+        # latent attention over every causal key at A.X-K1's widths and the
+        # document-QA cell's sizes: 64 absorbed heads over 512 + 64 lanes in
+        # rows of 128, 14 336 pages, tables of 1 600 pages (a chunk of 64
+        # pages: the VMEM side of the chunk rule) or of 32 (the table's side)
+        from dynamo_tpu.ops.attention import LatentQuery
+
+        def fn(attn, *args):
+            return getattr(attn, question)(
+                *args, latent=LatentQuery(scale=0.13086))
+        fn.asks_seam = True
+
+        def build(sh):
+            s, *_ = _shapes(sh)
+            page = s((14336, BS, 4, D), BF)
+            q = s((q_tokens, 64, 640), BF)
+            r = s((rows,), I32)
+            if question == "decode":
+                return (q, page, page, s((rows, max_blocks), I32), r)
+            if question == "chunk":
+                return (q, page, page, s((max_blocks,), I32), s((), I32),
+                        s((), I32), s((q_tokens,), I32))
+            return (q, page, page, s((rows, max_blocks), I32), r, r, r)
+        return fn, build
+
     def moves(fn, n_ids, with_pages):
         def build(sh):
             s, cache, _, _, ids = _shapes(sh)
@@ -265,6 +290,12 @@ def _cases():
         "sparse-latent-decode": (sparse_decode, sparse_shapes(8, 8)),
         "sparse-latent-staged-all-the-vmem": sparse_launch(4096),
         "sparse-latent-too-wide-to-stage": sparse_launch(4097),
+        # the seam's dense-latent question (PR 33): decode rows, a lone chunk
+        # and the mixed step, each one launch; the chunk rule's two sides
+        "paged-latent-decode": latent("decode", 8, 8),
+        "paged-latent-chunk-S512": latent("chunk", 512, 1),
+        "paged-latent-mixed": latent("ragged", 520, 9),
+        "paged-latent-mixed-S128-narrow-table": latent("ragged", 136, 9, 32),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),
