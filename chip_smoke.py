@@ -763,14 +763,22 @@ def _kernel_child() -> None:
     # decode rows over 25k keys (tails of 1, 15 and 17 tokens past a page,
     # an empty row), a 512-query chunk at a 25k context's tail, and a mixed
     # step of a 320-query chunk + 8 decode rows in ONE launch; each against
-    # the highest-precision twin, and its nanoseconds a (query, key) pair
+    # the highest-precision twin, and its nanoseconds a (query, key) pair.
+    # Each TWICE: every table a run of consecutive pages (a whole chunk is one
+    # descriptor an array), then the same pages at shuffled places of a second
+    # pool (two descriptors a page): bitwise the same answer
     from dynamo_tpu.ops import pallas_latent as plat
 
     DNB, DMB, dctx = 14401, 1600, 25000
     dlat, daux = rnd(DNB, BS, 4, 128), rnd(DNB, BS, 4, 128)
-    dtables = jnp.asarray(
-        rng.permutation(DNB - 1)[: 9 * DMB].reshape(9, DMB) + 1, jnp.int32
-    )
+    run_tables = 1 + np.arange(9 * DMB).reshape(9, DMB)
+    place = np.concatenate([[0], 1 + rng.permutation(DNB - 1)])
+    back = jnp.asarray(np.argsort(place))
+    layouts = [
+        ("runs", dlat, daux, jnp.asarray(run_tables, jnp.int32)),
+        ("shuffled", dlat[back], daux[back],
+         jnp.asarray(place[run_tables], jnp.int32)),
+    ]
     ref_latent = highest(att.paged_latent_attention)
     dscale = 0.13086
 
@@ -784,35 +792,49 @@ def _kernel_child() -> None:
             q_len0 * (q_len0 - 1) // 2
         )
         lens = jnp.asarray(lens, jnp.int32)
-        tb = dtables[: len(lens)]
         qd = rnd(n_chunk + n_one, 64, 640)
-        run = lambda: plat.paged_latent_attention(  # noqa: E731
-            qd, dlat, daux, tb, q_lens, lens, scale=dscale, n_chunk=n_chunk)
-        got = run()
-        # the twin scores the whole packed buffer for every row: the chunk
-        # and the one-token rows are asked of it apart
-        parts = []
-        if n_chunk:
-            parts.append(ref_latent(
-                qd[:n_chunk], dlat, daux, tb[:1], jnp.zeros((1,), jnp.int32),
-                q_lens[:1], lens[:1], dscale))
-        if n_one:
-            parts.append(ref_latent(
-                qd[n_chunk:], dlat, daux, tb[first:], jnp.arange(n_one),
-                q_lens[first:], lens[first:], dscale))
-        compare(name, got, jnp.concatenate(parts, axis=0))
-        empty = n_chunk + np.flatnonzero(np.asarray(lens[first:]) == 0)
-        if np.asarray(got, np.float32)[empty].any():
-            raise SystemExit(f"{name}: an empty row is not zeros")
-        times = []
-        for _ in range(6):
-            t0 = time.perf_counter()
-            jax.block_until_ready(run())
-            times.append(time.perf_counter() - t0)
-        took = sorted(times[1:])[2]
-        print(f"KERNEL {name}: {took * 1e3:.3f} ms, "
-              f"{took * 1e9 / pairs:.2f} ns a (query, key) pair over "
-              f"{pairs} pairs", flush=True)
+        outs = []
+        for kind, lat_pool, aux_pool, tables in layouts:
+            tb = tables[: len(lens)]
+            whole, as_runs = plat.chunk_reads(lat_pool, tb, q_lens, lens)
+            if int(as_runs) != (int(whole) if kind == "runs" else 0):
+                raise SystemExit(f"{name}, {kind}: {int(as_runs)} of "
+                                 f"{int(whole)} whole chunks are runs")
+            run = lambda: plat.paged_latent_attention(  # noqa: E731
+                qd, lat_pool, aux_pool, tb, q_lens, lens, scale=dscale,
+                n_chunk=n_chunk)
+            got = run()
+            # the twin scores the whole packed buffer for every row: the
+            # chunk and the one-token rows are asked of it apart
+            parts = []
+            if n_chunk:
+                parts.append(ref_latent(
+                    qd[:n_chunk], lat_pool, aux_pool, tb[:1],
+                    jnp.zeros((1,), jnp.int32), q_lens[:1], lens[:1], dscale))
+            if n_one:
+                parts.append(ref_latent(
+                    qd[n_chunk:], lat_pool, aux_pool, tb[first:],
+                    jnp.arange(n_one), q_lens[first:], lens[first:], dscale))
+            compare(f"{name}, {kind}", got, jnp.concatenate(parts, axis=0))
+            empty = n_chunk + np.flatnonzero(np.asarray(lens[first:]) == 0)
+            if np.asarray(got, np.float32)[empty].any():
+                raise SystemExit(f"{name}, {kind}: an empty row is not zeros")
+            times = []
+            for _ in range(6):
+                t0 = time.perf_counter()
+                jax.block_until_ready(run())
+                times.append(time.perf_counter() - t0)
+            took = sorted(times[1:])[2]
+            print(f"KERNEL {name}, {kind} ({int(as_runs)} of {int(whole)} "
+                  f"whole chunks one descriptor an array): {took * 1e3:.3f} "
+                  f"ms, {took * 1e9 / pairs:.2f} ns a (query, key) pair over "
+                  f"{pairs} pairs", flush=True)
+            outs.append(got)
+        if not bool(jnp.all(outs[0] == outs[1])):
+            raise SystemExit(f"{name}: runs and shuffled pages give "
+                             "different bits")
+        print(f"KERNEL {name}: runs and shuffled pages bitwise equal",
+              flush=True)
 
     latent_case(
         "paged_latent_attention 8 decode rows over 25k keys, tails, an "

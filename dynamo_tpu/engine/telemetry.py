@@ -178,6 +178,13 @@ class StepStats:
     # context), and those rows, both summed over layers; the same readback
     mla_keys_attended: Optional[int] = None
     mla_decode_rows: Optional[int] = None
+    # ... and by the chunk: the whole chunks of pages under the contexts of
+    # the step's latent rows (a mixed step's chunk row too), summed over
+    # layers, and those of them whose pages lie one after the other in the
+    # pool, which the kernel reads with one descriptor an array
+    # (ops/pallas_latent.py): counted from the step's own tables
+    mla_chunks_whole: Optional[int] = None
+    mla_chunks_run: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -193,6 +200,14 @@ def moe_load_imbalance(s: StepStats) -> Optional[float]:
     if not s.moe_tokens_routed or not s.moe_experts_touched:
         return None
     return s.moe_load_max * s.moe_experts_touched / s.moe_tokens_routed
+
+
+def run_chunk_share(steps) -> Optional[float]:
+    """Of the whole chunks the steps' latent rows read, the share read as
+    runs of consecutive pages; None where none was read."""
+    whole = sum(s.mla_chunks_whole or 0 for s in steps)
+    run = sum(s.mla_chunks_run or 0 for s in steps)
+    return run / whole if whole else None
 
 
 class EngineTelemetry:
@@ -337,6 +352,7 @@ class EngineTelemetry:
                     "phase": moe.phase,
                     "keys_attended": moe.mla_keys_attended,
                     "decode_rows": moe.mla_decode_rows,
+                    "run_chunk_share": run_chunk_share(recent),
                 }
         return out
 

@@ -604,6 +604,9 @@ def _rows_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
     )
     if cfg.index_topk > 0:
         carry["selected"] = dsa.selected
+    else:
+        # the same tables in every layer: the last layer's count is each's
+        carry["chunk_reads"] = ask["latent"].chunk_reads
     return o
 
 
@@ -685,7 +688,8 @@ def read_counters(cfg: MlaConfig) -> Tuple[str, ...]:
         return ()
     if cfg.index_topk > 0:
         return ("dsa_keys_causal", "dsa_keys_scored", "dsa_keys_selected")
-    return ("mla_keys_attended", "mla_decode_rows")
+    return ("mla_keys_attended", "mla_decode_rows", "mla_chunks_whole",
+            "mla_chunks_run")
 
 
 def forward(
@@ -703,7 +707,8 @@ def forward(
     """``stats`` (moe.RoutingStats): the one-chip grouped expert path counts
     its routing into it, a configuration with an indexer the keys its real
     decode rows saw, scored and attended, and a rows-layout latent without
-    one the keys its real decode rows attended over and those rows."""
+    one the keys its real decode rows attended over, those rows, and the
+    whole chunks of pages its rows read, of which as runs."""
     if lora is not None:
         raise NotImplementedError("LoRA is not supported for the MLA family")
     x = params["embed"][token_ids] if inputs_embeds is None else inputs_embeds
@@ -732,10 +737,15 @@ def forward(
                 ),
             )
         else:
-            # the keys attended over (each row its whole context), the rows
+            # the keys attended over (each row its whole context), the rows;
+            # the whole chunks of pages under the contexts of the step's
+            # latent rows (the chunk's row too) and those read as runs
+            whole, run = carry["chunk_reads"]
             stats.add_reads(
                 mla_keys_attended=seen.sum() * cfg.num_layers,
                 mla_decode_rows=rows.sum() * cfg.num_layers,
+                mla_chunks_whole=whole * cfg.num_layers,
+                mla_chunks_run=run * cfg.num_layers,
             )
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 

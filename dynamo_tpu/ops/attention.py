@@ -478,9 +478,14 @@ class DsaQuery:
 @dataclasses.dataclass
 class LatentQuery:
     """What a latent layer WITHOUT an indexer, its cache held as rows, asks
-    of the attention seam besides q, k and v: every causal key."""
+    of the attention seam besides q, k and v: every causal key. The seam
+    leaves in ``chunk_reads`` what the launch reads by the chunk: the whole
+    chunks under its rows' contexts and those of them that are runs of
+    consecutive pages (two scalars; ops/pallas_latent.chunk_reads). A
+    trace-time object, as a ``DsaQuery`` is."""
 
     scale: float                       # softmax scale (YaRN's factor in it)
+    chunk_reads: Optional[Tuple[jax.Array, jax.Array]] = None
 
 
 def dsa_index_scores(iq: jax.Array, iw: jax.Array, keys: jax.Array) -> jax.Array:

@@ -115,8 +115,14 @@ class PagedAttention:
         """Attention of packed queries ``q [Tq, h, rank + 128]`` over every
         causal latent row of their contexts: the first ``n_chunk`` queries
         are ``tables[0]``'s chunk (``q_lens[0]`` of them real), every later
-        query is a one-token row of its own (``q_lens`` 0 = empty)."""
+        query is a one-token row of its own (``q_lens`` 0 = empty). Leaves
+        in ``latent.chunk_reads`` what the kernel's chunk rule reads of these
+        tables whole and as runs (the step's counters; dead code where no
+        one reads them)."""
+        from . import pallas_latent as plat
+
         with jax.named_scope("latent_attend"):
+            latent.chunk_reads = plat.chunk_reads(kc, tables, q_lens, seq_lens)
             if not self.use_pallas:
                 first = 1 if n_chunk else 0
                 q_starts = jnp.concatenate([
@@ -126,8 +132,6 @@ class PagedAttention:
                 return att.paged_latent_attention(
                     q, kc, vc, tables, q_starts, q_lens, seq_lens, latent.scale
                 )
-            from . import pallas_latent as plat
-
             return plat.paged_latent_attention(
                 q, kc, vc, tables, q_lens, seq_lens, scale=latent.scale,
                 n_chunk=n_chunk, interpret=self.interpret,

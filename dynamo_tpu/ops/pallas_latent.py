@@ -10,15 +10,14 @@ this one streams a row's pages, whole.
 
 Layout (ops/attention.py): both paged arrays are ``[num_blocks, block_size,
 rows, 128]`` in bf16, the latent in the first, ``[k_pe | 0]`` in row 0 of the
-second. The launch sees them as ``[tokens, rows, 128]`` and ``[tokens, rows /
-2, 2, 128]``: a page of the first is one copy of ``block_size`` whole tokens,
-and of the second only each token's first ``(2, 128)`` tile is read (the rows
-behind it hold nothing a latent without an indexer wrote): 1 536 bytes a key
-at 512 + 64 lanes, of which 1 152 are the key. In VMEM a pair of rows shares a
-32-bit word, so a chunk's buffers are cut into bf16 matrices by a shift and a
-mask (ops/pallas_sparse._halves) and laid side by side as ONE ``[chunk tokens,
-rank + 128]`` matrix ``[c | k_pe | 0]``: the scores are one product against it
-and the values one product against its first ``rank`` lanes.
+second. The launch sees both as ``[tokens, rows, 128]``: a page of either is
+one copy of ``block_size`` whole tokens (of the second only row 0 is used; the
+rows behind it ride along: 2 048 bytes a key at 512 + 64 lanes, of which 1 152
+are the key). In VMEM a pair of rows shares a 32-bit word, so a chunk's
+buffers are cut into bf16 matrices by a shift and a mask
+(ops/pallas_sparse._halves) and laid side by side as ONE ``[chunk tokens, rank
++ 128]`` matrix ``[c | k_pe | 0]``: the scores are one product against it and
+the values one product against its first ``rank`` lanes.
 
 Rows of a launch, known when it is traced: optionally ONE chunk row (the
 first ``n_chunk`` packed queries, at the tail of ``tables[0]``'s context),
@@ -32,13 +31,29 @@ row (``h`` rows: a 128 x 128 array of the matrix unit streams 64 rows a load
 of its weights, so such a row runs well under the rate of its reads; PERF.md
 section 6, PR 33 has the nanoseconds). A program walks its row's pages up to
 its last query's position in chunks of ``pallas_paged.chunk_pages`` pages, two
-slots: chunk ``c + 1`` is in flight while chunk ``c`` is computed. Page copies
-go through a ``PageReader`` whose whole chunk is waited for ONCE an array (a
-DMA semaphore counts bytes; a tail chunk waits page by page). Online softmax
-in float32 scratch; only the chunks from a tile's first query's own position
-on are masked. The first program zeroes the latent's buffer, so a row of it
-holds zeros or a token ever after and a masked key's weight (exactly 0) never
-meets a NaN.
+slots: chunk ``c + 1`` is in flight while chunk ``c`` is computed. Online
+softmax in float32 scratch; only the chunks from a tile's first query's own
+position on are masked. The first program zeroes the latent's buffer, so a row
+of it holds zeros or a token ever after and a masked key's weight (exactly 0)
+never meets a NaN.
+
+How a chunk is read (``_LatentPages``) is chosen a chunk, from what the tables
+hold. The scalar unit issues copy descriptors in the same instruction stream
+as the products, so a descriptor costs the launch its issue time whatever it
+moves (the bytes themselves arrive at 87% of the byte peak under the products).
+A WHOLE chunk whose ``chunk_pages`` table entries are consecutive block ids (a
+prompt admitted in one go into a pool that hands out low ids first; ``runs``,
+one compare of the tables in the launch's XLA wrapper, handed in as a fourth
+scalar-prefetch operand) is ONE descriptor an array: 1 MiB, contiguous in the
+token view. Every other whole chunk is started page by page, two descriptors
+a page, ``UNROLL`` pages a pass; either way it is waited for ONCE an array (a
+DMA semaphore counts bytes). A tail chunk starts and waits page by page. On a
+v5e, 64 heads, 25 000-key contexts, launches chained inside one jit (PERF.md
+section 6, PR 34): 8 decode rows 1.199 ms as runs, 1.516 page by page (the
+products alone 1.147, the copies alone 0.43 either way); a 512-query chunk
+12.57 / 13.84 (12.39); a 320-query chunk + 8 rows 9.13 / 10.24 (8.93): a page's
+two descriptors cost a launch about 26 ns, a run's two about nothing. The
+output is bitwise the same whichever way a chunk came in.
 """
 
 from __future__ import annotations
@@ -61,49 +76,96 @@ KERNEL_NAME = "paged_latent_attention"
 # v5e (fewer re-reads of the context, more rows a load of the matrix unit's
 # weights; PERF.md section 6, PR 33)
 Q_TILE = 16
-# pages a pass of the loop that starts a whole chunk's copies (the scalar unit
-# issues one descriptor after the other, in the products' instruction stream):
-# 8 decode rows over 25k keys ran 2.35 / 2.20 / 2.14 / 2.14 ms at 1 / 4 / 8 /
-# 16, bitwise the same (PERF.md section 6, PR 33)
+# pages a pass of the loop that starts, page by page, a whole chunk that is not
+# a run (the scalar unit issues one descriptor after the other, in the
+# products' instruction stream): 8 decode rows over 25k keys ran 2.35 / 2.20 /
+# 2.14 / 2.14 ms at 1 / 4 / 8 / 16, bitwise the same (PERF.md section 6, PR 33;
+# launches timed alone, 0.65 ms of dispatch in each). What is left at 8 is the
+# descriptors themselves: 1.516 ms against 1.199 where every whole chunk is a
+# run and 1.147 with no copy at all (PR 34)
 UNROLL = 8
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-class _LatentPages(paged.PageReader):
-    """A chunk's page copies out of the token-row views: a page of the latent
-    whole, of the second array each token's first tile. A whole chunk is
-    started ``UNROLL`` pages a pass and waited for once an array."""
+def chunk_runs(tables: jax.Array, cp: int) -> jax.Array:
+    """``[R, mb // cp]`` bool: which whole chunks of ``cp`` entries of each
+    table are consecutive block ids, so that a chunk's tokens lie one after
+    the other in the pool. Every neighbour is compared: first and last id
+    alone do not prove a run (``[5, 100, 7, 8]``)."""
+    R, mb = tables.shape
+    n = mb // cp
+    t = tables[:, :n * cp].reshape(R, n, cp)
+    return jnp.all(t[:, :, 1:] == t[:, :, :-1] + 1, axis=-1)
 
-    def __init__(self, tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bs, cp):
+
+def _chunk_pages(k_cache: jax.Array, mb: int) -> int:
+    _, bs, n_rows, lanes = k_cache.shape
+    return paged.chunk_pages(bs, n_rows, lanes, k_cache.dtype, mb)
+
+
+def chunk_reads(k_cache: jax.Array, tables: jax.Array, q_lens: jax.Array,
+                seq_lens: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """What a launch over these rows reads by the chunk: the whole chunks
+    under its rows' contexts (a row with no query reads nothing; a chunk row
+    counts once, whatever its tiles), and those of them read as runs."""
+    bs = k_cache.shape[1]
+    cp = _chunk_pages(k_cache, tables.shape[1])
+    n_pages = jnp.where(q_lens > 0, -(-seq_lens // bs), 0)
+    runs = chunk_runs(tables, cp)
+    read = jnp.arange(runs.shape[1])[None, :] < (n_pages // cp)[:, None]
+    return jnp.sum(read), jnp.sum(read & runs)
+
+
+class _LatentPages(paged.PageReader):
+    """A chunk's copies out of the token-row views, both arrays alike. A
+    whole chunk that ``runs_ref`` marks is ONE descriptor an array; any other
+    whole chunk is started ``UNROLL`` pages a pass; both are waited for once
+    an array. A tail chunk goes page by page (the base class)."""
+
+    def __init__(self, tables_ref, runs_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
+                 bs, cp):
         super().__init__(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem)
-        self.bs, self.cp = bs, cp
+        self.runs_ref, self.bs, self.cp = runs_ref, bs, cp
 
     def copies(self, slot, idx, j):
-        (k_hbm, k_buf, ksem), (v_hbm, v_buf, vsem) = self.pairs
         src = pl.ds(idx * self.bs, self.bs)
         dst = pl.ds(j * self.bs, self.bs)
         return [
-            pltpu.make_async_copy(
-                k_hbm.at[src], k_buf.at[slot, dst], ksem.at[slot]),
-            pltpu.make_async_copy(
-                v_hbm.at[src, 0], v_buf.at[slot, dst], vsem.at[slot]),
+            pltpu.make_async_copy(hbm.at[src], buf.at[slot, dst], sem.at[slot])
+            for hbm, buf, sem in self.pairs
         ]
 
-    def start(self, base, num_pages, slot):
+    def start(self, base, num_pages, slot, chunk):
+        """``chunk``: the chunk's place in ``runs_ref`` (read only where the
+        chunk is whole: a row's tail chunk may lie past its last entry)."""
         whole = num_pages == self.cp
         unroll = UNROLL if self.cp % UNROLL == 0 else 1
 
         @pl.when(whole)
         def _chunk():
-            def group(g, carry):
-                for i in range(unroll):
-                    j = g * unroll + i
-                    for copy in self.copies(
-                            slot, self.tables_ref[base + j], j):
-                        copy.start()
-                return carry
+            run = self.runs_ref[chunk] != 0
 
-            jax.lax.fori_loop(0, self.cp // unroll, group, 0)
+            @pl.when(run)
+            def _run():
+                src = pl.ds(
+                    pl.multiple_of(self.tables_ref[base] * self.bs, self.bs),
+                    self.cp * self.bs,
+                )
+                for hbm, buf, sem in self.pairs:
+                    pltpu.make_async_copy(
+                        hbm.at[src], buf.at[slot], sem.at[slot]).start()
+
+            @pl.when(jnp.logical_not(run))
+            def _pages():
+                def group(g, carry):
+                    for i in range(unroll):
+                        j = g * unroll + i
+                        for copy in self.copies(
+                                slot, self.tables_ref[base + j], j):
+                            copy.start()
+                    return carry
+
+                jax.lax.fori_loop(0, self.cp // unroll, group, 0)
 
         @pl.when(jnp.logical_not(whole))
         def _tail():
@@ -114,7 +176,8 @@ class _LatentPages(paged.PageReader):
 
         @pl.when(whole)
         def _chunk():
-            # never started: the descriptors say how many bytes to wait for
+            # never started: the descriptors say how many bytes to wait for,
+            # the same however the chunk was started
             for _, buf, sem in self.pairs:
                 pltpu.make_async_copy(
                     buf.at[slot], buf.at[slot], sem.at[slot]).wait()
@@ -124,20 +187,20 @@ class _LatentPages(paged.PageReader):
             super(_LatentPages, self).wait(num_pages, slot)
 
 
-def _kernel(lens_ref, qlens_ref, tables_ref, *refs, bs: int, cp: int,
-            mb: int, lat_rows: int, scale: float, n_ct: int, qt: int,
+def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
+            cp: int, mb: int, lat_rows: int, scale: float, n_ct: int, qt: int,
             n_one: int):
     # scalar prefetch (SMEM): lens [R] context lengths, qlens [R] query
-    # lengths, tables [R * mb]
+    # lengths, tables [R * mb], runs [R * (mb // cp)] (chunk_runs)
     it = iter(refs)
     qc_ref = next(it) if n_ct else None    # VMEM [qt, h, rank + 128]
     q1_ref = next(it) if n_one else None   # VMEM [1, h, rank + 128]
     k_hbm = next(it)        # ANY/HBM [nb * bs, rows, 128] the latent
-    v_hbm = next(it)        # ANY/HBM [nb * bs, rows / 2, 2, 128]; [t, 0, 0] = k_pe
+    v_hbm = next(it)        # ANY/HBM [nb * bs, rows, 128]; [t, 0] = k_pe
     oc_ref = next(it) if n_ct else None    # VMEM [qt, h, rank]
     o1_ref = next(it) if n_one else None   # VMEM [1, h, rank]
     k_buf = next(it)        # VMEM [2, T, rows, 128] bf16
-    v_buf = next(it)        # VMEM [2, T, 2, 128] bf16
+    v_buf = next(it)        # VMEM [2, T, rows, 128] bf16
     kcat = next(it)         # VMEM [T, rank + 128] bf16: [c | k_pe | 0]
     m_scr = next(it)        # VMEM [M, 1] f32
     l_scr = next(it)        # VMEM [M, 1] f32
@@ -148,9 +211,11 @@ def _kernel(lens_ref, qlens_ref, tables_ref, *refs, bs: int, cp: int,
     rank = lat_rows * LATENT_LANES
     h = (qc_ref if n_ct else q1_ref).shape[1]
     i = pl.program_id(0)
-    pages = _LatentPages(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bs, cp)
+    pages = _LatentPages(
+        tables_ref, runs_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bs, cp)
     k_words = k_buf.bitcast(jnp.uint32)     # [2, T, rows / 2, 128]
-    v_words = v_buf.bitcast(jnp.uint32)     # [2, T, 1, 128]
+    v_words = v_buf.bitcast(jnp.uint32)     # [2, T, rows / 2, 128]
+    n_runs = mb // cp                       # runs_ref entries a row
 
     @pl.when(i == 0)
     def _clean():
@@ -188,14 +253,15 @@ def _kernel(lens_ref, qlens_ref, tables_ref, *refs, bs: int, cp: int,
 
         @pl.when(n_chunks > 0)
         def _first():
-            pages.start(r * mb, count(0), 0)
+            pages.start(r * mb, count(0), 0, r * n_runs)
 
         def chunk(c, carry, *, masked):
             slot = jax.lax.rem(c, 2)
 
             @pl.when(c + 1 < n_chunks)
             def _next():
-                pages.start(r * mb + (c + 1) * cp, count(c + 1), 1 - slot)
+                pages.start(r * mb + (c + 1) * cp, count(c + 1), 1 - slot,
+                            r * n_runs + c + 1)
 
             pages.wait(count(c), slot)
             split(slot)
@@ -297,8 +363,9 @@ def paged_latent_attention(
         )
     qt = Q_TILE
     n_ct = -(-n_chunk // qt)
-    cp = paged.chunk_pages(bs, n_rows, lanes, k_cache.dtype, mb)
+    cp = _chunk_pages(k_cache, mb)
     T = cp * bs
+    tables = tables.astype(jnp.int32)
     M = max(qt * h if n_ct else 0, h)
 
     operands, in_specs, out_shapes, out_specs = [], [], [], []
@@ -322,13 +389,13 @@ def paged_latent_attention(
             n_ct=n_ct, qt=qt, n_one=n_one,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(n_ct + n_one,),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, T, n_rows, lanes), k_cache.dtype),
-                pltpu.VMEM((2, T, 2, lanes), v_cache.dtype),
+                pltpu.VMEM((2, T, n_rows, lanes), v_cache.dtype),
                 pltpu.VMEM((T, width), k_cache.dtype),
                 pltpu.VMEM((M, 1), jnp.float32),
                 pltpu.VMEM((M, 1), jnp.float32),
@@ -346,9 +413,10 @@ def paged_latent_attention(
         name=KERNEL_NAME,
     )(
         seq_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
-        tables.reshape(-1).astype(jnp.int32), *operands,
+        tables.reshape(-1), chunk_runs(tables, cp).reshape(-1).astype(jnp.int32),
+        *operands,
         k_cache.reshape(nb * bs, n_rows, lanes),
-        v_cache.reshape(nb * bs, n_rows // 2, 2, lanes),
+        v_cache.reshape(nb * bs, n_rows, lanes),
     )
     parts = []
     if n_ct:

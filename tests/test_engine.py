@@ -111,6 +111,27 @@ class TestBlockAllocator:
         with pytest.raises(OutOfBlocks):
             a.allocate(1)
 
+    def test_a_prompt_gets_runs_of_consecutive_pages_also_after_eviction(self):
+        """What ops/pallas_latent.py's one-descriptor chunk leans on and this
+        allocator gives without being asked: a prompt admitted into a fresh
+        pool gets consecutive ids, low ones first; sealed pages released in
+        table order are evicted in that order, so a second long prompt that
+        has to evict them still gets runs, broken only where the free list
+        ends and eviction begins."""
+        cp = 64                                   # pages a chunk at 512 + 64 lanes
+        a = BlockAllocator(1 + 4000, 16)
+        doc = a.allocate(1536)
+        assert doc == list(range(1, 1537))
+        for bid, h in zip(doc, compute_sequence_hashes(list(range(1536 * 16)), 16)):
+            a.commit(bid, h)
+        a.release(doc)                            # a table, walked in order
+        assert a.cached_blocks == 1536
+        second = a.allocate(3000)                 # 2464 free, then 536 evicted
+        assert second == list(range(1537, 4001)) + list(range(1, 537))
+        chunks = np.asarray(second[: 3000 // cp * cp]).reshape(-1, cp)
+        runs = (np.diff(chunks, axis=1) == 1).all(axis=1)
+        assert runs.sum() == len(runs) - 1 and not runs[2464 // cp]
+
 
 # ------------------------------------------------------------------- engine
 def tiny_engine(tp=1, **kw) -> TpuEngine:

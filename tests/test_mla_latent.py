@@ -19,6 +19,7 @@ from benchmarks import system
 from benchmarks.adapters import mla as adapter
 from benchmarks.reference import mla_decoder as ref
 from dynamo_tpu.engine.engine import TpuEngine, TpuEngineConfig
+from dynamo_tpu.engine.telemetry import run_chunk_share
 from dynamo_tpu.models import mla, moe as moelib, registry
 from dynamo_tpu.ops import attention as att
 from dynamo_tpu.ops import pallas_latent as plat
@@ -95,7 +96,8 @@ def test_the_engine_takes_the_rows_layout_on_the_one_chip_text_path():
     assert cfg.rows_capable and not cfg.latent_rows and (cfg.num_kv_heads, cfg.head_dim) == (1, 272)
     placed = registry.place_latent(cfg, **ONE_CHIP)
     assert placed.latent_rows and (placed.num_kv_heads, placed.head_dim, placed.index_topk) == (2, 128, 0)
-    assert registry.read_counters(placed) == ("mla_keys_attended", "mla_decode_rows")
+    assert registry.read_counters(placed) == (
+        "mla_keys_attended", "mla_decode_rows", "mla_chunks_whole", "mla_chunks_run")
     assert registry.read_counters(cfg) == ()
     pub = mla.MlaConfig.axk1()
     assert (pub.num_kv_heads, pub.head_dim) == (1, 576)
@@ -261,11 +263,18 @@ async def test_chunked_prefill_and_decode_match_the_reference_in_float32():
     assert all(s.mla_decode_rows is None for s in steps if s.phase == "prefill")
 
 
-async def test_the_interpreted_kernel_serves_mixed_steps_in_bfloat16():
+async def test_the_interpreted_kernel_serves_mixed_steps_in_bfloat16(monkeypatch):
     """use_pallas forced on the CPU: the launch paged_latent_attention and
     the Pallas expert multiplication run interpreted, chunks ride fused
     mixed steps. bf16 against the float32 reference moves a tiny model's
-    logprobs by a few hundredths; a skipped layer moves them by far more."""
+    logprobs by a few hundredths; a skipped layer moves them by far more.
+    At 2 pages a chunk the contexts hold whole chunks: those of a prompt,
+    admitted in one go into a fresh pool, are runs of consecutive pages and
+    come in as one copy an array; a page a row took while decoding beside
+    the others breaks its chunk's run, and the step's counters say so."""
+    from dynamo_tpu.ops import pallas_paged as paged
+
+    monkeypatch.setattr(paged, "chunk_pages", lambda *a: 2)
     cfg = file_cfg("bfloat16", reference_tolerance={"worst_nat": 0.7, "mean_nat": 0.07})
     engine = engine_of(cfg, use_pallas=True)
     steps = []
@@ -282,6 +291,10 @@ async def test_the_interpreted_kernel_serves_mixed_steps_in_bfloat16():
         engine.stop()
     assert {"mixed", "decode"} <= {s.phase for s in steps}
     assert any(s.mla_keys_attended for s in steps if s.phase == "mixed")
+    counted = [s for s in steps if s.mla_chunks_whole is not None]
+    assert counted and all(s.phase in ("decode", "mixed") for s in counted)
+    assert all(0 <= s.mla_chunks_run <= s.mla_chunks_whole and s.mla_chunks_whole % 3 == 0 for s in counted)
+    assert 0.5 < run_chunk_share(steps) < 1.0 and run_chunk_share([s for s in steps if s.phase == "prefill"]) is None
 
 
 @pytest.mark.parametrize("what,kw", [
@@ -485,6 +498,121 @@ def test_the_kernel_across_chunks_and_tails(monkeypatch, chunk_pages):
         qc, kc, vc, tables, jnp.asarray([0, 24, 25]), q_lens, seq, 0.125), np.float32)
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
     assert not got[13:24].any()
+
+
+# a whole chunk of consecutive pages is ONE descriptor an array -------------
+def _run_tables(cp):
+    """Three rows of three chunks, each row consecutive ids, and ids to
+    spare behind them."""
+    mb = 3 * cp
+    return np.arange(1, 3 * mb + 1).reshape(3, mb), 3 * mb + 1
+
+
+def _spoil(cp, *places):
+    tables, spare = _run_tables(cp)
+    for i, place in enumerate(places):
+        tables[0, place] = spare + i
+    return tables
+
+
+def _swapped(cp):
+    # [f, f + 2, f + 1, f + 3, ...]: first and last id are a run's, the middle
+    # is not (at 2 pages a chunk the two sit either side of an edge: [5, 100 |
+    # 7, 8] in small)
+    tables, _ = _run_tables(cp)
+    tables[0, [1, 2]] = tables[0, [2, 1]]
+    return tables
+
+
+RUN_TABLES = {
+    "all_runs": lambda cp: _run_tables(cp)[0],
+    "no_runs": lambda cp: np.random.default_rng(cp).permutation(
+        np.arange(1, 9 * cp + 1)).reshape(3, 3 * cp),
+    "broken_at_first_page": lambda cp: _spoil(cp, 0),
+    "broken_at_middle_page": lambda cp: _spoil(cp, cp + cp // 2),
+    "broken_at_last_page": lambda cp: _spoil(cp, 2 * cp - 1),
+    "descending": lambda cp: _run_tables(cp)[0][:, ::-1].copy(),
+    "first_and_last_a_runs_middle_not": _swapped,
+    # ids 2 .. 2 cp - 1 lie one after the other across the edge of chunks 0
+    # and 1, and neither chunk is a run
+    "run_across_a_chunks_edge": lambda cp: _spoil(cp, 0, 2 * cp - 1),
+}
+# the launch's shape on tables that are all runs: (n_chunk, q_lens, contexts
+# in tokens as a function of a chunk's T)
+RUN_SHAPES = {
+    "only_chunk_a_tail": (0, [1, 1, 1], lambda T: [T - BS, 3, T // 2]),
+    "an_empty_row": (0, [1, 0, 1], lambda T: [2 * T + 5, 0, 3 * T]),
+    "lone_chunk": (24, [13], lambda T: [2 * T + 7]),
+    "decode_rows": (0, [1, 1, 1], lambda T: [T + 1, 3 * T, 2 * T]),
+    "mixed_launch": (24, [13, 1, 1], lambda T: [3 * T, 2 * T + 15, T]),
+}
+
+
+def _numpy_runs(tables, cp):
+    """chunk_runs the slow way: a chunk's ids are first, first + 1, ..."""
+    R, mb = tables.shape
+    return np.asarray([[
+        list(tables[r, c * cp:(c + 1) * cp]) == list(range(tables[r, c * cp], tables[r, c * cp] + cp))
+        for c in range(mb // cp)] for r in range(R)])
+
+
+@pytest.mark.parametrize("chunk_pages", [2, 5, 8])
+@pytest.mark.parametrize("case", [*RUN_TABLES, *RUN_SHAPES])
+def test_a_run_of_pages_is_read_as_one_copy_and_changes_no_bit(monkeypatch, case, chunk_pages):
+    """The launch against itself with no chunk a run: the same pages at
+    shuffled places of a second pool, the tables following them, so that every
+    whole chunk goes page by page there. Bitwise the same, for tables that
+    are runs, that are not, that break a run at a chunk's first, middle or
+    last page or only in its middle, that descend, that run across a chunk's
+    edge; for a row whose only chunk is a tail, an empty row, a lone chunk,
+    decode rows and the mixed launch; the counter reads what the tables hold."""
+    from dynamo_tpu.ops import pallas_paged as paged
+
+    cp = chunk_pages
+    monkeypatch.setattr(paged, "chunk_pages", lambda *a: cp)
+    T = cp * BS
+    n_chunk, q_lens, lens = RUN_SHAPES.get(case, RUN_SHAPES["mixed_launch"])
+    lens = lens(T)
+    tables = RUN_TABLES.get(case, RUN_TABLES["all_runs"])(cp)[:len(lens)]
+    nb = 9 * cp + 3
+    rng = np.random.default_rng(cp)
+    kc, vc = (jnp.asarray(rng.normal(size=(nb, BS, ROWS, 128)), jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(n_chunk + len(lens) - bool(n_chunk), H, RANK + 128)), jnp.bfloat16)
+    q_lens, seq = jnp.asarray(q_lens, jnp.int32), jnp.asarray(lens, jnp.int32)
+
+    def launch(kc, vc, tables):
+        return plat.paged_latent_attention(
+            q, kc, vc, jnp.asarray(tables, jnp.int32), q_lens, seq, scale=0.125,
+            n_chunk=n_chunk, interpret=True)
+
+    # the same pages somewhere else: page i of the pool at place[i]
+    while True:
+        place = rng.permutation(nb)
+        if not _numpy_runs(place[tables], cp).any():
+            break
+    back = np.argsort(place)
+    got, want = launch(kc, vc, tables), launch(kc[back], vc[back], place[tables])
+    assert bool(jnp.all(got == want))
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), n_chunk + jnp.arange(len(lens) - 1)]) \
+        if n_chunk else jnp.arange(len(lens))
+    twin = att.paged_latent_attention(q, kc, vc, jnp.asarray(tables), starts, q_lens, seq, 0.125)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(twin, np.float32), atol=2e-2, rtol=2e-2)
+    # what the kernel was told and what the step counts: every neighbour compared
+    runs = _numpy_runs(tables, cp)
+    assert (np.asarray(plat.chunk_runs(jnp.asarray(tables), cp)) == runs).all()
+    whole = [(-(-n // BS)) // cp if ql else 0 for n, ql in zip(lens, np.asarray(q_lens))]
+    n_whole = sum(whole)
+    n_run = sum(int(runs[r, :w].sum()) for r, w in enumerate(whole))
+    counted = tuple(int(x) for x in plat.chunk_reads(kc, jnp.asarray(tables), q_lens, seq))
+    assert counted == (n_whole, n_run)
+    assert tuple(int(x) for x in plat.chunk_reads(kc, jnp.asarray(place[tables]), q_lens, seq)) == (n_whole, 0)
+    share = {"all_runs": 1.0, "no_runs": 0.0, "descending": 0.0, "decode_rows": 1.0, "mixed_launch": 1.0,
+             "lone_chunk": 1.0, "broken_at_first_page": 5 / 6, "broken_at_middle_page": 5 / 6,
+             "broken_at_last_page": 5 / 6, "run_across_a_chunks_edge": 4 / 6}
+    if case in share:
+        assert n_whole and n_run / n_whole == share[case]
+    if case == "only_chunk_a_tail":
+        assert counted == (0, 0)
 
 
 def test_the_launch_refuses_what_it_cannot_read():
