@@ -111,22 +111,13 @@ def _phase_summary(samples: list) -> dict:
         ),
         "mean_tokens_per_step": round(sum(s.tokens for s in samples) / n, 2),
     }
-    # async host step-prep overlap (engine/prep.py, DTPU_ASYNC_PREP): how
-    # many chunk-carrying steps consumed a prebuilt pack, the host-prep ms
-    # that ran UNDER the previous step's device compute, and the residual
-    # wait the dispatch still paid
+    # async host step-prep (engine/prep.py, DTPU_ASYNC_PREP): how many
+    # chunk-carrying steps consumed a prebuilt pack
     prepped = [s for s in samples if getattr(s, "prep_hit", None) is not None]
     if prepped:
-        hits = [s for s in prepped if s.prep_hit]
         out["prep"] = {
             "steps": len(prepped),
-            "hits": len(hits),
-            "overlapped_build_ms": round(
-                sum(s.prep_build_s for s in hits) * 1e3, 3
-            ),
-            "residual_wait_ms": round(
-                sum(s.prep_wait_s for s in hits) * 1e3, 3
-            ),
+            "hits": sum(1 for s in prepped if s.prep_hit),
         }
     return out
 

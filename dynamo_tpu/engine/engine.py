@@ -69,7 +69,16 @@ from ..runtime.tracing import get_tracer
 from ..tokens import TokenBlockSequence
 from .allocator import BlockAllocator, OutOfBlocks
 from . import step_args
-from .telemetry import PENDING_SPANS_MAX, StepStats, loop_span, pending_spans
+from .telemetry import (
+    PENDING_SPANS_MAX,
+    StepStats,
+    loop_span,
+    now_ns,
+    pending_request_spans,
+    pending_spans,
+    record_request_span,
+    submit_span,
+)
 from .sampling import (
     TOP_LOGPROBS_K,
     apply_penalties,
@@ -319,9 +328,11 @@ class _Seq:
     # draft prefill: their draft KV would never be read.
     spec_ok: bool = True
     done: bool = False
-    # lifecycle milestones (unix ns, 0 = not reached): stamped host-side by
-    # the loop / accept path, turned into engine.queue / engine.prefill /
-    # engine.decode spans + flight-recorder events when the request finishes
+    # lifecycle milestones (0 = not reached) on time.monotonic_ns(), the
+    # clock of the loop's spans (telemetry.now_ns): stamped host-side by the
+    # loop / accept path, turned into engine.queue / engine.prefill /
+    # engine.decode spans and the SLO ledger's intervals when the request
+    # finishes; TpuEngine._unix_ns moves one onto the wall clock
     t_queued: int = 0
     t_admitted: int = 0
     t_prefill_start: int = 0
@@ -771,6 +782,15 @@ class TpuEngine:
         # after every prefill chunk / consumed decode horizon; None = off.
         # Workers wire EngineTelemetry.on_step; bench.py wires a collector.
         self.stats_hook: Optional[Any] = None
+        # pending spans and admission waits since the last StepStats
+        # (engine/telemetry.py loop_span): filled only while stats_hook is
+        # set, bounded, carried away by _step_stats. The loop's own, and
+        # those with a request for a subject (``submit``, ``deliver``)
+        self._host_spans = pending_spans()
+        self._request_spans = pending_request_spans()
+        self._admit_waits: deque = deque(maxlen=PENDING_SPANS_MAX)
+        # wall clock less the loop's monotonic one, taken as the loop starts
+        self._wall_offset_ns = 0
         self._executor = ThreadPoolExecutor(max_workers=1, thread_name_prefix="tpu-step")
         # result readback pool: each in-flight horizon's packed fetch runs on
         # its own thread; a fetch waits for its horizon's compute (latency,
@@ -2343,272 +2363,290 @@ class TpuEngine:
     async def generate(
         self, request: Any, context: Context
     ) -> AsyncIterator[BackendOutput]:
-        req = request if isinstance(request, PreprocessedRequest) else (
-            PreprocessedRequest.from_obj(request)
-        )
-        n_prompt = len(req.token_ids) + len(req.prior_token_ids)
-        if n_prompt >= self.cfg.max_context:
-            raise ValueError(
-                f"prompt {n_prompt} tokens exceeds engine max_context "
-                f"{self.cfg.max_context}"
+        # ``submit`` (engine/telemetry.py): this thread is the step loop's too,
+        # and what it does here it does inside the loop's yield / idle / awaits
+        with submit_span(self) as sub:
+            req = request if isinstance(request, PreprocessedRequest) else (
+                PreprocessedRequest.from_obj(request)
             )
-        if n_prompt // self.cfg.block_size + 2 > self.cfg.num_blocks:
-            # would wait forever in admission — no amount of eviction frees
-            # enough pages for this prompt
-            raise ContextLengthError(
-                f"prompt {n_prompt} tokens cannot fit the KV pool "
-                f"({self.cfg.num_blocks} blocks x {self.cfg.block_size})"
-            )
-        wanted_procs = req.annotations.get("logits_processors") or []
-        if wanted_procs:
-            known = {n for n, _ in self.cfg.logits_processors}
-            bad = [n for n in wanted_procs if n not in known]
-            if bad:
-                raise InvalidRequestError(f"unknown logits processors {bad!r}")
-        lora_name = req.annotations.get("lora")
-        if lora_name:
-            if self.lora is None:
-                raise InvalidRequestError("engine built without LoRA support")
-            if self.lora.slot_of(lora_name) == 0:
-                raise InvalidRequestError(f"unknown LoRA adapter {lora_name!r}")
-        guided_tables = None
-        if req.sampling.guided is not None:
-            if not self.guided_enabled:
-                # soft specs (derived, e.g. from a forced tool_choice —
-                # llm/preprocessor.py) degrade to unconstrained sampling;
-                # explicit guided_* options fail loudly
-                if not req.sampling.guided.get("soft"):
-                    raise GuidedRejectedError(
-                        "engine built without guided decoding "
-                        "(guided_max_states=0)"
-                    )
-            else:
-                try:
-                    guided_tables = await self._compile_guided(
-                        req.sampling.guided
-                    )
-                except ValueError:
+            sub.request_id = req.request_id
+            n_prompt = len(req.token_ids) + len(req.prior_token_ids)
+            if n_prompt >= self.cfg.max_context:
+                raise ValueError(
+                    f"prompt {n_prompt} tokens exceeds engine max_context "
+                    f"{self.cfg.max_context}"
+                )
+            if n_prompt // self.cfg.block_size + 2 > self.cfg.num_blocks:
+                # would wait forever in admission — no amount of eviction frees
+                # enough pages for this prompt
+                raise ContextLengthError(
+                    f"prompt {n_prompt} tokens cannot fit the KV pool "
+                    f"({self.cfg.num_blocks} blocks x {self.cfg.block_size})"
+                )
+            wanted_procs = req.annotations.get("logits_processors") or []
+            if wanted_procs:
+                known = {n for n, _ in self.cfg.logits_processors}
+                bad = [n for n in wanted_procs if n not in known]
+                if bad:
+                    raise InvalidRequestError(f"unknown logits processors {bad!r}")
+            lora_name = req.annotations.get("lora")
+            if lora_name:
+                if self.lora is None:
+                    raise InvalidRequestError("engine built without LoRA support")
+                if self.lora.slot_of(lora_name) == 0:
+                    raise InvalidRequestError(f"unknown LoRA adapter {lora_name!r}")
+            guided_tables = None
+            if req.sampling.guided is not None:
+                if not self.guided_enabled:
+                    # soft specs (derived, e.g. from a forced tool_choice —
+                    # llm/preprocessor.py) degrade to unconstrained sampling;
+                    # explicit guided_* options fail loudly
                     if not req.sampling.guided.get("soft"):
-                        raise
-                    # reference behavior: a failed tool-choice derivation
-                    # logs and serves unconstrained (common_ext.rs:190)
-                    log.warning(
-                        "soft guided grammar rejected; serving unconstrained"
-                    )
-        if req.annotations.get("op") == "embed":
-            loop = asyncio.get_event_loop()
-            block_ids: Optional[List[int]] = None
-            S = len(req.token_ids)
-            if S > self.cfg.prefill_chunk:
-                # long input: temporary pages for the chunked pooled forward
-                # (allocated here on the loop thread — the allocator is
-                # single-threaded; never committed, released below)
-                need = (S + self.cfg.block_size - 1) // self.cfg.block_size
-                if not self.allocator.can_allocate(need):
-                    raise ValueError(
-                        f"no KV capacity for a {S}-token embedding "
-                        f"({need} blocks needed); retry later"
-                    )
-                block_ids = self.allocator.allocate(need)
-            try:
-                vec = await loop.run_in_executor(
-                    self._executor, self._run_embed, list(req.token_ids),
-                    block_ids,
-                )
-            finally:
-                if block_ids is not None:
-                    self.allocator.release(block_ids)
-            yield BackendOutput(
-                finish_reason=FINISH_STOP,
-                annotations={
-                    "embedding": [float(v) for v in vec],
-                    "input_tokens": len(req.token_ids),
-                },
-            )
-            return
-        self._ensure_loop()
-        if req.annotations.get("images"):
-            if self.cfg.vision is None:
-                raise InvalidRequestError("engine built without a vision tower")
-        all_tokens = list(req.token_ids) + list(req.prior_token_ids)
-        st = _Seq(
-            req=req,
-            context=context,
-            out_queue=asyncio.Queue(),
-            seq=TokenBlockSequence(all_tokens, self.cfg.block_size),
-            last_token=all_tokens[-1] if all_tokens else 0,
-            guided_tables=guided_tables,
-        )
-        if guided_tables is not None and req.prior_token_ids:
-            # disagg decode hop / migration resume: tokens generated so far
-            # (on the prefill worker / the dead worker) already consumed
-            # grammar transitions — seed the FSM past them instead of
-            # restarting at 0 (which would let the grammar accept a fresh
-            # full match appended to the prior output)
-            try:
-                st.guided_state = guided_tables.walk(
-                    0, [int(t) for t in req.prior_token_ids]
-                )
-            except ValueError as e:
-                raise GuidedRejectedError(
-                    f"prior tokens violate the guided grammar: {e}"
-                ) from e
-        if self.cfg.spec_draft is not None:
-            s = req.sampling
-            st.spec_ok = (
-                s.temperature == 0.0
-                and s.logprobs == 0
-                and s.presence_penalty == 0.0
-                and s.frequency_penalty == 0.0
-                and s.repetition_penalty == 1.0
-                and not wanted_procs
-                and guided_tables is None
-            )
-        if req.annotations.get("images"):
-            loop_mm = asyncio.get_event_loop()
-            st.mm_embeds, st.mm_mask = await loop_mm.run_in_executor(
-                self._executor, self._encode_images, req
-            )
-            # prior_token_ids (migration replay / disagg decode hop) extend
-            # the prompt past token_ids: pad the override arrays to the full
-            # prefill length (generated text is never an image span)
-            extra = len(all_tokens) - len(st.mm_mask)
-            if extra > 0:
-                st.mm_embeds = np.concatenate(
-                    [st.mm_embeds,
-                     np.zeros((extra, st.mm_embeds.shape[1]), np.float32)]
-                )
-                st.mm_mask = np.concatenate(
-                    [st.mm_mask, np.zeros(extra, bool)]
-                )
-            # placeholder ids hash identically across different images:
-            # never match or publish this prompt's blocks. (A future
-            # refinement: salt the block hashes with each image's content
-            # hash at its placeholder run, making mm prefixes cacheable
-            # instead of uncacheable.)
-            st.no_cache = True
-        # disaggregated decode: pull the prefill worker's KV pages first so
-        # admission sees them as a cached prefix (no recompute)
-        flight = get_flight_recorder()
-        kv_plan = req.kv_transfer
-        if (kv_plan and kv_plan.get("tier")
-                and getattr(self, "kv_directory", None) is not None
-                and kv_plan.get("holder") == self.kv_directory.holder):
-            # the planner picked us as the peer: our own G2/G3 already holds
-            # these blocks, and the kvbm onboard below imports them without
-            # a loopback wire copy. Drop the plan instead of self-fetching.
-            kv_plan = None
-        if kv_plan and kv_plan.get("address"):
-            # global-directory plan (tier=True): pull from the peer's KVBM
-            # G2/G3 tiers instead of its device cache. The fetch holds a
-            # directory fetch lease that MUST be discharged on every path
-            # (RESOURCE-LEAK "fetch-lease"): commit on any import, abort on
-            # zero progress or failure — abort IS the recompute fallback,
-            # never a stuck request.
-            is_tier = bool(kv_plan.get("tier"))
-            fetch_lease = (
-                self.kv_directory.begin_fetch(
-                    kv_plan.get("holder", ""),
-                    [int(h) for h in kv_plan.get("hashes", [])],
-                )
-                if is_tier and self.kv_directory is not None else None
-            )
-            # the fetch lifecycle lands on the request's timeline (PR 16
-            # gap): started/committed/aborted bracket the wire pull, so the
-            # attribution plane charges this wait to kv_fetch and a stuck
-            # fetch is visible as started-without-terminal
-            flight.record(
-                req.request_id, "fetch_started",
-                holder=kv_plan.get("holder", ""), tier=is_tier,
-                blocks=len(kv_plan.get("hashes", [])),
-            )
-            try:
-                got = await self._get_transfer_client().fetch_and_import(
-                    kv_plan["address"],
-                    [int(h) for h in kv_plan.get("hashes", [])],
-                    traceparent=req.annotations.get("traceparent"),
-                    stream=bool(kv_plan.get("stream")),
-                    tier=is_tier,
-                )
-                if fetch_lease is not None:
-                    if got > 0:
-                        self.kv_directory.commit_fetch(fetch_lease, got)
-                    else:
-                        self.kv_directory.abort_fetch(fetch_lease)
-                if got > 0:
-                    flight.record(
-                        req.request_id, "fetch_committed", tokens=got,
-                    )
+                        raise GuidedRejectedError(
+                            "engine built without guided decoding "
+                            "(guided_max_states=0)"
+                        )
                 else:
-                    flight.record(
-                        req.request_id, "fetch_aborted",
-                        reason="zero_progress",
-                    )
-                log.debug("imported %d transferred kv tokens for %s", got, req.request_id[:8])
-                flight.record(
-                    req.request_id, "transfer",
-                    tokens=got, address=kv_plan["address"],
+                    try:
+                        guided_tables = await sub.away(self._compile_guided(
+                            req.sampling.guided
+                        ))
+                    except ValueError:
+                        if not req.sampling.guided.get("soft"):
+                            raise
+                        # reference behavior: a failed tool-choice derivation
+                        # logs and serves unconstrained (common_ext.rs:190)
+                        log.warning(
+                            "soft guided grammar rejected; serving unconstrained"
+                        )
+            if req.annotations.get("op") == "embed":
+                loop = asyncio.get_event_loop()
+                block_ids: Optional[List[int]] = None
+                S = len(req.token_ids)
+                if S > self.cfg.prefill_chunk:
+                    # long input: temporary pages for the chunked pooled forward
+                    # (allocated here on the loop thread — the allocator is
+                    # single-threaded; never committed, released below)
+                    need = (S + self.cfg.block_size - 1) // self.cfg.block_size
+                    if not self.allocator.can_allocate(need):
+                        raise ValueError(
+                            f"no KV capacity for a {S}-token embedding "
+                            f"({need} blocks needed); retry later"
+                        )
+                    block_ids = self.allocator.allocate(need)
+                try:
+                    vec = await sub.away(loop.run_in_executor(
+                        self._executor, self._run_embed, list(req.token_ids),
+                        block_ids,
+                    ))
+                finally:
+                    if block_ids is not None:
+                        self.allocator.release(block_ids)
+                sub.close()  # no span across the caller's turn
+                yield BackendOutput(
+                    finish_reason=FINISH_STOP,
+                    annotations={
+                        "embedding": [float(v) for v in vec],
+                        "input_tokens": len(req.token_ids),
+                    },
                 )
-            except Exception as e:
-                if fetch_lease is not None:
-                    self.kv_directory.abort_fetch(fetch_lease)
-                log.exception("kv transfer failed; recomputing prefill locally")
-                flight.record(
-                    req.request_id, "fetch_aborted", reason=str(e)[:200],
-                )
-                flight.record(
-                    req.request_id, "transfer",
-                    tokens=0, error=str(e)[:200],
-                    address=kv_plan["address"],
-                )
-        if self.kvbm is not None:
-            try:
-                await self._onboard_from_kvbm(st)
-            except Exception:
-                log.exception("kvbm onboard failed; prefilling from scratch")
-        # disaggregated prefill: announce our pages on the way out
-        is_prefill_side = req.annotations.get("disagg") == "prefill"
-        st.sla = spec_from_annotations(req.annotations)
-        st.t_queued = time.time_ns()
-        queued_fields: Dict[str, Any] = dict(
-            prompt_tokens=n_prompt, waiting=len(self._waiting),
-        )
-        if st.sla is not None:
-            # the queued event carries the promise so /debug/requests?id=
-            # can compute the budget breakdown (runtime/slo.py) at read time
-            queued_fields.update(
-                sla_class=st.sla.sla_class,
-                ttft_target_s=st.sla.ttft_target_s,
-                itl_target_s=st.sla.itl_target_s,
-                deadline_s=st.sla.deadline_s,
+                return
+            self._ensure_loop()
+            if req.annotations.get("images"):
+                if self.cfg.vision is None:
+                    raise InvalidRequestError("engine built without a vision tower")
+            all_tokens = list(req.token_ids) + list(req.prior_token_ids)
+            st = _Seq(
+                req=req,
+                context=context,
+                out_queue=asyncio.Queue(),
+                seq=TokenBlockSequence(all_tokens, self.cfg.block_size),
+                last_token=all_tokens[-1] if all_tokens else 0,
+                guided_tables=guided_tables,
             )
-        flight.record(req.request_id, "queued", **queued_fields)
-        self._waiting.append(st)
-        self._wake.set()
+            if guided_tables is not None and req.prior_token_ids:
+                # disagg decode hop / migration resume: tokens generated so far
+                # (on the prefill worker / the dead worker) already consumed
+                # grammar transitions — seed the FSM past them instead of
+                # restarting at 0 (which would let the grammar accept a fresh
+                # full match appended to the prior output)
+                try:
+                    st.guided_state = guided_tables.walk(
+                        0, [int(t) for t in req.prior_token_ids]
+                    )
+                except ValueError as e:
+                    raise GuidedRejectedError(
+                        f"prior tokens violate the guided grammar: {e}"
+                    ) from e
+            if self.cfg.spec_draft is not None:
+                s = req.sampling
+                st.spec_ok = (
+                    s.temperature == 0.0
+                    and s.logprobs == 0
+                    and s.presence_penalty == 0.0
+                    and s.frequency_penalty == 0.0
+                    and s.repetition_penalty == 1.0
+                    and not wanted_procs
+                    and guided_tables is None
+                )
+            if req.annotations.get("images"):
+                loop_mm = asyncio.get_event_loop()
+                st.mm_embeds, st.mm_mask = await sub.away(
+                    loop_mm.run_in_executor(
+                        self._executor, self._encode_images, req
+                    )
+                )
+                # prior_token_ids (migration replay / disagg decode hop) extend
+                # the prompt past token_ids: pad the override arrays to the full
+                # prefill length (generated text is never an image span)
+                extra = len(all_tokens) - len(st.mm_mask)
+                if extra > 0:
+                    st.mm_embeds = np.concatenate(
+                        [st.mm_embeds,
+                         np.zeros((extra, st.mm_embeds.shape[1]), np.float32)]
+                    )
+                    st.mm_mask = np.concatenate(
+                        [st.mm_mask, np.zeros(extra, bool)]
+                    )
+                # placeholder ids hash identically across different images:
+                # never match or publish this prompt's blocks. (A future
+                # refinement: salt the block hashes with each image's content
+                # hash at its placeholder run, making mm prefixes cacheable
+                # instead of uncacheable.)
+                st.no_cache = True
+            # disaggregated decode: pull the prefill worker's KV pages first so
+            # admission sees them as a cached prefix (no recompute)
+            flight = get_flight_recorder()
+            kv_plan = req.kv_transfer
+            if (kv_plan and kv_plan.get("tier")
+                    and getattr(self, "kv_directory", None) is not None
+                    and kv_plan.get("holder") == self.kv_directory.holder):
+                # the planner picked us as the peer: our own G2/G3 already holds
+                # these blocks, and the kvbm onboard below imports them without
+                # a loopback wire copy. Drop the plan instead of self-fetching.
+                kv_plan = None
+            if kv_plan and kv_plan.get("address"):
+                # global-directory plan (tier=True): pull from the peer's KVBM
+                # G2/G3 tiers instead of its device cache. The fetch holds a
+                # directory fetch lease that MUST be discharged on every path
+                # (RESOURCE-LEAK "fetch-lease"): commit on any import, abort on
+                # zero progress or failure — abort IS the recompute fallback,
+                # never a stuck request.
+                is_tier = bool(kv_plan.get("tier"))
+                fetch_lease = (
+                    self.kv_directory.begin_fetch(
+                        kv_plan.get("holder", ""),
+                        [int(h) for h in kv_plan.get("hashes", [])],
+                    )
+                    if is_tier and self.kv_directory is not None else None
+                )
+                # the fetch lifecycle lands on the request's timeline (PR 16
+                # gap): started/committed/aborted bracket the wire pull, so the
+                # attribution plane charges this wait to kv_fetch and a stuck
+                # fetch is visible as started-without-terminal
+                flight.record(
+                    req.request_id, "fetch_started",
+                    holder=kv_plan.get("holder", ""), tier=is_tier,
+                    blocks=len(kv_plan.get("hashes", [])),
+                )
+                try:
+                    got = await sub.away(
+                        self._get_transfer_client().fetch_and_import(
+                            kv_plan["address"],
+                            [int(h) for h in kv_plan.get("hashes", [])],
+                            traceparent=req.annotations.get("traceparent"),
+                            stream=bool(kv_plan.get("stream")),
+                            tier=is_tier,
+                        )
+                    )
+                    if fetch_lease is not None:
+                        if got > 0:
+                            self.kv_directory.commit_fetch(fetch_lease, got)
+                        else:
+                            self.kv_directory.abort_fetch(fetch_lease)
+                    if got > 0:
+                        flight.record(
+                            req.request_id, "fetch_committed", tokens=got,
+                        )
+                    else:
+                        flight.record(
+                            req.request_id, "fetch_aborted",
+                            reason="zero_progress",
+                        )
+                    log.debug("imported %d transferred kv tokens for %s", got, req.request_id[:8])
+                    flight.record(
+                        req.request_id, "transfer",
+                        tokens=got, address=kv_plan["address"],
+                    )
+                except Exception as e:
+                    if fetch_lease is not None:
+                        self.kv_directory.abort_fetch(fetch_lease)
+                    log.exception("kv transfer failed; recomputing prefill locally")
+                    flight.record(
+                        req.request_id, "fetch_aborted", reason=str(e)[:200],
+                    )
+                    flight.record(
+                        req.request_id, "transfer",
+                        tokens=0, error=str(e)[:200],
+                        address=kv_plan["address"],
+                    )
+            if self.kvbm is not None:
+                try:
+                    await sub.away(self._onboard_from_kvbm(st))
+                except Exception:
+                    log.exception("kvbm onboard failed; prefilling from scratch")
+            # disaggregated prefill: announce our pages on the way out
+            is_prefill_side = req.annotations.get("disagg") == "prefill"
+            st.sla = spec_from_annotations(req.annotations)
+            st.t_queued = now_ns()
+            queued_fields: Dict[str, Any] = dict(
+                prompt_tokens=n_prompt, waiting=len(self._waiting),
+                # what this thread spent on the request so far, the step
+                # loop waiting: the request's ``submit`` spans up to here
+                submit_ms=round(sub.held_ms(), 3),
+            )
+            if st.sla is not None:
+                # the queued event carries the promise so /debug/requests?id=
+                # can compute the budget breakdown (runtime/slo.py) at read time
+                queued_fields.update(
+                    sla_class=st.sla.sla_class,
+                    ttft_target_s=st.sla.ttft_target_s,
+                    itl_target_s=st.sla.itl_target_s,
+                    deadline_s=st.sla.deadline_s,
+                )
+            flight.record(req.request_id, "queued", **queued_fields)
+            self._waiting.append(st)
+            self._wake.set()
         while True:
             item = await st.out_queue.get()
             if item is None:
                 return
-            if (
-                is_prefill_side
-                and item.finish_reason is not None
-                and self.transfer_address is not None
-                and not st.no_cache
-            ):
-                prompt_blocks = len(req.token_ids) // self.cfg.block_size
-                item.kv_transfer = {
-                    "address": self.transfer_address,
-                    "hashes": [int(h) for h in st.seq.sequence_hashes()[:prompt_blocks]],
-                    "num_tokens": prompt_blocks * self.cfg.block_size,
-                    # this server speaks the block-window streaming protocol
-                    "stream": True,
-                }
-            if item.finish_reason is not None:
-                # observability BEFORE the final yield: consumers typically
-                # return at the finish frame, which closes this generator at
-                # the yield (code after it would never run)
-                self._request_finished(st, item.finish_reason)
-            yield item
+            # ``deliver``: from here to the caller asking for the next item,
+            # or closing the generator at the yield
+            t_got = now_ns()
+            try:
+                if (
+                    is_prefill_side
+                    and item.finish_reason is not None
+                    and self.transfer_address is not None
+                    and not st.no_cache
+                ):
+                    prompt_blocks = len(req.token_ids) // self.cfg.block_size
+                    item.kv_transfer = {
+                        "address": self.transfer_address,
+                        "hashes": [int(h) for h in st.seq.sequence_hashes()[:prompt_blocks]],
+                        "num_tokens": prompt_blocks * self.cfg.block_size,
+                        # this server speaks the block-window streaming protocol
+                        "stream": True,
+                    }
+                if item.finish_reason is not None:
+                    # observability BEFORE the final yield: consumers typically
+                    # return at the finish frame, which closes this generator at
+                    # the yield (code after it would never run)
+                    self._request_finished(st, item.finish_reason)
+                yield item
+            finally:
+                record_request_span(self, "deliver", t_got, req.request_id)
             if item.finish_reason is not None:
                 return
 
@@ -3004,16 +3042,14 @@ class TpuEngine:
                 )
 
     # ------------------------------------------------------------- step loop
-    # pending host spans and admission waits since the last StepStats
-    # (engine/telemetry.py loop_span): made by _loop, filled only while
-    # stats_hook is set, bounded, carried away by _step_stats
-    _host_spans: Optional[deque] = None
-    _admit_waits: Optional[deque] = None
+    def _unix_ns(self, t_ns: int) -> int:
+        """A stamp of the loop's clock (``telemetry.now_ns``) as unix ns, for
+        the sinks that carry wall time (OTLP spans, the SLO ledger)."""
+        return t_ns + self._wall_offset_ns
 
     async def _loop(self) -> None:
         loop = asyncio.get_event_loop()
-        self._host_spans = pending_spans()
-        self._admit_waits = deque(maxlen=PENDING_SPANS_MAX)
+        self._wall_offset_ns = time.time_ns() - now_ns()
         try:
             while True:
                 if not self._waiting and all(s is None for s in self._slots):
@@ -3056,7 +3092,7 @@ class TpuEngine:
                         pick = None
                     elif pick is not None:
                         if pick.t_prefill_start == 0:
-                            pick.t_prefill_start = time.time_ns()
+                            pick.t_prefill_start = now_ns()
                         chunk_from = pick.prefill_pos
                         # mixed continuous batching: when decode rows are
                         # resident (and no horizon is in flight to carry
@@ -3374,10 +3410,10 @@ class TpuEngine:
                     if other is not None and other is not st:
                         self._slot_dirty[j] = True
             admitted.append(st)
-            st.t_admitted = time.time_ns()
+            st.t_admitted = now_ns()
             if self.stats_hook is not None:
                 self._admit_waits.append(
-                    max(0, st.t_admitted - st.t_queued) / 1e9
+                    (st.t_admitted - st.t_queued) / 1e9
                 )
             get_flight_recorder().record(
                 st.req.request_id, "admitted",
@@ -4444,7 +4480,7 @@ class TpuEngine:
             if wid is not None:
                 ann["worker_id"] = wid
         if first_ann and (emit_ids or finish is not None) and st.t_first_token == 0:
-            st.t_first_token = time.time_ns()
+            st.t_first_token = now_ns()
             get_flight_recorder().record(
                 st.req.request_id, "first_token", slot=st.slot,
             )
@@ -4513,22 +4549,23 @@ class TpuEngine:
             return
         tp = st.req.annotations.get("traceparent")
         status = "ERROR" if finish_reason == FINISH_ERROR else "OK"
+        unix = self._unix_ns
         if st.t_queued and st.t_admitted:
             tracer.emit(
-                "engine.queue", st.t_queued, st.t_admitted,
+                "engine.queue", unix(st.t_queued), unix(st.t_admitted),
                 traceparent=tp, request_id=rid,
             )
         prefill_start = st.t_prefill_start or st.t_admitted
         if prefill_start and st.t_first_token:
             tracer.emit(
-                "engine.prefill", prefill_start, st.t_first_token,
+                "engine.prefill", unix(prefill_start), unix(st.t_first_token),
                 traceparent=tp, request_id=rid,
                 prompt_tokens=len(st.req.token_ids),
                 cached_tokens=st.cached_tokens,
             )
         if st.t_first_token:
             tracer.emit(
-                "engine.decode", st.t_first_token, time.time_ns(),
+                "engine.decode", unix(st.t_first_token), unix(now_ns()),
                 traceparent=tp, request_id=rid, status=status,
                 tokens=st.produced, finish=finish_reason,
             )
@@ -4540,15 +4577,17 @@ class TpuEngine:
         when present (same-host wall clock), else on engine queue entry;
         ITL is the request's mean decode gap."""
         spec = st.sla
-        now_ns = time.time_ns()
-        t0 = sla_t0_ns(st.req.annotations) or st.t_queued
+        now = now_ns()
+        # the frontend's receipt stamp is unix ns: onto the stamps' clock
+        t0 = sla_t0_ns(st.req.annotations)
+        t0 = t0 - self._wall_offset_ns if t0 else st.t_queued
         ttft_s = (
             (st.t_first_token - t0) / 1e9 if st.t_first_token else None
         )
         itl_s = None
         if st.t_first_token and st.produced > 1:
-            itl_s = (now_ns - st.t_first_token) / 1e9 / (st.produced - 1)
-        e2e_s = (now_ns - t0) / 1e9
+            itl_s = (now - st.t_first_token) / 1e9 / (st.produced - 1)
+        e2e_s = (now - t0) / 1e9
         met = get_slo_accountant().record(
             st.req.model, spec,
             ttft_s=ttft_s, itl_s=itl_s,
@@ -4594,6 +4633,9 @@ class TpuEngine:
         spans, waits = self._host_spans, self._admit_waits
         host_spans = tuple(spans.popleft() for _ in range(len(spans)))
         admit_wait_s = tuple(waits.popleft() for _ in range(len(waits)))
+        # a whole number of spans: every extend adds four values
+        rspans = self._request_spans
+        request_spans = tuple(rspans.popleft() for _ in range(len(rspans)))
         # set by the step's own readback; a prefill-only step has none
         routed, touched, load_max, *reads = self._moe_last or (None,) * 3
         # behind them what a latent's decode rows read, by StepStats' names
@@ -4614,11 +4656,10 @@ class TpuEngine:
                 kv_free_blocks=self.allocator.free_blocks,
                 kv_total_blocks=self.cfg.num_blocks,
                 spec_acceptance=spec_acc,
-                prep_hit=(prep["hit"] if prep is not None else None),
-                prep_build_s=(prep["build_s"] if prep is not None else 0.0),
-                prep_wait_s=(prep["wait_s"] if prep is not None else 0.0),
+                prep_hit=prep,
                 host_spans=host_spans,
                 admit_wait_s=admit_wait_s,
+                request_spans=request_spans,
                 moe_tokens_routed=routed,
                 moe_experts_touched=touched,
                 moe_load_max=load_max,

@@ -28,8 +28,10 @@ device compute — moving booking to another thread would buy races, not
 overlap.
 
 ``DTPU_ASYNC_PREP`` (default on) gates the pipeline; ``StepStats`` carries
-``prep_hit``/``prep_build_s``/``prep_wait_s`` so BENCH's
-``detail.step_telemetry`` shows how much host prep actually overlapped.
+``prep_hit``, whether the step consumed a prebuild. Nothing here takes a
+time of its own: what a dispatch still waits for a build lies in its
+``pack`` span (engine/telemetry.py ``loop_span``, the engine's one timing
+mechanism), and a miss shows as three more ``h2d_placements``.
 Multihost engines keep serial prep (dispatch args there are part of the
 leader's replay-ordered broadcast).
 """
@@ -37,9 +39,8 @@ leader's replay-ordered broadcast).
 from __future__ import annotations
 
 import os
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 from ..runtime.config import ENV_ASYNC_PREP
 
@@ -66,10 +67,11 @@ class ChunkPrep:
         self._ex = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="tpu-prep"
         )
-        # request_id -> (key, Future[(arrays, uploads, build_s)])
+        # request_id -> (key, Future[(arrays, uploads)])
         self._pending: Dict[str, Tuple[tuple, Future]] = {}
-        # stats of the most recent take(), consumed by engine._step_stats
-        self.last: Optional[Dict[str, Any]] = None
+        # whether the most recent take() found a chunk prebuilt (None: none
+        # was scheduled), consumed by engine._step_stats
+        self.last: Optional[bool] = None
 
     @staticmethod
     def _key(rid: str, token_ids, start: int, chunk_len: int,
@@ -85,13 +87,12 @@ class ChunkPrep:
         )
 
     def _build(self, token_ids, start: int, chunk_len: int, block_ids):
-        t0 = time.perf_counter()
         arrays = self._chunk_arrays(token_ids, start, chunk_len, block_ids)
         uploads = (
             tuple(self._upload(a) for a in arrays)
             if self._upload is not None else None
         )
-        return arrays, uploads, time.perf_counter() - t0
+        return arrays, uploads
 
     def schedule(self, rid: str, token_ids, start: int, chunk_len: int,
                  block_ids) -> None:
@@ -121,25 +122,19 @@ class ChunkPrep:
             self.last = None
             return None
         key, fut = ent
+        self.last = False
         if key != self._key(rid, token_ids, start, chunk_len, block_ids):
-            self.last = {"hit": False, "build_s": 0.0, "wait_s": 0.0}
             return None
-        t0 = time.perf_counter()
         try:
-            arrays, uploads, build_s = fut.result()
+            built = fut.result()
         except Exception:
             # a prep failure must never take the dispatch down; the serial
             # path recomputes (and surfaces any real packing error)
-            self.last = {"hit": False, "build_s": 0.0, "wait_s": 0.0}
             return None
-        self.last = {
-            "hit": True,
-            "build_s": build_s,
-            "wait_s": time.perf_counter() - t0,
-        }
-        return arrays, uploads
+        self.last = True
+        return built
 
-    def pop_last(self) -> Optional[Dict[str, Any]]:
+    def pop_last(self) -> Optional[bool]:
         last, self.last = self.last, None
         return last
 

@@ -23,6 +23,20 @@ the last ``StepStats``). Spans are opened on the loop thread
 (``LOOP_PHASES``) and on the one step-executor thread, inside ``step``
 (``EXECUTOR_PHASES``); never under ``jit``.
 
+A span may have a SUBJECT, the id of the request whose work it is
+(``REQUEST_PHASES``): ``submit``, the loop thread's synchronous work in
+``TpuEngine.generate`` before a request is queued (``submit_span`` cuts it
+at every ``await``: a span covers only time the thread was held), and
+``deliver``, from a result leaving the request's queue to the caller asking
+for the next one (``record_request_span``: it is held across a suspension
+point, so it is two stamps and NO annotation, since two coroutines'
+annotations would interleave on one thread's line of a profile). They run on
+the event-loop thread INSIDE the loop's ``yield``, ``idle`` and the awaits of
+``step`` and ``fetch``, say to whom the loop gave the thread, and ride the
+next ``StepStats`` in a field of their own, ``request_spans`` (``name, t0_ns,
+t1_ns, request_id``, four values a span; ``span_quads``): ``host_spans``
+keeps its names, its form and its tiling of a tick.
+
 ``host_spans`` is FLAT, three values a span, and not a tuple per span
 (``span_triples`` reads it back as triples). A hook that keeps its
 ``StepStats`` keeps the spans, and a tuple per span is a dozen more objects
@@ -68,7 +82,12 @@ LOOP_PHASES = ("idle", "admit", "book", "step", "fetch", "emit", "reap",
                "publish", "yield")
 # the step-executor thread's, inside a ``step`` span of the loop thread
 EXECUTOR_PHASES = ("pack", "upload", "launch", "sync")
-_ANNOTATION = {p: f"dtpu.loop.{p}" for p in LOOP_PHASES + EXECUTOR_PHASES}
+_HOST_PHASES = LOOP_PHASES + EXECUTOR_PHASES
+# spans with a subject, a request's id: on the event-loop thread, inside the
+# loop's ``yield`` / ``idle`` and the awaits of ``step`` / ``fetch``
+REQUEST_PHASES = ("submit", "deliver")
+_ANNOTATION = {p: f"dtpu.loop.{p}" for p in _HOST_PHASES}
+_ANNOTATION["submit"] = "dtpu.req.submit"  # ``deliver`` opens none
 # pending spans / admission waits kept on an engine between two StepStats:
 # beyond this the oldest go (a hook set on a loop that turns without stepping)
 PENDING_SPANS_MAX = 4096
@@ -85,7 +104,22 @@ def span_triples(host_spans: Tuple[Any, ...]):
     return zip(host_spans[0::3], host_spans[1::3], host_spans[2::3])
 
 
-_now_ns = time.monotonic_ns
+def pending_request_spans() -> collections.deque:
+    """The engine's pending list of spans with a subject: ``name, t0_ns,
+    t1_ns, request_id`` of each, flat."""
+    return collections.deque(maxlen=4 * PENDING_SPANS_MAX)
+
+
+def span_quads(request_spans: Tuple[Any, ...]):
+    """``StepStats.request_spans`` (or a pending list) as ``(name, t0_ns,
+    t1_ns, request_id)``, in the order the spans ended."""
+    return zip(request_spans[0::4], request_spans[1::4],
+               request_spans[2::4], request_spans[3::4])
+
+
+# the one clock of the loop's spans, a request's stamps (engine ``_Seq``)
+# and the benchmark's marker
+now_ns = time.monotonic_ns
 
 
 class loop_span(jax.profiler.TraceAnnotation):
@@ -93,24 +127,88 @@ class loop_span(jax.profiler.TraceAnnotation):
     loop (module docstring). It IS the annotation (a TraceMe starts when it
     is constructed), so a span costs one object: a dozen are made every loop
     tick. The recorded span holds the annotation's own cost: that is the
-    phase's, not a hole between two phases."""
+    phase's, not a hole between two phases. With a ``request_id`` the span
+    has a subject and goes to the engine's ``_request_spans``."""
 
-    __slots__ = ("_engine", "_name", "_t0")
+    __slots__ = ("_engine", "_name", "_t0", "_request_id")
 
-    def __init__(self, engine: Any, name: str):
-        self._t0 = _now_ns()
+    def __init__(self, engine: Any, name: str,
+                 request_id: Optional[str] = None):
+        self._t0 = now_ns()
         super().__init__(_ANNOTATION[name])
         self._engine = engine
         self._name = name
+        self._request_id = request_id
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         super().__exit__(exc_type, exc, tb)
-        t1 = _now_ns()
-        if self._engine.stats_hook is not None:
+        if self._request_id is not None:
+            record_request_span(
+                self._engine, self._name, self._t0, self._request_id
+            )
+        elif self._engine.stats_hook is not None:
             # one extend: the loop thread and the executor thread both come
             # here, and the list stays a whole number of triples
-            self._engine._host_spans.extend((self._name, self._t0, t1))
+            self._engine._host_spans.extend(
+                (self._name, self._t0, now_ns())
+            )
         return False
+
+
+def record_request_span(engine: Any, name: str, t0_ns: int,
+                        request_id: str) -> None:
+    """A span of ``REQUEST_PHASES`` that ends now: kept, like the loop's,
+    only while ``engine.stats_hook`` is set."""
+    if engine.stats_hook is not None:
+        engine._request_spans.extend((name, t0_ns, now_ns(), request_id))
+
+
+class submit_span:
+    """``submit``: the loop thread's synchronous work on one request from
+    the entry of ``TpuEngine.generate`` to the request being queued, as one
+    ``loop_span`` for each stretch between two ``await``s::
+
+        with submit_span(engine) as sub:
+            ...; sub.request_id = req.request_id
+            x = await sub.away(something())     # not the thread's time
+
+    ``held_ms()`` is the stretches' summed length so far, the open one
+    included: what the flight recorder's ``queued`` event states."""
+
+    __slots__ = ("_engine", "request_id", "_held_ns", "_open")
+
+    def __init__(self, engine: Any):
+        self._engine = engine
+        self.request_id = ""
+        self._held_ns = 0
+        self._open: Optional[loop_span] = None
+
+    def __enter__(self) -> "submit_span":
+        self._open = loop_span(self._engine, "submit", self.request_id)
+        return self
+
+    def close(self) -> None:
+        span, self._open = self._open, None
+        if span is not None:
+            span._request_id = self.request_id  # known once the request is read
+            span.__exit__(None, None, None)
+            self._held_ns += now_ns() - span._t0
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.close()
+        return False
+
+    async def away(self, awaitable):
+        self.close()
+        try:
+            return await awaitable
+        finally:
+            self.__enter__()
+
+    def held_ms(self) -> float:
+        span = self._open
+        open_ns = now_ns() - span._t0 if span is not None else 0
+        return (self._held_ns + open_ns) / 1e6
 
 
 def _ns_by_phase(steps) -> Dict[str, int]:
@@ -138,19 +236,21 @@ class StepStats:
     kv_total_blocks: int
     spec_acceptance: Optional[float] = None  # None unless spec decoding on
     # async host step-prep (engine/prep.py, DTPU_ASYNC_PREP): whether this
-    # chunk-carrying step consumed a prebuilt pack, how long the prebuild
-    # took (that time ran UNDER the previous step's device compute when
-    # hit), and how long the dispatch still had to wait on it. None/0 on
-    # decode-only steps and with async prep off.
+    # chunk-carrying step consumed a prebuilt pack (what the dispatch still
+    # waited for it lies in its ``pack`` span). None on decode-only steps
+    # and with async prep off.
     prep_hit: Optional[bool] = None
-    prep_build_s: float = 0.0
-    prep_wait_s: float = 0.0
     # what the host did since the last StepStats: phase, t0_ns, t1_ns of
     # each span on time.monotonic_ns(), FLAT (span_triples above; module
     # docstring), loop-thread and executor-thread spans together, and
     # queued -> admitted seconds of each request admitted since then
     host_spans: Tuple[Any, ...] = ()
     admit_wait_s: Tuple[float, ...] = ()
+    # ... and what it did for whom inside the loop's yield / idle / awaits:
+    # name, t0_ns, t1_ns, request_id of each ``submit`` and ``deliver`` span
+    # that ended since then, FLAT, four values a span (span_quads above), on
+    # the same clock. NOT part of host_spans' tiling of a tick
+    request_spans: Tuple[Any, ...] = ()
     # expert routing of the step (one-chip grouped MoE path; None elsewhere
     # and on prefill-only steps, which have no readback to carry them):
     # (token, expert) rows routed, T x K summed over layers; experts with at
@@ -243,10 +343,6 @@ class EngineTelemetry:
         )
         self._kv_free = scope.gauge(M.KV_FREE_BLOCKS, "free KV blocks")
         self._kv_total = scope.gauge(M.KV_TOTAL_BLOCKS, "configured KV blocks")
-        self._decode_blocks = scope.gauge(
-            M.WORKER_ACTIVE_DECODE_BLOCKS,
-            "active decode blocks this worker reports to the router",
-        )
         self._spec = scope.gauge(
             M.SPEC_ACCEPTANCE,
             "speculative decoding acceptance rate (emitted / drafted)",
@@ -305,7 +401,7 @@ class EngineTelemetry:
             # mean host seconds per loop phase per step over the window
             "loop_phases": {
                 name: round(loop_ns[name] / 1e9 / len(recent), 6)
-                for name in _ANNOTATION if name in loop_ns
+                for name in _HOST_PHASES if name in loop_ns
             },
         }
         if recent:
@@ -369,23 +465,31 @@ class EngineTelemetry:
             self._kv_active.set(s.kv_active_blocks)
             self._kv_free.set(s.kv_free_blocks)
             self._kv_total.set(s.kv_total_blocks)
-            self._decode_blocks.set(s.kv_active_blocks)
             if s.spec_acceptance is not None:
                 self._spec.set(s.spec_acceptance)
             imbalance = moe_load_imbalance(s)
             if imbalance is not None:
                 self._moe_imbalance.set(imbalance)
             spent = _ns_by_phase((s,))
-            for name in _ANNOTATION:  # the label's fixed set
+            for name in _HOST_PHASES:  # the label's fixed set
                 if name in spent:
                     self._loop_phase.inc(spent[name] / 1e9, phase=name)
             if s.duration_s > self.slow_step_s:
                 self.slow_steps += 1
                 self._slow.inc(phase=s.phase)
+                # the phase that held most of the host's time since the last
+                # StepStats; the executor's phases lie inside ``step``
+                if "step" in spent:
+                    spent["step"] -= sum(
+                        spent.get(p, 0) for p in EXECUTOR_PHASES
+                    )
+                longest = max(spent, key=spent.get, default="no span")
                 log.warning(
-                    "slow %s step: %.0f ms (threshold %.0f ms; occupancy "
-                    "%d/%d, queue %d, kv %d/%d blocks)",
-                    s.phase, s.duration_s * 1e3, self.slow_step_s * 1e3,
+                    "slow %s step: %.0f ms of which %s %.0f ms (threshold "
+                    "%.0f ms; occupancy %d/%d, queue %d, kv %d/%d blocks)",
+                    s.phase, s.duration_s * 1e3,
+                    longest, spent.get(longest, 0) / 1e6,
+                    self.slow_step_s * 1e3,
                     s.batch_occupancy, s.batch_size, s.queue_depth,
                     s.kv_active_blocks, s.kv_total_blocks,
                 )

@@ -37,7 +37,6 @@ OUTPUT_TOKENS = f"{PREFIX}_output_tokens_total"
 KV_ACTIVE_BLOCKS = f"{PREFIX}_kv_active_blocks"
 KV_TOTAL_BLOCKS = f"{PREFIX}_kv_total_blocks"
 KV_HIT_TOKENS = f"{PREFIX}_kv_cached_tokens_total"
-WORKER_ACTIVE_DECODE_BLOCKS = f"{PREFIX}_worker_active_decode_blocks"
 # engine step telemetry (engine/telemetry.py): per-step loop observability
 KV_FREE_BLOCKS = f"{PREFIX}_kv_free_blocks"
 STEP_DURATION_SECONDS = f"{PREFIX}_engine_step_duration_seconds"

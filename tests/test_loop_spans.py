@@ -4,8 +4,8 @@ One tiny engine serves two waves of requests, once for the whole module:
 the first with no ``stats_hook`` (nothing may accumulate), the second with a
 hook and a ``jax.profiler`` trace around it, marked as ``benchmarks/run.py``
 marks its traced sub-window. The cases below read what that left: the
-``host_spans`` and ``admit_wait_s`` of every ``StepStats``, and the trace's
-``dtpu.loop.*`` events. The waves reach all four executor paths: a lone
+``host_spans``, ``request_spans`` and ``admit_wait_s`` of every ``StepStats``,
+and the trace's ``dtpu.loop.*`` and ``dtpu.req.submit`` events. The waves reach all four executor paths: a lone
 prefill, fused mixed steps (a prompt arriving beside a resident decode),
 horizons, and single-step decodes (more requests than slots).
 """
@@ -88,6 +88,7 @@ async def _serve(trace_dir):
         out["pending_without_hook"] = (
             len(engine._host_spans), len(engine._admit_waits)
         )
+        out["request_spans_without_hook"] = len(engine._request_spans)
         steps = []
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -122,6 +123,8 @@ def served(tmp_path_factory):
                     events.setdefault(ev.name[len("dtpu.loop."):], []).append(
                         (int(ev.start_ns), plane.name)
                     )
+                elif ev.name.startswith("dtpu.req."):
+                    out.setdefault("request_events", []).append(ev.name)
                 elif ev.name == MARKER:
                     marker = int(ev.start_ns)
     out["trace_events"], out["marker_start"] = events, marker
@@ -249,6 +252,111 @@ def test_step_stats_defaults_are_empty():
         kv_total_blocks=0,
     )
     assert s.host_spans == () and s.admit_wait_s == ()
+    assert s.request_spans == ()
+
+
+# -- (d2) spans with a request for a subject: a field of their own ------------
+def _quads(served):
+    return [q for s in served["steps"] for q in T.span_quads(s.request_spans)]
+
+
+def test_host_spans_keep_their_thirteen_names_as_triples(served):
+    """``request_spans`` is a NEW field: what ``host_spans`` holds, and what
+    ``dtpu_engine_loop_phase_seconds_total`` and ``/debug/worker`` read from
+    it, is what it was."""
+    assert T.LOOP_PHASES + T.EXECUTOR_PHASES == (
+        "idle", "admit", "book", "step", "fetch", "emit", "reap", "publish",
+        "yield", "pack", "upload", "launch", "sync",
+    )
+    assert T.REQUEST_PHASES == ("submit", "deliver")
+    for step in served["steps"]:
+        assert len(step.host_spans) % 3 == 0
+        names = set(step.host_spans[0::3])
+        assert names <= set(T.LOOP_PHASES + T.EXECUTOR_PHASES)
+        assert not names & set(T.REQUEST_PHASES)
+        assert all(type(v) is int for v in step.host_spans[1::3] + step.host_spans[2::3])
+
+
+def test_request_spans_are_flat_quads_of_strings_and_integers(served):
+    import gc
+
+    steps = served["steps"]
+    assert any(s.request_spans for s in steps)
+    for s in steps:
+        flat = s.request_spans
+        assert len(flat) % 4 == 0
+        assert set(flat[0::4]) <= set(T.REQUEST_PHASES)
+        assert all(type(v) is int for v in flat[1::4] + flat[2::4])
+        assert all(type(v) is str for v in flat[0::4] + flat[3::4])
+        assert all(t0 <= t1 for _, t0, t1, _ in T.span_quads(flat))
+    gc.collect()
+    assert not any(gc.is_tracked(s.request_spans) for s in steps)
+
+
+def test_no_request_span_accumulates_without_a_hook(served):
+    assert served["request_spans_without_hook"] == 0
+
+
+@pytest.mark.parametrize("hook, kept", [(None, 0), (print, T.PENDING_SPANS_MAX)])
+def test_pending_request_spans_are_bounded(hook, kept):
+    """A hook set on a loop that turns without stepping: requests come, are
+    refused or cancelled, and no ``StepStats`` carries their spans away."""
+    engine = _FakeEngine(hook)
+    engine._request_spans = T.pending_request_spans()
+    for k in range(T.PENDING_SPANS_MAX + 50):
+        with T.loop_span(engine, "submit", f"r{k}"):
+            pass
+        T.record_request_span(engine, "deliver", T.now_ns(), f"r{k}")
+    quads = list(T.span_quads(tuple(engine._request_spans)))
+    assert len(quads) == kept and len(engine._request_spans) == 4 * kept
+    assert len(engine._host_spans) == 0  # a span with a subject is not a loop phase
+    if kept:  # the oldest went whole: what is left still reads as quads
+        assert all(n in T.REQUEST_PHASES and t0 <= t1 and rid.startswith("r")
+                   for n, t0, t1, rid in quads)
+
+
+def test_every_request_has_one_submit_span_inside_a_span_of_the_loop_thread(served):
+    """``generate`` runs on the loop's thread: its synchronous work before a
+    request is queued lies INSIDE the span in which the loop gave the thread
+    away (parked, ``sleep(0)``, or awaiting the executor or a readback), on
+    the same clock, and says whose work it was."""
+    quads = _quads(served)
+    submits = [q for q in quads if q[0] == "submit"]
+    assert sorted(rid for _, _, _, rid in submits) == [f"p{k}" for k in range(N_REQUESTS)]
+    away = [
+        (t0, t1) for s in served["steps"] for n, t0, t1 in _spans(s)
+        if n in ("yield", "idle", "step", "fetch")
+    ]
+    for _, t0, t1, rid in submits:
+        assert t1 > t0
+        assert any(a <= t0 and t1 <= b for a, b in away), (
+            f"the submit span of {rid} lies in no yield / idle / step / fetch span"
+        )
+    # between the caller's call and its first token, on time.monotonic_ns()
+    by_id = {rid: (t0, t1) for _, t0, t1, rid in submits}
+    for k, rec in enumerate(served["requests"]):
+        t0, t1 = by_id[f"p{k}"]
+        assert rec["t_call"] <= t0 <= t1 <= rec["t_first"]
+
+
+def test_deliver_spans_of_one_request_do_not_overlap(served):
+    by_id = {}
+    for name, t0, t1, rid in _quads(served):
+        if name == "deliver":
+            by_id.setdefault(rid, []).append((t0, t1))
+    assert sorted(by_id) == [f"p{k}" for k in range(N_REQUESTS)]
+    for rid, spans in by_id.items():
+        spans.sort()
+        for (_, a1), (b0, _) in zip(spans, spans[1:]):
+            assert a1 <= b0, f"two deliver spans of {rid} overlap"
+    # a span an item; the last one closes as the caller leaves at the finish frame
+    submit_end = {rid: t1 for n, _, t1, rid in _quads(served) if n == "submit"}
+    assert all(spans[0][0] >= submit_end[rid] for rid, spans in by_id.items())
+
+
+def test_submit_opens_an_annotation_and_deliver_none(served):
+    assert set(served["request_events"]) == {"dtpu.req.submit"}
+    assert len(served["request_events"]) == N_REQUESTS
 
 
 # -- (e) the two sinks are one clock -----------------------------------------

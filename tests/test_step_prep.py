@@ -39,8 +39,7 @@ def test_prep_hit_returns_identical_arrays():
     for a, b in zip(arrays, serial):
         np.testing.assert_array_equal(a, b)
     assert uploads is None
-    assert prep.last["hit"] is True
-    assert prep.last["build_s"] >= 0.0
+    assert prep.last is True
     prep.stop()
 
 
@@ -53,7 +52,7 @@ def test_prep_key_mismatch_falls_back():
     blocks = list(range(1, 26))
     prep.schedule("r1", prompt, 16, 16, blocks)
     assert prep.take("r1", prompt, 32, 16, blocks) is None  # moved start
-    assert prep.last == {"hit": False, "build_s": 0.0, "wait_s": 0.0}
+    assert prep.last is False
     prep.schedule("r1", prompt, 16, 16, blocks)
     assert prep.take("r1", prompt, 16, 16, blocks[:-1]) is None  # block span
     assert prep.take("r2", prompt, 16, 16, blocks) is None  # unknown request
@@ -96,7 +95,7 @@ def test_prep_upload_callable_and_failure_isolation():
     bad = ChunkPrep(boom, upload=None)
     bad.schedule("r", prompt, 0, 16, blocks)
     assert bad.take("r", prompt, 0, 16, blocks) is None
-    assert bad.last["hit"] is False
+    assert bad.last is False
     bad.stop()
     prep.stop()
 
@@ -111,30 +110,27 @@ def test_prep_env_gate(monkeypatch):
     assert async_prep_enabled()
 
 
-def test_step_stats_carries_prep_fields():
-    """The fields bench.py's detail.step_telemetry.<phase>.prep summary
-    reads (schema pinned here so the BENCH JSON cannot silently drop the
-    overlap measurement)."""
+def test_step_stats_carries_prep_hit():
+    """The field bench.py's detail.step_telemetry.<phase>.prep summary and
+    tier-1's byte-identity proof read. The prebuild takes no time of its
+    own: what a dispatch waits for it lies in its ``pack`` span."""
     s = StepStats(
         phase="mixed", duration_s=0.01, batch_occupancy=2, batch_size=4,
         tokens=33, queue_depth=0, kv_active_blocks=1, kv_free_blocks=1,
-        kv_total_blocks=2, prep_hit=True, prep_build_s=0.002,
-        prep_wait_s=0.0001,
+        kv_total_blocks=2, prep_hit=True,
     )
-    assert s.prep_hit is True and s.prep_build_s > 0
+    assert s.prep_hit is True
+    assert not hasattr(s, "prep_build_s") and not hasattr(s, "prep_wait_s")
     # defaults keep decode-only steps clean
     d = StepStats(
         phase="decode", duration_s=0.01, batch_occupancy=2, batch_size=4,
         tokens=4, queue_depth=0, kv_active_blocks=1, kv_free_blocks=1,
         kv_total_blocks=2,
     )
-    assert d.prep_hit is None and d.prep_build_s == 0.0
+    assert d.prep_hit is None
 
     import bench
 
     summary = bench._phase_summary([s, s])
-    assert summary["prep"] == {
-        "steps": 2, "hits": 2,
-        "overlapped_build_ms": 4.0, "residual_wait_ms": 0.2,
-    }
+    assert summary["prep"] == {"steps": 2, "hits": 2}
     assert "prep" not in bench._phase_summary([d])

@@ -294,7 +294,10 @@ def test_step_telemetry_label_hierarchy_and_gauges():
         if l.startswith(M.SLOW_STEPS_TOTAL + "{")
     )
     assert 'phase="decode"' in slow_line and slow_line.rstrip().endswith("1.0")
-    assert M.SPEC_ACCEPTANCE in text and M.WORKER_ACTIVE_DECODE_BLOCKS in text
+    assert M.SPEC_ACCEPTANCE in text and M.KV_ACTIVE_BLOCKS + "{" in text
+    # one gauge for the blocks active sequences pin: its twin under another
+    # name (dtpu_worker_active_decode_blocks) had no reader and went, PR 35
+    assert "worker_active_decode_blocks" not in text
 
 
 def test_kv_router_overlap_emits_hit_tokens():
