@@ -2455,11 +2455,15 @@ class TpuEngine:
             if req.annotations.get("images"):
                 if self.cfg.vision is None:
                     raise InvalidRequestError("engine built without a vision tower")
-            all_tokens = list(req.token_ids) + list(req.prior_token_ids)
+            all_tokens = req.token_ids
+            if req.prior_token_ids:
+                all_tokens = [*all_tokens, *req.prior_token_ids]
             st = _Seq(
                 req=req,
                 context=context,
                 out_queue=asyncio.Queue(),
+                # the sequence keeps its own flat copy: this is the one copy
+                # of the prompt, and its block hashes are one pass over it
                 seq=TokenBlockSequence(all_tokens, self.cfg.block_size),
                 last_token=all_tokens[-1] if all_tokens else 0,
                 guided_tables=guided_tables,

@@ -14,6 +14,7 @@ from .blocks import (
     TokenBlockSequence,
     compute_block_hash,
     compute_sequence_hashes,
+    hash_blocks,
 )
 
 __all__ = [
@@ -23,4 +24,5 @@ __all__ = [
     "TokenBlockSequence",
     "compute_block_hash",
     "compute_sequence_hashes",
+    "hash_blocks",
 ]
