@@ -849,6 +849,41 @@ def _kernel_child() -> None:
         [dctx, dctx, 24577, 17, 0, dctx + 15, 1, 24591, dctx],
     )
 
+    # the state-space mixer's decode recurrence (PR 39) at Falcon-H1-34B's
+    # widths, 128 rows of which some are dead: the state in place, live rows
+    # only; and the decode question at the family's 5 query heads a kv head
+    from dynamo_tpu.ops import pallas_ssm
+
+    SR, SH, SG, SN, SP = 128, 32, 2, 256, 128
+    live = jnp.asarray(np.arange(SR) % 5 != 3)
+    S0 = rnd(SR, SH, SN, SP).astype(jnp.float32)
+    sx, sB, sC = rnd(SR, SH, SP), rnd(SR, SG, SN), rnd(SR, SG, SN)
+    sdt = jax.nn.softplus(rnd(SR, SH).astype(jnp.float32) - 2.0)
+    sA = -jnp.exp(rnd(SH).astype(jnp.float32))
+    sD = jnp.ones((SH,), jnp.float32)
+    want_S, want_y = jax.jit(pallas_ssm.ssm_state_update_reference)(
+        S0, sx, sB, sC, sdt, sA, sD, live)
+    got_S, got_y = pallas_ssm.ssm_state_update(S0 + 0, sx, sB, sC, sdt, sA, sD, live)
+    compare("ssm_state_update 128 rows, 26 dead: the state", got_S, want_S)
+    compare("ssm_state_update 128 rows, 26 dead: y", got_y, want_y)
+    dead = ~np.asarray(live)
+    if not np.array_equal(np.asarray(got_S)[dead], np.asarray(S0)[dead]):
+        raise SystemExit("ssm_state_update: a dead row's state moved")
+    g5_lens = np.asarray([1, 16, 17, 333, 1024, 1311, 0, 700], np.int32)
+    q5 = rnd(8, 20, D)
+    k5, v5 = rnd(NB, BS, 4, D), rnd(NB, BS, 4, D)
+    g5_args = (q5, k5, v5, tables[:8], jnp.asarray(g5_lens))
+    got = np.asarray(kernels.decode(*g5_args), np.float32)
+    # an empty row is zeros from the kernel and a mean over masked keys
+    # from the twin: held apart, as in the 8k case above
+    if got[g5_lens == 0].any():
+        raise SystemExit("decode question, 5 a group: an empty row is not zeros")
+    compare(
+        "decode question, 20 q / 4 kv heads (5 a group), one empty row",
+        got[g5_lens > 0],
+        np.asarray(highest(twins.decode)(*g5_args), np.float32)[g5_lens > 0],
+    )
+
     # block moves are copies: exact
     ids = jnp.asarray(rng.permutation(NB)[:32], jnp.int32)
     got = bc.gather_blocks(k_cache, ids)

@@ -29,7 +29,7 @@ import os
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
-from functools import partial
+from functools import partial, wraps
 from typing import Any, AsyncIterator, Dict, List, Optional, Tuple
 
 import jax
@@ -235,6 +235,9 @@ def _model_param_bytes(mcfg) -> int:
         top_k = getattr(mcfg, "num_experts_per_tok", 2) or 2
         moe_inter = getattr(mcfg, "moe_intermediate_size", mcfg.intermediate_size)
         per_layer = h * (q + 2 * kv) + q * h + 3 * h * moe_inter * top_k
+    if registry.is_falcon_h1(mcfg):
+        # the state-space mixer's two projections beside attention's
+        per_layer += h * mcfg.in_proj_size + mcfg.mamba_d_ssm * h
     return 2 * (per_layer * mcfg.num_layers + embed)
 
 
@@ -496,6 +499,9 @@ class TpuEngine:
                     "eos_id) — see guided.vocab_bytes_from_tokenizer"
                 )
         registry.check_dsa_supported(self.mcfg, **asked)
+        registry.check_state_supported(
+            self.mcfg, **asked, kvbm=kvbm is not None
+        )
         if registry.is_gptoss(self.mcfg) or registry.is_gemma(self.mcfg):
             # the ragged kernel carries per-row window/sink/softcap
             # attributes, so use_pallas serves these families too. Only the
@@ -647,6 +653,22 @@ class TpuEngine:
                     else self._init_params_sharded(config.seed, self.mcfg)
                 )
                 self.k_caches, self.v_caches = self._init_caches()
+        # the second kind of state (engine/state_cache.py): what a family
+        # keeps a SLOT beside its pages; None for every family whose only
+        # state is pages, whose programs then neither take nor return it
+        self.state = None
+        spec = registry.state_spec(self.mcfg)
+        if spec:
+            from .state_cache import SlotState
+
+            with self.mesh:
+                self.state = SlotState(
+                    spec, self.mcfg.num_layers, config.max_batch_size,
+                    NamedSharding(self.mesh, P()),
+                )
+        # the recurrence's counts since the last StepStats (_count_ssm)
+        self._ssm_counts = [0, 0, 0]
+        self._prefix_reusable = registry.prefix_reusable(self.mcfg)
 
         # --- speculative decoding: draft model + shadow paged cache ---
         # The draft cache mirrors the main cache's block geometry and is
@@ -702,6 +724,9 @@ class TpuEngine:
         self._freqs = np.zeros(B, np.float32)
         self._reps = np.ones(B, np.float32)
         self._lp_ns = np.zeros(B, np.int32)    # requested top-logprobs per slot
+        # tokens a slot's request asked for: a horizon of a family with slot
+        # state stops a row's recurrence there (decode_multi, max_new)
+        self._max_new = np.full(B, np.iinfo(np.int32).max, np.int32)
         self._lora_slots = np.zeros(B, np.int32)  # adapter slot per batch slot
         self._lp_masks = np.zeros(
             (B, max(1, len(config.logits_processors))), bool
@@ -869,6 +894,7 @@ class TpuEngine:
         if self.cfg.pp > 1:
             # transfer gathers iterate per-layer cache lists; pp stacks them
             raise ValueError("pp serving does not cover KV transfer yet")
+        registry.check_state_supported(self.mcfg, transfer=True)
         from ..runtime.request_plane.tcp import TcpRequestServer
         from .transfer import KvCommitSignal, KvTransferServer
 
@@ -888,6 +914,8 @@ class TpuEngine:
     def _get_transfer_client(self):
         if self._transfer_client is None:
             from .transfer import KvTransferClient
+
+            registry.check_state_supported(self.mcfg, transfer=True)
 
             self._transfer_client = KvTransferClient(self)
         return self._transfer_client
@@ -1295,8 +1323,10 @@ class TpuEngine:
         moe_counted = self._moe_counted
 
         def call_fwd(params, tokens, positions, attend, lora_tables, lora_ids,
-                     mm_embeds=None, mm_mask=None, moe_stats=None):
+                     mm_embeds=None, mm_mask=None, moe_stats=None, mix=None):
             kw = {}
+            if mix is not None:
+                kw["mix"] = mix
             if moe_stats is not None:
                 kw["stats"] = moe_stats
             if lora_enabled:
@@ -1321,6 +1351,49 @@ class TpuEngine:
         attn = PagedAttention(
             self.mesh, self.use_pallas, self.kernels_interpreted
         )
+
+        # the second seam (models/falcon_h1.py): ``mix`` owns a family's slot
+        # state (engine/state_cache.py) as ``attend`` owns the pages. The
+        # programs below build one only where they were handed ``state``: a
+        # TRACE-time branch, every other family's programs are unchanged.
+        # ``state``: name -> one array a layer, written through in place as
+        # the cache lists are
+        if self.state is not None:
+            from ..models import falcon_h1 as fh1
+            from ..ops import pallas_ssm
+            from .state_cache import fresh
+
+            ssm_update = (
+                partial(pallas_ssm.ssm_state_update,
+                        interpret=self.kernels_interpreted)
+                if self.use_pallas else pallas_ssm.ssm_state_update_reference
+            )
+
+        def chunk_mix(params, state, slot, chunk_start, n_real):
+            """One request's chunk: scan from its slot's state (zeros for a
+            prompt's first chunk), the identity past ``n_real``."""
+            def mix(xBC, dt, l):
+                S, T = state["ssm"][l], state["conv"][l]
+                y, s1, t1 = fh1.mix_chunk(
+                    params["layers"][l], mcfg, xBC, dt,
+                    fresh(S[slot], chunk_start), fresh(T[slot], chunk_start),
+                    n_real,
+                )
+                state["ssm"][l] = S.at[slot].set(s1)
+                state["conv"][l] = T.at[slot].set(t1)
+                return y
+            return mix
+
+        def rows_mix(params, state, live):
+            """One token a slot ([B, 1, ...] in and out): rows that are not
+            ``live`` leave their slot alone."""
+            def mix(xBC, dt, l):
+                y, state["ssm"][l], state["conv"][l] = fh1.mix_rows(
+                    params["layers"][l], mcfg, xBC[:, 0], dt[:, 0],
+                    state["ssm"][l], state["conv"][l], live, ssm_update,
+                )
+                return y[:, None]
+            return mix
 
         procs = cfg.logits_processors
 
@@ -1412,7 +1485,7 @@ class TpuEngine:
                     new_block_ids, step, seeds, temps, top_ks, top_ps, min_ps,
                     pres, freqs, reps, prompt_masks, lora_tables, lora_ids,
                     proc_masks, mm_embeds, mm_mask,
-                    g_active=None, g_class=None, g_trans=None):
+                    g_active=None, g_class=None, g_trans=None, state=None):
             # tokens/positions: [S_pad] — ONE chunk of the prompt (the whole
             # prompt when it fits a bucket); step: the chunk's per-step values
             # (step_args.py: its block-table row [max_blocks_per_seq], span,
@@ -1471,6 +1544,9 @@ class TpuEngine:
             hidden = call_fwd(
                 params, tokens, positions, attend, lora_tables, lora_id,
                 mm_embeds=mm_embeds, mm_mask=mm_mask,
+                mix=None if state is None else chunk_mix(
+                    params, state, slot, chunk_start, total_len - chunk_start
+                ),
             )
 
             def sample_branch(counts):
@@ -1529,7 +1605,7 @@ class TpuEngine:
         def decode(params, k_caches, v_caches, counts, step, block_tables,
                    seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
                    prompt_masks, lora_tables, lora_ids, proc_masks,
-                   g_active=None, g_class=None, g_trans=None):
+                   g_active=None, g_class=None, g_trans=None, state=None):
             # step: the [B] per-step rows (step_args.py); block_tables:
             # [B, max_blocks_per_seq]
             a = step_args.unpack(step, cfg.max_batch_size)
@@ -1552,6 +1628,9 @@ class TpuEngine:
             hidden = call_fwd(
                 params, tokens[:, None], positions[:, None], attend,
                 lora_tables, lora_ids, moe_stats=moe_stats,
+                mix=None if state is None else rows_mix(
+                    params, state, seq_lens > 0
+                ),
             )  # [B, 1, H]
             logits = logits_fn(params, mcfg, hidden[:, 0])  # [B, V]
             pen = apply_penalties(logits, counts, prompt_masks, pres, freqs, reps)
@@ -1577,7 +1656,7 @@ class TpuEngine:
                          top_ps, min_ps, pres, freqs, reps, prompt_masks,
                          lp_need, lora_tables, lora_ids, proc_masks,
                          g_active=None, g_state=None, g_class=None,
-                         g_trans=None):
+                         g_trans=None, state=None, max_new=None):
             """cfg.decode_steps decode iterations in one program: each step
             writes the fed token's KV, attends, samples, and feeds the sample
             back — tokens only reach the host once per horizon. seq_lens==0
@@ -1591,7 +1670,9 @@ class TpuEngine:
             need_pen = counts_need(pres, freqs, reps, proc_masks)
 
             def one_step(carry, s):
-                k_caches, v_caches, counts, tokens, seq_lens, g_st = carry
+                # slot state, where the family has it, rides the carry
+                # behind the pages: ``st`` is {} for every other family
+                k_caches, v_caches, counts, tokens, seq_lens, g_st, st = carry
                 positions = jnp.maximum(seq_lens - 1, 0)
                 write_blocks = jnp.where(
                     active,
@@ -1617,6 +1698,11 @@ class TpuEngine:
                 hidden = call_fwd(
                     params, tokens[:, None], positions[:, None], attend,
                     lora_tables, lora_ids, moe_stats=moe_stats,
+                    # a row that has sampled what its request asked for (the
+                    # host learns it a horizon late) leaves its slot alone:
+                    # a finished slot holds the state after its last fed token
+                    mix=rows_mix(params, st, active & (steps0 + s < max_new))
+                    if st else None,
                 )
                 logits = logits_fn(params, mcfg, hidden[:, 0])
                 pen = apply_penalties(logits, counts, prompt_masks, pres, freqs, reps)
@@ -1633,7 +1719,7 @@ class TpuEngine:
                 tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
                 seq_lens = seq_lens + active.astype(jnp.int32)
                 return (
-                    (k_caches, v_caches, counts, toks, seq_lens, g_st),
+                    (k_caches, v_caches, counts, toks, seq_lens, g_st, st),
                     pack_step(
                         toks, lps, tlp_vals, tlp_ids,
                         moe=None if moe_stats is None else moe_stats.reduce(),
@@ -1641,13 +1727,16 @@ class TpuEngine:
                 )
 
             g0 = g_state if g_state is not None else jnp.zeros_like(tokens)
-            (k_caches, v_caches, counts, tokens, seq_lens, g_out), packed = (
+            (k_caches, v_caches, counts, tokens, seq_lens, g_out, st), packed = (
                 jax.lax.scan(
                     one_step,
-                    (k_caches, v_caches, counts, tokens, seq_lens, g0),
+                    (k_caches, v_caches, counts, tokens, seq_lens, g0,
+                     {} if state is None else state),
                     jnp.arange(cfg.decode_steps),
                 )
             )
+            if state is not None:
+                state.update(st)
             next_steps = steps0 + jnp.where(active, cfg.decode_steps, 0)
             out = (
                 k_caches, v_caches, counts, _fetchable(packed),
@@ -1660,7 +1749,7 @@ class TpuEngine:
                        block_tables, seeds, temps, top_ks, top_ps, min_ps,
                        pres, freqs, reps, prompt_masks, lora_tables,
                        lora_ids, proc_masks,
-                       g_active=None, g_class=None, g_trans=None):
+                       g_active=None, g_class=None, g_trans=None, state=None):
             """ONE fused continuous-batching step: a prefill chunk of one
             sequence (c_* args — the prefill() conventions) rides along with
             the resident decode batch (d_* args — the decode() conventions)
@@ -1744,9 +1833,22 @@ class TpuEngine:
                 jnp.concatenate([c_positions < c_total_len, active]),
                 jnp.concatenate([jnp.zeros((S_pad,), bool), active]),
             )
+            mix = None
+            if state is not None:
+                # the chunk's row scans, the decode rows advance one token:
+                # the chunk's slot is prefilling, so never a live decode row
+                c_mix = chunk_mix(params, state, c_slot, c_chunk_start, chunk_len)
+                d_mix = rows_mix(params, state, active)
+
+                def mix(xBC, dt, l):
+                    return jnp.concatenate([
+                        c_mix(xBC[:S_pad], dt[:S_pad], l),
+                        d_mix(xBC[S_pad:, None], dt[S_pad:, None], l)[:, 0],
+                    ])
+
             hidden = call_fwd(
                 params, tokens, positions, attend, lora_tables,
-                packed_lora_ids, moe_stats=moe_stats,
+                packed_lora_ids, moe_stats=moe_stats, mix=mix,
             )  # [S_pad + B, H]
 
             # -- decode epilogue: verbatim decode() ---------------------------
@@ -2069,11 +2171,35 @@ class TpuEngine:
                 spec_multi, donate_argnums=(2, 3, 4, 5)
             )
 
+        def step_program(fn):
+            """A step program jitted with its pages and counts donated. A
+            family with slot state gets the same program with the state
+            taken fifth, returned fourth and donated too; the call sites
+            stay as they are (the state is handed in and taken back here),
+            and every other family's jitted program is the one it was."""
+            if self.state is None:
+                return jax.jit(fn, donate_argnums=(1, 2, 3))
+
+            @wraps(fn)  # the program keeps its name on the trace
+            def with_state(params, k_caches, v_caches, counts, state, *rest, **kw):
+                out = fn(params, k_caches, v_caches, counts, *rest, state=state, **kw)
+                return out[:3] + (state,) + out[3:]
+
+            jitted = jax.jit(with_state, donate_argnums=(1, 2, 3, 4))
+
+            def call(*args, **kw):
+                out = jitted(*args[:4], self.state.arrays, *args[4:], **kw)
+                self.state.arrays = out[3]
+                return out[:3] + out[4:]
+
+            call.jitted = jitted  # the program itself (tests lower it)
+            return call
+
         self._embed_chunk_fn = jax.jit(embed_chunk, donate_argnums=(1, 2))
-        self._mixed_fn = jax.jit(mixed_step, donate_argnums=(1, 2, 3))
-        self._prefill_fn = jax.jit(prefill, donate_argnums=(1, 2, 3))
-        self._decode_fn = jax.jit(decode, donate_argnums=(1, 2, 3))
-        self._decode_multi_fn = jax.jit(decode_multi, donate_argnums=(1, 2, 3))
+        self._mixed_fn = step_program(mixed_step)
+        self._prefill_fn = step_program(prefill)
+        self._decode_fn = step_program(decode)
+        self._decode_multi_fn = step_program(decode_multi)
         self._reset_slot_fn = jax.jit(reset_slot, donate_argnums=(0, 1))
         self._embed_fn = jax.jit(embed)
         if self._mh is not None:
@@ -2467,6 +2593,9 @@ class TpuEngine:
                 seq=TokenBlockSequence(all_tokens, self.cfg.block_size),
                 last_token=all_tokens[-1] if all_tokens else 0,
                 guided_tables=guided_tables,
+                # a block hash restores pages and no recurrent state: such a
+                # family declines prefix hits and publishes no block
+                no_cache=not self._prefix_reusable,
             )
             if guided_tables is not None and req.prior_token_ids:
                 # disagg decode hop / migration resume: tokens generated so far
@@ -3342,6 +3471,9 @@ class TpuEngine:
             self._freqs[slot] = s.frequency_penalty
             self._reps[slot] = s.repetition_penalty
             self._lp_ns[slot] = min(max(s.logprobs, 0), TOP_LOGPROBS_K)
+            big = np.iinfo(np.int32).max
+            asked = st.req.stop.max_tokens
+            self._max_new[slot] = big if asked is None else min(asked, big)
             seed = s.seed
             self._seeds[slot] = np.uint32(
                 seed if seed is not None else self._host_rng.integers(1 << 32)
@@ -3592,6 +3724,7 @@ class TpuEngine:
         with loop_span(self, "launch"):
             (self.k_caches, self.v_caches, self.output_counts, tok, lp,
              tlp_vals, tlp_ids) = self._prefill_fn(*args)
+            self._count_ssm(0, chunk_len, 0)
         with loop_span(self, "pack"):
             # the next chunk's arrays, built under this chunk's compute
             del args  # donated caches: hold no stale handles
@@ -3706,6 +3839,12 @@ class TpuEngine:
         # chunked: the caller pre-allocated temporary pages (loop thread
         # owns the allocator); each chunk writes KV + attends over the
         # gathered prefix, the final chunk yields the pooled vector
+        if self.state is not None:
+            raise ValueError(
+                "an embedding input above the largest prefill bucket is not "
+                "served by a family with slot state: temporary pages carry "
+                "keys from chunk to chunk, nothing carries a recurrent state"
+            )
         cap = self.cfg.prefill_chunk
         table = np.zeros(self.cfg.max_blocks_per_seq, np.int32)
         table[: len(block_ids)] = block_ids
@@ -3785,6 +3924,7 @@ class TpuEngine:
              tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids) = (
                 self._mixed_fn(*args)
             )
+            self._count_ssm(np.count_nonzero(d_seq_lens), chunk_len, 1)
         with loop_span(self, "pack"):
             # the next chunk's arrays, built under this step's compute
             del args  # donated caches: hold no stale handles
@@ -4162,6 +4302,11 @@ class TpuEngine:
                     *g_args,
                 )
             args = self._upload(args)
+            # slot state: a row's recurrence stops at what its request asked
+            quota = (
+                {} if self.state is None
+                else {"max_new": self._dev("max_new", self._max_new)}
+            )
         # no "sync" here: a horizon's results are awaited by the loop ("fetch")
         with loop_span(self, "launch"):
             if spec:
@@ -4174,7 +4319,7 @@ class TpuEngine:
                     packed, tokens, seq_lens, steps, seqs,
                     spec_k=self.cfg.spec_k,
                 )
-            res = self._decode_multi_fn(*args)
+            res = self._decode_multi_fn(*args, **quota)
             del args  # donated caches: hold no stale handles
             g_state_out = None
             if self.guided_enabled:
@@ -4237,6 +4382,17 @@ class TpuEngine:
         against a ~0.9ms/token device program)."""
         if chain.spec_k is not None:
             return self._apply_packed_spec(chain, packed_np)
+        if self.state is not None:
+            # a consumed horizon advanced each of its snapshot's rows until
+            # the row had sampled what its request asked (decode_multi)
+            n = self.cfg.decode_steps
+            self._count_ssm(
+                sum(
+                    n if st.req.stop.max_tokens is None
+                    else min(n, max(st.req.stop.max_tokens - st.produced, 0))
+                    for st in chain.seqs if st is not None
+                ), 0, n,
+            )
         K = TOP_LOGPROBS_K
         toks = packed_np[:, :, 0].astype(np.int32)
         lps = packed_np[:, :, 1]
@@ -4365,6 +4521,7 @@ class TpuEngine:
             (self.k_caches, self.v_caches, self.output_counts, toks, lps,
              tlp_vals, tlp_ids) = self._decode_fn(*args)
             del args  # donated caches: hold no stale handles
+            self._count_ssm(np.count_nonzero(seq_lens), 0, 1)
         with loop_span(self, "sync"):
             return self._decode_results(seqs, toks, lps, tlp_ids, tlp_vals,
                                         lp_need)
@@ -4612,6 +4769,19 @@ class TpuEngine:
                 st.req.request_id, "slo_violation", **fields
             )
 
+    def _count_ssm(self, row_steps: int, chunk_tokens: int, steps: int) -> None:
+        """A family with slot state (nothing for any other): what a dispatch
+        advanced, for the next StepStats (``ssm_rows_updated``: ``row_steps``,
+        one for each live row of each of its ``steps``, a layer;
+        ``ssm_tokens_scanned``; ``ssm_decode_steps``): host arithmetic on the
+        step's own shapes."""
+        if self.state is None:
+            return
+        c, L = self._ssm_counts, self.mcfg.num_layers
+        c[0] += int(row_steps) * L
+        c[1] += chunk_tokens * L
+        c[2] += steps
+
     def _step_stats(self, phase: str, duration_s: float, tokens: int) -> None:
         """Feed one StepStats to the hook — scalars the loop already holds;
         never forces a device sync (engine/telemetry.py)."""
@@ -4646,13 +4816,20 @@ class TpuEngine:
         reads = dict(zip(self._read_counters, reads))
         held = getattr(self.mcfg, "experts_held", None) is not None
         self._moe_last = None
+        occupancy = sum(1 for s in self._slots if s is not None and not s.done)
+        if self.state is not None:
+            rows, scanned, steps = self._ssm_counts
+            self._ssm_counts = [0, 0, 0]
+            reads.update(
+                ssm_rows_updated=rows, ssm_tokens_scanned=scanned,
+                ssm_decode_steps=steps,
+                ssm_state_bytes=occupancy * self.state.bytes_per_slot,
+            )
         try:
             hook(StepStats(
                 phase=phase,
                 duration_s=duration_s,
-                batch_occupancy=sum(
-                    1 for s in self._slots if s is not None and not s.done
-                ),
+                batch_occupancy=occupancy,
                 batch_size=self.cfg.max_batch_size,
                 tokens=int(tokens),
                 queue_depth=len(self._waiting),
@@ -4770,6 +4947,13 @@ class TpuEngine:
             "decode_steps": self.cfg.decode_steps,
             "decode_pipeline": self.cfg.decode_pipeline,
         }
+        if self.state is not None:
+            # the second kind of state: bytes held a slot and in all
+            snap["slot_state"] = {
+                "bytes_per_slot": self.state.bytes_per_slot,
+                "bytes": self.state.nbytes,
+                "slots": self.state.slots,
+            }
         if self.cfg.spec_draft is not None:
             snap["spec"] = dict(self.spec_stats)
         if self._eplb_enabled:

@@ -285,6 +285,16 @@ class StepStats:
     # (ops/pallas_latent.py): counted from the step's own tables
     mla_chunks_whole: Optional[int] = None
     mla_chunks_run: Optional[int] = None
+    # a family with slot state beside its pages (a state-space mixer;
+    # engine/state_cache.py), from the step's own shapes on the host: live
+    # decode rows x layers whose recurrence the step advanced by one token
+    # (a horizon: a row x the steps it took before its request had what it
+    # asked for), a chunk's real tokens x layers it scanned,
+    # and the bytes of state the slots in use hold. None elsewhere
+    ssm_rows_updated: Optional[int] = None
+    ssm_tokens_scanned: Optional[int] = None
+    ssm_decode_steps: Optional[int] = None   # decode steps those rows took
+    ssm_state_bytes: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -362,6 +372,10 @@ class EngineTelemetry:
         self._moe_imbalance = scope.gauge(
             M.MOE_LOAD_IMBALANCE,
             "largest expert load over the mean load of touched experts, last step",
+        )
+        self._ssm_bytes = scope.gauge(
+            M.SSM_STATE_BYTES,
+            "bytes of recurrent (state-space) state held by the slots in use",
         )
         self.slow_steps = 0
         # small rolling window + last-seen gauges for the /debug/worker
@@ -450,6 +464,13 @@ class EngineTelemetry:
                     "decode_rows": moe.mla_decode_rows,
                     "run_chunk_share": run_chunk_share(recent),
                 }
+        if last is not None and last.ssm_state_bytes is not None:
+            # the second kind of state: what the window's steps advanced
+            out["ssm"] = {
+                "state_bytes": last.ssm_state_bytes,
+                "rows_updated": sum(s.ssm_rows_updated or 0 for s in recent),
+                "tokens_scanned": sum(s.ssm_tokens_scanned or 0 for s in recent),
+            }
         return out
 
     def on_step(self, s: StepStats) -> None:
@@ -467,6 +488,8 @@ class EngineTelemetry:
             self._kv_total.set(s.kv_total_blocks)
             if s.spec_acceptance is not None:
                 self._spec.set(s.spec_acceptance)
+            if s.ssm_state_bytes is not None:
+                self._ssm_bytes.set(s.ssm_state_bytes)
             imbalance = moe_load_imbalance(s)
             if imbalance is not None:
                 self._moe_imbalance.set(imbalance)

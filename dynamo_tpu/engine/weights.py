@@ -30,6 +30,8 @@ def config_from_hf(path: str) -> LlamaConfig:
         return _gptoss_config_from_hf(hf)
     if hf.get("model_type", "") in ("gemma2", "gemma3", "gemma3_text"):
         return _gemma_config_from_hf(hf)
+    if hf.get("model_type", "") == "falcon_h1":
+        return _falcon_h1_config_from_hf(hf)
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return LlamaConfig(
         vocab_size=hf["vocab_size"],
@@ -46,6 +48,50 @@ def config_from_hf(path: str) -> LlamaConfig:
         or hf.get("model_type", "") == "qwen2",
         qk_norm=hf.get("model_type", "") == "qwen3",
         tie_embeddings=hf.get("tie_word_embeddings", False),
+    )
+
+
+def _falcon_h1_config_from_hf(hf: dict):
+    """Falcon-H1's config.json -> FalconH1Config: every key that shapes the
+    computation, the multipliers as data (benchmarks/adapters/falcon_h1.py is
+    the benchmark's own copy of this mapping)."""
+    from ..models.falcon_h1 import FalconH1Config
+
+    if hf.get("mamba_proj_bias") or hf.get("attention_bias") or hf.get("mlp_bias"):
+        raise ValueError("falcon_h1 with projection biases is not built")
+    if not hf.get("mamba_rms_norm", True) or hf.get("rope_scaling"):
+        raise ValueError("falcon_h1 without the mixer's gated RMSNorm, or "
+                         "with scaled rotary positions, is not built")
+    return FalconH1Config(
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"],
+        intermediate_size=hf["intermediate_size"],
+        rope_theta=float(hf.get("rope_theta", 1e11)),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        max_position=hf.get("max_position_embeddings", 8192),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        mamba_d_ssm=hf["mamba_d_ssm"],
+        mamba_n_heads=hf["mamba_n_heads"],
+        mamba_d_head=hf["mamba_d_head"],
+        mamba_d_state=hf["mamba_d_state"],
+        mamba_n_groups=hf["mamba_n_groups"],
+        mamba_d_conv=hf["mamba_d_conv"],
+        mamba_chunk_size=hf.get("mamba_chunk_size", 128),
+        mamba_conv_bias=hf.get("mamba_conv_bias", True),
+        mamba_norm_before_gate=hf.get("mamba_norm_before_gate", False),
+        embedding_multiplier=hf.get("embedding_multiplier", 1.0),
+        lm_head_multiplier=hf.get("lm_head_multiplier", 1.0),
+        attention_in_multiplier=hf.get("attention_in_multiplier", 1.0),
+        attention_out_multiplier=hf.get("attention_out_multiplier", 1.0),
+        key_multiplier=hf.get("key_multiplier", 1.0),
+        ssm_in_multiplier=hf.get("ssm_in_multiplier", 1.0),
+        ssm_out_multiplier=hf.get("ssm_out_multiplier", 1.0),
+        ssm_multipliers=tuple(hf.get("ssm_multipliers", (1.0,) * 5)),
+        mlp_multipliers=tuple(hf.get("mlp_multipliers", (1.0, 1.0))),
     )
 
 
@@ -197,9 +243,18 @@ def load_params(path: str, cfg: Optional[LlamaConfig] = None) -> Dict[str, Any]:
     """Map HF llama/qwen (or deepseek-MLA) tensor names onto our pytree."""
     from ..models.mla import MlaConfig
 
+    from ..models.falcon_h1 import FalconH1Config
     from ..models.gptoss import GptOssConfig
 
     cfg = cfg or config_from_hf(path)
+    if isinstance(cfg, FalconH1Config):
+        raise NotImplementedError(
+            "no checkpoint loader for falcon_h1 yet: the family serves random "
+            "weights (models/falcon_h1.init_params); the mapping of "
+            "model.layers.N.mamba.{in_proj,conv1d,dt_bias,A_log,D,norm,out_proj}"
+            ", self_attn.*_proj and feed_forward.*_proj onto its pytree has "
+            "not been held to a real checkpoint (ROADMAP R11)"
+        )
     if isinstance(cfg, MlaConfig):
         return _load_params_mla(path, cfg)
     if isinstance(cfg, GptOssConfig):

@@ -13,7 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas_moe import grouped_matmul, grouped_matmul_reference
 from ..parallel.mesh import AXIS_TP, shard_map
-from . import gemma, gptoss, llama, mla, moe
+from . import falcon_h1, gemma, gptoss, llama, mla, moe
 
 
 def is_moe(cfg) -> bool:
@@ -32,12 +32,17 @@ def is_gemma(cfg) -> bool:
     return isinstance(cfg, gemma.GemmaConfig)
 
 
+def is_falcon_h1(cfg) -> bool:
+    return isinstance(cfg, falcon_h1.FalconH1Config)
+
+
 def supports_pp(cfg) -> bool:
     """Pipeline-parallel serving covers the dense llama family only: the
     stage placement stacks per-layer params homogeneously, which MoE expert
-    stacks, MLA latent projections, and gpt-oss/gemma windowed-attention
-    extras do not fit (parallel/pp_serving.py)."""
-    return not (is_moe(cfg) or is_mla(cfg) or is_gptoss(cfg) or is_gemma(cfg))
+    stacks, MLA latent projections, gpt-oss/gemma windowed-attention extras
+    and a state-space mixer's slot state do not fit (parallel/pp_serving.py)."""
+    return not (is_moe(cfg) or is_mla(cfg) or is_gptoss(cfg) or is_gemma(cfg)
+                or is_falcon_h1(cfg))
 
 
 def check_pp_supported(cfg) -> None:
@@ -48,8 +53,8 @@ def check_pp_supported(cfg) -> None:
     if not supports_pp(cfg):
         raise ValueError(
             f"pp serving supports dense llama-family models only; "
-            f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma) is not stacked "
-            f"for pipeline stages — configure this preset with pp=1 "
+            f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1) is not "
+            f"stacked for pipeline stages — configure this preset with pp=1 "
             f"(use tp/sp/dp instead)"
         )
 
@@ -121,7 +126,59 @@ def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
             raise ValueError(f"{what} does not run with {why}")
 
 
+def state_spec(cfg) -> tuple:
+    """Per-layer arrays ONE SLOT holds beside the paged keys, as (name,
+    shape, dtype): a state-space mixer's recurrent state and its
+    convolution's tail (``falcon_h1.state_spec``); () for every family whose
+    only state is pages. engine/state_cache.py builds the store from it, and
+    the step programs take and return it only where it is not empty."""
+    return falcon_h1.state_spec(cfg) if is_falcon_h1(cfg) else ()
+
+
+def prefix_reusable(cfg) -> bool:
+    """Whether a block hash restores everything a request needs of its
+    prefix. Pages, yes; a recurrent state is not kept per block, so a family
+    with ``state_spec`` declines prefix hits (the prompt prefills whole) and
+    neither registers nor publishes its blocks as reusable."""
+    return not state_spec(cfg)
+
+
+def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
+                          kv_quantized=False, vision=False, transfer=False,
+                          kvbm=False) -> None:
+    """A family that keeps slot state beside its pages (``state_spec``) runs
+    on the one-chip text path; what it cannot do yet is refused here, at
+    engine construction (``transfer``: where the transfer plane is asked
+    for), each with its reason."""
+    if not state_spec(cfg):
+        return
+    what = f"a state-space mixer's slot state ({type(cfg).__name__})"
+    refusals = [
+        (tp > 1, "tp > 1: the mixer's heads and its groups are not sharded "
+                 "yet (param_specs, the state's sharding)"),
+        (pp > 1 or sp > 1, "pp / sp > 1: neither the wavefront nor the ring "
+                           "carries the recurrent state from stage to stage "
+                           "or shard to shard"),
+        (spec, "a speculative draft: verify rows would need the state rolled "
+               "back to the last accepted token"),
+        (lora, "LoRA: the family has no adapter path"),
+        (kv_quantized, "kv_dtype=int8: the family's cell runs bf16 pages and "
+                       "a float32 state; an 8-bit cache is not calibrated "
+                       "for it"),
+        (vision, "vision: multimodal serving covers the dense family only"),
+        (transfer, "the KV transfer plane (disaggregation, evacuation): it "
+                   "moves pages and knows no slot state"),
+        (kvbm, "KVBM offload tiers: they keep pages by block hash and know "
+               "no slot state"),
+    ]
+    for hit, why in refusals:
+        if hit:
+            raise ValueError(f"{what} does not run with {why}")
+
+
 def family(cfg):
+    if is_falcon_h1(cfg):
+        return falcon_h1
     if is_mla(cfg):
         return mla
     if is_gptoss(cfg):
@@ -166,6 +223,8 @@ def forward_fn(cfg, mesh=None, use_pallas: bool = False,
       moe_ffn_ep_psum — each shard computes only its local experts, one
       psum combines (same collective as a TP row matmul)
     """
+    if is_falcon_h1(cfg):
+        return falcon_h1.forward
     if is_gptoss(cfg):
         if mesh is None or mesh.shape.get(AXIS_TP, 1) == 1:
             return gptoss.forward

@@ -46,6 +46,7 @@ SPEC_ACCEPTANCE = f"{PREFIX}_engine_spec_acceptance_rate"
 SLOW_STEPS_TOTAL = f"{PREFIX}_engine_slow_steps_total"
 LOOP_PHASE_SECONDS_TOTAL = f"{PREFIX}_engine_loop_phase_seconds_total"
 MOE_LOAD_IMBALANCE = f"{PREFIX}_engine_moe_load_imbalance"
+SSM_STATE_BYTES = f"{PREFIX}_engine_ssm_state_bytes"
 # resilience (runtime/resilience.py): per-policy retry/breaker observability
 KV_WIRE_BANDWIDTH = f"{PREFIX}_kv_wire_bandwidth_bytes_per_s"
 PREFILL_DEFLECTED_TOTAL = f"{PREFIX}_prefill_deflected_total"

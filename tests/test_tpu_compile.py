@@ -245,6 +245,19 @@ def _cases():
             return (q, page, page, s((rows, max_blocks), I32), r, r, r)
         return fn, build
 
+    def ssm_update(rows):
+        # the state-space mixer's decode recurrence at Falcon-H1-34B's
+        # widths: 32 heads x [256 state, 128 lanes] float32 a row, 2 groups
+        from dynamo_tpu.ops import pallas_ssm
+
+        def build(sh):
+            s, *_ = _shapes(sh)
+            return (s((rows, 32, 256, 128), F32), s((rows, 32, 128), BF),
+                    s((rows, 2, 256), BF), s((rows, 2, 256), BF),
+                    s((rows, 32), F32), s((32,), F32), s((32,), F32),
+                    s((rows,), jnp.bool_))
+        return pallas_ssm.ssm_state_update, build
+
     def moves(fn, n_ids, with_pages):
         def build(sh):
             s, cache, _, _, ids = _shapes(sh)
@@ -296,6 +309,13 @@ def _cases():
         "paged-latent-chunk-S512": latent("chunk", 512, 1),
         "paged-latent-mixed": latent("ragged", 520, 9),
         "paged-latent-mixed-S128-narrow-table": latent("ragged", 136, 9, 32),
+        # the wide-chat cell (PR 39): 20 q / 4 kv heads, FIVE query heads a kv
+        # head (every other cell has a power of two), 128 rows over tables of
+        # 82 pages; a 512-token chunk beside them; and the recurrence's launch
+        "decode-bf16-5-heads-a-group-wide-cell": decode(4, 20, 128, 82, 8192),
+        "unified-5-heads-a-group-wide-cell": unified_cell(
+            20, 4, 512 + 128, 129, 82, 8192),
+        "ssm-state-update-rows128": ssm_update(128),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),
