@@ -1521,7 +1521,7 @@ class TpuEngine:
                     valid = (positions < total_len)[:, None, None]
                     k_w = jnp.where(valid, k_new, 0.0)
                     v_w = jnp.where(valid, v_new, 0.0)
-                kc, vc = att.write_prefill_kv(
+                kc, vc = attn.write_chunk(
                     k_caches[layer_idx], v_caches[layer_idx], k_w, v_w, new_block_ids
                 )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
@@ -1791,7 +1791,9 @@ class TpuEngine:
                     validc = (c_positions < c_total_len)[:, None, None]
                     k_c = jnp.where(validc, k_c, 0.0)
                     v_c = jnp.where(validc, v_c, 0.0)
-                kc, vc = att.write_prefill_kv(
+                # the chunk's whole pages through the seam: on the view the
+                # launch below reads, so nothing re-tiles the pool between
+                kc, vc = attn.write_chunk(
                     kc, vc, k_c, v_c, c_new_block_ids
                 )
                 kc, vc = att.write_decode_kv(

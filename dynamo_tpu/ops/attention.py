@@ -230,6 +230,7 @@ def write_prefill_kv(
     k_new: jax.Array,         # [S_pad, kvh, d] (S_pad multiple of bs)
     v_new: jax.Array,
     block_ids: jax.Array,     # [S_pad // bs] destination blocks for the span
+    page_view: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Scatter a contiguous span of new KV into its pages (prefill path).
 
@@ -241,7 +242,26 @@ def write_prefill_kv(
     per-block amax (and thus the scale) is computed in one shot — no rescale
     ever needed on this path. The amax covers EVERY row passed, so callers
     must zero bucket-padding rows first (the engine's prefill attend does)
-    or pad activations inflate the real tokens' scale."""
+    or pad activations inflate the real tokens' scale.
+
+    ``page_view``: write a float pool as the Pallas kernels read it,
+    ``[num_blocks, bs * kvh, d]`` (the same bytes in the same pages). The
+    seam (ops/paged_attention.write_chunk) asks for it where a kernel takes
+    the pool whole: the TPU's layout assignment gives a scatter of
+    ``[bs, kvh, d]`` windows a pool tiled over ``(bs, d)`` when ``kvh`` is
+    under a sublane tile (4 rows of 128 lanes a token), the kernel's operand
+    is tiled over ``(kvh, d)``, and the pool is copied there and back around
+    every layer's write (PERF.md section 6, PR 40)."""
+    if page_view and not is_quantized(k_cache):
+        nb, bs, kvh, d = k_cache.shape
+
+        def put(cache, new):
+            pages = cache.reshape(nb, bs * kvh, d).at[block_ids].set(
+                new.reshape(-1, bs * kvh, d)
+            )
+            return pages.reshape(cache.shape)
+
+        return put(k_cache, k_new), put(v_cache, v_new)
     bs = k_cache.shape[1]
     S = k_new.shape[0]
     k_blocks = k_new.reshape(S // bs, bs, *k_new.shape[1:])

@@ -16,6 +16,12 @@ straight through.
   by their tail.
 - Pallas off (CPU, pp, a one-head latent, the families off the auto rule): the
   pure-JAX twins of ops/attention.py, which are also the tests' reference.
+- Before a chunk is read it is written: ``write_chunk`` puts a chunk's whole
+  pages into the pool (``prefill``, and the chunk of a ``mixed_step``) on the
+  view the kernels read wherever a kernel takes the pool whole, so that
+  nothing between the write and the launch re-tiles the pool
+  (ops/attention.write_prefill_kv has the reason). Decode rows' tokens are
+  written by the programs themselves (ops/attention.write_decode_kv).
 - A layer that hands a ``dsa`` (ops/attention.DsaQuery: latent attention over
   the positions a learned indexer selects, models/mla.py) asks ONE further
   question of the same rows, ``_selected``: score the indexer against the
@@ -136,6 +142,18 @@ class PagedAttention:
                 q, kc, vc, tables, q_lens, seq_lens, scale=latent.scale,
                 n_chunk=n_chunk, interpret=self.interpret,
             )
+
+    def write_chunk(self, kc, vc, k_new, v_new, block_ids):
+        """A chunk's whole pages into the pool, before the launch that reads
+        them (ops/attention.write_prefill_kv has the contract). Where a
+        Pallas kernel takes the pool whole (Pallas on, one device: under
+        ``tp`` the kernel's view exists only inside its ``shard_map``) the
+        pages are written on the view the kernel reads, so that nothing
+        between the write and the launch has the pool to re-tile."""
+        return att.write_prefill_kv(
+            kc, vc, k_new, v_new, block_ids,
+            page_view=self.use_pallas and self.mesh.size == 1,
+        )
 
     def decode(self, q, kc, vc, tables, seq_lens, dsa=None, latent=None,
                **extra):

@@ -45,6 +45,19 @@ scalar-prefetched table indices and dequantize in-register; that scale-row
 copy is interpret-only (Mosaic refuses its unaligned minor dim), so the
 engine refuses int8 + Pallas on the TPU backend at construction.
 
+Who writes what this kernel reads: the kernel writes nothing (Mosaic refuses a
+sub-page slice in HBM, so a token could only go in as a whole page through
+VMEM). The decode rows of every program (``decode``, ``decode_multi``, the
+rows behind a ``mixed_step``'s chunk) are one XLA scatter of ``[kv_heads, d]``
+tokens at ``(block, offset)`` into the 4-D pool (ops/attention.write_decode_kv):
+layout assignment gives that scatter the kernel's tiling and copies nothing. A
+chunk's whole pages (``prefill``, ``mixed_step``) are scattered into THIS view,
+``[num_blocks, block_size * kv_heads, head_dim]`` (the seam's ``write_chunk``):
+written as ``[block_size, kv_heads, head_dim]`` windows of the 4-D pool they
+made XLA tile a pool of 4 kv heads over ``(block_size, head_dim)``, and each of
+a layer's two arrays was copied to that tiling and back around the launch, a
+quarter of the wide-chat cell's device time (PERF.md section 6, PR 40).
+
 Grid: ``(Tq_pad / q_block,)`` — one program per BLOCK of ``q_block`` (128)
 packed query tokens, all heads; each block is written by exactly one program
 (zeros for tokens no row owns). Inside a program a loop walks the R rows and

@@ -14,12 +14,15 @@ import os
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from dynamo_tpu.ops import attention as att
 from dynamo_tpu.ops import block_copy as bc
 from dynamo_tpu.ops import pallas_moe as pmoe
 from dynamo_tpu.ops import pallas_unified as pun
@@ -384,6 +387,75 @@ def test_sharded_unified_compiles_on_tp4_mesh(v5e):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce(" not in text and "all-gather(" not in text
+
+
+# One layer's ``attend`` of ``mixed_step`` (engine/engine.py): the chunk's
+# whole pages and the decode rows' tokens written into a donated pool, then
+# the one launch that reads it. name -> (q heads, rows a token, decode rows,
+# chunk tokens, pages a table, pages in the pool, latent): the wide-chat
+# cell's Falcon-H1 (20 q / 4 kv heads, 128 rows), the sparse-expert cell's
+# Mellum2 (32 / 4), the long-cache cell's InternLM2 (8 kv heads: a sublane
+# tile of its own), the document-QA cell's latent held as 4 rows of 128 lanes
+MIXED_ATTENDS = {
+    "kvh4-h20-wide-cell": (20, 4, 128, 128, 82, 8192, False),
+    "kvh4-h32-moe-cell": (32, 4, 16, 512, 448, 7168, False),
+    "kvh8-h16-longcache-cell": (16, 8, 12, 512, 544, 6400, False),
+    "latent-rows-docqa-cell": (64, 4, 8, 512, 1600, 14336, True),
+}
+
+
+def _pool_copies(seam, write_chunk, case):
+    """The ``copy`` / ``copy-start`` instructions of the compiled attend
+    whose result has as many elements as one array of the pool."""
+    h, kvh, rows, S, mb, pages, latent = MIXED_ATTENDS[case]
+    kw = {"latent": att.LatentQuery(scale=0.13086)} if latent else {}
+
+    def attend(kc, vc, q, k_new, v_new, c_blocks, wb, wo, tables, q_lens, lens):
+        kc, vc = write_chunk(kc, vc, k_new[:S], v_new[:S], c_blocks)
+        kc, vc = att.write_decode_kv(kc, vc, k_new[S:], v_new[S:], wb, wo)
+        q_starts = jnp.concatenate(
+            [jnp.zeros((1,), I32), S + jnp.arange(rows, dtype=I32)])
+        return kc, vc, seam.ragged(q, kc, vc, tables, q_starts, q_lens, lens, **kw)
+
+    s, *_ = _shapes(SingleDeviceSharding(seam.mesh.devices.flat[0]))
+    pool, new, r = s((pages, BS, kvh, D), BF), s((S + rows, kvh, D), BF), s((rows + 1,), I32)
+    q = s((S + rows, 64, 640) if latent else (S + rows, h, D), BF)
+    text = jax.jit(attend, donate_argnums=(0, 1)).lower(
+        pool, pool, q, new, new, s((S // BS,), I32), s((rows,), I32),
+        s((rows,), I32), s((rows + 1, mb), I32), r, r,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    found = re.findall(
+        r"^\s*(?:ROOT )?(\S+) = \(?\w+\[([\d,]+)\](\{[^}]*\})?.* copy(?:-start)?\(",
+        text, re.M)
+    return [(name, layout) for name, dims, layout in found
+            if math.prod(map(int, dims.split(","))) == pages * BS * kvh * D]
+
+
+@pytest.mark.parametrize("case", sorted(MIXED_ATTENDS))
+def test_mixed_attend_copies_no_array_of_the_pools_size(chip_seam, case):
+    """With the chunk's pages written as the seam writes them
+    (``write_chunk``: on the kernel's own view of the pool) nothing between
+    the two cache writes and the launch re-tiles the pool: the compiled
+    attend holds no ``copy`` of an array of the pool's size. On the chip
+    each was 0.36 ms a 134 MB array, four a layer (PERF.md section 6, PR
+    40)."""
+    assert _pool_copies(chip_seam, chip_seam.write_chunk, case) == []
+
+
+@pytest.mark.parametrize("case,relaid", [
+    ("kvh4-h20-wide-cell", True), ("kvh4-h32-moe-cell", True),
+    ("latent-rows-docqa-cell", True), ("kvh8-h16-longcache-cell", False)])
+def test_the_four_dimensional_chunk_write_is_what_relays_the_pool(
+        chip_seam, case, relaid):
+    """The control: the same attend with the chunk's pages scattered into
+    the 4-D pool (``write_prefill_kv`` as every other program calls it) has
+    each of the two arrays copied to the scatter's tiling and back where a
+    token is 4 rows of 128 lanes, and none at 8 kv heads: the test above
+    sees what the chip's trace saw. When a compiler stops doing that at 4
+    rows, this fails: ``write_chunk`` can write the 4-D pool again."""
+    copies = _pool_copies(chip_seam, att.write_prefill_kv, case)
+    assert (len(copies) >= 4) == relaid, copies
 
 
 @pytest.mark.parametrize("kernel", ["decode", "unified", "gather-scales"])
