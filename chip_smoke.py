@@ -884,6 +884,64 @@ def _kernel_child() -> None:
         np.asarray(highest(twins.decode)(*g5_args), np.float32)[g5_lens > 0],
     )
 
+    # a KDA layer's decode recurrence (PR 41: the gated delta rule with a
+    # decay a channel) at Solar-Open2-250B's widths, 128 rows of which some
+    # are dead: the matrix state in place, live rows only
+    from dynamo_tpu.ops import pallas_kda
+
+    KH, KD = 64, 128
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    K0 = rnd(SR, KH, KD, KD).astype(jnp.float32)
+    kq = unit(rnd(SR, KH, KD).astype(jnp.float32)) * KD ** -0.5
+    kk = unit(rnd(SR, KH, KD).astype(jnp.float32))
+    kv = rnd(SR, KH, KD)
+    # decays from a channel that forgets inside a token to one that keeps a thousand
+    kalpha = jnp.exp(-jnp.exp(2.5 * rnd(SR, KH, KD).astype(jnp.float32) - 3.0))
+    kbeta = 2.0 * jax.nn.sigmoid(rnd(SR, KH).astype(jnp.float32))
+    want_S, want_y = jax.jit(pallas_kda.kda_state_update_reference)(
+        K0, kq, kk, kv, kalpha, kbeta, live)
+    got_S, got_y = pallas_kda.kda_state_update(K0 + 0, kq, kk, kv, kalpha, kbeta, live)
+    compare("kda_state_update 128 rows, 26 dead: the state", got_S, want_S)
+    compare("kda_state_update 128 rows, 26 dead: y", got_y, want_y)
+    if not np.array_equal(np.asarray(got_S)[dead], np.asarray(K0)[dead]):
+        raise SystemExit("kda_state_update: a dead row's state moved")
+    # the state is float32 IN FLIGHT too, which the benchmark's comparison
+    # cannot tell (it reads what is kept: PERF.md section 7 (17)): a step
+    # rounded to bf16 anywhere would be 2^-9 of the state away, and the loose
+    # tolerance above would pass it. The kernel and a run's chunked scan are
+    # held to the float32 recurrence at 2^-14
+    def float32_close(name, got, want):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        err = float(np.max(np.abs(got - want) / np.maximum(1.0, np.abs(want))))
+        print(f"KERNEL {name}: max relative |got - float32 recurrence| = {err:.3e} "
+              f"(2^-14 = 6.1e-05 allowed; a bf16 step reads 2e-03)", flush=True)
+        if not err <= 2.0 ** -14:
+            raise SystemExit(f"{name}: not a float32 computation ({err:.3e})")
+
+    float32_close("kda_state_update, the state in flight", got_S, want_S)
+    del K0, got_S, want_S
+    KT, KHs = 96, 8  # three chunks of the scan, 8 heads of one row's run
+    # the log of the decay drawn itself, as the model makes it: finite (the
+    # log of an alpha that underflowed is not), to 30 a token
+    kg = -jnp.minimum(jnp.exp(2.5 * rnd(KT, KHs, KD).astype(jnp.float32) - 3.0), 30.0)
+    srun = (kq[:KT, :KHs], kk[:KT, :KHs], kv[:KT, :KHs], kg, kbeta[:KT, :KHs])
+    S_run = rnd(KHs, KD, KD).astype(jnp.float32)
+
+    @jax.jit
+    def token_by_token(S, q, k, v, g, beta):
+        def token(s, inp):
+            q_t, k_t, v_t, g_t, b_t = (x[None] for x in inp)
+            s, y = pallas_kda.kda_state_update_reference(
+                s, q_t, k_t, v_t, jnp.exp(g_t), b_t, jnp.ones((1,), bool))
+            return s, y[0]
+        s, ys = jax.lax.scan(token, S[None], (q, k, v, g, beta))
+        return ys, s[0]
+
+    got_y, got_S = jax.jit(pallas_kda.kda_scan)(S_run, *srun)
+    want_y, want_S = token_by_token(S_run, *srun)
+    float32_close("kda_scan 96 tokens x 8 heads, the state after the run", got_S, want_S)
+    float32_close("kda_scan 96 tokens x 8 heads, y", got_y, want_y)
+
     # block moves are copies: exact
     ids = jnp.asarray(rng.permutation(NB)[:32], jnp.int32)
     got = bc.gather_blocks(k_cache, ids)

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import math
 import os
 import time
 from collections import deque
@@ -222,23 +223,30 @@ class TpuEngineConfig:
 
 
 def _model_param_bytes(mcfg) -> int:
-    """Rough bf16 parameter footprint — the per-decode-step HBM traffic
-    floor (every weight is read once per step at small batch)."""
-    h = mcfg.hidden_size
-    q = mcfg.num_heads * mcfg.head_dim
-    kv = mcfg.num_kv_heads * mcfg.head_dim
-    per_layer = h * (q + 2 * kv) + q * h + 3 * h * mcfg.intermediate_size
-    embed = mcfg.vocab_size * h * (1 if mcfg.tie_embeddings else 2)
-    n_experts = getattr(mcfg, "num_experts", 0) or 0
-    if n_experts:
-        # active experts only (top-k routing): traffic, not capacity
-        top_k = getattr(mcfg, "num_experts_per_tok", 2) or 2
-        moe_inter = getattr(mcfg, "moe_intermediate_size", mcfg.intermediate_size)
-        per_layer = h * (q + 2 * kv) + q * h + 3 * h * moe_inter * top_k
-    if registry.is_falcon_h1(mcfg):
-        # the state-space mixer's two projections beside attention's
-        per_layer += h * mcfg.in_proj_size + mcfg.mamba_d_ssm * h
-    return 2 * (per_layer * mcfg.num_layers + embed)
+    """Parameter bytes a decode step reads at small batch: the per-step HBM
+    traffic floor. Counted from the shapes of the family's OWN parameter
+    pytree (``registry.init_params`` in the abstract: nothing is drawn), so
+    a new family brings no arithmetic here. An expert stack (a leaf the
+    family names, ``registry.expert_stack_leaves``: stacked over the experts
+    the chip holds) counts its top-k experts only: traffic, not capacity."""
+    shapes = jax.eval_shape(
+        lambda k: registry.init_params(k, mcfg), jax.random.PRNGKey(0)
+    )
+    stacks = registry.expert_stack_leaves(mcfg)
+    top_k = getattr(mcfg, "num_experts_per_tok", 0)
+
+    def leaf_bytes(name, x):
+        n = math.prod(x.shape) * x.dtype.itemsize
+        if name not in stacks:
+            return n
+        return n * min(top_k, x.shape[0]) // x.shape[0]
+
+    total = sum(
+        leaf_bytes(name, x) for name, x in shapes.items() if name != "layers"
+    )
+    for layer in shapes["layers"]:
+        total += sum(leaf_bytes(name, x) for name, x in layer.items())
+    return int(total)
 
 
 def measure_device_rtt(device, tries: int = 3) -> float:
@@ -662,12 +670,15 @@ class TpuEngine:
             from .state_cache import SlotState
 
             with self.mesh:
+                # one array a name a layer that KEEPS slot state
                 self.state = SlotState(
-                    spec, self.mcfg.num_layers, config.max_batch_size,
-                    NamedSharding(self.mesh, P()),
+                    spec, len(registry.state_layers(self.mcfg)),
+                    config.max_batch_size, NamedSharding(self.mesh, P()),
                 )
-        # the recurrence's counts since the last StepStats (_count_ssm)
-        self._ssm_counts = [0, 0, 0]
+        # the recurrence's counts since the last StepStats (_count_state),
+        # reported under the family's prefix (ssm_* / kda_*)
+        self._state_counts = [0, 0, 0]
+        self._state_prefix = registry.state_prefix(self.mcfg)
         self._prefix_reusable = registry.prefix_reusable(self.mcfg)
 
         # --- speculative decoding: draft model + shadow paged cache ---
@@ -926,9 +937,11 @@ class TpuEngine:
         register_llm advertises for transfer-aware disagg routing)."""
         from ..kvbm.layout import kv_bytes_per_token
 
+        # the layout counts every layer; only page_layers hold pages
+        held = len(registry.page_layers(self.mcfg)) / self.mcfg.num_layers
         return int(
             kv_bytes_per_token(self.mcfg, self.cfg.block_size, self.cfg.kv_dtype)
-            * self.cfg.block_size
+            * self.cfg.block_size * held
         )
 
     def _evacuation_plan(self, st) -> Optional[Dict[str, Any]]:
@@ -1035,6 +1048,9 @@ class TpuEngine:
         sharding = NamedSharding(
             self.mesh, registry.kv_cache_spec(mcfg, tp_n)
         )
+        # one pair of arrays a layer that KEEPS pages (registry.page_layers:
+        # every layer, but for a family whose layers are of different kinds)
+        n_paged = len(registry.page_layers(mcfg))
         # host-side zeros: device_put shards them per-process (jnp.zeros would
         # commit to the local default device — invalid for a multi-host mesh)
         if quantized:
@@ -1051,12 +1067,12 @@ class TpuEngine:
                     jax.device_put(np.zeros(s_shape, SCALE_DTYPE), s_sharding),
                 )
 
-            k = [qzeros() for _ in range(mcfg.num_layers)]
-            v = [qzeros() for _ in range(mcfg.num_layers)]
+            k = [qzeros() for _ in range(n_paged)]
+            v = [qzeros() for _ in range(n_paged)]
             return k, v
         zeros = partial(np.zeros, shape, mcfg.dtype)
-        k = [jax.device_put(zeros(), sharding) for _ in range(mcfg.num_layers)]
-        v = [jax.device_put(zeros(), sharding) for _ in range(mcfg.num_layers)]
+        k = [jax.device_put(zeros(), sharding) for _ in range(n_paged)]
+        v = [jax.device_put(zeros(), sharding) for _ in range(n_paged)]
         return k, v
 
     def _resolve_use_pallas(self) -> bool:
@@ -1321,10 +1337,25 @@ class TpuEngine:
         vision_enabled = cfg.vision is not None
 
         moe_counted = self._moe_counted
+        # model layer -> its place among the layers that keep pages / slot
+        # state (None: every layer does, the model's index is the place)
+        page_of = registry.layer_index(
+            registry.page_layers(mcfg), mcfg.num_layers
+        )
+        state_of = registry.layer_index(
+            registry.state_layers(mcfg), mcfg.num_layers
+        ) if self.state is not None else None
 
         def call_fwd(params, tokens, positions, attend, lora_tables, lora_ids,
                      mm_embeds=None, mm_mask=None, moe_stats=None, mix=None):
             kw = {}
+            if page_of is not None:
+                # a family whose layers are of different kinds: a layer's
+                # pages by its place among page_layers
+                paged = attend
+
+                def attend(q, k_new, v_new, layer_idx, **extra):
+                    return paged(q, k_new, v_new, page_of[layer_idx], **extra)
             if mix is not None:
                 kw["mix"] = mix
             if moe_stats is not None:
@@ -1352,46 +1383,50 @@ class TpuEngine:
             self.mesh, self.use_pallas, self.kernels_interpreted
         )
 
-        # the second seam (models/falcon_h1.py): ``mix`` owns a family's slot
-        # state (engine/state_cache.py) as ``attend`` owns the pages. The
-        # programs below build one only where they were handed ``state``: a
-        # TRACE-time branch, every other family's programs are unchanged.
-        # ``state``: name -> one array a layer, written through in place as
-        # the cache lists are
+        # the second seam: ``mix`` owns a family's slot state
+        # (engine/state_cache.py) as ``attend`` owns the pages. The family's
+        # two mixing functions come from the registry (``mixers``) and the
+        # state's names from ``state_spec``; nothing here names a family.
+        # The programs below build a ``mix`` only where they were handed
+        # ``state``: a TRACE-time branch, every other family's programs are
+        # unchanged. ``state``: name -> one array a state layer, written
+        # through in place as the cache lists are
         if self.state is not None:
-            from ..models import falcon_h1 as fh1
-            from ..ops import pallas_ssm
             from .state_cache import fresh
 
-            ssm_update = (
-                partial(pallas_ssm.ssm_state_update,
-                        interpret=self.kernels_interpreted)
-                if self.use_pallas else pallas_ssm.ssm_state_update_reference
+            mix_chunk, mix_rows = registry.mixers(
+                mcfg, self.use_pallas, self.kernels_interpreted
             )
+            names = [name for name, _, _ in self.state.spec]
 
         def chunk_mix(params, state, slot, chunk_start, n_real):
             """One request's chunk: scan from its slot's state (zeros for a
             prompt's first chunk), the identity past ``n_real``."""
-            def mix(xBC, dt, l):
-                S, T = state["ssm"][l], state["conv"][l]
-                y, s1, t1 = fh1.mix_chunk(
-                    params["layers"][l], mcfg, xBC, dt,
-                    fresh(S[slot], chunk_start), fresh(T[slot], chunk_start),
-                    n_real,
+            def mix(*xs):
+                *xs, l = xs
+                i = l if state_of is None else state_of[l]
+                held = [state[name][i] for name in names]
+                y, *new = mix_chunk(
+                    params["layers"][l], mcfg, *xs,
+                    *(fresh(a[slot], chunk_start) for a in held), n_real,
                 )
-                state["ssm"][l] = S.at[slot].set(s1)
-                state["conv"][l] = T.at[slot].set(t1)
+                for name, a, n in zip(names, held, new):
+                    state[name][i] = a.at[slot].set(n)
                 return y
             return mix
 
         def rows_mix(params, state, live):
             """One token a slot ([B, 1, ...] in and out): rows that are not
             ``live`` leave their slot alone."""
-            def mix(xBC, dt, l):
-                y, state["ssm"][l], state["conv"][l] = fh1.mix_rows(
-                    params["layers"][l], mcfg, xBC[:, 0], dt[:, 0],
-                    state["ssm"][l], state["conv"][l], live, ssm_update,
+            def mix(*xs):
+                *xs, l = xs
+                i = l if state_of is None else state_of[l]
+                y, *new = mix_rows(
+                    params["layers"][l], mcfg, *(x[:, 0] for x in xs),
+                    *(state[name][i] for name in names), live,
                 )
+                for name, n in zip(names, new):
+                    state[name][i] = n
                 return y[:, None]
             return mix
 
@@ -1842,10 +1877,11 @@ class TpuEngine:
                 c_mix = chunk_mix(params, state, c_slot, c_chunk_start, chunk_len)
                 d_mix = rows_mix(params, state, active)
 
-                def mix(xBC, dt, l):
+                def mix(*xs):
+                    *xs, l = xs
                     return jnp.concatenate([
-                        c_mix(xBC[:S_pad], dt[:S_pad], l),
-                        d_mix(xBC[S_pad:, None], dt[S_pad:, None], l)[:, 0],
+                        c_mix(*(x[:S_pad] for x in xs), l),
+                        d_mix(*(x[S_pad:, None] for x in xs), l)[:, 0],
                     ])
 
             hidden = call_fwd(
@@ -3726,7 +3762,7 @@ class TpuEngine:
         with loop_span(self, "launch"):
             (self.k_caches, self.v_caches, self.output_counts, tok, lp,
              tlp_vals, tlp_ids) = self._prefill_fn(*args)
-            self._count_ssm(0, chunk_len, 0)
+            self._count_state(0, chunk_len, 0)
         with loop_span(self, "pack"):
             # the next chunk's arrays, built under this chunk's compute
             del args  # donated caches: hold no stale handles
@@ -3926,7 +3962,7 @@ class TpuEngine:
              tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids) = (
                 self._mixed_fn(*args)
             )
-            self._count_ssm(np.count_nonzero(d_seq_lens), chunk_len, 1)
+            self._count_state(np.count_nonzero(d_seq_lens), chunk_len, 1)
         with loop_span(self, "pack"):
             # the next chunk's arrays, built under this step's compute
             del args  # donated caches: hold no stale handles
@@ -4388,7 +4424,7 @@ class TpuEngine:
             # a consumed horizon advanced each of its snapshot's rows until
             # the row had sampled what its request asked (decode_multi)
             n = self.cfg.decode_steps
-            self._count_ssm(
+            self._count_state(
                 sum(
                     n if st.req.stop.max_tokens is None
                     else min(n, max(st.req.stop.max_tokens - st.produced, 0))
@@ -4523,7 +4559,7 @@ class TpuEngine:
             (self.k_caches, self.v_caches, self.output_counts, toks, lps,
              tlp_vals, tlp_ids) = self._decode_fn(*args)
             del args  # donated caches: hold no stale handles
-            self._count_ssm(np.count_nonzero(seq_lens), 0, 1)
+            self._count_state(np.count_nonzero(seq_lens), 0, 1)
         with loop_span(self, "sync"):
             return self._decode_results(seqs, toks, lps, tlp_ids, tlp_vals,
                                         lp_need)
@@ -4771,15 +4807,15 @@ class TpuEngine:
                 st.req.request_id, "slo_violation", **fields
             )
 
-    def _count_ssm(self, row_steps: int, chunk_tokens: int, steps: int) -> None:
+    def _count_state(self, row_steps: int, chunk_tokens: int, steps: int) -> None:
         """A family with slot state (nothing for any other): what a dispatch
-        advanced, for the next StepStats (``ssm_rows_updated``: ``row_steps``,
-        one for each live row of each of its ``steps``, a layer;
-        ``ssm_tokens_scanned``; ``ssm_decode_steps``): host arithmetic on the
-        step's own shapes."""
+        advanced, for the next StepStats, under the family's prefix
+        (``<ssm|kda>_rows_updated``: ``row_steps``, one for each live row of
+        each of its ``steps``, a STATE layer; ``_tokens_scanned``;
+        ``_decode_steps``): host arithmetic on the step's own shapes."""
         if self.state is None:
             return
-        c, L = self._ssm_counts, self.mcfg.num_layers
+        c, L = self._state_counts, self.state.num_layers
         c[0] += int(row_steps) * L
         c[1] += chunk_tokens * L
         c[2] += steps
@@ -4820,13 +4856,15 @@ class TpuEngine:
         self._moe_last = None
         occupancy = sum(1 for s in self._slots if s is not None and not s.done)
         if self.state is not None:
-            rows, scanned, steps = self._ssm_counts
-            self._ssm_counts = [0, 0, 0]
-            reads.update(
-                ssm_rows_updated=rows, ssm_tokens_scanned=scanned,
-                ssm_decode_steps=steps,
-                ssm_state_bytes=occupancy * self.state.bytes_per_slot,
-            )
+            rows, scanned, steps = self._state_counts
+            self._state_counts = [0, 0, 0]
+            pre = self._state_prefix
+            reads.update({
+                f"{pre}_rows_updated": rows, f"{pre}_tokens_scanned": scanned,
+                f"{pre}_decode_steps": steps,
+                # the slot store's bytes, whatever recurrence fills it
+                "ssm_state_bytes": occupancy * self.state.bytes_per_slot,
+            })
         try:
             hook(StepStats(
                 phase=phase,

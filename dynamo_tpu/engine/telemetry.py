@@ -294,7 +294,17 @@ class StepStats:
     ssm_rows_updated: Optional[int] = None
     ssm_tokens_scanned: Optional[int] = None
     ssm_decode_steps: Optional[int] = None   # decode steps those rows took
+    # the slot store's bytes in use, whatever recurrence fills it (a
+    # state-space mixer's, a linear-attention layer's)
     ssm_state_bytes: Optional[int] = None
+    # the same three counts for a family whose slot state is a
+    # linear-attention layer's matrix state (gated delta rule: solar_open2),
+    # over the layers that KEEP state (registry.state_layers): live decode
+    # rows x those layers advanced one token, a chunk's real tokens x those
+    # layers scanned, the decode steps the rows took. None elsewhere
+    kda_rows_updated: Optional[int] = None
+    kda_tokens_scanned: Optional[int] = None
+    kda_decode_steps: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -375,7 +385,8 @@ class EngineTelemetry:
         )
         self._ssm_bytes = scope.gauge(
             M.SSM_STATE_BYTES,
-            "bytes of recurrent (state-space) state held by the slots in use",
+            "bytes of slot state (a state-space mixer's, a linear-attention "
+            "layer's) held by the slots in use",
         )
         self.slow_steps = 0
         # small rolling window + last-seen gauges for the /debug/worker
@@ -466,10 +477,15 @@ class EngineTelemetry:
                 }
         if last is not None and last.ssm_state_bytes is not None:
             # the second kind of state: what the window's steps advanced
-            out["ssm"] = {
+            # (under the family's prefix: a state-space mixer "ssm", a
+            # linear-attention layer "kda")
+            pre = "kda" if last.kda_rows_updated is not None else "ssm"
+            out[pre] = {
                 "state_bytes": last.ssm_state_bytes,
-                "rows_updated": sum(s.ssm_rows_updated or 0 for s in recent),
-                "tokens_scanned": sum(s.ssm_tokens_scanned or 0 for s in recent),
+                "rows_updated": sum(
+                    getattr(s, f"{pre}_rows_updated") or 0 for s in recent),
+                "tokens_scanned": sum(
+                    getattr(s, f"{pre}_tokens_scanned") or 0 for s in recent),
             }
         return out
 

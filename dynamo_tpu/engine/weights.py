@@ -32,6 +32,8 @@ def config_from_hf(path: str) -> LlamaConfig:
         return _gemma_config_from_hf(hf)
     if hf.get("model_type", "") == "falcon_h1":
         return _falcon_h1_config_from_hf(hf)
+    if hf.get("model_type", "") == "solar_open2":
+        return _solar_open2_config_from_hf(hf)
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return LlamaConfig(
         vocab_size=hf["vocab_size"],
@@ -48,6 +50,46 @@ def config_from_hf(path: str) -> LlamaConfig:
         or hf.get("model_type", "") == "qwen2",
         qk_norm=hf.get("model_type", "") == "qwen3",
         tie_embeddings=hf.get("tie_word_embeddings", False),
+    )
+
+
+def _solar_open2_config_from_hf(hf: dict):
+    """Solar Open 2's config.json -> SolarOpen2Config: every key that shapes
+    the computation; what the keys do not fix is the class's own default
+    (benchmarks/adapters/solar_open2.py is the benchmark's own copy of this
+    mapping, with its held share of the experts)."""
+    from ..models.solar_open2 import SolarOpen2Config
+
+    lin = hf["linear_attn_config"]
+    L = hf["num_hidden_layers"]
+    if hf.get("use_rope") or hf.get("kda_use_full_proj") or hf.get("first_k_dense_replace"):
+        raise ValueError("solar_open2 with rotary positions, full-rank decay "
+                         "projections or leading dense layers is not built")
+    return SolarOpen2Config(
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"],
+        intermediate_size=hf["intermediate_size"],
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+        max_position=hf.get("max_position_embeddings", 8192),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        gqa_layers=tuple(i for i in hf["gqa_layers"] if i < L),
+        use_gqa_gate=bool(hf.get("use_gqa_gate", True)),
+        kda_num_heads=lin["num_heads"],
+        kda_head_dim=lin["head_dim"],
+        kda_conv_kernel=lin["short_conv_kernel_size"],
+        kda_low_rank=lin["head_dim"],
+        kda_allow_neg_eigval=bool(hf.get("kda_allow_neg_eigval", True)),
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        num_shared_experts=hf.get("n_shared_experts", 1),
     )
 
 
@@ -254,6 +296,15 @@ def load_params(path: str, cfg: Optional[LlamaConfig] = None) -> Dict[str, Any]:
             "model.layers.N.mamba.{in_proj,conv1d,dt_bias,A_log,D,norm,out_proj}"
             ", self_attn.*_proj and feed_forward.*_proj onto its pytree has "
             "not been held to a real checkpoint (ROADMAP R11)"
+        )
+    from ..models.solar_open2 import SolarOpen2Config
+
+    if isinstance(cfg, SolarOpen2Config):
+        raise NotImplementedError(
+            "no checkpoint loader for solar_open2 yet: the family serves "
+            "random weights (models/solar_open2.init_params); the mapping of "
+            "the checkpoint's tensors onto its pytree has not been held to a "
+            "real checkpoint (ROADMAP R11)"
         )
     if isinstance(cfg, MlaConfig):
         return _load_params_mla(path, cfg)

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -164,6 +165,13 @@ def state_spec(cfg: FalconH1Config) -> Tuple[Tuple[str, Tuple[int, ...], Any], .
         ("ssm", (cfg.mamba_n_heads, cfg.mamba_d_state, cfg.mamba_d_head), F32),
         ("conv", (cfg.mamba_d_conv - 1, cfg.conv_dim), cfg.dtype),
     )
+
+
+def state_update(use_pallas: bool, interpret: bool = False) -> Callable:
+    """The decode rows' recurrence: the Pallas launch or its twin."""
+    if use_pallas:
+        return partial(pallas_ssm.ssm_state_update, interpret=interpret)
+    return pallas_ssm.ssm_state_update_reference
 
 
 # ---------------------------------------------------------------------------

@@ -120,6 +120,10 @@ def rope_tables(cfg: GptOssConfig, positions: jax.Array):
 # ---------------------------------------------------------------------------
 
 
+# a layer's leaves stacked over the experts (registry.expert_stack_leaves)
+EXPERT_STACKS = ("w_gateup", "b_gateup", "w_edown", "b_edown")
+
+
 def init_layer_params(rng: jax.Array, cfg: GptOssConfig) -> Params:
     k = jax.random.split(rng, 10)
     h, qd, kvd = cfg.hidden_size, cfg.q_size, cfg.kv_size

@@ -477,62 +477,14 @@ def init_params(rng: jax.Array, cfg: MlaConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def route(p: Params, cfg: MlaConfig, x: jax.Array):
-    """Top-k router matching HF DeepseekV3TopkRouter semantics: sigmoid (V3)
-    or softmax (V2) scores; SELECTION uses scores + the aux-free balancing
-    bias (e_score_correction_bias) and optional group-limited top-k, while
-    the combine WEIGHTS are the unbiased scores gathered at the selected
-    indices, normalized then scaled. x [T, H] -> (weights [T,K] f32,
-    idx [T,K])."""
-    logits = (x.astype(jnp.float32) @ p["w_router"].astype(jnp.float32))
-    if cfg.moe_scoring == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-    sel = scores
-    bias = p.get("router_bias")
-    if bias is not None:
-        sel = sel + bias.astype(jnp.float32)
-    if cfg.n_group > 1:
-        T = sel.shape[0]
-        G, Eg = cfg.n_group, cfg.num_experts // cfg.n_group
-        group_scores = jax.lax.top_k(sel.reshape(T, G, Eg), 2)[0].sum(-1)
-        _, gidx = jax.lax.top_k(group_scores, cfg.topk_group)        # [T, tg]
-        gmask = jax.nn.one_hot(gidx, G, dtype=jnp.float32).sum(1)    # [T, G]
-        emask = jnp.repeat(gmask, Eg, axis=-1)                       # [T, E]
-        sel = jnp.where(emask > 0, sel, 0.0)  # HF masked_fill(~mask, 0.0)
-    _, topi = jax.lax.top_k(sel, cfg.num_experts_per_tok)
-    topw = jnp.take_along_axis(scores, topi, axis=-1)
-    if cfg.norm_topk_prob:
-        topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
-    return topw * cfg.routed_scaling_factor, topi
-
-
-def expert_params(p: Params) -> Params:
-    """Expert stacks under the names moe.py's kernels expect."""
-    return {"w_gate": p["w_egate"], "w_up": p["w_eup"], "w_down": p["w_edown"]}
-
-
-def _moe_ffn(
-    p: Params, cfg: MlaConfig, x: jax.Array, expert_fn=None, stats=None,
-    matmul=moelib.grouped_matmul_reference,
-) -> jax.Array:
-    """Routed experts (moe.py grouped path fed by this module's DeepSeek
-    router, or a mesh-aware ``expert_fn`` injected by the registry for EP)
-    + the always-on shared-expert SwiGLU. ``matmul`` is the grouped path's
-    multiplication (the Pallas kernel where the registry turns it on)."""
-    routed = route(p, cfg, x)
-    if expert_fn is not None:
-        y = expert_fn(expert_params(p), x, routed)
-    else:
-        y = moelib.moe_ffn_grouped(
-            expert_params(p), cfg, x, routed=routed, stats=stats,
-            matmul=matmul, held=cfg.experts_held,
-        )
-    if cfg.num_shared_experts > 0:
-        sg = jax.nn.silu((x @ p["w_shared_gate"]).astype(jnp.float32)).astype(x.dtype)
-        y = y + (sg * (x @ p["w_shared_up"])) @ p["w_shared_down"]
-    return y
+# ONE definition for every family whose expert layer is this one (models/
+# moe.py: sigmoid or softmax scores, a selection bias, optional groups,
+# normalised top-k, a held share, the shared expert): solar_open2 calls the
+# same three
+route = moelib.route_scored
+expert_params = moelib.expert_stacks
+EXPERT_STACKS = moelib.ROUTED_SHARED_STACKS
+_moe_ffn = moelib.routed_shared_ffn
 
 
 def _dense_ffn(p: Params, cfg: MlaConfig, x: jax.Array) -> jax.Array:

@@ -394,6 +394,80 @@ def moe_ffn_grouped(
 
 
 # ---------------------------------------------------------------------------
+# the scored router + shared expert (DeepSeek-V3 / glm4_moe lineage): ONE
+# definition for the families that route so (MlaConfig, SolarOpen2Config).
+# It reads field names only: moe_scoring, n_group, topk_group, num_experts,
+# num_experts_per_tok, norm_topk_prob, routed_scaling_factor, experts_held,
+# num_shared_experts; parameters w_router, router_bias, w_e*, w_shared_*
+# ---------------------------------------------------------------------------
+
+
+def route_scored(p: Params, cfg, x: jax.Array):
+    """Top-k router matching HF DeepseekV3TopkRouter semantics: sigmoid (V3)
+    or softmax (V2) scores; SELECTION uses scores + the aux-free balancing
+    bias (e_score_correction_bias) and optional group-limited top-k, while
+    the combine WEIGHTS are the unbiased scores gathered at the selected
+    indices, normalized then scaled. x [T, H] -> (weights [T,K] f32,
+    idx [T,K])."""
+    logits = (x.astype(jnp.float32) @ p["w_router"].astype(jnp.float32))
+    if cfg.moe_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    sel = scores
+    bias = p.get("router_bias")
+    if bias is not None:
+        sel = sel + bias.astype(jnp.float32)
+    if cfg.n_group > 1:
+        T = sel.shape[0]
+        G, Eg = cfg.n_group, cfg.num_experts // cfg.n_group
+        group_scores = jax.lax.top_k(sel.reshape(T, G, Eg), 2)[0].sum(-1)
+        _, gidx = jax.lax.top_k(group_scores, cfg.topk_group)        # [T, tg]
+        gmask = jax.nn.one_hot(gidx, G, dtype=jnp.float32).sum(1)    # [T, G]
+        emask = jnp.repeat(gmask, Eg, axis=-1)                       # [T, E]
+        sel = jnp.where(emask > 0, sel, 0.0)  # HF masked_fill(~mask, 0.0)
+    _, topi = jax.lax.top_k(sel, cfg.num_experts_per_tok)
+    topw = jnp.take_along_axis(scores, topi, axis=-1)
+    if cfg.norm_topk_prob:
+        topw = topw / (topw.sum(-1, keepdims=True) + 1e-20)
+    return topw * cfg.routed_scaling_factor, topi
+
+
+# a layer's leaves that are stacked over the experts the chip holds
+# (registry.expert_stack_leaves): a MoeConfig layer's, and those of a family
+# whose layer is routed_shared_ffn's (mla, solar_open2)
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+ROUTED_SHARED_STACKS = ("w_egate", "w_eup", "w_edown")
+
+
+def expert_stacks(p: Params) -> Params:
+    """Expert stacks under the names moe.py's kernels expect."""
+    return {k: p[name] for k, name in zip(EXPERT_STACKS, ROUTED_SHARED_STACKS)}
+
+
+def routed_shared_ffn(
+    p: Params, cfg, x: jax.Array, expert_fn=None, stats=None,
+    matmul=grouped_matmul_reference,
+) -> jax.Array:
+    """Routed experts (the grouped path above fed by ``route_scored``, or a
+    mesh-aware ``expert_fn`` injected by the registry for EP) + the always-on
+    shared-expert SwiGLU. ``matmul`` is the grouped path's multiplication
+    (the Pallas kernel where the registry turns it on)."""
+    routed = route_scored(p, cfg, x)
+    if expert_fn is not None:
+        y = expert_fn(expert_stacks(p), x, routed)
+    else:
+        y = moe_ffn_grouped(
+            expert_stacks(p), cfg, x, routed=routed, stats=stats,
+            matmul=matmul, held=cfg.experts_held,
+        )
+    if cfg.num_shared_experts > 0:
+        sg = jax.nn.silu((x @ p["w_shared_gate"]).astype(jnp.float32)).astype(x.dtype)
+        y = y + (sg * (x @ p["w_shared_up"])) @ p["w_shared_down"]
+    return y
+
+
+# ---------------------------------------------------------------------------
 # EP strategies
 # ---------------------------------------------------------------------------
 

@@ -13,7 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas_moe import grouped_matmul, grouped_matmul_reference
 from ..parallel.mesh import AXIS_TP, shard_map
-from . import falcon_h1, gemma, gptoss, llama, mla, moe
+from . import falcon_h1, gemma, gptoss, llama, mla, moe, solar_open2
 
 
 def is_moe(cfg) -> bool:
@@ -36,13 +36,18 @@ def is_falcon_h1(cfg) -> bool:
     return isinstance(cfg, falcon_h1.FalconH1Config)
 
 
+def is_solar_open2(cfg) -> bool:
+    return isinstance(cfg, solar_open2.SolarOpen2Config)
+
+
 def supports_pp(cfg) -> bool:
     """Pipeline-parallel serving covers the dense llama family only: the
     stage placement stacks per-layer params homogeneously, which MoE expert
     stacks, MLA latent projections, gpt-oss/gemma windowed-attention extras
-    and a state-space mixer's slot state do not fit (parallel/pp_serving.py)."""
+    and a family's slot state (a state-space mixer's, a linear-attention
+    layer's) do not fit (parallel/pp_serving.py)."""
     return not (is_moe(cfg) or is_mla(cfg) or is_gptoss(cfg) or is_gemma(cfg)
-                or is_falcon_h1(cfg))
+                or is_falcon_h1(cfg) or is_solar_open2(cfg))
 
 
 def check_pp_supported(cfg) -> None:
@@ -53,7 +58,7 @@ def check_pp_supported(cfg) -> None:
     if not supports_pp(cfg):
         raise ValueError(
             f"pp serving supports dense llama-family models only; "
-            f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1) is not "
+            f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1/solar-open2) is not "
             f"stacked for pipeline stages — configure this preset with pp=1 "
             f"(use tp/sp/dp instead)"
         )
@@ -61,9 +66,16 @@ def check_pp_supported(cfg) -> None:
 
 def counts_routing(cfg) -> bool:
     """Whether the family's one-chip forward takes a ``stats`` collector
-    (moe.RoutingStats): the grouped expert path of MoeConfig, and of an
-    MlaConfig with experts."""
-    return is_moe(cfg) or (is_mla(cfg) and cfg.num_experts > 0)
+    (moe.RoutingStats): the grouped expert path of MoeConfig, of an
+    MlaConfig with experts, and of SolarOpen2Config (every layer routes)."""
+    return is_moe(cfg) or (is_mla(cfg) and cfg.num_experts > 0) or is_solar_open2(cfg)
+
+
+def expert_stack_leaves(cfg) -> tuple:
+    """The names of a layer's leaves that are stacked over the experts the
+    chip holds (the family's ``EXPERT_STACKS``; none for a dense family): a
+    step reads its top-k of such a leaf, not all of it."""
+    return getattr(family(cfg), "EXPERT_STACKS", ())
 
 
 def place_latent(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
@@ -102,10 +114,11 @@ def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
     refused here, at engine construction, each with its reason. A latent
     without an indexer that states no layout never comes here as rows:
     ``place_latent`` leaves it one head wherever a refusal would hit."""
-    if not is_mla(cfg) or not (cfg.latent_rows or cfg.experts_held):
+    rows = is_mla(cfg) and cfg.latent_rows
+    if not (rows or getattr(cfg, "experts_held", None)):
         return
     what = ("a latent held as rows of 128 lanes (learned sparse attention, "
-            "or none) / a held share of the experts (MlaConfig)")
+            f"or none) / a held share of the experts ({type(cfg).__name__})")
     refusals = [
         (tp > 1, "tp > 1: the latent's rows are one head's and cannot shard "
                  "on heads (the cache would be cut between its lanes), and a "
@@ -116,7 +129,7 @@ def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
                            "layer to layer"),
         (spec, "a speculative draft: verify rows have no latent question in "
                "the attention seam yet"),
-        (lora, "LoRA: the MLA family has no adapter path"),
+        (lora, "LoRA: the family has no adapter path"),
         (kv_quantized, "kv_dtype=int8: the latent kernels read bf16 rows; an "
                        "8-bit latent needs its scales a token"),
         (vision, "vision: multimodal serving covers the dense family only"),
@@ -129,10 +142,66 @@ def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
 def state_spec(cfg) -> tuple:
     """Per-layer arrays ONE SLOT holds beside the paged keys, as (name,
     shape, dtype): a state-space mixer's recurrent state and its
-    convolution's tail (``falcon_h1.state_spec``); () for every family whose
-    only state is pages. engine/state_cache.py builds the store from it, and
-    the step programs take and return it only where it is not empty."""
-    return falcon_h1.state_spec(cfg) if is_falcon_h1(cfg) else ()
+    convolution's tail (``falcon_h1.state_spec``), a linear-attention
+    layer's matrix state and its tail (``solar_open2.state_spec``); () for
+    every family whose only state is pages. engine/state_cache.py builds the
+    store from it, one array a layer of ``state_layers``, and the step
+    programs take and return it only where it is not empty. The family's
+    module answers (its ``state_spec``), as it answers the three questions
+    below where it has something to say."""
+    own = getattr(family(cfg), "state_spec", None)
+    return own(cfg) if own else ()
+
+
+def page_layers(cfg) -> tuple:
+    """The model layers that keep PAGES of keys and values, in order: every
+    layer, except where a family's layers are of kinds that keep different
+    state (``solar_open2``: its softmax-attention layers only). The engine
+    allocates one pair of page arrays a layer named here and ``attend``
+    finds a layer's pair by its place in this tuple (``layer_index``). One
+    block table still serves every page layer."""
+    own = getattr(family(cfg), "page_layers", None)
+    return own(cfg) if own else tuple(range(cfg.num_layers))
+
+
+def state_layers(cfg) -> tuple:
+    """The model layers that keep SLOT STATE (``state_spec``), in order: none
+    for a family without it, every layer for Falcon-H1 (its mixer runs beside
+    attention in each), the linear-attention layers for ``solar_open2``."""
+    if not state_spec(cfg):
+        return ()
+    own = getattr(family(cfg), "state_layers", None)
+    return own(cfg) if own else tuple(range(cfg.num_layers))
+
+
+def layer_index(layers: tuple, num_layers: int):
+    """model layer -> its place among ``layers`` (what a seam indexes its
+    store by), or None where every layer is named: the seam then uses the
+    model's index as it always did, and nothing is wrapped."""
+    if layers == tuple(range(num_layers)):
+        return None
+    return {l: i for i, l in enumerate(layers)}
+
+
+def state_prefix(cfg) -> str:
+    """The prefix of the ``StepStats`` counters a family's recurrence is
+    counted under (``<prefix>_rows_updated``, ``_tokens_scanned``,
+    ``_decode_steps``): engine ``_count_state``. The family's
+    ``STATE_PREFIX``."""
+    return getattr(family(cfg), "STATE_PREFIX", "ssm")
+
+
+def mixers(cfg, use_pallas: bool = False, interpret: bool = False) -> tuple:
+    """The two mixing functions the engine's second seam is built from, for
+    a family with ``state_spec``: ``mix_chunk(p, cfg, *inputs, *state,
+    n_real) -> (y, *state')`` (a run of one request's tokens from its slot's
+    arrays, in ``state_spec``'s order) and ``mix_rows(p, cfg, *inputs,
+    *states, live) -> (y, *states')`` (one token a live row, its recurrence
+    the family's Pallas launch where ``use_pallas``)."""
+    fam = family(cfg)
+    return fam.mix_chunk, partial(
+        fam.mix_rows, update=fam.state_update(use_pallas, interpret)
+    )
 
 
 def prefix_reusable(cfg) -> bool:
@@ -152,10 +221,10 @@ def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
     for), each with its reason."""
     if not state_spec(cfg):
         return
-    what = f"a state-space mixer's slot state ({type(cfg).__name__})"
+    what = f"slot state ({type(cfg).__name__})"
     refusals = [
-        (tp > 1, "tp > 1: the mixer's heads and its groups are not sharded "
-                 "yet (param_specs, the state's sharding)"),
+        (tp > 1, "tp > 1: the recurrence's heads (and a mixer's groups) are "
+                 "not sharded yet (param_specs, the state's sharding)"),
         (pp > 1 or sp > 1, "pp / sp > 1: neither the wavefront nor the ring "
                            "carries the recurrent state from stage to stage "
                            "or shard to shard"),
@@ -177,6 +246,8 @@ def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
 
 
 def family(cfg):
+    if is_solar_open2(cfg):
+        return solar_open2
     if is_falcon_h1(cfg):
         return falcon_h1
     if is_mla(cfg):
@@ -225,6 +296,15 @@ def forward_fn(cfg, mesh=None, use_pallas: bool = False,
     """
     if is_falcon_h1(cfg):
         return falcon_h1.forward
+    if is_solar_open2(cfg):
+        # the held (or replicated) experts' grouped path, its multiplication
+        # as for MlaConfig below; tp > 1 is refused at construction
+        if use_pallas:
+            return partial(
+                solar_open2.forward,
+                matmul=partial(grouped_matmul, interpret=interpret),
+            )
+        return solar_open2.forward
     if is_gptoss(cfg):
         if mesh is None or mesh.shape.get(AXIS_TP, 1) == 1:
             return gptoss.forward
@@ -349,6 +429,12 @@ def param_specs(cfg) -> dict:
         "bk": P(AXIS_TP),
         "bv": P(AXIS_TP),
     }
+    if is_solar_open2(cfg):
+        # tp > 1 is refused at construction (check_state_supported): the
+        # attention layers' specs are the dense family's, their output gate
+        # follows the heads, everything else replicates
+        layer["w_gate"] = P(None, AXIS_TP)
+        return {"top": top, "layer": layer, "default": P()}
     if is_gptoss(cfg):
         layer.update({
             "bo": P(None),

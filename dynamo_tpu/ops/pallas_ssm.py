@@ -45,6 +45,29 @@ F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
 
 
+def live_row_table(live: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The scalar-prefetched compaction a slot-state kernel visits its rows
+    by (this module's and ops/pallas_kda.py's): (rows [R] int32, the live
+    rows first and in order, the rest repeating the last live row; the live
+    count, int32)."""
+    live = live.astype(bool)
+    R = live.shape[0]
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    rows = jnp.where(jnp.arange(R) < n_live, order, order[jnp.maximum(n_live - 1, 0)])
+    return rows, n_live
+
+
+def live_block_map(n_blocks: int):
+    """The index-map helper beside ``live_row_table``: visit ``i`` of head
+    block ``j`` reads row ``rows[i]``; a visit past the live count repeats
+    the last visit's block indices, so a dead row is neither read nor
+    written."""
+    def head_map(i, j, rows_ref, n_ref):
+        return rows_ref[i], jnp.where(i < n_ref[0], j, n_blocks - 1)
+    return head_map
+
+
 def _per_head(v: jax.Array, heads: int) -> jax.Array:
     """[..., G, N] -> [..., H, N]: head ``i`` reads group ``i // (H / G)``."""
     return jnp.repeat(v, heads // v.shape[-2], axis=-2)
@@ -124,17 +147,13 @@ def ssm_state_update(S, x, B, C, dt, A, D, live, *, interpret: bool = False,
         raise ValueError(f"{H} heads in {G} groups do not cut into blocks of {hb}")
     nj = H // hb
     live = live.astype(bool)
-    n_live = jnp.sum(live, dtype=jnp.int32)
-    # live rows first, in order; the rest repeat the last live row
-    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
-    rows = jnp.where(jnp.arange(R) < n_live, order, order[jnp.maximum(n_live - 1, 0)])
+    rows, n_live = live_row_table(live)
     xf = x.astype(F32)
     xdt = xf * dt[..., None]
     decay = jnp.broadcast_to(jnp.exp(dt * A)[..., None], (R, H, P))
     b3, c3 = B.reshape(R * G, 1, N), C.reshape(R * G, 1, N)
 
-    def head_map(i, j, rows_ref, n_ref):
-        return rows_ref[i], jnp.where(i < n_ref[0], j, nj - 1)
+    head_map = live_block_map(nj)
 
     def state_idx(i, j, rows_ref, n_ref):
         r, jj = head_map(i, j, rows_ref, n_ref)

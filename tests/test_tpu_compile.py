@@ -261,6 +261,18 @@ def _cases():
                     s((rows,), jnp.bool_))
         return pallas_ssm.ssm_state_update, build
 
+    def kda_update(rows):
+        # a KDA layer's decode recurrence at Solar-Open2-250B's widths: 64
+        # heads x [128 key channels, 128 value lanes] float32 a row
+        from dynamo_tpu.ops import pallas_kda
+
+        def build(sh):
+            s, *_ = _shapes(sh)
+            vec = s((rows, 64, 128), F32)
+            return (s((rows, 64, 128, 128), F32), vec, vec, s((rows, 64, 128), BF),
+                    vec, s((rows, 64), F32), s((rows,), jnp.bool_))
+        return pallas_kda.kda_state_update, build
+
     def moves(fn, n_ids, with_pages):
         def build(sh):
             s, cache, _, _, ids = _shapes(sh)
@@ -319,6 +331,13 @@ def _cases():
         "unified-5-heads-a-group-wide-cell": unified_cell(
             20, 4, 512 + 128, 129, 82, 8192),
         "ssm-state-update-rows128": ssm_update(128),
+        # the long-answer cell (PR 41): 64 q / 8 kv heads without positions,
+        # 128 rows over tables of 130 pages of a 12 288-page pool; a 512-token
+        # chunk beside them; and the delta rule's launch
+        "decode-bf16-64q-8kv-reason-cell": decode(8, 64, 128, 130, 12288),
+        "unified-64q-8kv-reason-cell": unified_cell(
+            64, 8, 512 + 128, 129, 130, 12288),
+        "kda-state-update-rows128": kda_update(128),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),
