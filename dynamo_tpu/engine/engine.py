@@ -355,12 +355,13 @@ class _Seq:
 
 @dataclasses.dataclass
 class _Chain:
-    """An in-flight multi-step decode dispatch: packed [2, N, B] results not
-    yet fetched, the device-side carry for dispatching the next horizon
+    """An in-flight decode dispatch, a link of the chain: results not yet
+    fetched (a horizon's packed [N, B, 2+2K]; a mixed step's token and
+    logprob arrays), the device-side carry for dispatching the next link
     without a host round-trip, and the per-slot sequence snapshot taken at
     dispatch time (results must never be applied to a sequence admitted into
     a recycled slot afterwards)."""
-    packed: jax.Array
+    packed: Any
     tokens: jax.Array
     seq_lens: jax.Array
     steps: jax.Array
@@ -376,6 +377,22 @@ class _Chain:
     # guided decoding: device-resident FSM states after this horizon (chained
     # dispatches carry it forward without a host round-trip)
     g_state: Optional[jax.Array] = None
+    # tokens a row advances before the host has read them: what a dispatch
+    # on top of this one books beyond its own (a horizon's decode_steps)
+    length: int = 0
+    # a mixed step (one token a decode row beside a prefill chunk) and what
+    # its StepStats needs once it is read: the chunk's tokens, whether the
+    # link before it was unread at its launch, when it was launched, whether
+    # its chunk came prebuilt, the placements its dispatch made; ``results``
+    # where it was read at once (the executor's ``sync``), else ``fetch``
+    # resolves to them
+    mixed: bool = False
+    chunk_tokens: int = 0
+    chained: bool = False
+    t_launch: float = 0.0
+    prep_hit: Optional[bool] = None
+    placed: int = 0
+    results: Any = None
 
 
 class TpuEngine:
@@ -794,10 +811,23 @@ class TpuEngine:
 
         self._waiting: List[_Seq] = []
         self._prefill_rr = 0  # round-robin cursor over prefilling sequences
-        # chained decode: FIFO of in-flight horizons (packed results + device
-        # carry); results are fetched decode_pipeline-1 horizons behind the
-        # dispatch front so readback RTT hides behind device compute
+        # chained decode: FIFO of in-flight links (results + device carry).
+        # Horizons' results are fetched decode_pipeline-1 horizons behind
+        # the dispatch front so readback RTT hides behind device compute; a
+        # mixed step is a link too, read behind the launch of the ONE
+        # program that follows it, whatever decode_pipeline says (_loop)
         self._chains: "deque[_Chain]" = deque()
+        # what a mixed step with no unread link before it takes for a carry:
+        # placed once, as a step program's results are (replicated over the
+        # mesh, committed), so that it and a link's tokens reach the same
+        # compiled program
+        self._no_carry = (
+            jax.device_put(
+                np.zeros(config.max_batch_size, np.int32),
+                NamedSharding(self.mesh, P()),
+            )
+            if self.mixed_enabled else None
+        )
         # device-resident copies of per-slot state, name -> (device array,
         # the host snapshot it was placed from), placed again only when the
         # host copy changes (_dev); the guided tables keep their versioned
@@ -1780,7 +1810,7 @@ class TpuEngine:
             return out + (g_out,) if g_active is not None else out
 
         def mixed_step(params, k_caches, v_caches, counts,
-                       c_tokens, c_positions, c_new_block_ids, step,
+                       c_tokens, c_positions, c_new_block_ids, step, carry,
                        block_tables, seeds, temps, top_ks, top_ps, min_ps,
                        pres, freqs, reps, prompt_masks, lora_tables,
                        lora_ids, proc_masks,
@@ -1795,15 +1825,22 @@ class TpuEngine:
             decode slots (query_len = 1, or 0 when inactive). Sampling
             epilogues are copied verbatim from prefill()/decode() so mixed
             steps are token-identical to the split dispatches. Both
-            halves' per-step values arrive in ``step`` (step_args.py)."""
+            halves' per-step values arrive in ``step`` (step_args.py).
+
+            A mixed step is a link of the decode chain: ``carry`` is the
+            sampled tokens of the mixed step launched before it, still on
+            the device, and the rows ``step`` marks ``carried`` are fed
+            from there (the loop launched this step before it read them);
+            every other row's token is the host's. Behind today's results
+            it returns its own carry as a horizon's (``toks`` itself,
+            ``seq_lens`` and ``steps`` advanced one token a live row)."""
             a = step_args.unpack(step, cfg.max_batch_size)
             c_block_table, c_total_len, c_chunk_start = (
                 a.table_row, a.total_len, a.chunk_start
             )
             c_slot, c_is_final, c_lp_need = a.slot, a.is_final, a.c_lp_need
-            d_tokens, d_positions, d_seq_lens = (
-                a.tokens, a.positions, a.seq_lens
-            )
+            d_tokens = jnp.where(a.carried != 0, carry, a.tokens)
+            d_positions, d_seq_lens = a.positions, a.seq_lens
             d_write_blocks, d_write_offsets = a.write_blocks, a.write_offsets
             steps, lp_need = a.steps, a.lp_need
             g_state, c_g_state = a.g_state, a.c_g_state
@@ -1962,8 +1999,10 @@ class TpuEngine:
                 (toks, lps, tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals,
                  c_tlp_ids),
             )
+            advanced = active.astype(jnp.int32)
             return (k_caches, v_caches, counts, toks, lps, tlp_vals, tlp_ids,
-                    c_tok, c_lp, c_tlp_vals, c_tlp_ids)
+                    c_tok, c_lp, c_tlp_vals, c_tlp_ids,
+                    d_seq_lens + advanced, steps + advanced)
 
         def reset_slot(prompt_masks, counts, slot, row):
             return prompt_masks.at[slot].set(row), counts.at[slot].set(0)
@@ -3243,6 +3282,15 @@ class TpuEngine:
                 did_mixed = False
                 mixed_blocked = False
                 pick = mixed_seqs = None
+                # a mixed step launched last tick and not read yet, alone in
+                # flight: THIS tick's program is launched on its carry
+                # before its results are read (below)
+                link = (
+                    self._chains[0]
+                    if len(self._chains) == 1 and self._chains[0].mixed
+                    else None
+                )
+                at_once = False
                 with loop_span(self, "book"):
                     prefilling = [
                         s for s in self._slots
@@ -3266,15 +3314,30 @@ class TpuEngine:
                             pick.t_prefill_start = now_ns()
                         chunk_from = pick.prefill_pos
                         # mixed continuous batching: when decode rows are
-                        # resident (and no horizon is in flight to carry
-                        # stale device state past the fused step), the chunk
-                        # rides along with ONE decode step in a single
-                        # program — decode never stalls behind the prefill
-                        if self.mixed_enabled and not self._chains:
-                            snap = self._decode_snapshot()
+                        # resident the chunk rides along with ONE decode
+                        # step in a single program — decode never stalls
+                        # behind the prefill. Horizons in flight drain
+                        # first (the host does not know a row's length
+                        # through a horizon it has not read); a mixed link
+                        # in flight does not: what it advanced is one token
+                        # a row, which stays on the device as this step's
+                        # input (_decode_dispatch_arrays)
+                        if self.mixed_enabled and (
+                            not self._chains or link is not None
+                        ):
+                            snap = self._decode_snapshot(link)
                             if any(s is not None for s in snap):
-                                if self._prepare_mixed(snap):
+                                at_once = self._reads_at_once(snap)
+                                if link is not None and at_once:
+                                    # the host's view of these rows has to
+                                    # be whole: the link is read first
+                                    pick = None
+                                elif self._prepare_mixed(snap, link):
                                     mixed_seqs = snap
+                                elif link is not None:
+                                    # no room past the token in flight:
+                                    # read the link, book from what it left
+                                    pick = None
                                 else:
                                     # booking failed (block pressure /
                                     # context headroom): this prefill runs
@@ -3282,24 +3345,26 @@ class TpuEngine:
                                     # pipelining rather than wait for a
                                     # fused step that cannot book
                                     mixed_blocked = True
+                                if pick is None:
+                                    self._prefill_rr -= 1  # its turn stays
                 if pick is not None:
                     t_step = time.perf_counter()
                     with loop_span(self, "step"):
                         if mixed_seqs is not None:
-                            results, res = await loop.run_in_executor(
+                            chain, res = await loop.run_in_executor(
                                 self._executor, self._run_mixed_step, pick,
-                                mixed_seqs,
+                                mixed_seqs, link, at_once,
                             )
+                            self._chains.append(chain)
                             did_mixed = True
                         else:
-                            results = []
                             res = await loop.run_in_executor(
                                 self._executor, self._run_prefill_chunk, pick
                             )
                     with loop_span(self, "emit"):
+                        # the chunk's step is launched: program order on the
+                        # device is what later readers of its pages depend on
                         self._commit_prefilled_blocks(pick)
-                        for rst, tok, lp, tids, tvals in results:
-                            self._accept_token(rst, tok, lp, tids, tvals)
                         if res is not None:
                             fut = self._fetch_executor.submit(
                                 self._fetch_prefill_result, *res
@@ -3309,22 +3374,24 @@ class TpuEngine:
                             )
                             self._prefill_tasks.add(task)
                             task.add_done_callback(self._prefill_tasks.discard)
-                        self._step_stats(
-                            "mixed" if mixed_seqs is not None else "prefill",
-                            time.perf_counter() - t_step,
-                            (pick.prefill_pos - chunk_from) + len(results),
-                        )
+                        if mixed_seqs is None:
+                            self._step_stats(
+                                "prefill", time.perf_counter() - t_step,
+                                pick.prefill_pos - chunk_from,
+                            )
                 # top up the horizon pipeline BEFORE fetching the oldest
-                # results: the readback overlaps the in-flight horizons'
-                # device compute. Dispatch runs on
-                # the executor: the first call jit-compiles (30-90s cold)
-                # and must not stall the event loop's lease heartbeats.
+                # results: the readback overlaps the in-flight links'
+                # device compute (at decode_pipeline 1 a horizon has no
+                # successor: it is launched and read in one tick). Dispatch
+                # runs on the executor: the first call jit-compiles (30-90s
+                # cold) and must not stall the event loop's lease heartbeats.
                 # while a mixed-eligible prefill is in progress, the pipeline
-                # is NOT topped up: in-flight chains drain (their carry
-                # predates the fused step's cache writes), and once empty
-                # every tick runs one fused chunk+decode step until the
-                # prefill completes — decode keeps advancing, prefill keeps
-                # chunking, nothing stalls
+                # is NOT topped up: in-flight horizons drain (the fused step
+                # takes no horizon's carry), and once they have, every tick
+                # launches one fused chunk+decode step on the carry of the
+                # one before and then reads that one, until the prefill
+                # completes — decode keeps advancing, prefill keeps
+                # chunking, and the device does not wait for the host's turn
                 with loop_span(self, "book"):
                     has_active = any(
                         s is not None and not s.done and s.prefilled
@@ -3341,15 +3408,17 @@ class TpuEngine:
                             and not self._waiting
                             and not did_mixed
                             and not mixed_wait
-                            and len(self._chains) < self.cfg.decode_pipeline
+                            and self._may_top_up()
                             and (not self._chains
                                  or self._can_chain(self._chains[-1]))
-                            and self._prepare_horizon(
-                                depth=len(self._chains) + 1)
+                            and self._prepare_horizon()
                         )
                         if top_up:
                             prev = self._chains[-1] if self._chains else None
-                            snapshot = self._decode_snapshot()
+                            snapshot = self._decode_snapshot(
+                                prev if prev is not None and prev.mixed
+                                else None
+                            )
                     if not top_up:
                         break
                     with loop_span(self, "step"):
@@ -3361,21 +3430,13 @@ class TpuEngine:
                             np.asarray, chain.packed
                         )
                         self._chains.append(chain)
-                if self._chains:
-                    chain = self._chains.popleft()
-                    t_step = time.perf_counter()
-                    with loop_span(self, "fetch"):
-                        packed = await asyncio.wrap_future(chain.fetch)
-                    with loop_span(self, "emit"):
-                        emitted_before = sum(
-                            s.produced for s in chain.seqs if s is not None
-                        )
-                        self._apply_packed(chain, packed)
-                        self._step_stats(
-                            "decode", time.perf_counter() - t_step,
-                            sum(s.produced for s in chain.seqs if s is not None)
-                            - emitted_before,
-                        )
+                if self._chains and not (
+                    did_mixed and len(self._chains) == 1 and not at_once
+                ):
+                    # the oldest link's results. A mixed step launched this
+                    # tick with none before it stays in flight: the next
+                    # tick launches on its carry first, and reads it then
+                    await self._read_chain(self._chains.popleft())
                 elif has_active and not did_mixed:
                     t_step = time.perf_counter()
                     with loop_span(self, "book"):
@@ -3437,6 +3498,74 @@ class TpuEngine:
             self._slots = [None] * self.cfg.max_batch_size
             self._seq_lens[:] = 0
             self._chains.clear()
+
+    async def _read_chain(self, chain: _Chain) -> None:
+        """Loop thread: await the oldest link's results (``fetch``) and hand
+        them to their requests (``emit``), then the link's StepStats."""
+        if chain.mixed:
+            if chain.results is None:
+                with loop_span(self, "fetch"):
+                    chain.results = await asyncio.wrap_future(chain.fetch)
+            with loop_span(self, "emit"):
+                results, self._moe_last = chain.results
+                kept = 0
+                for rst, tok, lp, tids, tvals in results:
+                    # ended while this link was in flight (a stop token, a
+                    # cancel: what the host could not foresee), perhaps
+                    # reaped: its token here is the discarded tail
+                    if rst.done or self._slots[rst.slot] is not rst:
+                        continue
+                    kept += 1
+                    self._accept_token(rst, tok, lp, tids, tvals)
+                self._count_state(kept, 0, 1)
+                self._step_stats(
+                    "mixed", time.perf_counter() - chain.t_launch,
+                    chain.chunk_tokens + kept, link=chain,
+                )
+            return
+        t_step = time.perf_counter()
+        with loop_span(self, "fetch"):
+            packed = await asyncio.wrap_future(chain.fetch)
+        with loop_span(self, "emit"):
+            emitted_before = sum(
+                s.produced for s in chain.seqs if s is not None
+            )
+            self._apply_packed(chain, packed)
+            self._step_stats(
+                "decode", time.perf_counter() - t_step,
+                sum(s.produced for s in chain.seqs if s is not None)
+                - emitted_before,
+            )
+
+    def _reads_at_once(self, seqs: List[Optional["_Seq"]]) -> bool:
+        """Whether a mixed step over the ``seqs`` snapshot has to be read
+        before the next step can be built, from what the loop can observe:
+        a guided row among its decode rows (the next dispatch resyncs the
+        host's FSM states, which are walked as tokens are accepted), or a
+        speculative draft (its rounds start from the host's tokens). Such a
+        step is read by the executor (``sync``), and nothing is launched on
+        top of one that is not."""
+        if self.cfg.spec_draft is not None:
+            return True
+        return self.guided_enabled and any(
+            st is not None and self._g_active[i] for i, st in enumerate(seqs)
+        )
+
+    def _may_top_up(self) -> bool:
+        """Whether one more horizon may be launched on the chain as it
+        stands: up to decode_pipeline horizons in flight, as ever. An
+        unread mixed link (always the oldest) allows exactly ONE program on
+        top of it, whatever decode_pipeline says, and none while the first
+        token of the chunk it carried is still on its way: that row joins
+        the batch from the host, so a horizon launched now would run all its
+        steps without it."""
+        if not self._chains:
+            return True
+        if self._chains[0].mixed:
+            return len(self._chains) == 1 and not any(
+                s is not None and s.prefill_inflight for s in self._slots
+            )
+        return len(self._chains) < self.cfg.decode_pipeline
 
     def _admit_cancelled(self) -> None:
         keep = []
@@ -3903,13 +4032,20 @@ class TpuEngine:
             )
         return np.asarray(vec)
 
-    def _run_mixed_step(self, st: _Seq, seqs: List[Optional["_Seq"]]):
+    def _run_mixed_step(self, st: _Seq, seqs: List[Optional["_Seq"]],
+                        prev: Optional[_Chain], at_once: bool):
         """Executor thread: ONE fused dispatch serving st's next prefill
         chunk AND a single decode step for the ``seqs`` snapshot (the mixed
-        continuous-batching step; engine _build_programs mixed_step).
-        Returns (decode acceptance tuples like _run_decode's, prefill
-        result tuple like _run_prefill_chunk's or None for intermediate
-        chunks)."""
+        continuous-batching step; engine _build_programs mixed_step), as a
+        link of the decode chain. With ``prev`` given (the mixed link
+        launched before this one, not read yet) the rows it advanced take
+        their token from its device carry. The results' readback starts at
+        once; they are read here, under ``sync``, only where the loop needs
+        them before it can build the next step (``at_once``), else by the
+        loop's ``fetch`` a tick later, behind the next launch.
+        Returns (the link, prefill result tuple like _run_prefill_chunk's
+        or None for intermediate chunks)."""
+        t_launch = time.perf_counter()
         with loop_span(self, "pack"):
             prompt = st.seq.tokens()
             start = st.prefill_pos
@@ -3920,16 +4056,17 @@ class TpuEngine:
             (tokens, positions, new_block_ids), dev = self._take_chunk_arrays(
                 st, prompt, start, chunk_len
             )
-            (d_positions, d_seq_lens, write_blocks, write_offsets, steps) = (
-                self._decode_dispatch_arrays(seqs)
-            )
+            prep_hit = self._prep.pop_last() if self._prep is not None else None
+            (d_positions, d_seq_lens, write_blocks, write_offsets, steps,
+             carried) = self._decode_dispatch_arrays(seqs, prev)
             lp_need = bool(np.any((self._lp_ns > 0) & (d_seq_lens > 0)))
             c_lp_need = self._lp_ns[st.slot] > 0
             g_dev, g_rows = (), {}
             if self.guided_enabled:
-                # decode rows resync the host FSM states (mixed steps are
-                # never chained); the chunk row's state travels by value
-                # like prefill
+                # decode rows resync the host FSM states (a mixed step
+                # with a guided decode row is read at once, so the next
+                # step finds them walked); the chunk row's state travels
+                # by value like prefill
                 g_dev = self._guided_dev()
                 g_rows = dict(g_state=self._g_state)
             d_tokens, d_pos_chunk, d_new_blocks = (
@@ -3944,12 +4081,17 @@ class TpuEngine:
                 c_g_state=st.guided_state,
                 tokens=self._tokens, positions=d_positions,
                 seq_lens=d_seq_lens, write_blocks=write_blocks,
-                write_offsets=write_offsets, steps=steps, **g_rows,
+                write_offsets=write_offsets, steps=steps, carried=carried,
+                **g_rows,
             )
         with loop_span(self, "upload"):
             args = self._upload((
                 self.params, self.k_caches, self.v_caches, self.output_counts,
                 d_tokens, d_pos_chunk, d_new_blocks, step,
+                # on the device either way, and no placement: the link's
+                # sampled tokens, or a constant placed once as a program's
+                # result is (so both come to the one compiled program)
+                prev.tokens if prev is not None else self._no_carry,
                 self._dev("tables", self._block_tables),
                 *self._slot_sampling_dev(),
                 self.prompt_masks, self._lora_tables(),
@@ -3959,19 +4101,35 @@ class TpuEngine:
             ))
         with loop_span(self, "launch"):
             (self.k_caches, self.v_caches, self.output_counts, toks, lps,
-             tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids) = (
-                self._mixed_fn(*args)
+             tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids,
+             seq_lens, next_steps) = self._mixed_fn(*args)
+            # the readback starts now: by the link's turn to be read the
+            # bytes are on the host (as a horizon's packed results)
+            results = (toks, lps) + ((tlp_ids, tlp_vals) if lp_need else ())
+            for x in results:
+                x.copy_to_host_async()
+            # the rows' share is counted when the link is read: what was kept
+            self._count_state(0, chunk_len, 0)
+            link = _Chain(
+                results, toks, seq_lens, next_steps, seqs, length=1,
+                mixed=True, chunk_tokens=chunk_len, chained=prev is not None,
+                t_launch=t_launch, prep_hit=prep_hit,
+                placed=self._h2d_placements,
             )
-            self._count_state(np.count_nonzero(d_seq_lens), chunk_len, 1)
+            self._h2d_placements = 0
         with loop_span(self, "pack"):
             # the next chunk's arrays, built under this step's compute
             del args  # donated caches: hold no stale handles
             st.prefill_pos = start + chunk_len
             self._schedule_next_chunk(st, prompt, is_final)
             self._advance_draft_prefill(st, prompt)
-        with loop_span(self, "sync"):
-            results = self._decode_results(seqs, toks, lps, tlp_ids,
-                                           tlp_vals, lp_need)
+        if at_once:
+            with loop_span(self, "sync"):
+                link.results = self._decode_results(seqs, *results)
+        else:
+            link.fetch = self._fetch_executor.submit(
+                self._decode_results, seqs, *results
+            )
         prefill_res = None
         if is_final:
             # same async-readback protocol as _run_prefill_chunk: the loop
@@ -3982,13 +4140,16 @@ class TpuEngine:
             prefill_res = (st, c_tok, c_lp,
                            c_tlp_ids if c_lp_need else None,
                            c_tlp_vals if c_lp_need else None)
-        return results, prefill_res
+        return link, prefill_res
 
     def _book_decode_blocks(
-        self, seqs: List[Optional["_Seq"]], extra_tokens: int
+        self, seqs: List[Optional["_Seq"]], extra_tokens: int,
+        prev: Optional[_Chain] = None,
     ) -> bool:
         """Pre-allocate pages so every active (prefilled, unfinished)
-        sequence in ``seqs`` can absorb ``extra_tokens`` more decode tokens.
+        sequence in ``seqs`` can absorb ``extra_tokens`` more decode tokens,
+        beyond the ``prev.length`` that a row of the unread link ``prev``
+        has in flight (the host's length does not hold them yet).
         All-or-nothing: on any failure (context headroom, block pressure)
         every block this call took is given back — otherwise the fallback
         path itself starves (the blocks would sit idle until finish). The
@@ -3998,10 +4159,10 @@ class TpuEngine:
         bs = self.cfg.block_size
         granted: List[Tuple[_Seq, int]] = []  # rollback on partial failure
         ok = True
-        for st in seqs:
+        for i, st in enumerate(seqs):
             if st is None or st.done or not st.prefilled:
                 continue
-            L = len(st.seq)
+            L = len(st.seq) + self._in_flight(prev, i, st)
             if L + extra_tokens >= self.cfg.max_context:
                 ok = False
                 break
@@ -4029,22 +4190,27 @@ class TpuEngine:
             return False
         return True
 
-    def _prepare_mixed(self, seqs: List[Optional["_Seq"]]) -> bool:
+    def _prepare_mixed(self, seqs: List[Optional["_Seq"]],
+                       prev: Optional[_Chain] = None) -> bool:
         """Book a mixed step: the chunk's pages were booked at admission
         (_try_admit allocates the whole prompt), so this books the DECODE
         half — every active row gets headroom for the one token the fused
-        step advances. False => fall back to the split prefill dispatch."""
-        return self._book_decode_blocks(seqs, 1)
+        step advances, past the one the unread link ``prev`` has in flight.
+        False => fall back to the split prefill dispatch (or, with
+        ``prev``, read it first)."""
+        return self._book_decode_blocks(seqs, 1, prev)
 
-    def _prepare_horizon(self, depth: int = 1) -> bool:
-        """Pre-allocate pages so every active sequence can absorb ``depth``
-        more decode horizons (depth=2 when dispatching on top of an in-flight
-        chain). False => fall back to the single-step program (block pressure
-        or a sequence within a horizon of max_context)."""
+    def _prepare_horizon(self) -> bool:
+        """Pre-allocate pages so every active sequence can absorb one more
+        decode horizon on top of what the in-flight chain advances (a
+        horizon its decode_steps, a mixed link one token). False => fall
+        back to the single-step program (block pressure or a sequence
+        within a horizon of max_context)."""
         n = self.cfg.decode_steps
         if n <= 1:
             return False
-        return self._book_decode_blocks(self._slots, depth * n)
+        ahead = sum(c.length for c in self._chains)
+        return self._book_decode_blocks(self._slots, ahead + n)
 
     def _lora_tables(self):
         return self.lora.tables() if self.lora is not None else {}
@@ -4258,15 +4424,42 @@ class TpuEngine:
             self._dev_cache["g/trans"],
         )
 
-    def _decode_snapshot(self) -> List[Optional["_Seq"]]:
+    def _decode_snapshot(
+        self, prev: Optional[_Chain] = None
+    ) -> List[Optional["_Seq"]]:
         """Loop-thread snapshot of decode-eligible slots. MUST be taken on
         the loop thread in the same tick as _can_chain/_prepare_horizon: an
         async prefill finishing mid-dispatch would otherwise widen the
-        active mask after those checks (stale carry token -> wrong KV)."""
-        return [
+        active mask after those checks (stale carry token -> wrong KV).
+        With ``prev``, an unread mixed link, a row of it that the host can
+        foresee ending on the token in flight (``max_tokens`` reached, the
+        caller gone) is left out: nothing is computed past such a finish.
+        One it cannot foresee (a stop token) costs one discarded token."""
+        snap = [
             st if (st is not None and not st.done and st.prefilled) else None
             for st in self._slots
         ]
+        if prev is not None:
+            for i, st in enumerate(snap):
+                ahead = self._in_flight(prev, i, st)
+                if not ahead:
+                    continue
+                limit = st.req.stop.max_tokens
+                if (
+                    (limit is not None and st.produced + ahead >= limit)
+                    or st.context.is_stopped()
+                ):
+                    snap[i] = None
+        return snap
+
+    @staticmethod
+    def _in_flight(prev: Optional[_Chain], i: int, st: Optional["_Seq"]) -> int:
+        """Tokens of the row ``st`` in slot ``i`` that the unread link
+        ``prev`` has sampled and the host has not read (0: not a row of
+        it): what the host's length, step count and booking lack."""
+        if prev is None or st is None or prev.seqs[i] is not st:
+            return 0
+        return prev.length
 
     def _dispatch_horizon(
         self, chain: Optional[_Chain], seqs: List[Optional["_Seq"]]
@@ -4355,7 +4548,7 @@ class TpuEngine:
                 packed.copy_to_host_async()
                 return _Chain(
                     packed, tokens, seq_lens, steps, seqs,
-                    spec_k=self.cfg.spec_k,
+                    spec_k=self.cfg.spec_k, length=self.cfg.decode_steps,
                 )
             res = self._decode_multi_fn(*args, **quota)
             del args  # donated caches: hold no stale handles
@@ -4371,7 +4564,8 @@ class TpuEngine:
             # bytes are already on host and np.asarray is a no-wait copy
             packed.copy_to_host_async()
             return _Chain(
-                packed, tokens, seq_lens, steps, seqs, g_state=g_state_out
+                packed, tokens, seq_lens, steps, seqs, g_state=g_state_out,
+                length=self.cfg.decode_steps,
             )
 
     def _spec_eligible(self, seqs: List[Optional["_Seq"]]) -> bool:
@@ -4475,13 +4669,17 @@ class TpuEngine:
             self.spec_stats["emitted"] += len(toks)
             self._accept_tokens(st, toks, lps, None, None)
 
-    def _decode_dispatch_arrays(self, seqs: List[Optional["_Seq"]]):
+    def _decode_dispatch_arrays(self, seqs: List[Optional["_Seq"]],
+                                prev: Optional[_Chain] = None):
         """Per-slot host arrays for ONE decode step over the ``seqs``
         snapshot — shared by _run_decode and _run_mixed_step so the
         write-block math and carry conventions can never drift between the
         split and fused paths. Also refreshes self._tokens with each row's
-        fed token. Returns (positions, seq_lens, write_blocks,
-        write_offsets, steps), all [B]."""
+        fed token. A row that the unread mixed link ``prev`` advanced is
+        ``carried``: its token is on the device, and everything else is the
+        host's length so far plus the one token in flight. Returns
+        (positions, seq_lens, write_blocks, write_offsets, steps, carried),
+        all [B]."""
         bs = self.cfg.block_size
         B = self.cfg.max_batch_size
         positions = np.zeros(B, np.int32)
@@ -4489,47 +4687,51 @@ class TpuEngine:
         write_blocks = np.zeros(B, np.int32)
         write_offsets = np.zeros(B, np.int32)
         steps = np.zeros(B, np.int32)
+        carried = np.zeros(B, np.int32)
         for i, st in enumerate(seqs):
             if st is None:
                 continue
-            L = len(st.seq)                    # includes the token being fed
+            ahead = self._in_flight(prev, i, st)
+            L = len(st.seq) + ahead            # includes the token being fed
             positions[i] = L - 1
             seq_lens[i] = L
-            self._tokens[i] = st.last_token
+            carried[i] = ahead
+            self._tokens[i] = 0 if ahead else st.last_token
             write_blocks[i] = st.block_ids[(L - 1) // bs]
             write_offsets[i] = (L - 1) % bs
-            steps[i] = st.produced
-        return positions, seq_lens, write_blocks, write_offsets, steps
+            steps[i] = st.produced + ahead
+        return positions, seq_lens, write_blocks, write_offsets, steps, carried
 
     def _decode_results(self, seqs: List[Optional["_Seq"]], toks, lps,
-                        tlp_ids, tlp_vals, lp_need: bool):
-        """Device outputs of one decode step -> per-sequence acceptance
-        tuples (shared by _run_decode and _run_mixed_step)."""
+                        tlp_ids=None, tlp_vals=None):
+        """Device outputs of one decode step -> (per-sequence acceptance
+        tuples, the step's routing counters or None); shared by _run_decode
+        and _run_mixed_step, the top-logprob rows only where a row asked.
+        Touches no engine state: a mixed link's runs on the fetch pool."""
         toks_np = np.asarray(toks)
         lps_np = np.asarray(lps)
+        moe = None
         if self._moe_counted:
             # [B + 3]: the step's routing counters behind the logprobs
-            self._moe_last = tuple(
-                int(x) for x in lps_np[self.cfg.max_batch_size:]
-            )
-        tlp_ids_np = np.asarray(tlp_ids) if lp_need else None
-        tlp_vals_np = np.asarray(tlp_vals) if lp_need else None
+            moe = tuple(int(x) for x in lps_np[self.cfg.max_batch_size:])
+        tlp_ids_np = np.asarray(tlp_ids) if tlp_ids is not None else None
+        tlp_vals_np = np.asarray(tlp_vals) if tlp_vals is not None else None
         results = []
         for i, st in enumerate(seqs):
             if st is None:
                 continue
-            if self._lp_ns[i] > 0 and tlp_ids_np is not None:
+            if st.req.sampling.logprobs > 0 and tlp_ids_np is not None:
                 results.append((st, int(toks_np[i]), float(lps_np[i]),
                                 tlp_ids_np[i], tlp_vals_np[i]))
             else:
                 results.append(
                     (st, int(toks_np[i]), float(lps_np[i]), None, None)
                 )
-        return results
+        return results, moe
 
     def _run_decode(self, seqs: List[Optional["_Seq"]]) -> List[Tuple[_Seq, int, float]]:
         with loop_span(self, "pack"):
-            (positions, seq_lens, write_blocks, write_offsets, steps) = (
+            (positions, seq_lens, write_blocks, write_offsets, steps, _) = (
                 self._decode_dispatch_arrays(seqs)
             )
             lp_need = bool(np.any((self._lp_ns > 0) & (seq_lens > 0)))
@@ -4561,8 +4763,10 @@ class TpuEngine:
             del args  # donated caches: hold no stale handles
             self._count_state(np.count_nonzero(seq_lens), 0, 1)
         with loop_span(self, "sync"):
-            return self._decode_results(seqs, toks, lps, tlp_ids, tlp_vals,
-                                        lp_need)
+            results, self._moe_last = self._decode_results(
+                seqs, toks, lps, *((tlp_ids, tlp_vals) if lp_need else ())
+            )
+            return results
 
     # -- host-side token bookkeeping -----------------------------------------
     def _accept_token(
@@ -4820,10 +5024,16 @@ class TpuEngine:
         c[1] += chunk_tokens * L
         c[2] += steps
 
-    def _step_stats(self, phase: str, duration_s: float, tokens: int) -> None:
+    def _step_stats(self, phase: str, duration_s: float, tokens: int,
+                    link: Optional[_Chain] = None) -> None:
         """Feed one StepStats to the hook — scalars the loop already holds;
-        never forces a device sync (engine/telemetry.py)."""
-        placed, self._h2d_placements = self._h2d_placements, 0
+        never forces a device sync (engine/telemetry.py). ``link``: the
+        mixed step this one is, read now and launched a tick ago."""
+        if link is not None:
+            # its own dispatch's: the next link's are already being counted
+            placed = link.placed
+        else:
+            placed, self._h2d_placements = self._h2d_placements, 0
         hook = self.stats_hook
         if hook is None:
             return
@@ -4833,12 +5043,14 @@ class TpuEngine:
                 self.spec_stats["rounds"] * self.spec_stats["k"]
             )
         # async step-prep accounting: only chunk-carrying phases consume a
-        # prebuild (engine/prep.py take())
-        prep = (
-            self._prep.pop_last()
-            if self._prep is not None and phase in ("prefill", "mixed")
-            else None
-        )
+        # prebuild (engine/prep.py take(); a mixed link popped its own at
+        # its launch, a tick before it is read)
+        if link is not None:
+            prep = link.prep_hit
+        elif self._prep is not None and phase == "prefill":
+            prep = self._prep.pop_last()
+        else:
+            prep = None
         # what the host did since the last StepStats (popleft, not a copy
         # and clear: the deques lose nothing to a concurrent append, and
         # the spans, three values each, stay whole)
@@ -4887,6 +5099,7 @@ class TpuEngine:
                 moe_held_experts_touched=touched if held else None,
                 **reads,
                 h2d_placements=placed,
+                mixed_chained=None if link is None else link.chained,
             ))
         except Exception:
             log.exception("stats hook failed")

@@ -24,10 +24,13 @@ from types import SimpleNamespace
 import numpy as np
 
 # [batch] int32 each: the decode half's per-slot values of ONE step
-# (engine _decode_dispatch_arrays) and the host's guided-decoding FSM states
+# (engine _decode_dispatch_arrays), the host's guided-decoding FSM states,
+# and which rows of a mixed step take their token from the device carry of
+# the mixed step launched before it (0 / 1; ``tokens`` is not read there:
+# the host has not seen that token yet)
 ROWS = (
     "tokens", "positions", "seq_lens", "write_blocks", "write_offsets",
-    "steps", "g_state",
+    "steps", "g_state", "carried",
 )
 # the chunk half's scalars (prefill's conventions) and the two top-logprob
 # switches; FLAGS are read back as booleans

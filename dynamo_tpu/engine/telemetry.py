@@ -4,7 +4,10 @@ The engine loop hands a ``StepStats`` to ``engine.stats_hook`` after every
 prefill chunk, every consumed decode horizon, and every fused ``mixed``
 continuous-batching step (one prefill chunk riding along with a decode step
 through the unified ragged kernel — its batch_occupancy shows how full the
-fused launch ran). The stats are host-side
+fused launch ran; handed over when the step's results are READ, a tick after
+its launch where the next mixed step was launched on its device carry first:
+``mixed_chained`` says so, the wait is then the loop's ``fetch``, and the
+executor's ``sync`` is left to the steps read at once). The stats are host-side
 scalars read off bookkeeping the loop already maintains — the hook NEVER
 touches jit-traced code or forces a device sync (durations are host wall
 time around executor calls; token counts come from ``_accept_tokens``'s own
@@ -311,6 +314,12 @@ class StepStats:
     # A steady synchronous step makes one (its packed buffer); the prep
     # thread's three a chunk, made under the previous step, are not counted
     h2d_placements: int = 0
+    # a ``mixed`` step only (None on ``prefill`` and ``decode``): whether it
+    # was launched while the mixed step before it had not been read, on that
+    # one's device carry (engine _loop: a mixed step is a link of the decode
+    # chain). False where the loop had to read first: the first mixed step
+    # after a horizon, a guided row, no room to book past the token in flight
+    mixed_chained: Optional[bool] = None
 
 
 def moe_load_imbalance(s: StepStats) -> Optional[float]:
@@ -434,6 +443,11 @@ class EngineTelemetry:
             out["h2d_placements"] = round(
                 sum(s.h2d_placements for s in recent) / len(recent), 3
             )
+            # of the window's mixed steps, the share launched before the
+            # one before them was read (absent where it held none)
+            mixed = [s.mixed_chained for s in recent if s.mixed_chained is not None]
+            if mixed:
+                out["mixed_chained"] = round(sum(mixed) / len(mixed), 3)
         last = self._last
         if last is not None:
             out["last"] = {

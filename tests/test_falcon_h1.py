@@ -418,7 +418,7 @@ def _program_leaves(engine):
         write_offsets=e._seq_lens, steps=e._seq_lens)
     mixed = head + state + (
         np.zeros(S, np.int32), np.zeros(S, np.int32), np.zeros(S // c.block_size, np.int32),
-        step, e._block_tables, *sampling, *tail)
+        step, np.zeros(B, np.int32), e._block_tables, *sampling, *tail)
     out = {}
     for name, args, kw in (("_decode_multi_fn", multi, quota), ("_mixed_fn", mixed, {})):
         fn = getattr(e, name)
@@ -445,7 +445,7 @@ def test_the_other_families_programs_take_and_return_what_they_did(family):
         pages = 2 * model.num_layers
         got = _program_leaves(engine)
         assert got["_decode_multi_fn"] == (n_params + pages + 1 + 17, pages + 1 + 4)
-        assert got["_mixed_fn"] == (n_params + pages + 1 + 16, pages + 1 + 8)
+        assert got["_mixed_fn"] == (n_params + pages + 1 + 17, pages + 1 + 10)
     finally:
         engine.stop()
 
@@ -456,7 +456,7 @@ def test_this_familys_programs_carry_the_state_behind_the_pages(served):
     pages = state = 2 * L
     got = _program_leaves(engine)
     assert got["_decode_multi_fn"] == (n_params + pages + 1 + state + 17 + 1, pages + 1 + state + 4)
-    assert got["_mixed_fn"] == (n_params + pages + 1 + state + 16, pages + 1 + state + 8)
+    assert got["_mixed_fn"] == (n_params + pages + 1 + state + 17, pages + 1 + state + 10)
 
 
 def test_the_registry_knows_the_family():

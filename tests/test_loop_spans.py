@@ -175,14 +175,16 @@ def test_executor_spans_lie_inside_a_step_span(served, name):
 
 
 def test_a_horizon_dispatch_does_not_wait(served):
-    """``sync`` is where an executor function waits for device results. A
-    mixed step holds one; a horizon's results are awaited by the loop, so
-    the ``step`` span of a dispatch (step, book, fetch on the loop thread)
-    holds none."""
+    """``sync`` is where an executor function waits for device results: a
+    lone decode step, and a mixed step that has to be read at once. A mixed
+    step is a link of the chain like a horizon (ISSUE 42): its results are
+    awaited by the loop (``fetch``), so the ``step`` span of either
+    dispatch holds no ``sync``."""
     horizons = 0
     for step in served["steps"]:
         if step.phase == "mixed":
-            assert "sync" in [n for n, _, _ in _spans(step)]
+            names = [n for n, _, _ in _spans(step)]
+            assert "fetch" in names and "sync" not in names
         loop = _loop_spans(step)
         syncs = [(t0, t1) for n, t0, t1 in _spans(step) if n == "sync"]
         for i in range(2, len(loop)):
@@ -191,6 +193,74 @@ def test_a_horizon_dispatch_does_not_wait(served):
                 _, a, b = loop[i - 2]
                 assert not any(a <= t0 and t1 <= b for t0, t1 in syncs)
     assert horizons > 0
+
+
+def test_a_chained_mixed_step_is_launched_before_the_one_before_it_is_read(served):
+    """Span order of the chain's mixed links (ISSUE 42): the StepStats of
+    mixed step N is made when N is read, so it carries step N + 1's
+    ``launch``; where N + 1 was launched on N's carry that ``launch`` ended
+    before N's ``fetch`` began, and the tick held no ``sync``."""
+    mixed = [s for s in served["steps"] if s.phase == "mixed"]
+    ahead = 0
+    for this, nxt in zip(mixed, mixed[1:]):
+        if not nxt.mixed_chained:
+            continue
+        spans = _spans(this)
+        launches = [t1 for n, _, t1 in spans if n == "launch"]
+        fetches = [t0 for n, t0, _ in spans if n == "fetch"]
+        assert launches and fetches, [n for n, _, _ in spans]
+        assert launches[-1] <= fetches[-1]
+        ahead += 1
+    assert ahead >= len(mixed) // 2 > 0
+
+
+@pytest.mark.parametrize("phase", ["prefill", "mixed", "decode"])
+def test_mixed_chained_is_a_mixed_steps_field(served, phase):
+    steps = [s for s in served["steps"] if s.phase == phase]
+    assert steps
+    if phase == "mixed":
+        assert all(isinstance(s.mixed_chained, bool) for s in steps)
+        assert any(s.mixed_chained for s in steps)
+    else:
+        assert all(s.mixed_chained is None for s in steps)
+    # /debug/worker: the share of the window's mixed steps, beside h2d_placements
+    tele = T.EngineTelemetry(M.MetricsScope())
+    for s in steps:
+        tele.on_step(s)
+    snap = tele.snapshot()
+    if phase == "mixed":
+        assert snap["mixed_chained"] == round(
+            sum(s.mixed_chained for s in steps[-128:]) / len(steps[-128:]), 3)
+    else:
+        assert "mixed_chained" not in snap and "h2d_placements" in snap
+
+
+@pytest.mark.parametrize("wait", ["sync", "fetch"])
+def test_the_slow_step_line_names_whichever_wait_it_was(wait):
+    """A mixed link's wait is the loop's ``fetch``; one read at once waits
+    under the executor's ``sync``. The worker's line says which."""
+    import logging
+
+    ms = 1_000_000
+    spans = ("yield", 0, 14 * ms, "pack", 15 * ms, 17 * ms, "launch", 17 * ms, 18 * ms)
+    spans += (
+        ("sync", 18 * ms, 1888 * ms, "step", 14 * ms, 1890 * ms) if wait == "sync"
+        else ("step", 14 * ms, 19 * ms, "fetch", 19 * ms, 1889 * ms)
+    )
+    step = T.StepStats(
+        phase="mixed", duration_s=1.9, batch_occupancy=8, batch_size=8,
+        tokens=520, queue_depth=0, kv_active_blocks=1, kv_free_blocks=1,
+        kv_total_blocks=2, host_spans=spans, mixed_chained=wait == "fetch",
+    )
+    seen = []
+    handler = logging.Handler()
+    handler.emit = lambda record: seen.append(record.getMessage())
+    T.log.addHandler(handler)
+    try:
+        T.EngineTelemetry(M.MetricsScope(), slow_step_s=1.0).on_step(step)
+    finally:
+        T.log.removeHandler(handler)
+    assert seen[0].startswith(f"slow mixed step: 1900 ms of which {wait} 1870 ms")
 
 
 # -- (c) one admission wait per admitted request -----------------------------

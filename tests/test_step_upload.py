@@ -135,6 +135,31 @@ async def test_a_steady_step_places_its_packed_buffer_and_nothing_else(runner):
     )
 
 
+async def test_a_chained_mixed_step_places_its_packed_buffer_and_no_carry():
+    """A mixed step launched on the carry of the one before it (ISSUE 42)
+    takes that carry as it is, on the device: no placement. Its StepStats
+    counts its own dispatch's placements, though it is made a tick later,
+    when the step is read and the next one's are already being counted."""
+    eng = make_engine(True)
+    stats = []
+    eng.stats_hook = stats.append
+    try:
+        await arrive_while_decoding(
+            eng, preq("r1", P_RESIDENT, 60), preq("r2", P_LONG, 2)
+        )
+    finally:
+        eng.stop()
+    chained = [s for s in steady(stats, "mixed") if s.mixed_chained]
+    assert len(chained) >= 3, [(s.phase, s.mixed_chained) for s in stats]
+    for s in chained:
+        assert s.h2d_placements == PACKED + (0 if s.prep_hit else CHUNK_ARRAYS), s
+    assert any(s.prep_hit for s in chained)
+    # and the mixed step that was NOT chained places no more for that
+    first = next(s for s in stats if s.phase == "mixed")
+    assert first.mixed_chained is False
+    assert all(s.mixed_chained is None for s in stats if s.phase != "mixed")
+
+
 async def _recycled_slot(eng, phases=None):
     """r1 stays resident; A (sampled) comes and goes; B, with another
     temperature, seed and top-k, is admitted into A's slot."""
