@@ -16,8 +16,16 @@ again, so there are at most ``tiles_m + E - 1`` visits; the tables that map
 a visit to its group and its row tile are scalar-prefetched, the BlockSpec
 index maps read them, and a visit stores only the rows its group owns
 (masked), so consecutive visits of one tile fill it between them. The grid
-is static at its largest; visits past the real count repeat the last one's
-block indices (no DMA) and do nothing.
+``(tiles_n, visits, tiles_k)`` is static at its largest, and a visit past
+the real count is DEAD: it computes nothing, and its index maps
+(``index_maps``) hold every block index of every operand at what the last
+real step had: the group, the row tile AND the k index, which the last real
+visit left at ``tiles_k - 1``. The pipeline copies a block only where its
+index changes, so a launch reads each touched expert's matrix once a row
+tile its rows straddle. Until PR 43 the k index walked on through the dead
+visits, and each of them read the last touched expert's whole matrix again
+wherever ``tiles_k > 1``. A launch with no real visit holds visit 0's
+indices and reads one block a pass over ``n``.
 
 With two right-hand sides the launch is the SwiGLU front half: both
 products accumulate side by side in float32 and the epilogue stores
@@ -39,7 +47,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 KERNEL_NAME = "moe_grouped_matmul"
 # rows per tile: one MXU pass deep; a decode batch of 16 rows x 8 experts is
-# exactly one tile, which every touched expert visits once
+# exactly one tile, which every touched expert visits once. The visits the
+# static grid has beyond those (tiles_m + E - 1 less the real count) are
+# dead: they hold the last real step's block indices, the k index among
+# them, so they cost a grid step each and no read
 ROW_TILE = 128
 # all right-hand-side tiles of one grid step together (each is double
 # buffered by the pipeline): 2 x 4 MiB + rows, output and accumulators stay
@@ -107,6 +118,29 @@ def visit_tables(group_sizes: jax.Array, m: int, tm: int):
     return offsets, group_of, tile_of, n_visits[None]
 
 
+def index_maps(tiles_k: int):
+    """The BlockSpec index maps ``(lhs, rhs, out)`` of the grid ``(n_i, v,
+    k_i)`` over the four scalar-prefetched tables. A dead visit (``v >=
+    n_visits``) returns what the step before it did: ``visit_tables`` holds
+    its group and row tile at the last real visit's, and its k index is held
+    here at ``tiles_k - 1``, where that visit ended; no index changes, so the
+    pipeline starts no copy."""
+
+    def held_k(v, k_i, n_visits):
+        return jnp.where(v < n_visits[0], k_i, tiles_k - 1)
+
+    def lhs_map(n_i, v, k_i, offsets, group_of, tile_of, n_visits):
+        return tile_of[v], held_k(v, k_i, n_visits)
+
+    def rhs_map(n_i, v, k_i, offsets, group_of, tile_of, n_visits):
+        return group_of[v], held_k(v, k_i, n_visits), n_i
+
+    def out_map(n_i, v, k_i, offsets, group_of, tile_of, n_visits):
+        return tile_of[v], n_i
+
+    return lhs_map, rhs_map, out_map
+
+
 def _kernel(offsets_ref, group_ref, tile_ref, n_ref, lhs_ref, *rest,
             n_rhs: int, tm: int, tiles_k: int):
     rhs_refs, out_ref, acc_refs = rest[:n_rhs], rest[n_rhs], rest[n_rhs + 1:]
@@ -161,15 +195,7 @@ def grouped_matmul(lhs: jax.Array, rhs: Sequence[jax.Array],
     tiles_k, tiles_n = k // tk, n // tn
     tables = visit_tables(group_sizes.astype(jnp.int32), m_pad, tm)
     visits = tables[1].shape[0]
-
-    def lhs_map(n_i, v, k_i, offsets, group_of, tile_of, n_visits):
-        return tile_of[v], k_i
-
-    def rhs_map(n_i, v, k_i, offsets, group_of, tile_of, n_visits):
-        return group_of[v], k_i, n_i
-
-    def out_map(n_i, v, k_i, offsets, group_of, tile_of, n_visits):
-        return tile_of[v], n_i
+    lhs_map, rhs_map, out_map = index_maps(tiles_k)
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_rhs=len(rhs), tm=tm, tiles_k=tiles_k),

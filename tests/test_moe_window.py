@@ -206,6 +206,90 @@ def test_grouped_matches_dense(T, matmul):
                                rtol=2e-5, atol=2e-5)
 
 
+def _sizes(E, at):
+    sizes = np.zeros(E, np.int32)
+    for e, n in at.items():
+        sizes[e] = n
+    return sizes
+
+
+# (E, rows, k, n, dtype, right-hand sides, group sizes): shapes at which
+# ``pick_tiles`` cuts k, so a visit is ``tiles_k`` grid steps
+LAUNCHES = {
+    # the long-document cell's decode step: 8 rows x 8 experts sorted, 2 of
+    # the 16 held experts get a row, 14 visits of 16 are dead
+    "decode-2-of-16": (16, 64, 3072, 512, jnp.bfloat16, 2, _sizes(16, {3: 5, 11: 2})),
+    # every row went to another chip's experts
+    "nobody": (16, 64, 3072, 512, jnp.bfloat16, 2, _sizes(16, {})),
+    # every group has rows and most start inside a row tile of 128
+    "all-straddle": (8, 512, 3072, 512, jnp.bfloat16, 2,
+                     np.array([100, 60, 130, 20, 70, 90, 30, 12], np.int32)),
+    # a chunk under a held share: 200 of 512 sorted rows are held experts',
+    # the last two row tiles are nobody's
+    "held-chunk-tail": (8, 512, 3072, 512, jnp.bfloat16, 2,
+                        _sizes(8, {1: 90, 2: 60, 6: 50})),
+    # the down projection's kind: one right-hand side, n cut as well
+    "tiles-n": (4, 64, 2048, 3072, jnp.float32, 1, _sizes(4, {0: 9, 2: 30})),
+}
+
+
+@pytest.mark.parametrize("case", LAUNCHES)
+def test_a_dead_visit_fetches_nothing(case):
+    """Walk the grid ``(tiles_n, visits, tiles_k)`` in the pipeline's order
+    through the kernel's own index maps and tables, and count a copy wherever
+    an operand's block index differs from the step before: each right-hand
+    side is read once a real visit a pass, and a dead step moves nothing."""
+    E, m, k, n, dtype, n_rhs, sizes = LAUNCHES[case]
+    tm = min(pallas_moe.ROW_TILE, m)  # as grouped_matmul cuts rows that need no padding
+    tk, tn = pallas_moe.pick_tiles(k, n, jnp.dtype(dtype).itemsize, n_rhs)
+    tiles_k, tiles_n = k // tk, n // tn
+    assert tiles_k > 1 and (case != "tiles-n" or tiles_n > 1)
+    tables = pallas_moe.visit_tables(jnp.asarray(sizes), m, tm)
+    visits, n_visits = tables[1].shape[0], int(tables[3][0])
+    assert visits == m // tm + E - 1
+    ends = np.cumsum(sizes)
+    assert n_visits == sum(-(-end // tm) - (end - size) // tm for end, size in zip(ends, sizes) if size)
+    n_i, v, k_i = (a.reshape(-1) for a in np.meshgrid(
+        np.arange(tiles_n), np.arange(visits), np.arange(tiles_k), indexing="ij"))
+    walked = [
+        np.stack(jax.vmap(lambda *step: index_map(*step, *tables))(n_i, v, k_i), axis=1)
+        for index_map in pallas_moe.index_maps(tiles_k)
+    ]
+    moved = [np.concatenate([[True], (w[1:] != w[:-1]).any(axis=1)]) for w in walked]
+    lhs_moved, rhs_moved, out_moved = moved
+    # a real visit walks k, so every one of its steps brings a block of the
+    # rows and of each right-hand side; a launch with no real visit holds
+    # visit 0's blocks and reads the one a pass over n that n_i moves
+    copies = tiles_n * n_visits * tiles_k
+    assert rhs_moved.sum() == max(copies, tiles_n)
+    assert lhs_moved.sum() == max(copies, 1)
+    # a dead step moves no operand (the output among them: no write-back),
+    # but where an empty launch starts its next pass
+    dead = (v >= n_visits) & ~((v == 0) & (k_i == 0))
+    for operand in moved:
+        assert not operand[dead].any()
+
+
+@pytest.mark.parametrize("case", LAUNCHES)
+def test_grouped_kernel_is_bitwise_the_ragged_dot(case):
+    """The interpreted kernel at the launches above against its twin, on the
+    rows a group owns (the others are unspecified). Small whole numbers over
+    a power of two: every partial sum is exact in float32, so the order in
+    which k is cut cannot show, and a wrong or a missing block does."""
+    E, m, k, n, dtype, n_rhs, sizes = LAUNCHES[case]
+    rng = np.random.default_rng(43)
+    lhs = jnp.asarray(rng.integers(-3, 4, (m, k), dtype=np.int8), dtype)
+    rhs = [jnp.asarray(rng.integers(-2, 3, (E, k, n), dtype=np.int8), dtype) / 64
+           for _ in range(n_rhs)]
+    got = pallas_moe.grouped_matmul(lhs, rhs, jnp.asarray(sizes), interpret=True)
+    want = pallas_moe.grouped_matmul_reference(lhs, rhs, jnp.asarray(sizes))
+    assert got.shape == want.shape == (m, n) and got.dtype == want.dtype
+    owned = int(sizes.sum())
+    got, want = (np.asarray(a[:owned].astype(jnp.float32)) for a in (got, want))
+    np.testing.assert_array_equal(got, want)
+    assert want.any() or not owned
+
+
 def test_grouped_takes_routing_from_the_caller():
     """``routed=`` as mla.py passes it: weights that do not sum to 1 and an
     expert table under the kernel's names."""
