@@ -307,8 +307,8 @@ def make_engine_config(args, mcfg, vcfg=None, logits_procs=(), spec_draft=None):
         vision=vcfg,
         spec_draft=spec_draft,
         spec_k=getattr(args, "spec_k", 4),
-        # the pp sampling epilogues don't carry the mask ops — force guided
-        # off rather than fail construction on default flags
+        # guidance is refused under pp (not tested there) — force it off
+        # rather than fail construction on default flags
         guided_max_states=(
             0 if getattr(args, "pp", 1) > 1
             else getattr(args, "guided_max_states", 0)

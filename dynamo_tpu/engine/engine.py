@@ -82,11 +82,10 @@ from .telemetry import (
 )
 from .sampling import (
     TOP_LOGPROBS_K,
-    apply_penalties,
-    logprobs_of,
-    sample_tokens,
-    top_logprobs,
-    update_counts,
+    SlotSampling,
+    counts_need,
+    first_token_epilogue,
+    rows_epilogue,
 )
 
 log = get_logger("engine")
@@ -515,8 +514,8 @@ class TpuEngine:
         if self.guided_enabled:
             if config.pp > 1:
                 raise ValueError(
-                    "guided decoding covers the non-pp engine (the pp "
-                    "sampling epilogues do not carry the mask ops)"
+                    "guided decoding covers the non-pp engine (not tested "
+                    "under pp)"
                 )
             if guided_vocab is None:
                 raise ValueError(
@@ -1146,226 +1145,44 @@ class TpuEngine:
             and not registry.is_gemma(mcfg)
         )
 
-    def _build_programs_pp(self) -> None:
-        """pp>1 programs: same signatures/state layout as _build_programs so
-        every call site (and the multihost replay table) is oblivious; the
-        forward is the shard_map wavefront from parallel/pp_serving.py.
-        LoRA/vision/logits-processor args are accepted and ignored (their
-        features are gated off at construction).
-
-        NOTE: the sampling/penalty/logprob epilogues deliberately mirror
-        _build_programs rather than sharing a parameterized builder — the
-        non-pp path is the measured-and-tuned TPU hot path and stays
-        refactor-free; test_pp_serving pins the two token-identical. A
-        sampling change must land in BOTH builders."""
-        cfg, mcfg = self.cfg, self.mcfg
+    def _pp_bodies(self):
+        """What differs under pp > 1: the BODIES of the step programs. Thin
+        adapters over the shard_map wavefronts of parallel/pp_serving.py, the
+        stacked caches the one element of ``k_caches`` / ``v_caches``. What pp
+        does not serve (LoRA, vision, slot state, routing counters) is
+        refused at construction, and those arguments are ignored here."""
         from ..parallel import pp_serving
 
-        logits_fn = self._lm_logits
-        pf_fwd = pp_serving.make_pp_prefill_forward(
-            self.mesh, mcfg, cfg.pp, cfg.tp
-        )
-        dc_fwd = pp_serving.make_pp_decode_forward(
-            self.mesh, mcfg, cfg.pp, cfg.tp
-        )
-        repl = NamedSharding(self.mesh, P())
+        ways = (self.mesh, self.mcfg, self.cfg.pp, self.cfg.tp)
 
-        def _fetchable(x):
-            return jax.lax.with_sharding_constraint(x, repl)
-
-        def pack_step(toks, lps, tlp_vals, tlp_ids):
-            return jnp.concatenate(
-                [
-                    toks.astype(jnp.float32)[:, None],
-                    lps[:, None],
-                    tlp_ids.astype(jnp.float32),
-                    tlp_vals,
-                ],
-                axis=-1,
-            )
-
-        def pen_need(pres, freqs, reps):
-            return jnp.any((pres != 0.0) | (freqs != 0.0) | (reps != 1.0))
-
-        def prefill(params, k_caches, v_caches, counts, tokens, positions,
-                    new_block_ids, step, seeds, temps, top_ks, top_ps, min_ps,
-                    pres, freqs, reps, prompt_masks, lora_tables, lora_ids,
-                    proc_masks, mm_embeds, mm_mask):
-            a = step_args.unpack(step, cfg.max_batch_size)
-            block_table, total_len = a.table_row, a.total_len
-            slot, is_final, lp_need = a.slot, a.is_final, a.c_lp_need
-            steps = jnp.zeros((1,), jnp.int32)
-            seeds, temp, top_k, top_p, min_p, pres, freq, rep = (
-                x[slot][None] for x in
-                (seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps)
-            )
-            hidden, k2, v2 = pf_fwd(
-                params, k_caches[0], v_caches[0], tokens, positions,
-                block_table, new_block_ids, total_len,
-            )
-
-            def sample_branch(counts):
-                last_idx = jnp.argmax(positions == total_len - 1)
-                logits = logits_fn(params, mcfg, hidden[last_idx][None])
-                pen = apply_penalties(
-                    logits, jnp.zeros_like(logits, jnp.int32),
-                    prompt_masks[slot][None], pres, freq, rep,
+        def body(forward):
+            # the bodies' own order of arguments is the forwards'
+            def run(params, k_caches, v_caches, *inputs, **_):
+                hidden, k_caches[0], v_caches[0] = forward(
+                    params, k_caches[0], v_caches[0], *inputs
                 )
-                tok = sample_tokens(pen, seeds, steps, temp, top_k, top_p, min_p)
-                counts = jax.lax.cond(
-                    pen_need(pres, freq, rep),
-                    lambda c: c.at[slot, tok[0]].add(1),
-                    lambda c: c,
-                    counts,
-                )
-                lp = logprobs_of(logits, tok)
-                tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
-                return counts, tok[0], lp[0], tlp_vals[0], tlp_ids[0]
+                return hidden
+            return run
 
-            def no_sample(counts):
-                K = TOP_LOGPROBS_K
-                return (
-                    counts, jnp.int32(0), jnp.float32(0.0),
-                    jnp.zeros((K,), jnp.float32), jnp.zeros((K,), jnp.int32),
-                )
-
-            counts, tok, lp, tlp_vals, tlp_ids = jax.lax.cond(
-                is_final, sample_branch, no_sample, counts
-            )
-            tok, lp, tlp_vals, tlp_ids = map(_fetchable, (tok, lp, tlp_vals, tlp_ids))
-            return [k2], [v2], counts, tok, lp, tlp_vals, tlp_ids
-
-        def decode(params, k_caches, v_caches, counts, step, block_tables,
-                   seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
-                   prompt_masks, lora_tables, lora_ids, proc_masks):
-            a = step_args.unpack(step, cfg.max_batch_size)
-            tokens, positions, seq_lens = a.tokens, a.positions, a.seq_lens
-            write_blocks, write_offsets = a.write_blocks, a.write_offsets
-            steps, lp_need = a.steps, a.lp_need
-            hidden, k2, v2 = dc_fwd(
-                params, k_caches[0], v_caches[0], tokens, positions,
-                block_tables, seq_lens, write_blocks, write_offsets,
-            )
-            logits = logits_fn(params, mcfg, hidden)
-            pen = apply_penalties(logits, counts, prompt_masks, pres, freqs, reps)
-            toks = sample_tokens(pen, seeds, steps, temps, top_ks, top_ps, min_ps)
-            counts = update_counts(
-                counts, toks, seq_lens > 0, pen_need(pres, freqs, reps)
-            )
-            lps = logprobs_of(logits, toks)
-            tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
-            toks, lps, tlp_vals, tlp_ids = map(
-                _fetchable, (toks, lps, tlp_vals, tlp_ids)
-            )
-            return [k2], [v2], counts, toks, lps, tlp_vals, tlp_ids
-
-        def decode_multi(params, k_caches, v_caches, counts, tokens, seq_lens,
-                         block_tables, active, seeds, steps0, temps, top_ks,
-                         top_ps, min_ps, pres, freqs, reps, prompt_masks,
-                         lp_need, lora_tables, lora_ids, proc_masks):
-            bs = cfg.block_size
-            need_pen = pen_need(pres, freqs, reps)
-
-            def one_step(carry, s):
-                k_caches, v_caches, counts, tokens, seq_lens = carry
-                positions = jnp.maximum(seq_lens - 1, 0)
-                write_blocks = jnp.where(
-                    active,
-                    jnp.take_along_axis(
-                        block_tables, (positions // bs)[:, None], axis=1
-                    )[:, 0],
-                    0,
-                )
-                write_offsets = jnp.where(active, positions % bs, 0)
-                hidden, k2, v2 = dc_fwd(
-                    params, k_caches[0], v_caches[0], tokens, positions,
-                    block_tables, seq_lens, write_blocks, write_offsets,
-                )
-                logits = logits_fn(params, mcfg, hidden)
-                pen = apply_penalties(
-                    logits, counts, prompt_masks, pres, freqs, reps
-                )
-                toks = sample_tokens(
-                    pen, seeds, steps0 + s, temps, top_ks, top_ps, min_ps
-                )
-                counts = update_counts(counts, toks, active, need_pen)
-                lps = logprobs_of(logits, toks)
-                tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
-                seq_lens = seq_lens + active.astype(jnp.int32)
-                return (
-                    ([k2], [v2], counts, toks, seq_lens),
-                    pack_step(toks, lps, tlp_vals, tlp_ids),
-                )
-
-            (k_caches, v_caches, counts, tokens, seq_lens), packed = (
-                jax.lax.scan(
-                    one_step,
-                    (k_caches, v_caches, counts, tokens, seq_lens),
-                    jnp.arange(cfg.decode_steps),
-                )
-            )
-            next_steps = steps0 + jnp.where(active, cfg.decode_steps, 0)
-            return (
-                k_caches, v_caches, counts, _fetchable(packed),
-                tokens, seq_lens, next_steps,
-            )
-
-        def reset_slot(prompt_masks, counts, slot, row):
-            return prompt_masks.at[slot].set(row), counts.at[slot].set(0)
-
-        em_fwd = pp_serving.make_pp_embed_forward(
-            self.mesh, mcfg, cfg.pp, cfg.tp
-        )
-
-        def embed(params, tokens, positions, last_idx):
-            """Pooled dense-causal forward through the pipeline: no KV pages
-            touched (embeddings never pollute the generation cache)."""
-            hidden = em_fwd(params, tokens, positions)
-            h = hidden[last_idx].astype(jnp.float32)
-            return _fetchable(h / jnp.maximum(jnp.linalg.norm(h), 1e-9))
-
-        def embed_chunk(params, k_caches, v_caches, tokens, positions,
-                        block_table, new_block_ids, total_len, last_idx,
-                        is_final):
-            """Chunked pooled forward through the pipeline: inputs past the
-            largest prefill bucket run like pp chunked prefill — each chunk
-            writes its KV into TEMPORARY pages via the wavefront prefill
-            forward (allocated by the caller, never committed, released
-            after) and attends over the gathered prefix; the final chunk
-            yields the normalized last-token hidden state. Same host-side
-            protocol as the non-pp embed_chunk, so _run_embed is oblivious."""
-            hidden, k2, v2 = pf_fwd(
-                params, k_caches[0], v_caches[0], tokens, positions,
-                block_table, new_block_ids, total_len,
-            )
-            vec = jax.lax.cond(
-                is_final,
-                lambda: (
-                    lambda h: h / jnp.maximum(jnp.linalg.norm(h), 1e-9)
-                )(hidden[last_idx].astype(jnp.float32)),
-                lambda: jnp.zeros((mcfg.hidden_size,), jnp.float32),
-            )
-            return [k2], [v2], _fetchable(vec)
-
-        self._prefill_fn = jax.jit(prefill, donate_argnums=(1, 2, 3))
-        self._decode_fn = jax.jit(decode, donate_argnums=(1, 2, 3))
-        self._decode_multi_fn = jax.jit(decode_multi, donate_argnums=(1, 2, 3))
-        self._reset_slot_fn = jax.jit(reset_slot, donate_argnums=(0, 1))
-        self._embed_fn = jax.jit(embed)
-        self._embed_chunk_fn = jax.jit(embed_chunk, donate_argnums=(1, 2))
-        if self._mh is not None:
-            self._wire_multihost()
+        chunk_body = body(pp_serving.make_pp_prefill_forward(*ways))
+        rows_body = body(pp_serving.make_pp_decode_forward(*ways))
+        embed_body = pp_serving.make_pp_embed_forward(*ways)
+        # a chunk of a long embedding input runs as a chunk of a prompt does
+        return chunk_body, rows_body, embed_body, chunk_body
 
     def _build_programs(self) -> None:
-        if self.cfg.pp > 1:
-            return self._build_programs_pp()
+        """Every step program is a BODY, which runs the layers and writes
+        the cache (``chunk_body``, ``rows_body``, a mixed step's own; under
+        pp ``_pp_bodies``), followed by an EPILOGUE that turns ``hidden``
+        into tokens (engine/sampling.py ``rows_epilogue``,
+        ``first_token_epilogue``). What a program appends to its readback
+        (the routing counters, ``pack_step``'s columns, ``_fetchable``) is
+        the step's and stays here."""
         cfg, mcfg = self.cfg, self.mcfg
         fwd, logits_fn = self._forward, self._lm_logits
         lora_enabled = self.lora is not None
         quantized = self.kv_quantized
-
         vision_enabled = cfg.vision is not None
-
         moe_counted = self._moe_counted
         # model layer -> its place among the layers that keep pages / slot
         # state (None: every layer does, the model's index is the place)
@@ -1403,8 +1220,6 @@ class TpuEngine:
                     mm_mask[..., None], mm_embeds.astype(base.dtype), base
                 )
                 return fwd(params, mcfg, safe, positions, attend, **kw)
-            if not kw:
-                return fwd(params, mcfg, tokens, positions, attend)
             return fwd(params, mcfg, tokens, positions, attend, **kw)
 
         # the one attention seam (ops/paged_attention.py): the programs below
@@ -1462,27 +1277,6 @@ class TpuEngine:
 
         procs = cfg.logits_processors
 
-        def pen_need(pres, freqs, reps):
-            return jnp.any((pres != 0.0) | (freqs != 0.0) | (reps != 1.0))
-
-        def counts_need(pres, freqs, reps, proc_masks):
-            """output_counts must be maintained for penalties AND for any
-            opted-in logits processor (processors read counts as documented
-            on-device state — logits_processing/)."""
-            need = pen_need(pres, freqs, reps)
-            if procs:
-                need = need | jnp.any(proc_masks)
-            return need
-
-        def run_procs(logits, masks, counts, steps, seq_lens):
-            if not procs:
-                return logits
-            from ..logits_processing import apply_processors
-
-            return apply_processors(procs, masks, logits, {
-                "output_counts": counts, "steps": steps, "seq_lens": seq_lens,
-            })
-
         # host-fetched outputs are pinned fully-replicated: on a single
         # process any addressable layout can be np.asarray'd, but the leader
         # of a multi-process mesh can only fetch data whose every shard is
@@ -1513,81 +1307,76 @@ class TpuEngine:
 
         def routing_stats(valid, decode_rows=None):
             """A collector for this forward's routing counts (one-chip MoE),
-            or None: a TRACE-time branch, other families' programs are
-            unchanged. ``decode_rows``: which of the rows are decode rows,
-            where not all are (a mixed step)."""
+            or None: a TRACE-time branch. ``decode_rows``: which of the rows
+            are decode rows, where not all are (a mixed step)."""
             if not moe_counted:
                 return None
             return moe_lib.RoutingStats(valid, decode_rows)
 
-        # guided decoding ops (cfg.guided_max_states > 0): one [B, C] row
-        # gather + one [B, V] class lookup per step. Callers pass g_* only
-        # when guidance is built in — `is None` is a TRACE-time branch, so
-        # the disabled engine's programs are bit-identical to before.
-        GNEG = jnp.float32(-1e30)
-
-        def gmask(logits, g_active, g_state, g_class, g_trans):
-            """Mask logits to the tokens legal from each row's FSM state."""
-            row = jnp.take_along_axis(
-                g_trans, g_state[:, None, None], axis=1
-            )[:, 0]                                             # [B, C]
-            ok = jnp.take_along_axis(row, g_class, axis=1) >= 0  # [B, V]
-            return jnp.where(g_active[:, None] & ~ok, GNEG, logits)
-
-        def gstep(g_state, toks, g_active, g_class, g_trans):
-            """Advance each row's FSM by its sampled token."""
-            cls = jnp.take_along_axis(g_class, toks[:, None], axis=1)[:, 0]
-            row = jnp.take_along_axis(
-                g_trans, g_state[:, None, None], axis=1
-            )[:, 0]
-            nxt = jnp.take_along_axis(row, cls[:, None], axis=1)[:, 0]
-            return jnp.where(g_active, jnp.maximum(nxt, 0), g_state)
-
         if cfg.sp > 1:
             from ..parallel import ring as ringlib
 
-        def prefill(params, k_caches, v_caches, counts, tokens, positions,
-                    new_block_ids, step, seeds, temps, top_ks, top_ps, min_ps,
-                    pres, freqs, reps, prompt_masks, lora_tables, lora_ids,
-                    proc_masks, mm_embeds, mm_mask,
-                    g_active=None, g_class=None, g_trans=None, state=None):
-            # tokens/positions: [S_pad] — ONE chunk of the prompt (the whole
-            # prompt when it fits a bucket); step: the chunk's per-step values
-            # (step_args.py: its block-table row [max_blocks_per_seq], span,
-            # slot and switches); the sampling arrays are the per-slot [B]
-            # ones every program takes, read at ``slot``
-            a = step_args.unpack(step, cfg.max_batch_size)
-            block_table, total_len, chunk_start = (
-                a.table_row, a.total_len, a.chunk_start
+        def real_rows(k_new, v_new, positions, total_len):
+            """A chunk's K/V as the pool takes them. Under an 8-bit pool the
+            bucket's PADDING rows are zeroed before quantize-on-write: they
+            share the last real block (token 0 at position max_context-1)
+            and would enter its amax and coarsen the real tokens. Never
+            attended (every mask keys off total_len), so zeros are safe."""
+            if not quantized:
+                return k_new, v_new
+            valid = (positions < total_len)[:, None, None]
+            return jnp.where(valid, k_new, 0.0), jnp.where(valid, v_new, 0.0)
+
+        def write_slots(block_tables, positions, active):
+            """(block, offset) where each live row's token at ``positions``
+            is written; scratch block 0 for the others."""
+            bs = cfg.block_size
+            blocks = jnp.where(
+                active,
+                jnp.take_along_axis(
+                    block_tables, (positions // bs)[:, None], axis=1
+                )[:, 0],
+                0,
             )
-            slot, is_final, lp_need, g_state = (
-                a.slot, a.is_final, a.c_lp_need, a.c_g_state
-            )
-            steps = jnp.zeros((1,), jnp.int32)
-            seeds, temp, top_k, top_p, min_p, pres, freq, rep = (
-                x[slot][None] for x in
-                (seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps)
-            )
-            lora_id = lora_ids[slot]
+            return blocks, jnp.where(active, positions % bs, 0)
+
+        def rows_attend(attn, k_caches, v_caches, block_tables, seq_lens,
+                        write_blocks, write_offsets):
+            """``attend`` of decode rows ([B, 1, ...] a layer): the fed
+            token's KV written, then attended at the end of its context."""
+            def attend(q, k_new, v_new, layer_idx, **extra):
+                kc, vc = att.write_decode_kv(
+                    k_caches[layer_idx], v_caches[layer_idx],
+                    k_new[:, 0], v_new[:, 0], write_blocks, write_offsets,
+                )
+                k_caches[layer_idx], v_caches[layer_idx] = kc, vc
+                out = attn.decode(
+                    q[:, 0], kc, vc, block_tables, seq_lens, **extra
+                )
+                return out[:, None]
+            return attend
+
+        def unit(h):
+            h = h.astype(jnp.float32)
+            return h / jnp.maximum(jnp.linalg.norm(h), 1e-9)
+
+        # ---- the bodies: (params, caches, inputs) -> hidden, the cache
+        # lists written through in place
+        def chunk_body(params, k_caches, v_caches, tokens, positions,
+                       block_table, new_block_ids, total_len, *, chunk_start,
+                       lora_tables, lora_id, mm_embeds, mm_mask, mix):
+            """ONE chunk of one prompt ([S_pad] tokens, the whole prompt when
+            it fits a bucket): its pages written, its rows attended over the
+            prefix and the chunk."""
 
             def attend(q, k_new, v_new, layer_idx, **extra):
                 # extra: per-layer attention variants the model opts into
                 # (sliding ``window``, per-head ``sinks`` — models/gptoss.py);
                 # plain families pass nothing and nothing changes
-                k_w, v_w = k_new, v_new
-                if quantized:
-                    # zero the chunk's PADDING rows before quantize-on-write:
-                    # a bucket-padded chunk shares its last real block with
-                    # pad rows (token 0 at position max_context-1) whose
-                    # activations would otherwise enter the per-block amax
-                    # and coarsen the real tokens' quantization. Pad rows
-                    # are never attended (every mask keys off total_len),
-                    # so zeros are safe — and exact for the amax.
-                    valid = (positions < total_len)[:, None, None]
-                    k_w = jnp.where(valid, k_new, 0.0)
-                    v_w = jnp.where(valid, v_new, 0.0)
                 kc, vc = attn.write_chunk(
-                    k_caches[layer_idx], v_caches[layer_idx], k_w, v_w, new_block_ids
+                    k_caches[layer_idx], v_caches[layer_idx],
+                    *real_rows(k_new, v_new, positions, total_len),
+                    new_block_ids,
                 )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 if cfg.sp > 1:
@@ -1606,115 +1395,125 @@ class TpuEngine:
                     positions, **extra
                 )
 
-            hidden = call_fwd(
+            return call_fwd(
                 params, tokens, positions, attend, lora_tables, lora_id,
+                mm_embeds=mm_embeds, mm_mask=mm_mask, mix=mix,
+            )
+
+        def rows_body(params, k_caches, v_caches, tokens, positions,
+                      block_tables, seq_lens, write_blocks, write_offsets, *,
+                      lora_tables, lora_ids, moe_stats, state, live=None):
+            """The decode rows: one fed token a slot ([B] each), written at
+            ``write_blocks`` / ``write_offsets`` (scratch block 0 for an empty
+            row) and attended at the end of its context. ``live``: the rows
+            whose slot ``state`` advances (None: every row with a context)."""
+            return call_fwd(
+                params, tokens[:, None], positions[:, None],
+                rows_attend(attn, k_caches, v_caches, block_tables, seq_lens,
+                            write_blocks, write_offsets),
+                lora_tables, lora_ids, moe_stats=moe_stats,
+                mix=rows_mix(
+                    params, state, seq_lens > 0 if live is None else live
+                ) if state else None,
+            )[:, 0]  # [B, H]
+
+        def embed_body(params, tokens, positions):
+            """Dense causal forward, no KV pages touched; padded tail
+            positions can't affect earlier queries (causal)."""
+
+            def attend(q, k_new, v_new, layer_idx, **extra):
+                return att.causal_attention(q, k_new, v_new, **extra)
+
+            return fwd(params, mcfg, tokens, positions, attend)  # [S, H]
+
+        def plain_chunk(forward, model, seam, params, k_caches, v_caches,
+                        tokens, positions, block_table, new_block_ids,
+                        total_len):
+            """A chunk through a model's bare forward (an embedding input
+            past the largest bucket, into TEMPORARY pages; the draft's share
+            of a prompt): its KV written, attended over the gathered prefix."""
+
+            def attend(q, k_new, v_new, layer_idx, **extra):
+                kc, vc = att.write_prefill_kv(
+                    k_caches[layer_idx], v_caches[layer_idx],
+                    *real_rows(k_new, v_new, positions, total_len),
+                    new_block_ids,
+                )
+                k_caches[layer_idx], v_caches[layer_idx] = kc, vc
+                # a chunk's first token is real: its position is the start
+                return seam.chunk(
+                    q, kc, vc, block_table, positions[0], total_len,
+                    positions, **extra
+                )
+
+            return forward(params, model, tokens, positions, attend)
+
+        embed_chunk_body = partial(plain_chunk, fwd, mcfg, attn)
+
+        if cfg.pp > 1:
+            # only the bodies differ: the programs below are pp's too
+            chunk_body, rows_body, embed_body, embed_chunk_body = (
+                self._pp_bodies()
+            )
+
+        # ---- the programs. _wire_multihost and tests/test_step_upload.py
+        # know their arguments and results by POSITION
+        def prefill(params, k_caches, v_caches, counts, tokens, positions,
+                    new_block_ids, step, seeds, temps, top_ks, top_ps, min_ps,
+                    pres, freqs, reps, prompt_masks, lora_tables, lora_ids,
+                    proc_masks, mm_embeds, mm_mask,
+                    g_active=None, g_class=None, g_trans=None, state=None):
+            # step: the chunk's per-step values (step_args.py: its block-table
+            # row, span, slot and switches); the sampling arrays are the
+            # per-slot [B] ones every program takes, read at ``slot``
+            a = step_args.unpack(step, cfg.max_batch_size)
+            smp = SlotSampling(
+                seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
+                prompt_masks, proc_masks, g_active, g_class, g_trans,
+            )
+            hidden = chunk_body(
+                params, k_caches, v_caches, tokens, positions, a.table_row,
+                new_block_ids, a.total_len, chunk_start=a.chunk_start,
+                lora_tables=lora_tables, lora_id=lora_ids[a.slot],
                 mm_embeds=mm_embeds, mm_mask=mm_mask,
                 mix=None if state is None else chunk_mix(
-                    params, state, slot, chunk_start, total_len - chunk_start
+                    params, state, a.slot, a.chunk_start,
+                    a.total_len - a.chunk_start,
                 ),
             )
-
-            def sample_branch(counts):
-                # logits at the last real token (positions are absolute; the
-                # last real new token sits where position == total_len - 1)
-                last_idx = jnp.argmax(positions == total_len - 1)
-                logits = logits_fn(params, mcfg, hidden[last_idx][None])  # [1, V]
-                pen = apply_penalties(
-                    logits, jnp.zeros_like(logits, jnp.int32),
-                    prompt_masks[slot][None], pres, freq, rep,
-                )
-                pen = run_procs(
-                    pen, proc_masks[slot][None],
-                    counts[slot][None], steps, total_len[None],
-                )
-                if g_active is not None:
-                    # first generated token: FSM at g_state (0, or past the
-                    # prior tokens on a disagg/migration resume). Full
-                    # [B, ...] tables indexed by slot (not pre-sliced rows):
-                    # the same device-resident unit the decode ops use, so
-                    # multihost replays it as shared state instead of
-                    # broadcasting megabyte rows per chunk.
-                    pen = gmask(
-                        pen, g_active[slot][None],
-                        jnp.full((1,), g_state, jnp.int32),
-                        g_class[slot][None], g_trans[slot][None],
-                    )
-                tok = sample_tokens(pen, seeds, steps, temp, top_k, top_p, min_p)
-                # the first generated token must enter the output counts, or
-                # the first decode step's penalties miss it
-                counts = jax.lax.cond(
-                    counts_need(pres, freq, rep, proc_masks[slot][None]),
-                    lambda c: c.at[slot, tok[0]].add(1),
-                    lambda c: c,
-                    counts,
-                )
-                lp = logprobs_of(logits, tok)
-                tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
-                return counts, tok[0], lp[0], tlp_vals[0], tlp_ids[0]
-
-            def no_sample(counts):
-                # intermediate chunk: KV written, no token sampled — skips
-                # the full-vocab lm_head matmul entirely
-                K = TOP_LOGPROBS_K
-                return (
-                    counts, jnp.int32(0), jnp.float32(0.0),
-                    jnp.zeros((K,), jnp.float32), jnp.zeros((K,), jnp.int32),
-                )
-
-            counts, tok, lp, tlp_vals, tlp_ids = jax.lax.cond(
-                is_final, sample_branch, no_sample, counts
+            counts, *first = first_token_epilogue(
+                partial(logits_fn, params, mcfg), hidden, positions,
+                a.total_len, a.slot, a.is_final, a.c_lp_need, smp, counts,
+                procs=procs, g_state=a.c_g_state,
             )
-            tok, lp, tlp_vals, tlp_ids = map(_fetchable, (tok, lp, tlp_vals, tlp_ids))
-            return k_caches, v_caches, counts, tok, lp, tlp_vals, tlp_ids
+            return (k_caches, v_caches, counts, *map(_fetchable, first))
 
         def decode(params, k_caches, v_caches, counts, step, block_tables,
                    seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
                    prompt_masks, lora_tables, lora_ids, proc_masks,
                    g_active=None, g_class=None, g_trans=None, state=None):
-            # step: the [B] per-step rows (step_args.py); block_tables:
-            # [B, max_blocks_per_seq]
+            # step: the [B] per-step rows (step_args.py)
             a = step_args.unpack(step, cfg.max_batch_size)
-            tokens, positions, seq_lens = a.tokens, a.positions, a.seq_lens
-            write_blocks, write_offsets = a.write_blocks, a.write_offsets
-            steps, lp_need, g_state = a.steps, a.lp_need, a.g_state
-
-            def attend(q, k_new, v_new, layer_idx, **extra):
-                kc, vc = att.write_decode_kv(
-                    k_caches[layer_idx], v_caches[layer_idx],
-                    k_new[:, 0], v_new[:, 0], write_blocks, write_offsets,
-                )
-                k_caches[layer_idx], v_caches[layer_idx] = kc, vc
-                out = attn.decode(
-                    q[:, 0], kc, vc, block_tables, seq_lens, **extra
-                )
-                return out[:, None]
-
-            moe_stats = routing_stats(seq_lens > 0)
-            hidden = call_fwd(
-                params, tokens[:, None], positions[:, None], attend,
-                lora_tables, lora_ids, moe_stats=moe_stats,
-                mix=None if state is None else rows_mix(
-                    params, state, seq_lens > 0
-                ),
-            )  # [B, 1, H]
-            logits = logits_fn(params, mcfg, hidden[:, 0])  # [B, V]
-            pen = apply_penalties(logits, counts, prompt_masks, pres, freqs, reps)
-            pen = run_procs(pen, proc_masks, counts, steps, seq_lens)
-            if g_active is not None:
-                pen = gmask(pen, g_active, g_state, g_class, g_trans)
-            toks = sample_tokens(pen, seeds, steps, temps, top_ks, top_ps, min_ps)
-            counts = update_counts(
-                counts, toks, seq_lens > 0, counts_need(pres, freqs, reps, proc_masks)
+            smp = SlotSampling(
+                seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
+                prompt_masks, proc_masks, g_active, g_class, g_trans,
             )
-            lps = logprobs_of(logits, toks)
+            moe_stats = routing_stats(a.seq_lens > 0)
+            hidden = rows_body(
+                params, k_caches, v_caches, a.tokens, a.positions,
+                block_tables, a.seq_lens, a.write_blocks, a.write_offsets,
+                lora_tables=lora_tables, lora_ids=lora_ids,
+                moe_stats=moe_stats, state=state,
+            )
+            toks, lps, tlp_vals, tlp_ids, counts, _ = rows_epilogue(
+                logits_fn(params, mcfg, hidden), smp, counts, a.steps,
+                a.seq_lens, a.lp_need, procs=procs, g_state=a.g_state,
+            )
             if moe_stats is not None:
                 # the counters ride the logprob readback: lps is [B + 3]
                 lps = jnp.concatenate([lps, moe_stats.reduce()])
-            tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
-            toks, lps, tlp_vals, tlp_ids = map(
-                _fetchable, (toks, lps, tlp_vals, tlp_ids)
-            )
-            return k_caches, v_caches, counts, toks, lps, tlp_vals, tlp_ids
+            return (k_caches, v_caches, counts,
+                    *map(_fetchable, (toks, lps, tlp_vals, tlp_ids)))
 
         def decode_multi(params, k_caches, v_caches, counts, tokens, seq_lens,
                          block_tables, active, seeds, steps0, temps, top_ks,
@@ -1731,57 +1530,36 @@ class TpuEngine:
             [N, B, 2+2K] (sampled token, its logprob, top-K logprob rows),
             plus the device-resident carry (tokens/seq_lens/steps) that lets
             the loop dispatch the next horizon without any host round-trip."""
-            bs = cfg.block_size
-            need_pen = counts_need(pres, freqs, reps, proc_masks)
+            smp = SlotSampling(
+                seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
+                prompt_masks, proc_masks, g_active, g_class, g_trans,
+            )
+            need_pen = counts_need(procs, pres, freqs, reps, proc_masks)
 
             def one_step(carry, s):
                 # slot state, where the family has it, rides the carry
                 # behind the pages: ``st`` is {} for every other family
                 k_caches, v_caches, counts, tokens, seq_lens, g_st, st = carry
                 positions = jnp.maximum(seq_lens - 1, 0)
-                write_blocks = jnp.where(
-                    active,
-                    jnp.take_along_axis(
-                        block_tables, (positions // bs)[:, None], axis=1
-                    )[:, 0],
-                    0,
+                write_blocks, write_offsets = write_slots(
+                    block_tables, positions, active
                 )
-                write_offsets = jnp.where(active, positions % bs, 0)
-
-                def attend(q, k_new, v_new, layer_idx, **extra):
-                    kc, vc = att.write_decode_kv(
-                        k_caches[layer_idx], v_caches[layer_idx],
-                        k_new[:, 0], v_new[:, 0], write_blocks, write_offsets,
-                    )
-                    k_caches[layer_idx], v_caches[layer_idx] = kc, vc
-                    out = attn.decode(
-                        q[:, 0], kc, vc, block_tables, seq_lens, **extra
-                    )
-                    return out[:, None]
-
                 moe_stats = routing_stats(active)
-                hidden = call_fwd(
-                    params, tokens[:, None], positions[:, None], attend,
-                    lora_tables, lora_ids, moe_stats=moe_stats,
+                hidden = rows_body(
+                    params, k_caches, v_caches, tokens, positions,
+                    block_tables, seq_lens, write_blocks, write_offsets,
+                    lora_tables=lora_tables, lora_ids=lora_ids,
+                    moe_stats=moe_stats, state=st,
                     # a row that has sampled what its request asked for (the
                     # host learns it a horizon late) leaves its slot alone:
                     # a finished slot holds the state after its last fed token
-                    mix=rows_mix(params, st, active & (steps0 + s < max_new))
-                    if st else None,
+                    live=active & (steps0 + s < max_new) if st else None,
                 )
-                logits = logits_fn(params, mcfg, hidden[:, 0])
-                pen = apply_penalties(logits, counts, prompt_masks, pres, freqs, reps)
-                pen = run_procs(pen, proc_masks, counts, steps0 + s, seq_lens)
-                if g_active is not None:
-                    pen = gmask(pen, g_active, g_st, g_class, g_trans)
-                toks = sample_tokens(
-                    pen, seeds, steps0 + s, temps, top_ks, top_ps, min_ps
+                toks, lps, tlp_vals, tlp_ids, counts, g_st = rows_epilogue(
+                    logits_fn(params, mcfg, hidden), smp, counts, steps0 + s,
+                    seq_lens, lp_need, active=active, procs=procs,
+                    g_state=g_st, advance_guided=True, need=need_pen,
                 )
-                if g_active is not None:
-                    g_st = gstep(g_st, toks, g_active, g_class, g_trans)
-                counts = update_counts(counts, toks, active, need_pen)
-                lps = logprobs_of(logits, toks)
-                tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
                 seq_lens = seq_lens + active.astype(jnp.int32)
                 return (
                     (k_caches, v_caches, counts, toks, seq_lens, g_st, st),
@@ -1822,8 +1600,8 @@ class TpuEngine:
             [S_pad + B]: the chunk's bucketed tokens first, then one decode
             token per slot; attention is ONE unified ragged launch where row
             0 is the chunk (query_len = chunk_len) and rows 1..B are the
-            decode slots (query_len = 1, or 0 when inactive). Sampling
-            epilogues are copied verbatim from prefill()/decode() so mixed
+            decode slots (query_len = 1, or 0 when inactive). Behind the
+            forward run the epilogues decode() and prefill() run, so mixed
             steps are token-identical to the split dispatches. Both
             halves' per-step values arrive in ``step`` (step_args.py).
 
@@ -1835,46 +1613,38 @@ class TpuEngine:
             it returns its own carry as a horizon's (``toks`` itself,
             ``seq_lens`` and ``steps`` advanced one token a live row)."""
             a = step_args.unpack(step, cfg.max_batch_size)
-            c_block_table, c_total_len, c_chunk_start = (
-                a.table_row, a.total_len, a.chunk_start
-            )
-            c_slot, c_is_final, c_lp_need = a.slot, a.is_final, a.c_lp_need
             d_tokens = jnp.where(a.carried != 0, carry, a.tokens)
-            d_positions, d_seq_lens = a.positions, a.seq_lens
-            d_write_blocks, d_write_offsets = a.write_blocks, a.write_offsets
-            steps, lp_need = a.steps, a.lp_need
-            g_state, c_g_state = a.g_state, a.c_g_state
+            smp = SlotSampling(
+                seeds, temps, top_ks, top_ps, min_ps, pres, freqs, reps,
+                prompt_masks, proc_masks, g_active, g_class, g_trans,
+            )
             S_pad = c_tokens.shape[0]
             B = d_tokens.shape[0]
-            chunk_len = c_total_len - c_chunk_start
+            chunk_len = a.total_len - a.chunk_start
             tokens = jnp.concatenate([c_tokens, d_tokens])
-            positions = jnp.concatenate([c_positions, d_positions])
-            active = d_seq_lens > 0
+            positions = jnp.concatenate([c_positions, a.positions])
+            active = a.seq_lens > 0
 
             def attend(q, k_new, v_new, layer_idx, **extra):
                 # extra: per-layer attention variants (sliding window,
                 # per-head sinks, softcap — gpt-oss/gemma) thread straight
-                # into the unified launch as per-row attributes
-                kc, vc = k_caches[layer_idx], v_caches[layer_idx]
-                k_c, v_c = k_new[:S_pad], v_new[:S_pad]
-                if quantized:
-                    # same pad-row zeroing as the prefill attend: bucket
-                    # padding must not enter the per-block quantize amax
-                    validc = (c_positions < c_total_len)[:, None, None]
-                    k_c = jnp.where(validc, k_c, 0.0)
-                    v_c = jnp.where(validc, v_c, 0.0)
-                # the chunk's whole pages through the seam: on the view the
-                # launch below reads, so nothing re-tiles the pool between
+                # into the unified launch as per-row attributes. The chunk's
+                # whole pages go through the seam: on the view the launch
+                # below reads, so nothing re-tiles the pool between
                 kc, vc = attn.write_chunk(
-                    kc, vc, k_c, v_c, c_new_block_ids
+                    k_caches[layer_idx], v_caches[layer_idx],
+                    *real_rows(
+                        k_new[:S_pad], v_new[:S_pad], c_positions, a.total_len
+                    ),
+                    c_new_block_ids,
                 )
                 kc, vc = att.write_decode_kv(
                     kc, vc, k_new[S_pad:], v_new[S_pad:],
-                    d_write_blocks, d_write_offsets,
+                    a.write_blocks, a.write_offsets,
                 )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 tables = jnp.concatenate(
-                    [c_block_table[None], block_tables], axis=0
+                    [a.table_row[None], block_tables], axis=0
                 )
                 q_starts = jnp.concatenate([
                     jnp.zeros((1,), jnp.int32),
@@ -1885,8 +1655,8 @@ class TpuEngine:
                     active.astype(jnp.int32),
                 ])
                 row_lens = jnp.concatenate([
-                    c_total_len[None].astype(jnp.int32),
-                    d_seq_lens.astype(jnp.int32),
+                    a.total_len[None].astype(jnp.int32),
+                    a.seq_lens.astype(jnp.int32),
                 ])
                 return attn.ragged(
                     q, kc, vc, tables, q_starts, q_lens, row_lens, **extra
@@ -1898,20 +1668,20 @@ class TpuEngine:
                 # decode token its own — batched LoRA rides the same launch
                 # (lora/adapters.make_lora_fn per-token branch)
                 packed_lora_ids = jnp.concatenate([
-                    jnp.full((S_pad,), lora_ids[c_slot], jnp.int32),
+                    jnp.full((S_pad,), lora_ids[a.slot], jnp.int32),
                     lora_ids.astype(jnp.int32),
                 ])
             else:
                 packed_lora_ids = lora_ids
             moe_stats = routing_stats(
-                jnp.concatenate([c_positions < c_total_len, active]),
+                jnp.concatenate([c_positions < a.total_len, active]),
                 jnp.concatenate([jnp.zeros((S_pad,), bool), active]),
             )
             mix = None
             if state is not None:
                 # the chunk's row scans, the decode rows advance one token:
                 # the chunk's slot is prefilling, so never a live decode row
-                c_mix = chunk_mix(params, state, c_slot, c_chunk_start, chunk_len)
+                c_mix = chunk_mix(params, state, a.slot, a.chunk_start, chunk_len)
                 d_mix = rows_mix(params, state, active)
 
                 def mix(*xs):
@@ -1926,134 +1696,49 @@ class TpuEngine:
                 packed_lora_ids, moe_stats=moe_stats, mix=mix,
             )  # [S_pad + B, H]
 
-            # -- decode epilogue: verbatim decode() ---------------------------
-            logits = logits_fn(params, mcfg, hidden[S_pad:])  # [B, V]
-            pen = apply_penalties(
-                logits, counts, prompt_masks, pres, freqs, reps
+            # the decode rows' tail, as decode()
+            toks, lps, tlp_vals, tlp_ids, counts, _ = rows_epilogue(
+                logits_fn(params, mcfg, hidden[S_pad:]), smp, counts, a.steps,
+                a.seq_lens, a.lp_need, active=active, procs=procs,
+                g_state=a.g_state,
             )
-            pen = run_procs(pen, proc_masks, counts, steps, d_seq_lens)
-            if g_active is not None:
-                pen = gmask(pen, g_active, g_state, g_class, g_trans)
-            toks = sample_tokens(
-                pen, seeds, steps, temps, top_ks, top_ps, min_ps
-            )
-            counts = update_counts(
-                counts, toks, active,
-                counts_need(pres, freqs, reps, proc_masks),
-            )
-            lps = logprobs_of(logits, toks)
             if moe_stats is not None:
                 # as in decode(): lps is [B + 3], chunk rows counted too
                 lps = jnp.concatenate([lps, moe_stats.reduce()])
-            tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
-
-            # -- chunk epilogue: verbatim prefill() (slot-sliced args) --------
-            def sample_branch(counts):
-                last_idx = jnp.argmax(c_positions == c_total_len - 1)
-                logits1 = logits_fn(params, mcfg, hidden[last_idx][None])
-                pen1 = apply_penalties(
-                    logits1, jnp.zeros_like(logits1, jnp.int32),
-                    prompt_masks[c_slot][None], pres[c_slot][None],
-                    freqs[c_slot][None], reps[c_slot][None],
-                )
-                pen1 = run_procs(
-                    pen1, proc_masks[c_slot][None], counts[c_slot][None],
-                    jnp.zeros((1,), jnp.int32), c_total_len[None],
-                )
-                if g_active is not None:
-                    pen1 = gmask(
-                        pen1, g_active[c_slot][None],
-                        jnp.full((1,), c_g_state, jnp.int32),
-                        g_class[c_slot][None], g_trans[c_slot][None],
-                    )
-                tok1 = sample_tokens(
-                    pen1, seeds[c_slot][None], jnp.zeros((1,), jnp.int32),
-                    temps[c_slot][None], top_ks[c_slot][None],
-                    top_ps[c_slot][None], min_ps[c_slot][None],
-                )
-                counts = jax.lax.cond(
-                    counts_need(
-                        pres[c_slot][None], freqs[c_slot][None],
-                        reps[c_slot][None], proc_masks[c_slot][None],
-                    ),
-                    lambda c: c.at[c_slot, tok1[0]].add(1),
-                    lambda c: c,
-                    counts,
-                )
-                lp1 = logprobs_of(logits1, tok1)
-                tlp_vals1, tlp_ids1 = top_logprobs(logits1, c_lp_need)
-                return counts, tok1[0], lp1[0], tlp_vals1[0], tlp_ids1[0]
-
-            def no_sample(counts):
-                K = TOP_LOGPROBS_K
-                return (
-                    counts, jnp.int32(0), jnp.float32(0.0),
-                    jnp.zeros((K,), jnp.float32), jnp.zeros((K,), jnp.int32),
-                )
-
-            counts, c_tok, c_lp, c_tlp_vals, c_tlp_ids = jax.lax.cond(
-                c_is_final, sample_branch, no_sample, counts
+            # the chunk's tail, as prefill()
+            counts, *first = first_token_epilogue(
+                partial(logits_fn, params, mcfg), hidden, c_positions,
+                a.total_len, a.slot, a.is_final, a.c_lp_need, smp, counts,
+                procs=procs, g_state=a.c_g_state,
             )
-            toks, lps, tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids = map(
-                _fetchable,
-                (toks, lps, tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals,
-                 c_tlp_ids),
-            )
+            read = tuple(map(_fetchable, (toks, lps, tlp_vals, tlp_ids, *first)))
             advanced = active.astype(jnp.int32)
-            return (k_caches, v_caches, counts, toks, lps, tlp_vals, tlp_ids,
-                    c_tok, c_lp, c_tlp_vals, c_tlp_ids,
-                    d_seq_lens + advanced, steps + advanced)
+            return (k_caches, v_caches, counts, *read,
+                    a.seq_lens + advanced, a.steps + advanced)
 
         def reset_slot(prompt_masks, counts, slot, row):
             return prompt_masks.at[slot].set(row), counts.at[slot].set(0)
 
         def embed(params, tokens, positions, last_idx):
             """Pooled forward for /v1/embeddings (reference: the Embedding
-            model type served by http/service/openai.rs:641): dense causal
-            attention (no KV pages touched — embeddings never pollute the
-            generation cache), last-token hidden state, L2-normalized.
-            Padded tail positions can't affect earlier queries (causal)."""
-
-            def attend(q, k_new, v_new, layer_idx, **extra):
-                return att.causal_attention(q, k_new, v_new, **extra)
-
-            hidden = fwd(params, mcfg, tokens, positions, attend)  # [S, H]
-            h = hidden[last_idx].astype(jnp.float32)
-            return _fetchable(h / jnp.maximum(jnp.linalg.norm(h), 1e-9))
+            model type served by http/service/openai.rs:641): last-token
+            hidden state, L2-normalized."""
+            hidden = embed_body(params, tokens, positions)
+            return _fetchable(unit(hidden[last_idx]))
 
         def embed_chunk(params, k_caches, v_caches, tokens, positions,
                         block_table, new_block_ids, total_len, last_idx,
                         is_final):
-            """Chunked pooled forward: inputs past the largest prefill
-            bucket run like chunked prefill — each chunk writes its KV into
-            TEMPORARY pages (allocated, never committed, released after) and
-            attends over the gathered prefix — but no token is sampled; the
-            final chunk returns the normalized last-token hidden state."""
-
-            def attend(q, k_new, v_new, layer_idx, **extra):
-                k_w, v_w = k_new, v_new
-                if quantized:
-                    # same pad-row zeroing as the prefill attend: keep
-                    # padding out of the per-block quantization amax
-                    valid = (positions < total_len)[:, None, None]
-                    k_w = jnp.where(valid, k_new, 0.0)
-                    v_w = jnp.where(valid, v_new, 0.0)
-                kc, vc = att.write_prefill_kv(
-                    k_caches[layer_idx], v_caches[layer_idx],
-                    k_w, v_w, new_block_ids,
-                )
-                k_caches[layer_idx], v_caches[layer_idx] = kc, vc
-                return attn.chunk(
-                    q, kc, vc, block_table, positions[0], total_len,
-                    positions, **extra
-                )
-
-            hidden = fwd(params, mcfg, tokens, positions, attend)
+            """Chunked pooled forward for inputs past the largest bucket:
+            each chunk's KV into TEMPORARY pages (the caller's, never
+            committed); the final chunk returns the pooled vector."""
+            hidden = embed_chunk_body(
+                params, k_caches, v_caches, tokens, positions, block_table,
+                new_block_ids, total_len,
+            )
             vec = jax.lax.cond(
                 is_final,
-                lambda: (
-                    lambda h: h / jnp.maximum(jnp.linalg.norm(h), 1e-9)
-                )(hidden[last_idx].astype(jnp.float32)),
+                lambda: unit(hidden[last_idx]),
                 lambda: jnp.zeros((mcfg.hidden_size,), jnp.float32),
             )
             return k_caches, v_caches, _fetchable(vec)
@@ -2089,20 +1774,10 @@ class TpuEngine:
                 """Write one bucketed chunk of the prompt's DRAFT KV (no
                 sampling): same chunk/padding conventions as the main
                 prefill so the host reuses _chunk_arrays verbatim."""
-
-                def attend(q, k_new, v_new, layer_idx, **extra):
-                    kc, vc = att.write_prefill_kv(
-                        dkc[layer_idx], dvc[layer_idx], k_new, v_new,
-                        new_block_ids,
-                    )
-                    dkc[layer_idx], dvc[layer_idx] = kc, vc
-                    # a chunk's first token is real: its position is the start
-                    return draft_attn.chunk(
-                        q, kc, vc, block_table, positions[0], total_len,
-                        positions, **extra
-                    )
-
-                draft_fwd(draft_params, dcfg, tokens, positions, attend)
+                plain_chunk(
+                    draft_fwd, dcfg, draft_attn, draft_params, dkc, dvc,
+                    tokens, positions, block_table, new_block_ids, total_len,
+                )
                 return dkc, dvc
 
             def spec_multi(params, draft_params, k_caches, v_caches, dkc, dvc,
@@ -2117,7 +1792,6 @@ class TpuEngine:
                 verified tokens, their logprobs. Carry (tokens/seq_lens/
                 steps) matches decode_multi's, so spec horizons chain with
                 normal ones."""
-                bs = cfg.block_size
 
                 def one_round(carry, _):
                     k_caches, v_caches, dkc, dvc, tokens, seq_lens = carry
@@ -2125,27 +1799,10 @@ class TpuEngine:
                     def draft_step(dc, j):
                         dkc, dvc, dt = dc
                         pos = jnp.maximum(seq_lens - 1, 0) + j
-                        wb = jnp.where(
-                            active,
-                            jnp.take_along_axis(
-                                block_tables, (pos // bs)[:, None], axis=1
-                            )[:, 0],
-                            0,
+                        attend = rows_attend(
+                            draft_attn, dkc, dvc, block_tables, seq_lens + j,
+                            *write_slots(block_tables, pos, active),
                         )
-                        wo = jnp.where(active, pos % bs, 0)
-
-                        def attend(q, k_new, v_new, layer_idx, **extra):
-                            kc2, vc2 = att.write_decode_kv(
-                                dkc[layer_idx], dvc[layer_idx],
-                                k_new[:, 0], v_new[:, 0], wb, wo,
-                            )
-                            dkc[layer_idx], dvc[layer_idx] = kc2, vc2
-                            out = draft_attn.decode(
-                                q[:, 0], kc2, vc2, block_tables,
-                                seq_lens + j, **extra
-                            )
-                            return out[:, None]
-
                         hidden = draft_fwd(
                             draft_params, dcfg, dt[:, None], pos[:, None],
                             attend,
@@ -2166,17 +1823,9 @@ class TpuEngine:
                     def attend(q, k_new, v_new, layer_idx, **extra):
                         kc2, vc2 = k_caches[layer_idx], v_caches[layer_idx]
                         for s in range(sk + 1):
-                            ps = start + s
-                            wb = jnp.where(
-                                active,
-                                jnp.take_along_axis(
-                                    block_tables, (ps // bs)[:, None], axis=1
-                                )[:, 0],
-                                0,
-                            )
-                            wo = jnp.where(active, ps % bs, 0)
                             kc2, vc2 = att.write_decode_kv(
-                                kc2, vc2, k_new[:, s], v_new[:, s], wb, wo
+                                kc2, vc2, k_new[:, s], v_new[:, s],
+                                *write_slots(block_tables, start + s, active),
                             )
                         k_caches[layer_idx], v_caches[layer_idx] = kc2, vc2
                         # verify rows: sk+1 candidate tokens at each
@@ -2273,7 +1922,8 @@ class TpuEngine:
             return call
 
         self._embed_chunk_fn = jax.jit(embed_chunk, donate_argnums=(1, 2))
-        self._mixed_fn = step_program(mixed_step)
+        if cfg.pp == 1:  # the fused step has a body of its own, and not pp's
+            self._mixed_fn = step_program(mixed_step)
         self._prefill_fn = step_program(prefill)
         self._decode_fn = step_program(decode)
         self._decode_multi_fn = step_program(decode_multi)
@@ -2295,71 +1945,26 @@ class TpuEngine:
         the leader wrapper also downgrades its own args to numpy).
         """
 
-        def _set_k(v):
-            self.k_caches = v
-
-        def _set_v(v):
-            self.v_caches = v
-
-        def _set_counts(v):
-            self.output_counts = v
-
-        def _set_pmasks(v):
-            self.prompt_masks = v
-
-        def _set_dk(v):
-            self.draft_k_caches = v
-
-        def _set_dv(v):
-            self.draft_v_caches = v
-
-        state_get = {
-            "params": lambda: self.params,
-            "k": lambda: self.k_caches,
-            "v": lambda: self.v_caches,
-            "counts": lambda: self.output_counts,
-            "pmasks": lambda: self.prompt_masks,
-            "lora": self._lora_tables,
-        }
-        state_set = {
-            "k": _set_k, "v": _set_v,
-            "counts": _set_counts, "pmasks": _set_pmasks,
+        # a replayed array's name -> the attribute that holds it
+        held = {
+            "k": "k_caches", "v": "v_caches",
+            "counts": "output_counts", "pmasks": "prompt_masks",
         }
         if self.cfg.spec_draft is not None:
-            state_get.update({
-                "draft_params": lambda: self.draft_params,
-                "dk": lambda: self.draft_k_caches,
-                "dv": lambda: self.draft_v_caches,
-            })
-            state_set.update({"dk": _set_dk, "dv": _set_dv})
-
-        def _set_g_active(v):
-            self._g_dev_active = v
-
-        def _set_g_class(v):
-            self._g_dev_class = v
-
-        def _set_g_trans(v):
-            self._g_dev_trans = v
-
-        if self._eplb_enabled:
-
-            def _set_params(v):
-                self.params = v
-
-            # EPLB rebalance swaps the whole params pytree (one replayed op)
-            state_set["params"] = _set_params
+            held.update(dk="draft_k_caches", dv="draft_v_caches")
         if self.guided_enabled:
-            state_get.update({
-                "g_active_dev": lambda: self._g_dev_active,
-                "g_class_dev": lambda: self._g_dev_class,
-                "g_trans_dev": lambda: self._g_dev_trans,
-            })
-            state_set.update({
-                "g_active_dev": _set_g_active,
-                "g_class_dev": _set_g_class,
-                "g_trans_dev": _set_g_trans,
-            })
+            held.update(
+                g_active_dev="_g_dev_active", g_class_dev="_g_dev_class",
+                g_trans_dev="_g_dev_trans",
+            )
+        state_get = {k: partial(getattr, self, a) for k, a in held.items()}
+        state_set = {k: partial(setattr, self, a) for k, a in held.items()}
+        state_get.update(params=lambda: self.params, lora=self._lora_tables)
+        if self.cfg.spec_draft is not None:
+            state_get["draft_params"] = lambda: self.draft_params
+        if self._eplb_enabled:
+            # EPLB rebalance swaps the whole params pytree (one replayed op)
+            state_set["params"] = partial(setattr, self, "params")
         ops = self._mh.router.table(
             ns=self._mh_ns, state_get=state_get, state_set=state_set,
         )

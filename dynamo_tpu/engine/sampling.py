@@ -14,11 +14,16 @@ Penalty semantics match vLLM/OpenAI:
 - repetition_penalty: tokens seen in prompt OR output; logit>0 ? l/r : l*r
 - frequency_penalty:  logits -= fp * count(token in output)
 - presence_penalty:   logits -= pp * (token in output)
+
+A step program (engine ``_build_programs``) is a body, which runs the layers,
+and an EPILOGUE, which turns ``hidden`` into tokens. Both epilogues are here,
+beside the primitives they compose: ``rows_epilogue`` (decode rows) and
+``first_token_epilogue`` (a prompt's final chunk). Nothing here knows the engine.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +35,34 @@ NEG_INF = -1e30
 # top_k matches — requests are never silently clamped below what the API
 # validated (lib/llm/src/protocols/openai/chat_completions/delta.rs analog).
 TOP_LOGPROBS_K = 20
+
+
+class SlotSampling(NamedTuple):
+    """The per-slot arrays every step program takes: ``[B]`` each,
+    ``prompt_masks`` ``[B, V]``, ``proc_masks`` ``[B, processors]``; and the
+    guided tables where guidance is built in (``g_active is None`` is a
+    TRACE-time branch: an engine without it has none of the mask ops)."""
+
+    seeds: jax.Array
+    temps: jax.Array
+    top_ks: jax.Array
+    top_ps: jax.Array
+    min_ps: jax.Array
+    pres: jax.Array
+    freqs: jax.Array
+    reps: jax.Array
+    prompt_masks: jax.Array
+    proc_masks: jax.Array
+    g_active: Optional[jax.Array] = None   # [B] bool
+    g_class: Optional[jax.Array] = None    # [B, V] token -> class
+    g_trans: Optional[jax.Array] = None    # [B, S, C] state, class -> state
+
+
+def pen_need(presence, frequency, repetition) -> jax.Array:
+    """Scalar bool: some row has a penalty on."""
+    return jnp.any(
+        (presence != 0.0) | (frequency != 0.0) | (repetition != 1.0)
+    )
 
 
 def apply_penalties(
@@ -53,9 +86,7 @@ def apply_penalties(
         l = l - presence[:, None] * out_seen.astype(jnp.float32)
         return l
 
-    need = jnp.any(
-        (presence != 0.0) | (frequency != 0.0) | (repetition != 1.0)
-    )
+    need = pen_need(presence, frequency, repetition)
     return jax.lax.cond(need, with_pen, lambda l: l, logits)
 
 
@@ -185,3 +216,162 @@ def update_counts(
         return c.at[rows, tokens].add(active.astype(jnp.int32))
 
     return jax.lax.cond(need, upd, lambda c: c, output_counts)
+
+
+def counts_need(procs, presence, frequency, repetition, proc_masks) -> jax.Array:
+    """output_counts must be maintained for penalties AND for any opted-in
+    logits processor (processors read counts as documented on-device state:
+    logits_processing/)."""
+    need = pen_need(presence, frequency, repetition)
+    if procs:
+        need = need | jnp.any(proc_masks)
+    return need
+
+
+def run_procs(procs, logits, masks, counts, steps, seq_lens) -> jax.Array:
+    """``procs`` (``TpuEngineConfig.logits_processors``) over the rows that
+    opted in (``masks``); no processors is a TRACE-time branch."""
+    if not procs:
+        return logits
+    from ..logits_processing import apply_processors
+
+    return apply_processors(procs, masks, logits, {
+        "output_counts": counts, "steps": steps, "seq_lens": seq_lens,
+    })
+
+
+# guided decoding: one [B, C] row gather + one [B, V] class lookup a step
+def gmask(logits, g_active, g_state, g_class, g_trans) -> jax.Array:
+    """Mask logits to the tokens legal from each row's FSM state."""
+    row = jnp.take_along_axis(
+        g_trans, g_state[:, None, None], axis=1
+    )[:, 0]                                             # [B, C]
+    ok = jnp.take_along_axis(row, g_class, axis=1) >= 0  # [B, V]
+    return jnp.where(g_active[:, None] & ~ok, NEG_INF, logits)
+
+
+def gstep(g_state, toks, g_active, g_class, g_trans) -> jax.Array:
+    """Advance each row's FSM by its sampled token."""
+    cls = jnp.take_along_axis(g_class, toks[:, None], axis=1)[:, 0]
+    row = jnp.take_along_axis(
+        g_trans, g_state[:, None, None], axis=1
+    )[:, 0]
+    nxt = jnp.take_along_axis(row, cls[:, None], axis=1)[:, 0]
+    return jnp.where(g_active, jnp.maximum(nxt, 0), g_state)
+
+
+def rows_epilogue(
+    logits: jax.Array,         # [B, V] float32
+    s: SlotSampling,
+    counts: jax.Array,         # [B, V] int32 output counts
+    steps: jax.Array,          # [B] int32 tokens sampled so far (the key)
+    seq_lens: jax.Array,       # [B] int32, 0 = an empty row
+    lp_need: jax.Array,        # scalar bool: some row wants top logprobs
+    *,
+    active: Optional[jax.Array] = None,   # [B] bool; None: seq_lens > 0
+    procs: Tuple[Tuple[str, Any], ...] = (),
+    g_state: Optional[jax.Array] = None,  # [B] int32 FSM states (guided)
+    advance_guided: bool = False,
+    need: Optional[jax.Array] = None,
+):
+    """The decode rows' tail: penalties, processors, guided mask, sample,
+    counts, logprob, top logprobs. Returns ``toks, lps, tlp_vals, tlp_ids,
+    counts, g_state``; ``g_state`` is advanced by the sampled tokens where
+    ``advance_guided`` (a horizon: the host walks it a horizon late), else
+    as it was given. ``need``: ``counts_need`` of these rows, for a caller
+    that computes it once outside its scan. An inactive row samples (its
+    token is discarded by the host) and leaves ``counts`` alone."""
+    pen = apply_penalties(
+        logits, counts, s.prompt_masks, s.pres, s.freqs, s.reps
+    )
+    pen = run_procs(procs, pen, s.proc_masks, counts, steps, seq_lens)
+    if s.g_active is not None:
+        pen = gmask(pen, s.g_active, g_state, s.g_class, s.g_trans)
+    toks = sample_tokens(
+        pen, s.seeds, steps, s.temps, s.top_ks, s.top_ps, s.min_ps
+    )
+    if advance_guided and s.g_active is not None:
+        g_state = gstep(g_state, toks, s.g_active, s.g_class, s.g_trans)
+    counts = update_counts(
+        counts, toks,
+        seq_lens > 0 if active is None else active,
+        counts_need(procs, s.pres, s.freqs, s.reps, s.proc_masks)
+        if need is None else need,
+    )
+    lps = logprobs_of(logits, toks)
+    tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
+    return toks, lps, tlp_vals, tlp_ids, counts, g_state
+
+
+def first_token_epilogue(
+    logits_fn: Callable[[jax.Array], jax.Array],  # [n, H] -> [n, V]
+    hidden: jax.Array,         # [S, H]: the chunk's rows first
+    positions: jax.Array,      # [S_pad] absolute positions of the chunk
+    total_len: jax.Array,      # scalar: the context once the chunk is in
+    slot: jax.Array,           # scalar: the request's row of ``s``
+    is_final: jax.Array,       # scalar bool: the prompt ends in this chunk
+    lp_need: jax.Array,        # scalar bool: the request wants top logprobs
+    s: SlotSampling,
+    counts: jax.Array,         # [B, V] int32, ``slot``'s row reset to zero
+    *,
+    procs: Tuple[Tuple[str, Any], ...] = (),
+    g_state: Optional[jax.Array] = None,  # scalar int32: the FSM state
+):
+    """A chunk's tail: on a prompt's final chunk the first generated token,
+    sampled as a decode row is from the one-row batch read at ``slot`` (no
+    output counts yet, step 0). Returns ``counts, tok, lp, tlp_vals,
+    tlp_ids`` (scalars and ``[K]`` rows). An intermediate chunk samples
+    nothing: zeros, the ``counts`` it was given, and no vocabulary product
+    (why this takes ``logits_fn`` and not logits)."""
+
+    def row(x):
+        return x[slot][None]
+
+    def sample_branch(counts):
+        # logits at the last real token (positions are absolute; the last
+        # real new token sits where position == total_len - 1)
+        last_idx = jnp.argmax(positions == total_len - 1)
+        logits = logits_fn(hidden[last_idx][None])  # [1, V]
+        pen = apply_penalties(
+            logits, jnp.zeros_like(logits, jnp.int32), row(s.prompt_masks),
+            row(s.pres), row(s.freqs), row(s.reps),
+        )
+        pen = run_procs(
+            procs, pen, row(s.proc_masks), row(counts),
+            jnp.zeros((1,), jnp.int32), total_len[None],
+        )
+        if s.g_active is not None:
+            # FSM at g_state (0, or past a resume's prior tokens). The full
+            # [B, ...] tables read at slot: the device-resident unit the
+            # decode rows use, which multihost replays as shared state
+            pen = gmask(
+                pen, row(s.g_active), jnp.full((1,), g_state, jnp.int32),
+                row(s.g_class), row(s.g_trans),
+            )
+        tok = sample_tokens(
+            pen, row(s.seeds), jnp.zeros((1,), jnp.int32), row(s.temps),
+            row(s.top_ks), row(s.top_ps), row(s.min_ps),
+        )
+        # the first generated token must enter the output counts, or the
+        # first decode step's penalties miss it
+        counts = jax.lax.cond(
+            counts_need(
+                procs, row(s.pres), row(s.freqs), row(s.reps),
+                row(s.proc_masks),
+            ),
+            lambda c: c.at[slot, tok[0]].add(1),
+            lambda c: c,
+            counts,
+        )
+        lp = logprobs_of(logits, tok)
+        tlp_vals, tlp_ids = top_logprobs(logits, lp_need)
+        return counts, tok[0], lp[0], tlp_vals[0], tlp_ids[0]
+
+    def no_sample(counts):
+        K = TOP_LOGPROBS_K
+        return (
+            counts, jnp.int32(0), jnp.float32(0.0),
+            jnp.zeros((K,), jnp.float32), jnp.zeros((K,), jnp.int32),
+        )
+
+    return jax.lax.cond(is_final, sample_branch, no_sample, counts)

@@ -8,7 +8,9 @@ state in float32 and the convolution's last inputs); this module holds one
 array ``[slots, *shape]`` a layer a name, on the engine's one device.
 
 The arrays travel through every step program like the pages: taken,
-returned, donated (``TpuEngine._build_programs``). Nothing here zeroes a
+returned, donated (``TpuEngine._build_programs``; a program's body writes
+them through its ``mix`` as it writes the pages through ``attend``, its
+epilogue never sees them). Nothing here zeroes a
 slot between requests: a prompt's first chunk (``chunk_start == 0``) starts
 from zeros INSIDE the prefill program (``fresh``), so a reused slot costs no
 dispatch and cannot leak its last holder's state.

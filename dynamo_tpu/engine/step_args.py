@@ -1,8 +1,8 @@
 """What changes every step, for a synchronous step program, in ONE int32 buffer.
 
-``prefill``, ``decode`` and ``mixed_step`` (engine ``_build_programs`` and
-``_build_programs_pp``) take what the host builds anew for each dispatch
-as a single argument: ``pack`` lays it out here on the host, ``unpack``
+``prefill``, ``decode`` and ``mixed_step`` (engine ``_build_programs``: one
+set of programs, whatever runs their bodies) take what the host builds anew
+for each dispatch as a single argument: ``pack`` lays it out here on the host, ``unpack``
 slices it inside the program, where a slice of an argument costs nothing.
 On a TPU v5e every host value handed to a jitted call is a transfer of its
 own at about 0.15 ms (0.25 ms through ``jnp.asarray``), so thirteen values

@@ -29,10 +29,11 @@ are additionally masked to scratch block 0 on invalid ticks (block 0 is
 never allocated). The final stage's outputs are psum-broadcast so sampling
 outside the shard_map sees replicated values.
 
-The engine plugs these in as drop-in forwards (engine/engine.py
-_build_programs, cfg.pp > 1): the surrounding program — sampling, penalties,
-logprobs, the decode_multi scan, donation, chained horizons — is unchanged,
-with the stacked caches living as 1-element k_caches/v_caches lists.
+The engine plugs these in as the BODIES of its step programs (engine/engine.py
+``_pp_bodies``: thin adapters, the stacked caches living as 1-element
+k_caches/v_caches lists). A step program is a body and an epilogue, and only
+the body is pp's: the epilogue (engine/sampling.py), the decode_multi scan,
+donation and chained horizons are the one set of programs every engine has.
 """
 
 from __future__ import annotations
