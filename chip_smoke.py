@@ -942,6 +942,35 @@ def _kernel_child() -> None:
     float32_close("kda_scan 96 tokens x 8 heads, the state after the run", got_S, want_S)
     float32_close("kda_scan 96 tokens x 8 heads, y", got_y, want_y)
 
+    # EVA's decode attention (PR 46) at EvaByte's widths: 24 rows of 32
+    # heads x 128 (multi-head) over a ring of 128 pages and up to 4 summary
+    # blocks of 8 pages each in one pool; rows at a window's first and last
+    # position, in window 0 (no summary read), one empty
+    del k_cache, v_cache
+    from dynamo_tpu.ops import pallas_eva
+
+    EB, EH, RP, NW = 24, 32, 128, 5
+    e_base = 1 + EB * RP
+    e_pool = e_base + (1 + EB * NW) * 8
+    ek, ev = rnd(e_pool, BS, EH, D), rnd(e_pool, BS, EH, D)
+    e_tables = np.zeros((EB, RP + NW), np.int32)
+    e_tables[:, :RP] = (rng.permutation(EB * RP) + 1).reshape(EB, RP)
+    e_tables[:, RP:] = (rng.permutation(EB * NW) + 1).reshape(EB, NW)
+    e_lens = rng.integers(1, 5 * 2048, EB).astype(np.int32)
+    e_lens[:6] = [0, 1, 2048, 2049, 4 * 2048, 10239]
+    e_args = (rnd(EB, EH, D), ek, ev, jnp.asarray(e_tables), jnp.asarray(e_lens))
+    e_query = att.EvaQuery(None, None, 2048, 16)  # the geometry: no vector is read
+    got = np.asarray(
+        pallas_eva.eva_decode_attention(*e_args, e_query, e_base), np.float32)
+    want = np.asarray(highest(lambda *a: att.eva_paged_decode_attention(
+        *a, e_query, e_base))(*e_args), np.float32)
+    if got[e_lens == 0].any():
+        raise SystemExit("eva_decode_attention: an empty row is not zeros")
+    compare("eva_decode_attention 24 rows x 32 heads, ring 2048, 4 summary blocks",
+            got[e_lens > 0], want[e_lens > 0])
+    del ek, ev
+    k_cache, v_cache = rnd(NB, BS, KVH, D), rnd(NB, BS, KVH, D)
+
     # block moves are copies: exact
     ids = jnp.asarray(rng.permutation(NB)[:32], jnp.int32)
     got = bc.gather_blocks(k_cache, ids)

@@ -19,6 +19,7 @@ from dynamo_tpu.engine.engine import TpuEngine, TpuEngineConfig
 from dynamo_tpu.engine.weights import config_from_hf, load_params
 from dynamo_tpu.kv_router import KvEventPublisher, WorkerMetricsPublisher
 from dynamo_tpu.llm import ModelDeploymentCard, ModelRuntimeConfig, register_llm
+from dynamo_tpu.models.evabyte import EvaByteConfig
 from dynamo_tpu.models.llama import LlamaConfig
 from dynamo_tpu.models.gemma import GemmaConfig
 from dynamo_tpu.models.gptoss import GptOssConfig
@@ -57,6 +58,10 @@ PRESETS = {
     "deepseek-v2-lite": MlaConfig.deepseek_v2_lite,
     "deepseek-v3": MlaConfig.deepseek_v3,
     "tiny-vl": lambda: LlamaConfig(),  # language side; vision below
+    # pages as a ring of one window with summaries by window (byte-level;
+    # --block-size 16, --prefill-chunk at most the window: 256 / 2048)
+    "tiny-evabyte": EvaByteConfig.tiny,
+    "evabyte-6.5b": EvaByteConfig.evabyte_6_5b,
 }
 
 from dynamo_tpu.models.vision import VisionConfig

@@ -7,10 +7,18 @@ which sequence-hash, mirrored after the reference's block pool + reuse logic
 for padding writes and never allocated.
 
 Emits stored/removed events (sequence-hash space) for the KV router feed.
+
+A family whose pages live one window (``models/registry.window_ring``) holds
+them as a RING (``Ring`` below): the table's entry of a position wraps, a
+request never holds more than a window's pages, and a closed window's pages
+are written again by the next one: nothing is released and taken again, nothing
+is copied. What such a request keeps of a closed window is a summary block,
+from a second ``BlockAllocator`` over a store of its own blocks.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from collections import OrderedDict
 from typing import Dict, List, Tuple
 
@@ -19,6 +27,56 @@ from ..tokens import SequenceHash
 
 class OutOfBlocks(Exception):
     pass
+
+
+@dataclasses.dataclass(frozen=True)
+class Ring:
+    """The geometry of pages with a lifetime shorter than their request:
+    ``positions`` a window (the ring), ``page`` tokens a page (a page is a
+    chunk: one summary each), ``max_context`` the longest sequence. A row's
+    table is ``pages`` ring entries, then one summary block a window
+    (``windows`` of them; a block is ``pages_per_block`` whole pages of
+    summaries, ops/attention.py has the layout)."""
+
+    positions: int
+    page: int
+    max_context: int
+
+    def __post_init__(self):
+        if self.positions % (self.page * self.page):
+            raise ValueError(
+                f"a window of {self.positions} positions is not a whole "
+                f"number of pages of summaries ({self.page} summaries of "
+                f"{self.page} positions each)"
+            )
+
+    @property
+    def pages(self) -> int:
+        return self.positions // self.page
+
+    @property
+    def windows(self) -> int:
+        return -(-self.max_context // self.positions)
+
+    @property
+    def pages_per_block(self) -> int:
+        return self.pages // self.page
+
+    @property
+    def table_width(self) -> int:
+        return self.pages + self.windows
+
+    def entry(self, position: int) -> int:
+        """The table entry whose page holds ``position``."""
+        return (position % self.positions) // self.page
+
+    def held(self, length: int) -> Tuple[int, int]:
+        """(pages, summary blocks) a sequence of ``length`` tokens holds: its
+        pages up to a whole ring, one block a window it has opened."""
+        return (
+            min(-(-length // self.page), self.pages),
+            -(-length // self.positions),
+        )
 
 
 class BlockAllocator:

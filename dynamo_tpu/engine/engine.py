@@ -68,7 +68,7 @@ from ..runtime.tasks import spawn_bg
 from ..runtime.logging import get_logger
 from ..runtime.tracing import get_tracer
 from ..tokens import TokenBlockSequence
-from .allocator import BlockAllocator, OutOfBlocks
+from .allocator import BlockAllocator, OutOfBlocks, Ring
 from . import step_args
 from .telemetry import (
     PENDING_SPANS_MAX,
@@ -217,7 +217,22 @@ class TpuEngineConfig:
         return self.prefill_buckets[-1]
 
     @property
+    def ring(self) -> Optional[Ring]:
+        """The family's ring of pages (models/registry.window_ring), or None:
+        a page lives as long as its request."""
+        positions = registry.window_ring(self.model)
+        if positions is None:
+            return None
+        return Ring(positions, self.block_size, self.max_context)
+
+    @property
     def max_blocks_per_seq(self) -> int:
+        """Entries of a row's block table: a page a ``block_size`` tokens of
+        ``max_context``; under a ring, the ring's pages and then a summary
+        block a window."""
+        ring = self.ring
+        if ring is not None:
+            return ring.table_width
         return (self.max_context + self.block_size - 1) // self.block_size
 
 
@@ -302,6 +317,8 @@ class _Seq:
     seq: TokenBlockSequence               # prompt + generated
     slot: int = -1
     block_ids: List[int] = dataclasses.field(default_factory=list)
+    # a family with a ring: the summary blocks of the windows it has opened
+    summary_ids: List[int] = dataclasses.field(default_factory=list)
     produced: int = 0
     last_token: int = 0
     cached_tokens: int = 0
@@ -526,6 +543,27 @@ class TpuEngine:
         registry.check_state_supported(
             self.mcfg, **asked, kvbm=kvbm is not None
         )
+        registry.check_eva_supported(
+            self.mcfg, **asked, kvbm=kvbm is not None
+        )
+        # pages that live one window (allocator.Ring) and summary blocks by
+        # window beside them, where the family says so; None for every other
+        self._ring = config.ring
+        if self._ring is not None:
+            chunk = self.mcfg.chunk_size
+            if chunk != config.block_size:
+                raise ValueError(
+                    f"a page is a chunk: block_size {config.block_size} != "
+                    f"the family's chunk_size {chunk}"
+                )
+            if any(self._ring.positions % b for b in config.prefill_buckets):
+                # a prefill chunk starts at a multiple of the largest bucket
+                # and is at most that long: it never straddles a window
+                raise ValueError(
+                    f"prefill_buckets {config.prefill_buckets} do not divide "
+                    f"the window of {self._ring.positions} positions: a "
+                    "chunk would straddle two windows"
+                )
         if registry.is_gptoss(self.mcfg) or registry.is_gemma(self.mcfg):
             # the ragged kernel carries per-row window/sink/softcap
             # attributes, so use_pallas serves these families too. Only the
@@ -598,6 +636,16 @@ class TpuEngine:
         self.kv_publisher = kv_publisher
         self.metrics_publisher = metrics_publisher
         self.allocator = BlockAllocator(config.num_blocks, config.block_size)
+        # the store of summary blocks: every window of as many requests at
+        # ``max_context`` as the page pool holds whole rings (block 0 is its
+        # scratch); its blocks are pages of the pool's arrays above
+        # ``num_blocks`` (_init_caches, ops/attention.py)
+        self.summary_allocator = None
+        if self._ring is not None:
+            rings = max((config.num_blocks - 1) // self._ring.pages, 1)
+            self.summary_allocator = BlockAllocator(
+                1 + rings * self._ring.windows, self._ring.pages
+            )
         self._host_rng = np.random.default_rng(config.seed)
         # multi-tier KV (kvbm/pool.py): sealed blocks write through to host
         # DRAM (G2) / disk (G3); admission onboards matched prefixes back
@@ -624,12 +672,12 @@ class TpuEngine:
         # mixed step already makes. None where no step carried them.
         # A latent held as rows adds what its decode rows read, under the
         # names of StepStats' fields (mla.read_counters: dsa_* or mla_*).
+        self._read_counters = registry.read_counters(self.mcfg)
         self._moe_counted = (
-            registry.counts_routing(self.mcfg) and config.pp == 1
-            and meshlib.tp_size(self.mesh) == 1
+            (registry.counts_routing(self.mcfg) or bool(self._read_counters))
+            and config.pp == 1 and meshlib.tp_size(self.mesh) == 1
         )
         self._moe_last: Optional[Tuple[int, ...]] = None
-        self._read_counters = registry.read_counters(self.mcfg)
         self._lm_logits = registry.lm_logits_fn(self.mcfg)
         with self.mesh:
             if params is None and (
@@ -742,7 +790,10 @@ class TpuEngine:
         self._slots: List[Optional[_Seq]] = [None] * B
         self._tokens = np.zeros(B, np.int32)
         self._seq_lens = np.zeros(B, np.int32)
-        self._block_tables = np.zeros((B, config.max_blocks_per_seq), np.int32)
+        # entries of a row's table (a ring's pages and its summary blocks,
+        # or a page a block_size of max_context): asked of the family once
+        self._table_width = config.max_blocks_per_seq
+        self._block_tables = np.zeros((B, self._table_width), np.int32)
         self._temps = np.zeros(B, np.float32)
         self._top_ks = np.zeros(B, np.int32)
         self._top_ps = np.ones(B, np.float32)
@@ -935,6 +986,7 @@ class TpuEngine:
             # transfer gathers iterate per-layer cache lists; pp stacks them
             raise ValueError("pp serving does not cover KV transfer yet")
         registry.check_state_supported(self.mcfg, transfer=True)
+        registry.check_eva_supported(self.mcfg, transfer=True)
         from ..runtime.request_plane.tcp import TcpRequestServer
         from .transfer import KvCommitSignal, KvTransferServer
 
@@ -956,6 +1008,7 @@ class TpuEngine:
             from .transfer import KvTransferClient
 
             registry.check_state_supported(self.mcfg, transfer=True)
+            registry.check_eva_supported(self.mcfg, transfer=True)
 
             self._transfer_client = KvTransferClient(self)
         return self._transfer_client
@@ -1067,8 +1120,14 @@ class TpuEngine:
         mcfg = mcfg if mcfg is not None else self.mcfg
         if quantized is None:
             quantized = self.kv_quantized
+        pages = self.cfg.num_blocks
+        if self._ring is not None and mcfg is self.mcfg:
+            # the summary blocks, whole pages each, above the ring's pages
+            pages += (
+                self.summary_allocator.num_blocks * self._ring.pages_per_block
+            )
         shape = (
-            self.cfg.num_blocks,
+            pages,
             self.cfg.block_size,
             mcfg.num_kv_heads,
             mcfg.head_dim,
@@ -1225,8 +1284,10 @@ class TpuEngine:
         # the one attention seam (ops/paged_attention.py): the programs below
         # state what rows they have, the seam picks the kernel or the twin
         attn = PagedAttention(
-            self.mesh, self.use_pallas, self.kernels_interpreted
+            self.mesh, self.use_pallas, self.kernels_interpreted,
+            summary_base=cfg.num_blocks,
         )
+        ring = self._ring
 
         # the second seam: ``mix`` owns a family's slot state
         # (engine/state_cache.py) as ``attend`` owns the pages. The family's
@@ -1331,10 +1392,12 @@ class TpuEngine:
             """(block, offset) where each live row's token at ``positions``
             is written; scratch block 0 for the others."""
             bs = cfg.block_size
+            # under a ring a position's page is its window's: the entry wraps
+            inside = positions if ring is None else positions % ring.positions
             blocks = jnp.where(
                 active,
                 jnp.take_along_axis(
-                    block_tables, (positions // bs)[:, None], axis=1
+                    block_tables, (inside // bs)[:, None], axis=1
                 )[:, 0],
                 0,
             )
@@ -1349,6 +1412,12 @@ class TpuEngine:
                     k_caches[layer_idx], v_caches[layer_idx],
                     k_new[:, 0], v_new[:, 0], write_blocks, write_offsets,
                 )
+                if "eva" in extra:
+                    # a row whose token filled its page: the page's summary
+                    kc, vc = attn.summarise_rows(
+                        kc, vc, block_tables, seq_lens, write_blocks,
+                        write_offsets, extra["eva"],
+                    )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 out = attn.decode(
                     q[:, 0], kc, vc, block_tables, seq_lens, **extra
@@ -1378,6 +1447,12 @@ class TpuEngine:
                     *real_rows(k_new, v_new, positions, total_len),
                     new_block_ids,
                 )
+                if "eva" in extra:
+                    # the summaries of the chunk's whole pages
+                    kc, vc = attn.summarise_chunk(
+                        kc, vc, k_new, v_new, block_table, chunk_start,
+                        total_len, extra["eva"],
+                    )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 if cfg.sp > 1:
                     # context-parallel chunk attention: queries + chunk KV
@@ -1421,7 +1496,9 @@ class TpuEngine:
             """Dense causal forward, no KV pages touched; padded tail
             positions can't affect earlier queries (causal)."""
 
-            def attend(q, k_new, v_new, layer_idx, **extra):
+            def attend(q, k_new, v_new, layer_idx, eva=None, **extra):
+                if eva is not None:  # a whole sequence from nothing
+                    return att.eva_attention(q, k_new, v_new, eva)
                 return att.causal_attention(q, k_new, v_new, **extra)
 
             return fwd(params, mcfg, tokens, positions, attend)  # [S, H]
@@ -1642,6 +1719,16 @@ class TpuEngine:
                     kc, vc, k_new[S_pad:], v_new[S_pad:],
                     a.write_blocks, a.write_offsets,
                 )
+                if "eva" in extra:
+                    # the chunk's whole pages, and the rows' filled ones
+                    kc, vc = attn.summarise_chunk(
+                        kc, vc, k_new[:S_pad], v_new[:S_pad], a.table_row,
+                        a.chunk_start, a.total_len, extra["eva"],
+                    )
+                    kc, vc = attn.summarise_rows(
+                        kc, vc, block_tables, a.seq_lens, a.write_blocks,
+                        a.write_offsets, extra["eva"],
+                    )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 tables = jnp.concatenate(
                     [a.table_row[None], block_tables], axis=0
@@ -2184,7 +2271,10 @@ class TpuEngine:
                     f"prompt {n_prompt} tokens exceeds engine max_context "
                     f"{self.cfg.max_context}"
                 )
-            if n_prompt // self.cfg.block_size + 2 > self.cfg.num_blocks:
+            pages = n_prompt // self.cfg.block_size + 1
+            if self._ring is not None:
+                pages = min(pages, self._ring.pages)  # a whole ring at most
+            if pages + 1 > self.cfg.num_blocks:
                 # would wait forever in admission — no amount of eviction frees
                 # enough pages for this prompt
                 raise ContextLengthError(
@@ -3098,7 +3188,7 @@ class TpuEngine:
                     annotations={"evacuation": evac} if evac else {},
                 ))
                 if st.block_ids:
-                    self.allocator.release(st.block_ids)
+                    self._release(st)
             self._waiting = []
             self._slots = [None] * self.cfg.max_batch_size
             self._seq_lens[:] = 0
@@ -3210,7 +3300,15 @@ class TpuEngine:
                 (prompt_len + self.cfg.block_size - 1) // self.cfg.block_size
                 - prefix_blocks
             )
-            if not self.allocator.can_allocate(blocks_needed):
+            windows = 0
+            if self._ring is not None:
+                # a prompt's pages are a ring's at most (the family declines
+                # prefix hits), and it opens a summary block a window: both
+                # are reserved here or the request waits
+                blocks_needed, windows = self._ring.held(prompt_len)
+            if not self.allocator.can_allocate(blocks_needed) or not (
+                self._take_summary_blocks(st, windows)
+            ):
                 self.allocator.release(prefix_ids)
                 still.append(st)
                 continue
@@ -3218,6 +3316,7 @@ class TpuEngine:
                 new_ids = self.allocator.allocate(blocks_needed)
             except OutOfBlocks:
                 self.allocator.release(prefix_ids)
+                self._release(st)
                 still.append(st)
                 continue
             st.block_ids = prefix_ids + new_ids
@@ -3233,6 +3332,7 @@ class TpuEngine:
             self._slots[slot] = st
             self._block_tables[slot].fill(0)
             self._block_tables[slot, : len(st.block_ids)] = st.block_ids
+            self._table_summary_blocks(st)
             self._seq_lens[slot] = prompt_len
             s = st.req.sampling
             self._temps[slot] = s.temperature
@@ -3335,6 +3435,40 @@ class TpuEngine:
         self._waiting = still
         return admitted
 
+    def _take_summary_blocks(self, st: _Seq, windows: int) -> bool:
+        """A family with a ring: take what ``st`` lacks of a summary block a
+        window for ``windows`` windows (one is taken as its window opens,
+        all are released with the request). False, and nothing taken, where
+        the store cannot give them; True at once for every other family."""
+        more = windows - len(st.summary_ids)
+        if self._ring is None or more <= 0:
+            return True
+        try:
+            new_ids = self.summary_allocator.allocate(more)
+        except OutOfBlocks:
+            return False
+        st.summary_ids.extend(new_ids)
+        if st.slot >= 0 and self._slots[st.slot] is st:
+            self._table_summary_blocks(st)
+        return True
+
+    def _table_summary_blocks(self, st: _Seq) -> None:
+        """``st``'s summary blocks into its row of the table, behind the
+        ring's entries, a window each."""
+        if st.summary_ids:
+            first = self._ring.pages
+            self._block_tables[
+                st.slot, first : first + len(st.summary_ids)
+            ] = st.summary_ids
+
+    def _release(self, st: _Seq) -> None:
+        """Everything ``st`` holds back to the free lists: its pages and,
+        under a ring, the summary blocks of the windows it opened."""
+        self.allocator.release(st.block_ids)
+        if st.summary_ids:
+            self.summary_allocator.release(st.summary_ids)
+        st.block_ids, st.summary_ids = [], []
+
     def _bucket(self, n: int) -> int:
         for b in self.cfg.prefill_buckets:
             if n <= b:
@@ -3378,7 +3512,14 @@ class TpuEngine:
         positions = np.full(S_pad, self.cfg.max_context - 1, np.int32)
         positions[:chunk_len] = np.arange(start, start + chunk_len)
         new_block_ids = np.zeros(S_pad // bs, np.int32)
-        real = block_ids[start // bs :][: S_pad // bs]
+        if self._ring is not None:
+            # the chunk's own pages of the ring (it lies in one window: the
+            # buckets divide it), none of the pages behind them, which hold
+            # the window before
+            first = self._ring.entry(start)
+            real = block_ids[first : first + -(-chunk_len // bs)]
+        else:
+            real = block_ids[start // bs :][: S_pad // bs]
         new_block_ids[: len(real)] = real
         return tokens, positions, new_block_ids
 
@@ -3476,7 +3617,7 @@ class TpuEngine:
                 # prior tokens for disagg/migration resumes)
                 g_dev = self._guided_dev()
             step = step_args.pack(
-                self.cfg.max_batch_size, self.cfg.max_blocks_per_seq,
+                self.cfg.max_batch_size, self._table_width,
                 table_row=self._block_tables[st.slot],
                 total_len=total_len, chunk_start=start, slot=st.slot,
                 is_final=is_final, c_lp_need=self._lp_ns[st.slot] > 0,
@@ -3611,14 +3752,15 @@ class TpuEngine:
         # chunked: the caller pre-allocated temporary pages (loop thread
         # owns the allocator); each chunk writes KV + attends over the
         # gathered prefix, the final chunk yields the pooled vector
-        if self.state is not None:
+        if self.state is not None or self._ring is not None:
             raise ValueError(
                 "an embedding input above the largest prefill bucket is not "
-                "served by a family with slot state: temporary pages carry "
-                "keys from chunk to chunk, nothing carries a recurrent state"
+                "served by a family with slot state or a ring: temporary "
+                "pages carry keys from chunk to chunk, nothing carries a "
+                "recurrent state or a window's summaries"
             )
         cap = self.cfg.prefill_chunk
-        table = np.zeros(self.cfg.max_blocks_per_seq, np.int32)
+        table = np.zeros(self._table_width, np.int32)
         table[: len(block_ids)] = block_ids
         vec = None
         _j = self._j
@@ -3679,7 +3821,7 @@ class TpuEngine:
                 else (tokens, positions, new_block_ids)
             )
             step = step_args.pack(
-                self.cfg.max_batch_size, self.cfg.max_blocks_per_seq,
+                self.cfg.max_batch_size, self._table_width,
                 table_row=self._block_tables[st.slot],
                 total_len=start + chunk_len, chunk_start=start, slot=st.slot,
                 is_final=is_final, c_lp_need=c_lp_need, lp_need=lp_need,
@@ -3762,7 +3904,9 @@ class TpuEngine:
         (_prepare_horizon) and the fused mixed step (_prepare_mixed), so
         the split and fused paths can never drift."""
         bs = self.cfg.block_size
-        granted: List[Tuple[_Seq, int]] = []  # rollback on partial failure
+        ring = self._ring
+        # (sequence, pages, summary blocks): rollback on partial failure
+        granted: List[Tuple[_Seq, int, int]] = []
         ok = True
         for i, st in enumerate(seqs):
             if st is None or st.done or not st.prefilled:
@@ -3772,6 +3916,16 @@ class TpuEngine:
                 ok = False
                 break
             needed = (L + extra_tokens) // bs + 1
+            if ring is not None:
+                # a whole ring at most; and a summary block a window the
+                # tokens booked here may open
+                needed, windows = ring.held(L + extra_tokens + 1)
+                held = len(st.summary_ids)
+                if not self._take_summary_blocks(st, windows):
+                    ok = False
+                    break
+                if len(st.summary_ids) > held:
+                    granted.append((st, 0, len(st.summary_ids) - held))
             extra = needed - len(st.block_ids)
             if extra > 0:
                 if not self.allocator.can_allocate(extra):
@@ -3786,12 +3940,17 @@ class TpuEngine:
                 st.block_ids.extend(new_ids)
                 for off, bid in enumerate(new_ids):
                     self._block_tables[st.slot, base + off] = bid
-                granted.append((st, len(new_ids)))
+                granted.append((st, len(new_ids), 0))
         if not ok:
-            for st, count in granted:
-                taken = st.block_ids[-count:]
-                del st.block_ids[-count:]
-                self.allocator.release(taken)
+            for st, count, blocks in granted:
+                if count:
+                    taken = st.block_ids[-count:]
+                    del st.block_ids[-count:]
+                    self.allocator.release(taken)
+                if blocks:
+                    taken = st.summary_ids[-blocks:]
+                    del st.summary_ids[-blocks:]
+                    self.summary_allocator.release(taken)
             return False
         return True
 
@@ -4285,7 +4444,7 @@ class TpuEngine:
         host's length so far plus the one token in flight. Returns
         (positions, seq_lens, write_blocks, write_offsets, steps, carried),
         all [B]."""
-        bs = self.cfg.block_size
+        bs, ring = self.cfg.block_size, self._ring
         B = self.cfg.max_batch_size
         positions = np.zeros(B, np.int32)
         seq_lens = np.zeros(B, np.int32)
@@ -4302,7 +4461,9 @@ class TpuEngine:
             seq_lens[i] = L
             carried[i] = ahead
             self._tokens[i] = 0 if ahead else st.last_token
-            write_blocks[i] = st.block_ids[(L - 1) // bs]
+            write_blocks[i] = st.block_ids[
+                (L - 1) // bs if ring is None else ring.entry(L - 1)
+            ]
             write_offsets[i] = (L - 1) % bs
             steps[i] = st.produced + ahead
         return positions, seq_lens, write_blocks, write_offsets, steps, carried
@@ -4347,7 +4508,7 @@ class TpuEngine:
                 g_dev = self._guided_dev()
                 g_rows = dict(g_state=self._g_state)
             step = step_args.pack(
-                self.cfg.max_batch_size, self.cfg.max_blocks_per_seq,
+                self.cfg.max_batch_size, self._table_width,
                 lp_need=lp_need, tokens=self._tokens, positions=positions,
                 seq_lens=seq_lens, write_blocks=write_blocks,
                 write_offsets=write_offsets, steps=steps, **g_rows,
@@ -4448,6 +4609,12 @@ class TpuEngine:
                             )
                     # ensure a block exists for the NEXT token's write position
                     needed_blocks = (L_before + 1) // self.cfg.block_size + 1
+                    if self._ring is not None:
+                        # a whole ring at most; the next token may open a
+                        # window, which takes its summary block now
+                        needed_blocks, windows = self._ring.held(L_before + 2)
+                        if not self._take_summary_blocks(st, windows):
+                            finish = FINISH_LENGTH
                     if needed_blocks > len(st.block_ids):
                         try:
                             (new_id,) = self.allocator.allocate(1)
@@ -4509,7 +4676,7 @@ class TpuEngine:
             if st is None:
                 continue
             if st.done or st.context.is_killed():
-                self.allocator.release(st.block_ids)
+                self._release(st)
                 self._slots[i] = None
                 self._seq_lens[i] = 0
                 if self.guided_enabled and self._g_active[i]:
@@ -4667,6 +4834,9 @@ class TpuEngine:
         request_spans = tuple(rspans.popleft() for _ in range(len(rspans)))
         # set by the step's own readback; a prefill-only step has none
         routed, touched, load_max, *reads = self._moe_last or (None,) * 3
+        if not registry.counts_routing(self.mcfg):
+            # a family that rides the readback for its read counters alone
+            routed = touched = load_max = None
         # behind them what a latent's decode rows read, by StepStats' names
         reads = dict(zip(self._read_counters, reads))
         held = getattr(self.mcfg, "experts_held", None) is not None
@@ -4811,6 +4981,15 @@ class TpuEngine:
                 "bytes_per_slot": self.state.bytes_per_slot,
                 "bytes": self.state.nbytes,
                 "slots": self.state.slots,
+            }
+        if self._ring is not None:
+            # the third kind of state: the ring's geometry and the store of
+            # summary blocks beside the ring pages
+            snap["ring"] = {
+                "positions": self._ring.positions,
+                "pages": self._ring.pages,
+                "summary_blocks": self.summary_allocator.num_blocks - 1,
+                "summary_blocks_free": self.summary_allocator.free_blocks,
             }
         if self.cfg.spec_draft is not None:
             snap["spec"] = dict(self.spec_stats)

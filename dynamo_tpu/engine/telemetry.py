@@ -308,6 +308,17 @@ class StepStats:
     kda_rows_updated: Optional[int] = None
     kda_tokens_scanned: Optional[int] = None
     kda_decode_steps: Optional[int] = None
+    # a family whose pages are a ring with summaries by window (EVA:
+    # models/evabyte.py), on the step's own readback beside moe_*: the real
+    # decode rows attended, the exact keys they read of their open windows
+    # and the summaries they read of the closed ones (all three summed over
+    # rows and layers), the windows those rows closed (a row at a window's
+    # first position), and the decode steps counted. None elsewhere
+    eva_rows_attended: Optional[int] = None
+    eva_window_keys: Optional[int] = None
+    eva_summaries_read: Optional[int] = None
+    eva_windows_closed: Optional[int] = None
+    eva_decode_steps: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -500,6 +511,14 @@ class EngineTelemetry:
                     getattr(s, f"{pre}_rows_updated") or 0 for s in recent),
                 "tokens_scanned": sum(
                     getattr(s, f"{pre}_tokens_scanned") or 0 for s in recent),
+            }
+        if any(s.eva_rows_attended for s in recent):
+            # the third kind of state: what the window's decode rows read of
+            # their rings and of their closed windows' summaries
+            out["eva"] = {
+                name: sum(getattr(s, f"eva_{name}") or 0 for s in recent)
+                for name in ("rows_attended", "window_keys", "summaries_read",
+                             "windows_closed", "decode_steps")
             }
         return out
 

@@ -325,8 +325,15 @@ class RoutingStats:
         experts where the layer holds a share), experts touched (summed over
         layers), the largest count on one expert; ``add_reads``' sums behind
         them, in the order they were named."""
-        c = jnp.stack(self.counts)                       # [L, E]
-        out = jnp.stack([c.sum(), (c > 0).sum(), c.max()]).astype(jnp.float32)
+        if not self.counts:
+            # a family without experts that rides the readback for its
+            # ``add_reads`` alone: the routing's three read zero
+            out = jnp.zeros((3,), jnp.float32)
+        else:
+            c = jnp.stack(self.counts)                   # [L, E]
+            out = jnp.stack(
+                [c.sum(), (c > 0).sum(), c.max()]
+            ).astype(jnp.float32)
         if self.reads is not None:
             out = jnp.concatenate([out, self.reads.astype(jnp.float32)])
         return out

@@ -13,7 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas_moe import grouped_matmul, grouped_matmul_reference
 from ..parallel.mesh import AXIS_TP, shard_map
-from . import falcon_h1, gemma, gptoss, llama, mla, moe, solar_open2
+from . import evabyte, falcon_h1, gemma, gptoss, llama, mla, moe, solar_open2
 
 
 def is_moe(cfg) -> bool:
@@ -40,14 +40,18 @@ def is_solar_open2(cfg) -> bool:
     return isinstance(cfg, solar_open2.SolarOpen2Config)
 
 
+def is_evabyte(cfg) -> bool:
+    return isinstance(cfg, evabyte.EvaByteConfig)
+
+
 def supports_pp(cfg) -> bool:
     """Pipeline-parallel serving covers the dense llama family only: the
     stage placement stacks per-layer params homogeneously, which MoE expert
-    stacks, MLA latent projections, gpt-oss/gemma windowed-attention extras
-    and a family's slot state (a state-space mixer's, a linear-attention
-    layer's) do not fit (parallel/pp_serving.py)."""
+    stacks, MLA latent projections, gpt-oss/gemma windowed-attention extras,
+    a family's slot state (a state-space mixer's, a linear-attention
+    layer's) and a ring with summaries do not fit (parallel/pp_serving.py)."""
     return not (is_moe(cfg) or is_mla(cfg) or is_gptoss(cfg) or is_gemma(cfg)
-                or is_falcon_h1(cfg) or is_solar_open2(cfg))
+                or is_falcon_h1(cfg) or is_solar_open2(cfg) or is_evabyte(cfg))
 
 
 def check_pp_supported(cfg) -> None:
@@ -58,7 +62,7 @@ def check_pp_supported(cfg) -> None:
     if not supports_pp(cfg):
         raise ValueError(
             f"pp serving supports dense llama-family models only; "
-            f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1/solar-open2) is not "
+            f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1/solar-open2/evabyte) is not "
             f"stacked for pipeline stages — configure this preset with pp=1 "
             f"(use tp/sp/dp instead)"
         )
@@ -99,10 +103,19 @@ def place_latent(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
     return dataclasses.replace(cfg, rows_layout=True) if one_chip_text else cfg
 
 
+def _refuse(what: str, refusals) -> None:
+    """Raise for the first ``(asked, why)`` of ``refusals`` that was asked."""
+    for hit, why in refusals:
+        if hit:
+            raise ValueError(f"{what} does not run with {why}")
+
+
 def read_counters(cfg) -> tuple:
     """The ``StepStats`` fields a family's forward adds behind the routing's
-    three on the step's readback (``mla.read_counters``); () for the others."""
-    return mla.read_counters(cfg) if is_mla(cfg) else ()
+    three on the step's readback (the family's ``read_counters``: a latent
+    held as rows, a ring with summaries); () for the others."""
+    own = getattr(family(cfg), "read_counters", None)
+    return own(cfg) if own else ()
 
 
 def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
@@ -134,9 +147,7 @@ def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
                        "8-bit latent needs its scales a token"),
         (vision, "vision: multimodal serving covers the dense family only"),
     ]
-    for hit, why in refusals:
-        if hit:
-            raise ValueError(f"{what} does not run with {why}")
+    _refuse(what, refusals)
 
 
 def state_spec(cfg) -> tuple:
@@ -204,12 +215,69 @@ def mixers(cfg, use_pallas: bool = False, interpret: bool = False) -> tuple:
     )
 
 
+def window_ring(cfg):
+    """Positions a request's PAGES cover before they are written again, or
+    None (every other family: a page lives as long as its request). With a
+    ring, position ``p`` lives in entry ``(p mod ring) // page`` of the
+    row's table, a request never holds more than ``ring / page`` pages, and
+    what it keeps of a closed window is ``summary_spec``."""
+    own = getattr(family(cfg), "window_ring", None)
+    return own(cfg) if own else None
+
+
+def summary_spec(cfg) -> tuple:
+    """The third answer beside ``page_layers`` and ``state_spec``: what ONE
+    CLOSED WINDOW of a request keeps a layer, as (name, shape, dtype)
+    (``evabyte.summary_spec``: a summary key and value a chunk a head); ()
+    for every family without a ``window_ring``. The engine keeps them in
+    summary blocks, one a window, taken when the window opens and released
+    with the request (engine ``summary_allocator``)."""
+    own = getattr(family(cfg), "summary_spec", None)
+    return own(cfg) if own else ()
+
+
 def prefix_reusable(cfg) -> bool:
     """Whether a block hash restores everything a request needs of its
     prefix. Pages, yes; a recurrent state is not kept per block, so a family
     with ``state_spec`` declines prefix hits (the prompt prefills whole) and
-    neither registers nor publishes its blocks as reusable."""
-    return not state_spec(cfg)
+    neither registers nor publishes its blocks as reusable. Nor does a ring:
+    a closed window's pages are gone, and its summaries are not kept by
+    block hash (they could be: a summary is a function of its own block)."""
+    return not state_spec(cfg) and window_ring(cfg) is None
+
+
+def check_eva_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
+                        kv_quantized=False, vision=False, transfer=False,
+                        kvbm=False) -> None:
+    """A family whose pages are a ring with summaries by window
+    (``window_ring``) runs on the one-chip text path; what it cannot do yet
+    is refused here, at engine construction (``transfer``: where the
+    transfer plane is asked for), each with its reason."""
+    if window_ring(cfg) is None:
+        return
+    what = f"a ring of pages with summaries by window ({type(cfg).__name__})"
+    refusals = [
+        (tp > 1, "tp > 1: the summary blocks are not sharded by heads yet "
+                 "(param_specs, the pool's sharding over the two learned "
+                 "vectors a head)"),
+        (pp > 1 or sp > 1, "pp / sp > 1: neither the wavefront nor the ring "
+                           "attention carries a window's summaries from "
+                           "stage to stage or shard to shard"),
+        (spec, "a speculative draft: verify rows would write pages of a "
+               "window they may not reach, and a rejected token's summary "
+               "would have to be rolled back"),
+        (lora, "LoRA: the family has no adapter path"),
+        (kv_quantized, "kv_dtype=int8: a summary is one key of many a "
+                       "softmax reads as often as any; an 8-bit summary is "
+                       "not calibrated (the family's cell holds it to bf16)"),
+        (vision, "vision: multimodal serving covers the dense family only"),
+        (transfer, "the KV transfer plane (disaggregation, evacuation): it "
+                   "moves pages by position and knows neither a ring nor "
+                   "summary blocks"),
+        (kvbm, "KVBM offload tiers: they keep pages by block hash, and a "
+               "ring's pages are overwritten under a live request"),
+    ]
+    _refuse(what, refusals)
 
 
 def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
@@ -240,12 +308,12 @@ def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
         (kvbm, "KVBM offload tiers: they keep pages by block hash and know "
                "no slot state"),
     ]
-    for hit, why in refusals:
-        if hit:
-            raise ValueError(f"{what} does not run with {why}")
+    _refuse(what, refusals)
 
 
 def family(cfg):
+    if is_evabyte(cfg):
+        return evabyte
     if is_solar_open2(cfg):
         return solar_open2
     if is_falcon_h1(cfg):
@@ -296,6 +364,8 @@ def forward_fn(cfg, mesh=None, use_pallas: bool = False,
     """
     if is_falcon_h1(cfg):
         return falcon_h1.forward
+    if is_evabyte(cfg):
+        return evabyte.forward
     if is_solar_open2(cfg):
         # the held (or replicated) experts' grouped path, its multiplication
         # as for MlaConfig below; tp > 1 is refused at construction
@@ -429,6 +499,15 @@ def param_specs(cfg) -> dict:
         "bk": P(AXIS_TP),
         "bv": P(AXIS_TP),
     }
+    if is_evabyte(cfg):
+        # tp > 1 is refused at construction (check_eva_supported): the
+        # dense family's specs, the two learned vectors a head follow them
+        layer.update({
+            "w_gate": P(None, AXIS_TP), "w_up": P(None, AXIS_TP),
+            "w_down": P(AXIS_TP, None),
+            "mu": P(AXIS_TP, None), "phi": P(AXIS_TP, None),
+        })
+        return {"top": top, "layer": layer, "default": P()}
     if is_solar_open2(cfg):
         # tp > 1 is refused at construction (check_state_supported): the
         # attention layers' specs are the dense family's, their output gate

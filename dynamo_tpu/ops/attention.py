@@ -632,3 +632,150 @@ def paged_latent_attention(
 
     outs = jax.vmap(one)(tables, q_starts, q_lens, seq_lens)
     return jnp.sum(outs, axis=0).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# EVA (models/evabyte.py): exact attention inside the query's window, one
+# learned summary a chunk of every window before it.
+#
+# What a request keeps, both in the pool's arrays. Its PAGES are a ring of
+# ``window / page`` entries (position ``p`` in entry ``(p mod window) //
+# page``), written again by the next window. Its SUMMARIES are kept by
+# window in summary blocks: block ``s`` is the pool's pages ``base +
+# s * ppb .. + ppb`` (``ppb = window / chunk / page``, a window's summaries
+# fill whole pages), summary ``i`` of the window a "token" at offset ``i``
+# of the block. A row's table is its ring's entries, then one summary block
+# a window (``[ring | blocks]``).
+#
+# To every attention launch such a row is ONE paged sequence (``eva_paged_
+# view``): the pages of its closed windows' summaries, then its ring's, the
+# query at the tail. Causal attention over that sequence IS the layer's one
+# softmax over both sets, so the dense family's twins and kernels serve it
+# with no change and nothing is merged.
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class EvaQuery:
+    """What an EVA layer asks of the attention seam besides q, k and v: its
+    two learned vectors a head and the window's geometry. A trace-time
+    object, as a ``DsaQuery`` is."""
+
+    mu: jax.Array                      # [h, d] the summary key's vector
+    phi: jax.Array                     # [h, d] the summary value's vector
+    window: int
+    chunk: int
+
+    @property
+    def chunks_per_window(self) -> int:
+        return self.window // self.chunk
+
+
+def eva_summarise(k: jax.Array, v: jax.Array, eva: EvaQuery
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """``k``, ``v`` [..., C, h, d] (a chunk's rotated keys and its values) ->
+    the chunk's summary key and value [..., h, d], both softmaxes over the
+    chunk's ``C`` positions, unscaled, in float32."""
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+    mu, phi = eva.mu.astype(jnp.float32), eva.phi.astype(jnp.float32)
+    a = jax.nn.softmax(jnp.sum(kf * mu, axis=-1), axis=-2)          # [..., C, h]
+    b = jax.nn.softmax(
+        jnp.sum(kf * phi, axis=-1) - 0.5 * jnp.sum(kf * kf, axis=-1), axis=-2
+    )
+    return (
+        jnp.sum(a[..., None] * kf, axis=-3).astype(k.dtype),
+        jnp.sum(b[..., None] * vf, axis=-3).astype(v.dtype),
+    )
+
+
+def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                  eva: EvaQuery) -> jax.Array:
+    """One whole sequence from nothing: ``q``, ``k``, ``v`` [S, h, d], ``S``
+    a whole number of chunks. The twin of what the seam serves from a ring
+    and a store: query ``n`` over the keys ``m <= n`` of its window and the
+    summaries of the chunks of the windows before it, one softmax."""
+    S, h, d = q.shape
+    W, C = eva.window, eva.chunk
+    ks, vs = eva_summarise(
+        k.reshape(S // C, C, h, d), v.reshape(S // C, C, h, d), eva
+    )
+    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    n = jnp.arange(S)
+    exact = (n[None, :] <= n[:, None]) & (n[None, :] // W == n[:, None] // W)
+    seen = (jnp.arange(S // C)[None, :] * C) // W < n[:, None] // W
+    scores = jnp.concatenate([
+        jnp.where(exact[:, None, :], _gqa_scores(q, k) * scale, NEG_INF),
+        jnp.where(seen[:, None, :], _gqa_scores(q, ks) * scale, NEG_INF),
+    ], axis=-1)
+    weights = jax.nn.softmax(scores, axis=-1)
+    return (
+        _gqa_values(weights[..., :S], v) + _gqa_values(weights[..., S:], vs)
+    ).astype(q.dtype)
+
+
+def eva_pages_per_block(eva: EvaQuery, page: int) -> int:
+    """Pages of one summary block: a window's summaries fill whole pages
+    (at the published sizes 128 summaries, 8 pages of 16)."""
+    if eva.chunk != page or eva.chunks_per_window % page:
+        raise ValueError(
+            f"an EVA ring needs page == chunk ({page} / {eva.chunk}) and a "
+            f"window's {eva.chunks_per_window} summaries to fill whole pages"
+        )
+    return eva.chunks_per_window // page
+
+
+def eva_paged_view(tables: jax.Array, seq_lens: jax.Array, eva: EvaQuery,
+                   page: int, base: int) -> Tuple[jax.Array, jax.Array]:
+    """``tables`` [R, ring pages + windows] (a row's ring, then its summary
+    blocks by window), ``seq_lens`` [R] (the context's length up to the
+    row's last query; 0 = an empty row) -> the rows as ONE paged sequence
+    each: tables [R, (windows - 1) ppb + ring pages] of the pages of the
+    closed windows' summaries and then the ring's, and its lengths (the
+    summaries visible, one key each, plus the positions of the open window
+    up to the last query). Entries past a row's length are never read."""
+    ppb = eva_pages_per_block(eva, page)
+    rp = eva.window // page
+    ring, blocks = tables[:, :rp], tables[:, rp:]
+    n_win = blocks.shape[1]
+    w = jnp.maximum(seq_lens - 1, 0) // eva.window       # the open window
+    j = jnp.arange((n_win - 1) * ppb + rp)[None, :]
+    from_summaries = base + jnp.take_along_axis(
+        blocks, jnp.minimum(j // ppb, n_win - 1), axis=1
+    ) * ppb + j % ppb
+    from_ring = jnp.take_along_axis(
+        ring, jnp.clip(j - (w * ppb)[:, None], 0, rp - 1), axis=1
+    )
+    view = jnp.where(j < (w * ppb)[:, None], from_summaries, from_ring)
+    lens = jnp.where(
+        seq_lens > 0, seq_lens - w * (eva.window - eva.chunks_per_window), 0
+    )
+    return view.astype(jnp.int32), lens.astype(jnp.int32)
+
+
+def eva_summary_slots(tables: jax.Array, positions: jax.Array,
+                      full: jax.Array, eva: EvaQuery, page: int, base: int
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """(page, offset) in the pool of the summary of the chunk that holds
+    ``positions`` ([N], in the rows of ``tables`` [N, ...]); scratch page 0
+    where ``full`` is false (the chunk's page is not whole yet)."""
+    ppb = eva_pages_per_block(eva, page)
+    rp = eva.window // page
+    block = jnp.take_along_axis(
+        tables[:, rp:], (positions // eva.window)[:, None], axis=1
+    )[:, 0]
+    i = (positions % eva.window) // eva.chunk            # summary of the window
+    return (
+        jnp.where(full, base + block * ppb + i // page, 0),
+        jnp.where(full, i % page, 0),
+    )
+
+
+def eva_paged_decode_attention(q: jax.Array, k_cache: jax.Array,
+                               v_cache: jax.Array, tables: jax.Array,
+                               seq_lens: jax.Array, eva: EvaQuery,
+                               base: int) -> jax.Array:
+    """Decode rows over a ring and summary blocks (``tables`` [B, ring pages
+    + windows], ``seq_lens`` the contexts' lengths with the fed token): the
+    pure-JAX twin of ops/pallas_eva.eva_decode_attention."""
+    view, lens = eva_paged_view(tables, seq_lens, eva, k_cache.shape[1], base)
+    return paged_decode_attention(q, k_cache, v_cache, view, lens)

@@ -40,6 +40,17 @@ straight through.
   ``_latent``. Pallas on, that is the launch
   ``paged_latent_attention`` (ops/pallas_latent.py), page-contiguous, for
   decode rows, a chunk and a mixed step alike (one launch each).
+- A layer that hands an ``eva`` (ops/attention.EvaQuery: exact attention in
+  the query's window, one learned summary a chunk of the windows before it,
+  models/evabyte.py) keeps a RING of pages and summary blocks by window in
+  the same pool, and asks the questions above of rows that are each ONE
+  paged sequence (``ops/attention.eva_paged_view``: the closed windows'
+  summaries, then the ring): decode rows are the launch
+  ``eva_decode_attention`` (ops/pallas_eva.py), a chunk and a mixed step the
+  ragged launch the dense family uses, with no change to it. Before the rows
+  are read their summaries are written (``summarise_chunk``,
+  ``summarise_rows``, scope ``eva_summarise``): a chunk's summary is final
+  once its page is full, and the step that fills the page writes it.
 """
 
 from __future__ import annotations
@@ -53,10 +64,14 @@ from . import attention as att
 
 
 class PagedAttention:
-    def __init__(self, mesh: Mesh, use_pallas: bool, interpret: bool = False):
+    def __init__(self, mesh: Mesh, use_pallas: bool, interpret: bool = False,
+                 summary_base: int = 0):
         self.mesh = mesh
         self.use_pallas = use_pallas
         self.interpret = interpret
+        # the pool's first page of summary blocks (a family with a ring:
+        # ops/attention.py has the layout); unused by every other family
+        self.summary_base = summary_base
 
     def _launch(self, q, kc, vc, tables, q_starts, q_lens, seq_lens,
                 window=None, sinks=None, softcap=None, **kw):
@@ -143,6 +158,58 @@ class PagedAttention:
                 n_chunk=n_chunk, interpret=self.interpret,
             )
 
+    def summarise_chunk(self, kc, vc, k_new, v_new, table, chunk_start,
+                        total_len, eva):
+        """The summaries of a chunk's WHOLE pages (``k_new`` [S_pad, h, d]
+        from ``chunk_start``, real up to ``total_len``; a chunk lies in one
+        window) into its window's summary block; a page the prompt does not
+        fill is summarised by the decode step that fills it."""
+        with jax.named_scope("eva_summarise"):
+            C = eva.chunk
+            n = k_new.shape[0] // C
+            ks, vs = att.eva_summarise(
+                k_new.reshape(n, C, *k_new.shape[1:]),
+                v_new.reshape(n, C, *v_new.shape[1:]), eva,
+            )
+            first = chunk_start + jnp.arange(n) * C
+            pages, offsets = att.eva_summary_slots(
+                jnp.broadcast_to(table[None], (n, table.shape[0])), first,
+                first + C <= total_len, eva, kc.shape[1], self.summary_base,
+            )
+            return att.write_decode_kv(kc, vc, ks, vs, pages, offsets)
+
+    def summarise_rows(self, kc, vc, tables, seq_lens, write_blocks,
+                       write_offsets, eva):
+        """Decode rows, their token written at ``(write_blocks,
+        write_offsets)`` (scratch page 0: not a live row): a row whose token
+        filled its page writes the page's summary, read back whole from the
+        pool, into its window's block."""
+        with jax.named_scope("eva_summarise"):
+            full = (write_blocks > 0) & (write_offsets == eva.chunk - 1)
+            pages, offsets = att.eva_summary_slots(
+                tables, jnp.maximum(seq_lens - 1, 0), full, eva, kc.shape[1],
+                self.summary_base,
+            )
+            # a row fills a page one step in ``chunk``: a loop over the rows
+            # that did (1.5 of 24 a step), not a product over all of them
+            rows = jnp.nonzero(full, size=full.shape[0], fill_value=0)[0]
+
+            def one(i, pools):
+                kc, vc = pools
+                r = rows[i]
+                ks, vs = att.eva_summarise(
+                    kc[write_blocks[r]], vc[write_blocks[r]], eva
+                )
+                return (kc.at[pages[r], offsets[r]].set(ks),
+                        vc.at[pages[r], offsets[r]].set(vs))
+
+            return jax.lax.fori_loop(0, jnp.sum(full), one, (kc, vc))
+
+    def _eva_view(self, kc, tables, seq_lens, eva):
+        return att.eva_paged_view(
+            tables, seq_lens, eva, kc.shape[1], self.summary_base
+        )
+
     def write_chunk(self, kc, vc, k_new, v_new, block_ids):
         """A chunk's whole pages into the pool, before the launch that reads
         them (ops/attention.write_prefill_kv has the contract). Where a
@@ -156,9 +223,21 @@ class PagedAttention:
         )
 
     def decode(self, q, kc, vc, tables, seq_lens, dsa=None, latent=None,
-               **extra):
+               eva=None, **extra):
         """Decode rows: ``q [B, h, d]``, one token a row at the end of a
         context of ``seq_lens[b]`` tokens (0 = an empty row)."""
+        if eva is not None:
+            with jax.named_scope("eva_attend"):
+                if not self.use_pallas:
+                    return att.eva_paged_decode_attention(
+                        q, kc, vc, tables, seq_lens, eva, self.summary_base
+                    )
+                from . import pallas_eva
+
+                return pallas_eva.eva_decode_attention(
+                    q, kc, vc, tables, seq_lens, eva, self.summary_base,
+                    interpret=self.interpret,
+                )
         if latent is not None:
             return self._latent(
                 q, kc, vc, tables, seq_lens > 0, seq_lens, latent, 0
@@ -185,10 +264,20 @@ class PagedAttention:
         )
 
     def chunk(self, q, kc, vc, table, chunk_start, total_len, positions,
-              dsa=None, latent=None, **extra):
+              dsa=None, latent=None, eva=None, **extra):
         """One chunk at its context's tail: ``q [S_pad, h, d]`` at absolute
         ``positions``, the real ones ``chunk_start .. total_len - 1``, over
         ONE ``table``; the chunk's own keys are already in the cache."""
+        if eva is not None:
+            # the row as one paged sequence, the chunk still at its tail
+            with jax.named_scope("eva_attend"):
+                view, lens = self._eva_view(kc, table[None], total_len[None], eva)
+                shift = total_len - lens[0]
+                return self.chunk(
+                    q, kc, vc, view[0], chunk_start - shift, lens[0],
+                    jnp.where(positions < total_len, positions - shift,
+                              lens[0] - 1),
+                )
         if latent is not None:
             return self._latent(
                 q, kc, vc, table[None], (total_len - chunk_start)[None],
@@ -211,12 +300,16 @@ class PagedAttention:
         )
 
     def ragged(self, q, kc, vc, tables, q_starts, q_lens, seq_lens,
-               dsa=None, latent=None, **extra):
+               dsa=None, latent=None, eva=None, **extra):
         """Ragged rows over a packed ``q [Tq, h, d]``: row ``r`` owns
         ``q[q_starts[r] : q_starts[r] + q_lens[r]]`` at the tail of its
         context (ops/attention.ragged_paged_attention has the contract).
         With a ``dsa`` or a ``latent`` the rows are the mixed step's: row 0 a chunk at the
         front of ``q``, every further row one token behind it."""
+        if eva is not None:
+            with jax.named_scope("eva_attend"):
+                view, lens = self._eva_view(kc, tables, seq_lens, eva)
+                return self.ragged(q, kc, vc, view, q_starts, q_lens, lens)
         if latent is not None:
             return self._latent(
                 q, kc, vc, tables, q_lens, seq_lens, latent,

@@ -216,8 +216,11 @@ def _decode_kernel(
     jax.lax.fori_loop(0, B, row_body, 0)
 
 
+KERNEL_NAME = "paged_decode_attention"
+
+
 @functools.partial(
-    jax.jit, static_argnames=("chunk_tokens", "interpret")
+    jax.jit, static_argnames=("chunk_tokens", "interpret", "name")
 )
 def paged_decode_attention(
     q: jax.Array,             # [B, h, d]
@@ -228,6 +231,7 @@ def paged_decode_attention(
     *,
     chunk_tokens: Optional[int] = None,
     interpret: bool = False,
+    name: str = KERNEL_NAME,
 ) -> jax.Array:
     """Ragged paged decode attention (Pallas). Same semantics as
     ``ops.attention.paged_decode_attention``; a row with ``seq_len == 0``
@@ -235,7 +239,9 @@ def paged_decode_attention(
     payload + per-block scales): the kernel DMAs the int8 pages plus their
     scale rows and dequantizes in-register, so the per-page HBM bytes halve
     vs bf16. ``chunk_tokens`` overrides the derived chunk size (tests: a
-    chunk loop over small contexts); no call site of the program sets it."""
+    chunk loop over small contexts); no call site of the program sets it.
+    ``name``: the launch's name on the device trace, for a family whose rows
+    are paged sequences of another make (ops/pallas_eva.py)."""
     B, h, d = q.shape
     nb, bs, kvh, _ = k_cache.shape
     max_blocks = block_tables.shape[1]
@@ -292,7 +298,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
         interpret=interpret,
-        name="paged_decode_attention",
+        name=name,
     )(
         block_tables.reshape(-1).astype(jnp.int32),
         seq_lens.astype(jnp.int32),

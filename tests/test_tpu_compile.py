@@ -273,6 +273,32 @@ def _cases():
                     vec, s((rows, 64), F32), s((rows,), jnp.bool_))
         return pallas_kda.kda_state_update, build
 
+    def eva(question, q_tokens, rows):
+        # EVA's attention at EvaByte's widths and the long-answer cell's
+        # sizes (PR 46): 32 heads x 128, multi-head, a ring of 128 pages and
+        # 5 summary blocks a row in a pool of 3 073 + 121 x 8 pages; decode
+        # rows are the launch ``eva_decode_attention``, a chunk and a mixed
+        # step the ragged launch over the rows as one paged sequence each
+        def fn(attn, *args):
+            *args, mu, phi = args
+            attn = PagedAttention(attn.mesh, True, summary_base=3073)
+            return getattr(attn, question)(
+                *args, eva=att.EvaQuery(mu, phi, 2048, 16))
+        fn.asks_seam = True
+
+        def build(sh):
+            s, *_ = _shapes(sh)
+            page = s((3073 + 121 * 8, BS, 32, D), BF)
+            q, vec = s((q_tokens, 32, D), BF), s((32, D), BF)
+            r = s((rows,), I32)
+            if question == "decode":
+                return (q, page, page, s((rows, 133), I32), r, vec, vec)
+            if question == "chunk":
+                return (q, page, page, s((133,), I32), s((), I32), s((), I32),
+                        s((q_tokens,), I32), vec, vec)
+            return (q, page, page, s((rows, 133), I32), r, r, r, vec, vec)
+        return fn, build
+
     def moves(fn, n_ids, with_pages):
         def build(sh):
             s, cache, _, _, ids = _shapes(sh)
@@ -338,6 +364,9 @@ def _cases():
         "unified-64q-8kv-reason-cell": unified_cell(
             64, 8, 512 + 128, 129, 130, 12288),
         "kda-state-update-rows128": kda_update(128),
+        "eva-decode-24-rows": eva("decode", 24, 24),
+        "eva-chunk-S512": eva("chunk", 512, 1),
+        "eva-mixed-S512-24-rows": eva("ragged", 536, 25),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),
@@ -460,6 +489,41 @@ def test_mixed_attend_copies_no_array_of_the_pools_size(chip_seam, case):
     each was 0.36 ms a 134 MB array, four a layer (PERF.md section 6, PR
     40)."""
     assert _pool_copies(chip_seam, chip_seam.write_chunk, case) == []
+
+
+def test_eva_mixed_attend_copies_no_array_of_the_pools_size(chip_seam):
+    """One layer's attend of a mixed step of the ring family (PR 46) at
+    EvaByte's widths: the chunk's pages and the rows' tokens written, the
+    chunk's summaries and the summaries of the pages the rows filled written
+    (a gather of those pages from the pool between the writes), then the
+    ragged launch over the rows as paged sequences. No ``copy`` of an array
+    of the pool's size (1 GB an array here)."""
+    B, S, h, pages = 24, 512, 32, 3073 + 121 * 8
+    seam = PagedAttention(chip_seam.mesh, True, summary_base=3073)
+
+    def attend(kc, vc, q, k_new, v_new, c_blocks, wb, wo, tables, q_lens, lens,
+               start, total, mu, phi):
+        e = att.EvaQuery(mu, phi, 2048, 16)
+        kc, vc = seam.write_chunk(kc, vc, k_new[:S], v_new[:S], c_blocks)
+        kc, vc = att.write_decode_kv(kc, vc, k_new[S:], v_new[S:], wb, wo)
+        kc, vc = seam.summarise_chunk(kc, vc, k_new[:S], v_new[:S], tables[0], start, total, e)
+        kc, vc = seam.summarise_rows(kc, vc, tables[1:], lens[1:], wb, wo, e)
+        q_starts = jnp.concatenate([jnp.zeros((1,), I32), S + jnp.arange(B, dtype=I32)])
+        return kc, vc, seam.ragged(q, kc, vc, tables, q_starts, q_lens, lens, eva=e)
+
+    s, *_ = _shapes(SingleDeviceSharding(seam.mesh.devices.flat[0]))
+    pool, new = s((pages, BS, h, D), BF), s((S + B, h, D), BF)
+    r, r1, vec = s((B,), I32), s((B + 1,), I32), s((h, D), BF)
+    text = jax.jit(attend, donate_argnums=(0, 1)).lower(
+        pool, pool, new, new, new, s((S // BS,), I32), r, r, s((B + 1, 133), I32),
+        r1, r1, s((), I32), s((), I32), vec, vec,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    found = re.findall(
+        r"^\s*(?:ROOT )?(\S+) = \(?\w+\[([\d,]+)\](\{[^}]*\})?.* copy(?:-start)?\(",
+        text, re.M)
+    assert [n for n, dims, _ in found
+            if math.prod(map(int, dims.split(","))) == pages * BS * h * D] == []
 
 
 @pytest.mark.parametrize("case,relaid", [
