@@ -13,11 +13,12 @@ rows, 128]`` in bf16, the latent in the first, ``[k_pe | 0]`` in row 0 of the
 second. The launch sees both as ``[tokens, rows, 128]``: a page of either is
 one copy of ``block_size`` whole tokens (of the second only row 0 is used; the
 rows behind it ride along: 2 048 bytes a key at 512 + 64 lanes, of which 1 152
-are the key). In VMEM a pair of rows shares a 32-bit word, so a chunk's
-buffers are cut into bf16 matrices by a shift and a mask
-(ops/pallas_sparse._halves) and laid side by side as ONE ``[chunk tokens, rank
-+ 128]`` matrix ``[c | k_pe | 0]``: the scores are one product against it and
-the values one product against its first ``rank`` lanes.
+are the key). In VMEM a pair of rows shares a 32-bit word, and so does a pair
+of TOKENS of a bf16 matrix: a chunk's buffers become ONE ``[chunk tokens, rank
++ 128]`` matrix ``[c | k_pe | 0]`` by integer moves alone (``_chunk_matrix``:
+the word-rows of even and of odd tokens, each a sublane-strided load of whole
+registers, a shift, a mask and an or): the scores are one product against it
+and the values one product against its first ``rank`` lanes.
 
 Rows of a launch, known when it is traced: optionally ONE chunk row (the
 first ``n_chunk`` packed queries, at the tail of ``tables[0]``'s context),
@@ -26,10 +27,14 @@ zeros back). A lone chunk, decode rows and the mixed step are the three
 shapes of it, each ONE launch, all named ``paged_latent_attention``.
 
 Grid: one program a tile of ``Q_TILE`` chunk queries (``Q_TILE * h`` rows
-through the matrix unit at once: bound by the products), then one a decode
-row (``h`` rows: a 128 x 128 array of the matrix unit streams 64 rows a load
-of its weights, so such a row runs well under the rate of its reads; PERF.md
-section 6, PR 33 has the nanoseconds). A program walks its row's pages up to
+through the matrix unit at once: a visit of a 1 024-key chunk is its products,
+13.6 us = 91% of the matrix unit's peak, the unpack and the copies under them),
+then one a decode row (``h`` rows: a visit is its copies, 2.7 us for 2 MiB =
+94% of the byte peak, the products and the unpack under them; PERF.md section
+6, PR 47 has the table. Until PR 47 the unpack ran one token a vector
+register and was 3.0 us of a decode visit's 5.9, 2.0 of a tile visit's 15.6:
+what the records called "the matrix unit streaming 64 rows a load of its
+weights" was that). A program walks its row's pages up to
 its last query's position in chunks of ``pallas_paged.chunk_pages`` pages, two
 slots: chunk ``c + 1`` is in flight while chunk ``c`` is computed. Online
 softmax in float32 scratch; only the chunks from a tile's first query's own
@@ -49,11 +54,13 @@ token view. Every other whole chunk is started page by page, two descriptors
 a page, ``UNROLL`` pages a pass; either way it is waited for ONCE an array (a
 DMA semaphore counts bytes). A tail chunk starts and waits page by page. On a
 v5e, 64 heads, 25 000-key contexts, launches chained inside one jit (PERF.md
-section 6, PR 34): 8 decode rows 1.199 ms as runs, 1.516 page by page (the
-products alone 1.147, the copies alone 0.43 either way); a 512-query chunk
-12.57 / 13.84 (12.39); a 320-query chunk + 8 rows 9.13 / 10.24 (8.93): a page's
-two descriptors cost a launch about 26 ns, a run's two about nothing. The
-output is bitwise the same whichever way a chunk came in.
+section 6, PR 34, with PR 33's unpack): 8 decode rows 1.199 ms as runs, 1.516
+page by page; a 512-query chunk 12.57 / 13.84; a 320-query chunk + 8 rows 9.13
+/ 10.24: a page's two descriptors cost a launch about 26 ns, a run's two about
+nothing. As runs since PR 47 (section 6, PR 47: a while loop of launches in
+one jit, which read the parent 0.891 / 12.40 / 8.20): 0.430 / 10.78 / 6.81,
+with the copies alone 0.408 / 2.31 / 1.73. The output is bitwise the same
+whichever way a chunk came in.
 """
 
 from __future__ import annotations
@@ -68,13 +75,14 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_paged as paged
 from .attention import LATENT_LANES
 from .pallas_paged import NEG_INF
-from .pallas_sparse import _halves
 
 KERNEL_NAME = "paged_latent_attention"
 # chunk queries a program: Q_TILE x heads rows against a chunk's keys. A lone
 # 512-query chunk over 25k keys at 64 heads ran 19.4 ms at 8, 15.0 at 16 on a
-# v5e (fewer re-reads of the context, more rows a load of the matrix unit's
-# weights; PERF.md section 6, PR 33)
+# v5e (PERF.md section 6, PR 33: what a wider tile halved was the unpack, paid
+# once a visit whatever the rows). Since PR 47 a visit at 16 is its products at
+# 91% of the matrix unit's peak with the unpack under them (10.78 ms the same
+# chunk, 10.78 with no unpack at all), so a wider tile has nothing left to take
 Q_TILE = 16
 # pages a pass of the loop that starts, page by page, a whole chunk that is not
 # a run (the scalar unit issues one descriptor after the other, in the
@@ -114,6 +122,43 @@ def chunk_reads(k_cache: jax.Array, tables: jax.Array, q_lens: jax.Array,
     runs = chunk_runs(tables, cp)
     read = jnp.arange(runs.shape[1])[None, :] < (n_pages // cp)[:, None]
     return jnp.sum(read), jnp.sum(read & runs)
+
+
+def _pack_pairs(even, odd):
+    """``[n, 128]`` uint32 word-rows of ``n`` even and ``n`` odd tokens (a
+    word holds two rows of its token: row ``2w`` the low half, ``2w + 1`` the
+    high half) -> those two rows as bf16 matrices ``[2n, 128]`` in the words
+    VMEM keeps such a matrix in (token ``2i`` the low half of word-row ``i``,
+    ``2i + 1`` the high half): the same bits moved, no float touched."""
+    low, high = jnp.uint32(0xFFFF), jnp.uint32(0xFFFF0000)
+    return (odd << 16) | (even & low), (odd & high) | (even >> 16)
+
+
+def _chunk_matrix(k_words, v_words, kcat, slot, T: int, lat_rows: int):
+    """The chunk in ``slot`` as ``[c | k_pe | 0]``, written into ``kcat``
+    (``[T / 2, rank + 128]`` uint32: the words of a ``[T, rank + 128]`` bf16
+    matrix). ``k_words`` / ``v_words``: the page buffers as ``[2 T rows / 2,
+    128]`` uint32, a token's word-rows one after the other, so a word-row of
+    every second token is ONE sublane-strided load a vector register, eight
+    tokens in it. Keep the read 2-D and strided: a slice ``[slot, :, w, :]``
+    of the 4-D buffer comes back one token a register with a rotate and a
+    select a token, 45 000 vector instructions a visit where these are 3 600
+    (PERF.md section 6, PR 47; tests/test_tpu_compile.py counts them)."""
+    nw = lat_rows // 2                       # word-rows a token
+    rank = lat_rows * LATENT_LANES
+    base = slot * (T * nw)
+
+    def pairs(words, w):
+        return _pack_pairs(
+            words[pl.ds(base + w, T // 2, stride=2 * nw), :],
+            words[pl.ds(base + nw + w, T // 2, stride=2 * nw), :],
+        )
+
+    for w in range(nw):
+        for half, x in enumerate(pairs(k_words, w)):
+            lane0 = (2 * w + half) * LATENT_LANES
+            kcat[:, lane0:lane0 + LATENT_LANES] = x
+    kcat[:, rank:] = pairs(v_words, 0)[0]
 
 
 class _LatentPages(paged.PageReader):
@@ -201,7 +246,8 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
     o1_ref = next(it) if n_one else None   # VMEM [1, h, rank]
     k_buf = next(it)        # VMEM [2, T, rows, 128] bf16
     v_buf = next(it)        # VMEM [2, T, rows, 128] bf16
-    kcat = next(it)         # VMEM [T, rank + 128] bf16: [c | k_pe | 0]
+    kcat = next(it)         # VMEM [T / 2, rank + 128] uint32: the words of
+    #                         the bf16 matrix [T, rank + 128] = [c | k_pe | 0]
     m_scr = next(it)        # VMEM [M, 1] f32
     l_scr = next(it)        # VMEM [M, 1] f32
     acc_scr = next(it)      # VMEM [M, rank] f32
@@ -213,8 +259,11 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
     i = pl.program_id(0)
     pages = _LatentPages(
         tables_ref, runs_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bs, cp)
-    k_words = k_buf.bitcast(jnp.uint32)     # [2, T, rows / 2, 128]
-    v_words = v_buf.bitcast(jnp.uint32)     # [2, T, rows / 2, 128]
+    # both slots as word-rows, a token's rows / 2 one after the other
+    n_words = 2 * T * (lat_rows // 2)
+    k_words = k_buf.bitcast(jnp.uint32).reshape(n_words, LATENT_LANES)
+    v_words = v_buf.bitcast(jnp.uint32).reshape(n_words, LATENT_LANES)
+    kmat = kcat.bitcast(k_buf.dtype)        # [T, rank + 128] bf16
     n_runs = mb // cp                       # runs_ref entries a row
 
     @pl.when(i == 0)
@@ -223,15 +272,6 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
         # the buffer holds zeros until it holds a token, never what VMEM
         # held before the launch
         k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
-
-    def split(slot):
-        """The chunk in ``slot`` as one ``[T, rank + 128]`` bf16 matrix."""
-        for w in range(lat_rows // 2):
-            for half, x in enumerate(_halves(k_words[slot, :, w, :])):
-                lane0 = (2 * w + half) * LATENT_LANES
-                kcat[:, lane0:lane0 + LATENT_LANES] = x
-        pe, _ = _halves(v_words[slot, :, 0, :])
-        kcat[:, rank:] = pe
 
     def attend(r, q, q_pos0, n_valid, kv_end):
         """``q [M, rank + 128]``: ``M / h`` queries of row ``r``, all heads,
@@ -264,9 +304,9 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
                             r * n_runs + c + 1)
 
             pages.wait(count(c), slot)
-            split(slot)
+            _chunk_matrix(k_words, v_words, kcat, slot, T, lat_rows)
             s = jax.lax.dot_general(
-                q, kcat[...], (((1,), (1,)), ((), ())),
+                q, kmat[...], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale                                           # [M, T]
             if masked:
@@ -285,7 +325,7 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
             l_scr[rows] = alpha * l_scr[rows] + jnp.sum(
                 p, axis=-1, keepdims=True)
             acc_scr[rows] = alpha * acc_scr[rows] + jnp.dot(
-                p.astype(kcat.dtype), kcat[:, :rank],
+                p.astype(kmat.dtype), kmat[:, :rank],
                 preferred_element_type=jnp.float32,
             )
             return carry
@@ -350,11 +390,12 @@ def paged_latent_attention(
     lat_rows = rank // lanes
     if (k_cache.dtype != jnp.bfloat16 or q.dtype != jnp.bfloat16
             or lanes != LATENT_LANES or rank % (2 * lanes)
-            or n_rows != lat_rows):
+            or n_rows != lat_rows or bs % 2):
         raise ValueError(
             "paged_latent_attention reads bf16 pages of 128 lanes a row, the "
-            f"latent an even number of rows; got {k_cache.dtype} "
-            f"{k_cache.shape}, q {q.dtype}, latent rank {rank}"
+            "latent an even number of rows, a page an even number of tokens; "
+            f"got {k_cache.dtype} {k_cache.shape}, q {q.dtype}, latent rank "
+            f"{rank}"
         )
     n_one = Tq - n_chunk
     if n_one != R - (1 if n_chunk else 0):
@@ -396,7 +437,7 @@ def paged_latent_attention(
             scratch_shapes=[
                 pltpu.VMEM((2, T, n_rows, lanes), k_cache.dtype),
                 pltpu.VMEM((2, T, n_rows, lanes), v_cache.dtype),
-                pltpu.VMEM((T, width), k_cache.dtype),
+                pltpu.VMEM((T // 2, width), jnp.uint32),
                 pltpu.VMEM((M, 1), jnp.float32),
                 pltpu.VMEM((M, 1), jnp.float32),
                 pltpu.VMEM((M, rank), jnp.float32),

@@ -458,8 +458,36 @@ def test_a_mixed_step_is_one_question(seams):
     assert text.count("pallas_call") == 1 and plat.KERNEL_NAME in text
 
 
+def _parents_chunk_matrix(k_words, v_words, kcat, slot, T, lat_rows):
+    """The unpack as PR 33 wrote it and PR 47 found it (45 000 vector
+    instructions a visit on the chip: a word-row of EVERY token out of the 4-D
+    buffer, one token a register, a float32 round trip a half), kept as the
+    twin of ``pallas_latent._chunk_matrix``: the same matrix, bit for bit."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dynamo_tpu.ops.pallas_sparse import _halves
+
+    nw = lat_rows // 2
+    k4, v4 = (w.reshape(2, T, nw, 128) for w in (k_words, v_words))
+    for w in range(nw):
+        for half, x in enumerate(_halves(k4[slot, :, w, :])):
+            lane0 = (2 * w + half) * 128
+            kcat[:, lane0:lane0 + 128] = pltpu.bitcast(x, jnp.uint32)
+    pe, _ = _halves(v4[slot, :, 0, :])
+    kcat[:, lat_rows * 128:] = pltpu.bitcast(pe, jnp.uint32)
+
+
+def _launch_with_the_parents_unpack(monkeypatch, *args, **kw):
+    """The launch traced anew (no jit cache) around the parent's unpack."""
+    with monkeypatch.context() as m:
+        m.setattr(plat, "_chunk_matrix", _parents_chunk_matrix)
+        return jax.jit(plat.paged_latent_attention.__wrapped__,
+                       static_argnames=("scale", "n_chunk", "interpret"))(*args, **kw)
+
+
+@pytest.mark.parametrize("lat_rows", [2, 4])
 @pytest.mark.parametrize("chunk_pages", [2, 5, 8])
-def test_the_kernel_across_chunks_and_tails(monkeypatch, chunk_pages):
+def test_the_kernel_across_chunks_and_tails(monkeypatch, chunk_pages, lat_rows):
     """``pallas_latent.paged_latent_attention`` interpreted, against the
     twin, with chunks of 2, 5 and 8 pages (8: a whole chunk's copies start
     ``UNROLL`` pages a pass) over tables of three chunks: contexts that end 1,
@@ -468,22 +496,28 @@ def test_the_kernel_across_chunks_and_tails(monkeypatch, chunk_pages):
     (not a whole tile) whose last tile is all padding. The interpreter's
     semaphore saturates where a whole chunk's wait is large (tests/
     test_mla_dsa.py has the note): tier-1 holds the answers, chip_smoke.py
-    the waits."""
+    the waits. At 2 rows a token (one word-row: the strided loads take every
+    second word) and at the cell's 4 (two); both launches bitwise what the
+    parent's unpack gives on the same buffers."""
     from dynamo_tpu.ops import pallas_paged as paged
 
     monkeypatch.setattr(paged, "chunk_pages", lambda *a: chunk_pages)
     T, mb = chunk_pages * BS, 3 * chunk_pages
+    RANK = lat_rows * 128
     rng = np.random.default_rng(chunk_pages)
-    kc = jnp.asarray(rng.normal(size=(3 * mb + 1, BS, ROWS, 128)), jnp.bfloat16)
-    vc = jnp.asarray(rng.normal(size=(3 * mb + 1, BS, ROWS, 128)), jnp.bfloat16)
+    kc = jnp.asarray(rng.normal(size=(3 * mb + 1, BS, lat_rows, 128)), jnp.bfloat16)
+    vc = jnp.asarray(rng.normal(size=(3 * mb + 1, BS, lat_rows, 128)), jnp.bfloat16)
     tables = jnp.asarray(rng.permutation(np.arange(1, 3 * mb + 1)).reshape(3, mb), jnp.int32)
     lens = [T + 1, 2 * T + 15, T + 17, 2 * T, 3, mb * BS, 0]
     tb = jnp.concatenate([tables, tables, tables[:1]])
     q = jnp.asarray(rng.normal(size=(len(lens), H, RANK + 128)), jnp.bfloat16)
     q_lens = jnp.asarray([int(n > 0) for n in lens], jnp.int32)
     seq = jnp.asarray(lens, jnp.int32)
-    got = np.asarray(plat.paged_latent_attention(
-        q, kc, vc, tb, q_lens, seq, scale=0.125, interpret=True), np.float32)
+    args = (q, kc, vc, tb, q_lens, seq)
+    got = plat.paged_latent_attention(*args, scale=0.125, interpret=True)
+    assert bool(jnp.all(got == _launch_with_the_parents_unpack(
+        monkeypatch, *args, scale=0.125, interpret=True)))
+    got = np.asarray(got, np.float32)
     want = np.asarray(att.paged_latent_attention(
         q, kc, vc, tb, jnp.arange(len(lens)), q_lens, seq, 0.125), np.float32)
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
@@ -492,8 +526,11 @@ def test_the_kernel_across_chunks_and_tails(monkeypatch, chunk_pages):
     qc = jnp.asarray(rng.normal(size=(24 + 2, H, RANK + 128)), jnp.bfloat16)
     q_lens = jnp.asarray([13, 1, 1], jnp.int32)
     seq = jnp.asarray([2 * T + 7, T, 1], jnp.int32)
-    got = np.asarray(plat.paged_latent_attention(
-        qc, kc, vc, tables, q_lens, seq, scale=0.125, n_chunk=24, interpret=True), np.float32)
+    args = (qc, kc, vc, tables, q_lens, seq)
+    got = plat.paged_latent_attention(*args, scale=0.125, n_chunk=24, interpret=True)
+    assert bool(jnp.all(got == _launch_with_the_parents_unpack(
+        monkeypatch, *args, scale=0.125, n_chunk=24, interpret=True)))
+    got = np.asarray(got, np.float32)
     want = np.asarray(att.paged_latent_attention(
         qc, kc, vc, tables, jnp.asarray([0, 24, 25]), q_lens, seq, 0.125), np.float32)
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
@@ -546,6 +583,22 @@ RUN_SHAPES = {
     "decode_rows": (0, [1, 1, 1], lambda T: [T + 1, 3 * T, 2 * T]),
     "mixed_launch": (24, [13, 1, 1], lambda T: [3 * T, 2 * T + 15, T]),
 }
+# PR 47: what the unpack has to hold, at the cell's 4 rows a token (two
+# word-rows, so a strided load skips a word): each bitwise the parent's unpack.
+# A tail chunk behind a longer row (its buffer rows past the tail hold the
+# longer row's tokens, or the zeros of the first program), a one-token
+# context, an empty row, tails of odd and of even token counts, and last pages
+# whose tokens past the context's end are another request's (planted: large
+# and finite; their weight is an exact 0)
+UNPACK_SHAPES = {
+    "a_tail_chunk_behind_a_longer_row": (0, [1, 1, 1], lambda T: [3 * T, BS + 3, 2 * T + BS]),
+    "a_one_token_context": (0, [1, 1, 1], lambda T: [1, T + 1, 2]),
+    "an_empty_row_between_tails": (24, [13, 0, 1], lambda T: [T + 9, 0, 5]),
+    "an_odd_tail": (0, [1, 1, 1], lambda T: [T + 7, 2 * T + BS + 1, 5]),
+    "an_even_tail": (0, [1, 1, 1], lambda T: [T + 6, 2 * T + BS + 2, 4]),
+    "a_partly_stale_last_page": (24, [13, 1, 1], lambda T: [2 * T + 3, T + BS + 5, 3 * T - 1]),
+}
+RUN_SHAPES.update(UNPACK_SHAPES)
 
 
 def _numpy_runs(tables, cp):
@@ -565,12 +618,15 @@ def test_a_run_of_pages_is_read_as_one_copy_and_changes_no_bit(monkeypatch, case
     are runs, that are not, that break a run at a chunk's first, middle or
     last page or only in its middle, that descend, that run across a chunk's
     edge; for a row whose only chunk is a tail, an empty row, a lone chunk,
-    decode rows and the mixed launch; the counter reads what the tables hold."""
+    decode rows and the mixed launch; the counter reads what the tables hold.
+    ``UNPACK_SHAPES`` at 4 rows a token; every case bitwise the parent's unpack."""
     from dynamo_tpu.ops import pallas_paged as paged
 
     cp = chunk_pages
     monkeypatch.setattr(paged, "chunk_pages", lambda *a: cp)
     T = cp * BS
+    ROWS = 4 if case in UNPACK_SHAPES else 2
+    RANK = ROWS * 128
     n_chunk, q_lens, lens = RUN_SHAPES.get(case, RUN_SHAPES["mixed_launch"])
     lens = lens(T)
     tables = RUN_TABLES.get(case, RUN_TABLES["all_runs"])(cp)[:len(lens)]
@@ -578,6 +634,12 @@ def test_a_run_of_pages_is_read_as_one_copy_and_changes_no_bit(monkeypatch, case
     rng = np.random.default_rng(cp)
     kc, vc = (jnp.asarray(rng.normal(size=(nb, BS, ROWS, 128)), jnp.bfloat16) for _ in range(2))
     q = jnp.asarray(rng.normal(size=(n_chunk + len(lens) - bool(n_chunk), H, RANK + 128)), jnp.bfloat16)
+    if case == "a_partly_stale_last_page":
+        stale = np.zeros((nb, BS), bool)
+        for row, n in zip(tables, lens):
+            stale[row[(n - 1) // BS], n % BS:] = bool(n % BS)
+        kc, vc = (jnp.where(stale[:, :, None, None], 1e4, x).astype(x.dtype) for x in (kc, vc))
+        assert stale.sum() == sum(BS - n % BS for n in lens if n % BS)
     q_lens, seq = jnp.asarray(q_lens, jnp.int32), jnp.asarray(lens, jnp.int32)
 
     def launch(kc, vc, tables):
@@ -593,6 +655,9 @@ def test_a_run_of_pages_is_read_as_one_copy_and_changes_no_bit(monkeypatch, case
     back = np.argsort(place)
     got, want = launch(kc, vc, tables), launch(kc[back], vc[back], place[tables])
     assert bool(jnp.all(got == want))
+    assert bool(jnp.all(got == _launch_with_the_parents_unpack(
+        monkeypatch, q, kc, vc, jnp.asarray(tables, jnp.int32), q_lens, seq, scale=0.125,
+        n_chunk=n_chunk, interpret=True)))
     starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), n_chunk + jnp.arange(len(lens) - 1)]) \
         if n_chunk else jnp.arange(len(lens))
     twin = att.paged_latent_attention(q, kc, vc, jnp.asarray(tables), starts, q_lens, seq, 0.125)
@@ -621,5 +686,7 @@ def test_the_launch_refuses_what_it_cannot_read():
     ones = jnp.ones((3,), jnp.int32)
     with pytest.raises(ValueError, match="bf16 pages"):
         plat.paged_latent_attention(q, kc.astype(jnp.float32), vc, tables, ones, ones, scale=1.0)
+    with pytest.raises(ValueError, match="even number of tokens"):
+        plat.paged_latent_attention(q, kc[:, :15], vc[:, :15], tables, ones, ones, scale=1.0)
     with pytest.raises(ValueError, match="do not make"):
         plat.paged_latent_attention(q, kc, vc, tables, ones, ones, scale=1.0, n_chunk=2)

@@ -13,6 +13,7 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
+import collections
 import functools
 import math
 import re
@@ -36,7 +37,30 @@ BF, I32, F32 = jnp.bfloat16, jnp.int32, jnp.float32
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def mosaic_dump(tmp_path_factory):
+    """Where Mosaic writes its passes for every kernel this module compiles:
+    the flag is read once, when the library starts, so it is set before `v5e`
+    describes a chip; `_empty_mosaic_dump` empties the directory after each
+    test (all of this module's kernels would leave 2 GB)."""
+    was = os.environ.get("LIBTPU_INIT_ARGS")
+    dump = tmp_path_factory.mktemp("mosaic")
+    os.environ["LIBTPU_INIT_ARGS"] = f"{was or ''} --xla_mosaic_dump_to={dump}"
+    yield dump
+    if was is None:
+        os.environ.pop("LIBTPU_INIT_ARGS", None)
+    else:
+        os.environ["LIBTPU_INIT_ARGS"] = was
+
+
+@pytest.fixture(autouse=True)
+def _empty_mosaic_dump(mosaic_dump):
+    yield
+    for f in mosaic_dump.iterdir():
+        f.unlink()
+
+
+@pytest.fixture(scope="module")
+def v5e(mosaic_dump):
     """Devices of a described v5e 2x2, with the persistent compilation cache
     off around the module (an entry written for a described chip cannot be
     read back without one; the next compile would warn and redo it)."""
@@ -386,6 +410,50 @@ def test_kernel_compiles_for_v5e(v5e, chip_seam, name):
     args = build(SingleDeviceSharding(v5e[0]))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _loop_bodies(llo: str, at_least: int = 1000):
+    """Mosaic's last pass as text -> a Counter of ``llo.`` operations for each
+    ``scf.for`` body of ``at_least`` lines (a chunk visit of a kernel that
+    walks chunks; nested loops counted with the body that holds them)."""
+    lines, open_loops, bodies = llo.split("\n"), [], []
+    for i, line in enumerate(lines):
+        text = line.lstrip()
+        indent = len(line) - len(text)
+        if text.startswith("scf.for"):
+            open_loops.append((indent, i))
+        elif text.startswith("}") and open_loops and open_loops[-1][0] == indent:
+            _, start = open_loops.pop()
+            if i - start >= at_least:
+                bodies.append(collections.Counter(
+                    op for l in lines[start:i]
+                    for op in re.findall(r"llo\.([a-z_.0-9]+)", l)))
+    return bodies
+
+
+def test_a_latent_chunk_visit_unpacks_whole_registers(v5e, chip_seam, mosaic_dump):
+    """ISSUE 47's tripwire, no chip needed. The decode launch at the
+    document-QA cell's shapes, by Mosaic's own dump: a chunk visit's body (the
+    two chunk loops, masked and not) reads its word-rows with sublane-strided
+    loads and holds no sublane rotate and no select but the causal mask's
+    (136 in the masked body). Read as ``k_words[slot, :, w, :]`` the unpack
+    came back one token a vector register: 4 480 rotates, 4 480 selects and
+    45 000 vector instructions a visit, half of a decode row's time on the
+    chip (PERF.md section 6, PR 47)."""
+    fn, build = CASES["paged-latent-decode"]
+    jax.jit(functools.partial(fn, chip_seam)).lower(
+        *build(SingleDeviceSharding(v5e[0]))).compile()
+    last = sorted(mosaic_dump.glob("*paged_latent_attention*finalize-llo*"))
+    if not last:
+        pytest.skip("this libtpu wrote no Mosaic dump (--xla_mosaic_dump_to)")
+    visits = _loop_bodies(last[-1].read_text())
+    assert len(visits) == 2, [sum(v.values()) for v in visits]
+    for ops in visits:
+        vector = sum(n for op, n in ops.items()
+                     if op.startswith("v") and op != "vbitcast")
+        assert ops["vmatmul"] and ops["vector_load_slane_stride"] == 384, ops
+        assert ops["vrot.slane"] + ops["vselect"] <= 256, ops
+        assert vector <= 8000, (vector, ops)
 
 
 def test_sharded_decode_compiles_on_tp4_mesh(v5e):
