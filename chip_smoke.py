@@ -849,6 +849,48 @@ def _kernel_child() -> None:
         [dctx, dctx, 24577, 17, 0, dctx + 15, 1, 24591, dctx],
     )
 
+    # the selection's read of the index keys (PR 48) at the long-document
+    # cell's shapes, given pages (no scoring, no top-k): the launch
+    # ``paged_index_keys`` against the twin's slice of row 1, bitwise, over
+    # tables that are runs and over the same pages at shuffled places, 8
+    # tables (decode rows) and 1 (a lone chunk), and tables of 1 590 pages
+    # (24 whole chunks and a tail of 54); ms a launch and GB/s of the 768 B a
+    # key it reads and writes, beside the twin's (20 dispatches back to back,
+    # the best of 3: the twin re-tiles the whole second array in each)
+    twin_keys = jax.jit(att.paged_index_keys, static_argnums=2)
+
+    def back_to_back(fn, *args):
+        best = float("inf")
+        for _ in range(3):
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            jax.block_until_ready([fn(*args) for _ in range(20)])
+            best = min(best, (time.perf_counter() - t0) / 20)
+        return best
+
+    for kind, _, aux_pool, pool_tables in layouts:
+        for n_tables, width in ((8, DMB), (1, DMB), (2, 1590)):
+            tb = pool_tables[:n_tables, :width]
+            whole, as_runs = psp.index_chunk_reads(tb)
+            if int(as_runs) != (int(whole) if kind == "runs" else 0):
+                raise SystemExit(f"index_keys, {kind}: {int(as_runs)} of "
+                                 f"{int(whole)} whole chunks are runs")
+            got = psp.paged_index_keys(aux_pool, tb, 128)
+            want = twin_keys(aux_pool, tb, 128)
+            if got.shape != want.shape or not bool(jnp.all(
+                    jax.lax.bitcast_convert_type(got, jnp.uint16)
+                    == jax.lax.bitcast_convert_type(want, jnp.uint16))):
+                raise SystemExit(f"index_keys, {kind}, {n_tables} tables of "
+                                 f"{width} pages: not the twin's bits")
+            t_launch = back_to_back(psp.paged_index_keys, aux_pool, tb, 128)
+            t_twin = back_to_back(twin_keys, aux_pool, tb, 128)
+            gb = n_tables * width * BS * 768 / 1e9
+            print(f"KERNEL index_keys {n_tables} tables x {width} pages, {kind} "
+                  f"({int(as_runs)} of {int(whole)} whole chunks one "
+                  f"descriptor): bitwise the twin; {t_launch * 1e3:.3f} ms a "
+                  f"launch, {gb / t_launch:.0f} GB/s; the twin "
+                  f"{t_twin * 1e3:.3f} ms", flush=True)
+
     # the state-space mixer's decode recurrence (PR 39) at Falcon-H1-34B's
     # widths, 128 rows of which some are dead: the state in place, live rows
     # only; and the decode question at the family's 5 query heads a kv head

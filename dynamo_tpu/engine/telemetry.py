@@ -276,6 +276,13 @@ class StepStats:
     dsa_keys_causal: Optional[int] = None
     dsa_keys_scored: Optional[int] = None
     dsa_keys_selected: Optional[int] = None
+    # ... and how the selecting layers read their index keys out of the pages
+    # (ops/pallas_sparse.py ``paged_index_keys``): the whole chunks of pages
+    # under the step's tables, summed over selecting layers, and those of
+    # them whose pages lie one after the other in the pool, read with one
+    # descriptor: counted from the step's own tables
+    dsa_index_chunks_whole: Optional[int] = None
+    dsa_index_chunks_run: Optional[int] = None
     # a latent cache WITHOUT an indexer (an MlaConfig held as rows): the
     # keys the step's real decode rows attended over (each its whole
     # context), and those rows, both summed over layers; the same readback
@@ -342,11 +349,12 @@ def moe_load_imbalance(s: StepStats) -> Optional[float]:
     return s.moe_load_max * s.moe_experts_touched / s.moe_tokens_routed
 
 
-def run_chunk_share(steps) -> Optional[float]:
-    """Of the whole chunks the steps' latent rows read, the share read as
-    runs of consecutive pages; None where none was read."""
-    whole = sum(s.mla_chunks_whole or 0 for s in steps)
-    run = sum(s.mla_chunks_run or 0 for s in steps)
+def run_chunk_share(steps, reader: str = "mla") -> Optional[float]:
+    """Of the whole chunks the steps' latent rows read (``reader`` "mla") or
+    their indexers' keys were read by ("dsa_index"), the share read as runs
+    of consecutive pages; None where none was read."""
+    whole = sum(getattr(s, f"{reader}_chunks_whole") or 0 for s in steps)
+    run = sum(getattr(s, f"{reader}_chunks_run") or 0 for s in steps)
     return run / whole if whole else None
 
 
@@ -491,6 +499,8 @@ class EngineTelemetry:
                     "keys_causal": moe.dsa_keys_causal,
                     "keys_scored": moe.dsa_keys_scored,
                     "keys_selected": moe.dsa_keys_selected,
+                    "index_run_chunk_share": run_chunk_share(
+                        recent, "dsa_index"),
                 }
             if moe.mla_keys_attended is not None:
                 # the last such step's dense latent reads

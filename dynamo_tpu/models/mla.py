@@ -556,6 +556,10 @@ def _rows_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
     )
     if cfg.index_topk > 0:
         carry["selected"] = dsa.selected
+        if dsa.index_chunk_reads is not None:
+            # the same tables in every layer: one selecting layer's count
+            # is each's
+            carry["index_chunk_reads"] = dsa.index_chunk_reads
     else:
         # the same tables in every layer: the last layer's count is each's
         carry["chunk_reads"] = ask["latent"].chunk_reads
@@ -639,7 +643,8 @@ def read_counters(cfg: MlaConfig) -> Tuple[str, ...]:
     if not cfg.latent_rows:
         return ()
     if cfg.index_topk > 0:
-        return ("dsa_keys_causal", "dsa_keys_scored", "dsa_keys_selected")
+        return ("dsa_keys_causal", "dsa_keys_scored", "dsa_keys_selected",
+                "dsa_index_chunks_whole", "dsa_index_chunks_run")
     return ("mla_keys_attended", "mla_decode_rows", "mla_chunks_whole",
             "mla_chunks_run")
 
@@ -658,7 +663,8 @@ def forward(
 ) -> jax.Array:
     """``stats`` (moe.RoutingStats): the one-chip grouped expert path counts
     its routing into it, a configuration with an indexer the keys its real
-    decode rows saw, scored and attended, and a rows-layout latent without
+    decode rows saw, scored and attended and the whole chunks of pages its
+    indexers' keys were read by, of which as runs, and a rows-layout latent without
     one the keys its real decode rows attended over, those rows, and the
     whole chunks of pages its rows read, of which as runs."""
     if lora is not None:
@@ -679,14 +685,20 @@ def forward(
         seen = jnp.where(rows, positions.reshape(-1) + 1, 0)
         if cfg.index_topk > 0:
             # the keys a row could see, those an indexer scored (selecting
-            # layers only) and those attended over
+            # layers only) and those attended over; the whole chunks of
+            # pages the selecting layers' read of the index keys took (the
+            # step's tables to their last page, a mixed step's chunk row
+            # too) and those read as runs
             n_sel = sum(_selects(cfg, i) for i in range(cfg.num_layers))
+            whole, run = carry["index_chunk_reads"]
             stats.add_reads(
                 dsa_keys_causal=seen.sum() * cfg.num_layers,
                 dsa_keys_scored=seen.sum() * n_sel,
                 dsa_keys_selected=(
                     jnp.minimum(seen, cfg.index_topk).sum() * cfg.num_layers
                 ),
+                dsa_index_chunks_whole=whole * n_sel,
+                dsa_index_chunks_run=run * n_sel,
             )
         else:
             # the keys attended over (each row its whole context), the rows;

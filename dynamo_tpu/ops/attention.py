@@ -485,14 +485,19 @@ class DsaQuery:
     seam scores them against the paged index keys, takes the ``topk`` largest
     causal scores a query and leaves the positions in ``selected`` [Tq, K]
     (``SEL_NONE`` pads). A layer that shares a selection hands the one it
-    inherited in ``selected`` and no ``index_q``. A trace-time object: it
-    lives for one forward pass of one program."""
+    inherited in ``selected`` and no ``index_q``. Where it selects, the seam
+    also leaves in ``index_chunk_reads`` what its read of the index keys
+    takes by the chunk: the whole chunks of pages under the rows' tables and
+    those of them that are runs of consecutive pages (two scalars;
+    ops/pallas_sparse.index_chunk_reads). A trace-time object: it lives for
+    one forward pass of one program."""
 
     scale: float                       # softmax scale, 1/sqrt(qk_head_dim)
     topk: int
     index_q: Optional[jax.Array] = None
     index_w: Optional[jax.Array] = None
     selected: Optional[jax.Array] = None
+    index_chunk_reads: Optional[Tuple[jax.Array, jax.Array]] = None
 
 
 @dataclasses.dataclass
@@ -547,7 +552,10 @@ def dsa_select(scores: jax.Array, q_pos: jax.Array, q_valid: jax.Array,
 
 def paged_index_keys(v_cache: jax.Array, tables: jax.Array, dim: int) -> jax.Array:
     """The index keys (their first ``dim`` lanes) of each table's context:
-    [R, max_blocks * bs, dim]."""
+    [R, max_blocks * bs, dim]. The pure-JAX twin of
+    ``ops.pallas_sparse.paged_index_keys``: row 1 of a token shares its
+    32-bit words with row 0 in the pool's tiling, so on a TPU this slice
+    re-tiles the whole array first."""
     keys = v_cache[tables, :, 1, :dim]               # [R, mb, bs, dim]
     return keys.reshape(tables.shape[0], -1, dim)
 

@@ -28,7 +28,10 @@ straight through.
   paged index keys (or take the selection the layer inherited), then attend
   each query over its own selected token rows. Pallas on, that is the launch
   ``sparse_latent_attention`` (ops/pallas_sparse.py) for decode rows, a chunk
-  and a mixed step alike; the scoring and the top-k stay XLA under the scopes
+  and a mixed step alike, and the index keys come out of the pages by the
+  launch ``paged_index_keys`` beside it (the one tile a token that holds
+  them, by runs of pages; the twin's slice of row 1 has XLA re-tile the whole
+  second array first); the scoring and the top-k stay XLA under the scopes
   ``dsa_index`` and ``dsa_select``. The seam tells the launch how many of
   its first queries are ONE row's chunk (``n_chunk``): the kernel stages
   that row's pages in VMEM once and those queries pick their keys there,
@@ -98,13 +101,24 @@ class PagedAttention:
         the context of ``tables[rows[i]]`` (``q_valid`` false = padding or
         an empty row: nothing selected, zeros back). The first ``n_chunk``
         queries are one row's chunk and are scored against that row's keys
-        in one product; every later query is a row of its own."""
+        in one product; every later query is a row of its own. A layer that
+        selects leaves in ``dsa.index_chunk_reads`` what the read of the
+        index keys takes of these tables whole and as runs (the step's
+        counters; dead code where no one reads them)."""
+        from . import pallas_sparse as ps
+
         if dsa.selected is None:
             Tq = q.shape[0]
             iq = dsa.index_q.reshape(Tq, *dsa.index_q.shape[-2:])
             iw = dsa.index_w.reshape(Tq, -1)
             with jax.named_scope("dsa_index"):
-                keys = att.paged_index_keys(vc, tables, iq.shape[-1])
+                dsa.index_chunk_reads = ps.index_chunk_reads(tables)
+                if self.use_pallas:
+                    keys = ps.paged_index_keys(
+                        vc, tables, iq.shape[-1], interpret=self.interpret
+                    )
+                else:
+                    keys = att.paged_index_keys(vc, tables, iq.shape[-1])
                 parts = []
                 if n_chunk:
                     parts.append(att.dsa_index_scores(
@@ -125,8 +139,6 @@ class PagedAttention:
                 return att.sparse_latent_attention(
                     q, kc, vc, tables, rows, dsa.selected, dsa.scale
                 )
-            from . import pallas_sparse as ps
-
             return ps.sparse_latent_attention(
                 q, kc, vc, tables, rows, dsa.selected, scale=dsa.scale,
                 n_chunk=n_chunk, interpret=self.interpret,

@@ -49,6 +49,25 @@ one of two ways, the products after it are the same:
 
 Every launch carries the name ``sparse_latent_attention``: the device trace
 and the benchmark's roofline reader find it by that name.
+
+A second launch of this file, ``paged_index_keys``, serves the selection in
+front of the attention: it copies that same ``[t, 0]`` tile of every token of
+each table's pages (the twin ``ops/attention.paged_index_keys`` slices row 1
+out of the 4-D pool, which XLA cannot do in place: it re-tiles the whole
+array first, 235 MB in and out at GLM-5.2's pool, every ``full`` layer of
+every step; PERF.md section 6, PR 48) and writes the index keys, the high
+halves of the tile's words, as the dense ``[R, mb * bs, 128]`` bf16 array the
+scoring product takes. Grid: one program a chunk of ``INDEX_CHUNK_PAGES``
+pages of one table, in order, two slots: the next program's chunk is in
+flight while this one is unpacked. A whole chunk whose table entries are
+consecutive block ids (``pallas_latent.chunk_runs``, scalar-prefetched) is ONE
+strided descriptor, any other ``INDEX_UNROLL`` pages a pass, both waited for
+once (a DMA semaphore counts bytes); a table's tail chunk goes page by page.
+The unpack is integer moves on whole registers (PR 47's lesson,
+``pallas_latent._chunk_matrix``): a token is one word-row of the buffer read
+2-D, even and odd tokens come in as two sublane-strided loads and leave as
+the words of a bf16 matrix, ``(odd & 0xFFFF0000) | (even >> 16)``; no float
+is touched, so whatever ``k_pe`` holds beside a key (a NaN too) stays out.
 """
 
 from __future__ import annotations
@@ -61,6 +80,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import LATENT_LANES, selected_token_rows
+from .pallas_latent import _pack_pairs, chunk_runs
 from .pallas_paged import NEG_INF
 
 KERNEL_NAME = "sparse_latent_attention"
@@ -74,6 +94,33 @@ UNROLL = 16
 # the most a launch stages of one row's context (a v5e core has 128 MiB of
 # VMEM; the buffers, the query blocks and Mosaic's own scratch take the rest)
 STAGED_VMEM_BYTES = 96 * 1024 * 1024
+
+INDEX_KERNEL_NAME = "paged_index_keys"
+# pages a program of ``paged_index_keys`` (a chunk: 512 B a token in, 256
+# out). 8 tables x 1 600 pages on a v5e, launches chained in one jit (PERF.md
+# section 6, PR 48): 0.172 / 0.147 / 0.143 / 0.148 ms as runs at 32 / 64 /
+# 128 / 320 pages (the copies alone 0.140: the unpack hides under them),
+# 0.250 / 0.232 / 0.226 / 0.217 page by page. 64 keeps more of a churned
+# pool's chunks runs than a wider one would, for what 128 gives back
+INDEX_CHUNK_PAGES = 64
+# pages a pass of the loop that starts a whole chunk that is not a run
+# (``pallas_latent.UNROLL``'s measurement: the descriptors are the cost)
+INDEX_UNROLL = 8
+# tokens a step of the unpack: 32 vector registers of even and of odd tokens
+INDEX_UNPACK_TOKENS = 512
+
+
+def _check_rows_pool(name: str, cache: jax.Array, rank: int = 0):
+    """Both launches read bf16 pages of 128 lanes a row, an even number of
+    rows a token (whole ``(2, 128)`` tiles)."""
+    lanes, n_rows = cache.shape[3], cache.shape[2]
+    if (cache.dtype != jnp.bfloat16 or lanes != LATENT_LANES
+            or rank % (2 * lanes) or n_rows % 2):
+        raise ValueError(
+            f"{name} reads bf16 pages of 128 lanes a row and "
+            f"an even number of rows; got {cache.dtype} {cache.shape}"
+            + (f", latent rank {rank}" if rank else "")
+        )
 
 
 def _halves(words):
@@ -278,13 +325,7 @@ def sparse_latent_attention(
     mb = tables.shape[1]
     rank = width - lanes
     lat_rows = rank // lanes
-    if (k_cache.dtype != jnp.bfloat16 or lanes != LATENT_LANES
-            or rank % (2 * lanes) or n_rows % 2):
-        raise ValueError(
-            "sparse_latent_attention reads bf16 pages of 128 lanes a row and "
-            f"an even number of rows; got {k_cache.dtype} {k_cache.shape}, "
-            f"latent rank {rank}"
-        )
+    _check_rows_pool("sparse_latent_attention", k_cache, rank)
     K = sel.shape[1]
     chunk = min(CHUNK, -(-K // UNROLL) * UNROLL)
     pad = (-K) % chunk
@@ -350,3 +391,149 @@ def sparse_latent_attention(
         v_cache.reshape(nb * bs, n_rows // 2, 2, lanes),
     )
     return out.transpose(0, 2, 1, 3).reshape(Tq, h, rank)
+
+
+def index_chunk_pages(tables: jax.Array) -> int:
+    """Pages a chunk of ``paged_index_keys`` over these tables."""
+    return min(INDEX_CHUNK_PAGES, tables.shape[1])
+
+
+def index_chunk_reads(tables: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """What ``paged_index_keys`` reads of these tables by the chunk: the
+    whole chunks (every table is read to its last page: the launch takes no
+    context length) and those of them read as runs of consecutive pages."""
+    runs = chunk_runs(tables, index_chunk_pages(tables))
+    return jnp.asarray(runs.size, jnp.int32), jnp.sum(runs)
+
+
+def _index_keys_kernel(tables_ref, runs_ref, v_hbm, o_ref, buf, sem, *,
+                       bs: int, cp: int, mb: int):
+    # scalar prefetch (SMEM): tables [R * mb], runs [R * (mb // cp)]
+    # v_hbm  ANY/HBM [nb * bs, rows / 2, 2, 128]; [t, 0] = [k_pe | index key]
+    # o_ref  VMEM [1, T, 128] bf16: chunk c of table r
+    # buf    VMEM [2, T, 2, 128] bf16; sem DMA [2 (slot)]
+    T = cp * bs
+    n_whole, tail = mb // cp, mb % cp
+    n_chunks = n_whole + (1 if tail else 0)
+    r, c = pl.program_id(0), pl.program_id(1)
+    i = r * n_chunks + c
+    slot = jax.lax.rem(i, 2)
+
+    def whole_or_tail(c, whole, tail_chunk):
+        """A table's chunks are whole but its last, where ``mb`` leaves a
+        tail: static sizes either way, one branch on ``c``."""
+        if not tail:
+            whole()
+        else:
+            pl.when(c < n_whole)(whole)
+            pl.when(c == n_whole)(tail_chunk)
+
+    def page(r, c, j, slot):
+        src = pl.ds(tables_ref[r * mb + c * cp + j] * bs, bs)
+        return pltpu.make_async_copy(
+            v_hbm.at[src, 0], buf.at[slot, pl.ds(j * bs, bs)], sem.at[slot])
+
+    def pages(r, c, slot, n: int):
+        unroll = INDEX_UNROLL if n % INDEX_UNROLL == 0 else 1
+
+        def group(g, carry):
+            for u in range(unroll):
+                page(r, c, g * unroll + u, slot).start()
+            return carry
+
+        jax.lax.fori_loop(0, n // unroll, group, 0)
+
+    def start(r, c, slot):
+        def whole():
+            run = runs_ref[r * n_whole + c] != 0
+
+            @pl.when(run)
+            def _run():
+                first = tables_ref[r * mb + c * cp] * bs
+                pltpu.make_async_copy(
+                    v_hbm.at[pl.ds(pl.multiple_of(first, bs), T), 0],
+                    buf.at[slot], sem.at[slot]).start()
+
+            pl.when(jnp.logical_not(run))(lambda: pages(r, c, slot, cp))
+
+        whole_or_tail(c, whole, lambda: pages(r, c, slot, tail))
+
+    def wait(c, slot):
+        def landed(n_tokens: int):
+            # never started: the descriptor says how many bytes to wait for,
+            # the same however the chunk was started
+            dst = buf.at[slot, pl.ds(0, n_tokens)]
+            pltpu.make_async_copy(dst, dst, sem.at[slot]).wait()
+
+        whole_or_tail(c, lambda: landed(T), lambda: landed(tail * bs))
+
+    @pl.when(i == 0)
+    def _first():
+        start(r, c, slot)
+
+    @pl.when(i + 1 < pl.num_programs(0) * n_chunks)
+    def _next():
+        last = c + 1 == n_chunks
+        start(jnp.where(last, r + 1, r), jnp.where(last, 0, c + 1), 1 - slot)
+
+    wait(c, slot)
+    # both slots as word-rows, a token each: its key the high halves
+    words = buf.bitcast(jnp.uint32).reshape(2 * T, LATENT_LANES)
+    for t0 in range(0, T, INDEX_UNPACK_TOKENS):
+        n = min(INDEX_UNPACK_TOKENS, T - t0) // 2
+        _, keys = _pack_pairs(
+            words[pl.ds(slot * T + t0, n, stride=2), :],
+            words[pl.ds(slot * T + t0 + 1, n, stride=2), :],
+        )
+        # the words of 2n tokens' keys, handed on as a bf16 VALUE: Mosaic
+        # re-tiles the registers for it (8 instructions a register where a
+        # uint32 buffer copied out by hand has none), under the copies all
+        # the same: 0.144 against 0.147 ms a decode launch on the chip
+        o_ref[0, pl.ds(t0, 2 * n), :] = pltpu.bitcast(keys, o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("dim", "interpret"))
+def paged_index_keys(
+    v_cache: jax.Array,      # [nb, bs, rows, 128] bf16, row 1 = [kI | 0]
+    tables: jax.Array,       # [R, mb] int32
+    dim: int,
+    *, interpret: bool = False,
+) -> jax.Array:
+    """ops/attention.paged_index_keys has the contract and is the twin: the
+    index keys (their first ``dim`` lanes) of each table's context, ``[R, mb *
+    bs, dim]``, the same bits."""
+    nb, bs, n_rows, lanes = v_cache.shape
+    R, mb = tables.shape
+    _check_rows_pool("paged_index_keys", v_cache)
+    if bs % 2 or dim > lanes:
+        raise ValueError(
+            f"paged_index_keys packs pairs of tokens of a page and reads at "
+            f"most {lanes} lanes a key; got pages of {bs}, dim {dim}"
+        )
+    cp = index_chunk_pages(tables)
+    T = cp * bs
+    tables = tables.astype(jnp.int32)
+    keys = pl.pallas_call(
+        functools.partial(_index_keys_kernel, bs=bs, cp=cp, mb=mb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R, -(-mb // cp)),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, T, lanes), lambda r, c, *_: (r, c, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, T, 2, lanes), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, mb * bs, lanes), v_cache.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # a program starts the next one's chunk: in order
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        interpret=interpret,
+        name=INDEX_KERNEL_NAME,
+    )(
+        tables.reshape(-1), chunk_runs(tables, cp).reshape(-1).astype(jnp.int32),
+        v_cache.reshape(nb * bs, n_rows // 2, 2, lanes),
+    )
+    return keys if dim == lanes else keys[..., :dim]

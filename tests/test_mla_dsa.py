@@ -103,6 +103,13 @@ async def test_chunked_prefill_and_decode_match_the_reference_in_float32():
         assert s.dsa_keys_scored * 3 == s.dsa_keys_causal * 2
         assert 0 < s.dsa_keys_selected <= s.dsa_keys_causal
         assert s.moe_held_experts_touched == s.moe_experts_touched <= 2 * 4 * 8
+        # a table of 8 pages is one chunk of the index keys' read: a table
+        # of each of the step's rows, in each of the 2 selecting layers, in
+        # each of a horizon's steps
+        rows = 4 + (s.phase == "mixed")
+        assert s.dsa_index_chunks_whole in (2 * rows, 2 * rows * 8), s
+        # ... of which none is a run: a context of 92 ends inside its table
+        assert s.dsa_index_chunks_run == 0
     assert all(s.dsa_keys_causal is None for s in steps if s.phase == "prefill")
 
 
@@ -322,6 +329,102 @@ def test_a_mixed_step_and_an_inherited_selection(seams):
     assert (sel[16] >= 0).sum() == TOPK and sel[16].max() <= 32
     again, _ = _both(seams, lambda: ask(jnp.asarray(sel)))
     np.testing.assert_array_equal(again, out)
+
+
+def _index_pool(nb, rows=4, bad_k_pe=False, seed=0):
+    """A second array whose row 1 holds index keys and whose other rows hold
+    something else; ``bad_k_pe``: row 0 (the low halves of the words the
+    keys share) holds NaNs and infinities."""
+    rng = np.random.default_rng(seed)
+    vc = rng.normal(size=(nb, BS, rows, 128)).astype(np.float32)
+    if bad_k_pe:
+        vc[:, :, 0, 0::3], vc[:, :, 0, 1::3], vc[:, :, 0, 2::3] = np.nan, np.inf, -np.inf
+    return rng, jnp.asarray(vc, jnp.bfloat16)
+
+
+def _bits(x):
+    return np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16))
+
+
+@pytest.mark.parametrize("case,n_tables,mb,dim,shuffled,bad_k_pe", [
+    ("runs", 2, 8, 128, False, False),
+    ("shuffled-page-by-page", 2, 8, 128, True, False),
+    ("a-tail-of-2-pages", 3, 10, 128, False, False),
+    ("a-tail-shuffled", 2, 11, 128, True, False),
+    ("one-table", 1, 12, 128, False, False),
+    ("nine-tables", 9, 6, 128, False, False),
+    ("narrower-than-a-chunk", 2, 3, 128, True, False),
+    ("dim-32", 2, 8, 32, False, False),
+    ("dim-64-shuffled-tail", 2, 9, 64, True, False),
+    ("k_pe-holds-nan-and-inf", 2, 9, 128, False, True),
+])
+def test_the_index_keys_launch_is_bitwise_the_twin(
+        monkeypatch, case, n_tables, mb, dim, shuffled, bad_k_pe):
+    """``pallas_sparse.paged_index_keys`` interpreted, chunks of 4 pages,
+    against ``att.paged_index_keys``: whole chunks read as runs (one strided
+    descriptor) and page by page, a table's tail chunk, a table narrower than
+    a chunk; the keys are the high halves of the words they share with
+    ``k_pe``, cut out by a mask, so a NaN beside a key stays beside it."""
+    from dynamo_tpu.ops import pallas_sparse as ps
+
+    monkeypatch.setattr(ps, "INDEX_CHUNK_PAGES", 4)
+    nb = 2 + n_tables * mb
+    rng, vc = _index_pool(nb, bad_k_pe=bad_k_pe, seed=mb)
+    ids = np.arange(1, 1 + n_tables * mb)
+    tables = jnp.asarray(
+        (rng.permutation(ids) if shuffled else ids).reshape(n_tables, mb), jnp.int32)
+    want = att.paged_index_keys(vc, tables, dim)
+    got = ps.paged_index_keys(vc, tables, dim, interpret=True)
+    assert got.shape == want.shape == (n_tables, mb * BS, dim) and got.dtype == want.dtype
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert not np.isnan(np.asarray(got, np.float32)).any()
+    whole, run = ps.index_chunk_reads(tables)
+    assert int(whole) == n_tables * (mb // min(4, mb))
+    assert int(run) == (0 if shuffled else int(whole))
+
+
+def test_a_pool_the_index_keys_launch_cannot_read_is_refused():
+    from dynamo_tpu.ops import pallas_sparse as ps
+
+    tables = jnp.ones((1, 4), jnp.int32)
+    for shape, dtype in (((8, BS, 4, 128), jnp.float32), ((8, BS, 3, 128), jnp.bfloat16),
+                         ((8, BS, 4, 64), jnp.bfloat16)):
+        with pytest.raises(ValueError, match="bf16 pages of 128 lanes"):
+            ps.paged_index_keys(jnp.zeros(shape, dtype), tables, 64, interpret=True)
+
+
+@pytest.mark.parametrize("kind", ["decode", "ragged"])
+def test_the_seam_selects_and_attends_alike_with_the_launch_and_the_twin(seams, kind):
+    """Through the seam, Pallas on: the index keys come from the launch
+    ``paged_index_keys`` (the twin's slice elsewhere); the same bits, so
+    ``dsa.selected`` is the twin's exactly, with a pool of 4 rows a token and
+    NaNs in ``k_pe``'s lanes past its 64; both leave the same chunk counts."""
+    rng, vc = _index_pool(NB, seed=11)
+    kc = jnp.asarray(rng.normal(size=(NB, BS, 4, 128)), jnp.bfloat16)
+    tables = jnp.asarray(rng.permutation(np.arange(1, NB))[:3 * MB].reshape(3, MB), jnp.int32)
+    if kind == "decode":
+        n, args = 3, (tables, jnp.asarray([90, 17, 0], jnp.int32))
+    else:
+        n, args = 18, (tables, jnp.asarray([0, 16, 17], jnp.int32),
+                       jnp.asarray([12, 1, 1], jnp.int32), jnp.asarray([60, 33, 96], jnp.int32))
+    q = jnp.asarray(rng.normal(size=(n, H, 2 * RANK + 128)), jnp.bfloat16)
+    iq = jnp.asarray(rng.normal(size=(n, 4, 32)), jnp.bfloat16)
+    iw = jnp.asarray(rng.normal(size=(n, 4)), jnp.float32)
+    outs, asked = [], []
+    for seam in seams:
+        dsa = att.DsaQuery(scale=0.125, topk=TOPK, index_q=iq, index_w=iw)
+        fn = lambda q, kc, vc: getattr(seam, kind)(q, kc, vc, *args, dsa=dsa)  # noqa: E731
+        names = [e.params.get("name") for e in jax.make_jaxpr(fn)(q, kc, vc).jaxpr.eqns
+                 if e.primitive.name == "jit"]
+        assert ("paged_index_keys" in names) == seam.use_pallas
+        dsa.selected = None
+        outs.append(np.asarray(fn(q, kc, vc), np.float32))
+        asked.append(dsa)
+    np.testing.assert_array_equal(*(np.asarray(d.selected) for d in asked))
+    assert (np.asarray(asked[0].selected) >= 0).any()
+    np.testing.assert_allclose(outs[1], outs[0], atol=2e-2, rtol=2e-2)
+    assert [int(x) for x in asked[0].index_chunk_reads] == [
+        int(x) for x in asked[1].index_chunk_reads] == [3, 0]
 
 
 def _scratch_operands(fn, *args):

@@ -158,6 +158,20 @@ def sparse_launch(mb):
     return fn, build
 
 
+def index_keys(tables):
+    """The read of the index keys in front of the selection (PR 48), at the
+    long-document cell's sizes: the second array of 14 336 pages, ``tables``
+    tables of 1 600 pages (8 decode rows; 1 a lone chunk)."""
+    from dynamo_tpu.ops import pallas_sparse as ps
+
+    def build(sharding):
+        def s(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+        return s((14336, 16, 4, 128), BF), s((tables, 1600), I32)
+    return (lambda vc, tb: ps.paged_index_keys(vc, tb, 128)), build
+
+
 def _cases():
     """name -> (fn, shape-args builder). Builders take the ShapeDtypeStruct
     factory so one table serves any sharding."""
@@ -368,6 +382,8 @@ def _cases():
         "sparse-latent-decode": (sparse_decode, sparse_shapes(8, 8)),
         "sparse-latent-staged-all-the-vmem": sparse_launch(4096),
         "sparse-latent-too-wide-to-stage": sparse_launch(4097),
+        "index-keys-decode": index_keys(8),
+        "index-keys-chunk": index_keys(1),
         # the seam's dense-latent question (PR 33): decode rows, a lone chunk
         # and the mixed step, each one launch; the chunk rule's two sides
         "paged-latent-decode": latent("decode", 8, 8),
@@ -412,6 +428,17 @@ def test_kernel_compiles_for_v5e(v5e, chip_seam, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _copies_of(text: str, elements: int):
+    """The ``copy`` / ``copy-start`` instructions of a compiled program whose
+    result has ``elements`` elements (an array of a pool's size), as (name,
+    layout)."""
+    found = re.findall(
+        r"^\s*(?:ROOT )?(\S+) = \(?\w+\[([\d,]+)\](\{[^}]*\})?.* copy(?:-start)?\(",
+        text, re.M)
+    return [(name, layout) for name, dims, layout in found
+            if math.prod(map(int, dims.split(","))) == elements]
+
+
 def _loop_bodies(llo: str, at_least: int = 1000):
     """Mosaic's last pass as text -> a Counter of ``llo.`` operations for each
     ``scf.for`` body of ``at_least`` lines (a chunk visit of a kernel that
@@ -454,6 +481,40 @@ def test_a_latent_chunk_visit_unpacks_whole_registers(v5e, chip_seam, mosaic_dum
         assert ops["vmatmul"] and ops["vector_load_slane_stride"] == 384, ops
         assert ops["vrot.slane"] + ops["vselect"] <= 256, ops
         assert vector <= 8000, (vector, ops)
+
+
+@pytest.mark.parametrize("case", ["sparse-latent-decode", "sparse-latent-mixed"])
+def test_a_selecting_attend_copies_no_array_of_the_pools_size(v5e, chip_seam, case):
+    """ISSUE 48's tripwire. Until PR 48 the seam took the index keys as
+    ``v_cache[tables, :, 1, :dim]``; row 1 of a token shares its 32-bit words
+    with row 0 in the pool's tiling, so XLA transposed the WHOLE second array
+    (117 440 512 elements, 235 MB in and out) in front of the gather, in
+    every ``full`` layer of every step: 0.72 ms of a launch's 0.86 on the chip
+    (PERF.md section 6, PR 48). The compiled attend holds no such copy."""
+    fn, build = CASES[case]
+    text = jax.jit(functools.partial(fn, chip_seam)).lower(
+        *build(SingleDeviceSharding(v5e[0]))).compile().as_text()
+    assert _copies_of(text, 14336 * 16 * 4 * 128) == []
+    assert text.count("tpu_custom_call") >= 2        # the keys, then the attend
+
+
+def test_the_index_keys_unpack_moves_whole_registers(v5e, mosaic_dump):
+    """The launch's program body by Mosaic's own dump, at the cell's decode
+    shapes: a chunk of 1 024 tokens comes in as sublane-strided loads of
+    whole registers (128: the even and the odd tokens of 64 registers of
+    keys) and no sublane rotate or select a token; read as ``buf[slot, :, 0,
+    :]`` it would come back one token a register (PR 47)."""
+    fn, build = CASES["index-keys-decode"]
+    # a function jit has not seen: a cached executable writes no dump
+    jax.jit(lambda *a: fn(*a)).lower(*build(SingleDeviceSharding(v5e[0]))).compile()
+    last = sorted(mosaic_dump.glob("*paged_index_keys*finalize-llo*"))
+    if not last:
+        pytest.skip("this libtpu wrote no Mosaic dump (--xla_mosaic_dump_to)")
+    ops = collections.Counter(re.findall(r"llo\.([a-z_.0-9]+)", last[-1].read_text()))
+    vector = sum(n for op, n in ops.items() if op.startswith("v") and op != "vbitcast")
+    assert ops["vector_load_slane_stride"] == 128, ops
+    assert ops["vrot.slane"] + ops["vselect"] <= 16, ops
+    assert vector <= 1500, (vector, ops)
 
 
 def test_sharded_decode_compiles_on_tp4_mesh(v5e):
@@ -541,11 +602,7 @@ def _pool_copies(seam, write_chunk, case):
         s((rows,), I32), s((rows + 1, mb), I32), r, r,
     ).compile().as_text()
     assert "tpu_custom_call" in text
-    found = re.findall(
-        r"^\s*(?:ROOT )?(\S+) = \(?\w+\[([\d,]+)\](\{[^}]*\})?.* copy(?:-start)?\(",
-        text, re.M)
-    return [(name, layout) for name, dims, layout in found
-            if math.prod(map(int, dims.split(","))) == pages * BS * kvh * D]
+    return _copies_of(text, pages * BS * kvh * D)
 
 
 @pytest.mark.parametrize("case", sorted(MIXED_ATTENDS))
@@ -587,11 +644,7 @@ def test_eva_mixed_attend_copies_no_array_of_the_pools_size(chip_seam):
         r1, r1, s((), I32), s((), I32), vec, vec,
     ).compile().as_text()
     assert "tpu_custom_call" in text
-    found = re.findall(
-        r"^\s*(?:ROOT )?(\S+) = \(?\w+\[([\d,]+)\](\{[^}]*\})?.* copy(?:-start)?\(",
-        text, re.M)
-    assert [n for n, dims, _ in found
-            if math.prod(map(int, dims.split(","))) == pages * BS * h * D] == []
+    assert _copies_of(text, pages * BS * h * D) == []
 
 
 @pytest.mark.parametrize("case,relaid", [
