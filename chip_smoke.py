@@ -1011,6 +1011,70 @@ def _kernel_child() -> None:
     compare("eva_decode_attention 24 rows x 32 heads, ring 2048, 4 summary blocks",
             got[e_lens > 0], want[e_lens > 0])
     del ek, ev
+
+    # pages by layer kind (PR 49) at Command A+'s widths: 128 query heads over
+    # 8 kv heads x 128 (SIXTEEN a kv head), 24 rows. The full layer's decode
+    # launch over tables of 2 112 pages (33k keys), the sliding layers' over
+    # their group's SHIFTED run of a row's table (ops/paged_attention
+    # GroupView: 290 pages from the oldest page a row still holds, lengths
+    # counted from there) under the window of 4 096; a row at its first
+    # token, one whose window has not filled, one at a page's edge, an empty
+    # one. Pages are drawn from one pool of 8 192 (rows may share pages:
+    # nothing is written). The twins gather a row's whole context in
+    # float32: the first 8 rows of each launch are held to them
+    from dynamo_tpu.ops.paged_attention import GroupView
+
+    CB, CH, CMB, CWP, CW = 24, 128, 2112, 290, 4096
+    cpool = 8192
+    ck, cv = rnd(cpool, BS, KVH, D), rnd(cpool, BS, KVH, D)
+    c_lens = rng.integers(CW + 600, CMB * BS, CB).astype(np.int32)
+    c_lens[:8] = [0, 1, 33792, 4096, 4097, 3000, 33000, 8192]
+    c_tables = np.zeros((CB, CMB + CWP + 1), np.int32)
+    c_tables[:, :CMB] = rng.integers(1, cpool, (CB, CMB))
+    view = GroupView(CMB, CWP, BS)
+    first = np.maximum(c_lens - 1 - CW + 1, 0) // BS           # WindowGroup.first_needed
+    c_tables[:, CMB:CMB + CWP] = rng.integers(1, cpool, (CB, CWP))
+    c_tables[:, CMB + CWP] = first
+    cq = rnd(CB, CH, D)
+    full_args = (cq, ck, cv, jnp.asarray(c_tables[:, :CMB]), jnp.asarray(c_lens))
+    got = np.asarray(kernels.decode(*full_args), np.float32)
+    if got[0].any():
+        raise SystemExit("decode question, 16 heads a kv head: an empty row is not zeros")
+    first8 = lambda q, kc, vc, *rows: (q[:8], kc, vc, *(r[:8] for r in rows))  # noqa: E731
+    compare("decode question 24 rows x 128 heads (16 a kv head), 33k keys",
+            got[1:8], highest(twins.decode)(*first8(*full_args))[1:8])
+    run_tables, run_lens, _ = view.rows(
+        jnp.asarray(c_tables), jnp.asarray(c_lens), jnp.asarray(c_lens > 0))
+    if not np.array_equal(np.asarray(run_lens), np.where(c_lens > 0, c_lens - first * BS, 0)):
+        raise SystemExit("GroupView.rows: lengths are not counted from the run's first page")
+    win_args = (cq, ck, cv, run_tables, run_lens)
+    got = np.asarray(kernels.decode(*win_args, window=CW), np.float32)
+    if got[0].any():
+        raise SystemExit("windowed decode over a shifted table: an empty row is not zeros")
+    compare("windowed decode 24 rows x 128 heads over a shifted table, window 4096",
+            got[1:8],
+            highest(lambda *a: twins.decode(*a, window=CW))(
+                *first8(*win_args))[1:8])
+    del ck, cv
+
+    # ...and its expert multiplication: 16 held experts of [4 096, 4 096]
+    # (100.7 MB each over three matrices, the widest yet); 192 sorted rows is
+    # a decode step's 24 rows x top 8 were they all held, one expert empty
+    c_sizes = jnp.asarray([30, 0, 7, 20, 1, 40, 2, 12, 16, 9, 11, 5, 13, 8, 15, 3], jnp.int32)
+    c_rows = rnd(192, 4096)
+    cg, cu = (rnd(16, 4096, 4096) * 0.0156 for _ in range(2))
+    cd = rnd(16, 4096, 4096) * 0.0156
+    # the twin at the default precision: at these sizes ``ragged_dot`` under
+    # "highest" is itself a Mosaic kernel, which refuses bf16 rows ("Bad lhs
+    # type", my chip run, PR 49)
+    wide_twin = jax.jit(pmoe.grouped_matmul_reference)
+    act = pmoe.grouped_matmul(c_rows, (cg, cu), c_sizes)
+    compare("moe_grouped_matmul gate, up and SwiGLU, 16 experts of [4096, 4096]",
+            act, wide_twin(c_rows, (cg, cu), c_sizes))
+    compare("moe_grouped_matmul down, 16 experts of [4096, 4096]",
+            pmoe.grouped_matmul(act, (cd,), c_sizes),
+            wide_twin(act, (cd,), c_sizes))
+    del cg, cu, cd
     k_cache, v_cache = rnd(NB, BS, KVH, D), rnd(NB, BS, KVH, D)
 
     # block moves are copies: exact

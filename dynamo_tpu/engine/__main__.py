@@ -19,6 +19,7 @@ from dynamo_tpu.engine.engine import TpuEngine, TpuEngineConfig
 from dynamo_tpu.engine.weights import config_from_hf, load_params
 from dynamo_tpu.kv_router import KvEventPublisher, WorkerMetricsPublisher
 from dynamo_tpu.llm import ModelDeploymentCard, ModelRuntimeConfig, register_llm
+from dynamo_tpu.models.cohere2_moe import Cohere2MoeConfig
 from dynamo_tpu.models.evabyte import EvaByteConfig
 from dynamo_tpu.models.llama import LlamaConfig
 from dynamo_tpu.models.gemma import GemmaConfig
@@ -62,6 +63,9 @@ PRESETS = {
     # --block-size 16, --prefill-chunk at most the window: 256 / 2048)
     "tiny-evabyte": EvaByteConfig.tiny,
     "evabyte-6.5b": EvaByteConfig.evabyte_6_5b,
+    # pages by layer kind: sliding layers hold one window, full layers all
+    "tiny-cohere2-moe": Cohere2MoeConfig.tiny,
+    "command-a-plus": Cohere2MoeConfig.command_a_plus,
 }
 
 from dynamo_tpu.models.vision import VisionConfig

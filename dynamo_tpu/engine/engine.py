@@ -50,7 +50,7 @@ from ..models import llama, registry
 from ..models import moe as moe_lib
 from ..models.vision import IMAGE_TOKEN_ID
 from ..ops import attention as att
-from ..ops.paged_attention import PagedAttention
+from ..ops.paged_attention import GroupView, PagedAttention
 from ..parallel import mesh as meshlib
 from ..runtime.config import ENV_KV_BLOCK_SIZE, env_int
 from ..runtime.device import device_info, hbm_bytes_per_s, on_tpu
@@ -68,7 +68,7 @@ from ..runtime.tasks import spawn_bg
 from ..runtime.logging import get_logger
 from ..runtime.tracing import get_tracer
 from ..tokens import TokenBlockSequence
-from .allocator import BlockAllocator, OutOfBlocks, Ring
+from .allocator import BlockAllocator, OutOfBlocks, Ring, WindowGroup
 from . import step_args
 from .telemetry import (
     PENDING_SPANS_MAX,
@@ -196,6 +196,12 @@ class TpuEngineConfig:
     # (amax/254 per element). The draft model's shadow cache stays in model
     # dtype — it is small and its values only steer acceptance, never output.
     kv_dtype: str = "auto"
+    # pages by layer kind (models/registry.page_groups with a windowed
+    # group): the pages of a windowed group's pool. None = the engine's rule
+    # (``window_pool_pages``): a row's table and one more window a row.
+    # ``num_blocks`` stays the pages of the group that lives as long as the
+    # request.
+    window_blocks: Optional[int] = None
 
     def __post_init__(self):
         bad = [b for b in self.prefill_buckets if b % self.block_size]
@@ -234,6 +240,28 @@ class TpuEngineConfig:
         if ring is not None:
             return ring.table_width
         return (self.max_context + self.block_size - 1) // self.block_size
+
+    def window_table_pages(self, window: int) -> int:
+        """Entries of a windowed group's run in a row's table: the window, and
+        ahead of it the largest prefill bucket or what the decode horizons in
+        flight book (whichever is longer), and one page (a window does not
+        start on a page's edge)."""
+        ahead = max(
+            self.prefill_chunk,
+            (self.decode_steps or 1) * (self.decode_pipeline or 1) + 2,
+        )
+        return -(-(window + ahead) // self.block_size) + 1
+
+    def window_pool_pages(self, window: int) -> int:
+        """THE rule that sizes a windowed group's pool from what a
+        deployment states already (rows, window, buckets): every row's table
+        full, and one window more a row (the tail of a cached prefix that the
+        row's next request comes back to while the last one's pages are still
+        cached), and the scratch page. ``window_blocks`` overrides it."""
+        if self.window_blocks is not None:
+            return int(self.window_blocks)
+        per_row = self.window_table_pages(window) + -(-window // self.block_size)
+        return 1 + self.max_batch_size * per_row
 
 
 def _model_param_bytes(mcfg) -> int:
@@ -319,6 +347,10 @@ class _Seq:
     block_ids: List[int] = dataclasses.field(default_factory=list)
     # a family with a ring: the summary blocks of the windows it has opened
     summary_ids: List[int] = dataclasses.field(default_factory=list)
+    # pages by layer kind: for each windowed group the run of pages held,
+    # ``win_ids[g]`` the pages of page indexes ``win_first[g] ..``
+    win_first: List[int] = dataclasses.field(default_factory=list)
+    win_ids: List[List[int]] = dataclasses.field(default_factory=list)
     produced: int = 0
     last_token: int = 0
     cached_tokens: int = 0
@@ -546,6 +578,9 @@ class TpuEngine:
         registry.check_eva_supported(
             self.mcfg, **asked, kvbm=kvbm is not None
         )
+        registry.check_groups_supported(
+            self.mcfg, **asked, kvbm=kvbm is not None
+        )
         # pages that live one window (allocator.Ring) and summary blocks by
         # window beside them, where the family says so; None for every other
         self._ring = config.ring
@@ -646,6 +681,23 @@ class TpuEngine:
             self.summary_allocator = BlockAllocator(
                 1 + rings * self._ring.windows, self._ring.pages
             )
+        # pages by layer kind (registry.page_groups): the first group's pages
+        # live as long as the request and are ``allocator``'s, as every other
+        # family's; each further group has a pool, an allocator and a run of
+        # a row's table of its own (allocator.WindowGroup). Empty for the
+        # families that answer one group
+        self._win_groups: List[WindowGroup] = []
+        col = config.max_blocks_per_seq
+        for layers, window in registry.page_groups(self.mcfg)[1:]:
+            pages = config.window_table_pages(window)
+            self._win_groups.append(WindowGroup(
+                tuple(layers), int(window), config.block_size, pages, col,
+                BlockAllocator(
+                    config.window_pool_pages(window), config.block_size,
+                    keep_hits=True,
+                ),
+            ))
+            col += pages + 1
         self._host_rng = np.random.default_rng(config.seed)
         # multi-tier KV (kvbm/pool.py): sealed blocks write through to host
         # DRAM (G2) / disk (G3); admission onboards matched prefixes back
@@ -792,7 +844,9 @@ class TpuEngine:
         self._seq_lens = np.zeros(B, np.int32)
         # entries of a row's table (a ring's pages and its summary blocks,
         # or a page a block_size of max_context): asked of the family once
-        self._table_width = config.max_blocks_per_seq
+        self._table_width = config.max_blocks_per_seq + sum(
+            g.pages + 1 for g in self._win_groups
+        )
         self._block_tables = np.zeros((B, self._table_width), np.int32)
         self._temps = np.zeros(B, np.float32)
         self._top_ks = np.zeros(B, np.int32)
@@ -987,6 +1041,7 @@ class TpuEngine:
             raise ValueError("pp serving does not cover KV transfer yet")
         registry.check_state_supported(self.mcfg, transfer=True)
         registry.check_eva_supported(self.mcfg, transfer=True)
+        registry.check_groups_supported(self.mcfg, transfer=True)
         from ..runtime.request_plane.tcp import TcpRequestServer
         from .transfer import KvCommitSignal, KvTransferServer
 
@@ -1009,6 +1064,7 @@ class TpuEngine:
 
             registry.check_state_supported(self.mcfg, transfer=True)
             registry.check_eva_supported(self.mcfg, transfer=True)
+            registry.check_groups_supported(self.mcfg, transfer=True)
 
             self._transfer_client = KvTransferClient(self)
         return self._transfer_client
@@ -1139,6 +1195,14 @@ class TpuEngine:
         # one pair of arrays a layer that KEEPS pages (registry.page_layers:
         # every layer, but for a family whose layers are of different kinds)
         n_paged = len(registry.page_layers(mcfg))
+        # pages by layer kind: a windowed group's layers have its pool's pages
+        # (int8 and a draft's shadow cache are refused more than one group)
+        pool = {
+            l: g.allocator.num_blocks
+            for g in (self._win_groups if mcfg is self.mcfg else ())
+            for l in g.layers
+        }
+        sizes = [pool.get(l, pages) for l in registry.page_layers(mcfg)]
         # host-side zeros: device_put shards them per-process (jnp.zeros would
         # commit to the local default device — invalid for a multi-host mesh)
         if quantized:
@@ -1158,9 +1222,9 @@ class TpuEngine:
             k = [qzeros() for _ in range(n_paged)]
             v = [qzeros() for _ in range(n_paged)]
             return k, v
-        zeros = partial(np.zeros, shape, mcfg.dtype)
-        k = [jax.device_put(zeros(), sharding) for _ in range(n_paged)]
-        v = [jax.device_put(zeros(), sharding) for _ in range(n_paged)]
+        zeros = lambda n: np.zeros((n, *shape[1:]), mcfg.dtype)  # noqa: E731
+        k = [jax.device_put(zeros(n), sharding) for n in sizes]
+        v = [jax.device_put(zeros(n), sharding) for n in sizes]
         return k, v
 
     def _resolve_use_pallas(self) -> bool:
@@ -1288,6 +1352,23 @@ class TpuEngine:
             summary_base=cfg.num_blocks,
         )
         ring = self._ring
+        # pages by layer kind: a page layer's place -> its group's view of a
+        # row's table (ops/paged_attention.GroupView); {} for a family of
+        # one group, whose programs take the tables as they always did
+        views = {}
+        if self._win_groups:
+            place = page_of if page_of is not None else {
+                l: l for l in range(mcfg.num_layers)
+            }
+            main = GroupView(
+                0, cfg.max_blocks_per_seq, cfg.block_size, shifted=False
+            )
+            views = {i: main for i in place.values()}
+            for g in self._win_groups:
+                views.update({
+                    place[l]: GroupView(g.col, g.pages, cfg.block_size)
+                    for l in g.layers
+                })
 
         # the second seam: ``mix`` owns a family's slot state
         # (engine/state_cache.py) as ``attend`` owns the pages. The family's
@@ -1408,20 +1489,24 @@ class TpuEngine:
             """``attend`` of decode rows ([B, 1, ...] a layer): the fed
             token's KV written, then attended at the end of its context."""
             def attend(q, k_new, v_new, layer_idx, **extra):
+                # the rows as the layer's own page group holds them; a
+                # family of one group: as they came
+                tables, lens, pages = (
+                    views[layer_idx].rows(block_tables, seq_lens, write_blocks > 0)
+                    if views else (block_tables, seq_lens, write_blocks)
+                )
                 kc, vc = att.write_decode_kv(
                     k_caches[layer_idx], v_caches[layer_idx],
-                    k_new[:, 0], v_new[:, 0], write_blocks, write_offsets,
+                    k_new[:, 0], v_new[:, 0], pages, write_offsets,
                 )
                 if "eva" in extra:
                     # a row whose token filled its page: the page's summary
                     kc, vc = attn.summarise_rows(
-                        kc, vc, block_tables, seq_lens, write_blocks,
-                        write_offsets, extra["eva"],
+                        kc, vc, tables, lens, pages, write_offsets,
+                        extra["eva"],
                     )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
-                out = attn.decode(
-                    q[:, 0], kc, vc, block_tables, seq_lens, **extra
-                )
+                out = attn.decode(q[:, 0], kc, vc, tables, lens, **extra)
                 return out[:, None]
             return attend
 
@@ -1442,16 +1527,26 @@ class TpuEngine:
                 # extra: per-layer attention variants the model opts into
                 # (sliding ``window``, per-head ``sinks`` — models/gptoss.py);
                 # plain families pass nothing and nothing changes
+                # the chunk as the layer's own page group holds it; a family
+                # of one group: as it came
+                table, start, end, pos, ids = (
+                    views[layer_idx].chunk(
+                        block_table, chunk_start, total_len, positions,
+                        new_block_ids,
+                    ) if views else (
+                        block_table, chunk_start, total_len, positions,
+                        new_block_ids,
+                    )
+                )
                 kc, vc = attn.write_chunk(
                     k_caches[layer_idx], v_caches[layer_idx],
-                    *real_rows(k_new, v_new, positions, total_len),
-                    new_block_ids,
+                    *real_rows(k_new, v_new, positions, total_len), ids,
                 )
                 if "eva" in extra:
                     # the summaries of the chunk's whole pages
                     kc, vc = attn.summarise_chunk(
-                        kc, vc, k_new, v_new, block_table, chunk_start,
-                        total_len, extra["eva"],
+                        kc, vc, k_new, v_new, table, start, end,
+                        extra["eva"],
                     )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 if cfg.sp > 1:
@@ -1465,10 +1560,7 @@ class TpuEngine:
                         self.mesh, q, k_new, v_new, k_ctx, v_ctx,
                         positions, chunk_start, chunk_start,
                     )
-                return attn.chunk(
-                    q, kc, vc, block_table, chunk_start, total_len,
-                    positions, **extra
-                )
+                return attn.chunk(q, kc, vc, table, start, end, pos, **extra)
 
             return call_fwd(
                 params, tokens, positions, attend, lora_tables, lora_id,
@@ -1708,31 +1800,41 @@ class TpuEngine:
                 # into the unified launch as per-row attributes. The chunk's
                 # whole pages go through the seam: on the view the launch
                 # below reads, so nothing re-tiles the pool between
+                # chunk and rows as the layer's own page group holds them; a
+                # family of one group: as they came
+                table, c_end, ids, rows, lens, pages = (
+                    a.table_row, a.total_len, c_new_block_ids, block_tables,
+                    a.seq_lens, a.write_blocks,
+                )
+                if views:
+                    view = views[layer_idx]
+                    table, _, c_end, _, ids = view.chunk(
+                        table, a.chunk_start, c_end, c_positions, ids
+                    )
+                    rows, lens, pages = view.rows(rows, lens, pages > 0)
                 kc, vc = attn.write_chunk(
                     k_caches[layer_idx], v_caches[layer_idx],
                     *real_rows(
                         k_new[:S_pad], v_new[:S_pad], c_positions, a.total_len
                     ),
-                    c_new_block_ids,
+                    ids,
                 )
                 kc, vc = att.write_decode_kv(
-                    kc, vc, k_new[S_pad:], v_new[S_pad:],
-                    a.write_blocks, a.write_offsets,
+                    kc, vc, k_new[S_pad:], v_new[S_pad:], pages,
+                    a.write_offsets,
                 )
                 if "eva" in extra:
                     # the chunk's whole pages, and the rows' filled ones
                     kc, vc = attn.summarise_chunk(
-                        kc, vc, k_new[:S_pad], v_new[:S_pad], a.table_row,
-                        a.chunk_start, a.total_len, extra["eva"],
+                        kc, vc, k_new[:S_pad], v_new[:S_pad], table,
+                        a.chunk_start, c_end, extra["eva"],
                     )
                     kc, vc = attn.summarise_rows(
-                        kc, vc, block_tables, a.seq_lens, a.write_blocks,
-                        a.write_offsets, extra["eva"],
+                        kc, vc, rows, lens, pages, a.write_offsets,
+                        extra["eva"],
                     )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
-                tables = jnp.concatenate(
-                    [a.table_row[None], block_tables], axis=0
-                )
+                tables = jnp.concatenate([table[None], rows], axis=0)
                 q_starts = jnp.concatenate([
                     jnp.zeros((1,), jnp.int32),
                     S_pad + jnp.arange(B, dtype=jnp.int32),
@@ -1742,8 +1844,8 @@ class TpuEngine:
                     active.astype(jnp.int32),
                 ])
                 row_lens = jnp.concatenate([
-                    a.total_len[None].astype(jnp.int32),
-                    a.seq_lens.astype(jnp.int32),
+                    c_end[None].astype(jnp.int32),
+                    lens.astype(jnp.int32),
                 ])
                 return attn.ragged(
                     q, kc, vc, tables, q_starts, q_lens, row_lens, **extra
@@ -2281,6 +2383,15 @@ class TpuEngine:
                     f"prompt {n_prompt} tokens cannot fit the KV pool "
                     f"({self.cfg.num_blocks} blocks x {self.cfg.block_size})"
                 )
+            for grp in self._win_groups:
+                # a windowed group: its table's pages at most, of its own pool
+                if min(pages, grp.pages) + 1 > grp.allocator.num_blocks:
+                    raise ContextLengthError(
+                        f"prompt {n_prompt} tokens cannot fit the pool of the "
+                        f"page group of layers {list(grp.layers)} "
+                        f"({grp.allocator.num_blocks} blocks x "
+                        f"{self.cfg.block_size}, a window of {grp.window})"
+                    )
             wanted_procs = req.annotations.get("logits_processors") or []
             if wanted_procs:
                 known = {n for n, _ in self.cfg.logits_processors}
@@ -3004,6 +3115,12 @@ class TpuEngine:
                             cumulative_tokens=pick.produced,
                         ))
                         pick = None
+                    elif pick is not None and not self._slide_chunk(pick):
+                        # a windowed group's pool cannot give the chunk's
+                        # pages now: its turn stays, the rows that decode
+                        # (or finish) let pages go
+                        pick = None
+                        self._prefill_rr -= 1
                     elif pick is not None:
                         if pick.t_prefill_start == 0:
                             pick.t_prefill_start = now_ns()
@@ -3294,7 +3411,14 @@ class TpuEngine:
             reusable = min(len(hashes), (prompt_len - 1) // self.cfg.block_size)
             if st.no_cache:
                 reusable = 0
-            prefix_ids = self.allocator.acquire_prefix(hashes[:reusable])
+            if self._win_groups:
+                # pages by layer kind: a hit is as long as EVERY group can
+                # restore it (the windowed groups the window that ends there)
+                found = self.allocator.match_prefix(hashes[:reusable])
+                found = found[: self._hit_blocks(hashes, len(found))]
+                prefix_ids = self.allocator.acquire(found)
+            else:
+                prefix_ids = self.allocator.acquire_prefix(hashes[:reusable])
             prefix_blocks = len(prefix_ids)
             blocks_needed = (
                 (prompt_len + self.cfg.block_size - 1) // self.cfg.block_size
@@ -3308,7 +3432,7 @@ class TpuEngine:
                 blocks_needed, windows = self._ring.held(prompt_len)
             if not self.allocator.can_allocate(blocks_needed) or not (
                 self._take_summary_blocks(st, windows)
-            ):
+            ) or not self._admit_windows(st, hashes, prefix_blocks):
                 self.allocator.release(prefix_ids)
                 still.append(st)
                 continue
@@ -3333,6 +3457,8 @@ class TpuEngine:
             self._block_tables[slot].fill(0)
             self._block_tables[slot, : len(st.block_ids)] = st.block_ids
             self._table_summary_blocks(st)
+            for g in range(len(self._win_groups)):
+                self._table_window(st, g)
             self._seq_lens[slot] = prompt_len
             s = st.req.sampling
             self._temps[slot] = s.temperature
@@ -3461,12 +3587,156 @@ class TpuEngine:
                 st.slot, first : first + len(st.summary_ids)
             ] = st.summary_ids
 
+    # -- pages by layer kind: the windowed groups (allocator.WindowGroup) ----
+    def _hit_blocks(self, hashes, n: int) -> int:
+        """The longest prefix hit, in blocks and at most ``n``, that every
+        windowed group can restore: a hit of ``P`` tokens needs each such
+        group's pages covering ``[P - window, P)``. Where one is gone (its
+        pool gave it up) the hit is shortened to end at it, and looked at
+        again; 0 declines the hit. Never taken on a page that is not there."""
+        bs = self.cfg.block_size
+        while n > 0:
+            for grp in self._win_groups:
+                lo = grp.first_needed(n * bs)
+                gone = next(
+                    (i for i in range(n - 1, lo - 1, -1)
+                     if grp.allocator.lookup(hashes[i]) is None), None,
+                )
+                if gone is not None:
+                    n = gone
+                    break
+            else:
+                break
+        return n
+
+    def _admit_windows(self, st: _Seq, hashes, prefix_blocks: int) -> bool:
+        """Admission in every windowed group, or the request waits (False,
+        nothing held): the pages of a hit's last window pinned, the pool able
+        to give what the request will hold at most while it prefills, and its
+        first chunk's pages taken."""
+        if not self._win_groups:
+            return True
+        bs = self.cfg.block_size
+        P = prefix_blocks * bs
+        prompt_pages = -(-len(st.seq) // bs)
+        st.win_first, st.win_ids = [], []
+        for grp in self._win_groups:
+            lo = grp.first_needed(P)
+            st.win_first.append(lo)
+            st.win_ids.append(grp.allocator.acquire([
+                grp.allocator.lookup(h) for h in hashes[lo:prefix_blocks]
+            ]))
+            most = min(prompt_pages - lo, grp.pages) - (prefix_blocks - lo)
+            if not grp.allocator.can_allocate(most):
+                self._release_windows(st)
+                return False
+        if self._slide(st, P, min(len(st.seq), P + self.cfg.prefill_chunk)) is None:
+            self._release_windows(st)
+            return False
+        return True
+
+    def _slide(self, st: _Seq, q: int, upto: int):
+        """Every windowed group of ``st`` moved on: the pages that lie wholly
+        behind the window of a query at position ``q`` (the earliest any
+        later step of ``st`` can ask) are let go (a reference dropped: a page
+        a cached prefix or another request shares stays, and nothing is
+        written in place), and pages are taken so that positions below
+        ``upto`` have one. Returns the pages taken a group (for a caller
+        that rolls back), or None, and nothing taken, where a pool cannot
+        give them."""
+        bs = self.cfg.block_size
+        taken: List[int] = []
+        for g, grp in enumerate(self._win_groups):
+            ids = st.win_ids[g]
+            drop = min(max(grp.first_needed(q) - st.win_first[g], 0), len(ids))
+            if drop:
+                grp.allocator.release(ids[:drop], behind=True)
+                del ids[:drop]
+                st.win_first[g] += drop
+                grp.released += drop
+            if not ids:
+                st.win_first[g] = max(st.win_first[g], grp.first_needed(q))
+            more = -(-upto // bs) - (st.win_first[g] + len(ids))
+            if more > 0:
+                try:
+                    new_ids = grp.allocator.allocate(more)
+                except OutOfBlocks:
+                    self._unslide(st, taken)
+                    return None
+                st.win_ids[g] = ids + new_ids
+                ids = st.win_ids[g]
+            taken.append(max(more, 0))
+            if len(ids) > grp.pages:
+                raise RuntimeError(
+                    f"a row holds {len(ids)} pages of a windowed group whose "
+                    f"table has {grp.pages}"
+                )
+            # most calls move nothing (a token inside its page): the row's
+            # run is written again only where it changed
+            if (drop or more > 0) and st.slot >= 0 and self._slots[st.slot] is st:
+                self._table_window(st, g)
+        return taken
+
+    def _slide_chunk(self, st: _Seq) -> bool:
+        """Before ``st``'s next prefill chunk is launched: its windowed
+        groups moved on to the chunk (True at once for a family of one
+        group). False: a pool cannot give the pages now and the chunk waits
+        for the other rows to let some go; where no other row is left to do
+        so, the request ends as one that ran out of pages does."""
+        if not self._win_groups:
+            return True
+        start = st.prefill_pos
+        upto = min(len(st.seq), start + self.cfg.prefill_chunk)
+        if self._slide(st, start, upto) is not None:
+            return True
+        if not any(s is not None and s is not st and not s.done
+                   for s in self._slots):
+            st.done = True
+            st.out_queue.put_nowait(BackendOutput(
+                finish_reason=FINISH_LENGTH, cumulative_tokens=st.produced,
+            ))
+        return False
+
+    def _unslide(self, st: _Seq, taken: List[int]) -> None:
+        """Give back the pages ``_slide`` took last (its return)."""
+        for g, n in enumerate(taken):
+            if n:
+                ids = st.win_ids[g]
+                self._win_groups[g].allocator.release(ids[-n:])
+                del ids[-n:]
+                if st.slot >= 0 and self._slots[st.slot] is st:
+                    self._table_window(st, g)
+
+    def _table_window(self, st: _Seq, g: int) -> None:
+        """``st``'s run of windowed group ``g`` into its row of the table,
+        and behind it the page index the run starts at."""
+        grp, ids = self._win_groups[g], st.win_ids[g]
+        row = self._block_tables[st.slot]
+        row[grp.col : grp.col + grp.pages] = 0
+        row[grp.col : grp.col + len(ids)] = ids
+        row[grp.col + grp.pages] = st.win_first[g]
+
+    def _commit_windows(self, st: _Seq, block: int, seq_hash) -> None:
+        """A sealed block becomes content-addressed in every windowed group
+        that still holds its page."""
+        for g, grp in enumerate(self._win_groups):
+            i = block - st.win_first[g]
+            if 0 <= i < len(st.win_ids[g]):
+                grp.allocator.commit(st.win_ids[g][i], seq_hash)
+
+    def _release_windows(self, st: _Seq) -> None:
+        for grp, ids in zip(self._win_groups, st.win_ids):
+            grp.allocator.release(ids)
+        st.win_first, st.win_ids = [], []
+
     def _release(self, st: _Seq) -> None:
-        """Everything ``st`` holds back to the free lists: its pages and,
-        under a ring, the summary blocks of the windows it opened."""
+        """Everything ``st`` holds back to the free lists: its pages (of
+        every group) and, under a ring, the summary blocks of the windows it
+        opened."""
         self.allocator.release(st.block_ids)
         if st.summary_ids:
             self.summary_allocator.release(st.summary_ids)
+        self._release_windows(st)
         st.block_ids, st.summary_ids = [], []
 
     def _bucket(self, n: int) -> int:
@@ -3488,6 +3758,7 @@ class TpuEngine:
         upto = min(st.prefill_pos // self.cfg.block_size, len(hashes))
         for i in range(st.commit_upto, upto):
             self.allocator.commit(st.block_ids[i], hashes[i])
+            self._commit_windows(st, i, hashes[i])
             if self.kvbm is not None:
                 self._offload_pending.append((st.block_ids[i], hashes[i], 0))
         if upto > st.commit_upto and self.kv_commits is not None:
@@ -3752,12 +4023,13 @@ class TpuEngine:
         # chunked: the caller pre-allocated temporary pages (loop thread
         # owns the allocator); each chunk writes KV + attends over the
         # gathered prefix, the final chunk yields the pooled vector
-        if self.state is not None or self._ring is not None:
+        if self.state is not None or self._ring is not None or self._win_groups:
             raise ValueError(
                 "an embedding input above the largest prefill bucket is not "
-                "served by a family with slot state or a ring: temporary "
-                "pages carry keys from chunk to chunk, nothing carries a "
-                "recurrent state or a window's summaries"
+                "served by a family with slot state, a ring or pages by "
+                "layer kind: temporary pages of ONE table carry keys from "
+                "chunk to chunk, nothing carries a recurrent state, a "
+                "window's summaries or a second group's table"
             )
         cap = self.cfg.prefill_chunk
         table = np.zeros(self._table_width, np.int32)
@@ -3907,6 +4179,7 @@ class TpuEngine:
         ring = self._ring
         # (sequence, pages, summary blocks): rollback on partial failure
         granted: List[Tuple[_Seq, int, int]] = []
+        slid: List[Tuple[_Seq, List[int]]] = []
         ok = True
         for i, st in enumerate(seqs):
             if st is None or st.done or not st.prefilled:
@@ -3926,6 +4199,14 @@ class TpuEngine:
                     break
                 if len(st.summary_ids) > held:
                     granted.append((st, 0, len(st.summary_ids) - held))
+            if self._win_groups:
+                # every windowed group: behind the host's last token let
+                # go, a page for every position booked here
+                took = self._slide(st, len(st.seq) - 1, L + extra_tokens + 1)
+                if took is None:
+                    ok = False
+                    break
+                slid.append((st, took))
             extra = needed - len(st.block_ids)
             if extra > 0:
                 if not self.allocator.can_allocate(extra):
@@ -3942,6 +4223,8 @@ class TpuEngine:
                     self._block_tables[st.slot, base + off] = bid
                 granted.append((st, len(new_ids), 0))
         if not ok:
+            for st, took in slid:
+                self._unslide(st, took)
             for st, count, blocks in granted:
                 if count:
                     taken = st.block_ids[-count:]
@@ -4603,6 +4886,9 @@ class TpuEngine:
                         self.allocator.commit(
                             st.block_ids[sealed.position], sealed.sequence_hash
                         )
+                        self._commit_windows(
+                            st, sealed.position, sealed.sequence_hash
+                        )
                         if self.kvbm is not None:
                             self._offload_pending.append(
                                 (st.block_ids[sealed.position], sealed.sequence_hash, 1)
@@ -4615,6 +4901,10 @@ class TpuEngine:
                         needed_blocks, windows = self._ring.held(L_before + 2)
                         if not self._take_summary_blocks(st, windows):
                             finish = FINISH_LENGTH
+                    if self._win_groups and self._slide(
+                        st, len(st.seq) - 1, L_before + 2
+                    ) is None:
+                        finish = FINISH_LENGTH  # out of memory: end gracefully
                     if needed_blocks > len(st.block_ids):
                         try:
                             (new_id,) = self.allocator.allocate(1)
@@ -4852,6 +5142,20 @@ class TpuEngine:
                 # the slot store's bytes, whatever recurrence fills it
                 "ssm_state_bytes": occupancy * self.state.bytes_per_slot,
             })
+        if self._win_groups:
+            # pages by layer kind: what the live rows hold a group, what
+            # their windows let go since the last StepStats
+            live = [s for s in self._slots if s is not None and not s.done]
+            reads["page_groups_held"] = (
+                sum(len(s.block_ids) for s in live),
+                *(sum(len(s.win_ids[g]) for s in live if s.win_ids)
+                  for g in range(len(self._win_groups))),
+            )
+            reads["page_groups_released"] = (
+                0, *(grp.released for grp in self._win_groups)
+            )
+            for grp in self._win_groups:
+                grp.released = 0
         try:
             hook(StepStats(
                 phase=phase,
@@ -4991,6 +5295,19 @@ class TpuEngine:
                 "summary_blocks": self.summary_allocator.num_blocks - 1,
                 "summary_blocks_free": self.summary_allocator.free_blocks,
             }
+        if self._win_groups:
+            # pages by layer kind: each windowed group's geometry and pool
+            snap["page_groups"] = [
+                {
+                    "layers": list(g.layers), "window": g.window,
+                    "table_pages": g.pages,
+                    "blocks": g.allocator.num_blocks - 1,
+                    "active_blocks": g.allocator.active_blocks,
+                    "cached_blocks": g.allocator.cached_blocks,
+                    "free_blocks": g.allocator.free_blocks,
+                }
+                for g in self._win_groups
+            ]
         if self.cfg.spec_draft is not None:
             snap["spec"] = dict(self.spec_stats)
         if self._eplb_enabled:
@@ -5030,6 +5347,8 @@ class TpuEngine:
         if "g1" in levels:
             before = self.allocator.cached_blocks
             self.allocator.clear()
+            for grp in self._win_groups:
+                grp.allocator.clear()
             # clear() intentionally emits no per-hash events (comment there):
             # the wholesale CLEARED event resets this worker in the indexer
             if self.kv_publisher is not None:

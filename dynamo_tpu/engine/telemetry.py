@@ -326,6 +326,21 @@ class StepStats:
     eva_summaries_read: Optional[int] = None
     eva_windows_closed: Optional[int] = None
     eva_decode_steps: Optional[int] = None
+    # a family whose pages are kept BY LAYER KIND (registry.page_groups with
+    # more than one group; models/cohere2_moe.py), a number a group in the
+    # family's order (the group that lives as long as the request first):
+    # the pages the live rows hold, on the host, and the pages its rows let
+    # go behind their windows since the last StepStats (0 for a group whose
+    # pages live as long as the request). None for one group
+    page_groups_held: Optional[Tuple[int, ...]] = None
+    page_groups_released: Optional[Tuple[int, ...]] = None
+    # ... and on the step's own readback beside moe_*: the keys the step's
+    # real decode rows read in sliding and in full layers, and those rows,
+    # each summed over rows and the layers of its kind
+    win_keys_read: Optional[int] = None
+    full_keys_read: Optional[int] = None
+    win_decode_rows: Optional[int] = None
+    full_decode_rows: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -529,6 +544,20 @@ class EngineTelemetry:
                 name: sum(getattr(s, f"eva_{name}") or 0 for s in recent)
                 for name in ("rows_attended", "window_keys", "summaries_read",
                              "windows_closed", "decode_steps")
+            }
+        if last is not None and last.page_groups_held is not None:
+            # pages by layer kind: what the live rows hold a group, and what
+            # the window's steps let go behind their windows
+            out["page_groups"] = {
+                "held": list(last.page_groups_held),
+                "released": [
+                    sum(col) for col in zip(*(
+                        s.page_groups_released for s in recent
+                        if s.page_groups_released is not None
+                    ))
+                ],
+                "win_keys_read": sum(s.win_keys_read or 0 for s in recent),
+                "full_keys_read": sum(s.full_keys_read or 0 for s in recent),
             }
         return out
 

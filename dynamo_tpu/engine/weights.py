@@ -34,6 +34,8 @@ def config_from_hf(path: str) -> LlamaConfig:
         return _falcon_h1_config_from_hf(hf)
     if hf.get("model_type", "") == "solar_open2":
         return _solar_open2_config_from_hf(hf)
+    if hf.get("model_type", "") == "cohere2_moe":
+        return _cohere2_moe_config_from_hf(hf)
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return LlamaConfig(
         vocab_size=hf["vocab_size"],
@@ -90,6 +92,43 @@ def _solar_open2_config_from_hf(hf: dict):
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
         num_shared_experts=hf.get("n_shared_experts", 1),
+    )
+
+
+def _cohere2_moe_config_from_hf(hf: dict):
+    """Command A+'s config.json -> Cohere2MoeConfig: every key that shapes
+    the computation (benchmarks/adapters/cohere2_moe.py is the benchmark's
+    own copy of this mapping, with its held share of the experts)."""
+    from ..models.cohere2_moe import Cohere2MoeConfig
+
+    L = hf["num_hidden_layers"]
+    if (not hf.get("use_parallel_block", True) or hf.get("use_qk_norm")
+            or hf.get("attention_bias") or hf.get("first_k_dense_replace")
+            or hf.get("position_embedding_type", "rope_gptj") != "rope_gptj"
+            or hf.get("expert_selection_fn", "sigmoid") != "sigmoid"):
+        raise ValueError("cohere2_moe with a sequential block, a q/k norm, "
+                         "biases, leading dense layers, another rotary layout "
+                         "or another router is not built")
+    return Cohere2MoeConfig(
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"],
+        layer_types=tuple(hf["layer_types"][:L]),
+        sliding_window=int(hf["sliding_window"]),
+        rope_theta=float(hf.get("rope_theta", 50000.0)),
+        layer_norm_eps=float(hf.get("layer_norm_eps", 1e-5)),
+        max_position=hf.get("max_position_embeddings", 8192),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        logit_scale=float(hf.get("logit_scale", 1.0)),
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["intermediate_size"],
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        num_shared_experts=hf.get("num_shared_experts", 0),
+        shared_expert_combination=hf.get("shared_expert_combination_strategy", "average"),
     )
 
 
@@ -306,6 +345,10 @@ def load_params(path: str, cfg: Optional[LlamaConfig] = None) -> Dict[str, Any]:
             "the checkpoint's tensors onto its pytree has not been held to a "
             "real checkpoint (ROADMAP R11)"
         )
+    from ..models.cohere2_moe import Cohere2MoeConfig
+
+    if isinstance(cfg, Cohere2MoeConfig):
+        return _load_params_cohere2_moe(path, cfg)
     if isinstance(cfg, MlaConfig):
         return _load_params_mla(path, cfg)
     if isinstance(cfg, GptOssConfig):
@@ -378,6 +421,66 @@ def _deinterleave_rope_rows(w: np.ndarray, nope: int, rope: int, heads: int) -> 
     perm = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
     w = np.concatenate([w[:, :nope, :], rot[:, perm, :]], axis=1)
     return w.reshape(out, inner)
+
+
+def _load_params_cohere2_moe(path: str, cfg) -> Dict[str, Any]:
+    """Map a cohere2_moe checkpoint onto the models/cohere2_moe.py pytree.
+    The checkpoint rotates pairs ``(2i, 2i + 1)`` (``rope_gptj``); our
+    apply_rope is rotate-half, so ``q_proj`` / ``k_proj`` rows of the layers
+    that rotate are de-interleaved a head (``_deinterleave_rope_rows`` over
+    the whole head). The attention, norm and embedding names are the cohere2
+    lineage's; the expert names (``mlp.gate``, ``mlp.experts.E.*``,
+    ``mlp.shared_experts.J.*``) are ASSUMED from the MoE lineages the
+    repository loads and have not been held to a real checkpoint (ROADMAP
+    R11). The shared experts go side by side into one SwiGLU."""
+    dt, d = cfg.dtype, cfg.head_dim
+    layers: list = [dict() for _ in range(cfg.num_layers)]
+    params: Dict[str, Any] = {"layers": layers}
+    experts: Dict[tuple, np.ndarray] = {}
+
+    def put(arr: np.ndarray) -> jnp.ndarray:
+        return jnp.asarray(arr, dt)
+
+    for name, w in _open_safetensors(path):
+        if name == "model.embed_tokens.weight":
+            params["embed"] = put(w)
+        elif name == "model.norm.weight":
+            params["final_norm"] = put(w)
+        elif name == "lm_head.weight":
+            params["lm_head"] = put(w.T)
+        elif name.startswith("model.layers."):
+            parts = name.split(".")
+            li, rest = int(parts[2]), ".".join(parts[3:])
+            if li >= cfg.num_layers:
+                continue
+            lp, rotates = layers[li], cfg.window_for_layer(li) is not None
+            if rest == "input_layernorm.weight":
+                lp["norm"] = put(w)
+            elif rest in ("self_attn.q_proj.weight", "self_attn.k_proj.weight"):
+                heads = cfg.num_heads if "q_proj" in rest else cfg.num_kv_heads
+                w = _deinterleave_rope_rows(w, 0, d, heads) if rotates else w
+                lp["wq" if "q_proj" in rest else "wk"] = put(w.T)
+            elif rest == "self_attn.v_proj.weight":
+                lp["wv"] = put(w.T)
+            elif rest == "self_attn.o_proj.weight":
+                lp["wo"] = put(w.T)
+            elif rest == "mlp.gate.weight":
+                lp["w_router"] = put(w.T)
+            elif rest.startswith(("mlp.experts.", "mlp.shared_experts.")):
+                kind, e, proj = parts[4], int(parts[5]), parts[6]
+                experts[(li, kind, e, proj)] = w
+            else:
+                log.debug("ignoring unmapped tensor %s", name)
+        else:
+            log.debug("ignoring unmapped tensor %s", name)
+    for li, lp in enumerate(layers):
+        for proj, leaf in (("gate_proj", "gate"), ("up_proj", "up"), ("down_proj", "down")):
+            routed = [experts[(li, "experts", e, proj)].T for e in range(cfg.num_experts)]
+            shared = [experts[(li, "shared_experts", j, proj)].T
+                      for j in range(cfg.num_shared_experts)]
+            lp[f"w_e{leaf}"] = put(np.stack(routed))
+            lp[f"w_shared_{leaf}"] = put(np.concatenate(shared, axis=0 if leaf == "down" else 1))
+    return params
 
 
 def _load_params_gemma(path: str, cfg) -> Dict[str, Any]:

@@ -32,6 +32,7 @@ only).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import List, Optional, Tuple
@@ -459,7 +460,11 @@ def routed_shared_ffn(
     """Routed experts (the grouped path above fed by ``route_scored``, or a
     mesh-aware ``expert_fn`` injected by the registry for EP) + the always-on
     shared-expert SwiGLU. ``matmul`` is the grouped path's multiplication
-    (the Pallas kernel where the registry turns it on)."""
+    (the Pallas kernel where the registry turns it on). A family whose
+    shared experts are COMBINED otherwise than by their sum says so in
+    ``cfg.shared_expert_scale`` (an average of n: 1 / n on the one SwiGLU of
+    their summed width), and names the shared branch for a device trace in
+    ``cfg.shared_scope``; a family that states neither runs as it did."""
     routed = route_scored(p, cfg, x)
     if expert_fn is not None:
         y = expert_fn(expert_stacks(p), x, routed)
@@ -469,8 +474,12 @@ def routed_shared_ffn(
             matmul=matmul, held=cfg.experts_held,
         )
     if cfg.num_shared_experts > 0:
-        sg = jax.nn.silu((x @ p["w_shared_gate"]).astype(jnp.float32)).astype(x.dtype)
-        y = y + (sg * (x @ p["w_shared_up"])) @ p["w_shared_down"]
+        scope = getattr(cfg, "shared_scope", None)
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            sg = jax.nn.silu((x @ p["w_shared_gate"]).astype(jnp.float32)).astype(x.dtype)
+            shared = (sg * (x @ p["w_shared_up"])) @ p["w_shared_down"]
+            scale = getattr(cfg, "shared_expert_scale", 1.0)
+            y = y + (shared if scale == 1.0 else shared * scale)
     return y
 
 

@@ -13,7 +13,9 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas_moe import grouped_matmul, grouped_matmul_reference
 from ..parallel.mesh import AXIS_TP, shard_map
-from . import evabyte, falcon_h1, gemma, gptoss, llama, mla, moe, solar_open2
+from . import (
+    cohere2_moe, evabyte, falcon_h1, gemma, gptoss, llama, mla, moe, solar_open2,
+)
 
 
 def is_moe(cfg) -> bool:
@@ -44,14 +46,20 @@ def is_evabyte(cfg) -> bool:
     return isinstance(cfg, evabyte.EvaByteConfig)
 
 
+def is_cohere2_moe(cfg) -> bool:
+    return isinstance(cfg, cohere2_moe.Cohere2MoeConfig)
+
+
 def supports_pp(cfg) -> bool:
     """Pipeline-parallel serving covers the dense llama family only: the
     stage placement stacks per-layer params homogeneously, which MoE expert
     stacks, MLA latent projections, gpt-oss/gemma windowed-attention extras,
     a family's slot state (a state-space mixer's, a linear-attention
-    layer's) and a ring with summaries do not fit (parallel/pp_serving.py)."""
+    layer's), a ring with summaries and a parallel block with experts do not
+    fit (parallel/pp_serving.py)."""
     return not (is_moe(cfg) or is_mla(cfg) or is_gptoss(cfg) or is_gemma(cfg)
-                or is_falcon_h1(cfg) or is_solar_open2(cfg) or is_evabyte(cfg))
+                or is_falcon_h1(cfg) or is_solar_open2(cfg) or is_evabyte(cfg)
+                or is_cohere2_moe(cfg))
 
 
 def check_pp_supported(cfg) -> None:
@@ -62,7 +70,8 @@ def check_pp_supported(cfg) -> None:
     if not supports_pp(cfg):
         raise ValueError(
             f"pp serving supports dense llama-family models only; "
-            f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1/solar-open2/evabyte) is not "
+            f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1/solar-open2/evabyte/"
+            f"cohere2-moe) is not "
             f"stacked for pipeline stages — configure this preset with pp=1 "
             f"(use tp/sp/dp instead)"
         )
@@ -71,8 +80,10 @@ def check_pp_supported(cfg) -> None:
 def counts_routing(cfg) -> bool:
     """Whether the family's one-chip forward takes a ``stats`` collector
     (moe.RoutingStats): the grouped expert path of MoeConfig, of an
-    MlaConfig with experts, and of SolarOpen2Config (every layer routes)."""
-    return is_moe(cfg) or (is_mla(cfg) and cfg.num_experts > 0) or is_solar_open2(cfg)
+    MlaConfig with experts, of SolarOpen2Config and of Cohere2MoeConfig
+    (every layer routes)."""
+    return (is_moe(cfg) or (is_mla(cfg) and cfg.num_experts > 0)
+            or is_solar_open2(cfg) or is_cohere2_moe(cfg))
 
 
 def expert_stack_leaves(cfg) -> tuple:
@@ -173,6 +184,19 @@ def page_layers(cfg) -> tuple:
     block table still serves every page layer."""
     own = getattr(family(cfg), "page_layers", None)
     return own(cfg) if own else tuple(range(cfg.num_layers))
+
+
+def page_groups(cfg) -> tuple:
+    """The page layers in GROUPS, each ``(layers, lifetime)``: ``lifetime``
+    None (a page lives as long as its request) or a window in positions (a
+    page is let go once it lies wholly behind ``position - window``). A
+    group has page arrays, a ``BlockAllocator`` and a table a row of its
+    own; the group that lives as long as the request comes first. Every
+    family answers ONE group of all its ``page_layers`` with lifetime None,
+    and the engine builds for it what it always built, unless its module
+    says otherwise (``cohere2_moe.page_groups``: pages by layer kind)."""
+    own = getattr(family(cfg), "page_groups", None)
+    return own(cfg) if own else ((page_layers(cfg), None),)
 
 
 def state_layers(cfg) -> tuple:
@@ -280,6 +304,47 @@ def check_eva_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
     _refuse(what, refusals)
 
 
+def check_groups_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
+                            kv_quantized=False, vision=False, transfer=False,
+                            kvbm=False) -> None:
+    """A family whose pages are kept by layer kind (more than one
+    ``page_groups``) runs on the one-chip text path; what it cannot do yet
+    is refused here, at engine construction (``transfer``: where the
+    transfer plane is asked for), each with its reason."""
+    groups = page_groups(cfg)
+    if len(groups) == 1:
+        return
+    if groups[0][1] is not None:
+        raise ValueError(
+            f"{type(cfg).__name__}: the first page group lives as long as "
+            "the request (the engine's own table and allocator are its)"
+        )
+    what = f"pages kept by layer kind ({type(cfg).__name__})"
+    refusals = [
+        (tp > 1, "tp > 1: the groups' pools are not sharded by heads yet "
+                 "(a pool's sharding and its table a group), and a held "
+                 "share of the experts is already one chip's"),
+        (pp > 1 or sp > 1, "pp / sp > 1: the wavefront stacks ONE pool over "
+                           "its stages and the ring attends one table; "
+                           "neither knows a group's table or its shift"),
+        (spec, "a speculative draft: its shadow cache is addressed by the "
+               "ONE table of the main cache, and verify rows would read "
+               "pages a window has let go"),
+        (lora, "LoRA: the family has no adapter path"),
+        (kv_quantized, "kv_dtype=int8: the scale rows are sized by ONE "
+                       "pool's page count, and the family's cell holds its "
+                       "pages to bf16"),
+        (vision, "vision: multimodal serving covers the dense family only"),
+        (transfer, "the KV transfer plane (disaggregation, evacuation): it "
+                   "moves the pages of ONE table by position over every "
+                   "page layer"),
+        (kvbm, "KVBM offload tiers: kvbm/layout.py counts num_layers pages "
+               "a block hash, and a windowed group's pages are let go "
+               "under a live request"),
+    ]
+    _refuse(what, refusals)
+
+
 def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
                           kv_quantized=False, vision=False, transfer=False,
                           kvbm=False) -> None:
@@ -312,6 +377,8 @@ def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
 
 
 def family(cfg):
+    if is_cohere2_moe(cfg):
+        return cohere2_moe
     if is_evabyte(cfg):
         return evabyte
     if is_solar_open2(cfg):
@@ -366,15 +433,15 @@ def forward_fn(cfg, mesh=None, use_pallas: bool = False,
         return falcon_h1.forward
     if is_evabyte(cfg):
         return evabyte.forward
-    if is_solar_open2(cfg):
+    if is_solar_open2(cfg) or is_cohere2_moe(cfg):
         # the held (or replicated) experts' grouped path, its multiplication
         # as for MlaConfig below; tp > 1 is refused at construction
+        fwd = family(cfg).forward
         if use_pallas:
             return partial(
-                solar_open2.forward,
-                matmul=partial(grouped_matmul, interpret=interpret),
+                fwd, matmul=partial(grouped_matmul, interpret=interpret)
             )
-        return solar_open2.forward
+        return fwd
     if is_gptoss(cfg):
         if mesh is None or mesh.shape.get(AXIS_TP, 1) == 1:
             return gptoss.forward
@@ -507,6 +574,10 @@ def param_specs(cfg) -> dict:
             "w_down": P(AXIS_TP, None),
             "mu": P(AXIS_TP, None), "phi": P(AXIS_TP, None),
         })
+        return {"top": top, "layer": layer, "default": P()}
+    if is_cohere2_moe(cfg):
+        # tp > 1 is refused at construction (check_groups_supported): the
+        # attention's specs are the dense family's, everything else replicates
         return {"top": top, "layer": layer, "default": P()}
     if is_solar_open2(cfg):
         # tp > 1 is refused at construction (check_state_supported): the

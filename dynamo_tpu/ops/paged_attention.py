@@ -54,9 +54,18 @@ straight through.
   are read their summaries are written (``summarise_chunk``,
   ``summarise_rows``, scope ``eva_summarise``): a chunk's summary is final
   once its page is full, and the step that fills the page writes it.
+- A family whose pages are kept BY LAYER KIND (models/registry.page_groups)
+  hands the launches above the rows of a layer's OWN group (``GroupView``):
+  a windowed group's run of a row's table, which starts at the oldest page
+  the row still holds, with lengths and query offsets shifted by that page's
+  first position. Causal and window masks depend on differences of positions
+  only, and keys are rotated before they are written, so the launches run as
+  they are.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +73,52 @@ from jax.sharding import Mesh
 
 from ..parallel.mesh import AXIS_TP
 from . import attention as att
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupView:
+    """One page group's share of a row's block table, for a family whose
+    pages are kept by layer kind (engine/allocator.WindowGroup has the
+    host's side): the group's pages at columns ``col .. col + pages`` and,
+    for a windowed group (``shifted``), the page index its run starts at in
+    column ``col + pages``. A step program's ``attend`` finds a layer's view
+    by the layer's place among the page layers and asks it for the rows as
+    that group's launch takes them."""
+
+    col: int
+    pages: int
+    page: int                  # tokens a page
+    shifted: bool = True
+
+    def _run(self, tables):
+        """(the group's columns, the position its first entry starts at)."""
+        run = tables[..., self.col : self.col + self.pages]
+        if not self.shifted:
+            return run, jnp.zeros(tables.shape[:-1], jnp.int32)
+        return run, tables[..., self.col + self.pages] * self.page
+
+    def rows(self, tables, seq_lens, live):
+        """Decode rows (one token a row, written at ``seq_lens - 1``) ->
+        (tables, lengths, write pages) of this group; scratch page 0 for a
+        row that is not ``live``."""
+        run, base = self._run(tables)
+        lens = jnp.where(seq_lens > 0, seq_lens - base, 0)
+        entry = jnp.clip((lens - 1) // self.page, 0, self.pages - 1)
+        pages = jnp.take_along_axis(run, entry[:, None], axis=1)[:, 0]
+        return run, lens, jnp.where(live, pages, 0)
+
+    def chunk(self, table, chunk_start, total_len, positions, ids):
+        """One chunk from ``chunk_start``, ``ids`` its pages in the group
+        that lives as long as the request (the host's) -> (table,
+        chunk_start, total_len, positions, the chunk's pages) of this
+        group. A windowed group's pages are read off its run: the run is as
+        wide as a window, a chunk and a page, so the slice never clamps."""
+        run, base = self._run(table)
+        if self.shifted:
+            ids = jax.lax.dynamic_slice(
+                run, ((chunk_start - base) // self.page,), ids.shape
+            )
+        return run, chunk_start - base, total_len - base, positions - base, ids
 
 
 class PagedAttention:

@@ -247,14 +247,14 @@ def _cases():
                         (r,) if window else ())
         return (windowed if window else pun.ragged_paged_attention), build
 
-    def grouped(rows_sorted, k, n, n_rhs):
+    def grouped(rows_sorted, k, n, n_rhs, experts=64):
         # the same cell's expert layer: 64 experts, hidden 2 304, width 896;
         # 128 sorted rows is a decode step (16 rows x top 8), 4 224 a
         # 512-token chunk beside 16 decode rows
         def build(sh):
             s, *_ = _shapes(sh)
-            return (s((rows_sorted, k), BF), s((64,), I32)) + (
-                s((64, k, n), BF),
+            return (s((rows_sorted, k), BF), s((experts,), I32)) + (
+                s((experts, k, n), BF),
             ) * n_rhs
 
         def fn(lhs, sizes, *rhs):
@@ -407,6 +407,27 @@ def _cases():
         "eva-decode-24-rows": eva("decode", 24, 24),
         "eva-chunk-S512": eva("chunk", 512, 1),
         "eva-mixed-S512-24-rows": eva("ragged", 536, 25),
+        # the contract-sessions cell (PR 49): 128 q / 8 kv heads, SIXTEEN
+        # query heads a kv head, pages by layer kind. The full layer's rows
+        # over tables of 2 112 pages of a 53 248-page pool (24 decode rows at
+        # 33k keys; a 512-token chunk beside them), the sliding layers' over
+        # their group's SHIFTED run of 290 pages of a 13 105-page pool (24
+        # one-token rows at 4 096 keys under the window; the chunk beside
+        # them), and the expert multiplication at 16 held experts of
+        # [4 096, 4 096]: 192 sorted rows is a decode step (24 rows x top 8),
+        # 4 288 a 512-token chunk beside them
+        "decode-bf16-128q-8kv-contract-cell-full": decode(
+            8, 128, 24, 2112, 53248),
+        "unified-128q-8kv-contract-cell-windowed-decode": unified_cell(
+            128, 8, 24, 24, 290, 13105, window=True),
+        "unified-128q-8kv-contract-cell-full-mixed": unified_cell(
+            128, 8, 512 + 24, 25, 2112, 53248),
+        "unified-128q-8kv-contract-cell-windowed-mixed": unified_cell(
+            128, 8, 512 + 24, 25, 290, 13105, window=True),
+        "grouped-matmul-gate-up-16x4096-rows192": grouped(
+            192, 4096, 4096, 2, experts=16),
+        "grouped-matmul-down-16x4096-rows4288": grouped(
+            4288, 4096, 4096, 1, experts=16),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),

@@ -87,7 +87,8 @@ RESOURCES: Tuple[ResourceSpec, ...] = (
         doc="KV cache pages booked from the engine allocator: every "
             "allocate/acquire_prefix must be released or appended to a "
             "sequence's block table (block_ids; under a ring, its summary "
-            "blocks by window, summary_ids) on every path out, or the "
+            "blocks by window, summary_ids; a windowed page group's run, "
+            "win_ids) on every path out, or the "
             "pool drains one failed dispatch at a time.",
         paths=("dynamo_tpu/engine/",),
         acquire=(
@@ -95,7 +96,7 @@ RESOURCES: Tuple[ResourceSpec, ...] = (
             ("acquire_prefix", ("allocator", "alloc")),
         ),
         release=(("release", ("allocator", "alloc")),),
-        owners=("block_ids", "summary_ids"),
+        owners=("block_ids", "summary_ids", "win_ids"),
         exempt_functions=("allocate", "acquire_prefix", "release"),
     ),
     ResourceSpec(
