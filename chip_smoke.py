@@ -891,6 +891,87 @@ def _kernel_child() -> None:
                   f"launch, {gb / t_launch:.0f} GB/s; the twin "
                   f"{t_twin * 1e3:.3f} ms", flush=True)
 
+    # the decode launch reads a whole chunk of consecutive pages as ONE
+    # descriptor an array (PR 50, ops/pallas_paged.PageReader) at the
+    # contract cell's widths (128 q / 8 kv heads, 32 KiB pages, 32 a chunk:
+    # 4 rows over 33.2k keys in tables of 2 112, one a tail of a page, one
+    # empty, one of 64 whole chunks exactly) and the sparse-expert cell's (32
+    # / 4, 16 KiB pages, 64 a chunk: 16 rows around 6.5k keys in tables of
+    # 448): over tables that are runs, then over the same pages at shuffled
+    # places of a second pool (every whole chunk page by page, one wait an
+    # array): each against the twin, both bitwise the same; then 40 launches
+    # back to back, runs and shuffled in turn (a miscounted semaphore shows
+    # on the chip only, as a hang or a stale row); and ms a launch, chained
+    # in one program (a launch alone is mostly its dispatch)
+    from dynamo_tpu.ops import pallas_attention as pa
+    from dynamo_tpu.ops import pallas_paged as ppaged
+
+    ref_decode = highest(att.paged_decode_attention)
+
+    @jax.jit
+    def chained_decode(q, kp, vp, tb, lens):
+        def body(_, q):
+            out = pa.paged_decode_attention(q, kp, vp, tb, lens)
+            return q.at[:, :, :1].add((out[:, :, :1] * 0).astype(q.dtype))
+        return jax.lax.fori_loop(0, 20, body, q)
+
+    def decode_runs_case(name, h, kvh, mb, lens):
+        rows = len(lens)
+        nb = rows * mb + 1
+        kp, vp = rnd(nb, BS, kvh, D), rnd(nb, BS, kvh, D)
+        run_tb = 1 + np.arange(rows * mb).reshape(rows, mb)
+        place = np.concatenate([[0], 1 + rng.permutation(nb - 1)])
+        back = jnp.asarray(np.argsort(place))
+        qd = rnd(rows, h, D)
+        cp = ppaged.chunk_pages(BS, kvh, D, bf, mb)
+        whole = sum(-(-n // BS) // cp for n in lens)
+        lens = jnp.asarray(lens, jnp.int32)
+        outs, launches = [], []
+        for kind, kpool, vpool, tb in (
+            ("runs", kp, vp, run_tb),
+            ("shuffled", kp[back], vp[back], place[run_tb]),
+        ):
+            tb = jnp.asarray(tb, jnp.int32)
+            as_runs = int(jnp.sum(ppaged.chunk_runs(tb, cp)))
+            if as_runs != (rows * (mb // cp) if kind == "runs" else 0):
+                raise SystemExit(f"{name}, {kind}: {as_runs} chunks are runs")
+            args = (qd, kpool, vpool, tb, lens)
+            got = pa.paged_decode_attention(*args)
+            live = np.asarray(lens) > 0
+            if np.asarray(got, np.float32)[~live].any():
+                raise SystemExit(f"{name}, {kind}: an empty row is not zeros")
+            compare(f"{name}, {kind}", np.asarray(got, np.float32)[live],
+                    np.asarray(ref_decode(*args), np.float32)[live])
+            jax.block_until_ready(chained_decode(*args))
+            t0 = time.perf_counter()
+            jax.block_until_ready(chained_decode(*args))
+            took = (time.perf_counter() - t0) / 20
+            print(f"KERNEL {name}, {kind} ({whole if kind == 'runs' else 0} "
+                  f"of {whole} whole chunks one descriptor an array): "
+                  f"{took * 1e3:.3f} ms a launch of 20 in one program",
+                  flush=True)
+            outs.append(got)
+            launches.append(args)
+        if not bool(jnp.all(outs[0] == outs[1])):
+            raise SystemExit(f"{name}: runs and shuffled pages give "
+                             "different bits")
+        stale = sum(
+            jnp.any(pa.paged_decode_attention(*launches[i % 2]) != outs[0])
+            for i in range(40)
+        )
+        if int(stale):
+            raise SystemExit(f"{name}: {int(stale)} of 40 launches back to "
+                             "back differ from the first")
+        print(f"KERNEL {name}: runs and shuffled pages bitwise equal, 40 "
+              "launches back to back bitwise the first", flush=True)
+
+    decode_runs_case(
+        "paged_decode_attention 128 q / 8 kv heads, 4 rows over 33.2k keys",
+        128, 8, 2112, [33200, 33281, 0, 32768])
+    decode_runs_case(
+        "paged_decode_attention 32 q / 4 kv heads, 16 rows over 6.5k keys",
+        32, 4, 448, [6500 - 37 * i for i in range(14)] + [1, 7168])
+
     # the state-space mixer's decode recurrence (PR 39) at Falcon-H1-34B's
     # widths, 128 rows of which some are dead: the state in place, live rows
     # only; and the decode question at the family's 5 query heads a kv head

@@ -351,6 +351,10 @@ class _Seq:
     # ``win_ids[g]`` the pages of page indexes ``win_first[g] ..``
     win_first: List[int] = dataclasses.field(default_factory=list)
     win_ids: List[List[int]] = dataclasses.field(default_factory=list)
+    # run_chunks[w]: of the first ``w`` whole chunks of ``block_ids`` (the
+    # decode kernel's chunks of pages), those that are consecutive block ids;
+    # grown as chunks fill (engine ``_count_paged``)
+    run_chunks: List[int] = dataclasses.field(default_factory=lambda: [0])
     produced: int = 0
     last_token: int = 0
     cached_tokens: int = 0
@@ -1315,6 +1319,11 @@ class TpuEngine:
         state_of = registry.layer_index(
             registry.state_layers(mcfg), mcfg.num_layers
         ) if self.state is not None else None
+        # page layer -> pages a chunk, for the layers whose decode rows the
+        # decode-only kernel serves: filled as ``rows_attend`` is traced,
+        # read by ``_count_paged``
+        paged_layers = self._paged_layers = {}
+        self._paged_counts = [0, 0]
 
         def call_fwd(params, tokens, positions, attend, lora_tables, lora_ids,
                      mm_embeds=None, mm_mask=None, moe_stats=None, mix=None):
@@ -1506,6 +1515,11 @@ class TpuEngine:
                         extra["eva"],
                     )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
+                cp = attn.decode_chunk_pages(kc, tables, extra)
+                if cp is not None:
+                    # known once traced: this layer's decode rows are the
+                    # decode-only kernel's, which walks chunks of ``cp`` pages
+                    paged_layers[layer_idx] = cp
                 out = attn.decode(q[:, 0], kc, vc, tables, lens, **extra)
                 return out[:, None]
             return attend
@@ -4661,6 +4675,12 @@ class TpuEngine:
         against a ~0.9ms/token device program)."""
         if chain.spec_k is not None:
             return self._apply_packed_spec(chain, packed_np)
+        # a consumed horizon: its snapshot's rows, ``decode_steps`` steps
+        # each from the context the host holds now (earlier links are read)
+        self._count_paged(
+            chain.seqs, [s and len(s.seq) for s in chain.seqs],
+            self.cfg.decode_steps,
+        )
         if self.state is not None:
             # a consumed horizon advanced each of its snapshot's rows until
             # the row had sampled what its request asked (decode_multi)
@@ -4811,6 +4831,7 @@ class TpuEngine:
              tlp_vals, tlp_ids) = self._decode_fn(*args)
             del args  # donated caches: hold no stale handles
             self._count_state(np.count_nonzero(seq_lens), 0, 1)
+            self._count_paged(seqs, seq_lens, 1)
         with loop_span(self, "sync"):
             results, self._moe_last = self._decode_results(
                 seqs, toks, lps, *((tlp_ids, tlp_vals) if lp_need else ())
@@ -5086,6 +5107,40 @@ class TpuEngine:
         c[1] += chunk_tokens * L
         c[2] += steps
 
+    def _count_paged(self, seqs, contexts, steps: int) -> None:
+        """Decode rows of a dispatch whose attention is the decode-only
+        kernel's in some layers (nothing where no layer's is): row ``i`` of
+        ``seqs`` attended over ``contexts[i] + k`` keys in step ``k`` of
+        ``steps``. Counts the whole chunks of pages under those contexts and
+        those of them that are runs of consecutive block ids, by the kernel's
+        own chunk rule (ops/pallas_paged.py), x the layers that launch it,
+        for the next StepStats: host arithmetic on ids the host holds. A
+        request's runs are looked at once a chunk, as it fills."""
+        if not self._paged_layers:
+            return
+        cp = next(iter(self._paged_layers.values()))
+        T, bs = cp * self.cfg.block_size, self.cfg.block_size
+        whole = run = 0
+        for st, ctx in zip(seqs, contexts):
+            if st is None or ctx <= 0:
+                continue
+            # step k attends over ctx + k keys: (ctx + k + bs - 1) // T whole
+            # chunks, of the pages the request holds
+            first, held = int(ctx) + bs - 1, len(st.block_ids) // cp
+            cum = st.run_chunks
+            for w in range(first // T, (first + steps - 1) // T + 1):
+                n = min(first + steps, (w + 1) * T) - max(first, w * T)
+                w = min(w, held)
+                while len(cum) <= w:
+                    ids = st.block_ids[(len(cum) - 1) * cp : len(cum) * cp]
+                    cum.append(cum[-1] + all(
+                        b - a == 1 for a, b in zip(ids, ids[1:])))
+                whole += w * n
+                run += cum[w] * n
+        layers = len(self._paged_layers)
+        self._paged_counts[0] += whole * layers
+        self._paged_counts[1] += run * layers
+
     def _step_stats(self, phase: str, duration_s: float, tokens: int,
                     link: Optional[_Chain] = None) -> None:
         """Feed one StepStats to the hook — scalars the loop already holds;
@@ -5142,6 +5197,10 @@ class TpuEngine:
                 # the slot store's bytes, whatever recurrence fills it
                 "ssm_state_bytes": occupancy * self.state.bytes_per_slot,
             })
+        if self._paged_layers:
+            reads["paged_chunks_whole"], reads["paged_chunks_run"] = (
+                self._paged_counts)
+            self._paged_counts = [0, 0]
         if self._win_groups:
             # pages by layer kind: what the live rows hold a group, what
             # their windows let go since the last StepStats

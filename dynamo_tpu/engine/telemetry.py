@@ -295,6 +295,14 @@ class StepStats:
     # (ops/pallas_latent.py): counted from the step's own tables
     mla_chunks_whole: Optional[int] = None
     mla_chunks_run: Optional[int] = None
+    # decode rows the decode-only kernel serves (ops/pallas_attention.py),
+    # in a decode step or horizon: the whole chunks of pages under the rows'
+    # contexts, x the steps each took x the layers that launch it, and those
+    # of them whose pages are consecutive block ids, which the kernel reads
+    # with one descriptor an array (ops/pallas_paged.PageReader): counted on
+    # the host from the ids it holds; None where no layer launches it
+    paged_chunks_whole: Optional[int] = None
+    paged_chunks_run: Optional[int] = None
     # a family with slot state beside its pages (a state-space mixer;
     # engine/state_cache.py), from the step's own shapes on the host: live
     # decode rows x layers whose recurrence the step advanced by one token
@@ -365,9 +373,10 @@ def moe_load_imbalance(s: StepStats) -> Optional[float]:
 
 
 def run_chunk_share(steps, reader: str = "mla") -> Optional[float]:
-    """Of the whole chunks the steps' latent rows read (``reader`` "mla") or
-    their indexers' keys were read by ("dsa_index"), the share read as runs
-    of consecutive pages; None where none was read."""
+    """Of the whole chunks the steps' latent rows read (``reader`` "mla"),
+    their indexers' keys were read by ("dsa_index") or their decode rows
+    read through the decode-only kernel ("paged"), the share read as runs of
+    consecutive pages; None where none was read."""
     whole = sum(getattr(s, f"{reader}_chunks_whole") or 0 for s in steps)
     run = sum(getattr(s, f"{reader}_chunks_run") or 0 for s in steps)
     return run / whole if whole else None
@@ -525,6 +534,13 @@ class EngineTelemetry:
                     "decode_rows": moe.mla_decode_rows,
                     "run_chunk_share": run_chunk_share(recent),
                 }
+        if any(s.paged_chunks_whole is not None for s in recent):
+            # the decode-only kernel's reads by the chunk, over the window
+            out["paged"] = {
+                "chunks_whole": sum(s.paged_chunks_whole or 0 for s in recent),
+                "chunks_run": sum(s.paged_chunks_run or 0 for s in recent),
+                "run_chunk_share": run_chunk_share(recent, "paged"),
+            }
         if last is not None and last.ssm_state_bytes is not None:
             # the second kind of state: what the window's steps advanced
             # (under the family's prefix: a state-space mixer "ssm", a

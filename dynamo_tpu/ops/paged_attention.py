@@ -289,6 +289,22 @@ class PagedAttention:
             page_view=self.use_pallas and self.mesh.size == 1,
         )
 
+    def decode_chunk_pages(self, kc, tables, extra):
+        """Pages a chunk of the decode-only kernel's walk over decode rows
+        asked with ``extra`` (``decode``'s keywords), or None where another
+        launch serves them: what the host counts a step's whole chunks and
+        runs of pages by (engine ``_count_paged``). Shapes only."""
+        if not self.use_pallas or extra:
+            return None
+        from . import pallas_paged as paged
+
+        pages = getattr(kc, "data", kc)     # QuantizedKV: its int8 payload
+        _, bs, kvh, d = pages.shape
+        return paged.chunk_pages(
+            bs, kvh // self.mesh.shape[AXIS_TP], d, pages.dtype,
+            tables.shape[1],
+        )
+
     def decode(self, q, kc, vc, tables, seq_lens, dsa=None, latent=None,
                eva=None, **extra):
         """Decode rows: ``q [B, h, d]``, one token a row at the end of a

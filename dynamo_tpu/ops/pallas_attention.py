@@ -40,8 +40,20 @@ may hold NaN, and 0 * NaN = NaN); full chunks skip both.
 In flight: while chunk ``c`` of a row is computed, chunk ``c + 1`` is being
 read into the other slot; while a row's last chunk is computed, the first
 chunk of the next non-empty row is. Rows with ``seq_len == 0`` (padding) read
-nothing, wait for nothing and return zeros. One DMA semaphore a slot and
-kind: every page copy of a chunk signals it, and it is waited once a page.
+nothing, wait for nothing and return zeros.
+
+How a chunk is read is ``pallas_paged.PageReader``'s rule, chosen a chunk
+from what the tables hold: a WHOLE chunk whose table entries are consecutive
+block ids (a prompt admitted in one go into a pool that hands out low ids
+first; ``chunk_runs`` of the tables, one compare in the launch's XLA wrapper,
+a third scalar-prefetch operand) is ONE descriptor an array, K and V; any
+other whole chunk is started page by page; both are waited for once an array
+(a DMA semaphore counts bytes). A row's tail chunk starts and waits page by
+page. The scalar unit issues descriptors in the products' instruction stream,
+12 ns each: at 32 KiB pages a chunk was 64 of them and at 16 KiB 128, on top
+of the products wherever those do not hide under the chunk's bytes (128 query
+heads; PERF.md section 6, PR 50 has the table). The output is bitwise the
+same whichever way a chunk came in.
 """
 
 from __future__ import annotations
@@ -65,6 +77,8 @@ def _decode_kernel(
     # scalar prefetch (SMEM)
     tables_ref,     # [B * max_blocks] int32 flattened block tables
     lens_ref,       # [B] int32 context lengths (incl. current token)
+    runs_ref,       # [B * (max_blocks // CP)] int32: which whole chunks of a
+    #                 table are consecutive block ids (pallas_paged.chunk_runs)
     # inputs
     q_ref,          # VMEM [B, h, d] every sequence's query
     k_hbm,          # ANY/HBM [num_blocks, bs * kvh, d] (model dtype or int8)
@@ -102,6 +116,7 @@ def _decode_kernel(
     pages = paged.PageReader(
         tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
         (ks_hbm, vs_hbm, ks_buf, vs_buf, ssem) if quantized else None,
+        runs_ref=runs_ref, chunk_pages=CP,
     )
 
     def pages_in_chunk(row, c):
@@ -109,7 +124,8 @@ def _decode_kernel(
 
     def start_chunk(row, c, slot):
         pages.start(
-            row * max_blocks + c * CP, pages_in_chunk(row, c), slot
+            row * max_blocks + c * CP, pages_in_chunk(row, c), slot,
+            row * (max_blocks // CP) + c,
         )
 
     def wait_chunk(row, c, slot):
@@ -250,7 +266,7 @@ def paged_decode_attention(
     if chunk_tokens is None:
         chunk_pages = paged.chunk_pages(bs, kvh, d, pages.dtype, max_blocks)
     else:
-        chunk_pages = max(1, chunk_tokens // bs)
+        chunk_pages = max(1, min(chunk_tokens // bs, max_blocks))
 
     kernel = functools.partial(
         _decode_kernel, max_blocks=max_blocks, chunk_pages=chunk_pages,
@@ -278,7 +294,7 @@ def paged_decode_attention(
     if quantized:
         scratch.append(pltpu.SemaphoreType.DMA((2, 2)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(1,),
         in_specs=[pl.BlockSpec((B, h, d), lambda i, *_: (0, 0, 0))]
         + cache_specs,
@@ -289,6 +305,7 @@ def paged_decode_attention(
     def rows(cache):  # [nb, bs, kvh, d] -> [nb, bs * kvh, d]: the same bytes
         return cache.reshape(nb, bs * kvh, d)
 
+    block_tables = block_tables.astype(jnp.int32)
     cache_args = (
         (rows(k_cache.data), rows(v_cache.data), k_cache.scale, v_cache.scale)
         if quantized else (rows(k_cache), rows(v_cache))
@@ -300,8 +317,10 @@ def paged_decode_attention(
         interpret=interpret,
         name=name,
     )(
-        block_tables.reshape(-1).astype(jnp.int32),
+        block_tables.reshape(-1),
         seq_lens.astype(jnp.int32),
+        paged.chunk_runs(block_tables, chunk_pages).reshape(-1).astype(
+            jnp.int32),
         q,
         *cache_args,
     )

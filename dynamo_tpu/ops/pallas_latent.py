@@ -42,16 +42,19 @@ position on are masked. The first program zeroes the latent's buffer, so a row
 of it holds zeros or a token ever after and a masked key's weight (exactly 0)
 never meets a NaN.
 
-How a chunk is read (``_LatentPages``) is chosen a chunk, from what the tables
-hold. The scalar unit issues copy descriptors in the same instruction stream
+How a chunk is read is chosen a chunk, from what the tables hold: the rule
+is ``pallas_paged.PageReader``'s since PR 50 (the decode kernel reads by it
+too) and ``_LatentPages`` adds only what a page and a run ARE in the
+token-row views. The scalar unit issues copy descriptors in the same instruction stream
 as the products, so a descriptor costs the launch its issue time whatever it
 moves (the bytes themselves arrive at 87% of the byte peak under the products).
 A WHOLE chunk whose ``chunk_pages`` table entries are consecutive block ids (a
-prompt admitted in one go into a pool that hands out low ids first; ``runs``,
-one compare of the tables in the launch's XLA wrapper, handed in as a fourth
-scalar-prefetch operand) is ONE descriptor an array: 1 MiB, contiguous in the
-token view. Every other whole chunk is started page by page, two descriptors
-a page, ``UNROLL`` pages a pass; either way it is waited for ONCE an array (a
+prompt admitted in one go into a pool that hands out low ids first;
+``pallas_paged.chunk_runs``, one compare of the tables in the launch's XLA
+wrapper, handed in as a fourth scalar-prefetch operand) is ONE descriptor an
+array: 1 MiB, contiguous in the token view. Every other whole chunk is
+started page by page, two descriptors a page, ``pallas_paged.UNROLL`` pages a
+pass; either way it is waited for ONCE an array (a
 DMA semaphore counts bytes). A tail chunk starts and waits page by page. On a
 v5e, 64 heads, 25 000-key contexts, launches chained inside one jit (PERF.md
 section 6, PR 34, with PR 33's unpack): 8 decode rows 1.199 ms as runs, 1.516
@@ -84,26 +87,7 @@ KERNEL_NAME = "paged_latent_attention"
 # 91% of the matrix unit's peak with the unpack under them (10.78 ms the same
 # chunk, 10.78 with no unpack at all), so a wider tile has nothing left to take
 Q_TILE = 16
-# pages a pass of the loop that starts, page by page, a whole chunk that is not
-# a run (the scalar unit issues one descriptor after the other, in the
-# products' instruction stream): 8 decode rows over 25k keys ran 2.35 / 2.20 /
-# 2.14 / 2.14 ms at 1 / 4 / 8 / 16, bitwise the same (PERF.md section 6, PR 33;
-# launches timed alone, 0.65 ms of dispatch in each). What is left at 8 is the
-# descriptors themselves: 1.516 ms against 1.199 where every whole chunk is a
-# run and 1.147 with no copy at all (PR 34)
-UNROLL = 8
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
-
-
-def chunk_runs(tables: jax.Array, cp: int) -> jax.Array:
-    """``[R, mb // cp]`` bool: which whole chunks of ``cp`` entries of each
-    table are consecutive block ids, so that a chunk's tokens lie one after
-    the other in the pool. Every neighbour is compared: first and last id
-    alone do not prove a run (``[5, 100, 7, 8]``)."""
-    R, mb = tables.shape
-    n = mb // cp
-    t = tables[:, :n * cp].reshape(R, n, cp)
-    return jnp.all(t[:, :, 1:] == t[:, :, :-1] + 1, axis=-1)
 
 
 def _chunk_pages(k_cache: jax.Array, mb: int) -> int:
@@ -119,7 +103,7 @@ def chunk_reads(k_cache: jax.Array, tables: jax.Array, q_lens: jax.Array,
     bs = k_cache.shape[1]
     cp = _chunk_pages(k_cache, tables.shape[1])
     n_pages = jnp.where(q_lens > 0, -(-seq_lens // bs), 0)
-    runs = chunk_runs(tables, cp)
+    runs = paged.chunk_runs(tables, cp)
     read = jnp.arange(runs.shape[1])[None, :] < (n_pages // cp)[:, None]
     return jnp.sum(read), jnp.sum(read & runs)
 
@@ -162,15 +146,15 @@ def _chunk_matrix(k_words, v_words, kcat, slot, T: int, lat_rows: int):
 
 
 class _LatentPages(paged.PageReader):
-    """A chunk's copies out of the token-row views, both arrays alike. A
-    whole chunk that ``runs_ref`` marks is ONE descriptor an array; any other
-    whole chunk is started ``UNROLL`` pages a pass; both are waited for once
-    an array. A tail chunk goes page by page (the base class)."""
+    """A chunk's copies out of the token-row views, both arrays alike: a page
+    is ``bs`` whole tokens, a run of pages one stretch of tokens. Which
+    chunk goes which way, and the one wait an array, are the base class's."""
 
     def __init__(self, tables_ref, runs_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
                  bs, cp):
-        super().__init__(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem)
-        self.runs_ref, self.bs, self.cp = runs_ref, bs, cp
+        super().__init__(tables_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
+                         runs_ref=runs_ref, chunk_pages=cp)
+        self.bs = bs
 
     def copies(self, slot, idx, j):
         src = pl.ds(idx * self.bs, self.bs)
@@ -180,56 +164,13 @@ class _LatentPages(paged.PageReader):
             for hbm, buf, sem in self.pairs
         ]
 
-    def start(self, base, num_pages, slot, chunk):
-        """``chunk``: the chunk's place in ``runs_ref`` (read only where the
-        chunk is whole: a row's tail chunk may lie past its last entry)."""
-        whole = num_pages == self.cp
-        unroll = UNROLL if self.cp % UNROLL == 0 else 1
-
-        @pl.when(whole)
-        def _chunk():
-            run = self.runs_ref[chunk] != 0
-
-            @pl.when(run)
-            def _run():
-                src = pl.ds(
-                    pl.multiple_of(self.tables_ref[base] * self.bs, self.bs),
-                    self.cp * self.bs,
-                )
-                for hbm, buf, sem in self.pairs:
-                    pltpu.make_async_copy(
-                        hbm.at[src], buf.at[slot], sem.at[slot]).start()
-
-            @pl.when(jnp.logical_not(run))
-            def _pages():
-                def group(g, carry):
-                    for i in range(unroll):
-                        j = g * unroll + i
-                        for copy in self.copies(
-                                slot, self.tables_ref[base + j], j):
-                            copy.start()
-                    return carry
-
-                jax.lax.fori_loop(0, self.cp // unroll, group, 0)
-
-        @pl.when(jnp.logical_not(whole))
-        def _tail():
-            super(_LatentPages, self).start(base, num_pages, slot)
-
-    def wait(self, num_pages, slot):
-        whole = num_pages == self.cp
-
-        @pl.when(whole)
-        def _chunk():
-            # never started: the descriptors say how many bytes to wait for,
-            # the same however the chunk was started
-            for _, buf, sem in self.pairs:
-                pltpu.make_async_copy(
-                    buf.at[slot], buf.at[slot], sem.at[slot]).wait()
-
-        @pl.when(jnp.logical_not(whole))
-        def _tail():
-            super(_LatentPages, self).wait(num_pages, slot)
+    def run_copies(self, slot, idx):
+        src = pl.ds(
+            pl.multiple_of(idx * self.bs, self.bs), self.cp * self.bs)
+        return [
+            pltpu.make_async_copy(hbm.at[src], buf.at[slot], sem.at[slot])
+            for hbm, buf, sem in self.pairs
+        ]
 
 
 def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
@@ -454,7 +395,8 @@ def paged_latent_attention(
         name=KERNEL_NAME,
     )(
         seq_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
-        tables.reshape(-1), chunk_runs(tables, cp).reshape(-1).astype(jnp.int32),
+        tables.reshape(-1),
+        paged.chunk_runs(tables, cp).reshape(-1).astype(jnp.int32),
         *operands,
         k_cache.reshape(nb * bs, n_rows, lanes),
         v_cache.reshape(nb * bs, n_rows, lanes),

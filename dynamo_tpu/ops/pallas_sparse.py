@@ -60,7 +60,7 @@ halves of the tile's words, as the dense ``[R, mb * bs, 128]`` bf16 array the
 scoring product takes. Grid: one program a chunk of ``INDEX_CHUNK_PAGES``
 pages of one table, in order, two slots: the next program's chunk is in
 flight while this one is unpacked. A whole chunk whose table entries are
-consecutive block ids (``pallas_latent.chunk_runs``, scalar-prefetched) is ONE
+consecutive block ids (``pallas_paged.chunk_runs``, scalar-prefetched) is ONE
 strided descriptor, any other ``INDEX_UNROLL`` pages a pass, both waited for
 once (a DMA semaphore counts bytes); a table's tail chunk goes page by page.
 The unpack is integer moves on whole registers (PR 47's lesson,
@@ -80,8 +80,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import LATENT_LANES, selected_token_rows
-from .pallas_latent import _pack_pairs, chunk_runs
-from .pallas_paged import NEG_INF
+from .pallas_latent import _pack_pairs
+from .pallas_paged import NEG_INF, chunk_runs
 
 KERNEL_NAME = "sparse_latent_attention"
 # selected tokens a chunk: 2 slots x (rows + 2) x 256 x 256 B. 512 reads level
@@ -104,7 +104,7 @@ INDEX_KERNEL_NAME = "paged_index_keys"
 # pool's chunks runs than a wider one would, for what 128 gives back
 INDEX_CHUNK_PAGES = 64
 # pages a pass of the loop that starts a whole chunk that is not a run
-# (``pallas_latent.UNROLL``'s measurement: the descriptors are the cost)
+# (``pallas_paged.UNROLL``'s measurement: the descriptors are the cost)
 INDEX_UNROLL = 8
 # tokens a step of the unpack: 32 vector registers of even and of odd tokens
 INDEX_UNPACK_TOKENS = 512

@@ -62,7 +62,10 @@ def pytest_pyfunc_call(pyfuncitem):
         kwargs = {
             name: pyfuncitem.funcargs[name] for name in pyfuncitem._fixtureinfo.argnames
         }
-        asyncio.run(asyncio.wait_for(fn(**kwargs), timeout=120))
+        # a hang's limit, not a budget: the slowest honest test (an indexer
+        # engine with its kernels interpreted, twice against the reference)
+        # takes 85-95 s alone and met 120 s beside five other workers
+        asyncio.run(asyncio.wait_for(fn(**kwargs), timeout=300))
         return True
     return None
 

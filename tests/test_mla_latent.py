@@ -23,6 +23,7 @@ from dynamo_tpu.engine.telemetry import run_chunk_share
 from dynamo_tpu.models import mla, moe as moelib, registry
 from dynamo_tpu.ops import attention as att
 from dynamo_tpu.ops import pallas_latent as plat
+from dynamo_tpu.ops import pallas_paged as paged
 from dynamo_tpu.ops.paged_attention import PagedAttention
 from dynamo_tpu.parallel.mesh import make_mesh
 
@@ -620,8 +621,6 @@ def test_a_run_of_pages_is_read_as_one_copy_and_changes_no_bit(monkeypatch, case
     edge; for a row whose only chunk is a tail, an empty row, a lone chunk,
     decode rows and the mixed launch; the counter reads what the tables hold.
     ``UNPACK_SHAPES`` at 4 rows a token; every case bitwise the parent's unpack."""
-    from dynamo_tpu.ops import pallas_paged as paged
-
     cp = chunk_pages
     monkeypatch.setattr(paged, "chunk_pages", lambda *a: cp)
     T = cp * BS
@@ -664,7 +663,7 @@ def test_a_run_of_pages_is_read_as_one_copy_and_changes_no_bit(monkeypatch, case
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(twin, np.float32), atol=2e-2, rtol=2e-2)
     # what the kernel was told and what the step counts: every neighbour compared
     runs = _numpy_runs(tables, cp)
-    assert (np.asarray(plat.chunk_runs(jnp.asarray(tables), cp)) == runs).all()
+    assert (np.asarray(paged.chunk_runs(jnp.asarray(tables), cp)) == runs).all()
     whole = [(-(-n // BS)) // cp if ql else 0 for n, ql in zip(lens, np.asarray(q_lens))]
     n_whole = sum(whole)
     n_run = sum(int(runs[r, :w].sum()) for r, w in enumerate(whole))

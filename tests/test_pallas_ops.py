@@ -170,6 +170,104 @@ def test_pallas_decode_reads_exactly_the_context(name):
     np.testing.assert_allclose(got[live], ref[live], atol=tol, rtol=tol)
 
 
+def _run_tables(how, rows, cp, chunks, rng):
+    """``rows`` tables of ``chunks`` whole chunks of ``cp`` pages (+ a tail
+    chunk's room): every chunk consecutive ids (``runs``), none (``shuffled``)
+    or every second one (``mixed``). The same CONTENT is at the same table
+    place in all three: ``place[i]`` is where ``runs``' page ``i`` lives."""
+    mb = (chunks + 1) * cp
+    runs = 1 + np.arange(rows * mb).reshape(rows, mb)
+    nb = rows * mb + 1
+    if how == "runs":
+        return runs, np.arange(nb)
+    while True:
+        place = np.arange(nb)
+        moved = rng.permutation(np.arange(1, nb))
+        if how == "mixed":
+            # a chunk in two stays where it is; the others trade places
+            # among themselves
+            stays = (((np.arange(1, nb) - 1) % mb) // cp) % 2 == 0
+            moved = np.arange(1, nb)
+            moved[~stays] = rng.permutation(moved[~stays])
+        place[1:] = moved
+        got = np.asarray(paged.chunk_runs(jnp.asarray(place[runs]), cp))
+        want = np.zeros_like(got) if how == "shuffled" else np.tile(
+            np.arange(chunks + 1) % 2 == 0, (rows, 1))
+        if (got == want).all():
+            return place[runs], place
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("chunk_pages", [2, 8])
+def test_pallas_decode_reads_a_run_of_pages_as_one_copy_bitwise(chunk_pages, int8):
+    """A whole chunk whose table entries are consecutive block ids is ONE
+    descriptor an array; any other whole chunk goes page by page; both are
+    waited for once an array (ops/pallas_paged.PageReader). The same pages
+    behind tables that are (a) all runs, (b) all shuffled, (c) every second
+    chunk a run give BITWISE the same output, and the twin's within today's
+    tolerance: rows of whole chunks + a tail chunk, of whole chunks exactly
+    (the row's last chunk, masked, is then a run), a tail alone, an empty
+    row, one token."""
+    from dynamo_tpu.ops import quant
+
+    cp, bs, kvh, h, d, chunks = chunk_pages, 16, 4, 8, 32, 3
+    T = cp * bs
+    lens = [3 * T + 5, 3 * T, 0, T - 3, 2 * T + bs, 1]
+    rng = np.random.default_rng(50 + cp)
+    nb = len(lens) * (chunks + 1) * cp + 1
+    q = jnp.asarray(rng.standard_normal((len(lens), h, d)), jnp.bfloat16)
+    k, v = (rng.standard_normal((nb, bs, kvh, d)).astype(np.float32)
+            for _ in range(2))
+    seq = jnp.asarray(lens, jnp.int32)
+
+    def launch(how):
+        tables, place = _run_tables(how, len(lens), cp, chunks, rng)
+        back = np.argsort(place)
+        pools = [jnp.asarray(x[back], jnp.bfloat16) for x in (k, v)]
+        if int8:
+            pools = [quant.QuantizedKV(*quant.quantize_blocks(
+                x.astype(jnp.float32))) for x in pools]
+        tables = jnp.asarray(tables, jnp.int32)
+        got = pa.paged_decode_attention(
+            q, *pools, tables, seq, chunk_tokens=T, interpret=True)
+        with jax.default_matmul_precision("highest"):
+            twin = att.paged_decode_attention(q, *pools, tables, seq)
+        return np.asarray(got, np.float32), np.asarray(twin, np.float32)
+
+    runs, twin = launch("runs")
+    live = np.asarray(lens) > 0
+    assert not runs[~live].any()
+    np.testing.assert_allclose(runs[live], twin[live], atol=2.0 ** -7, rtol=2.0 ** -7)
+    for how in ("shuffled", "mixed"):
+        got, _ = launch(how)
+        assert (got == runs).all(), how
+
+
+def test_pallas_decode_reads_runs_the_same_under_tp2():
+    """Each shard of a tp=2 mesh computes the tables' run flags for itself
+    (the tables are replicated) and reads its own kv heads' pages by them:
+    the one-device launch's answer."""
+    from dynamo_tpu.parallel.mesh import AXIS_TP, make_mesh
+
+    cp, bs, kvh, h, d = 2, 16, 4, 8, 32
+    T = cp * bs
+    lens = jnp.asarray([3 * T + 5, 0, T - 3, 2 * T + bs], jnp.int32)
+    rng = np.random.default_rng(52)
+    tables, _ = _run_tables("mixed", 4, cp, 3, rng)
+    tables = jnp.asarray(tables, jnp.int32)
+    k, v = (jnp.asarray(rng.standard_normal((tables.size + 1, bs, kvh, d)),
+                        jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((4, h, d)), jnp.bfloat16)
+    kw = dict(chunk_tokens=T, interpret=True)
+    sharded = pa.sharded_paged_decode_attention(
+        make_mesh(tp=2), AXIS_TP, q, k, v, tables, lens, **kw)
+    one = pa.paged_decode_attention(q, k, v, tables, lens, **kw)
+    # a shard's product sums over half the columns: the output's ulp
+    np.testing.assert_allclose(
+        np.asarray(sharded, np.float32), np.asarray(one, np.float32),
+        atol=2.0 ** -7, rtol=2.0 ** -7)
+
+
 def test_derived_chunk_fits_the_vmem_budget():
     """The chunk is what the budget holds in two slots of K and V, and never
     more pages than a row has."""

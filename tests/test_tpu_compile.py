@@ -13,8 +13,11 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
 
+import base64
 import collections
 import functools
+import hashlib
+import json
 import math
 import re
 
@@ -424,6 +427,9 @@ def _cases():
             128, 8, 512 + 24, 25, 2112, 53248),
         "unified-128q-8kv-contract-cell-windowed-mixed": unified_cell(
             128, 8, 512 + 24, 25, 290, 13105, window=True),
+        # the sparse-expert cell's full layers (PR 50: 32 q / 4 kv heads, 16
+        # KiB pages, 64 of them a chunk): 16 decode rows over tables of 448
+        "decode-bf16-32q-4kv-moe-cell-full": decode(4, 32, 16, 448, 7168),
         "grouped-matmul-gate-up-16x4096-rows192": grouped(
             192, 4096, 4096, 2, experts=16),
         "grouped-matmul-down-16x4096-rows4288": grouped(
@@ -538,10 +544,16 @@ def test_the_index_keys_unpack_moves_whole_registers(v5e, mosaic_dump):
     assert vector <= 1500, (vector, ops)
 
 
-def test_sharded_decode_compiles_on_tp4_mesh(v5e):
+@pytest.mark.parametrize("h,kvh,rows,mb,nb", [
+    (H, KVH, B, MB, NB),
+    (128, 8, 24, 2112, 53248),       # the contract cell's full layer
+    (32, 4, 16, 448, 7168),          # the sparse-expert cell's full layers
+], ids=["qwen3", "contract-cell", "moe-cell"])
+def test_sharded_decode_compiles_on_tp4_mesh(v5e, h, kvh, rows, mb, nb):
     """The decode question on a tp=4 mesh of the described devices: q on
-    heads, pages on kv heads (2 per shard), one custom call per device and
-    no collective (attention is head-wise independent)."""
+    heads, pages on kv heads (2, 2 and 1 a shard), one custom call per device
+    and no collective (attention is head-wise independent; each shard
+    computes the tables' run flags for itself)."""
     from dynamo_tpu.parallel.mesh import AXIS_TP, make_mesh
 
     mesh = make_mesh(tp=4, devices=v5e)
@@ -551,15 +563,105 @@ def test_sharded_decode_compiles_on_tp4_mesh(v5e):
             shape, dtype, sharding=NamedSharding(mesh, spec)
         )
 
-    cache = s((NB, BS, KVH, D), BF, P(None, None, AXIS_TP, None))
+    cache = s((nb, BS, kvh, D), BF, P(None, None, AXIS_TP, None))
     args = (
-        s((B, H, D), BF, P(None, AXIS_TP, None)), cache, cache,
-        s((B, MB), I32, P()), s((B,), I32, P()),
+        s((rows, h, D), BF, P(None, AXIS_TP, None)), cache, cache,
+        s((rows, mb), I32, P()), s((rows,), I32, P()),
     )
     text = jax.jit(PagedAttention(mesh, True).decode).lower(
         *args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce(" not in text and "all-gather(" not in text
+
+
+def _dma_sites(llo: str):
+    """Mosaic's last pass as text -> its DMA operations in order, as (op, the
+    innermost construct that holds it, how many it holds)."""
+    stack, sites = [], []
+    for line in llo.split("\n"):
+        text = line.lstrip()
+        indent = len(line) - len(text)
+        if text.startswith("}") and not text.startswith("} else"):
+            while stack and stack[-1][0] >= indent:
+                stack.pop()
+        opened = re.match(r"(?:%[^=]*= )?(scf\.(?:for|if|while))", text)
+        if opened:
+            stack.append((indent, opened.group(1), len(stack) and id(line)))
+        for op in re.findall(r"llo\.(enqueue_dma|dma_done)", text):
+            key = (op, stack[-1][1:] if stack else None)
+            if sites and sites[-1][0] == key:
+                sites[-1][1] += 1
+            else:
+                sites.append([key, 1])
+    return [(op, held[0] if held else None, n) for (op, held), n in sites]
+
+
+@pytest.mark.parametrize("case", [
+    "decode-bf16-128q-8kv-contract-cell-full", "decode-bf16-32q-4kv-moe-cell-full"])
+def test_a_run_chunk_of_the_decode_kernel_starts_one_dma_an_array(
+        v5e, chip_seam, mosaic_dump, case):
+    """ISSUE 50's tripwire, no chip needed. By Mosaic's own dump of the
+    decode launch at the contract cell's and the sparse-expert cell's
+    shapes: each of its three places that start a chunk holds a branch of
+    TWO descriptors (a whole chunk that is a run: K and V, one each), a loop
+    of ``UNROLL`` pages' (a whole chunk that is none) and a loop of one
+    page's (a tail); each of its two places that wait holds two waits for a
+    whole chunk, however it was started, and a loop of one page's. Until
+    PR 50 a chunk of 32 or 64 pages was 64 or 128 starts and as many waits,
+    one after the other in the products' instruction stream."""
+    from dynamo_tpu.ops import pallas_paged as paged
+
+    fn, build = CASES[case]
+    jax.jit(functools.partial(fn, chip_seam)).lower(
+        *build(SingleDeviceSharding(v5e[0]))).compile()
+    last = sorted(mosaic_dump.glob("*paged_decode_attention*finalize-llo*"))
+    if not last:
+        pytest.skip("this libtpu wrote no Mosaic dump (--xla_mosaic_dump_to)")
+    start = [("enqueue_dma", "scf.if", 2),
+             ("enqueue_dma", "scf.for", 2 * paged.UNROLL),
+             ("enqueue_dma", "scf.for", 2)]
+    wait = [("dma_done", "scf.if", 2), ("dma_done", "scf.for", 2)]
+    assert _dma_sites(last[-1].read_text()) == start + start + wait + start + wait
+
+
+# sha256 of what these launches lowered to at the PARENT of PR 50 (commit
+# 786917a, this installation's JAX, the described v5e): the StableHLO around
+# the custom call and the Mosaic module in it, printed without debug
+# locations (the serialised module carries file names and line numbers). A
+# PR that changes one of these kernels on purpose re-records its hash.
+PARENT_KERNEL_TEXTS = json.loads(open(os.path.join(
+    os.path.dirname(__file__), "data", "kernel_texts_pr49.json")).read())
+
+
+def _lowered_text_hash(text: str) -> str:
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    body = r'(\\22body\\22: \\22)([A-Za-z0-9+/=]+)'
+    parts = [re.sub(body, r"\1", text)]
+    for _, payload in re.findall(body, text):
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(payload))
+            parts.append(module.operation.get_asm(enable_debug_info=False))
+    assert len(parts) > 1, "no Mosaic module in the lowered text"
+    return hashlib.sha256("\n".join(parts).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_KERNEL_TEXTS))
+def test_the_ragged_and_the_latent_launch_lower_to_the_parents_text(
+        v5e, chip_seam, case):
+    """PR 50 moved the run rule of ``_LatentPages`` into ``PageReader`` and
+    gave it to the decode kernel ALONE: the ragged launch (plain, windowed,
+    with sinks and a softcap, at the sparse-expert and the contract cell's
+    shapes) still starts and waits page by page, and the latent launch reads
+    runs as it did. Both lower to the text the parent lowered."""
+    fn, build = CASES[case]
+    if getattr(fn, "asks_seam", False):
+        fn = functools.partial(fn, chip_seam)
+    text = jax.jit(fn).lower(*build(SingleDeviceSharding(v5e[0]))).as_text()
+    assert _lowered_text_hash(text) == PARENT_KERNEL_TEXTS[case]
 
 
 def test_sharded_unified_compiles_on_tp4_mesh(v5e):
