@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import itertools
 import math
 import os
 import time
@@ -73,10 +74,14 @@ from . import step_args
 from .telemetry import (
     PENDING_SPANS_MAX,
     StepStats,
+    launch,
     loop_span,
     now_ns,
+    pending_arrivals,
+    pending_launches,
     pending_request_spans,
     pending_spans,
+    record_arrival,
     record_request_span,
     submit_span,
 )
@@ -432,16 +437,19 @@ class _Chain:
     # tokens a row advances before the host has read them: what a dispatch
     # on top of this one books beyond its own (a horizon's decode_steps)
     length: int = 0
+    # its launch's place in the launch ledger (telemetry.launch): whoever
+    # reads its results stamps their arrival under it
+    seq: int = -1
     # a mixed step (one token a decode row beside a prefill chunk) and what
     # its StepStats needs once it is read: the chunk's tokens, whether the
-    # link before it was unread at its launch, when it was launched, whether
-    # its chunk came prebuilt, the placements its dispatch made; ``results``
-    # where it was read at once (the executor's ``sync``), else ``fetch``
-    # resolves to them
+    # link before it was unread at its launch, when its call began (on the
+    # spans' clock, ``now_ns``), whether its chunk came prebuilt, the
+    # placements its dispatch made; ``results`` where it was read at once
+    # (the executor's ``sync``), else ``fetch`` resolves to them
     mixed: bool = False
     chunk_tokens: int = 0
     chained: bool = False
-    t_launch: float = 0.0
+    t0_ns: int = 0
     prep_hit: Optional[bool] = None
     placed: int = 0
     results: Any = None
@@ -962,6 +970,13 @@ class TpuEngine:
         # those with a request for a subject (``submit``, ``deliver``)
         self._host_spans = pending_spans()
         self._request_spans = pending_request_spans()
+        # the launch ledger (telemetry.launch / record_arrival): the pending
+        # records, the counter that numbers every jitted call the loop
+        # makes, and the newest launch whose results the loop has taken
+        self._launches = pending_launches()
+        self._arrivals = pending_arrivals()
+        self._launch_seq = itertools.count()
+        self._read_seq = -1
         self._admit_waits: deque = deque(maxlen=PENDING_SPANS_MAX)
         # wall clock less the loop's monotonic one, taken as the loop starts
         self._wall_offset_ns = 0
@@ -1010,6 +1025,8 @@ class TpuEngine:
             )
             self.encoder_cache = EncoderCacheManager()
         self._mm_zero: Dict[int, Tuple[jax.Array, jax.Array]] = {}
+        # the (1, 1) dummy pair of an engine without vision, made once
+        self._mm_none: Optional[Tuple[jax.Array, jax.Array]] = None
         # multi-LoRA adapter tables (static shapes; see lora/adapters.py)
         self.lora = None
         if config.lora_max_adapters > 0:
@@ -3102,16 +3119,18 @@ class TpuEngine:
                 did_mixed = False
                 mixed_blocked = False
                 pick = mixed_seqs = None
-                # a mixed step launched last tick and not read yet, alone in
-                # flight: THIS tick's program is launched on its carry
-                # before its results are read (below)
-                link = (
-                    self._chains[0]
-                    if len(self._chains) == 1 and self._chains[0].mixed
-                    else None
-                )
                 at_once = False
                 with loop_span(self, "book"):
+                    # a mixed step launched last tick and not read yet, alone
+                    # in flight: THIS tick's program is launched on its carry
+                    # before its results are read (below). Inside the span:
+                    # rebinding ``link`` lets go of the link of the tick
+                    # before, and freeing its device arrays takes 40-150 us
+                    link = (
+                        self._chains[0]
+                        if len(self._chains) == 1 and self._chains[0].mixed
+                        else None
+                    )
                     prefilling = [
                         s for s in self._slots
                         if s is not None and not s.done and not s.prefilled
@@ -3196,7 +3215,7 @@ class TpuEngine:
                                 self._fetch_prefill_result, *res
                             )
                             task = asyncio.ensure_future(
-                                self._finish_prefill(res[0], fut)
+                                self._finish_prefill(res[0], fut, res[-1])
                             )
                             self._prefill_tasks.add(task)
                             task.add_done_callback(self._prefill_tasks.discard)
@@ -3253,7 +3272,7 @@ class TpuEngine:
                             snapshot,
                         )
                         chain.fetch = self._fetch_executor.submit(
-                            np.asarray, chain.packed
+                            self._fetch_packed, chain
                         )
                         self._chains.append(chain)
                 if self._chains and not (
@@ -3332,6 +3351,7 @@ class TpuEngine:
             if chain.results is None:
                 with loop_span(self, "fetch"):
                     chain.results = await asyncio.wrap_future(chain.fetch)
+                    self._took(chain.seq)
             with loop_span(self, "emit"):
                 results, self._moe_last = chain.results
                 kept = 0
@@ -3345,13 +3365,14 @@ class TpuEngine:
                     self._accept_token(rst, tok, lp, tids, tvals)
                 self._count_state(kept, 0, 1)
                 self._step_stats(
-                    "mixed", time.perf_counter() - chain.t_launch,
+                    "mixed", (now_ns() - chain.t0_ns) / 1e9,
                     chain.chunk_tokens + kept, link=chain,
                 )
             return
         t_step = time.perf_counter()
         with loop_span(self, "fetch"):
             packed = await asyncio.wrap_future(chain.fetch)
+            self._took(chain.seq)
         with loop_span(self, "emit"):
             emitted_before = sum(
                 s.produced for s in chain.seqs if s is not None
@@ -3541,7 +3562,8 @@ class TpuEngine:
                     # image placeholders sit above the vocab: they are not
                     # sampleable, so they simply don't enter the mask
                     row[ids[ids < self.mcfg.vocab_size]] = 1
-                self.prompt_masks, self.output_counts = self._reset_slot_fn(
+                _, (self.prompt_masks, self.output_counts) = launch(
+                    self, self._reset_slot_fn, 0,
                     self.prompt_masks, self.output_counts,
                     self._j(np.int32(slot)), self._j(row),
                 )
@@ -3863,13 +3885,12 @@ class TpuEngine:
             dtok, dpos, dnb = self._chunk_arrays(
                 prompt, dstart, dlen, st.block_ids
             )
-            self.draft_k_caches, self.draft_v_caches = (
-                self._draft_prefill_fn(
-                    self.draft_params, self.draft_k_caches,
-                    self.draft_v_caches, _j(dtok), _j(dpos),
-                    _j(self._block_tables[st.slot]), _j(dnb),
-                    _j(np.int32(dstart + dlen)),
-                )
+            _, (self.draft_k_caches, self.draft_v_caches) = launch(
+                self, self._draft_prefill_fn, len(dtok),
+                self.draft_params, self.draft_k_caches,
+                self.draft_v_caches, _j(dtok), _j(dpos),
+                _j(self._block_tables[st.slot]), _j(dnb),
+                _j(np.int32(dstart + dlen)),
             )
             st.draft_prefill_pos = dstart + dlen
 
@@ -3877,7 +3898,8 @@ class TpuEngine:
         """Prefill ONE bounded chunk of st's prompt (reference chunked
         prefill, protocols.rs:112): writes the chunk's KV pages; the final
         chunk also samples the first token. Returns None for intermediate
-        chunks, else the (st, tok, lp, tlp...) acceptance tuple."""
+        chunks, else the (st, tok, lp, tlp..., the launch's seq) acceptance
+        tuple."""
         with loop_span(self, "pack"):
             prompt = st.seq.tokens()
             start = st.prefill_pos
@@ -3920,8 +3942,9 @@ class TpuEngine:
                 *g_dev,
             ))
         with loop_span(self, "launch"):
-            (self.k_caches, self.v_caches, self.output_counts, tok, lp,
-             tlp_vals, tlp_ids) = self._prefill_fn(*args)
+            seq, (self.k_caches, self.v_caches, self.output_counts, tok, lp,
+                  tlp_vals, tlp_ids) = launch(
+                self, self._prefill_fn, S_pad, *args)
             self._count_state(0, chunk_len, 0)
         with loop_span(self, "pack"):
             # the next chunk's arrays, built under this chunk's compute
@@ -3940,7 +3963,7 @@ class TpuEngine:
         lp.copy_to_host_async()
         want_tlp = self._lp_ns[st.slot] > 0
         return (st, tok, lp, tlp_ids if want_tlp else None,
-                tlp_vals if want_tlp else None)
+                tlp_vals if want_tlp else None, seq)
 
     def _mm_chunk(self, st: _Seq, start: int, chunk_len: int, S_pad: int):
         """Per-chunk soft-token override arrays for the prefill program.
@@ -3949,7 +3972,14 @@ class TpuEngine:
         if self.cfg.vision is None:
             if self._mh is not None:  # host dummies: see _j
                 return (np.zeros((1, 1), self.mcfg.dtype), np.zeros((1,), bool))
-            return (jnp.zeros((1, 1), self.mcfg.dtype), jnp.zeros((1,), bool))
+            # made once: a ``jnp.zeros`` a chunk is device programs of its
+            # own beside the chunk's (four ``convert_element_type`` a lone
+            # chunk, which no launch record names: PERF.md section 6, PR 51)
+            if self._mm_none is None:
+                self._mm_none = (
+                    jnp.zeros((1, 1), self.mcfg.dtype), jnp.zeros((1,), bool)
+                )
+            return self._mm_none
         H = self.mcfg.hidden_size
         if st.mm_embeds is None:
             # text-only request on a vision engine: reuse one cached zero
@@ -4029,11 +4059,12 @@ class TpuEngine:
             tokens = np.zeros(S_pad, np.int32)
             tokens[:S] = token_ids
             positions = np.arange(S_pad, dtype=np.int32)
-            vec = self._embed_fn(
+            seq, vec = launch(
+                self, self._embed_fn, S_pad,
                 self.params, self._j(tokens), self._j(positions),
                 self._j(np.int32(S - 1)),
             )
-            return np.asarray(vec)
+            return self._read_embedding(seq, vec)
         # chunked: the caller pre-allocated temporary pages (loop thread
         # owns the allocator); each chunk writes KV + attends over the
         # gathered prefix, the final chunk yields the pooled vector
@@ -4056,14 +4087,15 @@ class TpuEngine:
             tokens, positions, nbi = self._chunk_arrays(
                 token_ids, start, chunk_len, block_ids
             )
-            (self.k_caches, self.v_caches, vec) = self._embed_chunk_fn(
+            seq, (self.k_caches, self.v_caches, vec) = launch(
+                self, self._embed_chunk_fn, len(tokens),
                 self.params, self.k_caches, self.v_caches,
                 _j(tokens), _j(positions), _j(table), _j(nbi),
                 _j(np.int32(start + chunk_len)),
                 _j(np.int32(chunk_len - 1)),
                 _j(np.bool_(is_final)),
             )
-        return np.asarray(vec)
+        return self._read_embedding(seq, vec)
 
     def _run_mixed_step(self, st: _Seq, seqs: List[Optional["_Seq"]],
                         prev: Optional[_Chain], at_once: bool):
@@ -4078,7 +4110,6 @@ class TpuEngine:
         loop's ``fetch`` a tick later, behind the next launch.
         Returns (the link, prefill result tuple like _run_prefill_chunk's
         or None for intermediate chunks)."""
-        t_launch = time.perf_counter()
         with loop_span(self, "pack"):
             prompt = st.seq.tokens()
             start = st.prefill_pos
@@ -4133,9 +4164,12 @@ class TpuEngine:
                 *g_dev,
             ))
         with loop_span(self, "launch"):
-            (self.k_caches, self.v_caches, self.output_counts, toks, lps,
-             tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids,
-             seq_lens, next_steps) = self._mixed_fn(*args)
+            t0_ns = now_ns()
+            seq, (
+                self.k_caches, self.v_caches, self.output_counts, toks, lps,
+                tlp_vals, tlp_ids, c_tok, c_lp, c_tlp_vals, c_tlp_ids,
+                seq_lens, next_steps,
+            ) = launch(self, self._mixed_fn, len(tokens), *args)
             # the readback starts now: by the link's turn to be read the
             # bytes are on the host (as a horizon's packed results)
             results = (toks, lps) + ((tlp_ids, tlp_vals) if lp_need else ())
@@ -4145,8 +4179,8 @@ class TpuEngine:
             self._count_state(0, chunk_len, 0)
             link = _Chain(
                 results, toks, seq_lens, next_steps, seqs, length=1,
-                mixed=True, chunk_tokens=chunk_len, chained=prev is not None,
-                t_launch=t_launch, prep_hit=prep_hit,
+                seq=seq, mixed=True, chunk_tokens=chunk_len,
+                chained=prev is not None, t0_ns=t0_ns, prep_hit=prep_hit,
                 placed=self._h2d_placements,
             )
             self._h2d_placements = 0
@@ -4158,21 +4192,23 @@ class TpuEngine:
             self._advance_draft_prefill(st, prompt)
         if at_once:
             with loop_span(self, "sync"):
-                link.results = self._decode_results(seqs, *results)
+                link.results = self._decode_results(seq, seqs, *results)
+                self._took(seq)
         else:
             link.fetch = self._fetch_executor.submit(
-                self._decode_results, seqs, *results
+                self._decode_results, seq, seqs, *results
             )
         prefill_res = None
         if is_final:
             # same async-readback protocol as _run_prefill_chunk: the loop
-            # hands these to the fetch pool so the D2H RTT overlaps
+            # hands these to the fetch pool so the D2H RTT overlaps. No seq:
+            # the launch's one arrival is its decode rows' (_decode_results)
             st.prefill_inflight = True
             c_tok.copy_to_host_async()
             c_lp.copy_to_host_async()
             prefill_res = (st, c_tok, c_lp,
                            c_tlp_ids if c_lp_need else None,
-                           c_tlp_vals if c_lp_need else None)
+                           c_tlp_vals if c_lp_need else None, -1)
         return link, prefill_res
 
     def _book_decode_blocks(
@@ -4276,17 +4312,41 @@ class TpuEngine:
     def _lora_tables(self):
         return self.lora.tables() if self.lora is not None else {}
 
-    def _fetch_prefill_result(self, st, tok, lp, tlp_ids, tlp_vals):
-        """Fetch pool thread: the blocking device->host conversion."""
-        return (
+    def _fetch_prefill_result(self, st, tok, lp, tlp_ids, tlp_vals, seq):
+        """Fetch pool thread: the blocking device->host conversion, and the
+        arrival of launch ``seq``'s results (a lone chunk's; -1: none)."""
+        res = (
             st, int(tok), float(lp),
             np.asarray(tlp_ids) if tlp_ids is not None else None,
             np.asarray(tlp_vals) if tlp_vals is not None else None,
         )
+        record_arrival(self, seq)
+        return res
 
-    async def _finish_prefill(self, st: "_Seq", fut) -> None:
+    def _fetch_packed(self, chain: _Chain) -> np.ndarray:
+        """Fetch pool thread: a horizon's packed results, and their arrival."""
+        packed = np.asarray(chain.packed)
+        record_arrival(self, chain.seq)
+        return packed
+
+    def _took(self, seq: int) -> None:
+        """The loop has the results of launch ``seq`` in hand: what the
+        record of a later launch says it came ``after``."""
+        if seq > self._read_seq:
+            self._read_seq = seq
+
+    def _read_embedding(self, seq: int, vec) -> np.ndarray:
+        """Executor thread: the pooled vector of launch ``seq``, read at
+        once: its arrival, and taken."""
+        out = np.asarray(vec)
+        record_arrival(self, seq)
+        self._took(seq)
+        return out
+
+    async def _finish_prefill(self, st: "_Seq", fut, seq: int) -> None:
         """Loop thread: apply a prefill's first token once its readback
-        lands; the sequence becomes decode-eligible here."""
+        (launch ``seq``'s, -1 where it rides a mixed link's) lands; the
+        sequence becomes decode-eligible here."""
         try:
             _st, tok, lp, tlp_ids, tlp_vals = await asyncio.wrap_future(fut)
         except Exception:
@@ -4304,6 +4364,7 @@ class TpuEngine:
             self._wake.set()
             return
         st.prefill_inflight = False
+        self._took(seq)
         if st.done or self._slots[st.slot] is not st:
             return  # cancelled/reaped while the fetch was in flight
         st.prefilled = True
@@ -4602,16 +4663,18 @@ class TpuEngine:
         # no "sync" here: a horizon's results are awaited by the loop ("fetch")
         with loop_span(self, "launch"):
             if spec:
-                (self.k_caches, self.v_caches, self.draft_k_caches,
-                 self.draft_v_caches, packed, tokens, seq_lens, steps) = (
-                    self._spec_multi_fn(*args)
-                )
+                seq, (
+                    self.k_caches, self.v_caches, self.draft_k_caches,
+                    self.draft_v_caches, packed, tokens, seq_lens, steps,
+                ) = launch(self, self._spec_multi_fn, self.cfg.spec_k, *args)
                 packed.copy_to_host_async()
                 return _Chain(
-                    packed, tokens, seq_lens, steps, seqs,
+                    packed, tokens, seq_lens, steps, seqs, seq=seq,
                     spec_k=self.cfg.spec_k, length=self.cfg.decode_steps,
                 )
-            res = self._decode_multi_fn(*args, **quota)
+            seq, res = launch(
+                self, self._decode_multi_fn, self.cfg.decode_steps,
+                *args, **quota)
             del args  # donated caches: hold no stale handles
             g_state_out = None
             if self.guided_enabled:
@@ -4626,7 +4689,7 @@ class TpuEngine:
             packed.copy_to_host_async()
             return _Chain(
                 packed, tokens, seq_lens, steps, seqs, g_state=g_state_out,
-                length=self.cfg.decode_steps,
+                length=self.cfg.decode_steps, seq=seq,
             )
 
     def _spec_eligible(self, seqs: List[Optional["_Seq"]]) -> bool:
@@ -4771,20 +4834,22 @@ class TpuEngine:
             steps[i] = st.produced + ahead
         return positions, seq_lens, write_blocks, write_offsets, steps, carried
 
-    def _decode_results(self, seqs: List[Optional["_Seq"]], toks, lps,
-                        tlp_ids=None, tlp_vals=None):
-        """Device outputs of one decode step -> (per-sequence acceptance
-        tuples, the step's routing counters or None); shared by _run_decode
-        and _run_mixed_step, the top-logprob rows only where a row asked.
-        Touches no engine state: a mixed link's runs on the fetch pool."""
+    def _decode_results(self, seq: int, seqs: List[Optional["_Seq"]], toks,
+                        lps, tlp_ids=None, tlp_vals=None):
+        """Device outputs of one decode step (launch ``seq``) ->
+        (per-sequence acceptance tuples, the step's routing counters or
+        None); shared by _run_decode and _run_mixed_step, the top-logprob
+        rows only where a row asked. Touches no engine state but the
+        arrival it stamps: a mixed link's runs on the fetch pool."""
         toks_np = np.asarray(toks)
         lps_np = np.asarray(lps)
+        tlp_ids_np = np.asarray(tlp_ids) if tlp_ids is not None else None
+        tlp_vals_np = np.asarray(tlp_vals) if tlp_vals is not None else None
+        record_arrival(self, seq)
         moe = None
         if self._moe_counted:
             # [B + 3]: the step's routing counters behind the logprobs
             moe = tuple(int(x) for x in lps_np[self.cfg.max_batch_size:])
-        tlp_ids_np = np.asarray(tlp_ids) if tlp_ids is not None else None
-        tlp_vals_np = np.asarray(tlp_vals) if tlp_vals is not None else None
         results = []
         for i, st in enumerate(seqs):
             if st is None:
@@ -4827,15 +4892,18 @@ class TpuEngine:
                 *g_dev,
             ))
         with loop_span(self, "launch"):
-            (self.k_caches, self.v_caches, self.output_counts, toks, lps,
-             tlp_vals, tlp_ids) = self._decode_fn(*args)
+            seq, (self.k_caches, self.v_caches, self.output_counts, toks, lps,
+                  tlp_vals, tlp_ids) = launch(
+                self, self._decode_fn, 1, *args)
             del args  # donated caches: hold no stale handles
             self._count_state(np.count_nonzero(seq_lens), 0, 1)
             self._count_paged(seqs, seq_lens, 1)
         with loop_span(self, "sync"):
             results, self._moe_last = self._decode_results(
-                seqs, toks, lps, *((tlp_ids, tlp_vals) if lp_need else ())
+                seq, seqs, toks, lps,
+                *((tlp_ids, tlp_vals) if lp_need else ())
             )
+            self._took(seq)
             return results
 
     # -- host-side token bookkeeping -----------------------------------------
@@ -5177,6 +5245,10 @@ class TpuEngine:
         # a whole number of spans: every extend adds four values
         rspans = self._request_spans
         request_spans = tuple(rspans.popleft() for _ in range(len(rspans)))
+        # the launch ledger: whole records too (seven values, two values)
+        made, landed = self._launches, self._arrivals
+        launches = tuple(made.popleft() for _ in range(len(made)))
+        arrivals = tuple(landed.popleft() for _ in range(len(landed)))
         # set by the step's own readback; a prefill-only step has none
         routed, touched, load_max, *reads = self._moe_last or (None,) * 3
         if not registry.counts_routing(self.mcfg):
@@ -5231,6 +5303,8 @@ class TpuEngine:
                 host_spans=host_spans,
                 admit_wait_s=admit_wait_s,
                 request_spans=request_spans,
+                launches=launches,
+                arrivals=arrivals,
                 moe_tokens_routed=routed,
                 moe_experts_touched=touched,
                 moe_load_max=load_max,

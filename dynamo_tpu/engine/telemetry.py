@@ -40,6 +40,33 @@ next ``StepStats`` in a field of their own, ``request_spans`` (``name, t0_ns,
 t1_ns, request_id``, four values a span; ``span_quads``): ``host_spans``
 keeps its names, its form and its tiling of a tick.
 
+``launch`` is the loop's one way to call a jitted program on the device
+(prefill, mixed_step, decode_multi, decode, spec_multi, the draft's chunk,
+reset_slot, embed, embed_chunk): the LAUNCH LEDGER. It leaves a record of the
+call, ``seq, program, key, t0_ns, t1_ns, compiled, after`` (``launch_records``):
+``seq`` one counter an engine in launch order; ``program`` the jitted
+function's name, what a device trace shows behind ``jit_``; ``key`` what
+selects the compiled executable among that program's (a chunk's bucket; the
+steps a decode program advances a row); the call's two stamps; whether THIS
+call compiled a program or loaded one from the compile cache (JAX recorded a
+backend compile on the calling thread across it: seconds, and not the
+milliseconds of a call that only meets its arguments in a form it had not
+seen, a device array where a host array was); and ``after``, the ``seq`` of
+the newest launch whose results the loop had taken when it made this one (-1
+for none; a link launched on the device carry of the one before comes after
+an OLDER launch's results).
+``record_arrival`` stamps ``seq, t_ns`` on the thread that learns a launch's
+results are on the host, as the blocking conversion returns: the fetch pool's,
+or the executor's under ``sync``. Both ride the next ``StepStats`` as
+``launches`` and ``arrivals``, flat, from bounded pending lists filled only
+while ``stats_hook`` is set, like the spans: launch k, the device's k-th
+execution of a program and arrival k are one to one, which is what lets a
+reader put the host's and the device's clocks of a profile together
+(``benchmarks/metrics/_launches.py``). Three device programs the engine's
+process runs are NOT the loop's and leave no record: the vision encoder
+(``encode_image``) and a disaggregated worker's ``kv_gather`` /
+``kv_scatter``; a reader sees their executions as unjoined.
+
 ``host_spans`` is FLAT, three values a span, and not a tuple per span
 (``span_triples`` reads it back as triples). A hook that keeps its
 ``StepStats`` keeps the spans, and a tuple per span is a dozen more objects
@@ -55,7 +82,11 @@ gauges, spec-decode acceptance) under the caller's hierarchy labels
 (``dtpu_namespace``/``dtpu_component``), and logs any step slower than
 ``DTPU_SLOW_STEP_MS`` (default 1000 ms — a horizon is tens of decode steps;
 a multi-second step means the device stalled, the host fell behind, or a
-program compiled). ``bench.py`` attaches its own collector to the same hook to put
+program compiled: the line names the ``program[key]`` whose call took longest
+since the step before and says ``compiled`` where it did), counts the launches
+by program and key for ``/debug/worker`` (``programs``), and warns once for
+each launch that compiled: a worker attaches it as it reports ready, so every
+compile it sees is one a request waited for. ``bench.py`` attaches its own collector to the same hook to put
 mean/p99 step time in the BENCH JSON.
 """
 
@@ -63,10 +94,12 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
+import jax.monitoring
 
 from ..runtime import metrics as M
 from ..runtime.config import ENV_SLOW_STEP_MS, env_float
@@ -120,9 +153,80 @@ def span_quads(request_spans: Tuple[Any, ...]):
                request_spans[2::4], request_spans[3::4])
 
 
-# the one clock of the loop's spans, a request's stamps (engine ``_Seq``)
-# and the benchmark's marker
+# the one clock of the loop's spans, a request's stamps (engine ``_Seq``),
+# the launch ledger and the benchmark's marker
 now_ns = time.monotonic_ns
+
+LAUNCH_VALUES = 7  # seq, program, key, t0_ns, t1_ns, compiled, after
+
+
+def pending_launches() -> collections.deque:
+    """The engine's pending launch records, ``LAUNCH_VALUES`` each, flat."""
+    return collections.deque(maxlen=LAUNCH_VALUES * PENDING_SPANS_MAX)
+
+
+def pending_arrivals() -> collections.deque:
+    """The engine's pending arrivals: ``seq, t_ns`` of each, flat."""
+    return collections.deque(maxlen=2 * PENDING_SPANS_MAX)
+
+
+def launch_records(launches: Tuple[Any, ...]):
+    """``StepStats.launches`` (or a pending list) as ``(seq, program, key,
+    t0_ns, t1_ns, compiled, after)``, in launch order."""
+    return zip(*(launches[i::LAUNCH_VALUES] for i in range(LAUNCH_VALUES)))
+
+
+def arrival_records(arrivals: Tuple[int, ...]):
+    """``StepStats.arrivals`` (or a pending list) as ``(seq, t_ns)``, in the
+    order the results landed."""
+    return zip(arrivals[0::2], arrivals[1::2])
+
+
+class _Compiles(threading.local):
+    """Backend compiles (and loads from the compile cache: JAX records both
+    under one event) that THIS thread has made."""
+    n = 0
+
+
+_compiles = _Compiles()
+
+
+def _count_compile(event: str, _seconds: float, **_kw) -> None:
+    # JAX calls its listeners on the thread that compiled
+    if event == "/jax/core/compile/backend_compile_duration":
+        _compiles.n += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+def launch(engine: Any, fn: Any, key: Any, *args, **kw):
+    """Call the jitted program ``fn`` for the serving loop and, while a
+    ``stats_hook`` is set, leave its record in the launch ledger (module
+    docstring). ``fn`` is the jitted function or a wrapper that names it as
+    ``fn.jitted`` (``step_program``, the multihost leader's dispatch).
+    Returns ``(seq, fn's result)``: the caller keeps ``seq`` for whoever
+    reads the results."""
+    seq = next(engine._launch_seq)
+    if engine.stats_hook is None:
+        return seq, fn(*args, **kw)
+    compiled = _compiles.n
+    t0 = now_ns()
+    out = fn(*args, **kw)
+    t1 = now_ns()
+    # one extend, as a span's: the list stays a whole number of records
+    engine._launches.extend((
+        seq, getattr(fn, "jitted", fn).__name__, key, t0, t1,
+        _compiles.n > compiled, engine._read_seq,
+    ))
+    return seq, out
+
+
+def record_arrival(engine: Any, seq: int) -> None:
+    """The results of launch ``seq`` are on the host, now: called on the
+    thread that learns it, as its blocking conversion returns."""
+    if engine.stats_hook is not None and seq >= 0:
+        engine._arrivals.extend((seq, now_ns()))
 
 
 class loop_span(jax.profiler.TraceAnnotation):
@@ -254,6 +358,12 @@ class StepStats:
     # that ended since then, FLAT, four values a span (span_quads above), on
     # the same clock. NOT part of host_spans' tiling of a tick
     request_spans: Tuple[Any, ...] = ()
+    # the launch ledger since then: seq, program, key, t0_ns, t1_ns,
+    # compiled, after of each jitted call the loop made (launch_records
+    # above), and seq, t_ns of each arrival of a launch's results
+    # (arrival_records), both FLAT, on the same clock
+    launches: Tuple[Any, ...] = ()
+    arrivals: Tuple[int, ...] = ()
     # expert routing of the step (one-chip grouped MoE path; None elsewhere
     # and on prefill-only steps, which have no readback to carry them):
     # (token, expert) rows routed, T x K summed over layers; experts with at
@@ -448,6 +558,8 @@ class EngineTelemetry:
             maxlen=128
         )
         self._last: Optional[StepStats] = None
+        # the launch ledger folded: program -> key -> [launches, compiled]
+        self._programs: Dict[str, Dict[Any, list]] = {}
 
     def snapshot(self) -> Dict[str, Any]:
         """The step-telemetry section of the worker's ``/debug/worker``
@@ -479,6 +591,15 @@ class EngineTelemetry:
             "loop_phases": {
                 name: round(loop_ns[name] / 1e9 / len(recent), 6)
                 for name in _HOST_PHASES if name in loop_ns
+            },
+            # every jitted call the loop made since this telemetry was
+            # attached, by program and by what selects its executable
+            "programs": {
+                program: {
+                    str(key): {"launches": n, "compiled": c}
+                    for key, (n, c) in by_key.items()
+                }
+                for program, by_key in self._programs.items()
             },
         }
         if recent:
@@ -601,6 +722,17 @@ class EngineTelemetry:
             for name in _HOST_PHASES:  # the label's fixed set
                 if name in spent:
                     self._loop_phase.inc(spent[name] / 1e9, phase=name)
+            calls = list(launch_records(s.launches))
+            for _, program, key, t0, t1, compiled, _ in calls:
+                count = self._programs.setdefault(program, {}).setdefault(
+                    key, [0, 0])
+                count[0] += 1
+                count[1] += compiled
+                if compiled:
+                    log.warning(
+                        "program compiled while serving: %s[%s], %.1f s",
+                        program, key, (t1 - t0) / 1e9,
+                    )
             if s.duration_s > self.slow_step_s:
                 self.slow_steps += 1
                 self._slow.inc(phase=s.phase)
@@ -611,11 +743,19 @@ class EngineTelemetry:
                         spent.get(p, 0) for p in EXECUTOR_PHASES
                     )
                 longest = max(spent, key=spent.get, default="no span")
+                launched = ""
+                if calls:  # the longest call made since the step before
+                    _, program, key, t0, t1, compiled, _ = max(
+                        calls, key=lambda rec: rec[4] - rec[3])
+                    launched = "; launched %s[%s] in %.0f ms%s" % (
+                        program, key, (t1 - t0) / 1e6,
+                        ", compiled" if compiled else "",
+                    )
                 log.warning(
-                    "slow %s step: %.0f ms of which %s %.0f ms (threshold "
+                    "slow %s step: %.0f ms of which %s %.0f ms%s (threshold "
                     "%.0f ms; occupancy %d/%d, queue %d, kv %d/%d blocks)",
                     s.phase, s.duration_s * 1e3,
-                    longest, spent.get(longest, 0) / 1e6,
+                    longest, spent.get(longest, 0) / 1e6, launched,
                     self.slow_step_s * 1e3,
                     s.batch_occupancy, s.batch_size, s.queue_depth,
                     s.kv_active_blocks, s.kv_total_blocks,

@@ -458,6 +458,9 @@ class MultihostOps:
                 _trace("leader: dispatched %s", wire_name)
                 return out
 
+        # the program under the broadcast: the launch ledger reads its name
+        # and its cache (engine/telemetry.py launch)
+        dispatch.jitted = getattr(fn, "jitted", fn)
         return dispatch
 
     # ----------------------------------------------------------- follower side

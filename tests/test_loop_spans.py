@@ -57,17 +57,24 @@ async def _one(engine, req, rec):
 
 async def _wave(engine, tag):
     """A resident decode, then five prompts of three chunks beside it.
-    Returns one record per request, in the order they were queued."""
+    Returns one record per request, in the order they were queued. No wave
+    finds the other's prompts in the prefix cache (``salt``): each prompt is
+    three chunks' worth of mixed steps every time."""
     recs = [{} for _ in range(N_REQUESTS)]
+    salt = ord(tag)
     first = asyncio.create_task(_one(
-        engine, _req(f"{tag}0", [(i * 37 + 11) % 500 for i in range(30)], 40),
+        engine, _req(f"{tag}0", [(i * 37 + 11 + salt) % 500 for i in range(30)], 160),
         recs[0],
     ))
-    await asyncio.sleep(0.05)
+    # the resident outlasts the wave, and the rest arrive once it decodes (a
+    # tiny engine's launches return at once on the CPU: sent together, or
+    # behind a resident that has already finished, prompts only prefill)
+    while "t_first" not in recs[0]:
+        await asyncio.sleep(0.001)
     rest = [
         asyncio.create_task(_one(
             engine,
-            _req(f"{tag}{k}", [(i * 53 + 7 * k) % 500 for i in range(70)], 12),
+            _req(f"{tag}{k}", [(i * 53 + 7 * k + salt) % 500 for i in range(70)], 12),
             recs[k],
         ))
         for k in range(1, N_REQUESTS)
