@@ -24,6 +24,7 @@ from dynamo_tpu.models.evabyte import EvaByteConfig
 from dynamo_tpu.models.llama import LlamaConfig
 from dynamo_tpu.models.gemma import GemmaConfig
 from dynamo_tpu.models.gptoss import GptOssConfig
+from dynamo_tpu.models.minicpm_sala import MiniCpmSalaConfig
 from dynamo_tpu.models.mla import MlaConfig
 from dynamo_tpu.models.moe import MoeConfig
 from dynamo_tpu.runtime import DistributedRuntime, RuntimeConfig, init_logging
@@ -66,6 +67,11 @@ PRESETS = {
     # pages by layer kind: sliding layers hold one window, full layers all
     "tiny-cohere2-moe": Cohere2MoeConfig.tiny,
     "command-a-plus": Cohere2MoeConfig.command_a_plus,
+    # block-sparse attention over pooled keys in one layer of four, slot
+    # state (lightning attention) in the other three (--block-size 16: a
+    # pooled key's stride is the page)
+    "tiny-minicpm-sala": MiniCpmSalaConfig.tiny,
+    "minicpm-sala": MiniCpmSalaConfig.minicpm_sala_9b,
 }
 
 from dynamo_tpu.models.vision import VisionConfig
@@ -170,6 +176,10 @@ def parse_args():
     p.add_argument("--decode-pipeline", type=int, default=None,
                    help="in-flight decode horizons; default auto-tunes with "
                         "--decode-steps (multihost pins 2)")
+    p.add_argument("--ready-single-step", action="store_true",
+                   help="build the single-step decode program (the loop's "
+                        "fallback while a request waits) at start, not at "
+                        "the first tick that finds a request waiting")
     p.add_argument("--weight-service", default=None, metavar="SOCK",
                    help="unix socket of a weight owner process "
                         "(engine/weight_service.py; reference "
@@ -296,6 +306,7 @@ def make_engine_config(args, mcfg, vcfg=None, logits_procs=(), spec_draft=None):
     return TpuEngineConfig(
         decode_steps=decode_steps,
         decode_pipeline=decode_pipeline,
+        ready_single_step=getattr(args, "ready_single_step", False),
         model=mcfg,
         num_blocks=args.num_blocks,
         block_size=args.block_size,

@@ -207,6 +207,12 @@ class TpuEngineConfig:
     # ``num_blocks`` stays the pages of the group that lives as long as the
     # request.
     window_blocks: Optional[int] = None
+    # the single-step ``decode`` (what the loop falls back to while a request
+    # waits for a slot or for pages) is run over no row when the engine is
+    # built, so its compile, or its load from the compile cache, is paid
+    # then and not at the first tick that finds a request waiting, with
+    # every resident row stalled behind it (TpuEngine._ready_single_step)
+    ready_single_step: bool = False
 
     def __post_init__(self):
         bad = [b for b in self.prefill_buckets if b % self.block_size]
@@ -514,6 +520,11 @@ class TpuEngine:
                 raise ValueError("multihost serving does not cover LoRA yet")
             if config.vision is not None:
                 raise ValueError("multihost serving does not cover vision yet")
+            if config.ready_single_step:
+                raise ValueError(
+                    "multihost serving does not cover ready_single_step (a "
+                    "step at construction, before the followers replay)"
+                )
             if kvbm is not None:
                 raise ValueError("multihost serving does not cover kvbm tiers yet")
         if config.pp > 1:
@@ -611,6 +622,13 @@ class TpuEngine:
                     f"the window of {self._ring.positions} positions: a "
                     "chunk would straddle two windows"
                 )
+        pooled = registry.pooled_keys(self.mcfg)
+        if pooled is not None and pooled.stride != config.block_size:
+            raise ValueError(
+                f"a pooled key's stride is the page: block_size "
+                f"{config.block_size} != the family's kernel_stride "
+                f"{pooled.stride}"
+            )
         if registry.is_gptoss(self.mcfg) or registry.is_gemma(self.mcfg):
             # the ragged kernel carries per-row window/sink/softcap
             # attributes, so use_pallas serves these families too. Only the
@@ -1053,6 +1071,20 @@ class TpuEngine:
         self.kv_commits = None
         self._probe_load_fn = None  # EPLB load probe, jitted on first use
         self._build_programs()
+        if config.ready_single_step:
+            self._ready_single_step()
+
+    def _ready_single_step(self) -> None:
+        """``TpuEngineConfig.ready_single_step``: ``decode`` over NO row
+        (every row inactive: scratch writes, no state, no token), twice: on
+        the arrays as they were placed, and on the first call's results,
+        which is the variant every later call is (a program whose donated
+        arguments came from ``device_put`` is compiled again when they come
+        from another program). Leaves the counters as it found them."""
+        kept = self._moe_last, list(self._state_counts)
+        for _ in range(2):
+            self._run_decode([None] * self.cfg.max_batch_size)
+        self._moe_last, self._state_counts = kept
 
     # ------------------------------------------------------ kv transfer wiring
     async def serve_transfer(self, host: str = "127.0.0.1") -> str:
@@ -1203,6 +1235,10 @@ class TpuEngine:
             pages += (
                 self.summary_allocator.num_blocks * self._ring.pages_per_block
             )
+        if registry.pooled_keys(mcfg) is not None:
+            # one pooled key a block id, a row each of the K pool's pages
+            # above the requests' (ops/attention.py, InfLlmQuery)
+            pages += att.infllm_pool_pages(pages, self.cfg.block_size)
         shape = (
             pages,
             self.cfg.block_size,
@@ -1531,6 +1567,12 @@ class TpuEngine:
                         kc, vc, tables, lens, pages, write_offsets,
                         extra["eva"],
                     )
+                if "infllm" in extra:
+                    # ... or the pooled key of the page before it
+                    kc = attn.pool_rows(
+                        kc, tables, lens, pages, write_offsets,
+                        extra["infllm"],
+                    )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 cp = attn.decode_chunk_pages(kc, tables, extra)
                 if cp is not None:
@@ -1579,6 +1621,11 @@ class TpuEngine:
                         kc, vc, k_new, v_new, table, start, end,
                         extra["eva"],
                     )
+                if "infllm" in extra:
+                    # the pooled keys the chunk's whole pages make final
+                    kc = attn.pool_chunk(
+                        kc, k_new, table, start, end, extra["infllm"]
+                    )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 if cfg.sp > 1:
                     # context-parallel chunk attention: queries + chunk KV
@@ -1619,9 +1666,12 @@ class TpuEngine:
             """Dense causal forward, no KV pages touched; padded tail
             positions can't affect earlier queries (causal)."""
 
-            def attend(q, k_new, v_new, layer_idx, eva=None, **extra):
+            def attend(q, k_new, v_new, layer_idx, eva=None, infllm=None,
+                       **extra):
                 if eva is not None:  # a whole sequence from nothing
                     return att.eva_attention(q, k_new, v_new, eva)
+                if infllm is not None:
+                    return att.infllm_attention(q, k_new, v_new, infllm)
                 return att.causal_attention(q, k_new, v_new, **extra)
 
             return fwd(params, mcfg, tokens, positions, attend)  # [S, H]
@@ -1639,6 +1689,11 @@ class TpuEngine:
                     *real_rows(k_new, v_new, positions, total_len),
                     new_block_ids,
                 )
+                if "infllm" in extra:
+                    kc = seam.pool_chunk(
+                        kc, k_new, block_table, positions[0], total_len,
+                        extra["infllm"],
+                    )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 # a chunk's first token is real: its position is the start
                 return seam.chunk(
@@ -1863,6 +1918,16 @@ class TpuEngine:
                     kc, vc = attn.summarise_rows(
                         kc, vc, rows, lens, pages, a.write_offsets,
                         extra["eva"],
+                    )
+                if "infllm" in extra:
+                    # the pooled keys the chunk and the rows made final
+                    kc = attn.pool_chunk(
+                        kc, k_new[:S_pad], table, a.chunk_start, c_end,
+                        extra["infllm"],
+                    )
+                    kc = attn.pool_rows(
+                        kc, rows, lens, pages, a.write_offsets,
+                        extra["infllm"],
                     )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 tables = jnp.concatenate([table[None], rows], axis=0)
@@ -5265,7 +5330,7 @@ class TpuEngine:
             pre = self._state_prefix
             reads.update({
                 f"{pre}_rows_updated": rows, f"{pre}_tokens_scanned": scanned,
-                f"{pre}_decode_steps": steps,
+                f"{pre}_decode_steps": steps, "state_prefix": pre,
                 # the slot store's bytes, whatever recurrence fills it
                 "ssm_state_bytes": occupancy * self.state.bytes_per_slot,
             })

@@ -433,6 +433,25 @@ class StepStats:
     kda_rows_updated: Optional[int] = None
     kda_tokens_scanned: Optional[int] = None
     kda_decode_steps: Optional[int] = None
+    # ... and for one whose matrix state decays by a fixed rate a head
+    # (lightning attention: minicpm_sala)
+    lightning_rows_updated: Optional[int] = None
+    lightning_tokens_scanned: Optional[int] = None
+    lightning_decode_steps: Optional[int] = None
+    # the prefix the three counts above came under (registry.state_prefix,
+    # asked by the engine): "ssm", "kda", "lightning". None without slot state
+    state_prefix: Optional[str] = None
+    # a family whose page layers choose BLOCKS of keys from pooled keys
+    # (InfLLM-v2: models/minicpm_sala.py), on the step's own readback beside
+    # moe_*: the keys the step's real decode rows' launches were handed (the
+    # views' lengths, a kv head: ops/attention.infllm_decode_rows) and the
+    # causal keys they could have read, the (row, layer)s whose context was past
+    # dense_len (each summed over rows and sparse layers), and the pooled
+    # keys every token of the step made final (a key a kv head). None elsewhere
+    infllm_keys_selected: Optional[int] = None
+    infllm_keys_causal: Optional[int] = None
+    infllm_rows_sparse: Optional[int] = None
+    infllm_pooled_keys_written: Optional[int] = None
     # a family whose pages are a ring with summaries by window (EVA:
     # models/evabyte.py), on the step's own readback beside moe_*: the real
     # decode rows attended, the exact keys they read of their open windows
@@ -664,15 +683,24 @@ class EngineTelemetry:
             }
         if last is not None and last.ssm_state_bytes is not None:
             # the second kind of state: what the window's steps advanced
-            # (under the family's prefix: a state-space mixer "ssm", a
-            # linear-attention layer "kda")
-            pre = "kda" if last.kda_rows_updated is not None else "ssm"
+            # (under the family's prefix, registry.state_prefix: a
+            # state-space mixer "ssm", a linear-attention layer "kda" or
+            # "lightning")
+            pre = last.state_prefix or "ssm"
             out[pre] = {
                 "state_bytes": last.ssm_state_bytes,
                 "rows_updated": sum(
                     getattr(s, f"{pre}_rows_updated") or 0 for s in recent),
                 "tokens_scanned": sum(
                     getattr(s, f"{pre}_tokens_scanned") or 0 for s in recent),
+            }
+        if any(s.infllm_keys_causal for s in recent):
+            # block-sparse attention over pooled keys: what the window's
+            # decode rows chose of what they could have read
+            out["infllm"] = {
+                name: sum(getattr(s, f"infllm_{name}") or 0 for s in recent)
+                for name in ("keys_selected", "keys_causal", "rows_sparse",
+                             "pooled_keys_written")
             }
         if any(s.eva_rows_attended for s in recent):
             # the third kind of state: what the window's decode rows read of

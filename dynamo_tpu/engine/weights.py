@@ -36,6 +36,8 @@ def config_from_hf(path: str) -> LlamaConfig:
         return _solar_open2_config_from_hf(hf)
     if hf.get("model_type", "") == "cohere2_moe":
         return _cohere2_moe_config_from_hf(hf)
+    if hf.get("model_type", "") == "minicpm_sala":
+        return _minicpm_sala_config_from_hf(hf)
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return LlamaConfig(
         vocab_size=hf["vocab_size"],
@@ -92,6 +94,48 @@ def _solar_open2_config_from_hf(hf: dict):
         norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
         num_shared_experts=hf.get("n_shared_experts", 1),
+    )
+
+
+def _minicpm_sala_config_from_hf(hf: dict):
+    """MiniCPM-SALA's config.json -> MiniCpmSalaConfig: every key that shapes
+    the computation; the selection's sizes, which the keys do not fix, are
+    ``sparse_config``'s where the file has one and the class's defaults (the
+    family's convention) where not. benchmarks/adapters/minicpm_sala.py is
+    the benchmark's own copy of this mapping, with the layers it runs."""
+    from ..models.minicpm_sala import MiniCpmSalaConfig
+
+    if (hf.get("attn_use_rope") or not hf.get("lightning_use_rope", True)
+            or not hf.get("qk_norm", True) or hf.get("attention_bias")
+            or not (hf.get("use_output_gate", True) and hf.get("use_output_norm", True)
+                    and hf.get("attn_use_output_gate", True))
+            or hf.get("lightning_nkv", hf["lightning_nh"]) != hf["lightning_nh"]):
+        raise ValueError("minicpm_sala with rotary sparse layers, lightning "
+                         "layers without rotary, no q/k norm, biases, no output "
+                         "gate or norm, or grouped lightning keys is not built")
+    sparse = hf.get("sparse_config") or {}
+    sizes = {k: sparse[k] for k in ("kernel_size", "kernel_stride", "block_size", "topk",
+                                    "init_blocks", "window_size", "dense_len") if k in sparse}
+    return MiniCpmSalaConfig(
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"],
+        intermediate_size=hf["intermediate_size"],
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        max_position=hf.get("max_position_embeddings", 8192),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        sparse_layers=tuple(i for i, kind in enumerate(hf["mixer_types"]) if kind == "minicpm4"),
+        scale_emb=float(hf.get("scale_emb", 1.0)),
+        scale_depth=float(hf.get("scale_depth", 1.0)),
+        mup_denominator=int(hf.get("mup_denominator", hf["num_hidden_layers"])),
+        dim_model_base=int(hf.get("dim_model_base", hf["hidden_size"])),
+        lightning_heads=hf["lightning_nh"],
+        lightning_head_dim=hf["lightning_head_dim"],
+        **sizes,
     )
 
 
@@ -344,6 +388,16 @@ def load_params(path: str, cfg: Optional[LlamaConfig] = None) -> Dict[str, Any]:
             "random weights (models/solar_open2.init_params); the mapping of "
             "the checkpoint's tensors onto its pytree has not been held to a "
             "real checkpoint (ROADMAP R11)"
+        )
+    from ..models.minicpm_sala import MiniCpmSalaConfig
+
+    if isinstance(cfg, MiniCpmSalaConfig):
+        raise NotImplementedError(
+            "no checkpoint loader for minicpm_sala yet: the family serves "
+            "random weights (models/minicpm_sala.init_params); the mapping of "
+            "model.layers.N.self_attn.{q,k,v,o}_proj, {q,k}_norm, o_gate / "
+            "z_proj, o_norm and mlp.*_proj onto its pytree has not been held "
+            "to a real checkpoint (none is here; ROADMAP R11)"
         )
     from ..models.cohere2_moe import Cohere2MoeConfig
 

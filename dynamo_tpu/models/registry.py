@@ -14,7 +14,8 @@ from jax.sharding import PartitionSpec as P
 from ..ops.pallas_moe import grouped_matmul, grouped_matmul_reference
 from ..parallel.mesh import AXIS_TP, shard_map
 from . import (
-    cohere2_moe, evabyte, falcon_h1, gemma, gptoss, llama, mla, moe, solar_open2,
+    cohere2_moe, evabyte, falcon_h1, gemma, gptoss, llama, minicpm_sala, mla,
+    moe, solar_open2,
 )
 
 
@@ -42,6 +43,10 @@ def is_solar_open2(cfg) -> bool:
     return isinstance(cfg, solar_open2.SolarOpen2Config)
 
 
+def is_minicpm_sala(cfg) -> bool:
+    return isinstance(cfg, minicpm_sala.MiniCpmSalaConfig)
+
+
 def is_evabyte(cfg) -> bool:
     return isinstance(cfg, evabyte.EvaByteConfig)
 
@@ -59,7 +64,7 @@ def supports_pp(cfg) -> bool:
     fit (parallel/pp_serving.py)."""
     return not (is_moe(cfg) or is_mla(cfg) or is_gptoss(cfg) or is_gemma(cfg)
                 or is_falcon_h1(cfg) or is_solar_open2(cfg) or is_evabyte(cfg)
-                or is_cohere2_moe(cfg))
+                or is_cohere2_moe(cfg) or is_minicpm_sala(cfg))
 
 
 def check_pp_supported(cfg) -> None:
@@ -71,7 +76,7 @@ def check_pp_supported(cfg) -> None:
         raise ValueError(
             f"pp serving supports dense llama-family models only; "
             f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1/solar-open2/evabyte/"
-            f"cohere2-moe) is not "
+            f"cohere2-moe/minicpm-sala) is not "
             f"stacked for pipeline stages — configure this preset with pp=1 "
             f"(use tp/sp/dp instead)"
         )
@@ -165,7 +170,8 @@ def state_spec(cfg) -> tuple:
     """Per-layer arrays ONE SLOT holds beside the paged keys, as (name,
     shape, dtype): a state-space mixer's recurrent state and its
     convolution's tail (``falcon_h1.state_spec``), a linear-attention
-    layer's matrix state and its tail (``solar_open2.state_spec``); () for
+    layer's matrix state and its tail (``solar_open2.state_spec``) or its
+    matrix state alone (``minicpm_sala.state_spec``); () for
     every family whose only state is pages. engine/state_cache.py builds the
     store from it, one array a layer of ``state_layers``, and the step
     programs take and return it only where it is not empty. The family's
@@ -197,6 +203,18 @@ def page_groups(cfg) -> tuple:
     says otherwise (``cohere2_moe.page_groups``: pages by layer kind)."""
     own = getattr(family(cfg), "page_groups", None)
     return own(cfg) if own else ((page_layers(cfg), None),)
+
+
+def pooled_keys(cfg):
+    """What a page layer keeps BESIDE its pages, or None (every other
+    family): an ``ops/attention.InfLlmQuery`` where it keeps ONE POOLED KEY a
+    page a kv head, by block id (the family's ``pooled_keys``: block-sparse
+    attention that chooses its blocks from them, ``minicpm_sala``). The
+    engine then gives the K pool a row a block id above the requests' pages
+    (``_init_caches``; ops/attention.py has the layout), holds the page to
+    the pooled keys' stride, and the attention seam writes and reads them."""
+    own = getattr(family(cfg), "pooled_keys", None)
+    return own(cfg) if own else None
 
 
 def state_layers(cfg) -> tuple:
@@ -381,6 +399,8 @@ def family(cfg):
         return cohere2_moe
     if is_evabyte(cfg):
         return evabyte
+    if is_minicpm_sala(cfg):
+        return minicpm_sala
     if is_solar_open2(cfg):
         return solar_open2
     if is_falcon_h1(cfg):
@@ -433,6 +453,8 @@ def forward_fn(cfg, mesh=None, use_pallas: bool = False,
         return falcon_h1.forward
     if is_evabyte(cfg):
         return evabyte.forward
+    if is_minicpm_sala(cfg):
+        return minicpm_sala.forward
     if is_solar_open2(cfg) or is_cohere2_moe(cfg):
         # the held (or replicated) experts' grouped path, its multiplication
         # as for MlaConfig below; tp > 1 is refused at construction
@@ -584,6 +606,15 @@ def param_specs(cfg) -> dict:
         # attention layers' specs are the dense family's, their output gate
         # follows the heads, everything else replicates
         layer["w_gate"] = P(None, AXIS_TP)
+        return {"top": top, "layer": layer, "default": P()}
+    if is_minicpm_sala(cfg):
+        # tp > 1 is refused at construction (check_state_supported): the
+        # dense family's specs, both kinds of layer's output gate follows
+        # the heads
+        layer.update({
+            "w_gate": P(None, AXIS_TP), "w_up": P(None, AXIS_TP),
+            "w_down": P(AXIS_TP, None), "w_ogate": P(None, AXIS_TP),
+        })
         return {"top": top, "layer": layer, "default": P()}
     if is_gptoss(cfg):
         layer.update({

@@ -23,6 +23,7 @@ against, float and int8 alike.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -787,3 +788,312 @@ def eva_paged_decode_attention(q: jax.Array, k_cache: jax.Array,
     pure-JAX twin of ops/pallas_eva.eva_decode_attention."""
     view, lens = eva_paged_view(tables, seq_lens, eva, k_cache.shape[1], base)
     return paged_decode_attention(q, k_cache, v_cache, view, lens)
+
+
+# ---------------------------------------------------------------------------
+# Block-sparse attention over POOLED KEYS (InfLLM-v2, MiniCPM4 arXiv
+# 2506.07900; models/minicpm_sala.py). A page layer keeps, beside its pages,
+# ONE POOLED KEY a page a kv head: key ``j`` is the mean of the ``kernel``
+# keys from ``j * stride`` on, with ``stride`` the page and ``kernel`` two
+# pages, so it is a function of pages ``j`` and ``j + 1`` and FINAL when page
+# ``j + 1`` fills. It is kept BY BLOCK ID, under the id of page ``j``, in the
+# K pool's pages above the requests' (``base`` on: page ``base + id // page``,
+# row ``id % page``; the same rows of the V pool hold nothing), so that it
+# rides every step program inside the array it already takes, returns and
+# donates. Past ``dense_len`` keys a query attends over the blocks of
+# ``block`` keys it CHOOSES, a kv head: the forced ones (the first
+# ``init_blocks`` and the ``window // block + 1`` that end at its own) and the
+# ``topk`` best of the others by the pooled scores.
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class InfLlmQuery:
+    """What a block-sparse layer asks of the attention seam besides q, k and
+    v: the sizes of its selection. A trace-time object, as an ``EvaQuery``."""
+
+    kernel: int                        # keys a pooled key averages
+    stride: int                        # keys between two pooled keys: the page
+    block: int                         # keys a block that is chosen whole
+    topk: int                          # blocks chosen beside the forced ones
+    init_blocks: int                   # forced: the context's first blocks
+    window: int                        # forced: the blocks that end at the query's
+    dense_len: int                     # contexts up to here attend every key
+    # where the seam leaves what each decode launch was HANDED, for the
+    # family's counters: the views' lengths [R, kvh], once a layer
+    handed: Optional[list] = dataclasses.field(default=None, compare=False, repr=False)
+
+    def __post_init__(self):
+        if self.kernel != 2 * self.stride or self.block % self.stride:
+            raise ValueError(
+                f"a pooled key is two pages ({self.kernel} / {self.stride}) "
+                f"and a block whole pages ({self.block})"
+            )
+
+    @property
+    def per_block(self) -> int:
+        """Pooled keys (and pages) a block."""
+        return self.block // self.stride
+
+    @property
+    def local_blocks(self) -> int:
+        return self.window // self.block + 1
+
+    @property
+    def max_chosen(self) -> int:
+        return self.topk + self.init_blocks + self.local_blocks
+
+
+def infllm_pool_pages(num_blocks: int, page: int) -> int:
+    """Pages the K pool holds above ``num_blocks`` for the pooled keys: one
+    row a block id."""
+    return -(-num_blocks // page)
+
+
+def _infllm_slots(ids: jax.Array, page: int, base: int):
+    """(page, row) in the K pool of the pooled key kept under block ``ids``
+    (scratch block 0's slot is scratch too)."""
+    return base + ids // page, ids % page
+
+
+def _page_pair_mean(pages: jax.Array) -> jax.Array:
+    """[..., 2, page, kvh, d] -> the pooled key [..., kvh, d] float32: each
+    page summed, then the two (one order of summation wherever a key is
+    pooled)."""
+    sums = jnp.sum(pages.astype(jnp.float32), axis=-3)
+    return (sums[..., 0, :, :] + sums[..., 1, :, :]) / (2 * pages.shape[-3])
+
+
+def infllm_pool_chunk(k_cache: jax.Array, k_new: jax.Array, table: jax.Array,
+                      chunk_start, total_len, spec: InfLlmQuery,
+                      base: int, page_view: bool = False) -> jax.Array:
+    """The pooled keys a chunk makes final (``k_new`` [S_pad, kvh, d] from
+    the page-aligned ``chunk_start``, real up to ``total_len``): key ``j``
+    for every page ``j + 1`` the chunk fills, the first of them from the
+    page BEFORE the chunk, read back from the pool. Returns the K pool.
+    ``page_view``: read and write the pool as the Pallas kernels read it and
+    as the chunk's own pages were just written (``write_prefill_kv`` has the
+    reason: a write in another view has the pool copied there and back)."""
+    bs = k_cache.shape[1]
+    if spec.stride != bs:
+        raise ValueError(f"a pooled key's stride is the page: {spec.stride} != {bs}")
+    n = k_new.shape[0] // bs
+    first = chunk_start // bs
+    nb, _, kvh, d = k_cache.shape
+    rows = k_cache.reshape(nb, bs * kvh, d) if page_view else k_cache
+    before = rows[table[jnp.maximum(first - 1, 0)]].reshape(bs, kvh, d)
+    pages = jnp.concatenate([before[None], k_new.reshape(n, bs, kvh, d)])
+    pooled = _page_pair_mean(jnp.stack([pages[:-1], pages[1:]], axis=1))
+    j = first - 1 + jnp.arange(n)
+    final = (j >= 0) & ((j + 2) * bs <= total_len)
+    ids = jnp.where(final, table[jnp.clip(j, 0, table.shape[0] - 1)], 0)
+    page, row = _infllm_slots(ids, bs, base)
+    pooled = pooled.astype(k_cache.dtype)
+    if not page_view:
+        return k_cache.at[page, row].set(pooled)
+    at = (row * kvh)[:, None] + jnp.arange(kvh)[None]
+    return rows.at[page[:, None], at].set(pooled).reshape(k_cache.shape)
+
+
+def infllm_pool_rows(k_cache: jax.Array, tables: jax.Array, seq_lens: jax.Array,
+                     write_blocks: jax.Array, write_offsets: jax.Array,
+                     spec: InfLlmQuery, base: int) -> jax.Array:
+    """Decode rows, their token written at ``(write_blocks, write_offsets)``
+    (scratch page 0: not a live row): a row whose token FILLED its page
+    makes the pooled key of the page before it final, from the two pages
+    read back whole. Returns the K pool."""
+    bs = k_cache.shape[1]
+    pos = jnp.maximum(seq_lens - 1, 0)
+    final = (write_blocks > 0) & (write_offsets == bs - 1) & (pos >= spec.kernel - 1)
+    j = jnp.maximum(pos // bs - 1, 0)
+    two = jnp.take_along_axis(tables, jnp.stack([j, j + 1], axis=1), axis=1)
+    pooled = _page_pair_mean(k_cache[two])                       # [R, kvh, d]
+    ids = jnp.where(final, two[:, 0], 0)
+    return k_cache.at[_infllm_slots(ids, bs, base)].set(pooled.astype(k_cache.dtype))
+
+
+def infllm_pooled_keys(k_cache: jax.Array, tables: jax.Array, base: int) -> jax.Array:
+    """``tables`` [R, mb] -> the pooled keys kept under their entries [R, mb,
+    kvh, d] (an entry whose key is not final yet holds what was there). Read
+    through the pool's page view, as every launch reads it."""
+    nb, bs, kvh, d = k_cache.shape
+    page, row = _infllm_slots(tables, bs, base)
+    at = (row * kvh)[..., None] + jnp.arange(kvh)
+    return k_cache.reshape(nb, bs * kvh, d)[page[..., None], at]
+
+
+def infllm_select(q: jax.Array, pooled: jax.Array, n_keys: jax.Array,
+                  spec: InfLlmQuery) -> Tuple[jax.Array, jax.Array]:
+    """The blocks each query chooses, a kv head. ``q`` [R, h, d] at the end
+    of contexts of ``n_keys`` [R] keys; ``pooled`` [R or 1, mb, kvh, d] (one
+    set for all: the queries are one row's chunk). A head's softmax over the
+    pooled keys that are final for the query, summed over the heads of a kv
+    head, max-pooled to blocks (``per_block + 1`` wide from one key before
+    the block's first); forced blocks first, then the ``topk`` best of the
+    blocks before the window. Returns (blocks [R, kvh, K] ascending, the
+    places past ``count`` holding ``nb``; count [R, kvh])."""
+    R, h, d = q.shape
+    mb, kvh = pooled.shape[1:3]
+    ppb = spec.per_block
+    nb = -(-mb // ppb)
+    qg = q.reshape(R, kvh, h // kvh, d).astype(jnp.float32)
+    pk = pooled.astype(jnp.float32)
+    s = (jnp.einsum("rkgd,jkd->rkgj", qg, pk[0]) if pk.shape[0] == 1
+         else jnp.einsum("rkgd,rjkd->rkgj", qg, pk)) * d ** -0.5
+    n_pool = jnp.maximum((n_keys - spec.kernel) // spec.stride + 1, 0)
+    final = (jnp.arange(mb)[None] < n_pool[:, None])[:, None, None]
+    p = jnp.where(final, jax.nn.softmax(jnp.where(final, s, NEG_INF), axis=-1), 0.0)
+    group = jnp.sum(p, axis=2)                                   # [R, kvh, mb]
+    # a block's score: the max over its pooled keys and the one before them
+    padded = jnp.pad(group, ((0, 0), (0, 0), (1, nb * ppb - mb)), constant_values=-1.0)
+    score = padded[..., 0::ppb][..., :nb]
+    for i in range(1, ppb + 1):
+        score = jnp.maximum(score, padded[..., i::ppb][..., :nb])
+    b = jnp.arange(nb)[None]
+    last = (jnp.maximum(n_keys - 1, 0) // spec.block)[:, None]
+    forced = (b <= last) & ((b < spec.init_blocks) | (b > last - spec.local_blocks))
+    cand = (b <= last - spec.local_blocks) & (b >= spec.init_blocks)
+    key = jnp.where(forced[:, None], 1e9, jnp.where(cand[:, None], score, -1e9))
+    K = min(spec.max_chosen, nb)
+    _, idx = jax.lax.top_k(key, K)
+    count = (jnp.sum(forced, axis=-1) + jnp.minimum(jnp.sum(cand, axis=-1), spec.topk))
+    count = jnp.minimum(count, K)[:, None]
+    blocks = jnp.sort(jnp.where(jnp.arange(K)[None, None] < count[..., None], idx, nb), axis=-1)
+    return blocks.astype(jnp.int32), jnp.broadcast_to(count, (R, kvh)).astype(jnp.int32)
+
+
+def infllm_view_width(spec: InfLlmQuery, page: int, max_blocks: int) -> int:
+    """Entries of a (row, kv head)'s table: the chosen blocks' pages, or
+    every page of a context that attends densely, whichever is more."""
+    return min(max_blocks, max(spec.max_chosen * spec.per_block, -(-spec.dense_len // page)))
+
+
+def infllm_paged_view(tables: jax.Array, n_keys: jax.Array, blocks: jax.Array,
+                      count: jax.Array, spec: InfLlmQuery, page: int
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """Decode rows as ONE paged sequence a (row, kv head): ``tables`` [R, mb],
+    ``n_keys`` [R] (0 = an empty row), ``blocks`` / ``count`` as
+    ``infllm_select`` gives them -> tables [R * kvh, W] and lengths [R *
+    kvh]. Past ``dense_len`` a view holds the pages of the blocks the kv head
+    chose, ascending, so the query's own block comes last and alone may be
+    partial; up to ``dense_len`` it holds the row's own pages. Entries past a
+    view's length are never read."""
+    R, mb = tables.shape
+    kvh = blocks.shape[1]
+    ppb = spec.per_block
+    W = infllm_view_width(spec, page, mb)
+    e = jnp.arange(W)
+    of_block = jnp.take_along_axis(
+        blocks, jnp.minimum(e // ppb, blocks.shape[-1] - 1)[None, None], axis=-1
+    ) * ppb + e % ppb                                            # [R, kvh, W]
+    chosen = jnp.take_along_axis(
+        jnp.broadcast_to(tables[:, None], (R, kvh, mb)), jnp.minimum(of_block, mb - 1), axis=-1
+    )
+    sparse = (n_keys > spec.dense_len)[:, None]
+    view = jnp.where(sparse[..., None], chosen, tables[:, None, :W])
+    last_fill = jnp.maximum(n_keys - 1, 0) % spec.block + 1
+    lens = jnp.where(sparse, (count - 1) * spec.block + last_fill[:, None], n_keys[:, None])
+    return (view.reshape(R * kvh, W).astype(jnp.int32),
+            lens.reshape(R * kvh).astype(jnp.int32))
+
+
+def infllm_decode_rows(q: jax.Array, k_cache: jax.Array, tables: jax.Array,
+                       seq_lens: jax.Array, spec: InfLlmQuery, base: int):
+    """What a decode launch over the chosen pages takes: the queries once a
+    kv head [R * kvh, h, d], the views and their lengths."""
+    kvh = k_cache.shape[2]
+    with jax.named_scope("infllm_select"):
+        blocks, count = infllm_select(
+            q, infllm_pooled_keys(k_cache, tables, base), seq_lens, spec
+        )
+        view, lens = infllm_paged_view(tables, seq_lens, blocks, count, spec, k_cache.shape[1])
+    if spec.handed is not None:
+        spec.handed.append(lens.reshape(-1, kvh))
+    return jnp.repeat(q, kvh, axis=0), view, lens
+
+
+def infllm_own_heads(out: jax.Array, kvh: int) -> jax.Array:
+    """[R * kvh, h, d], view (r, i)'s result for every head -> [R, h, d]:
+    each head from the view of its own kv head."""
+    RK, h, d = out.shape
+    o = out.reshape(RK // kvh, kvh, kvh, h // kvh, d)
+    i = jnp.arange(kvh)
+    return o[:, i, i].reshape(RK // kvh, h, d)
+
+
+def infllm_paged_decode_attention(q: jax.Array, k_cache: jax.Array,
+                                  v_cache: jax.Array, tables: jax.Array,
+                                  seq_lens: jax.Array, spec: InfLlmQuery,
+                                  base: int) -> jax.Array:
+    """Decode rows of a block-sparse layer: the pure-JAX twin of the launch
+    ``infllm_decode_attention`` (ops/paged_attention.py)."""
+    qv, view, lens = infllm_decode_rows(q, k_cache, tables, seq_lens, spec, base)
+    out = paged_decode_attention(qv, k_cache, v_cache, view, lens)
+    return infllm_own_heads(out, k_cache.shape[2])
+
+
+# queries a pass of the masked chunk attention: [h, Q, keys] float32 scores
+INFLLM_QUERY_BLOCK = 128
+
+
+def _infllm_masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                             pooled: jax.Array, n_keys: jax.Array,
+                             spec: InfLlmQuery) -> jax.Array:
+    """Queries ``q`` [Q, h, d], each at the end of ``n_keys`` [Q] of the keys
+    ``k``, ``v`` [T, kvh, d] of ONE sequence whose pooled keys are ``pooled``
+    [1, mb, kvh, d]: every query's own choice of blocks as a mask over all
+    ``T`` keys (every causal key where ``n_keys <= dense_len``)."""
+    Q, h, d = q.shape
+    T, kvh = k.shape[:2]
+    nb = -(-pooled.shape[1] // spec.per_block)
+    with jax.named_scope("infllm_select"):
+        blocks, _ = infllm_select(q, pooled, n_keys, spec)
+        chosen = jnp.any(blocks[..., None] == jnp.arange(nb), axis=-2)   # [Q, kvh, nb]
+    mask = jnp.repeat(chosen, spec.block, axis=-1)[..., :T]
+    mask = mask | (n_keys <= spec.dense_len)[:, None, None]
+    mask = mask & (jnp.arange(T)[None] < n_keys[:, None])[:, None]
+    scores = _gqa_scores(q, k).reshape(Q, kvh, h // kvh, T) * d ** -0.5
+    scores = jnp.where(mask[:, :, None], scores, NEG_INF)
+    weights = jax.nn.softmax(scores, axis=-1).reshape(Q, h, T)
+    return _gqa_values(weights, v).astype(q.dtype)
+
+
+def infllm_chunk_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                           table: jax.Array, positions: jax.Array, total_len,
+                           spec: InfLlmQuery, base: int) -> jax.Array:
+    """One chunk at its context's tail with queries past ``dense_len``:
+    every query's own choice of blocks as a MASK over the row's gathered
+    pages (the mathematics whole, the cost dense: the block-sparse prefill
+    kernel is queued, ROADMAP R23 (a)), ``INFLLM_QUERY_BLOCK`` queries a
+    pass. ``q`` [S_pad, h, d] at ``positions``; the chunk's keys and pooled
+    keys are already in the pool."""
+    S, h, d = q.shape
+    nb_pool, bs, kvh, _ = k_cache.shape
+    T = table.shape[0] * bs
+    # gathered through the page view (a 4-d gather has the pool re-tiled)
+    k_ctx, v_ctx = (c.reshape(nb_pool, bs * kvh, d)[table].reshape(T, kvh, d)
+                    for c in (k_cache, v_cache))
+    with jax.named_scope("infllm_select"):
+        pooled = infllm_pooled_keys(k_cache, table[None], base)
+    QB = math.gcd(S, INFLLM_QUERY_BLOCK)
+
+    def one(args):
+        qb, pos = args
+        return _infllm_masked_attention(
+            qb, k_ctx, v_ctx, pooled, jnp.minimum(pos + 1, total_len), spec)
+
+    out = jax.lax.map(one, (q.reshape(S // QB, QB, h, d), positions.reshape(S // QB, QB)))
+    return out.reshape(S, h, d)
+
+
+def infllm_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     spec: InfLlmQuery) -> jax.Array:
+    """A whole sequence from nothing (``q`` [S, h, d]; no pages, no pool):
+    the stateless twin, every pooled key computed from the keys themselves."""
+    S, _, d = q.shape
+    kvh = k.shape[1]
+    mb = -(-S // spec.stride)
+    kp = jnp.pad(k.astype(jnp.float32), ((0, (mb + 1) * spec.stride - S), (0, 0), (0, 0)))
+    pages = kp.reshape(mb + 1, spec.stride, kvh, d)
+    pooled = _page_pair_mean(jnp.stack([pages[:-1], pages[1:]], axis=1))[None]
+    return _infllm_masked_attention(q, k, v, pooled, jnp.arange(S) + 1, spec)

@@ -54,6 +54,21 @@ straight through.
   are read their summaries are written (``summarise_chunk``,
   ``summarise_rows``, scope ``eva_summarise``): a chunk's summary is final
   once its page is full, and the step that fills the page writes it.
+- A layer that hands an ``infllm`` (ops/attention.InfLlmQuery: block-sparse
+  attention over pooled keys, models/minicpm_sala.py) keeps ONE POOLED KEY a
+  page a kv head beside its pages, by block id, in the K pool's pages above
+  ``summary_base`` (ops/attention.py has the layout). Before the rows are
+  read the keys their tokens made final are written (``pool_chunk``,
+  ``pool_rows``, scope ``infllm_pool_write``). Decode rows read the pooled
+  keys of their tables and choose their blocks a kv head (scope
+  ``infllm_select``, XLA), then attend as ONE paged sequence a (row, kv
+  head) (``ops/attention.infllm_paged_view``: the chosen blocks' pages,
+  ascending) through the decode kernel's own walk under the launch name
+  ``infllm_decode_attention``; never every key. A chunk whose context ends
+  within ``dense_len`` is the dense family's launch; past it the chunk's
+  queries attend under a mask over the row's gathered pages (pure JAX: the
+  block-sparse prefill kernel is queued, ROADMAP). A mixed step asks both
+  questions, the chunk's and the rows'.
 - A family whose pages are kept BY LAYER KIND (models/registry.page_groups)
   hands the launches above the rows of a layer's OWN group (``GroupView``):
   a windowed group's run of a row's table, which starts at the oldest page
@@ -119,6 +134,12 @@ class GroupView:
                 run, ((chunk_start - base) // self.page,), ids.shape
             )
         return run, chunk_start - base, total_len - base, positions - base, ids
+
+
+# the launch over the chosen pages a (row, kv head): the decode kernel's body
+# (ops/pallas_attention.py) under a name of its own, so that no reader of the
+# dense launch's roofline, which reckons every causal key, ever counts it
+INFLLM_KERNEL_NAME = "infllm_decode_attention"
 
 
 class PagedAttention:
@@ -272,10 +293,53 @@ class PagedAttention:
 
             return jax.lax.fori_loop(0, jnp.sum(full), one, (kc, vc))
 
+    def pool_chunk(self, kc, k_new, table, chunk_start, total_len, infllm):
+        """The pooled keys a chunk's whole pages make final, into the K pool
+        (ops/attention.infllm_pool_chunk)."""
+        with jax.named_scope("infllm_pool_write"):
+            return att.infllm_pool_chunk(
+                kc, k_new, table, chunk_start, total_len, infllm,
+                self.summary_base, page_view=self._page_view,
+            )
+
+    def pool_rows(self, kc, tables, seq_lens, write_blocks, write_offsets,
+                  infllm):
+        """Decode rows: the pooled key a row's token made final by filling
+        its page (ops/attention.infllm_pool_rows)."""
+        with jax.named_scope("infllm_pool_write"):
+            return att.infllm_pool_rows(
+                kc, tables, seq_lens, write_blocks, write_offsets, infllm,
+                self.summary_base,
+            )
+
+    def _infllm_decode(self, q, kc, vc, tables, seq_lens, infllm):
+        """Decode rows of a block-sparse layer: the selection, then ONE
+        launch over a table a (row, kv head)."""
+        if not self.use_pallas:
+            return att.infllm_paged_decode_attention(
+                q, kc, vc, tables, seq_lens, infllm, self.summary_base
+            )
+        from . import pallas_attention as pa
+
+        qv, view, lens = att.infllm_decode_rows(
+            q, kc, tables, seq_lens, infllm, self.summary_base
+        )
+        with jax.named_scope("infllm_attend"):
+            out = pa.paged_decode_attention(
+                qv, kc, vc, view, lens, interpret=self.interpret,
+                name=INFLLM_KERNEL_NAME,
+            )
+        return att.infllm_own_heads(out, kc.shape[2])
+
     def _eva_view(self, kc, tables, seq_lens, eva):
         return att.eva_paged_view(
             tables, seq_lens, eva, kc.shape[1], self.summary_base
         )
+
+    @property
+    def _page_view(self) -> bool:
+        """Whether a chunk's writes go through the view the kernels read."""
+        return self.use_pallas and self.mesh.size == 1
 
     def write_chunk(self, kc, vc, k_new, v_new, block_ids):
         """A chunk's whole pages into the pool, before the launch that reads
@@ -285,8 +349,7 @@ class PagedAttention:
         pages are written on the view the kernel reads, so that nothing
         between the write and the launch has the pool to re-tile."""
         return att.write_prefill_kv(
-            kc, vc, k_new, v_new, block_ids,
-            page_view=self.use_pallas and self.mesh.size == 1,
+            kc, vc, k_new, v_new, block_ids, page_view=self._page_view,
         )
 
     def decode_chunk_pages(self, kc, tables, extra):
@@ -306,9 +369,11 @@ class PagedAttention:
         )
 
     def decode(self, q, kc, vc, tables, seq_lens, dsa=None, latent=None,
-               eva=None, **extra):
+               eva=None, infllm=None, **extra):
         """Decode rows: ``q [B, h, d]``, one token a row at the end of a
         context of ``seq_lens[b]`` tokens (0 = an empty row)."""
+        if infllm is not None:
+            return self._infllm_decode(q, kc, vc, tables, seq_lens, infllm)
         if eva is not None:
             with jax.named_scope("eva_attend"):
                 if not self.use_pallas:
@@ -347,10 +412,24 @@ class PagedAttention:
         )
 
     def chunk(self, q, kc, vc, table, chunk_start, total_len, positions,
-              dsa=None, latent=None, eva=None, **extra):
+              dsa=None, latent=None, eva=None, infllm=None, **extra):
         """One chunk at its context's tail: ``q [S_pad, h, d]`` at absolute
         ``positions``, the real ones ``chunk_start .. total_len - 1``, over
         ONE ``table``; the chunk's own keys are already in the cache."""
+        if infllm is not None:
+            dense = lambda: self.chunk(  # noqa: E731
+                q, kc, vc, table, chunk_start, total_len, positions
+            )
+            if table.shape[0] * kc.shape[1] <= infllm.dense_len:
+                return dense()      # no context this table holds selects
+            return jax.lax.cond(
+                total_len > infllm.dense_len,
+                lambda: att.infllm_chunk_attention(
+                    q, kc, vc, table, positions, total_len, infllm,
+                    self.summary_base,
+                ),
+                dense,
+            )
         if eva is not None:
             # the row as one paged sequence, the chunk still at its tail
             with jax.named_scope("eva_attend"):
@@ -383,12 +462,26 @@ class PagedAttention:
         )
 
     def ragged(self, q, kc, vc, tables, q_starts, q_lens, seq_lens,
-               dsa=None, latent=None, eva=None, **extra):
+               dsa=None, latent=None, eva=None, infllm=None, **extra):
         """Ragged rows over a packed ``q [Tq, h, d]``: row ``r`` owns
         ``q[q_starts[r] : q_starts[r] + q_lens[r]]`` at the tail of its
         context (ops/attention.ragged_paged_attention has the contract).
         With a ``dsa`` or a ``latent`` the rows are the mixed step's: row 0 a chunk at the
         front of ``q``, every further row one token behind it."""
+        if infllm is not None:
+            # the mixed step's two questions, asked apart: the chunk's
+            # queries (dense or under their mask), the rows' chosen pages
+            n_chunk = q.shape[0] - (tables.shape[0] - 1)
+            return jnp.concatenate([
+                self.chunk(
+                    q[:n_chunk], kc, vc, tables[0], seq_lens[0] - q_lens[0],
+                    seq_lens[0],
+                    seq_lens[0] - q_lens[0] + jnp.arange(n_chunk), infllm=infllm,
+                ),
+                self._infllm_decode(
+                    q[n_chunk:], kc, vc, tables[1:], seq_lens[1:], infllm
+                ),
+            ])
         if eva is not None:
             with jax.named_scope("eva_attend"):
                 view, lens = self._eva_view(kc, tables, seq_lens, eva)

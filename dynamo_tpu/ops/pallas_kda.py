@@ -76,9 +76,12 @@ def kda_state_update_reference(
     return jnp.where(keep[..., None], S2, S), jnp.where(keep, y, 0.0)
 
 
-def _update_kernel(rows_ref, n_ref, s_ref, cols_ref, v_ref, beta_ref,
-                   o_ref, y_ref):
+def _update_kernel(rows_ref, n_ref, s_ref, cols_ref, v_ref, *rest, delta: bool):
+    """``delta``: the delta rule's correction (``beta`` rides as one operand
+    more); without it the rule is plain decayed accumulation, ``S' + k
+    v^T`` (ops/pallas_lightning.py)."""
     del rows_ref  # read by the index maps
+    beta_ref, o_ref, y_ref = rest if delta else (None, *rest)
     i, n = pl.program_id(0), n_ref[0]
     hb = s_ref.shape[1]
 
@@ -90,8 +93,10 @@ def _update_kernel(rows_ref, n_ref, s_ref, cols_ref, v_ref, beta_ref,
             kc = cols[:, 3 * h + 1:3 * h + 2]
             qc = cols[:, 3 * h + 2:3 * h + 3]
             s1 = a * s_ref[0, h]
-            u = jnp.sum(s1 * kc, axis=0, keepdims=True)          # [1, dv]
-            w = beta_ref[0, h:h + 1, :] * (v_ref[0, h:h + 1, :] - u)
+            w = v_ref[0, h:h + 1, :]
+            if delta:
+                u = jnp.sum(s1 * kc, axis=0, keepdims=True)      # [1, dv]
+                w = beta_ref[0, h:h + 1, :] * (w - u)
             s2 = s1 + kc * w
             o_ref[0, h] = s2
             y_ref[0, h:h + 1, :] = jnp.sum(s2 * qc, axis=0, keepdims=True)
@@ -104,12 +109,15 @@ def _update_kernel(rows_ref, n_ref, s_ref, cols_ref, v_ref, beta_ref,
         y_ref[...] = jnp.zeros_like(y_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "head_block"),
+@functools.partial(jax.jit, static_argnames=("interpret", "head_block", "name"),
                    donate_argnums=(0,))
 def kda_state_update(S, q, k, v, alpha, beta, live, *, interpret: bool = False,
-                     head_block: int = HEAD_BLOCK):
+                     head_block: int = HEAD_BLOCK, name: str = KERNEL_NAME):
     """``kda_state_update_reference`` as one Pallas launch: ``S`` (donated)
-    is updated in place, live rows only. Returns (S', y [R, H, dv] float32)."""
+    is updated in place, live rows only. Returns (S', y [R, H, dv] float32).
+    ``beta`` None: the rule WITHOUT its delta correction, ``S = alpha S +
+    k v^T`` (a linear-attention layer with a plain decay: its launch, under
+    its own ``name``, is this skeleton: ops/pallas_lightning.py)."""
     R, H, dk, dv = S.shape
     hb = min(head_block, H)
     if H % hb or 3 * hb > LANES:
@@ -122,8 +130,10 @@ def kda_state_update(S, q, k, v, alpha, beta, live, *, interpret: bool = False,
     cols = jnp.stack([alpha, k, q], axis=2).astype(F32)          # [R, H, 3, dk]
     cols = cols.reshape(R, nj, 3 * hb, dk).transpose(0, 1, 3, 2)
     cols = jnp.pad(cols, ((0, 0),) * 3 + ((0, LANES - 3 * hb),))
-    vf = v.astype(F32)
-    beta_b = jnp.broadcast_to(beta.astype(F32)[..., None], (R, H, dv))
+    delta = beta is not None
+    operands = [S, cols, v.astype(F32)]
+    if delta:
+        operands.append(jnp.broadcast_to(beta.astype(F32)[..., None], (R, H, dv)))
 
     head_map = live_block_map(nj)
 
@@ -139,11 +149,11 @@ def kda_state_update(S, q, k, v, alpha, beta, live, *, interpret: bool = False,
     cols_spec = pl.BlockSpec((1, 1, dk, LANES), state_idx)
     vec_spec = pl.BlockSpec((1, hb, dv), vec_idx)
     S_new, y = pl.pallas_call(
-        _update_kernel,
+        functools.partial(_update_kernel, delta=delta),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, nj),
-            in_specs=[state_spec, cols_spec, vec_spec, vec_spec],
+            in_specs=[state_spec, cols_spec] + [vec_spec] * (len(operands) - 2),
             out_specs=[state_spec, vec_spec],
         ),
         out_shape=[
@@ -155,8 +165,8 @@ def kda_state_update(S, q, k, v, alpha, beta, live, *, interpret: bool = False,
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-        name=KERNEL_NAME,
-    )(rows, n_live[None], S, cols, vf, beta_b)
+        name=name,
+    )(rows, n_live[None], *operands)
     # a dead row's y was never written: select, do not multiply
     return S_new, jnp.where(live[:, None, None], y, 0.0)
 

@@ -314,6 +314,45 @@ def _cases():
                     vec, s((rows, 64), F32), s((rows,), jnp.bool_))
         return pallas_kda.kda_state_update, build
 
+    def lightning_update(rows):
+        # a lightning layer's decode recurrence at MiniCPM-SALA's widths: 32
+        # heads x [128 key channels, 128 value lanes] float32 a row, the
+        # delta rule's skeleton without its correction
+        from dynamo_tpu.ops import pallas_lightning
+
+        def build(sh):
+            s, *_ = _shapes(sh)
+            vec = s((rows, 32, 128), BF)
+            return (s((rows, 32, 128, 128), F32), s((rows, 32, 128), F32), vec,
+                    vec, s((32,), F32), s((rows,), jnp.bool_))
+        return pallas_lightning.lightning_state_update, build
+
+    def infllm(question, q_tokens, rows):
+        # block-sparse attention over pooled keys at MiniCPM-SALA's widths
+        # and the long-document cell's sizes (PR 54): 32 q / 2 kv heads, 32
+        # rows over tables of 1 160 pages in a pool of 37 136 + 2 321 pages;
+        # decode rows are the launch ``infllm_decode_attention`` over a view
+        # a (row, kv head), a chunk the dense launch or the mask
+        spec = att.InfLlmQuery(32, 16, 64, 64, 1, 2048, 8192)
+
+        def fn(attn, *args):
+            attn = PagedAttention(attn.mesh, True, summary_base=37136)
+            return getattr(attn, question)(*args, infllm=spec)
+        fn.asks_seam = True
+
+        def build(sh):
+            s, *_ = _shapes(sh)
+            page = s((37136 + 2321, BS, 2, D), BF)
+            q = s((q_tokens, 32, D), BF)
+            r = s((rows,), I32)
+            if question == "decode":
+                return (q, page, page, s((rows, 1160), I32), r)
+            if question == "chunk":
+                return (q, page, page, s((1160,), I32), s((), I32),
+                        s((), I32), s((q_tokens,), I32))
+            return (q, page, page, s((rows, 1160), I32), r, r, r)
+        return fn, build
+
     def eva(question, q_tokens, rows):
         # EVA's attention at EvaByte's widths and the long-answer cell's
         # sizes (PR 46): 32 heads x 128, multi-head, a ring of 128 pages and
@@ -407,6 +446,10 @@ def _cases():
         "unified-64q-8kv-reason-cell": unified_cell(
             64, 8, 512 + 128, 129, 130, 12288),
         "kda-state-update-rows128": kda_update(128),
+        "lightning-state-update-rows32": lightning_update(32),
+        "infllm-decode-32-rows": infllm("decode", 32, 32),
+        "infllm-chunk-S512": infllm("chunk", 512, 1),
+        "infllm-mixed-S512-32-rows": infllm("ragged", 544, 33),
         "eva-decode-24-rows": eva("decode", 24, 24),
         "eva-chunk-S512": eva("chunk", 512, 1),
         "eva-mixed-S512-24-rows": eva("ragged", 536, 25),
