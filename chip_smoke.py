@@ -766,8 +766,21 @@ def _kernel_child() -> None:
     # the highest-precision twin, and its nanoseconds a (query, key) pair.
     # Each TWICE: every table a run of consecutive pages (a whole chunk is one
     # descriptor an array), then the same pages at shuffled places of a second
-    # pool (two descriptors a page): bitwise the same answer
+    # pool (two descriptors a page): bitwise the same answer. Beside each time
+    # (20 dispatches back to back, the best of 3) the GB/s of what the launch
+    # copies (1 536 B a token of every page a program walks: the latent's four
+    # rows and the second array's first tile, PR 55) and of what it needs
+    # (1 152 B a key of each row's context, once a row: benchmarks/costs_mla.py)
     from dynamo_tpu.ops import pallas_latent as plat
+
+    def back_to_back(fn, *args):
+        best = float("inf")
+        for _ in range(3):
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            jax.block_until_ready([fn(*args) for _ in range(20)])
+            best = min(best, (time.perf_counter() - t0) / 20)
+        return best
 
     DNB, DMB, dctx = 14401, 1600, 25000
     dlat, daux = rnd(DNB, BS, 4, 128), rnd(DNB, BS, 4, 128)
@@ -791,6 +804,14 @@ def _kernel_child() -> None:
         pairs = sum(lens[first:]) + q_len0 * (lens[0] if first else 0) - (
             q_len0 * (q_len0 - 1) // 2
         )
+        # a program walks its row's pages up to its last query's position: a
+        # decode row its context, a tile of the chunk the context below it
+        walked = [-(-n // BS) for n in lens[first:]] + [
+            -(-(lens[0] - q_len0 + min(t0 + plat.Q_TILE, q_len0)) // BS)
+            for t0 in range(0, q_len0, plat.Q_TILE)
+        ]
+        copied_gb = sum(walked) * BS * 1536 / 1e9
+        needed_gb = sum(lens) * 1152 / 1e9
         lens = jnp.asarray(lens, jnp.int32)
         qd = rnd(n_chunk + n_one, 64, 640)
         outs = []
@@ -819,16 +840,13 @@ def _kernel_child() -> None:
             empty = n_chunk + np.flatnonzero(np.asarray(lens[first:]) == 0)
             if np.asarray(got, np.float32)[empty].any():
                 raise SystemExit(f"{name}, {kind}: an empty row is not zeros")
-            times = []
-            for _ in range(6):
-                t0 = time.perf_counter()
-                jax.block_until_ready(run())
-                times.append(time.perf_counter() - t0)
-            took = sorted(times[1:])[2]
+            took = back_to_back(run)
             print(f"KERNEL {name}, {kind} ({int(as_runs)} of {int(whole)} "
                   f"whole chunks one descriptor an array): {took * 1e3:.3f} "
-                  f"ms, {took * 1e9 / pairs:.2f} ns a (query, key) pair over "
-                  f"{pairs} pairs", flush=True)
+                  f"ms a launch, {took * 1e9 / pairs:.2f} ns a (query, key) "
+                  f"pair over {pairs} pairs; {copied_gb / took:.0f} GB/s "
+                  f"copied (1 536 B a token), {needed_gb / took:.0f} GB/s "
+                  f"needed (1 152 B a key)", flush=True)
             outs.append(got)
         if not bool(jnp.all(outs[0] == outs[1])):
             raise SystemExit(f"{name}: runs and shuffled pages give "
@@ -855,18 +873,9 @@ def _kernel_child() -> None:
     # tables that are runs and over the same pages at shuffled places, 8
     # tables (decode rows) and 1 (a lone chunk), and tables of 1 590 pages
     # (24 whole chunks and a tail of 54); ms a launch and GB/s of the 768 B a
-    # key it reads and writes, beside the twin's (20 dispatches back to back,
-    # the best of 3: the twin re-tiles the whole second array in each)
+    # key it reads and writes, beside the twin's (``back_to_back``: the twin
+    # re-tiles the whole second array in each)
     twin_keys = jax.jit(att.paged_index_keys, static_argnums=2)
-
-    def back_to_back(fn, *args):
-        best = float("inf")
-        for _ in range(3):
-            jax.block_until_ready(fn(*args))
-            t0 = time.perf_counter()
-            jax.block_until_ready([fn(*args) for _ in range(20)])
-            best = min(best, (time.perf_counter() - t0) / 20)
-        return best
 
     for kind, _, aux_pool, pool_tables in layouts:
         for n_tables, width in ((8, DMB), (1, DMB), (2, 1590)):

@@ -469,6 +469,16 @@ def paged_extend_attention(
 # V array holds no values: it is the layer's second kind of per-token state,
 # under the same block ids as the first.
 #
+# What a kernel copies of the V array is a token's FIRST ``(2, 128)`` tile
+# alone, rows 0 and 1 (512 of the token's ``rows * 256`` bytes, a strided copy
+# out of the view ``[tokens, rows / 2, 2, 128]``); a pair of rows shares a
+# 32-bit word, row 0 its low half. ``sparse_latent_attention`` copies a
+# selected token's tile and keeps the low halves (``k_pe``),
+# ``paged_index_keys`` a page's or a run of pages' and keeps the high halves
+# (the index key), ``paged_latent_attention`` a page's or a run's and keeps
+# the low halves (since PR 55; the whole token until then). Rows 2 and up are
+# written by nothing and read by nothing.
+#
 # A latent WITHOUT an indexer may keep the same two arrays (row 1 of the
 # second unwritten) and hands the seam a ``LatentQuery``: nothing selects,
 # every causal key is attended (``paged_latent_attention`` below).

@@ -10,15 +10,19 @@ this one streams a row's pages, whole.
 
 Layout (ops/attention.py): both paged arrays are ``[num_blocks, block_size,
 rows, 128]`` in bf16, the latent in the first, ``[k_pe | 0]`` in row 0 of the
-second. The launch sees both as ``[tokens, rows, 128]``: a page of either is
-one copy of ``block_size`` whole tokens (of the second only row 0 is used; the
-rows behind it ride along: 2 048 bytes a key at 512 + 64 lanes, of which 1 152
-are the key). In VMEM a pair of rows shares a 32-bit word, and so does a pair
-of TOKENS of a bf16 matrix: a chunk's buffers become ONE ``[chunk tokens, rank
-+ 128]`` matrix ``[c | k_pe | 0]`` by integer moves alone (``_chunk_matrix``:
-the word-rows of even and of odd tokens, each a sublane-strided load of whole
-registers, a shift, a mask and an or): the scores are one product against it
-and the values one product against its first ``rank`` lanes.
+second. The launch sees the latent as ``[tokens, rows, 128]``, whole tokens,
+and the second array as ``[tokens, rows / 2, 2, 128]``, of which it copies a
+token's FIRST tile alone (rows 0 and 1: ``k_pe`` and, where a layer has one,
+an index key; the rows behind them are written and read by nothing), as
+``paged_index_keys`` does: 1 024 + 512 = 1 536 bytes a key at 512 + 64 lanes,
+of which 1 152 are the key (2 048, the whole token of both, until PR 55). In
+VMEM a pair of rows shares a 32-bit word, and so does a pair of TOKENS of a
+bf16 matrix: a chunk's buffers become ONE ``[chunk tokens, rank + 128]``
+matrix ``[c | k_pe | 0]`` by integer moves alone (``_chunk_matrix``: the
+word-rows of even and of odd tokens, each a sublane-strided load of whole
+registers, a shift, a mask and an or; of the tile's words the low halves are
+kept, so whatever row 1 holds stays out): the scores are one product against
+it and the values one product against its first ``rank`` lanes.
 
 Rows of a launch, known when it is traced: optionally ONE chunk row (the
 first ``n_chunk`` packed queries, at the tail of ``tables[0]``'s context),
@@ -29,9 +33,10 @@ shapes of it, each ONE launch, all named ``paged_latent_attention``.
 Grid: one program a tile of ``Q_TILE`` chunk queries (``Q_TILE * h`` rows
 through the matrix unit at once: a visit of a 1 024-key chunk is its products,
 13.6 us = 91% of the matrix unit's peak, the unpack and the copies under them),
-then one a decode row (``h`` rows: a visit is its copies, 2.7 us for 2 MiB =
-94% of the byte peak, the products and the unpack under them; PERF.md section
-6, PR 47 has the table. Until PR 47 the unpack ran one token a vector
+then one a decode row (``h`` rows: a visit is its copies, 1.5 MiB since PR 55
+(2.7 us for 2 MiB = 94% of the byte peak until then), the products and the
+unpack under them; PERF.md section 6, PR 47 has the table, PR 55 the launch
+since. Until PR 47 the unpack ran one token a vector
 register and was 3.0 us of a decode visit's 5.9, 2.0 of a tile visit's 15.6:
 what the records called "the matrix unit streaming 64 rows a load of its
 weights" was that). A program walks its row's pages up to
@@ -52,18 +57,25 @@ A WHOLE chunk whose ``chunk_pages`` table entries are consecutive block ids (a
 prompt admitted in one go into a pool that hands out low ids first;
 ``pallas_paged.chunk_runs``, one compare of the tables in the launch's XLA
 wrapper, handed in as a fourth scalar-prefetch operand) is ONE descriptor an
-array: 1 MiB, contiguous in the token view. Every other whole chunk is
-started page by page, two descriptors a page, ``pallas_paged.UNROLL`` pages a
-pass; either way it is waited for ONCE an array (a
-DMA semaphore counts bytes). A tail chunk starts and waits page by page. On a
-v5e, 64 heads, 25 000-key contexts, launches chained inside one jit (PERF.md
-section 6, PR 34, with PR 33's unpack): 8 decode rows 1.199 ms as runs, 1.516
+array: the latent's 1 MiB, contiguous in the token view, and the second
+array's 512 KiB, 512 bytes a token at a stride of a token (``rows * 256``
+bytes). Every other whole chunk is started page by page, two descriptors a
+page (the second strided the same way), ``pallas_paged.UNROLL`` pages a
+pass; either way it is waited for ONCE an array, on a descriptor of the slot
+buffer's size (a DMA semaphore counts bytes). A tail chunk starts and waits
+page by page. On a v5e, 64 heads, 25 000-key contexts, launches chained
+inside one jit (PERF.md section 6, PR 34, with PR 33's unpack): 8 decode rows
+1.199 ms as runs, 1.516
 page by page; a 512-query chunk 12.57 / 13.84; a 320-query chunk + 8 rows 9.13
 / 10.24: a page's two descriptors cost a launch about 26 ns, a run's two about
 nothing. As runs since PR 47 (section 6, PR 47: a while loop of launches in
 one jit, which read the parent 0.891 / 12.40 / 8.20): 0.430 / 10.78 / 6.81,
-with the copies alone 0.408 / 2.31 / 1.73. The output is bitwise the same
-whichever way a chunk came in.
+with the copies alone 0.408 / 2.31 / 1.73. Since PR 55 (section 6, PR 55: the
+same loop, parent 0.426 / 10.80 / 6.81): 0.329 / 10.78 / 6.72 as runs, 700
+GB/s of what a decode launch copies; page by page 0.452 / 12.00 / 7.56, the
+parent's to 0.002 ms: there a decode launch is its descriptors' issue, not its
+bytes. The output is bitwise the same whichever way a chunk came in, and
+bitwise what it was when the whole token was copied.
 """
 
 from __future__ import annotations
@@ -122,33 +134,39 @@ def _chunk_matrix(k_words, v_words, kcat, slot, T: int, lat_rows: int):
     """The chunk in ``slot`` as ``[c | k_pe | 0]``, written into ``kcat``
     (``[T / 2, rank + 128]`` uint32: the words of a ``[T, rank + 128]`` bf16
     matrix). ``k_words`` / ``v_words``: the page buffers as ``[2 T rows / 2,
-    128]`` uint32, a token's word-rows one after the other, so a word-row of
+    128]`` / ``[2 T, 128]`` uint32, a token's word-rows one after the other
+    (the latent's ``rows / 2``, the second array's one tile), so a word-row of
     every second token is ONE sublane-strided load a vector register, eight
     tokens in it. Keep the read 2-D and strided: a slice ``[slot, :, w, :]``
     of the 4-D buffer comes back one token a register with a rotate and a
     select a token, 45 000 vector instructions a visit where these are 3 600
     (PERF.md section 6, PR 47; tests/test_tpu_compile.py counts them)."""
-    nw = lat_rows // 2                       # word-rows a token
+    nw = lat_rows // 2                       # the latent's word-rows a token
     rank = lat_rows * LATENT_LANES
-    base = slot * (T * nw)
 
-    def pairs(words, w):
+    def pairs(words, w, nw):
+        """Word-row ``w`` of the ``nw`` a token of ``words`` holds."""
+        base = slot * (T * nw)
         return _pack_pairs(
             words[pl.ds(base + w, T // 2, stride=2 * nw), :],
             words[pl.ds(base + nw + w, T // 2, stride=2 * nw), :],
         )
 
     for w in range(nw):
-        for half, x in enumerate(pairs(k_words, w)):
+        for half, x in enumerate(pairs(k_words, w, nw)):
             lane0 = (2 * w + half) * LATENT_LANES
             kcat[:, lane0:lane0 + LATENT_LANES] = x
-    kcat[:, rank:] = pairs(v_words, 0)[0]
+    # the tile's low halves are k_pe; its high halves (row 1: an index key,
+    # or whatever the pool holds there) stay out of the matrix
+    kcat[:, rank:] = pairs(v_words, 0, 1)[0]
 
 
 class _LatentPages(paged.PageReader):
-    """A chunk's copies out of the token-row views, both arrays alike: a page
-    is ``bs`` whole tokens, a run of pages one stretch of tokens. Which
-    chunk goes which way, and the one wait an array, are the base class's."""
+    """A chunk's copies out of the token views: a page is ``bs`` tokens, a run
+    of pages one stretch of tokens; of the latent whole tokens, of the second
+    array each token's first tile (a strided descriptor, ONE a page or a run
+    all the same). Which chunk goes which way, and the one wait an array, are
+    the base class's."""
 
     def __init__(self, tables_ref, runs_ref, k_hbm, v_hbm, k_buf, v_buf, sem,
                  bs, cp):
@@ -156,21 +174,23 @@ class _LatentPages(paged.PageReader):
                          runs_ref=runs_ref, chunk_pages=cp)
         self.bs = bs
 
-    def copies(self, slot, idx, j):
-        src = pl.ds(idx * self.bs, self.bs)
-        dst = pl.ds(j * self.bs, self.bs)
+    def _tokens(self, slot, src, *dst):
+        """Descriptors of the tokens ``src`` into ``slot`` (at ``dst``)."""
+        (k_hbm, k_buf, k_sem), (v_hbm, v_buf, v_sem) = self.pairs
         return [
-            pltpu.make_async_copy(hbm.at[src], buf.at[slot, dst], sem.at[slot])
-            for hbm, buf, sem in self.pairs
+            pltpu.make_async_copy(
+                k_hbm.at[src], k_buf.at[slot, *dst], k_sem.at[slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[src, 0], v_buf.at[slot, *dst], v_sem.at[slot]),
         ]
 
+    def copies(self, slot, idx, j):
+        return self._tokens(
+            slot, pl.ds(idx * self.bs, self.bs), pl.ds(j * self.bs, self.bs))
+
     def run_copies(self, slot, idx):
-        src = pl.ds(
-            pl.multiple_of(idx * self.bs, self.bs), self.cp * self.bs)
-        return [
-            pltpu.make_async_copy(hbm.at[src], buf.at[slot], sem.at[slot])
-            for hbm, buf, sem in self.pairs
-        ]
+        return self._tokens(slot, pl.ds(
+            pl.multiple_of(idx * self.bs, self.bs), self.cp * self.bs))
 
 
 def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
@@ -182,11 +202,12 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
     qc_ref = next(it) if n_ct else None    # VMEM [qt, h, rank + 128]
     q1_ref = next(it) if n_one else None   # VMEM [1, h, rank + 128]
     k_hbm = next(it)        # ANY/HBM [nb * bs, rows, 128] the latent
-    v_hbm = next(it)        # ANY/HBM [nb * bs, rows, 128]; [t, 0] = k_pe
+    v_hbm = next(it)        # ANY/HBM [nb * bs, rows / 2, 2, 128]; [t, 0] =
+    #                         the tile [k_pe | index key], rows 0 and 1
     oc_ref = next(it) if n_ct else None    # VMEM [qt, h, rank]
     o1_ref = next(it) if n_one else None   # VMEM [1, h, rank]
     k_buf = next(it)        # VMEM [2, T, rows, 128] bf16
-    v_buf = next(it)        # VMEM [2, T, rows, 128] bf16
+    v_buf = next(it)        # VMEM [2, T, 2, 128] bf16: that tile a token
     kcat = next(it)         # VMEM [T / 2, rank + 128] uint32: the words of
     #                         the bf16 matrix [T, rank + 128] = [c | k_pe | 0]
     m_scr = next(it)        # VMEM [M, 1] f32
@@ -200,10 +221,11 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
     i = pl.program_id(0)
     pages = _LatentPages(
         tables_ref, runs_ref, k_hbm, v_hbm, k_buf, v_buf, sem, bs, cp)
-    # both slots as word-rows, a token's rows / 2 one after the other
-    n_words = 2 * T * (lat_rows // 2)
-    k_words = k_buf.bitcast(jnp.uint32).reshape(n_words, LATENT_LANES)
-    v_words = v_buf.bitcast(jnp.uint32).reshape(n_words, LATENT_LANES)
+    # both slots as word-rows: a token's rows / 2 one after the other, and
+    # its one tile of the second array
+    k_words = k_buf.bitcast(jnp.uint32).reshape(
+        2 * T * (lat_rows // 2), LATENT_LANES)
+    v_words = v_buf.bitcast(jnp.uint32).reshape(2 * T, LATENT_LANES)
     kmat = kcat.bitcast(k_buf.dtype)        # [T, rank + 128] bf16
     n_runs = mb // cp                       # runs_ref entries a row
 
@@ -377,7 +399,7 @@ def paged_latent_attention(
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, T, n_rows, lanes), k_cache.dtype),
-                pltpu.VMEM((2, T, n_rows, lanes), v_cache.dtype),
+                pltpu.VMEM((2, T, 2, lanes), v_cache.dtype),
                 pltpu.VMEM((T // 2, width), jnp.uint32),
                 pltpu.VMEM((M, 1), jnp.float32),
                 pltpu.VMEM((M, 1), jnp.float32),
@@ -399,7 +421,7 @@ def paged_latent_attention(
         paged.chunk_runs(tables, cp).reshape(-1).astype(jnp.int32),
         *operands,
         k_cache.reshape(nb * bs, n_rows, lanes),
-        v_cache.reshape(nb * bs, n_rows, lanes),
+        v_cache.reshape(nb * bs, n_rows // 2, 2, lanes),
     )
     parts = []
     if n_ct:

@@ -469,7 +469,8 @@ def _parents_chunk_matrix(k_words, v_words, kcat, slot, T, lat_rows):
     from dynamo_tpu.ops.pallas_sparse import _halves
 
     nw = lat_rows // 2
-    k4, v4 = (w.reshape(2, T, nw, 128) for w in (k_words, v_words))
+    # of the second array a slot holds one tile a token since PR 55: one word-row
+    k4, v4 = k_words.reshape(2, T, nw, 128), v_words.reshape(2, T, 1, 128)
     for w in range(nw):
         for half, x in enumerate(_halves(k4[slot, :, w, :])):
             lane0 = (2 * w + half) * 128
@@ -602,6 +603,14 @@ UNPACK_SHAPES = {
 RUN_SHAPES.update(UNPACK_SHAPES)
 
 
+def _row_starts(n_chunk, n_rows):
+    """Where each row's queries start in the packed buffer the twin takes: the
+    chunk's at 0, then one query a row."""
+    if not n_chunk:
+        return jnp.arange(n_rows)
+    return jnp.concatenate([jnp.zeros((1,), jnp.int32), n_chunk + jnp.arange(n_rows - 1)])
+
+
 def _numpy_runs(tables, cp):
     """chunk_runs the slow way: a chunk's ids are first, first + 1, ..."""
     R, mb = tables.shape
@@ -657,9 +666,8 @@ def test_a_run_of_pages_is_read_as_one_copy_and_changes_no_bit(monkeypatch, case
     assert bool(jnp.all(got == _launch_with_the_parents_unpack(
         monkeypatch, q, kc, vc, jnp.asarray(tables, jnp.int32), q_lens, seq, scale=0.125,
         n_chunk=n_chunk, interpret=True)))
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), n_chunk + jnp.arange(len(lens) - 1)]) \
-        if n_chunk else jnp.arange(len(lens))
-    twin = att.paged_latent_attention(q, kc, vc, jnp.asarray(tables), starts, q_lens, seq, 0.125)
+    twin = att.paged_latent_attention(
+        q, kc, vc, jnp.asarray(tables), _row_starts(n_chunk, len(lens)), q_lens, seq, 0.125)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(twin, np.float32), atol=2e-2, rtol=2e-2)
     # what the kernel was told and what the step counts: every neighbour compared
     runs = _numpy_runs(tables, cp)
@@ -677,6 +685,64 @@ def test_a_run_of_pages_is_read_as_one_copy_and_changes_no_bit(monkeypatch, case
         assert n_whole and n_run / n_whole == share[case]
     if case == "only_chunk_a_tail":
         assert counted == (0, 0)
+
+
+def _spoiled(vc, fill, rng):
+    """The second array with rows 1 and up of every token, of every page,
+    filled: NaN, or random bits (NaNs, infinities and huge values among them)."""
+    nb, bs, rows, lanes = vc.shape
+    if fill == "nan":
+        junk = jnp.full((nb, bs, rows - 1, lanes), jnp.nan, vc.dtype)
+    else:
+        junk = jax.lax.bitcast_convert_type(jnp.asarray(
+            rng.integers(0, 2 ** 16, (nb, bs, rows - 1, lanes)), jnp.uint16), vc.dtype)
+    return vc.at[:, :, 1:].set(junk)
+
+
+@pytest.mark.parametrize("fill", ["nan", "random_bits"])
+@pytest.mark.parametrize("pages", ["runs", "shuffled"])
+@pytest.mark.parametrize("case", [
+    "decode_rows", "lone_chunk", "mixed_launch", "only_chunk_a_tail", "an_empty_row"])
+def test_only_row_0_of_the_second_array_reaches_a_score(monkeypatch, case, pages, fill):
+    """PR 55: the launch copies of the second array a token's first tile alone
+    (rows 0 and 1) and keeps the low halves of its words (row 0, ``k_pe``).
+    With rows 1-3 of every token NaN, or random bits, the output is BITWISE
+    what it is over zeros there, and the twin's within the file's tolerance:
+    neither the rows it no longer copies nor the index-key half of the tile it
+    does copy reach a score. Decode rows (one with a tail chunk), a lone
+    chunk, the mixed launch, a row whose only chunk is a tail, an empty row;
+    tables that are runs and the same pages at shuffled places."""
+    cp, ROWS = 2, 4
+    monkeypatch.setattr(paged, "chunk_pages", lambda *a: cp)
+    T, RANK = cp * BS, ROWS * 128
+    n_chunk, q_lens, lens = RUN_SHAPES[case]
+    lens = lens(T)
+    tables, nb = _run_tables(cp)
+    tables = tables[:len(lens)]
+    rng = np.random.default_rng(len(case))
+    kc, vc = (jnp.asarray(rng.normal(size=(nb, BS, ROWS, 128)), jnp.bfloat16) for _ in range(2))
+    if pages == "shuffled":
+        place = np.concatenate([[0], 1 + rng.permutation(nb - 1)])
+        back = np.argsort(place)
+        kc, vc, tables = kc[back], vc[back], place[tables]
+    tables = jnp.asarray(tables, jnp.int32)
+    assert bool(paged.chunk_runs(tables, cp).all()) == (pages == "runs")
+    q = jnp.asarray(rng.normal(size=(n_chunk + len(lens) - bool(n_chunk), H, RANK + 128)), jnp.bfloat16)
+    q_lens, seq = jnp.asarray(q_lens, jnp.int32), jnp.asarray(lens, jnp.int32)
+
+    def launch(vc):
+        return plat.paged_latent_attention(
+            q, kc, vc, tables, q_lens, seq, scale=0.125, n_chunk=n_chunk, interpret=True)
+
+    spoiled = _spoiled(vc, fill, rng)
+    assert not bool(jnp.isfinite(spoiled[:, :, 1:].astype(jnp.float32)).all())
+    got = launch(spoiled)
+    assert bool(jnp.all(got == launch(vc.at[:, :, 1:].set(0))))
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all() and got.any()
+    twin = att.paged_latent_attention(
+        q, kc, spoiled, tables, _row_starts(n_chunk, len(lens)), q_lens, seq, 0.125)
+    np.testing.assert_allclose(got, np.asarray(twin, np.float32), atol=2e-2, rtol=2e-2)
 
 
 def test_the_launch_refuses_what_it_cannot_read():

@@ -533,7 +533,9 @@ def test_a_latent_chunk_visit_unpacks_whole_registers(v5e, chip_seam, mosaic_dum
     document-QA cell's shapes, by Mosaic's own dump: a chunk visit's body (the
     two chunk loops, masked and not) reads its word-rows with sublane-strided
     loads and holds no sublane rotate and no select but the causal mask's
-    (136 in the masked body). Read as ``k_words[slot, :, w, :]`` the unpack
+    (136 in the masked body), the second array's at one word-row a token
+    (PR 55: its slot holds a token's first tile alone). Read as
+    ``k_words[slot, :, w, :]`` the unpack
     came back one token a vector register: 4 480 rotates, 4 480 selects and
     45 000 vector instructions a visit, half of a decode row's time on the
     chip (PERF.md section 6, PR 47)."""
@@ -543,7 +545,8 @@ def test_a_latent_chunk_visit_unpacks_whole_registers(v5e, chip_seam, mosaic_dum
     last = sorted(mosaic_dump.glob("*paged_latent_attention*finalize-llo*"))
     if not last:
         pytest.skip("this libtpu wrote no Mosaic dump (--xla_mosaic_dump_to)")
-    visits = _loop_bodies(last[-1].read_text())
+    llo = last[-1].read_text()
+    visits = _loop_bodies(llo)
     assert len(visits) == 2, [sum(v.values()) for v in visits]
     for ops in visits:
         vector = sum(n for op, n in ops.items()
@@ -551,6 +554,12 @@ def test_a_latent_chunk_visit_unpacks_whole_registers(v5e, chip_seam, mosaic_dum
         assert ops["vmatmul"] and ops["vector_load_slane_stride"] == 384, ops
         assert ops["vrot.slane"] + ops["vselect"] <= 256, ops
         assert vector <= 8000, (vector, ops)
+    # a visit's 384: 256 over the latent's two word-rows a token (every fourth
+    # word-row: an even or an odd token's) and, since PR 55, 128 over the
+    # second array's ONE (every second); until then all 384 took every fourth
+    strides = collections.Counter(re.findall(
+        r"vector_load_slane_stride .*sublane_stride = (\d+)", llo))
+    assert strides == {"4": 2 * 256, "2": 2 * 128}, strides
 
 
 @pytest.mark.parametrize("case", ["sparse-latent-decode", "sparse-latent-mixed"])
@@ -639,6 +648,23 @@ def _dma_sites(llo: str):
     return [(op, held[0] if held else None, n) for (op, held), n in sites]
 
 
+def _page_reader_sites():
+    """What ``_dma_sites`` finds in a kernel that reads its chunks by
+    ``pallas_paged.PageReader`` with runs: each of its three places that start
+    a chunk holds a branch of TWO descriptors (a whole chunk that is a run:
+    one an array), a loop of ``UNROLL`` pages' (a whole chunk that is none)
+    and a loop of one page's (a tail); each of its two places that wait holds
+    two waits for a whole chunk, however it was started, and a loop of one
+    page's."""
+    from dynamo_tpu.ops import pallas_paged as paged
+
+    start = [("enqueue_dma", "scf.if", 2),
+             ("enqueue_dma", "scf.for", 2 * paged.UNROLL),
+             ("enqueue_dma", "scf.for", 2)]
+    wait = [("dma_done", "scf.if", 2), ("dma_done", "scf.for", 2)]
+    return start + start + wait + start + wait
+
+
 @pytest.mark.parametrize("case", [
     "decode-bf16-128q-8kv-contract-cell-full", "decode-bf16-32q-4kv-moe-cell-full"])
 def test_a_run_chunk_of_the_decode_kernel_starts_one_dma_an_array(
@@ -652,26 +678,56 @@ def test_a_run_chunk_of_the_decode_kernel_starts_one_dma_an_array(
     whole chunk, however it was started, and a loop of one page's. Until
     PR 50 a chunk of 32 or 64 pages was 64 or 128 starts and as many waits,
     one after the other in the products' instruction stream."""
-    from dynamo_tpu.ops import pallas_paged as paged
-
     fn, build = CASES[case]
     jax.jit(functools.partial(fn, chip_seam)).lower(
         *build(SingleDeviceSharding(v5e[0]))).compile()
     last = sorted(mosaic_dump.glob("*paged_decode_attention*finalize-llo*"))
     if not last:
         pytest.skip("this libtpu wrote no Mosaic dump (--xla_mosaic_dump_to)")
-    start = [("enqueue_dma", "scf.if", 2),
-             ("enqueue_dma", "scf.for", 2 * paged.UNROLL),
-             ("enqueue_dma", "scf.for", 2)]
-    wait = [("dma_done", "scf.if", 2), ("dma_done", "scf.for", 2)]
-    assert _dma_sites(last[-1].read_text()) == start + start + wait + start + wait
+    assert _dma_sites(last[-1].read_text()) == _page_reader_sites()
+
+
+def test_a_run_chunk_of_the_latent_kernel_starts_one_dma_an_array_the_second_strided(
+        v5e, chip_seam, mosaic_dump):
+    """ISSUE 55's tripwire, no chip needed. By Mosaic's own dump of the
+    latent decode launch at the document-QA cell's shapes (a chunk of 64
+    pages, 1 024 tokens): its places that start and wait for a chunk are
+    ``PageReader``'s, as the decode kernel's (a whole chunk that is a run:
+    ONE descriptor an array); every descriptor of the second array
+    reads the FIRST tile of its tokens, ``[tokens, 1 of rows / 2, 2, 128]``,
+    a run's over a source of ``cp * bs`` tokens, into a slot buffer of
+    ``[2, T, 2, 128]``: 512 of a token's 1 024 bytes. Until PR 55 it copied
+    the whole token into ``[2, T, 4, 128]``."""
+    fn, build = CASES["paged-latent-decode"]
+    # a function jit has not seen: a cached executable writes no dump
+    jax.jit(lambda *a: fn(chip_seam, *a)).lower(
+        *build(SingleDeviceSharding(v5e[0]))).compile()
+    last = sorted(mosaic_dump.glob("*paged_latent_attention*finalize-llo*"))
+    first = sorted(mosaic_dump.glob("*paged_latent_attention*original*"))
+    if not last or not first:
+        pytest.skip("this libtpu wrote no Mosaic dump (--xla_mosaic_dump_to)")
+    assert _dma_sites(last[-1].read_text()) == _page_reader_sites()
+    module = first[-1].read_text()
+    T, tokens = 64 * BS, 14336 * BS
+    pool = f"memref<{tokens}x2x2x128xbf16, #tpu.memory_space<any>>"
+    assert f"memref<2x{T}x2x128xbf16, #tpu.memory_space<vmem>>" in module
+    assert f"memref<2x{T}x4x128xbf16, #tpu.memory_space<vmem>>" in module  # the latent's
+    # every slice of the second array: a first tile, of a run's tokens or a page's
+    tiles = set(re.findall(
+        re.escape(pool) + r" -> memref<(\d+)x(\d+)x2x128xbf16", module))
+    assert tiles == {(str(T), "1"), (str(BS), "1")}, tiles
+    sources = re.findall(r"tpu\.enqueue_dma source\(%\w+ : memref<([\dx]+)xbf16", module)
+    assert set(sources) == {
+        f"{T}x4x128", f"{T}x2x128", f"{BS}x4x128", f"{BS}x2x128"}, set(sources)
+    assert sources.count(f"{T}x2x128") == sources.count(f"{T}x4x128") == 3
 
 
 # sha256 of what these launches lowered to at the PARENT of PR 50 (commit
 # 786917a, this installation's JAX, the described v5e): the StableHLO around
 # the custom call and the Mosaic module in it, printed without debug
 # locations (the serialised module carries file names and line numbers). A
-# PR that changes one of these kernels on purpose re-records its hash.
+# PR that changes one of these kernels on purpose re-records its hash (the
+# three ``paged-latent-*`` are what PR 55's launch lowers to).
 PARENT_KERNEL_TEXTS = json.loads(open(os.path.join(
     os.path.dirname(__file__), "data", "kernel_texts_pr49.json")).read())
 
@@ -698,8 +754,10 @@ def test_the_ragged_and_the_latent_launch_lower_to_the_parents_text(
     """PR 50 moved the run rule of ``_LatentPages`` into ``PageReader`` and
     gave it to the decode kernel ALONE: the ragged launch (plain, windowed,
     with sinks and a softcap, at the sparse-expert and the contract cell's
-    shapes) still starts and waits page by page, and the latent launch reads
-    runs as it did. Both lower to the text the parent lowered."""
+    shapes) still starts and waits page by page: it lowers to the text the
+    parent lowered. The three latent launches' hashes are PR 55's (the second
+    array's first tile alone is copied): re-recorded on purpose, every other
+    entry PR 49's."""
     fn, build = CASES[case]
     if getattr(fn, "asks_seam", False):
         fn = functools.partial(fn, chip_seam)
