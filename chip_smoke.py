@@ -867,6 +867,88 @@ def _kernel_child() -> None:
         [dctx, dctx, 24577, 17, 0, dctx + 15, 1, 24591, dctx],
     )
 
+    # the same launch UNDER A WINDOW (models/dots3_note.py's sliding layers)
+    # at dots3-note-prev's widths and the agent cell's shapes: 64 absorbed
+    # heads over 1024 + 64 lanes, the latent 8 rows of 128, the second array
+    # its ONE tile (2 rows), a windowed group's pool of 3 121 pages and its
+    # run of a row's table (162 pages: 513 keys, a 2 048-token chunk and a
+    # page), window 513: 16 decode rows (at the window's edge, past it, at
+    # the run's end, one key, an empty row), a lone 2 048-query chunk and a
+    # mixed step of a 512-query chunk + 16 rows; each against the
+    # highest-precision twin, over runs and over shuffled pages (bitwise the
+    # same), with its ms a launch and its share of the roofline
+    # (benchmarks/costs_dots3.py: a decode row min(len, 513) keys x 2 176 B;
+    # a chunk its visible pairs x 64 heads x (1088 + 1024) x 2 FLOP)
+    WNB, WMB, WIN = 3121, 162, 513
+    wlat, waux = rnd(WNB, BS, 8, 128), rnd(WNB, BS, 2, 128)
+    wrun = 1 + np.arange(17 * WMB).reshape(17, WMB)
+    wplace = np.concatenate([[0], 1 + rng.permutation(WNB - 1)])
+    wback = jnp.asarray(np.argsort(wplace))
+    wlayouts = [
+        ("runs", wlat, waux, jnp.asarray(wrun, jnp.int32)),
+        ("shuffled", wlat[wback], waux[wback],
+         jnp.asarray(wplace[wrun], jnp.int32)),
+    ]
+    wscale = 0.0625
+    peak_flops, peak_bytes = 197e12, 819e9
+
+    def windowed_case(name, n_chunk, q_len0, lens):
+        first = 1 if n_chunk else 0
+        n_one = len(lens) - first
+        q_lens = jnp.asarray(
+            [q_len0] * first + [int(n > 0) for n in lens[first:]], jnp.int32)
+        row_keys = sum(min(n, WIN) for n in lens[first:])
+        pairs = sum(min(lens[0] - q_len0 + i + 1, WIN) for i in range(q_len0)
+                    ) if first else 0
+        least = row_keys * 2176 / peak_bytes + pairs * 64 * 2112 * 2 / peak_flops
+        lens_j = jnp.asarray(lens, jnp.int32)
+        qd = rnd(n_chunk + n_one, 64, 1152)
+        outs = []
+        for kind, lat_pool, aux_pool, tables in wlayouts:
+            tb = tables[: len(lens)]
+            run = lambda: plat.paged_latent_attention(  # noqa: E731
+                qd, lat_pool, aux_pool, tb, q_lens, lens_j, scale=wscale,
+                n_chunk=n_chunk, window=WIN, name=plat.WINDOWED_KERNEL_NAME)
+            got = run()
+            parts = []
+            if n_chunk:
+                parts.append(ref_latent(
+                    qd[:n_chunk], lat_pool, aux_pool, tb[:1],
+                    jnp.zeros((1,), jnp.int32), q_lens[:1], lens_j[:1],
+                    wscale, window=WIN))
+            if n_one:
+                parts.append(ref_latent(
+                    qd[n_chunk:], lat_pool, aux_pool, tb[first:],
+                    jnp.arange(n_one), q_lens[first:], lens_j[first:],
+                    wscale, window=WIN))
+            want = jnp.concatenate(parts, axis=0)
+            # the chunk's real queries and the rows (padding is not compared)
+            real = jnp.asarray([i for i in range(n_chunk + n_one)
+                                if i >= n_chunk or i < q_len0])
+            compare(f"{name}, {kind}", got[real], want[real])
+            took = back_to_back(run)
+            print(f"KERNEL {name}, {kind}: {took * 1e3:.3f} ms a launch, "
+                  f"{100 * least / took:.1f}% of its roofline "
+                  f"({row_keys} row keys, {pairs} chunk pairs)", flush=True)
+            outs.append(got)
+        if not bool(jnp.all(outs[0] == outs[1])):
+            raise SystemExit(f"{name}: runs and shuffled pages give "
+                             "different bits")
+
+    windowed_case(
+        "windowed_latent_attention 16 decode rows, window 513", 0, 0,
+        [600, 513, 514, 512, 528, 2590, 1, 0, 1100, 1537, 2048, 529, 777,
+         1025, 2561, 1024],
+    )
+    windowed_case("windowed_latent_attention a 2048-query chunk behind 513 "
+                  "keys", 2048, 2048, [2561])
+    windowed_case(
+        "windowed_latent_attention mixed: a 512-query chunk (500 real) + 16 "
+        "decode rows", 512, 500,
+        [1030, 600, 513, 514, 512, 528, 2590, 1, 0, 1100, 1537, 2048, 529,
+         777, 1025, 2561, 1024],
+    )
+
     # the selection's read of the index keys (PR 48) at the long-document
     # cell's shapes, given pages (no scoring, no top-k): the launch
     # ``paged_index_keys`` against the twin's slice of row 1, bitwise, over

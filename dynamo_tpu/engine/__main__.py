@@ -20,6 +20,7 @@ from dynamo_tpu.engine.weights import config_from_hf, load_params
 from dynamo_tpu.kv_router import KvEventPublisher, WorkerMetricsPublisher
 from dynamo_tpu.llm import ModelDeploymentCard, ModelRuntimeConfig, register_llm
 from dynamo_tpu.models.cohere2_moe import Cohere2MoeConfig
+from dynamo_tpu.models.dots3_note import Dots3NoteConfig
 from dynamo_tpu.models.evabyte import EvaByteConfig
 from dynamo_tpu.models.llama import LlamaConfig
 from dynamo_tpu.models.gemma import GemmaConfig
@@ -67,6 +68,8 @@ PRESETS = {
     # pages by layer kind: sliding layers hold one window, full layers all
     "tiny-cohere2-moe": Cohere2MoeConfig.tiny,
     "command-a-plus": Cohere2MoeConfig.command_a_plus,
+    "tiny-dots3-note": Dots3NoteConfig.tiny,
+    "dots3-note": Dots3NoteConfig.dots3_note,
     # block-sparse attention over pooled keys in one layer of four, slot
     # state (lightning attention) in the other three (--block-size 16: a
     # pooled key's stride is the page)

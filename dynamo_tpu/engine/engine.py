@@ -268,7 +268,12 @@ class TpuEngineConfig:
         deployment states already (rows, window, buckets): every row's table
         full, and one window more a row (the tail of a cached prefix that the
         row's next request comes back to while the last one's pages are still
-        cached), and the scratch page. ``window_blocks`` overrides it."""
+        cached), and the scratch page. ``window_blocks`` overrides it. What a
+        page costs is the GROUP's: ``block_size`` tokens of its layers' own
+        shape (registry.page_shapes), summed over its layers, not
+        ``num_blocks``' bytes a page: dots3-note's sliding group is 3 layers x
+        (8 + 2) rows x 256 B = 7.5 KiB a token, 120 KiB a 16-token page,
+        where a page of its full group is 2 layers x (4 + 2) rows = 48 KiB."""
         if self.window_blocks is not None:
             return int(self.window_blocks)
         per_row = self.window_table_pages(window) + -(-window // self.block_size)
@@ -1260,6 +1265,11 @@ class TpuEngine:
             for l in g.layers
         }
         sizes = [pool.get(l, pages) for l in registry.page_layers(mcfg)]
+        # a token's shape in each layer's two arrays: ``shape``'s for every
+        # family, but for one that shapes a page group's arrays by its layers
+        # (registry.page_shapes: a latent of another width a layer kind, and
+        # of the second array the one tile a step reads)
+        tokens = registry.page_shapes(mcfg)
         # host-side zeros: device_put shards them per-process (jnp.zeros would
         # commit to the local default device — invalid for a multi-host mesh)
         if quantized:
@@ -1279,9 +1289,13 @@ class TpuEngine:
             k = [qzeros() for _ in range(n_paged)]
             v = [qzeros() for _ in range(n_paged)]
             return k, v
-        zeros = lambda n: np.zeros((n, *shape[1:]), mcfg.dtype)  # noqa: E731
-        k = [jax.device_put(zeros(n), sharding) for n in sizes]
-        v = [jax.device_put(zeros(n), sharding) for n in sizes]
+        zeros = lambda n, token: np.zeros(  # noqa: E731
+            (n, shape[1], *token), mcfg.dtype
+        )
+        k = [jax.device_put(zeros(n, t[0]), sharding)
+             for n, t in zip(sizes, tokens)]
+        v = [jax.device_put(zeros(n, t[1]), sharding)
+             for n, t in zip(sizes, tokens)]
         return k, v
 
     def _resolve_use_pallas(self) -> bool:

@@ -478,6 +478,14 @@ class StepStats:
     full_keys_read: Optional[int] = None
     win_decode_rows: Optional[int] = None
     full_decode_rows: Optional[int] = None
+    # a family whose SLIDING layers hold a latent of their own under a window
+    # (models/dots3_note.py; its full layers count under dsa_* above), on the
+    # same readback: the keys the step's real decode rows read inside their
+    # windows, those rows, and the real tokens of a mixed step's chunk, each
+    # summed over the sliding layers
+    winlat_keys_read: Optional[int] = None
+    winlat_rows: Optional[int] = None
+    winlat_chunk_tokens: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -724,6 +732,13 @@ class EngineTelemetry:
                 "win_keys_read": sum(s.win_keys_read or 0 for s in recent),
                 "full_keys_read": sum(s.full_keys_read or 0 for s in recent),
             }
+            if any(s.winlat_rows is not None for s in recent):
+                # a windowed latent: what the window's decode rows read of it
+                out["page_groups"].update({
+                    name: sum(getattr(s, name) or 0 for s in recent)
+                    for name in ("winlat_keys_read", "winlat_rows",
+                                 "winlat_chunk_tokens")
+                })
         return out
 
     def on_step(self, s: StepStats) -> None:

@@ -38,6 +38,8 @@ def config_from_hf(path: str) -> LlamaConfig:
         return _cohere2_moe_config_from_hf(hf)
     if hf.get("model_type", "") == "minicpm_sala":
         return _minicpm_sala_config_from_hf(hf)
+    if hf.get("model_type", "") == "dots3_note":
+        return _dots3_note_config_from_hf(hf)
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return LlamaConfig(
         vocab_size=hf["vocab_size"],
@@ -54,6 +56,58 @@ def config_from_hf(path: str) -> LlamaConfig:
         or hf.get("model_type", "") == "qwen2",
         qk_norm=hf.get("model_type", "") == "qwen3",
         tie_embeddings=hf.get("tie_word_embeddings", False),
+    )
+
+
+def _dots3_note_config_from_hf(hf: dict):
+    """dots3_note's public config.json -> Dots3NoteConfig (the language
+    model: the towers and the multi-token-prediction head have no key here).
+    A key whose value would change the computation is refused, not ignored
+    (benchmarks/adapters/dots3_note.py is the benchmark's side of this map,
+    with a held share of the experts)."""
+    from ..models.dots3_note import Dots3NoteConfig
+
+    gates = {hf.get("attention_gate_type"), hf.get("swa_attention_gate_type")}
+    if (gates != {"headwise"} or hf.get("rope_scaling") is not None
+            or hf.get("scoring_func") != "sigmoid"
+            or hf.get("topk_method") != "noaux_tc"
+            or hf.get("attention_bias") or hf.get("tie_word_embeddings")
+            or int(hf.get("moe_layer_freq", 1)) != 1):
+        raise ValueError(
+            "dots3_note with a gate that is not headwise on both kinds, "
+            "scaled rotary positions, a router other than sigmoid noaux_tc, "
+            "an attention bias, a tied head or experts in some layers only "
+            "is not built"
+        )
+    L = int(hf["num_hidden_layers"])
+    return Dots3NoteConfig(
+        vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+        num_layers=L, layer_types=tuple(hf["layer_types"][:L]),
+        intermediate_size=hf["intermediate_size"],
+        first_dense_layers=int(hf.get("first_k_dense_replace", 0)),
+        num_heads=hf["num_attention_heads"], q_lora_rank=hf["q_lora_rank"],
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"], v_head_dim=hf["v_head_dim"],
+        rope_theta=float(hf["rope_theta"]), index_topk=hf["index_topk"],
+        index_n_heads=hf["index_n_heads"], index_head_dim=hf["index_head_dim"],
+        swa_num_heads=hf["swa_num_attention_heads"],
+        swa_q_lora_rank=hf["swa_q_lora_rank"],
+        swa_kv_lora_rank=hf["swa_kv_lora_rank"],
+        swa_qk_nope_head_dim=hf["swa_qk_nope_head_dim"],
+        swa_qk_rope_head_dim=hf["swa_qk_rope_head_dim"],
+        swa_v_head_dim=hf["swa_v_head_dim"],
+        swa_rope_theta=float(hf["swa_rope_theta"]),
+        sliding_window=int(hf["sliding_window_size"]),
+        lora_rescale=bool(hf.get("apply_mla_qkv_lora_rescale", False)),
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        num_shared_experts=hf.get("n_shared_experts", 0), experts_held=None,
+        rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        max_position=hf.get("max_position_embeddings", 524288),
     )
 
 
@@ -398,6 +452,19 @@ def load_params(path: str, cfg: Optional[LlamaConfig] = None) -> Dict[str, Any]:
             "model.layers.N.self_attn.{q,k,v,o}_proj, {q,k}_norm, o_gate / "
             "z_proj, o_norm and mlp.*_proj onto its pytree has not been held "
             "to a real checkpoint (none is here; ROADMAP R11)"
+        )
+    from ..models.dots3_note import Dots3NoteConfig
+
+    if isinstance(cfg, Dots3NoteConfig):
+        raise NotImplementedError(
+            "no checkpoint loader for dots3_note yet: the family serves "
+            "random weights (models/dots3_note.init_params); a layer's tensors "
+            "would map as DeepSeek-V3's do (_load_params_mla, at the layer "
+            "kind's sizes: q_a_proj / q_b_proj / kv_a_proj_with_mqa / "
+            "kv_b_proj / o_proj, the indexer's wq_b / wk / k_norm / "
+            "weights_proj on full layers) plus the headwise gate onto w_g, "
+            "but the gate's and the sliding layers' tensor names have not "
+            "been held to a real checkpoint (none is here; ROADMAP R11)"
         )
     from ..models.cohere2_moe import Cohere2MoeConfig
 

@@ -143,6 +143,27 @@ class MlaConfig(LlamaConfig):
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # what a family of LAYER KINDS states of ONE kind's attention
+    # (models/dots3_note.py builds an MlaConfig a kind and calls this
+    # module's layer with it); each at its default leaves this family's
+    # programs as they were:
+    # - the two normalised latents multiplied by a constant (the LongCat-
+    #   Flash lineage's sqrt(hidden / rank)); the cache holds ``c`` after it
+    q_latent_scale: float = 1.0
+    kv_latent_scale: float = 1.0
+    # - a headwise output gate, sigmoid(h W_g) a head a token, before W_o
+    attention_gate: bool = False
+    # - a rows-layout latent without an indexer that attends its last
+    #   ``sliding_window`` keys alone (its own among them)
+    sliding_window: Optional[int] = None
+    # - rows a token of the SECOND paged array (0 = as many as the latent's:
+    #   the engine allocates a layer's two arrays alike); 2 = the one tile a
+    #   step reads, where the engine shapes a page group's arrays by layer
+    #   kind (registry.page_shapes)
+    aux_rows: int = 0
+    # - the prefix of the attention's scopes in a device trace ("" = the
+    #   seam's own, ``dsa_`` / ``latent_``)
+    trace_scope: str = ""
 
     def __post_init__(self):
         # the engine reads num_kv_heads/head_dim as the KV-cache layout;
@@ -380,14 +401,23 @@ def init_layer_params(rng: jax.Array, cfg: MlaConfig, layer_idx: int) -> Params:
         "w_dkv": (jax.random.normal(k[0], (h, rank + rope)) * scale).astype(cfg.dtype),
         "kv_norm": jnp.ones((rank,), cfg.dtype),
         # per-head up-projections, head-stacked so TP shards the head dim
+        # a matrix that reads a latent is drawn for the latent's energy: a
+        # rescaled latent (``kv_latent_scale`` s, RMS s) at 1 / (s sqrt(rank))
+        # = 1 / sqrt(hidden), the lineage's own convention for the rescale
         "w_uk": (
-            jax.random.normal(k[1], (nh, nope, rank)) / math.sqrt(rank)
+            jax.random.normal(k[1], (nh, nope, rank))
+            / (math.sqrt(rank) * cfg.kv_latent_scale)
         ).astype(cfg.dtype),
         "w_uv": (
-            jax.random.normal(k[2], (nh, rank, vd)) / math.sqrt(rank)
+            jax.random.normal(k[2], (nh, rank, vd))
+            / (math.sqrt(rank) * cfg.kv_latent_scale)
         ).astype(cfg.dtype),
         "wo": (jax.random.normal(k[3], (nh * vd, h)) * scale).astype(cfg.dtype),
     }
+    if cfg.attention_gate:
+        p["w_g"] = (
+            jax.random.normal(jax.random.fold_in(rng, 2), (h, nh)) * scale
+        ).astype(cfg.dtype)
     if cfg.q_lora_rank > 0:
         p["w_dq"] = (
             jax.random.normal(k[4], (h, cfg.q_lora_rank)) * scale
@@ -395,7 +425,7 @@ def init_layer_params(rng: jax.Array, cfg: MlaConfig, layer_idx: int) -> Params:
         p["q_norm"] = jnp.ones((cfg.q_lora_rank,), cfg.dtype)
         p["w_uq"] = (
             jax.random.normal(k[5], (cfg.q_lora_rank, nh * (nope + rope)))
-            / math.sqrt(cfg.q_lora_rank)
+            / (math.sqrt(cfg.q_lora_rank) * cfg.q_latent_scale)
         ).astype(cfg.dtype)
     else:
         p["wq"] = (
@@ -407,7 +437,7 @@ def init_layer_params(rng: jax.Array, cfg: MlaConfig, layer_idx: int) -> Params:
         nI, dI = cfg.index_n_heads, cfg.index_head_dim
         p["w_iq"] = (
             jax.random.normal(kx[0], (cfg.q_lora_rank, nI * dI))
-            / math.sqrt(cfg.q_lora_rank)
+            / (math.sqrt(cfg.q_lora_rank) * cfg.q_latent_scale)
         ).astype(cfg.dtype)
         p["w_ik"] = (jax.random.normal(kx[1], (h, dI)) * scale).astype(cfg.dtype)
         p["ik_norm_w"] = jnp.ones((dI,), cfg.dtype)
@@ -504,6 +534,14 @@ def _rope_front(x: jax.Array, cos: jax.Array, sin: jax.Array, rope: int):
     )
 
 
+def _scaled(x: jax.Array, scale: float) -> jax.Array:
+    """``x * scale`` rounded once (a normalised latent's constant rescale);
+    ``x`` itself at 1.0."""
+    if scale == 1.0:
+        return x
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
 def _lanes(x: jax.Array) -> jax.Array:
     """Zero-pad the last dim to a row of ``LATENT_LANES`` lanes."""
     pad = LATENT_LANES - x.shape[-1]
@@ -521,10 +559,13 @@ def _rows_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
     nI, dI = cfg.index_n_heads, cfg.index_head_dim
     lead = h.shape[:-1]
     kI = jnp.zeros((*lead, LATENT_LANES), cfg.dtype)
+    named = {"scope": cfg.trace_scope} if cfg.trace_scope else {}
     if cfg.index_topk == 0:
-        ask = {"latent": LatentQuery(scale=cfg.softmax_scale)}
+        ask = {"latent": LatentQuery(
+            scale=cfg.softmax_scale, window=cfg.sliding_window, **named
+        )}
     else:
-        dsa = DsaQuery(scale=cfg.softmax_scale, topk=cfg.index_topk)
+        dsa = DsaQuery(scale=cfg.softmax_scale, topk=cfg.index_topk, **named)
         ask = {"dsa": dsa}
     if _selects(cfg, layer_idx):
         qI = (cq @ p["w_iq"]).reshape(*lead, nI, dI)
@@ -543,11 +584,12 @@ def _rows_attention(p, cfg, h, cq, c, k_pe, q_abs, q_pe, cos, sin,
         )
     elif cfg.index_topk > 0:
         dsa.selected = carry["selected"]
-    rows = cfg.num_kv_heads
-    k_rows = c.reshape(*lead, rows, LATENT_LANES)
+    k_rows = c.reshape(*lead, cfg.num_kv_heads, LATENT_LANES)
     aux = jnp.stack([_lanes(k_pe[..., 0, :]), kI.astype(k_pe.dtype)], axis=-2)
     aux = jnp.pad(
-        aux, [(0, 0)] * len(lead) + [(0, rows - 2), (0, 0)]
+        aux,
+        [(0, 0)] * len(lead)
+        + [(0, (cfg.aux_rows or cfg.num_kv_heads) - 2), (0, 0)],
     )
     q_lat = jnp.concatenate([q_abs, _lanes(q_pe)], axis=-1)
     o = attend(
@@ -587,7 +629,10 @@ def layer_forward(
     # -- queries
     cq = None
     if cfg.q_lora_rank > 0:
-        cq = rms_norm(h @ p["w_dq"], p["q_norm"], cfg.rms_norm_eps)
+        cq = _scaled(
+            rms_norm(h @ p["w_dq"], p["q_norm"], cfg.rms_norm_eps),
+            cfg.q_latent_scale,
+        )
         q = cq @ p["w_uq"]
     else:
         q = h @ p["wq"]
@@ -596,7 +641,10 @@ def layer_forward(
     q_pe = apply_rope(q_pe, cos, sin)
     # -- latent KV
     ckv = h @ p["w_dkv"]                                   # [..., rank+rope]
-    c = rms_norm(ckv[..., :rank], p["kv_norm"], cfg.rms_norm_eps)
+    c = _scaled(
+        rms_norm(ckv[..., :rank], p["kv_norm"], cfg.rms_norm_eps),
+        cfg.kv_latent_scale,
+    )
     k_pe = apply_rope(ckv[..., None, rank:], cos, sin)     # [..., 1, rope]
     # -- absorb W_uk into q: MQA over the latent
     q_abs = jnp.einsum("...hn,hnr->...hr", q_nope, p["w_uk"])
@@ -623,6 +671,12 @@ def layer_forward(
         )                                                  # [..., nh, rank+rope]
     # -- un-absorb W_uv past the softmax
     attn = jnp.einsum("...hr,hrv->...hv", o[..., :rank], p["w_uv"])
+    if cfg.attention_gate:
+        # one scalar a head a token, on the un-absorbed values: it rides the
+        # product above (or the one below), no pass of its own
+        with jax.named_scope(f"{cfg.trace_scope or 'mla'}_gate"):
+            g = jax.nn.sigmoid((h @ p["w_g"]).astype(jnp.float32))
+            attn = (attn * g[..., None]).astype(attn.dtype)
     x = x + attn.reshape(*lead, nh * cfg.v_head_dim) @ p["wo"]
     # -- FFN
     h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)

@@ -14,8 +14,8 @@ from jax.sharding import PartitionSpec as P
 from ..ops.pallas_moe import grouped_matmul, grouped_matmul_reference
 from ..parallel.mesh import AXIS_TP, shard_map
 from . import (
-    cohere2_moe, evabyte, falcon_h1, gemma, gptoss, llama, minicpm_sala, mla,
-    moe, solar_open2,
+    cohere2_moe, dots3_note, evabyte, falcon_h1, gemma, gptoss, llama,
+    minicpm_sala, mla, moe, solar_open2,
 )
 
 
@@ -55,6 +55,10 @@ def is_cohere2_moe(cfg) -> bool:
     return isinstance(cfg, cohere2_moe.Cohere2MoeConfig)
 
 
+def is_dots3_note(cfg) -> bool:
+    return isinstance(cfg, dots3_note.Dots3NoteConfig)
+
+
 def supports_pp(cfg) -> bool:
     """Pipeline-parallel serving covers the dense llama family only: the
     stage placement stacks per-layer params homogeneously, which MoE expert
@@ -64,7 +68,8 @@ def supports_pp(cfg) -> bool:
     fit (parallel/pp_serving.py)."""
     return not (is_moe(cfg) or is_mla(cfg) or is_gptoss(cfg) or is_gemma(cfg)
                 or is_falcon_h1(cfg) or is_solar_open2(cfg) or is_evabyte(cfg)
-                or is_cohere2_moe(cfg) or is_minicpm_sala(cfg))
+                or is_cohere2_moe(cfg) or is_minicpm_sala(cfg)
+                or is_dots3_note(cfg))
 
 
 def check_pp_supported(cfg) -> None:
@@ -76,7 +81,7 @@ def check_pp_supported(cfg) -> None:
         raise ValueError(
             f"pp serving supports dense llama-family models only; "
             f"{type(cfg).__name__} (MoE/MLA/gpt-oss/gemma/falcon-h1/solar-open2/evabyte/"
-            f"cohere2-moe/minicpm-sala) is not "
+            f"cohere2-moe/minicpm-sala/dots3-note) is not "
             f"stacked for pipeline stages — configure this preset with pp=1 "
             f"(use tp/sp/dp instead)"
         )
@@ -85,10 +90,11 @@ def check_pp_supported(cfg) -> None:
 def counts_routing(cfg) -> bool:
     """Whether the family's one-chip forward takes a ``stats`` collector
     (moe.RoutingStats): the grouped expert path of MoeConfig, of an
-    MlaConfig with experts, of SolarOpen2Config and of Cohere2MoeConfig
-    (every layer routes)."""
+    MlaConfig with experts, of SolarOpen2Config, of Cohere2MoeConfig
+    (every layer routes) and of Dots3NoteConfig."""
     return (is_moe(cfg) or (is_mla(cfg) and cfg.num_experts > 0)
-            or is_solar_open2(cfg) or is_cohere2_moe(cfg))
+            or is_solar_open2(cfg) or is_cohere2_moe(cfg)
+            or is_dots3_note(cfg))
 
 
 def expert_stack_leaves(cfg) -> tuple:
@@ -134,36 +140,102 @@ def read_counters(cfg) -> tuple:
     return own(cfg) if own else ()
 
 
+# What the one-chip text path refuses a family, by what was asked, for each of
+# the two traits that bring a family here: a latent held as rows of 128 lanes
+# (or a held share of the experts), and pages kept by layer kind. A family
+# that is BOTH (models/dots3_note.py: two page groups, each a latent in rows)
+# is answered from the two together, through ``_one_chip_text_path``: one
+# list, whichever of the two checks the engine asks first.
+_ROWS_WHY = {
+    "tp": "tp > 1: the latent's rows are one head's and cannot shard "
+          "on heads (the cache would be cut between its lanes), and a "
+          "held share is already one chip's of a layer divided over "
+          "chips (the exchange is not built)",
+    "pp_sp": "pp / sp > 1: neither the wavefront nor the ring "
+             "carries the rows layout (or a selection) from "
+             "layer to layer",
+    "spec": "a speculative draft: verify rows have no latent question in "
+            "the attention seam yet",
+    "lora": "LoRA: the family has no adapter path",
+    "kv_quantized": "kv_dtype=int8: the latent kernels read bf16 rows; an "
+                    "8-bit latent needs its scales a token",
+    "vision": "vision: multimodal serving covers the dense family only",
+}
+_GROUPS_WHY = {
+    "tp": "tp > 1: the groups' pools are not sharded by heads yet "
+          "(a pool's sharding and its table a group), and a held "
+          "share of the experts is already one chip's",
+    "pp_sp": "pp / sp > 1: the wavefront stacks ONE pool over "
+             "its stages and the ring attends one table; "
+             "neither knows a group's table or its shift",
+    "spec": "a speculative draft: its shadow cache is addressed by the "
+            "ONE table of the main cache, and verify rows would read "
+            "pages a window has let go",
+    "lora": "LoRA: the family has no adapter path",
+    "kv_quantized": "kv_dtype=int8: the scale rows are sized by ONE "
+                    "pool's page count, and the family's cell holds its "
+                    "pages to bf16",
+    "vision": "vision: multimodal serving covers the dense family only",
+    "transfer": "the KV transfer plane (disaggregation, evacuation): it "
+                "moves the pages of ONE table by position over every "
+                "page layer",
+    "kvbm": "KVBM offload tiers: kvbm/layout.py counts num_layers pages "
+            "a block hash, and a windowed group's pages are let go "
+            "under a live request",
+}
+
+
+def _rows_or_share(cfg) -> bool:
+    return bool(getattr(cfg, "latent_rows", False)
+                or getattr(cfg, "experts_held", None))
+
+
+def _one_chip_text_path(cfg, *, rows: bool, groups: bool, tp=1, pp=1, sp=1,
+                        spec=False, lora=False, kv_quantized=False,
+                        vision=False, transfer=False, kvbm=False) -> None:
+    """Raise for the first thing asked that a trait of ``cfg`` refuses, with
+    every such trait's reason (``rows`` / ``groups``: the traits the caller
+    answers for; a family that has both is answered for both)."""
+    traits = []
+    if rows:
+        traits.append((
+            "a latent held as rows of 128 lanes (learned sparse attention, "
+            f"or none) / a held share of the experts ({type(cfg).__name__})",
+            _ROWS_WHY,
+        ))
+    if groups:
+        traits.append((
+            f"pages kept by layer kind ({type(cfg).__name__})", _GROUPS_WHY
+        ))
+    asked = [
+        ("tp", tp > 1), ("pp_sp", pp > 1 or sp > 1), ("spec", spec),
+        ("lora", lora), ("kv_quantized", kv_quantized), ("vision", vision),
+        ("transfer", transfer), ("kvbm", kvbm),
+    ]
+    _refuse(" + ".join(what for what, _ in traits), [
+        (hit, "; ".join(dict.fromkeys(
+            why[key] for _, why in traits if key in why)))
+        for key, hit in asked if any(key in why for _, why in traits)
+    ])
+
+
 def check_dsa_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
                         kv_quantized=False, vision=False) -> None:
     """A configuration whose latent is held as rows of 128 lanes (learned
     sparse attention, or a latent without an indexer that states
-    ``rows_layout``: ``MlaConfig.latent_rows``), or one that holds a share of
-    its experts, runs on the one-chip text path; what it cannot do yet is
+    ``rows_layout``: the family's ``latent_rows``), or one that holds a share
+    of its experts, runs on the one-chip text path; what it cannot do yet is
     refused here, at engine construction, each with its reason. A latent
     without an indexer that states no layout never comes here as rows:
-    ``place_latent`` leaves it one head wherever a refusal would hit."""
-    rows = is_mla(cfg) and cfg.latent_rows
-    if not (rows or getattr(cfg, "experts_held", None)):
+    ``place_latent`` leaves it one head wherever a refusal would hit. A
+    family that also keeps pages by layer kind is refused what EITHER trait
+    refuses, with both reasons (``_one_chip_text_path``)."""
+    if not _rows_or_share(cfg):
         return
-    what = ("a latent held as rows of 128 lanes (learned sparse attention, "
-            f"or none) / a held share of the experts ({type(cfg).__name__})")
-    refusals = [
-        (tp > 1, "tp > 1: the latent's rows are one head's and cannot shard "
-                 "on heads (the cache would be cut between its lanes), and a "
-                 "held share is already one chip's of a layer divided over "
-                 "chips (the exchange is not built)"),
-        (pp > 1 or sp > 1, "pp / sp > 1: neither the wavefront nor the ring "
-                           "carries the rows layout (or a selection) from "
-                           "layer to layer"),
-        (spec, "a speculative draft: verify rows have no latent question in "
-               "the attention seam yet"),
-        (lora, "LoRA: the family has no adapter path"),
-        (kv_quantized, "kv_dtype=int8: the latent kernels read bf16 rows; an "
-                       "8-bit latent needs its scales a token"),
-        (vision, "vision: multimodal serving covers the dense family only"),
-    ]
-    _refuse(what, refusals)
+    _one_chip_text_path(
+        cfg, rows=True, groups=len(page_groups(cfg)) > 1, tp=tp, pp=pp,
+        sp=sp, spec=spec, lora=lora, kv_quantized=kv_quantized, vision=vision,
+    )
 
 
 def state_spec(cfg) -> tuple:
@@ -203,6 +275,21 @@ def page_groups(cfg) -> tuple:
     says otherwise (``cohere2_moe.page_groups``: pages by layer kind)."""
     own = getattr(family(cfg), "page_groups", None)
     return own(cfg) if own else ((page_layers(cfg), None),)
+
+
+def page_shapes(cfg) -> tuple:
+    """A token's shape in each page layer's two arrays, in ``page_layers``'
+    order: ``((k rows, k lanes), (v rows, v lanes))`` a layer. Every family
+    answers ``(num_kv_heads, head_dim)`` for both arrays of every layer, and
+    the engine allocates what it always did, unless its module says
+    otherwise (``dots3_note.page_shapes``: a page GROUP's arrays take the
+    shape its layers need: the latent's own rows in the first, the one tile
+    a step reads in the second)."""
+    own = getattr(family(cfg), "page_shapes", None)
+    if own:
+        return own(cfg)
+    token = (cfg.num_kv_heads, cfg.head_dim)
+    return ((token, token),) * len(page_layers(cfg))
 
 
 def pooled_keys(cfg):
@@ -328,7 +415,9 @@ def check_groups_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
     """A family whose pages are kept by layer kind (more than one
     ``page_groups``) runs on the one-chip text path; what it cannot do yet
     is refused here, at engine construction (``transfer``: where the
-    transfer plane is asked for), each with its reason."""
+    transfer plane is asked for), each with its reason; a family that also
+    holds a latent as rows (or a share) is refused what EITHER trait refuses
+    (``_one_chip_text_path``, the list ``check_dsa_supported`` answers from)."""
     groups = page_groups(cfg)
     if len(groups) == 1:
         return
@@ -337,30 +426,11 @@ def check_groups_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
             f"{type(cfg).__name__}: the first page group lives as long as "
             "the request (the engine's own table and allocator are its)"
         )
-    what = f"pages kept by layer kind ({type(cfg).__name__})"
-    refusals = [
-        (tp > 1, "tp > 1: the groups' pools are not sharded by heads yet "
-                 "(a pool's sharding and its table a group), and a held "
-                 "share of the experts is already one chip's"),
-        (pp > 1 or sp > 1, "pp / sp > 1: the wavefront stacks ONE pool over "
-                           "its stages and the ring attends one table; "
-                           "neither knows a group's table or its shift"),
-        (spec, "a speculative draft: its shadow cache is addressed by the "
-               "ONE table of the main cache, and verify rows would read "
-               "pages a window has let go"),
-        (lora, "LoRA: the family has no adapter path"),
-        (kv_quantized, "kv_dtype=int8: the scale rows are sized by ONE "
-                       "pool's page count, and the family's cell holds its "
-                       "pages to bf16"),
-        (vision, "vision: multimodal serving covers the dense family only"),
-        (transfer, "the KV transfer plane (disaggregation, evacuation): it "
-                   "moves the pages of ONE table by position over every "
-                   "page layer"),
-        (kvbm, "KVBM offload tiers: kvbm/layout.py counts num_layers pages "
-               "a block hash, and a windowed group's pages are let go "
-               "under a live request"),
-    ]
-    _refuse(what, refusals)
+    _one_chip_text_path(
+        cfg, rows=_rows_or_share(cfg), groups=True, tp=tp, pp=pp, sp=sp,
+        spec=spec, lora=lora, kv_quantized=kv_quantized, vision=vision,
+        transfer=transfer, kvbm=kvbm,
+    )
 
 
 def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
@@ -395,6 +465,8 @@ def check_state_supported(cfg, *, tp=1, pp=1, sp=1, spec=False, lora=False,
 
 
 def family(cfg):
+    if is_dots3_note(cfg):
+        return dots3_note
     if is_cohere2_moe(cfg):
         return cohere2_moe
     if is_evabyte(cfg):
@@ -455,7 +527,7 @@ def forward_fn(cfg, mesh=None, use_pallas: bool = False,
         return evabyte.forward
     if is_minicpm_sala(cfg):
         return minicpm_sala.forward
-    if is_solar_open2(cfg) or is_cohere2_moe(cfg):
+    if is_solar_open2(cfg) or is_cohere2_moe(cfg) or is_dots3_note(cfg):
         # the held (or replicated) experts' grouped path, its multiplication
         # as for MlaConfig below; tp > 1 is refused at construction
         fwd = family(cfg).forward
@@ -597,7 +669,7 @@ def param_specs(cfg) -> dict:
             "mu": P(AXIS_TP, None), "phi": P(AXIS_TP, None),
         })
         return {"top": top, "layer": layer, "default": P()}
-    if is_cohere2_moe(cfg):
+    if is_cohere2_moe(cfg) or is_dots3_note(cfg):
         # tp > 1 is refused at construction (check_groups_supported): the
         # attention's specs are the dense family's, everything else replicates
         return {"top": top, "layer": layer, "default": P()}

@@ -254,9 +254,9 @@ def write_prefill_kv(
     is tiled over ``(kvh, d)``, and the pool is copied there and back around
     every layer's write (PERF.md section 6, PR 40)."""
     if page_view and not is_quantized(k_cache):
-        nb, bs, kvh, d = k_cache.shape
-
         def put(cache, new):
+            # each array's own rows: a family's second array may hold fewer
+            nb, bs, kvh, d = cache.shape
             pages = cache.reshape(nb, bs * kvh, d).at[block_ids].set(
                 new.reshape(-1, bs * kvh, d)
             )
@@ -461,7 +461,9 @@ def paged_extend_attention(
 # Layout of a latent layer's two paged arrays, both ``[num_blocks, block_size,
 # rows, 128]`` (``rows = max(kv_lora_rank / 128, 2)``, the engine's
 # ``num_kv_heads``; 128 lanes a row, so a token of either array is whole
-# Mosaic tiles and can be copied alone):
+# Mosaic tiles and can be copied alone; a family that shapes its page groups
+# by layer kind, models/registry.page_shapes, gives the V array the ONE tile
+# a step reads, 2 rows, and each group's K array its own latent's rows):
 #   K array: the normalised latent ``c``, ``kv_lora_rank / 128`` rows a token;
 #   V array: row 0 ``[k_pe | 0]`` (the rotated shared key), row 1 the index
 #            key ``[kI | 0]`` on layers with an indexer; further rows unused.
@@ -509,6 +511,9 @@ class DsaQuery:
     index_w: Optional[jax.Array] = None
     selected: Optional[jax.Array] = None
     index_chunk_reads: Optional[Tuple[jax.Array, jax.Array]] = None
+    # the prefix of the seam's scopes in a device trace (``<scope>_index``,
+    # ``<scope>_select``): a family names its own
+    scope: str = "dsa"
 
 
 @dataclasses.dataclass
@@ -518,10 +523,19 @@ class LatentQuery:
     leaves in ``chunk_reads`` what the launch reads by the chunk: the whole
     chunks under its rows' contexts and those of them that are runs of
     consecutive pages (two scalars; ops/pallas_latent.chunk_reads). A
-    trace-time object, as a ``DsaQuery`` is."""
+    trace-time object, as a ``DsaQuery`` is.
+
+    ``window``: a query at position ``i`` sees the keys ``i - window < j <=
+    i`` alone (a sliding layer's latent, models/dots3_note.py); the seam then
+    answers with the same launch under the name ``windowed_latent_attention``,
+    which starts a row's walk at the chunk of pages its window starts in.
+    ``scope`` prefixes the seam's scope in a device trace
+    (``<scope>_attend``)."""
 
     scale: float                       # softmax scale (YaRN's factor in it)
     chunk_reads: Optional[Tuple[jax.Array, jax.Array]] = None
+    window: Optional[int] = None
+    scope: str = "latent"
 
 
 def dsa_index_scores(iq: jax.Array, iw: jax.Array, keys: jax.Array) -> jax.Array:
@@ -599,7 +613,7 @@ def sparse_latent_attention(
     rank = q.shape[-1] - lanes
     tok = selected_token_rows(tables, rows, sel, bs)             # [Tq, K]
     c = k_cache.reshape(nb * bs, r * lanes)[tok][..., :rank]     # [Tq, K, rank]
-    pe = v_cache.reshape(nb * bs, r, lanes)[tok, 0]              # [Tq, K, 128]
+    pe = v_cache.reshape(nb * bs, -1, lanes)[tok, 0]             # [Tq, K, 128]
     keys = jnp.concatenate([c, pe], axis=-1)
     s = jnp.einsum("qhd,qkd->qhk", q, keys,
                    preferred_element_type=jnp.float32) * scale
@@ -621,9 +635,11 @@ def paged_latent_attention(
     q_lens: jax.Array,       # [R] queries of row r (0 = an empty row)
     seq_lens: jax.Array,     # [R] context length incl. the row's queries
     scale: float,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """MQA of every head of a query over EVERY causal latent row of its
-    context; values are the rows' latent. Ragged rows as in
+    context (under ``window``: the last ``window`` of them, its own among
+    them); values are the rows' latent. Ragged rows as in
     ``ragged_paged_attention``: row ``r`` owns ``q[q_starts[r] : q_starts[r]
     + q_lens[r]]`` at the tail of ``tables[r]``'s context. Returns [Tq, h,
     rank]; a query no row owns, or of an empty row, returns zeros. The
@@ -642,8 +658,10 @@ def paged_latent_attention(
         q_pos = seq_len - q_len + local
         s = jnp.einsum("qhd,td->qht", q, keys,
                        preferred_element_type=jnp.float32) * scale
-        seen = jnp.arange(keys.shape[0])[None, :] < jnp.minimum(
-            q_pos + 1, seq_len)[:, None]
+        key = jnp.arange(keys.shape[0])[None, :]
+        seen = key < jnp.minimum(q_pos + 1, seq_len)[:, None]
+        if window is not None:
+            seen &= key > (q_pos - window)[:, None]
         s = jnp.where(seen[:, None, :], s, NEG_INF)
         out = jnp.einsum("qht,tr->qhr", jax.nn.softmax(s, axis=-1),
                          c.astype(jnp.float32))

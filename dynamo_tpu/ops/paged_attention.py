@@ -42,7 +42,11 @@ straight through.
   further question: every query attends over every causal key of its row,
   ``_latent``. Pallas on, that is the launch
   ``paged_latent_attention`` (ops/pallas_latent.py), page-contiguous, for
-  decode rows, a chunk and a mixed step alike (one launch each).
+  decode rows, a chunk and a mixed step alike (one launch each). With a
+  ``window`` (a sliding layer's latent, models/dots3_note.py: the rows are a
+  windowed page group's, below) it is the same launch with a lower bound a
+  query, under the name ``windowed_latent_attention``: a row's walk starts at
+  the chunk of pages its window starts in, never at its context's head.
 - A layer that hands an ``eva`` (ops/attention.EvaQuery: exact attention in
   the query's window, one learned summary a chunk of the windows before it,
   models/evabyte.py) keeps a RING of pages and summary blocks by window in
@@ -187,7 +191,7 @@ class PagedAttention:
             Tq = q.shape[0]
             iq = dsa.index_q.reshape(Tq, *dsa.index_q.shape[-2:])
             iw = dsa.index_w.reshape(Tq, -1)
-            with jax.named_scope("dsa_index"):
+            with jax.named_scope(f"{dsa.scope}_index"):
                 dsa.index_chunk_reads = ps.index_chunk_reads(tables)
                 if self.use_pallas:
                     keys = ps.paged_index_keys(
@@ -208,7 +212,7 @@ class PagedAttention:
                         iq[n_chunk:], iw[n_chunk:], keys[bool(n_chunk):]
                     ))
                 scores = jnp.concatenate(parts, axis=0)
-            with jax.named_scope("dsa_select"):
+            with jax.named_scope(f"{dsa.scope}_select"):
                 dsa.selected = att.dsa_select(scores, q_pos, q_valid, dsa.topk)
         with jax.named_scope("sparse_attend"):
             if not self.use_pallas:
@@ -230,8 +234,14 @@ class PagedAttention:
         one reads them)."""
         from . import pallas_latent as plat
 
-        with jax.named_scope("latent_attend"):
+        with jax.named_scope(f"{latent.scope}_attend"):
             latent.chunk_reads = plat.chunk_reads(kc, tables, q_lens, seq_lens)
+            # a window is the same launch under a name of its own: a reader of
+            # ``paged_latent_attention``'s roofline reckons every causal key
+            windowed = {} if latent.window is None else {
+                "window": latent.window,
+                "name": plat.WINDOWED_KERNEL_NAME,
+            }
             if not self.use_pallas:
                 first = 1 if n_chunk else 0
                 q_starts = jnp.concatenate([
@@ -239,11 +249,12 @@ class PagedAttention:
                     n_chunk + jnp.arange(tables.shape[0] - first),
                 ])
                 return att.paged_latent_attention(
-                    q, kc, vc, tables, q_starts, q_lens, seq_lens, latent.scale
+                    q, kc, vc, tables, q_starts, q_lens, seq_lens,
+                    latent.scale, window=latent.window,
                 )
             return plat.paged_latent_attention(
                 q, kc, vc, tables, q_lens, seq_lens, scale=latent.scale,
-                n_chunk=n_chunk, interpret=self.interpret,
+                n_chunk=n_chunk, interpret=self.interpret, **windowed,
             )
 
     def summarise_chunk(self, kc, vc, k_new, v_new, table, chunk_start,

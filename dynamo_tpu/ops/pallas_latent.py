@@ -92,6 +92,10 @@ from .attention import LATENT_LANES
 from .pallas_paged import NEG_INF
 
 KERNEL_NAME = "paged_latent_attention"
+# the same launch under a ``window`` (a sliding layer's latent: a query sees
+# its last ``window`` keys). A name of its own: a reader of KERNEL_NAME's
+# roofline reckons every causal key and must never count it
+WINDOWED_KERNEL_NAME = "windowed_latent_attention"
 # chunk queries a program: Q_TILE x heads rows against a chunk's keys. A lone
 # 512-query chunk over 25k keys at 64 heads ran 19.4 ms at 8, 15.0 at 16 on a
 # v5e (PERF.md section 6, PR 33: what a wider tile halved was the unpack, paid
@@ -100,11 +104,20 @@ KERNEL_NAME = "paged_latent_attention"
 # chunk, 10.78 with no unpack at all), so a wider tile has nothing left to take
 Q_TILE = 16
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# tokens a chunk of a WINDOWED launch, at most: a tile of 16 queries sees 528
+# keys and a decode row 513, wherever they start in a chunk, so a visit walks
+# ``ceil(528 / T) + 1`` chunks at worst: 1 536 keys at the unwindowed rule's
+# 512 (rank 1 024), 1 024 at 256
+WINDOW_CHUNK_TOKENS = 256
 
 
-def _chunk_pages(k_cache: jax.Array, mb: int) -> int:
+def _chunk_pages(k_cache: jax.Array, mb: int, window: int | None = None) -> int:
     _, bs, n_rows, lanes = k_cache.shape
-    return paged.chunk_pages(bs, n_rows, lanes, k_cache.dtype, mb)
+    cp = paged.chunk_pages(bs, n_rows, lanes, k_cache.dtype, mb)
+    if window:
+        # whole registers of even and odd tokens: a chunk of 16 tokens or more
+        cp = min(cp, max(WINDOW_CHUNK_TOKENS // bs, -(-16 // bs)))
+    return cp
 
 
 def chunk_reads(k_cache: jax.Array, tables: jax.Array, q_lens: jax.Array,
@@ -195,7 +208,7 @@ class _LatentPages(paged.PageReader):
 
 def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
             cp: int, mb: int, lat_rows: int, scale: float, n_ct: int, qt: int,
-            n_one: int):
+            n_one: int, window: int):
     # scalar prefetch (SMEM): lens [R] context lengths, qlens [R] query
     # lengths, tables [R * mb], runs [R * (mb // cp)] (chunk_runs)
     it = iter(refs)
@@ -254,12 +267,26 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
         def count(c):
             return jnp.minimum(cp, n_pages - c * cp)
 
-        @pl.when(n_chunks > 0)
-        def _first():
-            pages.start(r * mb, count(0), 0, r * n_runs)
+        # under a window the walk starts at the chunk that holds the first
+        # query's oldest visible key, ``q_pos0 - window + 1``: the chunks
+        # before it hold keys no query of these sees
+        if window:
+            c_lo = jnp.minimum(
+                jnp.maximum(q_pos0 - window + 1, 0) // T, n_chunks)
+
+            @pl.when(n_chunks > c_lo)
+            def _first():
+                pages.start(
+                    r * mb + c_lo * cp, count(c_lo), 0, r * n_runs + c_lo)
+        else:
+            c_lo = 0
+
+            @pl.when(n_chunks > 0)
+            def _first():
+                pages.start(r * mb, count(0), 0, r * n_runs)
 
         def chunk(c, carry, *, masked):
-            slot = jax.lax.rem(c, 2)
+            slot = jax.lax.rem(c - c_lo, 2) if window else jax.lax.rem(c, 2)
 
             @pl.when(c + 1 < n_chunks)
             def _next():
@@ -275,6 +302,8 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
             if masked:
                 key = c * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
                 valid = jnp.logical_and(key <= q_pos, real)
+                if window:
+                    valid = jnp.logical_and(valid, key > q_pos - window)
                 s = jnp.where(valid, s, NEG_INF)
             m_prev = m_scr[rows]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -295,9 +324,14 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
 
         # chunks that end at or below the first query's position hold only
         # keys every query sees, and no row never read
-        c_tail = jnp.clip(q_pos0 // T, 0, n_chunks)
-        jax.lax.fori_loop(
-            0, c_tail, functools.partial(chunk, masked=False), 0)
+        if window:
+            # a window is three or four chunks, most with an edge of it (the
+            # lower or the causal one): every chunk is masked
+            c_tail = c_lo
+        else:
+            c_tail = jnp.clip(q_pos0 // T, 0, n_chunks)
+            jax.lax.fori_loop(
+                0, c_tail, functools.partial(chunk, masked=False), 0)
         jax.lax.fori_loop(
             c_tail, n_chunks, functools.partial(chunk, masked=True), 0)
         l = l_scr[rows]
@@ -332,7 +366,8 @@ def _kernel(lens_ref, qlens_ref, tables_ref, runs_ref, *refs, bs: int,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "n_chunk", "interpret")
+    jax.jit,
+    static_argnames=("scale", "n_chunk", "interpret", "window", "name"),
 )
 def paged_latent_attention(
     q: jax.Array,            # [Tq, h, rank + 128]: [absorbed q | q_pe | 0]
@@ -342,10 +377,12 @@ def paged_latent_attention(
     q_lens: jax.Array,       # [R] the chunk's real length, then 0 / 1 a row
     seq_lens: jax.Array,     # [R] context lengths incl. the row's queries
     *, scale: float, n_chunk: int = 0, interpret: bool = False,
+    window: int | None = None, name: str = KERNEL_NAME,
 ) -> jax.Array:
     """ops/attention.paged_latent_attention has the contract; returns
     [Tq, h, rank]. The first ``n_chunk`` queries are row 0's chunk (its
-    real queries first), every later query is a row of its own."""
+    real queries first), every later query is a row of its own. The second
+    array has rows of its own (at least the one tile a key's copy reads)."""
     Tq, h, width = q.shape
     nb, bs, n_rows, lanes = k_cache.shape
     R, mb = tables.shape
@@ -353,7 +390,8 @@ def paged_latent_attention(
     lat_rows = rank // lanes
     if (k_cache.dtype != jnp.bfloat16 or q.dtype != jnp.bfloat16
             or lanes != LATENT_LANES or rank % (2 * lanes)
-            or n_rows != lat_rows or bs % 2):
+            or n_rows != lat_rows or bs % 2 or v_cache.shape[2] % 2
+            or v_cache.shape[:2] != (nb, bs)):
         raise ValueError(
             "paged_latent_attention reads bf16 pages of 128 lanes a row, the "
             "latent an even number of rows, a page an even number of tokens; "
@@ -367,7 +405,7 @@ def paged_latent_attention(
         )
     qt = Q_TILE
     n_ct = -(-n_chunk // qt)
-    cp = _chunk_pages(k_cache, mb)
+    cp = _chunk_pages(k_cache, mb, window)
     T = cp * bs
     tables = tables.astype(jnp.int32)
     M = max(qt * h if n_ct else 0, h)
@@ -390,7 +428,7 @@ def paged_latent_attention(
     outs = pl.pallas_call(
         functools.partial(
             _kernel, bs=bs, cp=cp, mb=mb, lat_rows=lat_rows, scale=scale,
-            n_ct=n_ct, qt=qt, n_one=n_one,
+            n_ct=n_ct, qt=qt, n_one=n_one, window=int(window or 0),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -414,14 +452,14 @@ def paged_latent_attention(
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
-        name=KERNEL_NAME,
+        name=name,
     )(
         seq_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
         tables.reshape(-1),
         paged.chunk_runs(tables, cp).reshape(-1).astype(jnp.int32),
         *operands,
         k_cache.reshape(nb * bs, n_rows, lanes),
-        v_cache.reshape(nb * bs, n_rows // 2, 2, lanes),
+        v_cache.reshape(nb * bs, v_cache.shape[2] // 2, 2, lanes),
     )
     parts = []
     if n_ct:

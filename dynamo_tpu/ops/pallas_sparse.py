@@ -326,6 +326,7 @@ def sparse_latent_attention(
     rank = width - lanes
     lat_rows = rank // lanes
     _check_rows_pool("sparse_latent_attention", k_cache, rank)
+    _check_rows_pool("sparse_latent_attention", v_cache)
     K = sel.shape[1]
     chunk = min(CHUNK, -(-K // UNROLL) * UNROLL)
     pad = (-K) % chunk
@@ -388,7 +389,7 @@ def sparse_latent_attention(
     )(
         *prefetch, tok[:, None, :], qc, q[..., rank:],
         k_cache.reshape(nb * bs, n_rows, lanes),
-        v_cache.reshape(nb * bs, n_rows // 2, 2, lanes),
+        v_cache.reshape(nb * bs, v_cache.shape[2] // 2, 2, lanes),
     )
     return out.transpose(0, 2, 1, 3).reshape(Tq, h, rank)
 

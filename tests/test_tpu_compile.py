@@ -289,6 +289,43 @@ def _cases():
             return (q, page, page, s((rows, max_blocks), I32), r, r, r)
         return fn, build
 
+    def windowed_latent(question, q_tokens, rows, max_blocks=162):
+        # a sliding layer's latent at dots3-note's widths and the agent cell's
+        # sizes: 64 absorbed heads over 1024 + 64 lanes, the latent 8 rows of
+        # 128 and the second array its one tile (2 rows), a windowed group's
+        # pool of 3 121 pages and its run of a row's table (513 keys + a
+        # 2 048-token chunk + a page: 162 pages, a chunk of 32)
+        from dynamo_tpu.ops.attention import LatentQuery
+
+        def fn(attn, *args):
+            return getattr(attn, question)(
+                *args, latent=LatentQuery(scale=0.0625, window=513))
+        fn.asks_seam = True
+
+        def build(sh):
+            s, *_ = _shapes(sh)
+            kp, vp = s((3121, BS, 8, D), BF), s((3121, BS, 2, D), BF)
+            q = s((q_tokens, 64, 1152), BF)
+            r = s((rows,), I32)
+            if question == "decode":
+                return (q, kp, vp, s((rows, max_blocks), I32), r)
+            if question == "chunk":
+                return (q, kp, vp, s((max_blocks,), I32), s((), I32),
+                        s((), I32), s((q_tokens,), I32))
+            return (q, kp, vp, s((rows, max_blocks), I32), r, r, r)
+        return fn, build
+
+    def dots3_sparse(tq, rows, mb=2336):
+        # a full layer of dots3-note: 128 absorbed heads over 512 + 64 lanes,
+        # 64 index heads, the second array its one tile, tables of 37 376
+        # tokens (staged: 56 MiB of the chunk row's pages in VMEM)
+        def build(sh):
+            s, *_ = _shapes(sh)
+            return (s((tq, 128, 640), BF), s((37888, BS, 4, D), BF),
+                    s((37888, BS, 2, D), BF), s((rows, mb), I32),
+                    s((tq, 64, 128), BF), s((tq, 64), F32))
+        return build
+
     def ssm_update(rows):
         # the state-space mixer's decode recurrence at Falcon-H1-34B's
         # widths: 32 heads x [256 state, 128 lanes] float32 a row, 2 groups
@@ -432,6 +469,14 @@ def _cases():
         "paged-latent-chunk-S512": latent("chunk", 512, 1),
         "paged-latent-mixed": latent("ragged", 520, 9),
         "paged-latent-mixed-S128-narrow-table": latent("ragged", 136, 9, 32),
+        # the same launch under a window, at rank 1024 (dots3-note's sliding
+        # layers): decode rows, a lone 2 048-token chunk, the mixed step
+        "windowed-latent-decode": windowed_latent("decode", 16, 16),
+        "windowed-latent-chunk-S2048": windowed_latent("chunk", 2048, 1),
+        "windowed-latent-mixed-S512": windowed_latent("ragged", 528, 17),
+        # ... and its full layers' selection at 128 heads and 64 index heads
+        "sparse-latent-dots3-mixed": (sparse_mixed, dots3_sparse(528, 17)),
+        "sparse-latent-dots3-decode": (sparse_decode, dots3_sparse(16, 16)),
         # the wide-chat cell (PR 39): 20 q / 4 kv heads, FIVE query heads a kv
         # head (every other cell has a power of two), 128 rows over tables of
         # 82 pages; a 512-token chunk beside them; and the recurrence's launch
