@@ -553,6 +553,86 @@ def kernel_phase() -> Dict[str, Any]:
     return json.loads(result[0][len("KERNELS_OK "):])
 
 
+def selection_case() -> None:
+    """ISSUE 57's step 0, for the next reader to repeat: the indexer's exact
+    selection (``ops/attention.dsa_select``: a cut by counting, a list by
+    compaction) beside the ``lax.top_k`` it replaced (a full stable sort a
+    row on a TPU), at the two selecting cells' shapes: dots3's [2 064,
+    37 376] (a chunk of 2 048 + 16 decode rows), [528, 37 376], [16, 37 376]
+    (a decode step) and GLM's [520, 25 600], [8, 25 600]; 2 048 selected,
+    float32 scores (every seventh rounded to eighths: cuts inside runs of
+    equal values too) under the causal mask a chunk has, its padding
+    invalid. Each side's launches chained in ONE program (a launch's rows
+    wait for the one before), ms a launch; the two sides' sets compared row
+    by row on the chip's own outputs. Alone: ``chiprun -- python -c "import
+    chip_smoke; chip_smoke.selection_case()"`` (PERF.md section 6, PR 57,
+    has the table)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops import attention as att
+
+    K = 2048
+    rng = np.random.default_rng(SEED)
+
+    def top_k(scores, q_pos, q_valid):
+        # the selection as it was until PR 57, line for line
+        seen = (jnp.arange(scores.shape[1])[None, :] <= q_pos[:, None]) & q_valid[:, None]
+        _, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), K)
+        idx = idx.astype(jnp.int32)
+        ok = (idx <= q_pos[:, None]) & q_valid[:, None]
+        return jnp.where(ok, idx, att.SEL_NONE)
+
+    def chained(select, n):
+        def run(scores, q_pos, q_valid):
+            def body(_, carry):
+                pos, acc = carry
+                sel = select(scores, pos, q_valid)
+                # never true, and the compiler cannot know: the next launch
+                # waits for this one's rows
+                return (pos - (sel[:, 0] < -5).astype(jnp.int32),
+                        acc + jnp.sum(sel[:, :8], axis=1))
+            return jax.lax.fori_loop(0, n, body, (q_pos, jnp.zeros_like(q_pos)))
+        return jax.jit(run)
+
+    def ms_a_launch(select, n, args):
+        run = chained(select, n)
+        jax.block_until_ready(run(*args))
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(*args))
+            best = min(best, (time.perf_counter() - t0) / n)
+        return best * 1e3
+
+    for Q, T, n_chunk in ((2064, 37376, 2048), (528, 37376, 512), (16, 37376, 0),
+                          (520, 25600, 512), (8, 25600, 0)):
+        scores = rng.standard_normal((Q, T)).astype(np.float32)
+        scores[:, ::7] = np.round(scores[:, ::7] * 8) / 8
+        q_pos = np.concatenate([
+            T - n_chunk - 611 + np.arange(n_chunk),
+            rng.integers(T - 5000, T, size=Q - n_chunk),
+        ]).astype(np.int32)
+        q_valid = np.ones(Q, bool)
+        q_valid[max(n_chunk - 37, 0):n_chunk] = False
+        args = (jnp.asarray(scores), jnp.asarray(q_pos), jnp.asarray(q_valid))
+        got = np.asarray(jax.jit(att.dsa_select, static_argnums=3)(*args, K))
+        want = np.sort(np.asarray(jax.jit(top_k)(*args)), axis=1)
+        kept = (want >= 0).sum(axis=1)
+        for g, w, n in zip(got, want, kept):
+            if g[:n].tolist() != w[len(w) - n:].tolist() or (g[n:] != att.SEL_NONE).any():
+                raise SystemExit(f"dsa_select [{Q}, {T}]: not lax.top_k's set, "
+                                 f"ascending, SEL_NONE after")
+        n = 20 if Q <= 16 else 4
+        t_sort = ms_a_launch(top_k, n, args)
+        t_new = ms_a_launch(lambda s, p, v: att.dsa_select(s, p, v, K), n, args)
+        print(f"KERNEL dsa_select [{Q}, {T}] keep {K}: lax.top_k's sets, "
+              f"ascending ({int(kept.sum())} positions); {t_new:.3f} ms a "
+              f"launch, lax.top_k {t_sort:.3f} ms ({t_sort / t_new:.2f} x), "
+              f"{t_new / Q * 1e3:.2f} us a row", flush=True)
+
+
 def _kernel_child() -> None:
     """Runs IN THE CHILD that owns the chip: every Pallas kernel of the
     serving path, compiled (never interpreted) at qwen3-0.6b's shapes,
@@ -981,6 +1061,9 @@ def _kernel_child() -> None:
                   f"descriptor): bitwise the twin; {t_launch * 1e3:.3f} ms a "
                   f"launch, {gb / t_launch:.0f} GB/s; the twin "
                   f"{t_twin * 1e3:.3f} ms", flush=True)
+
+    # the selection behind those keys, beside the sort it replaced (PR 57)
+    selection_case()
 
     # the decode launch reads a whole chunk of consecutive pages as ONE
     # descriptor an array (PR 50, ops/pallas_paged.PageReader) at the

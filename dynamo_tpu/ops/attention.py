@@ -497,8 +497,9 @@ class DsaQuery:
     its head weights, already scaled) are set on a layer that selects; the
     seam scores them against the paged index keys, takes the ``topk`` largest
     causal scores a query and leaves the positions in ``selected`` [Tq, K]
-    (``SEL_NONE`` pads). A layer that shares a selection hands the one it
-    inherited in ``selected`` and no ``index_q``. Where it selects, the seam
+    (ascending, ``SEL_NONE`` pads after them: ``dsa_select``). A layer that
+    shares a selection hands the one it inherited in ``selected`` and no
+    ``index_q``. Where it selects, the seam
     also leaves in ``index_chunk_reads`` what its read of the index keys
     takes by the chunk: the whole chunks of pages under the rows' tables and
     those of them that are runs of consecutive pages (two scalars;
@@ -561,18 +562,148 @@ def dsa_index_scores(iq: jax.Array, iw: jax.Array, keys: jax.Array) -> jax.Array
     return acc
 
 
+# dsa_select: positions a block of the list's compaction (four 32-bit words
+# of a query's mask), and bits of the cut a counting round finds (a divisor of
+# 32; 2 = sixteen passes of three counts, the least time on a v5e: PERF.md
+# section 6, PR 57)
+SELECT_BLOCK = 128
+SELECT_BITS = 2
+
+
+def _order_keys(x: jax.Array) -> jax.Array:
+    """float32 -> the uint32 that orders as the float does (``-0.0`` just
+    under ``+0.0``): all bits of a negative flipped, the sign bit of the rest
+    set."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def _kth_largest(keys: jax.Array, k: int) -> jax.Array:
+    """keys [T, Q] uint32 -> [Q], the ``k``-th largest of each column, built
+    from its most significant bits down: a candidate stays if at least ``k``
+    keys are ``>=`` it. A round is ONE pass over the keys (a reduce of every
+    digit's count together), and nothing is sorted."""
+    digits = jnp.arange(1, 1 << SELECT_BITS, dtype=jnp.uint32)
+    rounds = 32 // SELECT_BITS
+
+    def one(i, cut):
+        shift = (rounds - 1 - i).astype(jnp.uint32) * SELECT_BITS
+        cand = cut[None, :] | (digits[:, None] << shift)                # [D, Q]
+        counts = jax.lax.reduce(
+            tuple((keys >= c[None, :]).astype(jnp.int32) for c in cand),
+            (jnp.int32(0),) * len(cand),
+            lambda a, b: tuple(x + y for x, y in zip(a, b)), (0,),
+        )
+        digit = sum((c >= k).astype(jnp.uint32) for c in counts)       # counts fall
+        return cut | (digit << shift)
+
+    return jax.lax.fori_loop(0, rounds, one, jnp.zeros(keys.shape[1:], jnp.uint32))
+
+
+def _pack_rows(m: jax.Array) -> jax.Array:
+    """[T, Q] bool -> [T // 32, Q] uint32, row ``t`` at bit ``t % 32``."""
+    T, Q = m.shape
+    bit = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))[None, :, None]
+    return jnp.sum(jnp.where(m.reshape(T // 32, 32, Q), bit, jnp.uint32(0)),
+                   axis=1, dtype=jnp.uint32)
+
+
+def _popcount(w: jax.Array) -> jax.Array:
+    return jax.lax.population_count(w).astype(jnp.int32)
+
+
+def _nth_set_bit(w: jax.Array, n: jax.Array) -> jax.Array:
+    """The place of the ``n``-th set bit (from 0) of each uint32 word, for
+    ``n`` under the word's count: halving, by the count of the low half."""
+    pos = jnp.zeros_like(n)
+    for half in (16, 8, 4, 2, 1):
+        low = _popcount(w & jnp.uint32((1 << half) - 1))
+        up = n >= low
+        n, w, pos = (jnp.where(up, n - low, n), jnp.where(up, w >> half, w),
+                     jnp.where(up, pos + half, pos))
+    return pos
+
+
+def _lowest_set_bits(w: jax.Array, n: jax.Array) -> jax.Array:
+    """Each word's ``n`` lowest set bits (none for ``n <= 0``, all of them
+    from the word's count on)."""
+    last = _nth_set_bit(w, jnp.clip(n - 1, 0, 31)).astype(jnp.uint32)
+    part = w & ((jnp.uint32(2) << last) - 1)
+    return jnp.where(n >= _popcount(w), w, jnp.where(n <= 0, jnp.uint32(0), part))
+
+
+def _set_positions(words: jax.Array, K: int) -> jax.Array:
+    """words [B, 4, Q] uint32, a query's mask by blocks of ``SELECT_BLOCK``
+    positions -> [K, Q] int32, the set positions ascending and ``SEL_NONE``
+    after them. No scatter and no gather: slot ``j`` finds its block by
+    comparing ``j`` with every block's running offset (one block holds it),
+    takes that block's words in the same pass, and reads the place of its bit
+    out of them."""
+    B = words.shape[0]
+    count = jnp.sum(_popcount(words), axis=1)                           # [B, Q]
+    end = jnp.cumsum(count, axis=0)
+    first = end - count
+    id_bits = max(1, (B - 1).bit_length())
+    if K << id_bits >= 2 ** 31:
+        raise ValueError(f"a selection of {K} of {B} blocks of {SELECT_BLOCK} "
+                         "positions does not fit the list's 32-bit tags")
+    tag = (first << id_bits) | jnp.arange(B, dtype=jnp.int32)[:, None]
+    j = jnp.arange(K, dtype=jnp.int32)[:, None, None]                   # [K, 1, 1]
+    mine = (first[None] <= j) & (j < end[None])                         # [K, B, Q]
+    held = jax.lax.reduce(
+        (jnp.where(mine, tag[None], 0),)
+        + tuple(jnp.where(mine, words[None, :, i], jnp.uint32(0)) for i in range(4)),
+        (jnp.int32(0),) + (jnp.uint32(0),) * 4,
+        lambda a, b: tuple(x | y for x, y in zip(a, b)), (1,),
+    )
+    tag, w = held[0], held[1:]                                          # [K, Q]
+    block, r = tag & ((1 << id_bits) - 1), j[:, 0] - (tag >> id_bits)
+    c0, c1, c2 = (_popcount(x) for x in w[:3])
+    starts = (jnp.zeros_like(c0), c0, c0 + c1, c0 + c1 + c2)          # a word's first r
+    which = sum((r >= s).astype(jnp.int32) for s in starts[1:])
+    pick = [which == i for i in range(3)]
+    place = _nth_set_bit(jnp.select(pick, w[:3], w[3]),
+                         r - jnp.select(pick, starts[:3], starts[3]))
+    pos = block * SELECT_BLOCK + which * 32 + place
+    return jnp.where(j[:, 0] < end[-1][None, :], pos, SEL_NONE)
+
+
 def dsa_select(scores: jax.Array, q_pos: jax.Array, q_valid: jax.Array,
                topk: int) -> jax.Array:
-    """Exact top-k of the causal scores: scores [Q, T], q_pos [Q] (a query
-    sees keys ``t <= q_pos``) -> [Q, min(topk, T)] int32 positions, the
-    selected ones first, ``SEL_NONE`` after them. Every causal position is
-    selected while there are at most ``topk`` of them."""
-    T = scores.shape[1]
-    seen = (jnp.arange(T)[None, :] <= q_pos[:, None]) & q_valid[:, None]
-    _, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), min(topk, T))
-    idx = idx.astype(jnp.int32)
-    ok = (idx <= q_pos[:, None]) & q_valid[:, None]
-    return jnp.where(ok, idx, SEL_NONE)
+    """Exact top-k of the causal scores: scores [Q, T] float32, q_pos [Q] (a
+    query sees keys ``t <= q_pos``) -> [Q, min(topk, T)] int32 positions, the
+    selected ones first and ASCENDING, ``SEL_NONE`` after them. Every causal
+    position is selected while there are at most ``topk`` of them; of equal
+    scores at the cut the lower positions are kept (``lax.top_k``'s rule).
+
+    No sort: the cut is found by counting (``_kth_largest``, 32 /
+    ``SELECT_BITS`` passes over the scores as ordered keys), the keys above it
+    and the first of the keys equal to it make a mask of one bit a position,
+    and the list is read out of the mask's words (``_set_positions``). The
+    passes run over the keys TRANSPOSED, queries on the lanes: a count is then
+    a sum down the rows, and a word of the mask 32 rows of it. A score is a
+    finite sum by construction; a NaN would sort by its bits, above ``+inf``
+    with the sign bit clear (selected first), below every score and every
+    masked key with it set (never selected)."""
+    Q, T = scores.shape
+    K = min(topk, T)
+    Tp = -(-T // SELECT_BLOCK) * SELECT_BLOCK
+    last = jnp.where(q_valid, q_pos, -1)                 # nothing seen: an empty row
+    seen = jnp.arange(T, dtype=jnp.int32)[None, :] <= last[:, None]
+    keys = _order_keys(jnp.where(seen, scores, -jnp.inf))
+    if Tp != T:
+        keys = jnp.pad(keys, ((0, 0), (0, Tp - T)))      # 0: under every key
+    keys = keys.T                                        # [Tp, Q]
+    cut = _kth_largest(keys, K)[None, :]
+    above, equal = _pack_rows(keys > cut), _pack_rows(keys == cut)      # [Tp // 32, Q]
+    # the bits of the positions a query sees: a masked key may equal the cut
+    n_seen = last[None, :] + 1 - 32 * jnp.arange(Tp // 32, dtype=jnp.int32)[:, None]
+    equal = equal & _lowest_set_bits(jnp.uint32(0xFFFFFFFF), n_seen)
+    need = K - jnp.sum(_popcount(above), axis=0)
+    n_equal = _popcount(equal)
+    before = jnp.cumsum(n_equal, axis=0) - n_equal
+    words = above | _lowest_set_bits(equal, need[None, :] - before)
+    return _set_positions(words.reshape(Tp // SELECT_BLOCK, 4, Q), K).T
 
 
 def paged_index_keys(v_cache: jax.Array, tables: jax.Array, dim: int) -> jax.Array:

@@ -32,7 +32,8 @@ straight through.
   launch ``paged_index_keys`` beside it (the one tile a token that holds
   them, by runs of pages; the twin's slice of row 1 has XLA re-tile the whole
   second array first); the scoring and the top-k stay XLA under the scopes
-  ``dsa_index`` and ``dsa_select``. The seam tells the launch how many of
+  ``dsa_index`` and ``dsa_select`` (a cut by counting and a list by
+  compaction: nothing is sorted). The seam tells the launch how many of
   its first queries are ONE row's chunk (``n_chunk``): the kernel stages
   that row's pages in VMEM once and those queries pick their keys there,
   where the table's width fits (a shape of the launch); decode rows, each a

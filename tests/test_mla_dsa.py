@@ -484,12 +484,73 @@ def test_the_kernel_across_chunks_tails_and_both_fillings(monkeypatch, filling, 
         np.testing.assert_array_equal(got, np.asarray(kernel(0)(*args), np.float32))
 
 
-def test_exact_top_k_of_the_causal_scores():
-    scores = jnp.asarray(np.random.default_rng(0).normal(size=(3, 40)), jnp.float32)
-    sel = np.asarray(att.dsa_select(scores, jnp.asarray([39, 9, 20]), jnp.asarray([True, True, False]), 16))
-    want = np.argsort(-np.asarray(scores[0]))[:16]
-    assert sorted(sel[0]) == sorted(want)
-    assert sorted(sel[1][sel[1] >= 0]) == list(range(10)) and (sel[2] == -1).all()
+def _select_case(name):
+    """(scores [Q, T], q_pos, q_valid, topk) of one case of the selection."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    normal = lambda Q, T: rng.normal(size=(Q, T)).astype(np.float32)   # noqa: E731
+    if name == "random":
+        return normal(3, 40), [39, 9, 20], [True, True, False], 16
+    if name == "cut-inside-a-run-of-equal-values":
+        # eighths: every cut falls in a run of ties, the lower positions win
+        return np.round(normal(16, 1280) * 8) / 8, rng.integers(300, 1280, 16), [True] * 16, 256
+    if name == "two-values-only":
+        return (rng.random((4, 640)) < 0.5).astype(np.float32), [639, 500, 77, 300], [True] * 4, 128
+    if name == "signed-zeros":
+        # +0.0 beside -0.0 (equal to lax.top_k), a few scores on either side
+        s = np.where(rng.random((6, 512)) < 0.5, 0.0, -0.0).astype(np.float32)
+        far = rng.random((6, 512)) < 0.1
+        return np.where(far, normal(6, 512), s), [511, 400, 300, 200, 511, 64], [True] * 6, 64
+    if name == "fewer-causal-keys-than-topk":
+        return normal(5, 384), [0, 5, 63, 64, 200], [True] * 5, 128
+    if name == "context-no-longer-than-topk":
+        return normal(4, 100), [99, 50, 0, 99], [True, True, True, False], 128
+    if name == "context-of-exactly-topk":
+        return normal(3, 256), [255, 100, 255], [True] * 3, 256
+    if name == "invalid-rows":
+        return normal(4, 300), [299, 10, 200, 150], [False, True, False, False], 32
+    if name == "context-not-a-multiple-of-128":
+        return normal(7, 1000), rng.integers(0, 1000, 7), [True] * 7, 200
+    if name == "infinite-scores":
+        s = normal(4, 256)
+        s[:, ::5], s[:, 1::7] = -np.inf, np.inf
+        return s, [255, 200, 30, 255], [True] * 4, 64
+    if name == "glm52-at-a-reduced-context":
+        # (T, topk) in the long-document cell's proportion (25 600, 2 048): a
+        # 24-token chunk behind its history and 8 decode rows
+        return (normal(32, 3200), np.r_[3000 + np.arange(24), rng.integers(2500, 3200, 8)],
+                np.r_[[True] * 20, [False] * 4, [True] * 8], 256)
+    if name == "dots3-at-a-reduced-context":
+        # ... and the agent cell's (37 376, 2 048)
+        return (normal(48, 4672), np.r_[4400 + np.arange(32), rng.integers(4000, 4672, 16)],
+                np.r_[[True] * 29, [False] * 3, [True] * 16], 256)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "random", "cut-inside-a-run-of-equal-values", "two-values-only", "signed-zeros",
+    "fewer-causal-keys-than-topk", "context-no-longer-than-topk", "context-of-exactly-topk",
+    "invalid-rows", "context-not-a-multiple-of-128", "infinite-scores",
+    "glm52-at-a-reduced-context", "dots3-at-a-reduced-context",
+])
+def test_exact_top_k_of_the_causal_scores(name):
+    """``dsa_select`` against ``jax.lax.top_k`` (the oracle: the sort stays
+    here and nowhere in the selection), set by set, and the list's form: the
+    selected first and ascending, ``SEL_NONE`` after them."""
+    scores, q_pos, q_valid, topk = _select_case(name)
+    scores, q_pos, q_valid = jnp.asarray(scores), jnp.asarray(q_pos, jnp.int32), jnp.asarray(q_valid)
+    T = scores.shape[1]
+    sel = np.asarray(jax.jit(att.dsa_select, static_argnums=3)(scores, q_pos, q_valid, topk))
+    assert sel.shape == (scores.shape[0], min(topk, T)) and sel.dtype == np.int32
+    seen = (np.arange(T)[None, :] <= np.asarray(q_pos)[:, None]) & np.asarray(q_valid)[:, None]
+    _, idx = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), min(topk, T))
+    for got, want, pos, ok in zip(sel, np.asarray(idx), np.asarray(q_pos), np.asarray(q_valid)):
+        want = sorted(int(i) for i in want if ok and i <= pos)
+        assert len(want) == (min(topk, pos + 1) if ok else 0)
+        assert got[:len(want)].tolist() == want          # the same set, ascending
+        assert (got[len(want):] == att.SEL_NONE).all()
+
+
+def test_the_index_scores_head_by_head_are_the_scores_of_all_heads_at_once():
     # head by head (a chunk against a long context) scores as all heads at once
     rng = np.random.default_rng(1)
     iq, iw, keys = rng.normal(size=(5, 4, 32)), rng.normal(size=(5, 4)), rng.normal(size=(70, 32))

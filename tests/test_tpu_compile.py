@@ -622,6 +622,44 @@ def test_a_selecting_attend_copies_no_array_of_the_pools_size(v5e, chip_seam, ca
     assert text.count("tpu_custom_call") >= 2        # the keys, then the attend
 
 
+SELECTING_ATTENDS = ["sparse-latent-decode", "sparse-latent-mixed",
+                     "sparse-latent-dots3-decode", "sparse-latent-dots3-mixed"]
+
+
+def _sorts_as_wide_as(text: str, width: int):
+    """The ``sort`` instructions of a compiled program with an operand
+    dimension of at least ``width``, as (name, dims)."""
+    found = re.findall(
+        r"^\s*(?:ROOT )?(\S+) = \(?\w+\[([\d,]+)\][^=]* sort\(", text, re.M)
+    return [(name, dims) for name, dims in found
+            if max(map(int, dims.split(","))) >= width]
+
+
+@pytest.mark.parametrize("case", SELECTING_ATTENDS)
+def test_a_selecting_attend_sorts_nothing_as_wide_as_its_context(v5e, chip_seam, case):
+    """ISSUE 57's tripwire. Until PR 57 ``dsa_select`` was ``lax.top_k``,
+    which the TPU's compiler lowers at this width to a full stable sort of
+    every query's scores with their indices (48 us a row of 37 376, 30% of
+    the agent cell's busy time: PERF.md section 6, PR 57). The selection
+    counts and compacts now: the compiled attend holds no ``sort`` over an
+    array as wide as the context."""
+    fn, build = CASES[case]
+    args = build(SingleDeviceSharding(v5e[0]))
+    context = args[3].shape[1] * 16
+    text = jax.jit(functools.partial(fn, chip_seam)).lower(*args).compile().as_text()
+    assert _sorts_as_wide_as(text, context) == []
+    assert text.count("tpu_custom_call") >= 2        # the keys, then the attend
+
+
+def test_the_reader_of_sorts_finds_the_one_lax_top_k_compiles_to(v5e):
+    """What the tripwire above looks for is there to be found: ``lax.top_k``
+    of 2 048 of a decode launch's 25 600 scores compiles for a v5e to a
+    ``sort`` as wide as the context."""
+    scores = jax.ShapeDtypeStruct((8, 25600), F32, sharding=SingleDeviceSharding(v5e[0]))
+    text = jax.jit(lambda x: jax.lax.top_k(x, 2048)[1]).lower(scores).compile().as_text()
+    assert _sorts_as_wide_as(text, 25600)
+
+
 def test_the_index_keys_unpack_moves_whole_registers(v5e, mosaic_dump):
     """The launch's program body by Mosaic's own dump, at the cell's decode
     shapes: a chunk of 1 024 tokens comes in as sublane-strided loads of
@@ -808,6 +846,33 @@ def test_the_ragged_and_the_latent_launch_lower_to_the_parents_text(
         fn = functools.partial(fn, chip_seam)
     text = jax.jit(fn).lower(*build(SingleDeviceSharding(v5e[0]))).as_text()
     assert _lowered_text_hash(text) == PARENT_KERNEL_TEXTS[case]
+
+
+# ... and of every other launch of this table at the PARENT of PR 57 (commit
+# 25a2f4e), the four selecting attends apart (``SELECTING_ATTENDS``: the
+# selection in front of their kernel is what PR 57 rewrote).
+PARENT_LAUNCH_TEXTS = json.loads(open(os.path.join(
+    os.path.dirname(__file__), "data", "launch_texts_pr56.json")).read())
+
+
+def test_every_launch_is_pinned_but_the_selecting_attends():
+    pinned = set(PARENT_KERNEL_TEXTS) | set(PARENT_LAUNCH_TEXTS)
+    assert set(CASES) - pinned == set(SELECTING_ATTENDS)
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_LAUNCH_TEXTS))
+def test_a_launch_without_a_selection_lowers_to_the_parents_text(
+        v5e, chip_seam, case):
+    """PR 57 rewrote ``dsa_select`` and nothing else: every launch of this
+    file that selects nothing (the families without an indexer, and the
+    indexer's own kernels taken alone) lowers to the text the parent lowered.
+    A PR that changes one on purpose re-records its hash (run
+    ``_lowered_text_hash`` in a checkout of its parent)."""
+    fn, build = CASES[case]
+    if getattr(fn, "asks_seam", False):
+        fn = functools.partial(fn, chip_seam)
+    text = jax.jit(fn).lower(*build(SingleDeviceSharding(v5e[0]))).as_text()
+    assert _lowered_text_hash(text) == PARENT_LAUNCH_TEXTS[case]
 
 
 def test_sharded_unified_compiles_on_tp4_mesh(v5e):
