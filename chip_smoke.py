@@ -553,6 +553,20 @@ def kernel_phase() -> Dict[str, Any]:
     return json.loads(result[0][len("KERNELS_OK "):])
 
 
+def _best_ms_a_launch(run, n: int, args) -> float:
+    """``run(*args)`` is one program of ``n`` chained launches, already
+    jitted: ms a launch, the best of three runs after the one that compiles."""
+    import jax
+
+    jax.block_until_ready(run(*args))
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(*args))
+        best = min(best, (time.perf_counter() - t0) / n)
+    return best * 1e3
+
+
 def selection_case() -> None:
     """ISSUE 57's step 0, for the next reader to repeat: the indexer's exact
     selection (``ops/attention.dsa_select``: a cut by counting, a list by
@@ -597,14 +611,7 @@ def selection_case() -> None:
         return jax.jit(run)
 
     def ms_a_launch(select, n, args):
-        run = chained(select, n)
-        jax.block_until_ready(run(*args))
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            jax.block_until_ready(run(*args))
-            best = min(best, (time.perf_counter() - t0) / n)
-        return best * 1e3
+        return _best_ms_a_launch(chained(select, n), n, args)
 
     for Q, T, n_chunk in ((2064, 37376, 2048), (528, 37376, 512), (16, 37376, 0),
                           (520, 25600, 512), (8, 25600, 0)):
@@ -631,6 +638,146 @@ def selection_case() -> None:
               f"ascending ({int(kept.sum())} positions); {t_new:.3f} ms a "
               f"launch, lax.top_k {t_sort:.3f} ms ({t_sort / t_new:.2f} x), "
               f"{t_new / Q * 1e3:.2f} us a row", flush=True)
+
+
+def index_scores_case() -> None:
+    """ISSUE 59's step 0, for the next reader to repeat: the indexer's scoring
+    alone (``ops/attention.dsa_index_scores``), head by head over ALL of a
+    chunk's queries as it ran until PR 59 (the scan carries the ``[Q, T]``
+    float32 sum through HBM, a read and a write of it a head) beside slabs of
+    512 and of 256 queries (a slab's sum stays on chip through the heads), at
+    the agent cell's two buckets and the width between (2 048, 1 024, 512
+    queries x 64 heads against 37 376 keys) and GLM's widest (512 x 32 against
+    25 600). The form is chosen by patching ``INDEX_SLAB_BYTES`` around the
+    trace, the way a test does; the slabbed scores are compared bit for bit
+    with the whole chunk's on the chip. Then a chunk launch's scores AND
+    selection at the first shape: ``dsa_select`` over the whole chunk's
+    scores, and inside the slab loop as the seam runs it (a slab's scores
+    never leave the chip for the counting passes), lists compared. Then the
+    seam's whole mixed attend (index keys, scores and selection a slab,
+    ``sparse_latent_attention`` with its staged pages) at 2 048 + 16 rows.
+    Launches chained in ONE program, ms a launch. What this case cannot see:
+    whether a slab stays on chip beside what else a STEP keeps there (slabs
+    of 512 rows do here and do not in the agent cell's step). Alone:
+    ``chiprun -- python -c "import chip_smoke;
+    chip_smoke.index_scores_case()"`` (PERF.md section 6, PR 59, has the
+    table)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops import attention as att
+    from dynamo_tpu.ops.paged_attention import PagedAttention
+    from dynamo_tpu.parallel.mesh import single_device_mesh
+
+    rng = np.random.default_rng(SEED)
+    bf = jnp.bfloat16
+    constant = att.INDEX_SLAB_BYTES
+
+    def rnd(*shape, dtype=bf):
+        return jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
+
+    def with_slab(rows, T, fn):
+        """``fn`` traced with slabs of ``rows`` queries (None: one slab)."""
+        def run(*a):
+            att.INDEX_SLAB_BYTES = 2 ** 62 if rows is None else rows * T * 4
+            try:
+                return fn(*a)
+            finally:
+                att.INDEX_SLAB_BYTES = constant
+        return run
+
+    def ms_a_launch(fn, n, args):
+        """``fn(iw, *rest) -> an array``: ``n`` launches, each one's weights
+        waiting for the one before (never changed, and the compiler cannot
+        know)."""
+        def run(iw, *rest):
+            def body(_, iw):
+                out = fn(iw, *rest)
+                return iw + (out.reshape(-1)[0] > 3e38).astype(iw.dtype)
+            return jax.lax.fori_loop(0, n, body, iw)
+        return _best_ms_a_launch(jax.jit(run), n, args)
+
+    forms = (("whole", None), ("slabs of 512", 512), ("slabs of 256", 256))
+    for Q, n, T in ((2048, 64, 37376), (1024, 64, 37376), (512, 64, 37376),
+                    (512, 32, 25600)):
+        iq, iw, keys = rnd(Q, n, 128), rnd(Q, n, dtype=jnp.float32), rnd(T, 128)
+        score = lambda iw, iq, keys: att.dsa_index_scores(iq, iw, keys)  # noqa: E731
+        whole = jax.jit(with_slab(None, T, score))(iw, iq, keys)
+        said = []
+        for name, rows in forms:
+            if rows is not None and rows >= Q:
+                continue                                 # one slab: the first form
+            fn = with_slab(rows, T, score)
+            if not bool(jnp.array_equal(jax.jit(fn)(iw, iq, keys), whole)):
+                raise SystemExit(f"dsa_index_scores [{Q}, {n}] x [{T}] in "
+                                 f"{name}: not the whole chunk's scores bit for bit")
+            said.append(f"{name} {ms_a_launch(fn, 4, (iw, iq, keys)):.3f} ms")
+        print(f"KERNEL dsa_index_scores [{Q}, {n}, 128] x [{T}, 128]: slabs bit "
+              f"for bit the whole chunk's; {', '.join(said)} "
+              f"(constant: slabs of {att.index_slab_rows(Q, T)})", flush=True)
+
+    # a chunk launch's scores and selection, the selection outside and inside
+    # the slab loop
+    Q, n, T, K = 2048, 64, 37376, 2048
+    iq, iw, keys = rnd(Q, n, 128), rnd(Q, n, dtype=jnp.float32), rnd(T, 128)
+    q_pos = jnp.asarray(T - Q - 611 + np.arange(Q), jnp.int32)
+    q_valid = jnp.asarray(np.arange(Q) < Q - 37)
+
+    def outside(iw, iq, keys):
+        return att.dsa_select(att.dsa_index_scores(iq, iw, keys), q_pos, q_valid, K)
+
+    def inside(rows):
+        return lambda iw, iq, keys: att.by_slabs(
+            lambda a, b, pos, valid: att.dsa_select(
+                att.dsa_index_scores(a, b, keys), pos, valid, K),
+            rows, iq, iw, q_pos, q_valid)
+
+    want = jax.jit(with_slab(None, T, outside))(iw, iq, keys)
+    said = []
+    for name, rows in forms:
+        ways = [("selection outside", with_slab(rows, T, outside))]
+        if rows is not None:
+            ways.append(("selection inside", with_slab(None, T, inside(rows))))
+        for way, fn in ways:
+            if not bool(jnp.array_equal(jax.jit(fn)(iw, iq, keys), want)):
+                raise SystemExit(f"scores and selection in {name}, {way}: not "
+                                 "the whole chunk's lists")
+            said.append(f"{name}, {way} {ms_a_launch(fn, 4, (iw, iq, keys)):.3f} ms")
+    print(f"KERNEL dsa_index_scores + dsa_select [{Q}, {n}, 128] x [{T}, 128] "
+          f"keep {K}: the same lists; {'; '.join(said)}", flush=True)
+
+    # the seam's mixed attend of a full layer of dots3-note at the agent
+    # cell's widest step: 2 048 chunk queries + 16 decode rows, 37 376 keys
+    R, MB, NB, H = 17, 2336, 37888, 128
+    seam = PagedAttention(single_device_mesh(), True)
+    Tq = Q + R - 1
+    q = rnd(Tq, H, 640)
+    kc, vc = rnd(NB, 16, 4, 128), rnd(NB, 16, 2, 128)
+    tables = jnp.asarray(np.stack(
+        [rng.permutation(np.arange(1, NB))[:MB] for _ in range(R)]), jnp.int32)
+    iq, iw = rnd(Tq, n, 128), rnd(Tq, n, dtype=jnp.float32)
+    q_starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                Q + jnp.arange(R - 1, dtype=jnp.int32)])
+    q_lens = jnp.concatenate([jnp.full((1,), Q, jnp.int32),
+                              jnp.ones((R - 1,), jnp.int32)])
+    seq_lens = jnp.asarray(np.r_[T - 611, rng.integers(T - 5000, T, R - 1)], jnp.int32)
+
+    def attend(iw, iq, q, kc, vc):
+        return seam.ragged(
+            q, kc, vc, tables, q_starts, q_lens, seq_lens,
+            dsa=att.DsaQuery(scale=1 / 16, topk=K, index_q=iq, index_w=iw))
+
+    args = (iw, iq, q, kc, vc)
+    want = jax.jit(with_slab(None, T, attend))(*args)
+    said = []
+    for name, rows in forms:
+        fn = with_slab(rows, T, attend)
+        if not bool(jnp.array_equal(jax.jit(fn)(*args), want)):
+            raise SystemExit(f"the mixed attend in {name}: not the whole chunk's output")
+        said.append(f"{name} {ms_a_launch(fn, 3, args):.3f} ms")
+    print(f"KERNEL mixed attend [{Q} + {R - 1} rows, {H} heads] x [{T}] keys, "
+          f"dots3-note's full layer: the same output; {', '.join(said)}", flush=True)
 
 
 def _kernel_child() -> None:

@@ -539,11 +539,71 @@ class LatentQuery:
     scope: str = "latent"
 
 
+# dsa_index_scores: the most bytes of one slab's float32 sum ``[rows, T]``, the
+# head scan's carry. Under it the v5e compiler keeps the carry on chip from the
+# first head to the last (``S(1)`` in the compiled text) and a head costs its
+# product; over it the carry lives in HBM and every head reads and writes all
+# of it (2 048 queries x 37 376 keys: 306 MB each way a head, 39 GB a call,
+# 59.1 ms on a v5e where slabs of 512 rows take 12.4 and of 256 13.4: PERF.md
+# section 6, PR 59). Alone, 512 rows x 37 376 keys (76.5 MB) stay on chip; IN
+# A STEP PROGRAM, beside what else the step keeps there, they do not (the agent
+# cell read 215 tokens/s at 80 MiB, 223 with no slabs, 287 at 64 MiB: 256 rows,
+# 38 MB). 64 MiB also leaves 512 queries x 25 600 keys (52.4 MB, GLM-5.2's
+# mixed step) the one slab they were.
+INDEX_SLAB_BYTES = 64 * 2 ** 20
+
+
+def index_slab_rows(Q: int, T: int) -> int:
+    """Queries a slab of a chunk's index scores: all ``Q`` while their float32
+    sum ``[Q, T]`` is at most ``INDEX_SLAB_BYTES``, else the largest power of
+    two not over ``Q`` whose sum is (8, a tile's rows, at the least)."""
+    if Q * T * 4 <= INDEX_SLAB_BYTES:
+        return Q
+    fit = max(8, INDEX_SLAB_BYTES // (4 * T))
+    return 1 << (min(fit, Q).bit_length() - 1)
+
+
+def by_slabs(fn, rows: int, *arrays: jax.Array) -> jax.Array:
+    """``fn`` of the arrays' leading ``Q`` rows, ``rows`` of them at a time,
+    one slab after the other; a last short slab is filled with zeros and its
+    filling cut from the result."""
+    Q = arrays[0].shape[0]
+    slabs = -(-Q // rows)
+    fill = slabs * rows - Q
+    if fill:
+        arrays = tuple(jnp.pad(a, ((0, fill),) + ((0, 0),) * (a.ndim - 1))
+                       for a in arrays)
+    out = jax.lax.map(
+        lambda slab: fn(*slab),
+        tuple(a.reshape(slabs, rows, *a.shape[1:]) for a in arrays),
+    )
+    out = out.reshape(slabs * rows, *out.shape[2:])
+    return out[:Q] if fill else out
+
+
+def _index_scores_head_by_head(iq: jax.Array, iw: jax.Array, keys: jax.Array) -> jax.Array:
+    def head(acc, j):
+        s = jnp.einsum("qd,td->qt", iq[:, j], keys,
+                       preferred_element_type=jnp.float32)
+        return acc + jax.nn.relu(s) * iw[:, j, None], None
+
+    zeros = jnp.zeros((iq.shape[0], keys.shape[0]), jnp.float32)
+    acc, _ = jax.lax.scan(head, zeros, jnp.arange(iq.shape[1]))
+    return acc
+
+
 def dsa_index_scores(iq: jax.Array, iw: jax.Array, keys: jax.Array) -> jax.Array:
     """``I[q, t] = sum_j iw[q, j] relu(iq[q, j] . keys[t])``: iq [Q, n, d],
     iw [Q, n] f32, keys [T, d] -> [Q, T] f32. All heads at once while the
-    per-head scores are small, else head by head (a chunk against a long
-    context: the [Q, n, T] scores would not fit)."""
+    per-head scores are small (the decode rows), else head by head, a SLAB of
+    ``index_slab_rows`` queries at a time (a chunk against a long context):
+    the float32 sum over the heads is then a slab's, small enough to stay on
+    chip through the heads, and is written once. (All heads at once would fit
+    at any size: the v5e compiler fuses the sum into the product with no
+    temporary. It is slower, 51.9 M cycles by the compiler's own model at
+    2 048 queries x 64 heads x 37 376 keys where eight slabs head by head are
+    21.1 M, which is why the head scan stays.) Rows are independent: the
+    slabs' scores are bit for bit one slab's."""
     Q, n, _ = iq.shape
     T = keys.shape[0]
     if Q * n * T * 4 <= 2 ** 28:
@@ -552,14 +612,11 @@ def dsa_index_scores(iq: jax.Array, iw: jax.Array, keys: jax.Array) -> jax.Array
         # a float32 sum, not a product on the matrix unit (which would round
         # the scores to bf16 first and move the cut)
         return jnp.sum(jax.nn.relu(s) * iw[:, :, None], axis=1)
-
-    def head(acc, j):
-        s = jnp.einsum("qd,td->qt", iq[:, j], keys,
-                       preferred_element_type=jnp.float32)
-        return acc + jax.nn.relu(s) * iw[:, j, None], None
-
-    acc, _ = jax.lax.scan(head, jnp.zeros((Q, T), jnp.float32), jnp.arange(n))
-    return acc
+    rows = index_slab_rows(Q, T)
+    if rows >= Q:
+        return _index_scores_head_by_head(iq, iw, keys)
+    return by_slabs(lambda a, b: _index_scores_head_by_head(a, b, keys),
+                    rows, iq, iw)
 
 
 # dsa_select: positions a block of the list's compaction (four 32-bit words

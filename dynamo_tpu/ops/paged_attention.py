@@ -200,21 +200,44 @@ class PagedAttention:
                     )
                 else:
                     keys = att.paged_index_keys(vc, tables, iq.shape[-1])
-                parts = []
-                if n_chunk:
-                    parts.append(att.dsa_index_scores(
-                        iq[:n_chunk], iw[:n_chunk], keys[0]
-                    ))
-                if Tq > n_chunk:
-                    one = lambda a, b, k: att.dsa_index_scores(  # noqa: E731
-                        a[None], b[None], k
-                    )[0]
-                    parts.append(jax.vmap(one)(
+
+            def chunk_scores(a, b):
+                with jax.named_scope(f"{dsa.scope}_index"):
+                    return att.dsa_index_scores(a, b, keys[0])
+
+            def row_scores():
+                one = lambda a, b, k: att.dsa_index_scores(  # noqa: E731
+                    a[None], b[None], k
+                )[0]
+                with jax.named_scope(f"{dsa.scope}_index"):
+                    return jax.vmap(one)(
                         iq[n_chunk:], iw[n_chunk:], keys[bool(n_chunk):]
-                    ))
-                scores = jnp.concatenate(parts, axis=0)
-            with jax.named_scope(f"{dsa.scope}_select"):
-                dsa.selected = att.dsa_select(scores, q_pos, q_valid, dsa.topk)
+                    )
+
+            def select(scores, pos, valid):
+                with jax.named_scope(f"{dsa.scope}_select"):
+                    return att.dsa_select(scores, pos, valid, dsa.topk)
+
+            slab = att.index_slab_rows(n_chunk, keys.shape[1]) if n_chunk else 0
+            if slab == n_chunk:
+                scores = []
+                if n_chunk:
+                    scores.append(chunk_scores(iq[:n_chunk], iw[:n_chunk]))
+                if Tq > n_chunk:
+                    scores.append(row_scores())
+                dsa.selected = select(jnp.concatenate(scores, axis=0), q_pos, q_valid)
+            else:
+                # a chunk wider than a slab of its index scores: a slab's
+                # lists are read out of its scores while those are on chip
+                # (the chunk's scores never lie in HBM); the decode rows' apart
+                parts = [att.by_slabs(
+                    lambda a, b, pos, valid: select(chunk_scores(a, b), pos, valid),
+                    slab, iq[:n_chunk], iw[:n_chunk], q_pos[:n_chunk],
+                    q_valid[:n_chunk],
+                )]
+                if Tq > n_chunk:
+                    parts.append(select(row_scores(), q_pos[n_chunk:], q_valid[n_chunk:]))
+                dsa.selected = jnp.concatenate(parts, axis=0)
         with jax.named_scope("sparse_attend"):
             if not self.use_pallas:
                 return att.sparse_latent_attention(

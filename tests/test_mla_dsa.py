@@ -550,13 +550,92 @@ def test_exact_top_k_of_the_causal_scores(name):
         assert (got[len(want):] == att.SEL_NONE).all()
 
 
-def test_the_index_scores_head_by_head_are_the_scores_of_all_heads_at_once():
-    # head by head (a chunk against a long context) scores as all heads at once
+@pytest.mark.parametrize("Q,n,d,T,rows,slabs", [
+    (5, 4, 32, 70, None, 0),            # all heads at once (the decode rows' branch)
+    (48, 64, 16, 22016, 64, 1),         # under one slab: head by head, no outer loop
+    (48, 64, 16, 22016, 16, 3),         # a multiple of the slab
+    (40, 64, 16, 27008, 16, 3),         # not a multiple: a last slab padded
+    (24, 128, 16, 22016, 16, 2),        # ... and the slab the largest power of two under Q
+    (48, 32, 16, 44032, 64, 1),         # GLM's head count
+    (48, 32, 16, 44032, 16, 3),
+    (36, 32, 16, 60032, 8, 5),
+], ids=lambda v: str(v))
+def test_the_index_scores_head_by_head_are_the_scores_of_all_heads_at_once(
+        monkeypatch, Q, n, d, T, rows, slabs):
+    """``dsa_index_scores`` against the float64 sum over all heads at once,
+    whichever way it goes: all heads at once, head by head over the whole
+    chunk, head by head a slab of queries at a time (``INDEX_SLAB_BYTES``
+    patched so that a CPU shape slabs). Rows are independent: the slabbed
+    scores are the one-slab scores BIT FOR BIT."""
     rng = np.random.default_rng(1)
-    iq, iw, keys = rng.normal(size=(5, 4, 32)), rng.normal(size=(5, 4)), rng.normal(size=(70, 32))
-    want = np.einsum("qjt,qj->qt", np.maximum(np.einsum("qjd,td->qjt", iq, keys), 0), iw)
-    got = att.dsa_index_scores(jnp.asarray(iq, jnp.float32), jnp.asarray(iw, jnp.float32), jnp.asarray(keys, jnp.float32))
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+    iq, iw, keys = rng.normal(size=(Q, n, d)), rng.normal(size=(Q, n)), rng.normal(size=(T, d))
+    want = np.zeros((Q, T))
+    for j in range(n):
+        want += np.maximum(iq[:, j] @ keys.T, 0) * iw[:, j, None]
+    args = [jnp.asarray(a, jnp.float32) for a in (iq, iw, keys)]
+    if rows is not None:
+        assert Q * n * T * 4 > 2 ** 28               # past the all-heads branch
+        monkeypatch.setattr(att, "INDEX_SLAB_BYTES", rows * T * 4)
+        assert att.index_slab_rows(Q, T) == (Q if slabs == 1 else rows)
+
+    def scores():
+        # a function of its own a trace: JAX keeps a trace by the function
+        # and the shapes, and would not see the constant move
+        return lambda *a: att.dsa_index_scores(*a)
+
+    # a head scan, and around it the slab loop where there are slabs
+    assert str(jax.make_jaxpr(scores())(*args)).count("scan[") == min(slabs, 2)
+    got = np.asarray(jax.jit(scores())(*args))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    if slabs > 1:
+        monkeypatch.setattr(att, "INDEX_SLAB_BYTES", Q * T * 4)
+        np.testing.assert_array_equal(got, np.asarray(jax.jit(scores())(*args)))
+
+
+@pytest.mark.parametrize("kind,n_chunk,rows", [
+    ("ragged", 96, 32),       # a mixed step: three slabs, then two decode rows
+    ("ragged", 80, 32),       # ... the last slab short
+    ("chunk", 96, 64),        # a lone chunk: a slab and a short one
+])
+def test_a_chunk_wider_than_a_slab_selects_the_whole_chunks_lists(monkeypatch, kind, n_chunk, rows):
+    """Through the seam, a chunk whose index scores are over
+    ``INDEX_SLAB_BYTES`` is scored AND selected a slab at a time (the chunk's
+    scores are never one array), the decode rows beside it apart: the lists
+    are ``dsa_select``'s over the whole chunk's scores row by row, and the
+    attend's output with them bit for bit."""
+    seam = PagedAttention(make_mesh(tp=1, devices=jax.devices()[:1]), False)
+    mb, nb, n = 2064, 2100, 64                    # 33 024 keys: a slab too is past all heads at once
+    rng, vc = _index_pool(nb, seed=5)
+    kc = jnp.asarray(rng.normal(size=(nb, BS, 4, 128)), jnp.bfloat16)
+    tables = jnp.asarray(np.stack([rng.permutation(np.arange(1, nb))[:mb] for _ in range(3)]), jnp.int32)
+    Tq = n_chunk + (2 if kind == "ragged" else 0)
+    q = jnp.asarray(rng.normal(size=(Tq, H, 2 * RANK + 128)), jnp.bfloat16)
+    iq = jnp.asarray(rng.normal(size=(Tq, n, 32)), jnp.bfloat16)
+    iw = jnp.asarray(rng.normal(size=(Tq, n)), jnp.float32)
+    start = mb * BS - 300
+    if kind == "ragged":
+        args = (tables, jnp.asarray([0, n_chunk, n_chunk + 1], jnp.int32),
+                jnp.asarray([n_chunk - 3, 1, 1], jnp.int32),
+                jnp.asarray([start + n_chunk - 3, 9000, 17], jnp.int32))
+    else:
+        args = (tables[0], jnp.int32(start), jnp.int32(start + n_chunk - 3),
+                start + jnp.arange(n_chunk, dtype=jnp.int32))
+    assert rows * n * mb * BS * 4 > 2 ** 28
+
+    def ask(slab_rows):
+        monkeypatch.setattr(att, "INDEX_SLAB_BYTES", slab_rows * mb * BS * 4)
+        dsa = att.DsaQuery(scale=0.125, topk=TOPK, index_q=iq, index_w=iw)
+        fn = lambda q, kc, vc: getattr(seam, kind)(q, kc, vc, *args, dsa=dsa)  # noqa: E731
+        scans = str(jax.make_jaxpr(fn)(q, kc, vc)).count("scan[")
+        dsa.selected = None
+        return np.asarray(fn(q, kc, vc), np.float32), np.asarray(dsa.selected), scans
+
+    whole, slabbed = ask(n_chunk), ask(rows)
+    assert slabbed[2] > whole[2]                          # the slab loop is there
+    assert (whole[1][:n_chunk - 3] >= 0).sum() == (n_chunk - 3) * TOPK
+    assert (whole[1][n_chunk - 3:n_chunk] == att.SEL_NONE).all()      # the chunk's padding
+    np.testing.assert_array_equal(slabbed[1], whole[1])
+    np.testing.assert_array_equal(slabbed[0], whole[0])
 
 
 def test_bf16_index_scores_move_a_few_keys_across_the_cut():

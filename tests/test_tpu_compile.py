@@ -477,6 +477,8 @@ def _cases():
         # ... and its full layers' selection at 128 heads and 64 index heads
         "sparse-latent-dots3-mixed": (sparse_mixed, dots3_sparse(528, 17)),
         "sparse-latent-dots3-decode": (sparse_decode, dots3_sparse(16, 16)),
+        # the agent cell's widest step: a chunk of 2 048 queries + 16 rows
+        "sparse-latent-dots3-chunk": (sparse_mixed, dots3_sparse(2064, 17)),
         # the wide-chat cell (PR 39): 20 q / 4 kv heads, FIVE query heads a kv
         # head (every other cell has a power of two), 128 rows over tables of
         # 82 pages; a 512-token chunk beside them; and the recurrence's launch
@@ -623,7 +625,8 @@ def test_a_selecting_attend_copies_no_array_of_the_pools_size(v5e, chip_seam, ca
 
 
 SELECTING_ATTENDS = ["sparse-latent-decode", "sparse-latent-mixed",
-                     "sparse-latent-dots3-decode", "sparse-latent-dots3-mixed"]
+                     "sparse-latent-dots3-decode", "sparse-latent-dots3-mixed",
+                     "sparse-latent-dots3-chunk"]
 
 
 def _sorts_as_wide_as(text: str, width: int):
@@ -649,6 +652,59 @@ def test_a_selecting_attend_sorts_nothing_as_wide_as_its_context(v5e, chip_seam,
     text = jax.jit(functools.partial(fn, chip_seam)).lower(*args).compile().as_text()
     assert _sorts_as_wide_as(text, context) == []
     assert text.count("tpu_custom_call") >= 2        # the keys, then the attend
+
+
+def _loop_carried(text: str, dtype: str = "f32"):
+    """The arrays of ``dtype`` that the ``while`` loops of a compiled program
+    carry, as (dims, on chip): ``S(1)`` in a layout is the compiler's on-chip
+    memory space, no ``S`` is HBM."""
+    found = []
+    for carry in re.findall(r"^\s*\S+ = \((.*)\) while\(", text, re.M):
+        for dims, layout in re.findall(dtype + r"\[([\d,]+)\]\{([^}]*)\}", carry):
+            found.append((tuple(map(int, dims.split(","))), "S(1)" in layout))
+    return found
+
+
+def test_a_chunk_of_2048_queries_carries_its_index_sum_a_slab_on_chip(v5e, chip_seam):
+    """ISSUE 59's tripwire. Until PR 59 the head scan of ``dsa_index_scores``
+    carried the float32 sum of ALL of a chunk's queries: at the agent cell's
+    widest step (2 048 + 16 rows behind 37 376 keys) ``f32[2048,37376]``, which
+    the compiler leaves in HBM, so a head read and wrote 306 MB each way (39 GB
+    a call, two calls a step: PERF.md section 6, PR 59). The compiled attend
+    now carries a SLAB's sum through the heads, on chip, counts the slab's
+    ordered keys there too (``dsa_select`` inside the slab loop), and holds
+    no array of the whole chunk's scores at all."""
+    rows = att.index_slab_rows(2048, 37376)
+    assert rows < 2048
+    fn, build = CASES["sparse-latent-dots3-chunk"]
+    text = jax.jit(functools.partial(fn, chip_seam)).lower(
+        *build(SingleDeviceSharding(v5e[0]))).compile().as_text()
+    for scores in ("2048,37376", "2064,37376", "37376,2048", "37376,2064",
+                   f"{2048 // rows},{rows},37376"):
+        assert f"[{scores}]" not in text, scores
+    sums, ordered_keys = _loop_carried(text), _loop_carried(text, "u32")
+    assert ((rows, 37376), True) in sums and ((rows, 37376), False) not in sums, sums
+    assert ((37376, rows), True) in ordered_keys, ordered_keys
+
+
+def test_the_reader_of_carries_finds_the_whole_chunks_sum_in_hbm(v5e, monkeypatch):
+    """What the tripwire above looks for is there to be found: the scores of
+    2 048 queries as ONE slab compile for a v5e to a head scan whose carry,
+    ``f32[2048,37376]``, is not on chip; GLM's mixed step (512 queries behind
+    25 600 keys, one slab by the constant as it stands) carries its sum there."""
+    sh = SingleDeviceSharding(v5e[0])
+
+    def carried(Q, n, T):
+        args = (jax.ShapeDtypeStruct((Q, n, 128), BF, sharding=sh),
+                jax.ShapeDtypeStruct((Q, n), F32, sharding=sh),
+                jax.ShapeDtypeStruct((T, 128), BF, sharding=sh))
+        fn = lambda *a: att.dsa_index_scores(*a)         # noqa: E731  a trace of its own
+        return _loop_carried(jax.jit(fn).lower(*args).compile().as_text())
+
+    assert att.index_slab_rows(512, 25600) == 512
+    assert ((512, 25600), True) in carried(512, 32, 25600)
+    monkeypatch.setattr(att, "INDEX_SLAB_BYTES", 2048 * 37376 * 4)
+    assert ((2048, 37376), False) in carried(2048, 64, 37376)
 
 
 def test_the_reader_of_sorts_finds_the_one_lax_top_k_compiles_to(v5e):
@@ -831,6 +887,14 @@ def _lowered_text_hash(text: str) -> str:
     return hashlib.sha256("\n".join(parts).encode()).hexdigest()
 
 
+def _lowered_hash_of(v5e, chip_seam, case: str) -> str:
+    fn, build = CASES[case]
+    if getattr(fn, "asks_seam", False):
+        fn = functools.partial(fn, chip_seam)
+    text = jax.jit(fn).lower(*build(SingleDeviceSharding(v5e[0]))).as_text()
+    return _lowered_text_hash(text)
+
+
 @pytest.mark.parametrize("case", sorted(PARENT_KERNEL_TEXTS))
 def test_the_ragged_and_the_latent_launch_lower_to_the_parents_text(
         v5e, chip_seam, case):
@@ -841,11 +905,7 @@ def test_the_ragged_and_the_latent_launch_lower_to_the_parents_text(
     parent lowered. The three latent launches' hashes are PR 55's (the second
     array's first tile alone is copied): re-recorded on purpose, every other
     entry PR 49's."""
-    fn, build = CASES[case]
-    if getattr(fn, "asks_seam", False):
-        fn = functools.partial(fn, chip_seam)
-    text = jax.jit(fn).lower(*build(SingleDeviceSharding(v5e[0]))).as_text()
-    assert _lowered_text_hash(text) == PARENT_KERNEL_TEXTS[case]
+    assert _lowered_hash_of(v5e, chip_seam, case) == PARENT_KERNEL_TEXTS[case]
 
 
 # ... and of every other launch of this table at the PARENT of PR 57 (commit
@@ -868,11 +928,29 @@ def test_a_launch_without_a_selection_lowers_to_the_parents_text(
     indexer's own kernels taken alone) lowers to the text the parent lowered.
     A PR that changes one on purpose re-records its hash (run
     ``_lowered_text_hash`` in a checkout of its parent)."""
-    fn, build = CASES[case]
-    if getattr(fn, "asks_seam", False):
-        fn = functools.partial(fn, chip_seam)
-    text = jax.jit(fn).lower(*build(SingleDeviceSharding(v5e[0]))).as_text()
-    assert _lowered_text_hash(text) == PARENT_LAUNCH_TEXTS[case]
+    assert _lowered_hash_of(v5e, chip_seam, case) == PARENT_LAUNCH_TEXTS[case]
+
+
+# ... and of the selecting attends whose chunk is ONE slab of
+# ``dsa_index_scores`` at the PARENT of PR 59 (commit 5a2699e): PR 59 scores
+# and selects a slab at a time the chunks whose float32 sum is over
+# ``INDEX_SLAB_BYTES`` and changes nothing else, so GLM's mixed step (512
+# queries behind 25 600 keys, 52.4 MB) and the decode rows of both models keep
+# the program they had. dots3-note's two buckets slab (``-dots3-mixed``: 512
+# queries behind 37 376 keys are two slabs of 256; ``-dots3-chunk``: eight).
+PARENT_SELECTING_TEXTS = json.loads(open(os.path.join(
+    os.path.dirname(__file__), "data", "selecting_texts_pr58.json")).read())
+
+
+def test_every_selecting_attend_is_pinned_but_the_two_that_slab():
+    assert set(SELECTING_ATTENDS) - set(PARENT_SELECTING_TEXTS) == {
+        "sparse-latent-dots3-mixed", "sparse-latent-dots3-chunk"}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_SELECTING_TEXTS))
+def test_a_selecting_attend_of_one_slab_lowers_to_the_parents_text(
+        v5e, chip_seam, case):
+    assert _lowered_hash_of(v5e, chip_seam, case) == PARENT_SELECTING_TEXTS[case]
 
 
 def test_sharded_unified_compiles_on_tp4_mesh(v5e):
