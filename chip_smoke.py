@@ -1292,6 +1292,11 @@ def _kernel_child() -> None:
     decode_runs_case(
         "paged_decode_attention 32 q / 4 kv heads, 16 rows over 6.5k keys",
         32, 4, 448, [6500 - 37 * i for i in range(14)] + [1, 7168])
+    # the looped decoder's cell (PR 60): ONE query head a kv head, which no
+    # other cell runs, 64 KiB pages, 8 rows at about 600 keys
+    decode_runs_case(
+        "paged_decode_attention 16 q / 16 kv heads (1 a group), 8 rows at 600 keys",
+        16, 16, 42, [600, 599, 0, 656, 257, 512, 1, 672])
 
     # the state-space mixer's decode recurrence (PR 39) at Falcon-H1-34B's
     # widths, 128 rows of which some are dead: the state in place, live rows

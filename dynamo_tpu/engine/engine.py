@@ -302,8 +302,11 @@ def _model_param_bytes(mcfg) -> int:
     total = sum(
         leaf_bytes(name, x) for name, x in shapes.items() if name != "layers"
     )
+    # a stack run several times a token (a slot a (pass, layer)) reads its
+    # layers once a pass: a layer does not stay on chip between them
+    passes = registry.page_passes(mcfg)
     for layer in shapes["layers"]:
-        total += sum(leaf_bytes(name, x) for name, x in layer.items())
+        total += passes * sum(leaf_bytes(name, x) for name, x in layer.items())
     return int(total)
 
 
@@ -1107,8 +1110,10 @@ class TpuEngine:
         register_llm advertises for transfer-aware disagg routing)."""
         from ..kvbm.layout import kv_bytes_per_token
 
-        # the layout counts every layer; only page_layers hold pages
-        held = len(registry.page_layers(self.mcfg)) / self.mcfg.num_layers
+        # the layout counts num_layers pages a block; a block holds a page
+        # a SLOT (registry.page_slots: fewer than the layers where only some
+        # keep pages, more where a (pass, layer) keeps its own)
+        held = registry.page_slots(self.mcfg) / self.mcfg.num_layers
         return int(
             kv_bytes_per_token(self.mcfg, self.cfg.block_size, self.cfg.kv_dtype)
             * self.cfg.block_size * held
@@ -1218,6 +1223,9 @@ class TpuEngine:
             # one pooled key a block id, a row each of the K pool's pages
             # above the requests' (ops/attention.py, InfLlmQuery)
             pages += att.infllm_pool_pages(pages, self.cfg.block_size)
+        # a slot a (pass, layer): a layer's arrays hold a pool a pass, one
+        # behind another, and a block id names its page in each
+        pages *= registry.page_passes(mcfg)
         shape = (
             pages,
             self.cfg.block_size,
@@ -1229,7 +1237,8 @@ class TpuEngine:
             self.mesh, registry.kv_cache_spec(mcfg, tp_n)
         )
         # one pair of arrays a layer that KEEPS pages (registry.page_layers:
-        # every layer, but for a family whose layers are of different kinds)
+        # every layer, but for a family whose layers are of different kinds),
+        # whatever the slots of pages each holds (registry.page_slots)
         n_paged = len(registry.page_layers(mcfg))
         # pages by layer kind: a windowed group's layers have its pool's pages
         # (int8 and a draft's shadow cache are refused more than one group)
@@ -1360,15 +1369,49 @@ class TpuEngine:
         state_of = registry.layer_index(
             registry.state_layers(mcfg), mcfg.num_layers
         ) if self.state is not None else None
-        # page layer -> pages a chunk, for the layers whose decode rows the
+        # a slot a (pass, layer): the family's forward runs its stack
+        # ``passes`` times and hands ``attend`` the pass (``page_pass``); 1,
+        # and no ``page_pass``, for every other family
+        passes = registry.page_passes(mcfg)
+
+        def at_pass(page_pass, *ids):
+            """Block ids as pass ``page_pass`` holds them: ``page_pass x
+            num_blocks`` further on in the layer's arrays (the scratch page
+            0 too: every pass has its own), a run of pages still a run; as
+            they came where there is no pass (every other family)."""
+            if page_pass is None:
+                return ids
+            return tuple(i + page_pass * cfg.num_blocks for i in ids)
+
+        def pass_loop(k_caches, v_caches):
+            """``loop`` of a forward that runs its stack several times a
+            token: the passes as ONE traced loop with the page arrays on its
+            carry (``attend`` reads and writes the lists in place, as in a
+            stack run once), so a program holds the layers' bodies once."""
+            def loop(one_pass, x, n):
+                def step(t, carry):
+                    x, k_caches[:], v_caches[:] = carry
+                    x = one_pass(x, t)
+                    return x, list(k_caches), list(v_caches)
+
+                x, k_caches[:], v_caches[:] = jax.lax.fori_loop(
+                    0, n, step, (x, list(k_caches), list(v_caches))
+                )
+                return x
+            return loop
+
+        # page slot -> pages a chunk, for the layers whose decode rows the
         # decode-only kernel serves: filled as ``rows_attend`` is traced,
         # read by ``_count_paged``
         paged_layers = self._paged_layers = {}
         self._paged_counts = [0, 0]
 
         def call_fwd(params, tokens, positions, attend, lora_tables, lora_ids,
-                     mm_embeds=None, mm_mask=None, moe_stats=None, mix=None):
+                     mm_embeds=None, mm_mask=None, moe_stats=None, mix=None,
+                     caches=None):
             kw = {}
+            if passes > 1 and caches is not None:
+                kw["loop"] = pass_loop(*caches)
             if page_of is not None:
                 # a family whose layers are of different kinds: a layer's
                 # pages by its place among page_layers
@@ -1538,13 +1581,14 @@ class TpuEngine:
                         write_blocks, write_offsets):
             """``attend`` of decode rows ([B, 1, ...] a layer): the fed
             token's KV written, then attended at the end of its context."""
-            def attend(q, k_new, v_new, layer_idx, **extra):
+            def attend(q, k_new, v_new, layer_idx, page_pass=None, **extra):
                 # the rows as the layer's own page group holds them; a
                 # family of one group: as they came
                 tables, lens, pages = (
                     views[layer_idx].rows(block_tables, seq_lens, write_blocks > 0)
                     if views else (block_tables, seq_lens, write_blocks)
                 )
+                tables, pages = at_pass(page_pass, tables, pages)
                 kc, vc = att.write_decode_kv(
                     k_caches[layer_idx], v_caches[layer_idx],
                     k_new[:, 0], v_new[:, 0], pages, write_offsets,
@@ -1566,7 +1610,11 @@ class TpuEngine:
                 if cp is not None:
                     # known once traced: this layer's decode rows are the
                     # decode-only kernel's, which walks chunks of ``cp`` pages
-                    paged_layers[layer_idx] = cp
+                    # (a launch a SLOT where a layer keeps one a pass)
+                    paged_layers.update(
+                        {layer_idx: cp} if page_pass is None
+                        else {(layer_idx, t): cp for t in range(passes)}
+                    )
                 out = attn.decode(q[:, 0], kc, vc, tables, lens, **extra)
                 return out[:, None]
             return attend
@@ -1584,7 +1632,7 @@ class TpuEngine:
             it fits a bucket): its pages written, its rows attended over the
             prefix and the chunk."""
 
-            def attend(q, k_new, v_new, layer_idx, **extra):
+            def attend(q, k_new, v_new, layer_idx, page_pass=None, **extra):
                 # extra: per-layer attention variants the model opts into
                 # (sliding ``window``, per-head ``sinks`` — models/gptoss.py);
                 # plain families pass nothing and nothing changes
@@ -1599,6 +1647,7 @@ class TpuEngine:
                         new_block_ids,
                     )
                 )
+                table, ids = at_pass(page_pass, table, ids)
                 kc, vc = attn.write_chunk(
                     k_caches[layer_idx], v_caches[layer_idx],
                     *real_rows(k_new, v_new, positions, total_len), ids,
@@ -1631,6 +1680,7 @@ class TpuEngine:
             return call_fwd(
                 params, tokens, positions, attend, lora_tables, lora_id,
                 mm_embeds=mm_embeds, mm_mask=mm_mask, mix=mix,
+                caches=(k_caches, v_caches),
             )
 
         def rows_body(params, k_caches, v_caches, tokens, positions,
@@ -1648,6 +1698,7 @@ class TpuEngine:
                 mix=rows_mix(
                     params, state, seq_lens > 0 if live is None else live
                 ) if state else None,
+                caches=(k_caches, v_caches),
             )[:, 0]  # [B, H]
 
         def embed_body(params, tokens, positions):
@@ -1655,7 +1706,8 @@ class TpuEngine:
             positions can't affect earlier queries (causal)."""
 
             def attend(q, k_new, v_new, layer_idx, eva=None, infllm=None,
-                       **extra):
+                       page_pass=None, **extra):
+                # ``page_pass``: no pages here, every pass attends its own
                 if eva is not None:  # a whole sequence from nothing
                     return att.eva_attention(q, k_new, v_new, eva)
                 if infllm is not None:
@@ -1671,21 +1723,21 @@ class TpuEngine:
             past the largest bucket, into TEMPORARY pages; the draft's share
             of a prompt): its KV written, attended over the gathered prefix."""
 
-            def attend(q, k_new, v_new, layer_idx, **extra):
+            def attend(q, k_new, v_new, layer_idx, page_pass=None, **extra):
+                table, ids = at_pass(page_pass, block_table, new_block_ids)
                 kc, vc = att.write_prefill_kv(
                     k_caches[layer_idx], v_caches[layer_idx],
-                    *real_rows(k_new, v_new, positions, total_len),
-                    new_block_ids,
+                    *real_rows(k_new, v_new, positions, total_len), ids,
                 )
                 if "infllm" in extra:
                     kc = seam.pool_chunk(
-                        kc, k_new, block_table, positions[0], total_len,
+                        kc, k_new, table, positions[0], total_len,
                         extra["infllm"],
                     )
                 k_caches[layer_idx], v_caches[layer_idx] = kc, vc
                 # a chunk's first token is real: its position is the start
                 return seam.chunk(
-                    q, kc, vc, block_table, positions[0], total_len,
+                    q, kc, vc, table, positions[0], total_len,
                     positions, **extra
                 )
 
@@ -1868,7 +1920,7 @@ class TpuEngine:
             positions = jnp.concatenate([c_positions, a.positions])
             active = a.seq_lens > 0
 
-            def attend(q, k_new, v_new, layer_idx, **extra):
+            def attend(q, k_new, v_new, layer_idx, page_pass=None, **extra):
                 # extra: per-layer attention variants (sliding window,
                 # per-head sinks, softcap — gpt-oss/gemma) thread straight
                 # into the unified launch as per-row attributes. The chunk's
@@ -1886,6 +1938,9 @@ class TpuEngine:
                         table, a.chunk_start, c_end, c_positions, ids
                     )
                     rows, lens, pages = view.rows(rows, lens, pages > 0)
+                table, ids, rows, pages = at_pass(
+                    page_pass, table, ids, rows, pages
+                )
                 kc, vc = attn.write_chunk(
                     k_caches[layer_idx], v_caches[layer_idx],
                     *real_rows(
@@ -1967,6 +2022,7 @@ class TpuEngine:
             hidden = call_fwd(
                 params, tokens, positions, attend, lora_tables,
                 packed_lora_ids, moe_stats=moe_stats, mix=mix,
+                caches=(k_caches, v_caches),
             )  # [S_pad + B, H]
 
             # the decode rows' tail, as decode()

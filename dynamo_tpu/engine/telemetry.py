@@ -486,6 +486,15 @@ class StepStats:
     winlat_keys_read: Optional[int] = None
     winlat_rows: Optional[int] = None
     winlat_chunk_tokens: Optional[int] = None
+    # a family that runs its stack several times a token and keeps a cache
+    # slot a (pass, layer) (models/ouro.py), on the same readback (decode and
+    # mixed steps): the real tokens that entered the stack (a chunk's and the
+    # decode rows), the same tokens counted once a pass they went through,
+    # and the keys the decode rows' attention read, summed over rows and
+    # slots. None elsewhere
+    ouro_stack_tokens: Optional[int] = None
+    ouro_pass_tokens: Optional[int] = None
+    ouro_slot_keys_read: Optional[int] = None
     # host-to-device placements the dispatches made since the last StepStats
     # (engine _upload / _dev): host values handed to a jitted call, one
     # transfer each, and per-slot arrays placed again because they changed.
@@ -717,6 +726,13 @@ class EngineTelemetry:
                 name: sum(getattr(s, f"eva_{name}") or 0 for s in recent)
                 for name in ("rows_attended", "window_keys", "summaries_read",
                              "windows_closed", "decode_steps")
+            }
+        if any(s.ouro_stack_tokens for s in recent):
+            # a stack run several times a token: what went through its
+            # passes, and what the decode rows read of its slots
+            out["ouro"] = {
+                name: sum(getattr(s, f"ouro_{name}") or 0 for s in recent)
+                for name in ("stack_tokens", "pass_tokens", "slot_keys_read")
             }
         if last is not None and last.page_groups_held is not None:
             # pages by layer kind: what the live rows hold a group, and what

@@ -158,7 +158,13 @@ def block_shape_for(mcfg, block_size: int, kv_dtype: str = "model") -> BlockShap
     """THE constructor for KV block shapes: storage dtype comes from the
     model config (bf16 models store bf16 blocks), or int8 for the quantized
     cache. Allocating a KV buffer with a raw np.float32 elsewhere is a lint
-    finding (tools/lint.py KV-DTYPE)."""
+    finding (tools/lint.py KV-DTYPE). ``num_layers`` here is the model's
+    LAYERS, one page each: a family whose block holds another number of
+    SLOTS of pages (``models/registry.page_slots``: fewer where only some
+    layers keep pages, more where a (pass, layer) keeps its own) is refused
+    the tiers and the transfer plane where the gather would miss slots
+    (``registry._WHY``), and ``engine.kv_bytes_per_block`` scales these bytes
+    by ``page_slots / num_layers``."""
     dtype = np.dtype(np.int8) if kv_dtype == "int8" else np.dtype(mcfg.dtype)
     return BlockShape(
         num_layers=mcfg.num_layers,

@@ -33,15 +33,20 @@ def begin(cfg):
     return {"layers": layers}, layers, lambda arr: jnp.asarray(arr, cfg.dtype)
 
 
-def layer_tensors(path: str, params, put, strip: str = ""):
+def layer_tensors(path: str, params, put, strip: str = "", top=None):
     """Yields ``(layer, name under model.layers.N, array)`` for a loader to
     place; the embedding, the final norm and the head are placed here (HF
     stores a Linear as [out, in]; ours are [in, out]), and a tensor that is
     none of these is passed over. ``strip``: a prefix some checkpoints put
-    before every name."""
+    before every name. ``top``: further tensors outside the layers that a
+    family names, ``name -> (ours, transpose)``."""
+    top = top or {}
     for name, w in open_safetensors(path):
         name = name.removeprefix(strip)
-        if name == "model.embed_tokens.weight":
+        if name in top:
+            ours, transpose = top[name]
+            params[ours] = put(w.T if transpose else w)
+        elif name == "model.embed_tokens.weight":
             params["embed"] = put(w)
         elif name == "model.norm.weight":
             params["final_norm"] = put(w)

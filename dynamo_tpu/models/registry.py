@@ -15,7 +15,7 @@ from ..parallel import mesh as meshlib
 from ..parallel.mesh import AXIS_TP
 from . import (
     cohere2_moe, dots3_note, evabyte, falcon_h1, gemma, gptoss, llama,
-    minicpm_sala, mla, moe, solar_open2,
+    minicpm_sala, mla, moe, ouro, solar_open2,
 )
 
 # Every family, once. A module names its configuration class (``CONFIG``),
@@ -24,7 +24,7 @@ from . import (
 # the dense family needs (docs/architecture.md "Adding a family").
 FAMILIES = (
     llama, moe, mla, gptoss, gemma, falcon_h1, solar_open2, evabyte,
-    cohere2_moe, minicpm_sala, dots3_note,
+    cohere2_moe, minicpm_sala, dots3_note, ouro,
 )
 
 
@@ -120,15 +120,16 @@ def read_counters(cfg) -> tuple:
 
 
 # What a family cannot do yet, as ``trait -> what was asked -> why``. The
-# first four traits are FOUND from what the family already declares, and put
-# it on the one-chip text path: slot state beside the pages (``state_spec``),
-# a ring of pages with summaries (``window_ring``), a latent held as rows of
-# 128 lanes or a held share of the experts (the configuration's
-# ``latent_rows`` / ``experts_held``), pages kept by layer kind (more than one
-# of ``page_groups``). A family of several (models/dots3_note.py: rows and
-# groups; models/solar_open2.py: state and a share) is answered from all of
-# them together. The rest a module STATES: its ``TRAITS``, and ``ADAPTERS``
-# where it has a LoRA path (``llama`` alone).
+# first five traits are FOUND from what the family already declares: slot
+# state beside the pages (``state_spec``), a ring of pages with summaries
+# (``window_ring``), a latent held as rows of 128 lanes or a held share of
+# the experts (the configuration's ``latent_rows`` / ``experts_held``), pages
+# kept by layer kind (more than one of ``page_groups``), which put it on the
+# one-chip text path, and page slots that outnumber the layers
+# (``page_passes`` above 1), which refuses no ``tp``. A family of several
+# (models/dots3_note.py: rows and groups; models/solar_open2.py: state and a
+# share) is answered from all of them together. The rest a module STATES: its
+# ``TRAITS``, and ``ADAPTERS`` where it has a LoRA path (``llama`` alone).
 _NO_ADAPTERS = "LoRA: the family has no adapter path"
 _DENSE_VISION = "vision: multimodal serving covers the dense family only"
 _WHAT = {
@@ -137,6 +138,8 @@ _WHAT = {
     "rows": "a latent held as rows of 128 lanes (learned sparse attention, "
             "or none) / a held share of the experts ({name})",
     "groups": "pages kept by layer kind ({name})",
+    "passes": "page slots that outnumber the layers, one a (pass, layer) "
+              "({name})",
 }
 _WHY = {
     "state": {
@@ -215,8 +218,27 @@ _WHY = {
                 "a block hash, and a windowed group's pages are let go "
                 "under a live request",
     },
+    "passes": {
+        "pp_sp": "pp / sp > 1: the wavefront stacks num_layers pools over "
+                 "its stages and runs them once (parallel/pp_serving.py), "
+                 "and the ring attends one table with no pass to offset it",
+        "spec": "a speculative draft: its shadow cache and the verify rows "
+                "are addressed by the ONE table with no pass to offset it",
+        "kv_quantized": "kv_dtype=int8: the scale rows are sized by "
+                        "num_blocks, not by the slots' pages, and the "
+                        "family's cell holds its pages to bf16 (an 8-bit "
+                        "cache is not calibrated for it)",
+        "vision": _DENSE_VISION,
+        "transfer": "the KV transfer plane (disaggregation, evacuation): it "
+                    "moves num_layers pages a block "
+                    "(kvbm/layout.block_shape_for), one pass's share of "
+                    "what a block of slots holds",
+        "kvbm": "KVBM offload tiers: kvbm/layout.py counts num_layers pages "
+                "a block hash, and the gather reads block ids below "
+                "num_blocks: one pass's slots of every layer",
+    },
     # stated by a module; each sentence is the whole message, and they are
-    # asked in this order once the four above have refused nothing
+    # asked in this order once the five above have refused nothing
     "window_extras": {
         "sp": "sliding-window attention (gpt-oss/gemma) does not ride the "
               "ring (sp) path yet; use chunked prefill on sp=1",
@@ -238,6 +260,7 @@ def traits(cfg) -> tuple:
         "rows": bool(getattr(cfg, "latent_rows", False)
                      or getattr(cfg, "experts_held", None)),
         "groups": len(page_groups(cfg)) > 1,
+        "passes": page_passes(cfg) > 1,
         "no_adapters": not getattr(family(cfg), "ADAPTERS", False),
     }
     stated = getattr(family(cfg), "TRAITS", ())
@@ -297,14 +320,35 @@ def state_spec(cfg) -> tuple:
 
 
 def page_layers(cfg) -> tuple:
-    """The model layers that keep PAGES of keys and values, in order: every
-    layer, except where a family's layers are of kinds that keep different
-    state (``solar_open2``: its softmax-attention layers only). The engine
-    allocates one pair of page arrays a layer named here and ``attend``
-    finds a layer's pair by its place in this tuple (``layer_index``). One
-    block table still serves every page layer."""
+    """The layers whose attention keeps PAGES of keys and values, in order:
+    every layer, except where a family's layers are of kinds that keep
+    different state (``solar_open2``: its softmax-attention layers only). The
+    engine allocates one pair of page arrays a layer named here and
+    ``attend`` finds a layer's pair by its place in this tuple
+    (``layer_index``). One block table still serves every page layer. How
+    many SLOTS of pages a block id names is ``page_slots``: a layer named
+    here may keep more than one (``page_passes``)."""
     own = _ask(cfg, "page_layers")
     return tuple(range(cfg.num_layers)) if own is None else own
+
+
+def page_passes(cfg) -> int:
+    """How many pools each page layer's arrays hold, one behind another: 1,
+    but for a family that runs its stack several times a token and keeps a
+    cache slot a (pass, layer) (``ouro.page_passes``). Block id ``b`` of pass
+    ``t`` is page ``t x num_blocks + b`` of the layer's arrays; the family's
+    ``forward`` hands ``attend`` the pass (``page_pass``) and the engine's
+    seams add ``t x num_blocks`` to the tables they hold, so a run of
+    consecutive pages stays a run."""
+    return int(_ask(cfg, "page_passes", 1))
+
+
+def page_slots(cfg) -> int:
+    """The slots of pages ONE block id names (what a block holds, a prefix
+    hit restores and a released block gives back): a slot a page layer, times
+    the passes that each keep their own (``page_passes``). It may outnumber
+    the model's layers."""
+    return len(page_layers(cfg)) * page_passes(cfg)
 
 
 def page_groups(cfg) -> tuple:

@@ -524,6 +524,13 @@ def _cases():
             192, 4096, 4096, 2, experts=16),
         "grouped-matmul-down-16x4096-rows4288": grouped(
             4288, 4096, 4096, 1, experts=16),
+        # the looped decoder's cell (PR 60): 16 q / 16 kv heads, ONE query
+        # head a kv head (no other cell's group is 1), 64 KiB pages; 8 decode
+        # rows over tables of 42 pages of a layer's four pools one behind
+        # another (4 x 344 pages), and a 256-token chunk beside them
+        "decode-bf16-16q-16kv-reason-steps-cell": decode(16, 16, 8, 42, 1376),
+        "unified-16q-16kv-reason-steps-cell": unified_cell(
+            16, 16, 256 + 8, 9, 42, 1376),
         "gather-blocks": moves(bc.gather_blocks, 1, False),
         "scatter-blocks": moves(bc.scatter_blocks, 1, True),
         "copy-blocks": moves(bc.copy_blocks, 2, False),
@@ -915,9 +922,16 @@ PARENT_LAUNCH_TEXTS = json.loads(open(os.path.join(
     os.path.dirname(__file__), "data", "launch_texts_pr56.json")).read())
 
 
+# launches added to the table since those texts were recorded: no parent
+# lowered them
+SINCE_THE_PARENTS_TEXTS = {
+    "decode-bf16-16q-16kv-reason-steps-cell", "unified-16q-16kv-reason-steps-cell",
+}
+
+
 def test_every_launch_is_pinned_but_the_selecting_attends():
     pinned = set(PARENT_KERNEL_TEXTS) | set(PARENT_LAUNCH_TEXTS)
-    assert set(CASES) - pinned == set(SELECTING_ATTENDS)
+    assert set(CASES) - pinned == set(SELECTING_ATTENDS) | SINCE_THE_PARENTS_TEXTS
 
 
 @pytest.mark.parametrize("case", sorted(PARENT_LAUNCH_TEXTS))
